@@ -122,9 +122,13 @@ def _hist_p99_ms(h):
 
 def main():
     t_start = time.time()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # the platform is jax's own choice (JAX_PLATFORMS, else what it
+    # finds): no default to cpu, no probe — and it is printed, so a
+    # number is never read as another platform's
     from tinysql_tpu.ops import kernels
-    kernels.ensure_live_backend()
+    dev0 = kernels.jax().devices()[0]
+    print(f"[serve] jax backend: {dev0.platform} ({dev0.device_kind})",
+          file=sys.stderr)
 
     n_clients = int(os.environ.get("SERVE_CLIENTS", "8"))
     sf = float(os.environ.get("SERVE_SF", "0.02"))
@@ -679,6 +683,8 @@ def main():
         "queue_wait_p99_ms": round(queue_p99_ms, 2),
         "queue_wait_stmts": queue_hist["count"],
         "total_bench_seconds": round(time.time() - t_start, 1),
+        "platform": dev0.platform,
+        "device_kind": dev0.device_kind,
     }
     print(json.dumps({"metric": "serve_qps", "value": round(qps, 2),
                       "unit": "qps", "detail": detail}))

@@ -387,7 +387,7 @@ def _dispatch(tk):
     want = s.query("select b, sum(a) from t group by b order by b").rows
     _tpu_session(s)
     with fail.armed("kernelDispatchError",
-                    exc=degrade.DeviceLost("tunnel dropped")):
+                    exc=degrade.DeviceLost("device dropped")):
         got = s.query("select b, sum(a) from t group by b order by b").rows
     assert got == want  # transparent CPU re-execution, same answer
     assert degrade.snapshot()["degraded_statements_total"] == 1
@@ -403,30 +403,6 @@ def _d2h(tk):
         got = s.query("select sum(a), count(*) from t").rows
     assert got == want
     assert degrade.snapshot()["device_loss_total"] == 1
-
-
-@chaos("backendProbeFail")
-def _probe(tk, monkeypatch=None):
-    from tinysql_tpu.ops import kernels
-    import jax
-    probed = kernels._probed
-    prev_plats = jax.config.jax_platforms
-    try:
-        kernels._probed = False
-        with fail.armed("backendProbeFail"):
-            kernels.ensure_live_backend(jax)  # must return, never hang
-        assert str(jax.config.jax_platforms) == "cpu"
-        # error actions (the only kind a spec string can arm besides
-        # return) mean "probe failed" too: pin cpu, never propagate
-        kernels._probed = False
-        with fail.armed("backendProbeFail", exc=RuntimeError("probe x")):
-            kernels.ensure_live_backend(jax)
-        assert str(jax.config.jax_platforms) == "cpu"
-    finally:
-        kernels._probed = probed
-        # un-pin: on a device-backed dev box the rest of the session
-        # must not silently run on cpu
-        jax.config.update("jax_platforms", prev_plats)
 
 
 @chaos("ddlStepError")
@@ -1011,6 +987,33 @@ def test_sysvar_armed_dispatch_fault_degrades_too(tk):
     finally:
         s.execute("set @@tidb_failpoints = ''")
     assert degrade.snapshot()["degraded_statements_total"] == 1
+
+
+@pytest.mark.parametrize("status, degrades", [
+    ("INTERNAL: the compiler refused this program", False),
+    ("RESOURCE_EXHAUSTED: out of device memory", False),
+    ("UNIMPLEMENTED: no lowering for this operation", False),
+    ("UNAVAILABLE: connection to the device lost", True),
+])
+def test_jax_runtime_error_degrades_only_on_lost_device(tk, status, degrades):
+    """jax raises ONE error type for a compile refusal, an exhausted
+    resource and a lost connection: only a status that says the device or
+    the connection went away re-runs on CPU and pins the process; every
+    other one fails the statement loudly and demotes nothing."""
+    from jax.errors import JaxRuntimeError
+    s, _ = tk
+    want = s.query("select sum(a) from t").rows
+    _tpu_session(s)
+    with fail.armed("kernelDispatchError", exc=JaxRuntimeError(status),
+                    times=1):
+        if degrades:
+            assert s.query("select sum(a) from t").rows == want
+        else:
+            with pytest.raises(JaxRuntimeError, match=status.split(":")[0]):
+                s.query("select sum(a) from t")
+    snap = degrade.snapshot()
+    assert snap["device_loss_total"] == (1 if degrades else 0)
+    assert snap["degraded_statements_total"] == (1 if degrades else 0)
 
 
 def test_device_loss_on_write_surfaces_error(tk):

@@ -7,6 +7,7 @@ INERT through the valid mask for the hash-agg and topk/sort kernels —
 the invariant the async block pipeline's per-block padding rides on.
 """
 import numpy as np
+import pytest
 
 from tinysql_tpu.ops import kernels
 
@@ -140,3 +141,111 @@ def test_sort_permutation_padding_inert():
     assert sorted(perm.tolist()) == [0, 1, 2, 3, 4]
     assert perm.tolist() == sorted(
         range(5), key=lambda i: (a[i], -b[i]))
+
+
+# ---- prefix_sum() / lex_head() -------------------------------------------
+
+def test_prefix_sum_matches_cumsum_across_the_chunk_boundary():
+    """Below PREFIX_CHUNKS a tree scan, above it chunks scanned in
+    lockstep: both are the inclusive prefix sum — exact for int64, to
+    float rounding for float64 (the order of addition differs)."""
+    j = kernels.jax()
+    rng = np.random.default_rng(3)
+    for n in (16, kernels.PREFIX_CHUNKS, 4 * kernels.PREFIX_CHUNKS):
+        xi = rng.integers(-1 << 40, 1 << 40, n)
+        np.testing.assert_array_equal(
+            np.asarray(j.jit(kernels.prefix_sum)(xi)), np.cumsum(xi))
+        xf = rng.uniform(0.0, 1e5, n)
+        np.testing.assert_allclose(
+            np.asarray(j.jit(kernels.prefix_sum)(xf)), np.cumsum(xf),
+            rtol=1e-12)
+
+
+def test_lex_head_is_the_head_of_a_stable_lexsort():
+    """Selection (k <= LEX_SELECT_MAX) and the full-sort fallback agree
+    with numpy's lexsort: last operand primary, ties to the lowest row,
+    infinities in order."""
+    j = kernels.jax()
+    rng = np.random.default_rng(1)
+    big = kernels.LEX_SELECT_MAX + 16
+    for n, k in ((16, 16), (1024, 16), (4096, 64), (100, 7), (256, big)):
+        ops = [rng.integers(0, 5, n).astype(np.int64),
+               rng.choice([1.5, -2.0, np.inf, -np.inf, 0.0, 3.25], n),
+               rng.integers(0, 2, n).astype(np.int8)]
+        got = np.asarray(j.jit(lambda o: kernels.lex_head(o, k))(ops))
+        np.testing.assert_array_equal(got, np.lexsort(ops)[:k])
+
+
+# ---- compile-cache placement ---------------------------------------------
+
+_CACHE_PROBE = """
+import json, sys
+from tinysql_tpu.ops import kernels
+before = kernels._cache_dir()
+jax = kernels.jax()
+in_jax = jax.config.jax_compilation_cache_dir
+jax.devices()                       # the backend exists from here on
+after_backend = kernels._cache_dir()
+from tinysql_tpu.session.session import new_session
+s = new_session()
+s.execute("set @@tidb_compile_cache_dir = %r")
+print(json.dumps({"before": before, "in_jax": in_jax,
+                  "after_backend": after_backend,
+                  "after_set": jax.config.jax_compilation_cache_dir,
+                  "warnings": [w[2] for w in s.last_warnings]}))
+"""
+
+
+@pytest.fixture(scope="module")
+def cache_probe(tmp_path_factory):
+    """``probe(env_dir)`` -> what a FRESH process saw (jax reads
+    JAX_COMPILATION_CACHE_DIR as it is imported, and the backend is not
+    yet there), one child per distinct ``env_dir``."""
+    import functools
+    import json
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    by_sysvar = str(tmp_path_factory.mktemp("cache") / "by-sysvar")
+
+    @functools.lru_cache(maxsize=None)
+    def probe(env_dir: str) -> dict:
+        env = dict(os.environ, PYTHONPATH=repo)  # conftest pins cpu for it
+        env.pop(kernels.CACHE_DIR_ENV, None)
+        if env_dir:
+            env[kernels.CACHE_DIR_ENV] = env_dir
+        r = subprocess.run(
+            [sys.executable, "-c", _CACHE_PROBE % by_sysvar],
+            capture_output=True, text=True, timeout=120, env=env, cwd=repo)
+        assert r.returncode == 0, r.stderr[-2000:]
+        return json.loads(r.stdout.strip().splitlines()[-1])
+    probe.repo, probe.by_sysvar = repo, by_sysvar
+    return probe
+
+
+def test_cache_dir_from_the_standard_variable_wins(cache_probe, tmp_path):
+    """Variable set: jax keeps its cache there, the engine sets none in
+    code, and a SET of the sysvar changes nothing and says which won."""
+    want = str(tmp_path / "from-env")
+    got = cache_probe(want)
+    assert got["before"] == got["in_jax"] == got["after_backend"] \
+        == got["after_set"] == want
+    assert len(got["warnings"]) == 1
+    assert kernels.CACHE_DIR_ENV in got["warnings"][0]
+    assert want in got["warnings"][0]
+
+
+def test_cache_dir_default_is_one_fixed_path(cache_probe):
+    """Variable unset: <repo>/.jax_cache — the same before and after the
+    backend exists, so two processes share it."""
+    import os
+    got = cache_probe("")
+    assert got["before"] == got["in_jax"] == got["after_backend"] \
+        == os.path.join(cache_probe.repo, ".jax_cache")
+
+
+def test_cache_dir_sysvar_moves_it_when_the_variable_is_unset(cache_probe):
+    got = cache_probe("")
+    assert got["after_set"] == cache_probe.by_sysvar
+    assert got["warnings"] == []

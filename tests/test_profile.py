@@ -318,3 +318,28 @@ def test_dispatch_storm_finding_over_sql(tp):
         assert rs.rows[0][1] == "critical"
     finally:
         tsring.RING.reset()
+
+
+def test_cost_tracking_counts_flops():
+    """counted_jit accrues XLA cost-model flops/bytes when tracking is on
+    (the bench's MFU accounting)."""
+    kernels.enable_cost_tracking(True)
+    try:
+        jn = kernels.jnp()
+        snap = kernels.stats_snapshot()
+        f = kernels.counted_jit(lambda a, b: a @ b)
+        x = jn.ones((64, 64))
+        f(x, x)                         # first sight: enqueues only
+        kernels.resolve_pending_costs()  # outside any timed region
+        f(x, x)
+        f(x, x)
+        d = kernels.stats_delta(snap)
+        assert d["dispatches"] == 3
+        if d["flops"] == 0:
+            # resolution degrades to zeros on backends without a cost model
+            pytest.skip("backend exposes no XLA cost analysis")
+        # 2 post-resolution dispatches x 2*64^3 flops per the cost model
+        assert d["flops"] == 2 * 2 * 64 ** 3, d
+        assert d["bytes_accessed"] > 0
+    finally:
+        kernels.enable_cost_tracking(False)

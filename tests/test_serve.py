@@ -283,13 +283,13 @@ def test_batched_equals_solo_byte_identical(server):
         == st0["stacked_occupancy_sum"] + len(qs)
     assert progcache.stats_snapshot()["misses"] == miss0  # zero compiles
     for s in sessions:
-        d = s.last_query_stats.device_totals()
-        # occupancy-weighted share of the one stacked dispatch: the sum
-        # across members reconciles with the global counter
-        assert d.get("coalesced") == 1 and d.get("dispatches", 0) > 0
+        assert s.last_query_stats.device_totals().get("coalesced") == 1
+    # the one stacked dispatch is an integer counter: it is attributed
+    # whole to one member (shardops.split_exact), and the sum across
+    # members reconciles with the global counter exactly
     total = sum(s.last_query_stats.device_totals().get("dispatches", 0)
                 for s in sessions)
-    assert total == pytest.approx(1.0)
+    assert total == 1
 
 
 def test_batch_duplicate_statements_share_round(server):

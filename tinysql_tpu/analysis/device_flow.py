@@ -130,7 +130,7 @@ _VAL_LAUNDER = {"bucket", "occupancy_bucket", "len", "stable_shape_key",
 
 #: mesh collectives: legal only under a shard_map wired through
 #: parallel/dist.py (shard_map_fn / shard_map_unchecked) — a raw
-#: collective outside that wiring dodges the version-fallback shim AND
+#: collective outside that wiring dodges the one import point AND
 #: the sharded tier's counter discipline
 _COLLECTIVES = {"psum", "pmin", "pmax", "all_gather", "all_to_all",
                 "ppermute", "psum_scatter", "axis_index", "pbroadcast"}
@@ -796,7 +796,7 @@ def _mesh_discipline_diags(prog: _Program) -> List[Diagnostic]:
     for m in prog.modules:
         if _mod_endswith(m.modpath, _MESH_OWNER):
             continue  # parallel/dist.py IS the wiring layer
-        # DF805a: raw shard_map import — the version-fallback shim and
+        # DF805a: raw shard_map import — the one import point and
         # the unchecked-replication variant live in dist.py alone
         for node in ast.walk(m.sf.tree):
             if isinstance(node, ast.ImportFrom):
@@ -809,7 +809,7 @@ def _mesh_discipline_diags(prog: _Program) -> List[Diagnostic]:
                         "DF805",
                         "raw shard_map import outside parallel/dist.py — "
                         "construct through dist.shard_map_fn / "
-                        "shard_map_unchecked (one jax-version fallback, "
+                        "shard_map_unchecked (one import point, "
                         "one replication-check policy)",
                         m.sf.path, node.lineno, node.col_offset))
             elif isinstance(node, ast.Import):

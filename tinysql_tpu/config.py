@@ -49,8 +49,8 @@ class Config:
     num_stores: int = 1
     use_tpu: bool = True
     # persistent XLA compile-cache directory; "" = <repo>/.jax_cache
-    # (ops/kernels.py _cache_dir resolution: sysvar tidb_compile_cache_dir
-    # > TINYSQL_JAX_CACHE env > this entry > default)
+    # (ops/kernels.py _cache_dir resolution: JAX_COMPILATION_CACHE_DIR
+    # env > sysvar tidb_compile_cache_dir > this entry > default)
     compile_cache_dir: str = ""
     # durability arming (kv/wal.py): directory for the MVCC WAL +
     # checkpoints.  "" = volatile in-memory store, byte-identical to the

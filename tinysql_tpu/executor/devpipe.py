@@ -901,7 +901,7 @@ class _AggIndexNode:
             prev_safe = jn.maximum(prev, 0)
 
             def seg(x_s):
-                c = jn.cumsum(x_s)
+                c = kernels.prefix_sum(x_s)
                 hi = c[ends]
                 lo = jn.where(prev >= 0, c[prev_safe],
                               jn.zeros((), dtype=x_s.dtype))
@@ -1854,7 +1854,7 @@ class _SortGroupNode:
                 # window sum [i, end_i] gathered at the leaders;
                 # contributions are pre-masked so the last group's window
                 # absorbing the invalid tail adds zero
-                c = jn.cumsum(x_s)
+                c = kernels.prefix_sum(x_s)
                 c0 = jn.concatenate([jn.zeros(1, dtype=x_s.dtype), c[:-1]])
                 return (c[end] - c0)[lead_pos]
 
@@ -2269,8 +2269,8 @@ class _OrderNode:
                     kvs.append(pairs[f])
                 else:
                     kvs.append(f(pairs, pr))
-            perm = jn.lexsort(_sort_ops(jn, kvs, descs, valid))
-            take = perm[off:kb]
+            take = kernels.lex_head(
+                _sort_ops(jn, kvs, descs, valid), kb)[off:]
             out_valid = valid[take]
             if count is not None:
                 # valid rows sort first, so the taken valid rows are a
@@ -2307,8 +2307,8 @@ class _OrderNode:
             si = lax.axis_index("shard").astype(jn.int64)
             gidx = si * per + jn.arange(per, dtype=jn.int64)
             kvs = pick_kvs(fn_kvs, pairs)
-            perm = jn.lexsort([gidx] + _sort_ops(jn, kvs, descs, valid))
-            take = perm[:kc]
+            take = kernels.lex_head(
+                [gidx] + _sort_ops(jn, kvs, descs, valid), kc)
             lanes = ([(kv[0][take], kv[1][take]) for kv in fn_kvs]
                      + [(v[take], m[take]) for v, m in pairs])
             g_valid = lax.all_gather(valid[take], "shard", tiled=True)

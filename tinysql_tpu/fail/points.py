@@ -80,11 +80,6 @@ KERNEL_DISPATCH_ERROR = register(
 KERNEL_D2H_ERROR = register(
     "kernelD2HError",
     "every device->host materialization (ops/kernels.py d2h/d2h_many)")
-BACKEND_PROBE_FAIL = register(
-    "backendProbeFail",
-    "backend liveness probe reports the device backend unreachable — "
-    "engine must pin jax_platforms=cpu instead of hanging "
-    "(ops/kernels.py ensure_live_backend)")
 
 # ---- DDL -------------------------------------------------------------------
 DDL_STEP_ERROR = register(

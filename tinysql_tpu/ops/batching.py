@@ -301,10 +301,10 @@ class BatchRound:
             _stat_add("stack_fallbacks")
             return False
         totals = cap.device_totals()
-        # exact occupancy split (shardops.split_exact): members' shares
-        # sum to the round's totals to the last ulp, so per-member (and,
-        # for sharded programs, per-shard) attribution reconciles with
-        # the global counters EXACTLY, not just approximately
+        # occupancy split (shardops.split_exact): integer counters split
+        # as integers and sum to the round's totals exactly, real-valued
+        # ones to float rounding — per-member (and, for sharded programs,
+        # per-shard) attribution reconciles with the global counters
         from . import shardops
         shares = shardops.split_exact(totals, n)
         if shardops.shards_of_key(p0.key) > 1:

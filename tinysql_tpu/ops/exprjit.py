@@ -28,7 +28,7 @@ def jnp():
     global _jnp
     if _jnp is None:
         from . import kernels
-        _jnp = kernels.jnp()  # shares x64 + backend-liveness handling
+        _jnp = kernels.jnp()  # shares the one-time jax configuration (x64)
     return _jnp
 
 

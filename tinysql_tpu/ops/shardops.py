@@ -141,32 +141,39 @@ def note_stacked_round() -> None:
 # ---- exact attribution splits ---------------------------------------------
 
 def split_exact(totals: dict, k: int) -> List[dict]:
-    """Split a device-counter dict into ``k`` per-member shares whose
-    per-key sums equal the input EXACTLY (float error included): the
-    first k-1 members take ``v / k`` and the last takes the remainder.
+    """Split a device-counter dict into ``k`` per-member shares.
+
+    INTEGER counters (dispatches, transfers, bytes) split as integers:
+    every member takes ``v // k`` and the last one also the remainder,
+    so the shares sum to ``v`` exactly, in any order, under any ``sum``.
+    REAL-valued ones (``device_s``, cost-model flops) split as ``v / k``
+    with the last member taking what is left; their sum equals ``v`` to
+    float rounding — a few ulp, depending on the order and on whether
+    the adder compensates (Python's ``sum`` does since 3.12) — and is
+    compared with a tolerance, never with ``==``.
     Used by the batching dispatch leg for occupancy shares and by the
-    sharded tier for per-shard shares — nesting the two (B members x N
-    shards) still sums exactly to the round's global counters."""
+    sharded tier for per-shard shares; the two nest (B members x N
+    shards)."""
     if k <= 1:
         return [dict(totals)]
     shares: List[dict] = [dict() for _ in range(k)]
     for key, v in totals.items():
-        q = v / k
-        acc = type(v)(0)
+        if isinstance(v, (int, np.integer)):
+            q, last = v // k, v // k + v % k
+        else:
+            q = v / k
+            last = v - q * (k - 1)
         for i in range(k - 1):
             shares[i][key] = q
-            acc += q
-        shares[k - 1][key] = v - acc
+        shares[k - 1][key] = last
     return shares
 
 
 def member_shard_shares(totals: dict, b: int, n: int) -> List[List[dict]]:
     """B x N attribution cells for one stacked-over-sharded dispatch:
-    member shares split exactly, each member's share split exactly again
-    across the N shards.  Summed in the nested reduction order (shards
-    within a member, then members — the order statements_summary
-    reconciles in) the cells equal ``totals`` key by key, exactly;
-    a flat sum over all B*N cells is order-sensitive float addition."""
+    member shares split by :func:`split_exact`, each member's share split
+    again across the N shards.  Integer counters sum back to ``totals``
+    exactly; real-valued ones to float rounding."""
     return [split_exact(m, n) for m in split_exact(totals, b)]
 
 
@@ -508,7 +515,7 @@ def fused_scalar_aggregate_sharded(mesh, dev_cols, agg_specs, arg_exprs,
 
     def build():
         arg_fns = [kernels._lower_arg(e) for e in arg_exprs]
-        shard_map, P = dist.shard_map_fn()
+        _, P = dist.shard_map_fn()
         col_spec = tuple(
             ((P("shard") if c[0] is not None else None, P("shard"))
              if c is not None else None)
@@ -557,9 +564,9 @@ def fused_scalar_aggregate_sharded(mesh, dev_cols, agg_specs, arg_exprs,
                             fill = jn.inf if func == "min" else -jn.inf
                         red = jn.min if func == "min" else jn.max
                         local = red(jn.where(live, av, fill))
-                        merged = (j.lax.pmin(local, "shard")
+                        merged = (dist.mesh_min(local)
                                   if func == "min"
-                                  else j.lax.pmax(local, "shard"))
+                                  else dist.mesh_max(local))
                         outs.append((merged[None], (cnt == 0)[None]))
                     else:  # pragma: no cover
                         raise ValueError(func)
@@ -570,14 +577,16 @@ def fused_scalar_aggregate_sharded(mesh, dev_cols, agg_specs, arg_exprs,
                 # empty shards past every real row before the pmin
                 local_first = jn.where(jn.any(valid),
                                        jn.argmax(valid) + base, nb)
-                first = j.lax.pmin(local_first, "shard")
+                first = dist.mesh_min(local_first)
                 first = jn.where(first >= nb, 0, first)
                 items = [n_valid[None], first[None]]
                 for v, m in outs:
                     items += [v, m]
                 return items
 
-            sm = shard_map(
+            # replicated by construction (psum, mesh_min/max): the
+            # gather-and-reduce merges are beyond the static checker
+            sm = dist.shard_map_unchecked(
                 body, mesh=mesh,
                 in_specs=(col_spec, P("shard"), (P(), P())),
                 out_specs=P())

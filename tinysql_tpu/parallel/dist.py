@@ -52,28 +52,36 @@ from ..ops import kernels
 
 
 def shard_map_fn():
-    """(shard_map, PartitionSpec) with the jax-version fallback in ONE
-    place — every mesh kernel imports through here."""
+    """(shard_map, PartitionSpec) — every mesh kernel imports through
+    here (qlint DF805)."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
     return shard_map, PartitionSpec
 
 
 def shard_map_unchecked(fn, mesh, in_specs, out_specs):
     """shard_map for kernels whose outputs are replicated by construction
     (all_gather + pure compute): the static replication checker cannot
-    prove it, so disable it — kwarg name varies by jax version."""
+    prove it, so it is turned off."""
     shard_map, _ = shard_map_fn()
-    for kw in ("check_vma", "check_rep"):
-        try:
-            return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **{kw: False})
-        except TypeError:
-            continue
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)
+
+
+def mesh_min(x, axis: str = "shard"):
+    """Inside shard_map: elementwise min over the mesh axis.  The TPU
+    lowers an all-reduce of an emulated 64-bit type for Sum only
+    (``lax.pmin`` of an int64 is refused as UNIMPLEMENTED), so the
+    partials — small per-shard tables — are gathered and reduced on
+    every shard."""
+    jax = kernels.jax()
+    return jax.numpy.min(jax.lax.all_gather(x, axis), axis=0)
+
+
+def mesh_max(x, axis: str = "shard"):
+    """Elementwise max over the mesh axis; see :func:`mesh_min`."""
+    jax = kernels.jax()
+    return jax.numpy.max(jax.lax.all_gather(x, axis), axis=0)
 
 
 def make_mesh(n_devices: Optional[int] = None):

@@ -69,8 +69,9 @@ DEFAULT_SYSVARS: Dict[str, Datum] = {
     # the TINYSQL_PIPELINE_DEPTH env var overrides for tests/CI
     "tidb_pipeline_depth": 2,
     # persistent XLA compile-cache directory so bucketed kernels survive
-    # process restarts ("" = engine default <repo>/.jax_cache; see
-    # ops/kernels.py set_compile_cache_dir for the resolution chain)
+    # process restarts ("" = engine default <repo>/.jax_cache; where
+    # JAX_COMPILATION_CACHE_DIR is set it wins and a SET only warns —
+    # ops/kernels.py _cache_dir has the resolution chain)
     "tidb_compile_cache_dir": "",
     # opt-in runtime arm of the qlint plan-device checker: verify every
     # placed plan's device invariants before execution (analysis/
@@ -1242,7 +1243,11 @@ class Session:
                 # bucket programs from this point on persist under the
                 # new directory (ops/kernels.py resolution chain)
                 from ..ops import kernels
-                kernels.set_compile_cache_dir(str(v) if v else "")
+                if not kernels.set_compile_cache_dir(str(v) if v else ""):
+                    self.add_warning(
+                        "Warning", 1105,
+                        f"{kernels.CACHE_DIR_ENV} is set and wins: the "
+                        f"compile cache stays in {kernels._cache_dir()}")
             elif name == "tidb_device_profile_rate":
                 # the dispatch path is process-global: apply immediately
                 # (ops/profiler.py owns the sampling decision)

@@ -58,6 +58,9 @@ import time
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
 
+# a chip belongs to one process at a time, and this harness starts and
+# SIGKILLs server children: parent and children are held to the CPU, so
+# none of them ever takes (or is left holding) the accelerator
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 OPENING = 100  # per-account opening balance
@@ -87,7 +90,7 @@ class ServerProc:
 
     def __init__(self, data_dir: str, extra_env=None):
         env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
+        env["JAX_PLATFORMS"] = "cpu"  # see the module-level note
         # tiny rotation threshold keeps checkpoints continual so the
         # mid-checkpoint window is routinely open
         env.setdefault("TINYSQL_WAL_CHECKPOINT_BYTES", "65536")

@@ -40,6 +40,9 @@ import time
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
 
+# a chip belongs to one process at a time, and this harness kills and
+# restarts server children (tools/crash_recovery.ServerProc pins them to
+# cpu): the parent stays on the CPU too, so nothing here takes the chip
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
