@@ -147,7 +147,6 @@ def main():
             s, tpch.QUERIES,
             stats_path=os.environ.get("TINYSQL_STATS_FEEDBACK", ""))
 
-    profile_dir = os.environ.get("TPCH_PROFILE")
     run_stats = {}
 
     def run(sql, tier):
@@ -255,22 +254,6 @@ def main():
                                   int(stats.get("dispatches", 0)),
                               **stats, **extra}
         return best, rows
-
-    if profile_dir:
-        # one traced warm run per query: jax.profiler device trace
-        # (viewable with tensorboard / xprof) — the device-occupancy
-        # artifact
-        try:
-            import jax
-            s.execute("set @@tidb_use_tpu = 1")
-            for name, sql in tpch.QUERIES.items():
-                s.query(sql)  # warm compile outside the trace
-                with jax.profiler.trace(os.path.join(profile_dir, name)):
-                    s.query(sql)
-            print(f"[bench] profiler traces in {profile_dir}",
-                  file=sys.stderr)
-        except Exception as e:  # pragma: no cover
-            print(f"[bench] profiler unavailable: {e}", file=sys.stderr)
 
     results = {}
     for name, sql in tpch.QUERIES.items():

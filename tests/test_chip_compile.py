@@ -224,7 +224,7 @@ def test_fused_devpipe_program(one_chip, chip_branches, monkeypatch,
     """Run the statement up to its first dispatch, capture the fused
     program with its inputs, and compile THAT for the described device
     instead of running it here."""
-    def capturing_jit(fn, **kw):
+    def capturing_jit(fn, name="", **kw):
         def call(*args):
             raise _Captured(jax.jit(fn, **kw), args)
         return call

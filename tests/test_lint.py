@@ -792,6 +792,32 @@ def test_ob406_reads_and_unrelated_names_silent(tmp_path):
     assert lint_obs_discipline(SourceFile(str(p))) == []
 
 
+def test_spantotals_fixture_fires_ob408():
+    sf = SourceFile(os.path.join(FIXDIR, "bad_spantotals.py"))
+    diags = lint_obs_discipline(sf)
+    # 3 subscript writes + 2 mutating calls + 2 calls of the private
+    # writer; reads, totals() and the look-alike class stay silent
+    assert sorted(d.line for d in diags) == [9, 10, 11, 12, 13, 14, 15], \
+        [d.format() for d in diags]
+    assert {d.rule for d in diags} == {"OB408"}
+
+
+def test_ob408_owning_module_exempt_and_tree_clean(tmp_path):
+    # obs/trace.py owns the table: a same-named file is exempt by
+    # basename, and it is the only writer in the package
+    p = tmp_path / "trace.py"
+    p.write_text("from tinysql_tpu.obs.trace import _TOTALS\n"
+                 "_TOTALS['x'] = [1, 0.0, 0.0, 0.0]\n")
+    assert lint_obs_discipline(SourceFile(str(p))) == []
+    pkg = os.path.join(REPO, "tinysql_tpu")
+    for where, _dirs, files in os.walk(pkg):
+        for f in files:
+            if f.endswith(".py"):
+                diags = lint_obs_discipline(
+                    SourceFile(os.path.join(where, f)))
+                assert not [d for d in diags if d.rule == "OB408"], f
+
+
 def test_memprof_fixture_fires_ob407():
     sf = SourceFile(os.path.join(FIXDIR, "bad_memprof.py"))
     diags = lint_obs_discipline(sf)
@@ -931,6 +957,7 @@ def test_corpus_plans_clean():
     ("obs", "bad_devtime.py"),
     ("obs", "bad_conprof.py"),
     ("obs", "bad_memprof.py"),
+    ("obs", "bad_spantotals.py"),
     ("conc", "bad_race.py"),
     ("conc", "bad_lockorder.py"),
     ("conc", "bad_blocking.py"),

@@ -33,6 +33,10 @@ def server():
     srv = Server(storage, port=0)
     srv.start()
     boot = Session(storage)
+    # these tests count the process's progcache misses around a round:
+    # the auto-prewarm worker (first cycle 60 s after start, well inside
+    # this module on a loaded machine) must not build beside them
+    boot.execute("set global tidb_auto_prewarm = 0")
     boot.execute("create database if not exists stk")
     boot.execute("use stk")
     boot.execute("create table t (a int primary key, b int, c double)")

@@ -385,7 +385,11 @@ def test_pool_worker_spans_parent_to_submitting_thread(storage):
         spans = s.last_query_stats.tracer.spans()
         execute = [sp for sp in spans if sp["name"] == "execute"]
         assert execute, spans
-        assert execute[0]["parent"] == root.sid
+        # ... through the connection thread's pool.wait (submit -> done)
+        wait = [sp for sp in outer.tracer.spans()
+                if sp["name"] == "pool.wait"]
+        assert wait and wait[0]["parent"] == root.sid, outer.tracer.spans()
+        assert execute[0]["parent"] == wait[0]["id"]
         # and the chain below it is intact: plan/place parent to execute
         children = {sp["name"] for sp in spans
                     if sp["parent"] == execute[0]["id"]}

@@ -58,6 +58,15 @@ Rules:
   ``HeapProfiler`` instances) would corrupt the rotation/eviction
   accounting behind ``information_schema.memory_usage`` and
   ``/debug/heap``.
+- **OB408**: span-totals write outside ``obs/trace.py``.  The table
+  ``name -> {count, sum_s, self_s, max_s}`` behind ``obs.trace.totals()``
+  is what the benchmark's span metrics read, and it is fed from one
+  place: a span that ENDS (``Tracer.end`` / ``add_complete``), jax's own
+  duration events, the collector's callback.  A subscript write or a
+  mutating call on ``_TOTALS`` / ``_GC``, or a call of the private
+  ``_count``, anywhere else would put seconds into a layer's metric
+  that no span measured (and ``self_s`` would no longer be a duration
+  less its children).  Reads go through ``totals()``.
 - **OB404**: metric-name drift.  In any module that touches the
   time-series ring (imports ``obs/tsring.py``, or IS it), every
   ``tinysql_*`` metric-name string literal must be declared in the
@@ -100,6 +109,9 @@ register_rules({
              "obs/conprof.py — only the sampler tick may claim "
              "statement CPU (cpu_s/cpu_samples) or mutate the "
              "window store",
+    "OB408": "span-totals write outside obs/trace.py — only a span that "
+             "ends (or jax's and the collector's own reports there) may "
+             "add to the table the benchmark's span metrics read",
     "OB407": "heap/HBM accumulator write outside obs/memprof.py — only "
              "the heap profiler's sampler tick may claim statement "
              "memory (heap_kb/heap_peak_kb/hbm_bytes) or mutate the "
@@ -151,6 +163,13 @@ MEMPROF_OWNING_MODULE = "memprof.py"
 
 #: mutating entry points on the heap-profiler store / its module facade
 _MEMPROF_WRITERS = {"sample_once", "reset"}
+
+
+#: the span-totals table and the collector's counters (OB408), their
+#: private writer, and the one module that may touch them
+SPAN_TOTALS_NAMES = {"_TOTALS", "_GC"}
+_SPAN_TOTALS_WRITER = "_count"
+SPAN_TOTALS_OWNING_MODULE = "trace.py"
 
 
 def _is_stats_target(e: ast.expr) -> bool:
@@ -227,6 +246,76 @@ def _lint_summary_writes(sf: SourceFile) -> List[Diagnostic]:
                 "other writer double-counts or bypasses window/eviction "
                 "accounting",
                 sf.path, node.lineno))
+    return diags
+
+
+# ---- OB408: span-totals write discipline ----------------------------------
+
+def _is_span_totals(e: ast.expr, trace_aliases: Set[str]) -> bool:
+    """``trace._TOTALS`` / ``obs.trace._GC`` under any import alias of
+    obs/trace.py, or the bare name where it was imported FROM there."""
+    if isinstance(e, ast.Attribute) and e.attr in SPAN_TOTALS_NAMES:
+        v = e.value
+        return (isinstance(v, ast.Name) and v.id in trace_aliases) \
+            or (isinstance(v, ast.Attribute) and v.attr == "trace")
+    return isinstance(e, ast.Name) and e.id in trace_aliases \
+        and e.id in SPAN_TOTALS_NAMES
+
+
+def _lint_span_totals_writes(sf: SourceFile) -> List[Diagnostic]:
+    # names provably bound to obs/trace.py or to its private state
+    aliases: Set[str] = set()
+    for node in ast.walk(sf.tree):
+        if isinstance(node, ast.Import):
+            for alias in node.names:
+                if alias.name.endswith("obs.trace") and alias.asname:
+                    aliases.add(alias.asname)
+        elif isinstance(node, ast.ImportFrom) and node.module is not None:
+            from_trace = node.module.rsplit(".", 1)[-1] == "trace"
+            for alias in node.names:
+                if alias.name == "trace" or (from_trace and alias.name in
+                                             SPAN_TOTALS_NAMES
+                                             | {_SPAN_TOTALS_WRITER}):
+                    aliases.add(alias.asname or alias.name)
+    if not aliases:
+        return []
+    diags: List[Diagnostic] = []
+
+    def flag(node: ast.AST, what: str) -> None:
+        diags.append(Diagnostic(
+            "OB408",
+            f"{what} writes the span totals outside obs/trace.py — "
+            "record a span (obs.context.span / process_span) and let "
+            "its end count", sf.path, node.lineno))
+
+    for node in ast.walk(sf.tree):
+        targets = []
+        if isinstance(node, ast.Assign):
+            targets = node.targets
+        elif isinstance(node, (ast.AugAssign, ast.AnnAssign)):
+            targets = [node.target]
+        for t in targets:
+            inner = t  # a rebinding of the name writes no table
+            while isinstance(inner, ast.Subscript):
+                inner = inner.value
+            if inner is not t and _is_span_totals(inner, aliases):
+                flag(t, "assignment")
+        if not isinstance(node, ast.Call):
+            continue
+        f = node.func
+        if isinstance(f, ast.Attribute):
+            if f.attr == _SPAN_TOTALS_WRITER \
+                    and ((isinstance(f.value, ast.Name)
+                          and f.value.id in aliases)
+                         or (isinstance(f.value, ast.Attribute)
+                             and f.value.attr == "trace")):
+                flag(node, "`trace._count(...)`")
+            elif f.attr in _MUTATORS | {"append", "extend", "insert"} \
+                    and _is_span_totals(f.value, aliases):
+                flag(node, f"`.{f.attr}(...)`")
+        elif isinstance(f, ast.Name) and f.id == _SPAN_TOTALS_WRITER \
+                and f.id in aliases:
+            flag(node, "`_count(...)`")
     return diags
 
 
@@ -518,6 +607,8 @@ def lint_obs_discipline(sf: SourceFile) -> List[Diagnostic]:
         diags.extend(_lint_conprof_writes(sf))
     if base != MEMPROF_OWNING_MODULE:
         diags.extend(_lint_memprof_writes(sf))
+    if base != SPAN_TOTALS_OWNING_MODULE:
+        diags.extend(_lint_span_totals_writes(sf))
     if base in OWNING_MODULES:
         return sf.filter(diags)
     for node in ast.walk(sf.tree):

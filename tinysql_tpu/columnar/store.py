@@ -25,7 +25,12 @@ import numpy as np
 
 from ..catalog.model import TableInfo
 from ..mytypes import EvalType
+from ..obs import context as _obs
 from ..obs import memprof as _memprof
+
+#: a memo builder may memoize parts of its own: only the outermost miss
+#: on a thread leaves a span, so the spans' seconds add up
+_memo_tls = threading.local()
 
 
 @dataclass
@@ -43,9 +48,26 @@ class ColumnarTable:
     cache: Dict[object, object] = field(default_factory=dict)
 
     def memo(self, key, build):
+        """The one door for derived state: dictionary builds, sorts,
+        padding and uploads.  A miss is a ``replica.memo`` span on the
+        live span (the statement that first needs the state pays)."""
         v = self.cache.get(key)
-        if v is None:
+        if v is not None:
+            return v
+        if getattr(_memo_tls, "inside", False):
             v = self.cache[key] = build()
+            return v
+        kind = key[0] if isinstance(key, tuple) and key else key
+        _memo_tls.inside = True
+        try:
+            with _obs.process_span("replica.memo", cat="replica",
+                                   kind=str(kind)) as sp:
+                v = self.cache[key] = build()
+                nbytes = getattr(v, "nbytes", None)
+                if nbytes is not None:
+                    sp.args["bytes"] = int(nbytes)
+        finally:
+            _memo_tls.inside = False
         return v
 
 

@@ -283,8 +283,14 @@ class _TView:
     column metadata (ret_type, string decode table) and bucket size."""
     __slots__ = ("emit", "nb", "meta")
 
-    def __init__(self, emit: Callable, nb: int, meta: List[tuple]):
-        self.emit = emit
+    def __init__(self, emit: Callable, nb: int, meta: List[tuple],
+                 scope: str):
+        # every operation a node traces carries the node's kind in its
+        # op_name (a child's emit runs inside its parent's: scopes nest)
+        def scoped(args):
+            with kernels.jax().named_scope(scope):
+                return emit(args)
+        self.emit = scoped
         self.nb = nb
         self.meta = meta
 
@@ -529,7 +535,7 @@ class _ReplicaLeaf:
             pairs = [(args[iv], args[im]) for iv, im in slots]
             valid = mask_fn(pairs, (args[ip], args[fp]), jn.arange(nb))
             return valid, pairs
-        return _TView(emit, nb, meta)
+        return _TView(emit, nb, meta, "leaf")
 
     # host info the parent join/agg stages need (valid after prepare())
     def replica(self):
@@ -592,7 +598,7 @@ class _HostLeaf:
 
         def emit(args):
             return args[vi], [(args[a], args[b]) for a, b in slots]
-        return _TView(emit, nb, meta)
+        return _TView(emit, nb, meta, "host")
 
     def chunk(self):
         return self._chk
@@ -706,25 +712,33 @@ def _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid, gmask,
     """Shared per-spec aggregation loop for both device group-by nodes
     (the subtle NULL-when-empty / avg-pairing semantics live ONCE here).
     gmask/gvals gather a lane into sorted order; seg_sum reduces a sorted
-    lane to [n_out]; seg_mm(av_s, live_s, kind) likewise for min/max."""
+    lane to [n_out]; seg_mm(av_s, live_s, kind) likewise for min/max.
+    The three stages carry their names into the profile: ``args``,
+    ``gather``, ``group_sums``."""
+    scope = kernels.jax().named_scope
     res = []
     for kind, af in zip(spec_kinds, arg_fns):
         if kind == "count_star":
             res.append((presence, jn.zeros(n_out, dtype=bool)))
             continue
-        av, an = af(pairs, pr)
-        live_s = gmask(valid & ~an)
-        cnt = seg_sum(live_s.astype(jn.int64))
-        if kind == "count":
-            res.append((cnt, jn.zeros(n_out, dtype=bool)))
-        elif kind in ("sum", "sum0"):
-            res.append((seg_sum(jn.where(live_s, gvals(av), 0)),
-                        jn.zeros(n_out, dtype=bool) if kind == "sum0"
-                        else cnt == 0))
-        else:  # min / max
-            fill = _mm_fill(jn, av.dtype, kind)
-            res.append((seg_mm(jn.where(live_s, gvals(av), fill),
-                               live_s, kind), cnt == 0))
+        with scope("args"):
+            av, an = af(pairs, pr)
+        with scope("gather"):
+            live_s = gmask(valid & ~an)
+            if kind != "count":
+                av_s = gvals(av)
+        with scope("group_sums"):
+            cnt = seg_sum(live_s.astype(jn.int64))
+            if kind == "count":
+                res.append((cnt, jn.zeros(n_out, dtype=bool)))
+            elif kind in ("sum", "sum0"):
+                res.append((seg_sum(jn.where(live_s, av_s, 0)),
+                            jn.zeros(n_out, dtype=bool) if kind == "sum0"
+                            else cnt == 0))
+            else:  # min / max
+                fill = _mm_fill(jn, av.dtype, kind)
+                res.append((seg_mm(jn.where(live_s, av_s, fill),
+                                   live_s, kind), cnt == 0))
     return res
 
 
@@ -932,7 +946,7 @@ class _AggIndexNode:
         for oc, m in zip(schema_cols, out_map):
             decode = decodes[m[1]] if m[0] == "gb" else None
             meta.append((oc.ret_type, decode))
-        return _TView(emit, ngb, meta)
+        return _TView(emit, ngb, meta, "aggindex")
 
     def build_key_info(self):
         """(lo, hi, pos_table np) for the parent join — static per
@@ -1131,7 +1145,7 @@ class _JoinNode:
             # probe key matches nothing and therefore SURVIVES
             valid_out = pvalid & (~match if anti else match)
             return valid_out, list(ppairs)
-        return _TView(emit, nb, ptv.meta)
+        return _TView(emit, nb, ptv.meta, "semijoin")
 
     # ---- multi-key unique build: composite lane + dense table ----------
 
@@ -1221,7 +1235,7 @@ class _JoinNode:
             meta = ptv.meta + btv.meta
         else:
             meta = btv.meta + ptv.meta
-        return _TView(emit, nb, meta)
+        return _TView(emit, nb, meta, "joinmk")
 
     # ---- unique build side: dense pos table + gather -------------------
 
@@ -1415,7 +1429,7 @@ class _JoinNode:
             meta = ptv.meta + btv.meta
         else:
             meta = btv.meta + ptv.meta
-        return _TView(emit, n * n * capp, meta)
+        return _TView(emit, n * n * capp, meta, "joinshuf")
 
     def _prepare_unique(self, pb, btv, ptv) -> Optional[_TView]:
         from ..parallel import dist as _dist
@@ -1495,7 +1509,7 @@ class _JoinNode:
             meta = ptv.meta + btv.meta
         else:
             meta = btv.meta + ptv.meta
-        return _TView(emit, nb, meta)
+        return _TView(emit, nb, meta, "join")
 
     # ---- general multiplicity: CSR over the build group index ----------
 
@@ -1685,7 +1699,7 @@ class _JoinNode:
             meta = ptv.meta + btv.meta
         else:
             meta = btv.meta + ptv.meta
-        return _TView(emit, ob * max(n_mesh, 1), meta)
+        return _TView(emit, ob * max(n_mesh, 1), meta, "joinm")
 
     def _per_probe_counts(self, raw, tbl, lo, hi, ptv, outer, cspec=None):
         """Host per-probe-row match-count UPPER bounds (pre-filter group
@@ -1882,7 +1896,7 @@ class _SortGroupNode:
         for oc, m in zip(schema_cols, out_map):
             decode = decodes[m[1]] if m[0] == "gb" else None
             meta.append((oc.ret_type, decode))
-        return _TView(emit, nb, meta)
+        return _TView(emit, nb, meta, "sortgroup")
 
     def close(self):
         _close_node(self.child)
@@ -1963,7 +1977,7 @@ class _ScalarAggNode:
             gvalid = jn.arange(ob) == 0  # exactly one result row
             return gvalid, [outs[m[1]] for m in out_map]
         meta = [(oc.ret_type, None) for oc in schema_cols]
-        return _TView(emit, ob, meta)
+        return _TView(emit, ob, meta, "scalaragg")
 
     def close(self):
         _close_node(self.child)
@@ -2103,7 +2117,7 @@ class _SelNode:
                 v, null = f(pairs, pr)
                 m = m & (v != 0) & ~null
             return m, pairs
-        return _TView(emit, tv.nb, tv.meta)
+        return _TView(emit, tv.nb, tv.meta, "sel")
 
     def close(self):
         _close_node(self.child)
@@ -2161,7 +2175,7 @@ class _ProjNode:
                 else:
                     outs.append(f(pairs, pr))
             return valid, outs
-        return _TView(emit, tv.nb, meta)
+        return _TView(emit, tv.nb, meta, "proj")
 
     def close(self):
         _close_node(self.child)
@@ -2278,7 +2292,7 @@ class _OrderNode:
                 out_valid = out_valid & (jn.arange(kb - off) < count)
             outs = [(v[take], m[take]) for v, m in pairs]
             return out_valid, outs
-        return _TView(emit, kb - off, tv.meta)
+        return _TView(emit, kb - off, tv.meta, "order")
 
     def _prepare_mesh(self, pb, tv, fns, key_ids, descs, off, kb, count,
                       ip, fp, mesh):
@@ -2343,7 +2357,7 @@ class _OrderNode:
                           [(P("shard"), P("shard"))] * npairs),
                 out_specs=(P(), [(P(), P())] * npairs))
             return sharded(fn_kvs, valid, list(pairs))
-        return _TView(emit, kb - off, tv.meta)
+        return _TView(emit, kb - off, tv.meta, "order_mesh")
 
     def close(self):
         _close_node(self.child)
@@ -2377,7 +2391,7 @@ class _LimitNode:
             pr = (args[ip], args[fp])
             rank = jn.cumsum(valid.astype(jn.int64))
             return valid & (rank > pr[0][0]) & (rank <= pr[0][1]), pairs
-        return _TView(emit, tv.nb, tv.meta)
+        return _TView(emit, tv.nb, tv.meta, "limit")
 
     def close(self):
         _close_node(self.child)
@@ -2634,6 +2648,8 @@ class DevPipeExec:
                      tuple(getattr(a, "shape", ())))
                     for a in pb.inputs)
         key = ("pipe", small, tuple(pb.kparts), sig)
+        # the program's name in a profile: its node kinds, leaves first
+        shape = "_".join(str(part[0]) for part in pb.kparts)
         if small:
             def build_small():
                 schema: list = []
@@ -2647,7 +2663,7 @@ class DevPipeExec:
                         flat.append(m)
                     return kernels.pack_arrays(schema, flat)
                 _note_compiled(pb.kparts)
-                return kernels.counted_jit(mega), schema
+                return kernels.counted_jit(mega, name=shape), schema
             fn, schema = progcache.get(key, build_small)
             vals = kernels.unpack_flat(fn(pb.inputs), schema)
             keep = np.nonzero(vals[0])[0]
@@ -2661,7 +2677,7 @@ class DevPipeExec:
                     valid, cols = emit(args)
                     return [valid] + [x for vm in cols for x in vm]
                 _note_compiled(pb.kparts)
-                return kernels.counted_jit(mega)
+                return kernels.counted_jit(mega, name=shape)
             fn = progcache.get(key, build_big)
             res = fn(pb.inputs)
             valid, items = res[0], list(res[1:])

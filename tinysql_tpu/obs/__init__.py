@@ -18,6 +18,13 @@ Three cooperating pieces:
   so two concurrent sessions collect disjoint counters while the global
   totals stay monotonic for ``/metrics``.  The devpipe producer thread
   inherits the creator's scope via ``contextvars.copy_context``.
+- **the span path** (`trace.py` + `context.py`): ``span`` (a
+  statement's) and ``process_span`` (work no one statement owns: a
+  wire command, a batch round's leg, a sampler's tick) share one parent
+  stack and three sinks: the tracer (``TRACE <stmt>``, ``/debug/trace``),
+  the profiler's clock (``tinysql/<name>`` annotations while a
+  ``jax.profiler`` session is open) and ``trace.totals()``, which the
+  chip benchmark reads (qlint OB408: written only from `trace.py`).
 - **RuntimeStats** (`context.py` + `runtime_stats.py`): per-operator
   actual rows, Next loops, wall time, and device counters, collected by
   wrapping the Open/Next/Close executor interface (``instrument_tree``)
@@ -68,12 +75,13 @@ Three cooperating pieces:
 See docs/OBSERVABILITY.md.
 """
 from .context import (QueryObs, RuntimeStats, activate, current,
-                      current_op, deactivate, record, record_hwm, span)
+                      current_op, deactivate, process_span, record,
+                      record_hwm, span)
 from .runtime_stats import instrument_tree
 from .trace import Tracer, recent_traces
 
 __all__ = [
     "QueryObs", "RuntimeStats", "Tracer", "activate", "current",
-    "current_op", "deactivate", "instrument_tree", "record", "record_hwm",
-    "recent_traces", "span",
+    "current_op", "deactivate", "instrument_tree", "process_span", "record",
+    "record_hwm", "recent_traces", "span",
 ]

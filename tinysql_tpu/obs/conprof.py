@@ -75,6 +75,8 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
+from .context import process_span
+
 DEFAULT_RATE_HZ = 10
 DEFAULT_WINDOW_S = 60
 DEFAULT_HISTORY = 15
@@ -626,15 +628,16 @@ class ConprofSampler:
                 continue
             elapsed = 0.0
             try:
-                self.profiler.sample_once(
-                    period,
-                    window_s=self._int_sysvar("tidb_conprof_window",
-                                              DEFAULT_WINDOW_S),
-                    history=self._int_sysvar("tidb_conprof_history",
-                                             DEFAULT_HISTORY),
-                    max_stacks=self._int_sysvar("tidb_conprof_max_stacks",
-                                                DEFAULT_MAX_STACKS),
-                    skip_idents=(threading.get_ident(),))
+                with process_span("bg.conprof", cat="background"):
+                    self.profiler.sample_once(
+                        period,
+                        window_s=self._int_sysvar("tidb_conprof_window",
+                                                  DEFAULT_WINDOW_S),
+                        history=self._int_sysvar("tidb_conprof_history",
+                                                 DEFAULT_HISTORY),
+                        max_stacks=self._int_sysvar(
+                            "tidb_conprof_max_stacks", DEFAULT_MAX_STACKS),
+                        skip_idents=(threading.get_ident(),))
             except Exception:
                 # a torn frame walk must never kill the sampler thread
                 import logging

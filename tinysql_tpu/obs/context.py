@@ -28,14 +28,13 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-from .trace import Tracer
+from .trace import CURRENT_SPAN as _CURRENT_SPAN
+from .trace import PROCESS, Span, Tracer
 
 _CURRENT: contextvars.ContextVar = contextvars.ContextVar(
     "tinysql_obs_query", default=None)
 _CURRENT_OP: contextvars.ContextVar = contextvars.ContextVar(
     "tinysql_obs_op", default=None)
-_CURRENT_SPAN: contextvars.ContextVar = contextvars.ContextVar(
-    "tinysql_obs_span", default=None)
 
 
 class RuntimeStats:
@@ -213,7 +212,9 @@ def record_bucket(b: int) -> None:
 
 # ---- spans ---------------------------------------------------------------
 
-@contextlib.contextmanager
+_NO_SPAN = contextlib.nullcontext()
+
+
 def span(name: str, cat: str = "query", **args):
     """Nested span on the current statement's tracer; no-op (None) when
     no statement scope is active.  Nesting rides a contextvar stack, so
@@ -221,15 +222,38 @@ def span(name: str, cat: str = "query", **args):
     span that was live at copy time."""
     q = _CURRENT.get()
     if q is None:
-        yield None
-        return
-    parent = _CURRENT_SPAN.get()
-    s = q.tracer.begin(name, cat=cat,
-                       parent=parent.sid if parent else None,
-                       args=args or None)
-    tok = _CURRENT_SPAN.set(s)
+        return _NO_SPAN
+    return q.tracer.begin(name, cat, _CURRENT_SPAN.get(), args)
+
+
+def process_span(name: str, cat: str = "process", **args):
+    """A span of work that no one statement owns (a wire command, a
+    batch round's leg, a sampler's tick): on the statement's tracer
+    where a statement scope is live (a replica preparation under a cold
+    first answer), else on the process's (``obs.trace.PROCESS``).  Same
+    parent stack as :func:`span`, so a statement's spans parent into the
+    process span that caused them."""
+    q = _CURRENT.get()
+    return (q.tracer if q is not None else PROCESS).begin(
+        name, cat, _CURRENT_SPAN.get(), args)
+
+
+def live_span() -> Optional[Span]:
+    return _CURRENT_SPAN.get()
+
+
+def span_of(ctx: contextvars.Context) -> Optional[Span]:
+    """The span that was live where ``ctx`` was copied."""
+    return ctx.get(_CURRENT_SPAN)
+
+
+@contextlib.contextmanager
+def under(parent: Optional[Span]):
+    """Make ``parent`` the parent of what this context records next: a
+    pool worker's leg adopts the member it runs inside the member's own
+    context, an event loop its command's span."""
+    tok = _CURRENT_SPAN.set(parent)
     try:
-        yield s
+        yield parent
     finally:
         _CURRENT_SPAN.reset(tok)
-        q.tracer.end(s)

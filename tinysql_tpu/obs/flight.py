@@ -59,6 +59,8 @@ import weakref
 import zlib
 from typing import Any, Dict, List, Optional, Tuple
 
+from .context import process_span
+
 __all__ = [
     "FlightStore", "FlightWriter", "DEFAULT_INTERVAL_S",
     "DEFAULT_RETENTION", "INCARNATION_COLUMNS", "current_incarnation",
@@ -562,7 +564,8 @@ class FlightWriter:
                 continue
             waited = 0.0
             try:
-                self.flush_now()
+                with process_span("bg.flight", cat="background"):
+                    self.flush_now()
             except Exception:
                 _bump("errors")
 

@@ -87,6 +87,7 @@ from collections import deque
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 from .. import fail
+from .context import process_span
 
 DEFAULT_RATE_HZ = 1
 DEFAULT_WINDOW_S = 60
@@ -926,15 +927,16 @@ class MemprofSampler:
                 continue
             elapsed = 0.0
             try:
-                self.profiler.sample_once(
-                    period,
-                    window_s=self._int_sysvar("tidb_memprof_window",
-                                              DEFAULT_WINDOW_S),
-                    history=self._int_sysvar("tidb_memprof_history",
-                                             DEFAULT_HISTORY),
-                    max_sites=self._int_sysvar("tidb_memprof_max_sites",
-                                               DEFAULT_MAX_SITES),
-                    skip_idents=(threading.get_ident(),))
+                with process_span("bg.memprof", cat="background"):
+                    self.profiler.sample_once(
+                        period,
+                        window_s=self._int_sysvar("tidb_memprof_window",
+                                                  DEFAULT_WINDOW_S),
+                        history=self._int_sysvar("tidb_memprof_history",
+                                                 DEFAULT_HISTORY),
+                        max_sites=self._int_sysvar(
+                            "tidb_memprof_max_sites", DEFAULT_MAX_SITES),
+                        skip_idents=(threading.get_ident(),))
             except Exception:
                 # a torn snapshot (or an armed memprofSampleError) must
                 # never kill the sampler thread — counted, logged, the

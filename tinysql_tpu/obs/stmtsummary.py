@@ -42,6 +42,8 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
+from .context import process_span
+
 DEFAULT_REFRESH_INTERVAL_S = 1800
 DEFAULT_MAX_STMT_COUNT = 200
 
@@ -362,7 +364,8 @@ class SummaryStore:
             if self.window_begin is None:
                 self.window_begin = now
             elif interval > 0 and now - self.window_begin >= interval:
-                self._rotate(now)
+                with process_span("bg.stmtsummary", cat="background"):
+                    self._rotate(now)
             if max_count > 0:
                 # enforce the cap even when it was LOWERED mid-window:
                 # one-in-one-out eviction alone would pin the entry

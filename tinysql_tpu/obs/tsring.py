@@ -42,6 +42,8 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
 
+from .context import process_span
+
 DEFAULT_INTERVAL_S = 5
 DEFAULT_RETENTION_S = 900
 
@@ -409,16 +411,18 @@ class Sampler:
             # (the serving-mode _PENDING_COSTS drain, ISSUE 11) — BEFORE
             # the sample, so resolved flops/bytes start accruing into
             # the very counters this tick snapshots
-            drain_pending_costs()
-            try:
-                self.ring.sample_once(
-                    retention_s=self._int_sysvar(
-                        "tidb_metrics_retention", DEFAULT_RETENTION_S))
-            except Exception:
-                # a broken source must never kill the sampler thread
-                import logging
-                logging.getLogger("tinysql_tpu.tsring").warning(
-                    "metrics sample failed", exc_info=True)
+            with process_span("bg.metrics", cat="background"):
+                drain_pending_costs()
+                try:
+                    self.ring.sample_once(
+                        retention_s=self._int_sysvar(
+                            "tidb_metrics_retention",
+                            DEFAULT_RETENTION_S))
+                except Exception:
+                    # a broken source must never kill the sampler thread
+                    import logging
+                    logging.getLogger("tinysql_tpu.tsring").warning(
+                        "metrics sample failed", exc_info=True)
 
 
 # ---- built-in sources -----------------------------------------------------

@@ -40,7 +40,9 @@ sequentially on it):
    are split across the members it served — occupancy-weighted for a
    stacked group, exact for a solo replay — so statements_summary and
    EXPLAIN ANALYZE stay truthful and member shares sum to the global
-   counters.
+   counters.  Its spans (``dispatch``, ``drain``) belong to no one
+   member: they go to the process's tracer, under the leg's
+   ``round.dispatch`` / ``round.stack`` span.
 3. **replay** — each parked member re-executes; at the same boundary it
    *consumes* its precomputed device output (matched by program key +
    the identity of the staged device arrays + its own param bytes) and
@@ -109,7 +111,8 @@ class Parked(Exception):
     """Control-flow signal of the collect leg: the statement reached a
     batchable warm dispatch and its params were captured.  Never
     surfaces to clients — only the pool's batch driver catches it, and
-    the session skips the observability fan-out for parked attempts."""
+    the session skips the statement fan-out for parked attempts (their
+    spans go to the process's tracer, under ``round.collect``)."""
 
 
 class _ParkedDispatch:
@@ -161,8 +164,10 @@ def _capture_scope():
     attribution shares (the replay-side consume records them into each
     member's own scope).  Without it the whole round's device_s and
     transfer bytes would land on no statement at all — the pool worker
-    drives the dispatch leg outside every member context."""
+    drives the dispatch leg outside every member context.  Its spans
+    are the process's."""
     cap = _obs.QueryObs()
+    cap.tracer = _obs.PROCESS
     tok = _obs.activate(cap)
     try:
         yield cap
@@ -182,6 +187,11 @@ class BatchRound:
         self.collecting = False
         self.replaying = False
         self.stack_max = max(int(stack_max), 0)
+        #: groups the dispatch leg served with ONE stacked dispatch
+        self.stacked_groups = 0
+        #: what the replay leg now running found: ``hit`` / ``miss``
+        #: (the pool resets it to ``none`` before each member's replay)
+        self.consumed = "none"
         self._parked: List[_ParkedDispatch] = []
         #: (key, arg_ids, params_key) -> [(out, share)]: a LIST because
         #: concurrent clients legitimately submit IDENTICAL statements —
@@ -225,16 +235,19 @@ class BatchRound:
                 order.append(k)
             groups[k].append(p)
         occ = 0
-        for k in order:
-            members = groups[k]
-            while members:
-                chunk = members[: max(self.stack_max, 1)]
-                members = members[len(chunk):]
-                if len(chunk) >= 2 and self._dispatch_stacked(chunk):
-                    occ += len(chunk)
-                    continue
-                for p in chunk:
-                    occ += self._dispatch_solo(p)
+        with _obs.process_span("round.dispatch", cat="serving",
+                               groups=len(order)) as leg:
+            for k in order:
+                members = groups[k]
+                while members:
+                    chunk = members[: max(self.stack_max, 1)]
+                    members = members[len(chunk):]
+                    if len(chunk) >= 2 and self._dispatch_stacked(chunk):
+                        occ += len(chunk)
+                        continue
+                    for p in chunk:
+                        occ += self._dispatch_solo(p)
+            leg.args["occupancy"] = occ
         if occ:
             _stat_add("batches")
             _stat_add("batched_statements", occ)
@@ -280,26 +293,29 @@ class BatchRound:
         from .exprjit import ParamTable
         p0 = chunk[0]
         n = len(chunk)
-        try:
-            ent = kernels.stacked_variant(
-                p0.key, p0.fn, kernels.occupancy_bucket(n))
-            if ent is None:
+        bucket = kernels.occupancy_bucket(n)
+        with _obs.process_span("round.stack", cat="serving",
+                               bucket=bucket, n=n) as sp:
+            try:
+                ent = kernels.stacked_variant(p0.key, p0.fn, bucket)
+                if ent is None:
+                    _stat_add("stack_fallbacks")
+                    return False
+                vfn, kind, schema = ent
+                sp.args["kind"] = kind
+                stacked = ParamTable.stack([p.params for p in chunk],
+                                           bucket)
+            except Exception:
                 _stat_add("stack_fallbacks")
                 return False
-            vfn, kind, schema = ent
-            stacked = ParamTable.stack(
-                [p.params for p in chunk], kernels.occupancy_bucket(n))
-        except Exception:
-            _stat_add("stack_fallbacks")
-            return False
-        try:
-            with _capture_scope() as cap:
-                res = vfn(*p0.args, kernels._params_dev(stacked))
-                if kind == "packed":
-                    rows = kernels.d2h_many(list(res))
-        except Exception:
-            _stat_add("stack_fallbacks")
-            return False
+            try:
+                with _capture_scope() as cap:
+                    res = vfn(*p0.args, kernels._params_dev(stacked))
+                    if kind == "packed":
+                        rows = kernels.d2h_many(list(res))
+            except Exception:
+                _stat_add("stack_fallbacks")
+                return False
         totals = cap.device_totals()
         # occupancy split (shardops.split_exact): integer counters split
         # as integers and sum to the round's totals exactly, real-valued
@@ -316,6 +332,7 @@ class BatchRound:
             else:
                 out = ("dev", tree_map(lambda x, i=i: x[i], res))
             self._store(p, out, shares[i])
+        self.stacked_groups += 1
         _stat_add("stacked_rounds")
         _stat_add("stacked_statements", n)
         _stat_add("stacked_occupancy_sum", n)
@@ -332,6 +349,7 @@ class BatchRound:
         the global counters."""
         outs = self._results.get(
             (key, _leaf_ids(args), _params_key(params)))
+        self.consumed = "hit" if outs else "miss"
         if outs:
             _stat_add("replays")
             out, share = outs.pop()

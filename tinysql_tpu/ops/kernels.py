@@ -15,6 +15,7 @@ never equi-join, NULL sorts first ASC / last DESC).
 from __future__ import annotations
 
 import os
+import re
 import threading
 import time
 
@@ -94,6 +95,13 @@ def jax():
             jax_mod.config.update("jax_compilation_cache_dir", _cache_dir())
         jax_mod.config.update(
             "jax_persistent_cache_min_compile_time_secs", 1.0)
+        # the span path's two hooks into jax (obs/ imports without it):
+        # live spans become profiler annotations, and jax's own
+        # trace / lower / load phases count in the span totals
+        from ..obs import trace as obs_trace
+        obs_trace.bind_profiler(jax_mod.profiler.TraceAnnotation)
+        jax_mod.monitoring.register_event_duration_secs_listener(
+            obs_trace.on_jax_duration)
         _jax = jax_mod
     return _jax
 
@@ -292,7 +300,23 @@ def resolve_pending_costs() -> None:
             pass  # backends without memory_analysis keep zeros
 
 
-def counted_jit(fn, **kw):
+_NOT_IN_A_NAME = re.compile(r"[^A-Za-z0-9_]+")
+
+
+def program_name(key: Optional[tuple], variant: str = "") -> str:
+    """What the profiler calls a program: its registry key's family
+    (``jit_<family>`` among the trace's ``XLA Modules``,
+    ``PjitFunction(<family>)`` on the host plane), ``kernel`` where it
+    is built outside the registry.  A builder that knows more (the fused
+    pipeline's node kinds, a stacked variant's bucket) passes it as
+    ``variant``."""
+    family = str(key[0]) if key else "kernel"
+    name = _NOT_IN_A_NAME.sub("_", f"{family}_{variant}" if variant
+                              else family).strip("_")
+    return name[:96] or "kernel"
+
+
+def counted_jit(fn, name: str = "", **kw):
     """jax.jit wrapper that counts program dispatches (and, when cost
     tracking is on, the dispatched program's flops / bytes accessed —
     first sight of a (program, shape) only ENQUEUES the analysis; counts
@@ -304,10 +328,14 @@ def counted_jit(fn, **kw):
     (ops/profiler.py, tidb_device_profile_rate) a sampled dispatch is
     closed with block_until_ready so the recorded wall is MEASURED
     device busy time, not async submit time."""
+    prog_key = progcache.building_key()
+    try:  # jax names the module after the function it wraps
+        fn.__name__ = fn.__qualname__ = program_name(prog_key, name)
+    except (AttributeError, TypeError):
+        pass  # a callable that keeps its own name
     # qlint: disable=TS104 -- counted_jit IS the wrapper factory; callers cache its result
     w = jax().jit(fn, **kw)
     costs: Dict[tuple, Optional[tuple]] = {}
-    prog_key = progcache.building_key()
 
     def call(*a, **k):
         fail.inject("kernelDispatchError")
@@ -425,19 +453,22 @@ def pack_arrays(schema: list, arrays) -> tuple:
     jn = jnp()
     del schema[:]
     ints, floats = [], []
-    for a in arrays:
-        if a.dtype == jn.float64:
-            schema.append(("float64", int(a.shape[0]), "f"))
-            floats.append(a)
-        elif a.dtype in (jn.int64, jn.bool_, jn.int32):
-            schema.append((str(a.dtype), int(a.shape[0]), "i"))
-            ints.append(a if a.dtype == jn.int64 else a.astype(jn.int64))
-        else:  # float32 etc. would silently truncate through the int path
-            raise TypeError(f"pack_arrays: unsupported dtype {a.dtype}")
-    zi = jn.zeros(0, dtype=jn.int64)
-    zf = jn.zeros(0, dtype=jn.float64)
-    return (jn.concatenate(ints) if ints else zi,
-            jn.concatenate(floats) if floats else zf)
+    with jax().named_scope("pack"):
+        for a in arrays:
+            if a.dtype == jn.float64:
+                schema.append(("float64", int(a.shape[0]), "f"))
+                floats.append(a)
+            elif a.dtype in (jn.int64, jn.bool_, jn.int32):
+                schema.append((str(a.dtype), int(a.shape[0]), "i"))
+                ints.append(a if a.dtype == jn.int64
+                            else a.astype(jn.int64))
+            else:  # float32 etc. would silently truncate through the int path
+                raise TypeError(
+                    f"pack_arrays: unsupported dtype {a.dtype}")
+        zi = jn.zeros(0, dtype=jn.int64)
+        zf = jn.zeros(0, dtype=jn.float64)
+        return (jn.concatenate(ints) if ints else zi,
+                jn.concatenate(floats) if floats else zf)
 
 
 def _split_flat(flat_i, flat_f, schema: list) -> List[np.ndarray]:
@@ -527,17 +558,19 @@ def prefix_sum(x):
     from jax import lax
     jn = jnp()
     n = int(x.shape[0])
-    if n <= PREFIX_CHUNKS or n % PREFIX_CHUNKS:
-        return lax.associative_scan(jn.add, x)
-    rows = n // PREFIX_CHUNKS
+    with jax().named_scope("prefix_sum"):
+        if n <= PREFIX_CHUNKS or n % PREFIX_CHUNKS:
+            return lax.associative_scan(jn.add, x)
+        rows = n // PREFIX_CHUNKS
 
-    def step(carry, row):
-        carry = carry + row
-        return carry, carry
-    totals, inner = lax.scan(step, jn.zeros(PREFIX_CHUNKS, dtype=x.dtype),
-                             x.reshape(PREFIX_CHUNKS, rows).T)
-    offsets = prefix_sum(totals) - totals
-    return (inner + offsets[None, :]).T.reshape(n)
+        def step(carry, row):
+            carry = carry + row
+            return carry, carry
+        totals, inner = lax.scan(
+            step, jn.zeros(PREFIX_CHUNKS, dtype=x.dtype),
+            x.reshape(PREFIX_CHUNKS, rows).T)
+        offsets = prefix_sum(totals) - totals
+        return (inner + offsets[None, :]).T.reshape(n)
 
 
 # one-RTT threshold: below this many rows, downloading the FULL dense
@@ -573,9 +606,11 @@ def _present_pack(presence, items, ob: int):
         schema: list = []
 
         def kernel(pres, arrs):
-            idx = jn_.nonzero(pres > 0, size=ob, fill_value=ns)[0]
-            safe = jn_.minimum(idx, ns - 1)
-            return pack_arrays(schema, [idx] + [a[safe] for a in arrs])
+            with jax().named_scope("present_pack"):
+                idx = jn_.nonzero(pres > 0, size=ob, fill_value=ns)[0]
+                safe = jn_.minimum(idx, ns - 1)
+                return pack_arrays(schema,
+                                   [idx] + [a[safe] for a in arrs])
         return counted_jit(kernel), schema
     fn, schema = progcache.get(key, build)
     vals = unpack_flat(fn(presence, items), schema)
@@ -1098,7 +1133,7 @@ def stacked_variant(key: tuple, base_fn, b: int):
         kern, schema = make_kernel()
         axes = tuple([None] * n_data + [0])
         vk = jax().vmap(kern, in_axes=axes)
-        return counted_jit(vk), kind, schema
+        return counted_jit(vk, name=f"b{b}"), kind, schema
     return progcache.get(_stacked_key(key, b), build)
 
 
@@ -2035,6 +2070,11 @@ def lex_head(ops, k: int):
     five operands at 2^21, took 1455 s to compile against 4.5 s with
     this (CHANGES.md, PR 22).  A longer head falls back to the full
     sort."""
+    with jax().named_scope("lex_head"):
+        return _lex_head(ops, k)
+
+
+def _lex_head(ops, k: int):
     from jax import lax
     jn = jnp()
     n = int(ops[0].shape[0])

@@ -61,12 +61,12 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
-from ..session.session import ResultSet
+from ..obs import context as obs_context
 from ..utils import interrupt
 from ..utils.interrupt import QueryKilled
 from . import protocol as p
 from .packetio import MAX_PAYLOAD, PacketIO
-from .server import ClientConn, _err_packet_for
+from .server import ClientConn, _err_packet_for, parse_sql
 
 log = logging.getLogger("tinysql_tpu.aio")
 
@@ -108,7 +108,7 @@ class _AioConn:
 
     __slots__ = ("cc", "sock", "salt", "state", "rbuf", "wbuf", "parts",
                  "last_rx", "stmts", "idx", "sql", "entry", "events",
-                 "pumping")
+                 "pumping", "span", "submitted")
 
     def __init__(self, cc: ClientConn):
         self.cc = cc
@@ -125,6 +125,12 @@ class _AioConn:
         self.entry = None  # in-flight pool entry (async COM_QUERY leg)
         self.events = 0
         self.pumping = False
+        #: the async COM_QUERY's ``wire.command`` span, begun when its
+        #: packet is complete and ended when its response is flushed: no
+        #: one stretch of the loop's thread runs it, so it is held here
+        #: and not on the thread's span stack
+        self.span = None
+        self.submitted = 0.0  # perf_counter at the in-flight submit
 
 
 class _Loop:
@@ -493,14 +499,21 @@ class _Loop:
             self._close_conn(conn)
             return
         if cmd == p.COM_QUERY:
-            self._start_query(conn, body.decode("utf-8", "replace"))
+            conn.span = obs_context.PROCESS.begin(
+                "wire.command", cat="wire", annotate=False,
+                args={"cmd": cmd, "conn": cc.conn_id})
+            with obs_context.under(conn.span):
+                self._start_query(conn, body.decode("utf-8", "replace"))
             return
-        try:
-            cc.dispatch_command(cmd, body)
-        except Exception as e:  # one bad command != dead conn
-            log.warning("aio conn-%d command error: %s", cc.conn_id, e)
-            cc.io.write_packet(_err_packet_for(e))
-        self._after_command(conn)
+        with obs_context.process_span("wire.command", cat="wire",
+                                      cmd=cmd, conn=cc.conn_id):
+            try:
+                cc.dispatch_command(cmd, body)
+            except Exception as e:  # one bad command != dead conn
+                log.warning("aio conn-%d command error: %s",
+                            cc.conn_id, e)
+                cc.io.write_packet(_err_packet_for(e))
+            self._after_command(conn)
 
     def _after_command(self, conn: _AioConn) -> None:
         if conn.state != "closed" and conn.cc.session.killed:
@@ -510,12 +523,12 @@ class _Loop:
 
     # ---- the async COM_QUERY driver -------------------------------------
     def _start_query(self, conn: _AioConn, sql: str) -> None:
-        from ..parser import parse
         cc = conn.cc
         try:
-            stmts = parse(sql)
+            stmts = parse_sql(sql)
         except Exception as e:
             cc.io.write_packet(p.err_packet(1064, str(e), "42000"))
+            self._end_command_span(conn)
             self._after_command(conn)
             return
         conn.sql = sql
@@ -538,6 +551,7 @@ class _Loop:
                 f"{conn.sql[:200]} [stmt {conn.idx + 1}/{len(conn.stmts)}]"
             if pool.routes_to_pool(stmt):
                 try:
+                    conn.submitted = time.perf_counter()
                     conn.entry = pool.submit(cc.session, stmt, label,
                                              on_done=self._done_cb(conn))
                 except Exception as e:  # 1041 shed / pool shutdown
@@ -553,7 +567,7 @@ class _Loop:
                 cc.io.write_packet(_err_packet_for(e))
                 self._finish_command(conn)
                 return
-            self._write_result(conn, rs, more)
+            cc.write_result(rs, more)
             conn.idx += 1
         self._finish_command(conn)
 
@@ -572,24 +586,27 @@ class _Loop:
             return  # connection closed mid-statement: drop the result
         conn.entry = None
         cc = conn.cc
-        if entry.error is not None:
-            log.debug("query error: %s", entry.error)
-            cc.io.write_packet(_err_packet_for(entry.error))
-            self._finish_command(conn)  # error aborts remaining stmts
-        else:
-            self._write_result(conn, entry.result,
-                               conn.idx + 1 < len(conn.stmts))
-            conn.idx += 1
-            self._advance(conn)
-        self._flush(conn)
+        # submit -> done was no thread's wait here: measured, not live
+        obs_context.PROCESS.add_complete(
+            "pool.wait", conn.submitted,
+            time.perf_counter() - conn.submitted, cat="serving",
+            up=conn.span, args={"verdict": entry.verdict})
+        with obs_context.under(conn.span):
+            if entry.error is not None:
+                log.debug("query error: %s", entry.error)
+                cc.io.write_packet(_err_packet_for(entry.error))
+                self._finish_command(conn)  # error aborts remaining stmts
+            else:
+                cc.write_result(entry.result,
+                                conn.idx + 1 < len(conn.stmts))
+                conn.idx += 1
+                self._advance(conn)
+            self._flush(conn)
 
-    def _write_result(self, conn: _AioConn, rs, more: bool) -> None:
-        cc = conn.cc
-        if isinstance(rs, ResultSet):
-            cc._write_resultset(rs, more)
-        else:
-            cc.io.write_packet(p.ok_packet(
-                affected=cc.session.last_affected, more_results=more))
+    def _end_command_span(self, conn: _AioConn) -> None:
+        sp, conn.span = conn.span, None
+        if sp is not None:
+            obs_context.PROCESS.end(sp)
 
     def _finish_command(self, conn: _AioConn) -> None:
         conn.stmts = []
@@ -599,8 +616,10 @@ class _Loop:
             conn.state = "ready"
         self._after_command(conn)
         self._flush(conn)
+        self._end_command_span(conn)
         if conn.state == "ready" and conn.rbuf:
-            self._pump(conn)  # commands pipelined during execution
+            with obs_context.under(None):  # the next command is no child
+                self._pump(conn)  # commands pipelined during execution
 
     def _on_kill(self, conn_id: int) -> None:
         """Self-pipe kill wake: close a killed idle connection NOW

@@ -20,7 +20,8 @@ from urllib.parse import parse_qs, urlparse
 #: the GET /debug/ index renders this list (ISSUE 20: the surfaces were
 #: discoverable only by reading docs)
 DEBUG_ENDPOINTS = (
-    ("/debug/trace", "last N query traces (JSON; chrome://tracing "
+    ("/debug/trace", "the process's own spans as one entry, then the "
+                     "last N query traces (JSON; chrome://tracing "
                      "loadable per entry; ?n=)"),
     ("/debug/slowlog", "recent structured slow-query records (JSON)"),
     ("/debug/stmtsummary", "statement summary current window (JSON; "
@@ -88,15 +89,20 @@ def _make_handler(server_ref):
                            "text/plain; version=0.0.4; charset=utf-8")
                 return
             if parsed.path == "/debug/trace":
-                from ..obs.trace import recent_traces
+                from ..obs.trace import process_trace, recent_traces
                 qs = parse_qs(parsed.query)
                 try:
                     n = int(qs.get("n", ["0"])[0])
                 except ValueError:
                     n = 0
                 n = n if n > 0 else None  # last-N only; junk = everything
+                # first: the process's own spans (wire commands, batch
+                # rounds, sampler ticks) as one more entry; a
+                # statement's batch_wait names its round's id there
+                proc = process_trace()
                 self._send(200, json.dumps(
-                    recent_traces(n), default=str).encode())
+                    ([proc] if proc else []) + recent_traces(n),
+                    default=str).encode())
                 return
             if parsed.path == "/debug/slowlog":
                 from ..obs.slowlog import recent

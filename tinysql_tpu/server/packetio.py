@@ -18,6 +18,8 @@ class PacketIO:
         self.conn = conn
         self.sequence = 0
         self._buf: Optional[bytearray] = None
+        #: framed bytes written so far (the ``wire.write`` span's size)
+        self.bytes_out = 0
 
     def reset_sequence(self) -> None:
         self.sequence = 0
@@ -64,6 +66,7 @@ class PacketIO:
             pos += len(part)
             if len(part) < MAX_PAYLOAD:
                 break
+        self.bytes_out += len(out)
         if self._buf is not None:
             self._buf += out
         else:

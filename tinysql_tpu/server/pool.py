@@ -52,6 +52,7 @@ from typing import List, Optional
 
 from . import admission
 from .. import fail
+from ..obs import context as obs_context
 from ..parser import ast
 from ..utils.interrupt import QueryKilled
 
@@ -163,10 +164,19 @@ class _Entry:
         self.claimed_at = time.monotonic()
         self.queue_wait_s = max(0.0, self.claimed_at - self.queued_mono)
 
-    def wait_info(self, batch_wait_s: float = 0.0) -> dict:
+    def wait_info(self, batch_wait_s: float = 0.0,
+                  round_id: Optional[int] = None) -> dict:
         return {"queue_wait_s": self.queue_wait_s,
                 "batch_wait_s": max(0.0, batch_wait_s),
-                "admission_verdict": self.verdict}
+                "admission_verdict": self.verdict,
+                "round": round_id}
+
+    def waiter(self) -> Optional[int]:
+        """Id of the span that was live where this entry was submitted
+        (``pool.wait``, or the event loop's ``wire.command``): what a
+        worker's leg carries to say whose statement it ran."""
+        sp = obs_context.span_of(self.ctx)
+        return sp.sid if sp is not None else None
 
     def complete(self, result=None, error: Optional[BaseException] = None):
         self.result = result
@@ -211,7 +221,12 @@ class StatementPool:
         statements bypass the pool entirely."""
         if not self.routes_to_pool(stmt):
             return session.execute_stmt(stmt, label)
-        return self._wait(self.submit(session, stmt, label))
+        # submit -> done, on the connection thread; the entry copies the
+        # context inside it, so the statement's spans parent here
+        with obs_context.process_span("pool.wait", cat="serving") as sp:
+            entry = self.submit(session, stmt, label)
+            sp.args["verdict"] = entry.verdict
+            return self._wait(entry)
 
     def submit(self, session, stmt, label: str, on_done=None) -> _Entry:
         """Enqueue one POOLED statement and return its entry without
@@ -366,12 +381,18 @@ class StatementPool:
             return
         group = [entry]
         try:
-            if entry.batchable:
-                group += self._form_group(entry)
-            if len(group) == 1:
+            if not entry.batchable:
                 self._run_one(entry)
-            else:
-                self._run_batch(group)
+                return
+            # a round's id is its span's: its legs carry it as their
+            # parent, its members' batch_wait spans as ``round``
+            with obs_context.process_span("round", cat="serving") as rs:
+                group += self._form_group(entry)
+                rs.args["members"] = len(group)
+                if len(group) == 1:
+                    self._run_one(entry)
+                else:
+                    self._run_batch(group)
         except BaseException as e:
             # backstop: NO claimed entry may ever be left incomplete —
             # a waiter with an unset done event would hang its
@@ -387,45 +408,58 @@ class StatementPool:
         """Pull same-digest batchable statements off the queue, topping
         up for at most ``tidb_batch_window_ms``."""
         max_size = self._gvar("tidb_batch_max_size", 16)
-        window_s = self._gvar("tidb_batch_window_ms", 2) / 1e3
-        deadline = time.monotonic() + window_s
+        window_ms = self._gvar("tidb_batch_window_ms", 2)
+        deadline = time.monotonic() + window_ms / 1e3
         members: List[_Entry] = []
-        while True:
-            with self._cv:
-                for e in list(self._queue):
-                    if len(members) + 1 >= max_size:
+        with obs_context.process_span("round.form", cat="serving",
+                                      window_ms=window_ms) as sp:
+            while True:
+                with self._cv:
+                    for e in list(self._queue):
+                        if len(members) + 1 >= max_size:
+                            break
+                        if e.batchable and e.digest == leader.digest:
+                            self._queue.remove(e)
+                            e.claim()
+                            e.state = "batched"
+                            members.append(e)
+                    remaining = deadline - time.monotonic()
+                    if len(members) + 1 >= max_size or remaining <= 0:
                         break
-                    if e.batchable and e.digest == leader.digest:
-                        self._queue.remove(e)
-                        e.claim()
-                        e.state = "batched"
-                        members.append(e)
-                remaining = deadline - time.monotonic()
-                if len(members) + 1 >= max_size or remaining <= 0:
-                    break
-                self._cv.wait(timeout=remaining)
+                    self._cv.wait(timeout=remaining)
+            sp.args["members"] = len(members) + 1
         return members
 
     @staticmethod
     def _exec_entry(entry: _Entry, rnd=None):
         """Run the entry's statement INSIDE the context captured at
         submit time (cross-thread span parenting, the PR 3 devpipe
-        idiom): the statement's parse→plan→execute span chain parents
-        to whatever span was live on the submitting thread instead of
-        starting an orphan chain on the worker.  The batch round (when
-        given) is activated inside that copied context — activating it
-        on the worker's own context would be invisible there."""
+        idiom): a solo statement's parse→plan→execute span chain parents
+        to whatever span was live on the submitting thread
+        (``pool.wait``) instead of starting an orphan chain on the
+        worker.  A batch round (when given) is activated inside that
+        copied context — activating it on the worker's own context would
+        be invisible there — and the round's leg that is live on the
+        worker (``round.collect`` / ``round.replay``) adopts the
+        member's spans: a round's legs account for what ran in them
+        (the leg's parent is the round: its id rides the wait info into
+        the member's ``batch_wait`` span)."""
+        if rnd is None:
+            entry.session.pending_wait = entry.wait_info()
+            return entry.ctx.run(entry.session.execute_stmt, entry.stmt,
+                                 entry.label)
+        leg = obs_context.live_span()
         entry.session.pending_wait = entry.wait_info(
-            batch_wait_s=(time.monotonic() - entry.claimed_at)
-            if rnd is not None else 0.0)
+            batch_wait_s=time.monotonic() - entry.claimed_at,
+            round_id=leg.parent if leg is not None else None)
 
         def _invoke():
-            if rnd is None:
-                return entry.session.execute_stmt(entry.stmt, entry.label)
             from ..ops import batching
             tok = batching.activate(rnd)
             try:
-                return entry.session.execute_stmt(entry.stmt, entry.label)
+                with obs_context.under(leg):
+                    return entry.session.execute_stmt(entry.stmt,
+                                                      entry.label)
             finally:
                 batching.deactivate(tok)
         return entry.ctx.run(_invoke)
@@ -439,15 +473,26 @@ class StatementPool:
             return
         admission.count_admitted()
         admission.record_queue_wait(entry.queue_wait_s)
-        try:
-            entry.complete(result=self._exec_entry(entry))
-        except BaseException as e:
-            entry.complete(error=e)
+        with obs_context.process_span("solo", cat="serving",
+                                      wait=entry.waiter()):
+            try:
+                entry.complete(result=self._exec_entry(entry))
+            except BaseException as e:
+                entry.complete(error=e)
 
     def _run_batch(self, group: List[_Entry]) -> None:
         """Drive one coalesced group through collect / dispatch / replay
-        (module docstring; ops/batching.py has the protocol contract)."""
+        (module docstring; ops/batching.py has the protocol contract).
+        Each leg is a child of the round's own span (``_serve`` opened
+        it before the group formed; a round driven directly, as the
+        tests' deterministic drive does, opens it here) and says in its
+        arguments how it ended."""
         from ..ops import batching
+        round_span = obs_context.live_span()
+        if round_span is None or round_span.name != "round":
+            with obs_context.process_span("round", cat="serving",
+                                          members=len(group)):
+                return self._run_batch(group)
         rnd = batching.BatchRound(
             stack_max=self._gvar("tidb_batch_stack_max", 16))
         pending: List[_Entry] = []
@@ -460,25 +505,33 @@ class StatementPool:
                 continue
             admission.count_admitted()
             rnd.collecting = True
-            try:
-                result = self._exec_entry(e, rnd)
-            except batching.Parked:
-                # wait accounting deferred to the replay leg: a parked
-                # member can still be killed before it ever executes,
-                # and a killed member must not count on the pool side
-                # (the claim() contract)
-                pending.append(e)
-            except BaseException as ex:
-                admission.record_queue_wait(e.queue_wait_s)
-                e.complete(error=ex)
-            else:
-                admission.record_queue_wait(e.queue_wait_s)
-                e.complete(result=result)
-            finally:
-                rnd.collecting = False
+            with obs_context.process_span("round.collect", cat="serving",
+                                          wait=e.waiter()) as leg:
+                try:
+                    result = self._exec_entry(e, rnd)
+                except batching.Parked:
+                    # wait accounting deferred to the replay leg: a
+                    # parked member can still be killed before it ever
+                    # executes, and a killed member must not count on
+                    # the pool side (the claim() contract)
+                    leg.args["outcome"] = "parked"
+                    pending.append(e)
+                except BaseException as ex:
+                    leg.args["outcome"] = "error"
+                    admission.record_queue_wait(e.queue_wait_s)
+                    e.complete(error=ex)
+                else:
+                    leg.args["outcome"] = "completed"
+                    admission.record_queue_wait(e.queue_wait_s)
+                    e.complete(result=result)
+                finally:
+                    rnd.collecting = False
+        round_span.args["parked"] = len(pending)
         if not pending:
             return
         occ = rnd.dispatch()
+        round_span.args.update(occupancy=occ,
+                               stacked_groups=rnd.stacked_groups)
         log.debug("batch round: %d member(s) through one program", occ)
         for e in pending:
             # a KILL that landed while this member sat parked (collect
@@ -490,17 +543,21 @@ class StatementPool:
                 continue
             admission.record_queue_wait(e.queue_wait_s)
             rnd.replaying = True
-            try:
-                # the replay leg re-deposits wait info (the parked
-                # collect leg consumed the first deposit but is
-                # invisible to observability): batch_wait now spans
-                # claim -> replay, i.e. the time spent waiting on the
-                # round's other members + the shared dispatch
-                e.complete(result=self._exec_entry(e, rnd))
-            except BaseException as ex:
-                e.complete(error=ex)
-            finally:
-                rnd.replaying = False
+            rnd.consumed = "none"
+            with obs_context.process_span("round.replay", cat="serving",
+                                          wait=e.waiter()) as leg:
+                try:
+                    # the replay leg re-deposits wait info (the parked
+                    # collect leg consumed the first deposit):
+                    # batch_wait now spans claim -> replay, i.e. the
+                    # time spent waiting on the round's other members +
+                    # the shared dispatch
+                    e.complete(result=self._exec_entry(e, rnd))
+                except BaseException as ex:
+                    e.complete(error=ex)
+                finally:
+                    rnd.replaying = False
+                    leg.args["consume"] = rnd.consumed
 
     # ---- introspection / lifecycle --------------------------------------
     def snapshot(self) -> dict:

@@ -15,6 +15,7 @@ import time
 import weakref
 from typing import Dict, Optional
 
+from ..obs import context as obs_context
 from ..session.session import ResultSet, Session
 from . import protocol as p
 from .packetio import PacketIO
@@ -49,6 +50,18 @@ def conn_gauges() -> dict:
             else:
                 out["idle"] += 1
     return out
+
+
+def parse_sql(sql: str) -> list:
+    """A command's SQL text parsed, under a ``wire.parse`` span: the
+    front ends parse before any statement scope exists, so this is the
+    one place a wire statement's parse time is recorded."""
+    from ..parser import parse
+    with obs_context.process_span("wire.parse", cat="wire",
+                                  bytes=len(sql)) as sp:
+        stmts = parse(sql)
+        sp.args["statements"] = len(stmts)
+    return stmts
 
 
 def _err_packet_for(e: Exception) -> bytes:
@@ -194,17 +207,20 @@ class ClientConn:
                 cmd, payload = data[0], data[1:]
                 if cmd == p.COM_QUIT:
                     return
-                try:
-                    self.dispatch_command(cmd, payload)
-                except ConnectionError:
-                    return
-                except Exception as e:  # one bad command != dead conn
-                    log.warning("conn-%d command error: %s",
-                                self.conn_id, e)
+                # packet read complete -> response flushed
+                with obs_context.process_span("wire.command", cat="wire",
+                                              cmd=cmd, conn=self.conn_id):
                     try:
-                        self.io.write_packet(_err_packet_for(e))
-                    except OSError:
+                        self.dispatch_command(cmd, payload)
+                    except ConnectionError:
                         return
+                    except Exception as e:  # one bad command != dead conn
+                        log.warning("conn-%d command error: %s",
+                                    self.conn_id, e)
+                        try:
+                            self.io.write_packet(_err_packet_for(e))
+                        except OSError:
+                            return
                 if self.session.killed:
                     # plain KILL <id>: the connection drops after the
                     # current command's response went out
@@ -317,27 +333,21 @@ class ClientConn:
         except ValueError as e:
             self.io.write_packet(p.err_packet(1367, str(e), "22007"))
             return
-        from ..parser import parse
-        stmts = parse(sql)
+        stmts = parse_sql(sql)
         if len(stmts) != 1:
             self.io.write_packet(p.err_packet(
                 1064, "prepared statement must be a single statement",
                 "42000"))
             return
         rs = self.server.pool.run(self.session, stmts[0], sql)
-        if isinstance(rs, ResultSet):
-            self._write_resultset(rs, binary=True)
-        else:
-            self.io.write_packet(p.ok_packet(
-                affected=self.session.last_affected))
+        self.write_result(rs, binary=True)
 
     def _run_sql(self, sql: str) -> None:
         """Execute statement-by-statement so each gets its own response,
         chained with SERVER_MORE_RESULTS_EXISTS (reference: conn.go
         handleQuery's multi-statement loop)."""
-        from ..parser import parse
         try:
-            stmts = parse(sql)
+            stmts = parse_sql(sql)
         except Exception as e:
             self.io.write_packet(p.err_packet(1064, str(e), "42000"))
             return
@@ -356,12 +366,22 @@ class ClientConn:
                 log.debug("query error: %s", e)
                 self.io.write_packet(_err_packet_for(e))
                 return  # error aborts the remaining statements
+            self.write_result(rs, more)
+
+    def write_result(self, rs, more: bool = False,
+                     binary: bool = False) -> None:
+        """One statement's response, a resultset or the OK packet, under
+        a ``wire.write`` span (both front ends answer through here)."""
+        with obs_context.process_span("wire.write", cat="wire") as sp:
+            sent = self.io.bytes_out
             if isinstance(rs, ResultSet):
-                self._write_resultset(rs, more)
+                sp.args["rows"] = len(rs.rows)
+                self._write_resultset(rs, more, binary)
             else:
                 self.io.write_packet(p.ok_packet(
                     affected=self.session.last_affected,
                     more_results=more))
+            sp.args["bytes"] = self.io.bytes_out - sent
 
     def _write_resultset(self, rs: ResultSet, more: bool = False,
                          binary: bool = False) -> None:
@@ -468,6 +488,8 @@ class Server:
         t = threading.Thread(target=self._accept_loop, daemon=True,
                              name="mysql-accept")
         t.start()
+        from ..obs.trace import watch_collector
+        watch_collector()
         self.prewarm.start()
         self.metrics_sampler.start()
         self.conprof_sampler.start()

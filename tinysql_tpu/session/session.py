@@ -499,8 +499,12 @@ class Session:
                     cat="serving",
                     args={"verdict": qobs.admission_verdict})
             if batch_s > 0:
+                # ``round``: the id of the round's own process span,
+                # which leads from this statement to the legs it waited
+                # through (/debug/trace keeps both)
                 qobs.tracer.add_complete("batch_wait", t1 - batch_s,
-                                         batch_s, cat="serving")
+                                         batch_s, cat="serving",
+                                         args={"round": wait.get("round")})
         self._plan_s = 0.0
         err = True
         parked = False
@@ -516,7 +520,8 @@ class Session:
             # a batch-round collect leg parking at the dispatch boundary
             # (ops/batching.Parked) is control flow, not a statement: it
             # must stay invisible to statements_summary / slow log /
-            # /metrics — the member's REPLAY execution reports instead
+            # /metrics — the member's REPLAY execution reports instead.
+            # Its spans (a second plan and place) are the round's
             from ..ops.batching import Parked
             parked = isinstance(e, Parked)
             raise
@@ -534,7 +539,9 @@ class Session:
                 info["queue_s"] = queue_s
                 info["batch_s"] = batch_s
             qobs.info = info
-            if not parked:
+            if parked:
+                obs_context.PROCESS.adopt(qobs.tracer)
+            else:
                 self._finish_obs(s, qobs, info, err, n_rows)
 
     def _finish_obs(self, stmt: ast.StmtNode, qobs, info: Dict[str, float],
