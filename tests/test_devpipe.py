@@ -8,7 +8,9 @@ import numpy as np
 import pytest
 
 from tinysql_tpu.columnar.store import bulk_load
+from tinysql_tpu.columnar.store import store_of
 from tinysql_tpu.executor import devpipe
+from tinysql_tpu.ops import kernels
 from tinysql_tpu.session.session import new_session
 
 
@@ -89,13 +91,35 @@ def counters(monkeypatch):
     return runs
 
 
-def _fixture_tables(tk, n=3000, seed=11):
+#: _AggIndexNode's two formulations, by the counter each bumps at
+#: prepare(); a fixture built for one side lands on it by its group count
+SIDES = ["dense", "sorted"]
+
+
+class _AggPaths:
+    """Growth of the agg_dense / agg_sorted counters since construction."""
+
+    def __init__(self):
+        self._since = {k: kernels.STATS[k] for k in ("agg_dense",
+                                                     "agg_sorted")}
+
+    def taken(self) -> dict:
+        return {k[4:]: kernels.STATS[k] - v
+                for k, v in self._since.items()}
+
+    def only(self, side: str) -> bool:
+        """Every _AggIndexNode prepared since took ``side``, and one did."""
+        got = self.taken()
+        return got.pop(side) >= 1 and not any(got.values())
+
+
+def _fixture_tables(tk, n=3000, seed=11, fk_hi=400):
     rng = np.random.default_rng(seed)
     a = np.arange(1, n + 1, dtype=np.int64)
     b = rng.integers(-50, 50, n).astype(np.int64)
     c = rng.random(n) * 100
     cnull = rng.random(n) < 0.1
-    fk = rng.integers(1, 400, n).astype(np.int64)
+    fk = rng.integers(1, fk_hi, n).astype(np.int64)
     fknull = rng.random(n) < 0.05
     _load(tk, "t", "a bigint primary key, b bigint, c double, fk bigint",
           {"a": (a, None), "b": (b, None), "c": (c, cnull),
@@ -127,13 +151,19 @@ def test_join_filters_both_sides(tk, counters):
                      "where t.c < 50 and u.v > 200")
 
 
-def test_agg_pushdown_join_via_group_index(tk, counters):
-    _fixture_tables(tk)
+@pytest.mark.parametrize("side", SIDES)
+def test_agg_pushdown_join_via_group_index(tk, counters, side):
+    # fk has 399 values (+ NULL), or 39: the partial aggregate's groups
+    _fixture_tables(tk, fk_hi=400 if side == "sorted" else 40)
+    paths = _AggPaths()
     # group by fk on the probe table -> partial agg build side via the
-    # replica group index (agg pushdown through the join), merged on u.v
+    # replica group index (agg pushdown through the join), merged on u.v:
+    # the join reads slot g of the [ngb] view through the index's
+    # pos-table, whichever formulation filled it
     assert_match(tk, "select u.v, count(*), sum(t.c) from t join u "
                      "on t.fk = u.k group by u.v")
-    assert counters["join"] >= 1
+    assert counters["join"] >= 1 and counters["agg"] >= 1
+    assert paths.only(side), paths.taken()
 
 
 def test_topn_over_join(tk, counters):
@@ -299,10 +329,13 @@ def test_group_index_single_null_group():
 
 # ---- multi-key group-by on the replica leaf (_AggIndexNode) -------------
 
-def _gb_fixture(tk, n=4000, seed=23):
+def _gb_fixture(tk, side="dense", n=4000, seed=23):
+    """(b, seg) has 12 x 4 = 48 groups with the NULLs (dense: at most
+    kernels.SEG_UNROLL), or 42 x 4 = 168 (sorted)."""
     rng = np.random.default_rng(seed)
+    span = 5 if side == "dense" else 20
     a = np.arange(1, n + 1, dtype=np.int64)
-    b = rng.integers(-5, 6, n).astype(np.int64)
+    b = rng.integers(-span, span + 1, n).astype(np.int64)
     bnull = rng.random(n) < 0.08
     c = rng.random(n) * 100
     cnull = rng.random(n) < 0.1
@@ -315,30 +348,148 @@ def _gb_fixture(tk, n=4000, seed=23):
            "seg": (seg, segnull), "d": (d, None)})
 
 
-def test_multikey_leaf_group_by_int_string(tk, counters):
-    _gb_fixture(tk)
+def test_seg_unroll_separates_the_fixtures_sides():
+    assert 48 <= kernels.SEG_UNROLL < 168
+
+
+@pytest.mark.parametrize("side", SIDES)
+def test_multikey_leaf_group_by_int_string(tk, counters, side):
+    _gb_fixture(tk, side)
+    paths = _AggPaths()
     assert_match(tk, "select b, seg, count(*), sum(c) from g "
                      "group by b, seg order by b, seg")
     assert counters["agg"] >= 1 and counters["sortgroup"] == 0
     assert counters["host"] == 0
+    assert paths.only(side), paths.taken()
 
 
-def test_multikey_leaf_avg_min_max(tk, counters):
-    _gb_fixture(tk)
-    assert_match(tk, "select seg, b, avg(c), min(d), max(d), min(b), "
-                     "count(c) from g group by seg, b order by seg, b")
+@pytest.mark.parametrize("side", SIDES)
+def test_multikey_leaf_avg_min_max(tk, counters, side):
+    _gb_fixture(tk, side)
+    paths = _AggPaths()
+    # c has NULLs: every aggregate kind over it, beside count(*)
+    assert_match(tk, "select seg, b, sum(c), avg(c), min(c), max(c), "
+                     "count(c), count(*), min(d), max(d), min(b) "
+                     "from g group by seg, b order by seg, b")
     assert counters["agg"] >= 1
+    assert paths.only(side), paths.taken()
 
 
-def test_multikey_leaf_q1_shape(tk, counters):
-    """TPC-H Q1 shape: two string keys, sums of expressions, avgs,
-    count(*), filter, order by the keys — must run via the group index
-    (one device program when fused)."""
-    _gb_fixture(tk)
+@pytest.mark.parametrize("side", SIDES)
+def test_multikey_leaf_all_null_group_sums_to_null(tk, counters, side):
+    _gb_fixture(tk, side)
+    # every c of one group NULL: its sum/avg/min/max are NULL and its
+    # count(c) is 0, while count(*) still counts the rows
+    tk.execute("update g set c = null where b = 2 and seg = 'BB'")
+    paths = _AggPaths()
+    q = ("select seg, b, sum(c), avg(c), min(c), max(c), count(c), "
+         "count(*) from g group by seg, b order by seg, b")
+    assert_match(tk, q)
+    row = [r for r in tk.query(q).rows if r[0] == "BB" and r[1] == 2]
+    assert len(row) == 1 and row[0][2:7] == [None] * 4 + [0], row
+    assert row[0][7] > 0
+    assert paths.only(side), paths.taken()
+
+
+@pytest.mark.parametrize("side", SIDES)
+def test_multikey_leaf_filter_empties_groups(tk, counters, side):
+    _gb_fixture(tk, side)
+    paths = _AggPaths()
+    # the filter leaves no row of seg 'AA', of NULL seg or of b = 3:
+    # those groups are ABSENT from the result, not rows of zeros
+    q = ("select seg, b, count(*), sum(c), count(c) from g "
+         "where seg > 'AA' and b <> 3 group by seg, b order by seg, b")
+    assert_match(tk, q, ordered=True)
+    rows = tk.query(q).rows
+    assert rows and all(r[0] in ("BB", "CC") and r[1] != 3 and r[2] > 0
+                        for r in rows), rows
+    # a filter no row passes: no group at all
+    assert_match(tk, "select seg, b, count(*) from g where d > 100 "
+                     "group by seg, b")
+    assert tk.query("select seg, b, count(*) from g where d > 100 "
+                    "group by seg, b").rows == []
+    assert paths.only(side), paths.taken()
+
+
+@pytest.mark.parametrize("side", SIDES)
+def test_multikey_leaf_q1_shape(tk, counters, side):
+    """TPC-H Q1 shape: two keys (one a string), sums of expressions,
+    avgs, count(*), filter, order by the keys — must run via the group
+    index (one device program when fused); Q1 itself has 4 groups and
+    takes the dense formulation."""
+    _gb_fixture(tk, side)
+    paths = _AggPaths()
     assert_match(tk, "select seg, b, sum(c) s1, sum(c * (1 - d/100)) s2, "
                      "avg(c), avg(d), count(*) from g where d < 9.5 "
                      "group by seg, b order by seg, b")
     assert counters["agg"] >= 1 and counters["host"] == 0
+    assert paths.only(side), paths.taken()
+    head = "aggdense" if side == "dense" else "aggindex"
+    assert any(k[0] == head for k in devpipe.COMPILED_NODE_KEYS)
+
+
+def test_group_count_crossing_threshold_gets_a_new_program(tk, counters):
+    """The formulation is chosen from the replica's group count and is
+    part of the program key: a table that grows past SEG_UNROLL groups
+    between two statements of one shape takes the other program, and
+    neither clobbers the other's cache entry."""
+    n = 3 * kernels.SEG_UNROLL - 12
+    k = np.arange(n, dtype=np.int64) % (kernels.SEG_UNROLL - 4)
+    _load(tk, "cross", "a bigint primary key, k bigint, x double",
+          {"a": (np.arange(1, n + 1, dtype=np.int64), None),
+           "k": (k, None), "x": (np.arange(n) * 0.5, None)})
+    q = "select k, count(*), sum(x), min(x) from cross group by k order by k"
+    paths = _AggPaths()
+    assert_match(tk, q, ordered=True)
+    assert paths.taken() == {"dense": 1, "sorted": 0}
+    tk.execute("insert into cross values " + ", ".join(
+        f"({n + 1 + i}, {1000 + i}, {i}.25)" for i in range(8)))
+    tk.query("select * from cross")     # hydrate the new replica version
+    assert_match(tk, q, ordered=True)
+    assert paths.taken() == {"dense": 1, "sorted": 1}
+    assert len(tk.query(q).rows) == kernels.SEG_UNROLL + 4
+    tk.execute("delete from cross where k >= 1000")
+    tk.query("select * from cross")
+    assert_match(tk, q, ordered=True)   # dense again: its entry is intact
+    assert paths.taken()["dense"] >= 2
+    heads = {key[0] for key in devpipe.COMPILED_NODE_KEYS}
+    assert {"aggdense", "aggindex"} <= heads
+
+
+def test_dense_row_gid_lane_sentinel_and_single_upload(tk, counters):
+    """The dense formulation's one lane: the group id of each ROW in the
+    narrowest integer type, the sentinel ngb on padding rows, uploaded
+    once per replica version."""
+    n = 21                                    # bucket 32: 11 padding rows
+    k = np.array([2, 0, 1] * 7, dtype=np.int64)   # row 0 is in group 2
+    x = np.arange(n) + 0.5
+    _load(tk, "pad", "a bigint primary key, k bigint, x double",
+          {"a": (np.arange(1, n + 1, dtype=np.int64), None),
+           "k": (k, None), "x": (x, None)})
+    q = "select k, count(*), sum(x) from pad group by k order by k"
+    h0 = kernels.STATS["h2d_bytes"]
+    rows = tk.query(q).rows
+    h1 = kernels.STATS["h2d_bytes"]
+    # row 0 is counted once, not once per padding slot (on the sorted
+    # path the padded order lane maps padding to row 0: its in_table mask)
+    assert rows == [[g, 7, float(x[k == g].sum())] for g in range(3)]
+    info = tk.infoschema().table_by_name("d", "pad")
+    rep = store_of(tk.storage).get(info.id)
+    lanes = [(key, v) for key, v in rep.cache.items()
+             if key[0] == "gi_rowgid"]
+    assert len(lanes) == 1
+    (_, _sids, nb), lane = lanes[0]
+    host = np.asarray(lane)
+    assert nb == 32 and host.shape == (32,) and host.dtype == np.uint8
+    assert (host[:n] == k).all()
+    assert (host[n:] == 16).all()             # ngb: matches no group
+    assert not [key for key in rep.cache
+                if key[0] in ("gi_order", "gi_sgid", "gi_ends")]
+    assert h1 - h0 >= nb                      # the lane went up once...
+    assert tk.query(q).rows == rows
+    h2 = kernels.STATS["h2d_bytes"]
+    assert h2 - h1 < nb, (h0, h1, h2)         # ...and only parameters after
+    assert rep.cache[lanes[0][0]] is lane
 
 
 def test_single_key_real_group_by(tk, counters):
@@ -376,8 +527,9 @@ def test_group_by_above_join_multikey(tk, counters):
                      "group by u.v, t.b order by u.v, t.b limit 50")
 
 
-def test_sortgroup_null_keys_group_together(tk, counters):
-    _gb_fixture(tk)
+@pytest.mark.parametrize("side", SIDES)
+def test_sortgroup_null_keys_group_together(tk, counters, side):
+    _gb_fixture(tk, side)
     # b has NULLs: all-NULL key rows form ONE group on both tiers
     assert_match(tk, "select b, count(*), min(c) from g group by b "
                      "order by b")

@@ -392,6 +392,13 @@ class GroupIndex:
         return np.repeat(np.arange(self.n_groups, dtype=np.int64),
                          self.raw_counts())
 
+    def row_gid(self, dtype) -> np.ndarray:
+        """Group id per ROW (host [n]) — the lane the dense aggregate
+        reduces over in row order."""
+        out = np.empty(len(self.order), dtype=dtype)
+        out[self.order] = self.sorted_gid()
+        return out
+
 
 def _group_index(rep, sids: tuple, key_cols: List[tuple]) -> GroupIndex:
     """sids: tuple of stable slot ids (one per key column)."""
@@ -709,12 +716,13 @@ def _mm_fill(jn, dtype, kind: str):
 
 def _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid, gmask,
                   gvals, seg_sum, seg_mm, presence, n_out):
-    """Shared per-spec aggregation loop for both device group-by nodes
+    """Shared per-spec aggregation loop for the device group-by nodes
     (the subtle NULL-when-empty / avg-pairing semantics live ONCE here).
-    gmask/gvals gather a lane into sorted order; seg_sum reduces a sorted
-    lane to [n_out]; seg_mm(av_s, live_s, kind) likewise for min/max.
-    The three stages carry their names into the profile: ``args``,
-    ``gather``, ``group_sums``."""
+    gmask/gvals take a lane into the order the reductions run in (a
+    gather to sorted order, or the identity in row order); seg_sum
+    reduces a lane to [n_out]; seg_mm(av_s, live_s, kind) likewise for
+    min/max.  The three stages carry their names into the profile:
+    ``args``, ``gather``, ``group_sums``."""
     scope = kernels.jax().named_scope
     res = []
     for kind, af in zip(spec_kinds, arg_fns):
@@ -769,13 +777,22 @@ def _gb_key_ok(e) -> bool:
 
 
 class _AggIndexNode:
-    """GROUP BY directly over the columnar replica, via the group index:
-    mask -> gather to sorted order -> cumsum -> boundary diff.  Multi-
-    column keys group by the tuple (strings ride dictionary codes); the
-    index is built ONCE per (replica version, key set) and memoized, so a
-    per-query aggregate is one fused program over [nb] with a tiny [ngb]
-    output.  Replaces the reference's partial-agg hash table
-    (aggregate.go:355 shuffle) for reader-rooted aggregates."""
+    """GROUP BY directly over the columnar replica, via the group index.
+    Multi-column keys group by the tuple (strings ride dictionary codes);
+    the index is built ONCE per (replica version, key set) and memoized,
+    so a per-query aggregate is one fused program over [nb] with a tiny
+    [ngb] output: slot g is group g of the index.  Replaces the
+    reference's partial-agg hash table (aggregate.go:355 shuffle) for
+    reader-rooted aggregates.  Two formulations, chosen at prepare() from
+    the index's group count (and part of the program key):
+
+    - dense (``aggdense``), at most kernels.SEG_UNROLL groups: the lanes
+      stay in row order and each group's sum is a masked reduction over
+      the group id of each row (kernels._SegReduce) — a few streaming
+      passes, no gather, no prefix sum;
+    - sorted (``aggindex``), more groups: mask -> gather to sorted order
+      -> prefix sum -> boundary diff, whose cost does not grow with the
+      group count."""
 
     def __init__(self, leaf: _ReplicaLeaf, plan, key_cols, specs, slots,
                  out_map):
@@ -853,12 +870,30 @@ class _AggIndexNode:
         ngb = kernels.bucket(max(ng, 1))
         nb = tv.nb
         jn = _jn()
-        io = pb.add(_dev_upload(rep, ("gi_order", sids, nb),
-                                lambda: kernels.pad1(gidx.order, nb)))
-        ie = pb.add(_dev_upload(rep, ("gi_ends", sids, ngb),
-                                lambda: kernels.pad1(
-                                    gidx.ends, ngb,
-                                    fill=max(rep.n_rows - 1, 0))))
+        dense = ng <= kernels.SEG_UNROLL
+        kernels.stats_add("agg_dense" if dense else "agg_sorted", 1)
+        need_mm = any(k in ("min", "max") for k, _ in self.specs)
+        ig = io = ie = isg = None
+        if dense:
+            # group id per ROW in the narrowest lane, sentinel ngb on
+            # padding rows (they match no group)
+            ig = pb.add(_dev_upload(
+                rep, ("gi_rowgid", sids, nb),
+                lambda: kernels.pad1(
+                    gidx.row_gid(np.min_scalar_type(ngb)), nb, fill=ngb)))
+        else:
+            io = pb.add(_dev_upload(rep, ("gi_order", sids, nb),
+                                    lambda: kernels.pad1(gidx.order, nb)))
+            ie = pb.add(_dev_upload(rep, ("gi_ends", sids, ngb),
+                                    lambda: kernels.pad1(
+                                        gidx.ends, ngb,
+                                        fill=max(rep.n_rows - 1, 0))))
+            if need_mm:
+                # group id per sorted position, sentinel ngb on padding —
+                # the segment-min/max lane
+                isg = pb.add(_dev_upload(
+                    rep, ("gi_sgid", sids, nb),
+                    lambda: kernels.pad1(gidx.sorted_gid(), nb, fill=ngb)))
         gb_slots = []
         for j, (gk, gn) in enumerate(gidx.keycols):
             ik = pb.add(_dev_upload(rep, ("gi_gkeys", sids, j, ngb),
@@ -867,14 +902,6 @@ class _AggIndexNode:
                                      lambda gn=gn: kernels.pad1(gn, ngb,
                                                                 True)))
             gb_slots.append((ik, ikn))
-        need_mm = any(k in ("min", "max") for k, _ in self.specs)
-        isg = None
-        if need_mm:
-            # group id per sorted position, sentinel ngb on padding —
-            # the segment-min/max lane
-            isg = pb.add(_dev_upload(
-                rep, ("gi_sgid", sids, nb),
-                lambda: kernels.pad1(gidx.sorted_gid(), nb, fill=ngb)))
         pt = ParamTable()
         pt.add_int(ng)
         pt.add_int(rep.n_rows)
@@ -889,22 +916,32 @@ class _AggIndexNode:
                 keys.append(f"{kind}:{stable_shape_key(a)}")
         ip, fp = pb.params(pt)
         # the cache key must pin EVERYTHING the traced closure depends
-        # on: key column ids + dtypes (int vs float key lanes retrace),
-        # the descriptor->spec slot mapping, and the output column map
+        # on: the formulation, key column ids + dtypes (int vs float key
+        # lanes retrace), the descriptor->spec slot mapping, and the
+        # output column map
         kdts = tuple((str(s), str(gk.dtype))
                      for s, (gk, _) in zip(sids, gidx.keycols))
-        pb.key(("aggindex", tuple(keys), kdts, tuple(self.slots),
+        head = "aggdense" if dense else "aggindex"
+        pb.key((head, tuple(keys), kdts, tuple(self.slots),
                 tuple(self.out_map), nb, ngb))
         spec_kinds = [k for k, _ in self.specs]
         slots = self.slots
         out_map = self.out_map
         schema_cols = self.plan.schema.columns
 
-        def emit(args):
+        def dense_reducers(args, valid, pr):
+            seg = kernels._SegReduce(kernels.jax(), jn, args[ig], valid,
+                                     ngb, unroll=True)
+            return dict(
+                gmask=lambda b: b, gvals=lambda v: v,
+                seg_sum=lambda x: seg.sum(x, valid),
+                seg_mm=lambda av, live, kind: seg.minmax(av, live,
+                                                         kind == "min"),
+                presence=seg.sum(valid.astype(jn.int64), valid))
+
+        def sorted_reducers(args, valid, pr):
             j = kernels.jax()
-            valid, pairs = tv.emit(args)
             order, ends = args[io], args[ie]
-            pr = (args[ip], args[fp])
             # padded sorted positions map to row 0 via the padded order
             # array — they MUST be masked or row 0 is counted once per
             # padding slot
@@ -926,14 +963,21 @@ class _AggIndexNode:
                 op = j.ops.segment_min if kind == "min" \
                     else j.ops.segment_max
                 return op(av_s, gl, num_segments=ngb + 1)[:ngb]
-            presence = seg(valid_s.astype(jn.int64))
-            res = _spec_results(
-                jn, spec_kinds, arg_fns, pairs, pr, valid,
+            return dict(
                 gmask=lambda b: b[order] & in_table,
                 gvals=lambda v: v[order],
-                seg_sum=seg, seg_mm=seg_mm, presence=presence, n_out=ngb)
+                seg_sum=seg, seg_mm=seg_mm,
+                presence=seg(valid_s.astype(jn.int64)))
+        reducers = dense_reducers if dense else sorted_reducers
+
+        def emit(args):
+            valid, pairs = tv.emit(args)
+            pr = (args[ip], args[fp])
+            red = reducers(args, valid, pr)
+            res = _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid,
+                                n_out=ngb, **red)
             outs = _slot_outputs(jn, res, slots)
-            gvalid = (jn.arange(ngb) < pr[0][0]) & (presence > 0)
+            gvalid = (jn.arange(ngb) < pr[0][0]) & (red["presence"] > 0)
             cols = []
             for m in out_map:
                 if m[0] == "agg":
@@ -946,7 +990,7 @@ class _AggIndexNode:
         for oc, m in zip(schema_cols, out_map):
             decode = decodes[m[1]] if m[0] == "gb" else None
             meta.append((oc.ret_type, decode))
-        return _TView(emit, ngb, meta, "aggindex")
+        return _TView(emit, ngb, meta, head)
 
     def build_key_info(self):
         """(lo, hi, pos_table np) for the parent join — static per

@@ -46,6 +46,12 @@ _DEVICE_METRICS = {
     "host_dispatches": ("tinysql_host_dispatches_total",
                         "Host-twin kernel invocations (numpy twins "
                         "serving the XLA:CPU backend)"),
+    "agg_dense": ("tinysql_agg_dense_total",
+                  "Fused GROUP BYs over a replica reduced in row order "
+                  "with masked reductions (at most SEG_UNROLL groups)"),
+    "agg_sorted": ("tinysql_agg_sorted_total",
+                   "Fused GROUP BYs over a replica reduced in sorted "
+                   "order (gather + prefix sum)"),
     "flops": ("tinysql_device_flops_total",
               "XLA cost-analysis FLOPs of dispatched programs"),
     "bytes_accessed": ("tinysql_device_bytes_accessed_total",

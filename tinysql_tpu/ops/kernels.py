@@ -121,6 +121,7 @@ def jnp():
 STATS = {"dispatches": 0, "d2h_transfers": 0, "d2h_bytes": 0,
          "h2d_transfers": 0, "h2d_bytes": 0,
          "host_dispatches": 0,
+         "agg_dense": 0, "agg_sorted": 0,
          "device_s": 0.0, "profiled_dispatches": 0,
          "flops": 0.0, "bytes_accessed": 0.0,
          "pipe_blocks": 0, "pipe_stage_s": 0.0, "pipe_dispatch_s": 0.0,
@@ -895,24 +896,35 @@ SEG_UNROLL = 64
 
 
 class _SegReduce:
-    """Segment-reduction strategy: scatter-based (any ns) or unrolled
-    masked reductions (small ns).  gid/valid fixed at construction."""
+    """Segment-reduction strategy: scatter-based (any ns) or masked
+    reductions in row order, one per segment (small ns).  gid/valid fixed
+    at construction.  ``unroll=None`` chooses from ns and the backend (the
+    per-operator tier); the fused pipeline's dense GROUP BY passes True,
+    having chosen from its group count alone."""
 
-    def __init__(self, j, jn, gid, valid, ns: int):
+    def __init__(self, j, jn, gid, valid, ns: int, unroll=None):
         self.j, self.jn, self.gid, self.valid, self.ns = j, jn, gid, valid, ns
         # XLA:CPU lowers scatter-adds to a tight loop (fast) and would pay
         # ns full passes for the unroll; on TPU it's the reverse
-        self.unroll = ns <= SEG_UNROLL and j.default_backend() != "cpu"
+        self.unroll = (ns <= SEG_UNROLL and j.default_backend() != "cpu") \
+            if unroll is None else unroll
         if self.unroll:
-            # one bool mask per segment; XLA fuses these into streaming
-            # passes over gid without materializing ns x n
-            self.seg_masks = [(gid == s) & valid for s in range(ns)]
+            # [ns, n] membership: XLA fuses it into each reduction's
+            # streaming pass over gid, it is never materialized
+            self.member = (gid[None, :] == jn.arange(
+                ns, dtype=gid.dtype)[:, None]) & valid[None, :]
+
+    def _masked(self, red, x, live, fill):
+        """red over each segment's live rows of x, fill elsewhere."""
+        jn = self.jn
+        fill = jn.full((), fill, dtype=x.dtype)
+        lx = jn.where(live, x, fill)
+        return red(jn.where(self.member, lx[None, :], fill), axis=1)
 
     def sum(self, x, live):
         jn = self.jn
         if self.unroll:
-            lx = jn.where(live, x, jn.zeros((), dtype=x.dtype))
-            return jn.stack([jn.sum(jn.where(sm, lx, 0)) for sm in self.seg_masks])
+            return self._masked(jn.sum, x, live, 0)
         gl = jn.where(self.valid & live, self.gid, self.ns)
         return self.j.ops.segment_sum(
             jn.where(live, x, 0), gl, num_segments=self.ns + 1)[:self.ns]
@@ -925,9 +937,7 @@ class _SegReduce:
         else:
             fill = jn.inf if is_min else -jn.inf
         if self.unroll:
-            red = jn.min if is_min else jn.max
-            return jn.stack([red(jn.where(sm & live, x, fill))
-                             for sm in self.seg_masks])
+            return self._masked(jn.min if is_min else jn.max, x, live, fill)
         gl = jn.where(self.valid & live, self.gid, self.ns)
         op = self.j.ops.segment_min if is_min else self.j.ops.segment_max
         return op(jn.where(live, x, fill), gl,
@@ -940,12 +950,9 @@ class _SegReduce:
         j, jn = self.j, self.jn
         n = self.gid.shape[0]
         if self.unroll:
-            presence = jn.stack([jn.sum(sm.astype(jn.int64))
-                                 for sm in self.seg_masks])
-            idx = jn.arange(n)
-            first = jn.stack([jn.min(jn.where(sm, idx, n))
-                              for sm in self.seg_masks])
-            return presence, first
+            return (self._masked(jn.sum, self.valid.astype(jn.int64),
+                                 self.valid, 0),
+                    self._masked(jn.min, jn.arange(n), self.valid, n))
         g = jn.where(self.valid, self.gid, self.ns)
         presence = j.ops.segment_sum(self.valid.astype(jn.int64), g,
                                      num_segments=self.ns + 1)[:self.ns]

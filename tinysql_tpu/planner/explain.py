@@ -213,6 +213,9 @@ def _device_info(st) -> str:
     hits, misses = d.get("progcache_hits", 0), d.get("progcache_misses", 0)
     if hits or misses:
         parts.append(f"cache:{int(hits)}h/{int(misses)}m")
+    if d.get("agg_dense") or d.get("agg_sorted"):
+        parts.append(f"agg:{int(d.get('agg_dense', 0))}dense"
+                     f"/{int(d.get('agg_sorted', 0))}sorted")
     if d.get("pipe_blocks"):
         from ..ops.kernels import pipe_overlap_frac
         overlap = pipe_overlap_frac(d)

@@ -386,9 +386,23 @@ class _Loop:
                 # once the peer drained the buffer
                 self._pump(conn)
 
+    def _recv_size(self, conn: _AioConn) -> int:
+        """Bytes to ask the socket for.  While a server that offers TLS
+        is in the handshake, never past the end of the frame in hand: a
+        client follows its SSLRequest at once with the ClientHello, which
+        belongs to the TLS wrap on the hand-off thread and cannot be put
+        back into the socket once the loop has read it."""
+        if conn.state != "handshake" or self.fe.server.ssl_ctx is None:
+            return 1 << 16
+        have = len(conn.rbuf)
+        if have < 4:
+            return 4 - have
+        length = conn.rbuf[0] | (conn.rbuf[1] << 8) | (conn.rbuf[2] << 16)
+        return max(4 + length - have, 1)
+
     def _on_readable(self, conn: _AioConn) -> None:
         try:
-            data = conn.sock.recv(1 << 16)
+            data = conn.sock.recv(self._recv_size(conn))
         except (BlockingIOError, InterruptedError):
             return
         except OSError:
