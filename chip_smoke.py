@@ -215,15 +215,15 @@ def _run_counted(client, sql: str):
     return rows, round(dt, 4), {
         k: int(d.get(k, 0)) for k in
         ("dispatches", "host_dispatches", "h2d_transfers", "h2d_bytes",
-         "d2h_transfers", "d2h_bytes", "progcache_misses")}
+         "d2h_transfers", "d2h_bytes", "progcache_misses",
+         "mesh_dispatches", "reshard_bytes")}
 
 
-def _query_runs(client, mirror, name: str, sql: str, runs,
-                no_twins: bool = True) -> tuple:
+def _query_runs(client, mirror, name: str, sql: str, runs) -> tuple:
     """EXPLAIN shows device placement; then one run per label in
-    ``runs``: rows equal sqlite's, compiled programs dispatched and
-    (``no_twins``) no numpy twin.  Returns (fields of the line, checks,
-    the last run's rows)."""
+    ``runs``: rows equal sqlite's, compiled programs dispatched and no
+    numpy twin.  Returns (fields of the line, checks, the last run's
+    rows)."""
     from bench import _rows_match
     _cols, plan = client.query("explain " + sql)
     placed = [r[0].strip() for r in plan if r[2] == "tpu"]
@@ -236,8 +236,7 @@ def _query_runs(client, mirror, name: str, sql: str, runs,
         out[run] = d
         checks[f"{run}_rows_equal_sqlite"] = _rows_match(rows, want)
         checks[f"{run}_dispatches>0"] = d["dispatches"] > 0
-        if no_twins:
-            checks[f"{run}_host_dispatches==0"] = d["host_dispatches"] == 0
+        checks[f"{run}_host_dispatches==0"] = d["host_dispatches"] == 0
     return out, checks, rows
 
 
@@ -305,41 +304,49 @@ def phase_write_then_read(env: _Loaded) -> None:
 
 
 def phase_mesh(env: _Loaded, watch: _LogWatch) -> None:
-    """``--mesh``: Q1 and Q3 under ``tidb_mesh_parallel = 1`` (off by
-    default) beside the same two on one device — rows equal to each
-    other and to sqlite — and what each device holds afterwards: code
-    that has only seen forced host devices may keep everything on the
-    first."""
+    """``--mesh``: Q1, Q3 and Q6 under ``tidb_mesh_parallel = 1`` (off by
+    default) beside the same three on one device — rows equal to each
+    other and to sqlite, no numpy twin, a warm statement one dispatch
+    over the mesh that lays out no input anew — and what each device
+    holds afterwards: the replica's lanes are spread over the mesh, so
+    the fullest device holds under half of it all."""
     import jax
     from bench import _rows_match
     from tinysql_tpu.bench import tpch
     c = env.client
-    for name in ("Q1", "Q3"):
+    for name in QUERY_NAMES:
         t0 = time.time()
         sql = tpch.QUERIES[name]
         c.query("set @@tidb_mesh_parallel = 0")
         _rows, one_cold_s, one_cold = _run_counted(c, sql)
         one_rows, one_warm_s, one_warm = _run_counted(c, sql)
         c.query("set @@tidb_mesh_parallel = 1")
-        # the mesh tier partitions join keys on the host by design
-        # (ops/shardops.py _Partitioned): twins are reported here, and
-        # only the default path of the one-chip run is held to none
         out, checks, mesh_rows = _query_runs(
-            c, env.mirror, name, sql, ("mesh_cold", "mesh_warm"),
-            no_twins=False)
+            c, env.mirror, name, sql, ("mesh_cold", "mesh_warm"))
         out.update(one_device_cold_s=one_cold_s, one_device_cold=one_cold,
                    one_device_warm_s=one_warm_s, one_device_warm=one_warm)
         checks["mesh_rows_equal_one_device"] = _rows_match(mesh_rows,
                                                            one_rows)
         checks["mesh_warm_compiles==0"] = \
             out["mesh_warm"]["progcache_misses"] == 0
+        checks["mesh_warm_dispatches==1"] = \
+            out["mesh_warm"]["dispatches"] == 1
+        checks["mesh_warm_reshard_bytes==0"] = \
+            out["mesh_warm"]["reshard_bytes"] == 0
         _finish("mesh", t0, out, checks)
     per_device = []
     for d in jax.devices():
         stats = d.memory_stats()
         per_device.append(None if stats is None
                           else int(stats["bytes_in_use"]))
-    checks = {"no_warning_on_tinysql_tpu_logger": not watch.failing}
+    held = [b for b in per_device if b is not None]
+    checks = {
+        "no_warning_on_tinysql_tpu_logger": not watch.failing,
+        # the CPU backend reports no memory statistics: only there may
+        # this check be skipped
+        "fullest_device_under_half_of_all":
+            2 * max(held) < sum(held) if held
+            else jax.devices()[0].platform == "cpu"}
     _line("mesh", time.time(), per_device_bytes_in_use=per_device,
           warnings=watch.failing, checks=checks)
     _require(checks, "mesh")
@@ -384,8 +391,8 @@ def main(argv=None) -> int:
                          "for the rehearsal and the tier-1 test only")
     ap.add_argument("--mesh", action="store_true",
                     help="four chips: the device and load phases, then "
-                         "only Q1 and Q3 under tidb_mesh_parallel = 1 "
-                         "beside the same two on one device")
+                         "only Q1, Q3 and Q6 under tidb_mesh_parallel = 1 "
+                         "beside the same three on one device")
     args = ap.parse_args(argv)
 
     watch = _LogWatch()

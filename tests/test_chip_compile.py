@@ -238,3 +238,56 @@ def test_fused_devpipe_program(one_chip, chip_branches, monkeypatch,
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
         got.value.args)
     _compile(got.value.fn, *abstract)
+
+
+# ---- the same three over the four-chip mesh --------------------------------
+
+@pytest.mark.parametrize("name", ["Q1", "Q3", "Q6"])
+def test_fused_mesh_program(topo, chip_branches, monkeypatch, tpch_session,
+                            name):
+    """Under ``tidb_mesh_parallel = 1`` with the session's mesh built from
+    the four described devices: the statement runs up to its first
+    dispatch, its lanes "placed" as shapes with their layouts (nothing
+    can be put on a described device), and the one fused program —
+    row-sharded lanes, per-shard partial states, their merge — compiles
+    for the v5e:2x2 with its collectives in it."""
+    from tinysql_tpu.parallel import dist
+    mesh = Mesh(np.array(topo.devices), ("shard",))
+    monkeypatch.setattr(dist, "make_mesh", lambda n=None: mesh)
+    monkeypatch.setattr(dist, "_SESSION_MESH", None)
+    monkeypatch.setattr(dist, "MIN_SHARD_ROWS", 16)  # Q6's small estimate
+    monkeypatch.setattr(
+        dist, "place", lambda host, layout: jax.ShapeDtypeStruct(
+            host.shape, host.dtype, sharding=layout))
+    upload = kernels.h2d
+    monkeypatch.setattr(
+        kernels, "h2d", lambda a, layout=None: upload(a) if layout is None
+        else jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype,
+                                  sharding=layout))
+
+    def capturing_jit(fn, name="", **kw):
+        def call(*args):
+            raise _Captured(jax.jit(fn, **kw), args)
+        return call
+    monkeypatch.setattr(kernels, "counted_jit", capturing_jit)
+    monkeypatch.setattr(kernels, "_stackable_jit",
+                        lambda fn, *a, **kw: capturing_jit(fn))
+    tpch_session.execute("set @@tidb_mesh_parallel = 1")
+    tpch_session.execute("set @@tidb_tpu_min_rows = 64")
+    try:
+        with pytest.raises(_Captured) as got:
+            tpch_session.query(tpch.QUERIES[name])
+    finally:
+        tpch_session.execute("set @@tidb_mesh_parallel = 0")
+        tpch_session.execute("set @@tidb_tpu_min_rows = 0")
+        progcache.clear()  # the registry now holds the capturing stand-in
+    rows, whole = dist.rows(mesh), dist.whole(mesh)
+    leaves = jax.tree_util.tree_leaves(got.value.args)
+    placed = [x for x in leaves if isinstance(x, jax.ShapeDtypeStruct)]
+    assert any(x.sharding == rows for x in placed)
+    abstract = jax.tree_util.tree_map(
+        lambda x: x if isinstance(x, jax.ShapeDtypeStruct)
+        else jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype,
+                                  sharding=whole), got.value.args)
+    compiled = _compile(got.value.fn, *abstract)
+    assert re.search(r"all-(gather|reduce)", compiled.as_text())

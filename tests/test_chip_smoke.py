@@ -62,6 +62,28 @@ def test_smoke_passes_on_tiny_data(chip_like, capsys):
     assert by_query["agg-after-update"]["replica"]["h2d_bytes"] > 0
 
 
+def test_mesh_smoke_passes_on_tiny_data(chip_like, capsys):
+    """``--mesh`` over conftest's forced host devices: every check holds,
+    the mesh phase among them to no numpy twin, one warm dispatch a
+    statement and no input laid out anew."""
+    rc = chip_smoke.main(TINY + ["--mesh"])
+    lines = _lines(capsys)
+    assert rc == 0, lines
+    phases = [line["phase"] for line in lines[:-1]]
+    assert phases == ["device", "load", "mesh", "mesh", "mesh", "mesh"]
+    for line in lines[:-1]:
+        assert all(line.get("checks", {}).values()), line
+    by_query = {line["query"]: line for line in lines if "query" in line}
+    assert set(by_query) == {"Q1", "Q3", "Q6"}
+    for q in by_query.values():
+        assert q["mesh_warm"]["host_dispatches"] == 0
+        assert q["mesh_warm"]["dispatches"] == 1
+        assert q["mesh_warm"]["reshard_bytes"] == 0
+    for name in ("Q1", "Q3"):
+        assert by_query[name]["mesh_warm"]["mesh_dispatches"] == 1
+    assert "fullest_device_under_half_of_all" in lines[-2]["checks"]
+
+
 def test_smoke_fails_on_the_wrong_platform(capsys):
     """No option: the script expects ``tpu``, jax finds the cpu — it must
     stop before any data is made, say ``"ok": false``, and exit non-zero."""

@@ -349,3 +349,280 @@ def test_mesh_join_strategy_cost_based(join_tk, monkeypatch):
                            "on big.fk = dim.k where big.x >= 9 "
                            "order by big.a limit 5").rows
     assert forced == single
+
+
+# ---- the mesh deployment: the replica laid out over the mesh ---------------
+# TPC-H's Q1 / Q3 / Q6 under tidb_mesh_parallel = 1 with the chip's
+# branches on (the fused pipeline forced, no numpy twin): the replica's
+# lanes are placed row-sharded over the mesh (parallel/dist.py rows /
+# whole), each statement is ONE dispatch, and a warm one moves nothing.
+
+MESH_SF = 0.02
+MESH_QUERIES = ("Q1", "Q3", "Q6")
+
+
+@pytest.fixture(scope="module")
+def tpch_mesh():
+    """(session, sqlite mirror, queries): TPC-H at SF 0.02, the device
+    kernels forced (no host twin), the planner's rows-per-shard floor
+    lowered so that Q6's small estimate still fans out over 8 shards."""
+    import os
+    from tinysql_tpu.bench import tpch
+    from tinysql_tpu.parallel import dist
+    prev_env = os.environ.get("TINYSQL_DEVICE_JOIN_ONLY")
+    prev_floor = dist.MIN_SHARD_ROWS
+    os.environ["TINYSQL_DEVICE_JOIN_ONLY"] = "1"
+    dist.MIN_SHARD_ROWS = 16
+    s = new_session()
+    data = tpch.generate(MESH_SF)
+    tpch.load(s, data=data)
+    s.execute("set @@tidb_devpipe = 1")
+    # low enough for Q6's estimate, high enough to keep the one-row
+    # projection above its aggregate on the host (as the default does)
+    s.execute("set @@tidb_tpu_min_rows = 64")
+    yield s, tpch.sqlite_mirror(data), tpch.QUERIES
+    dist.MIN_SHARD_ROWS = prev_floor
+    if prev_env is None:
+        os.environ.pop("TINYSQL_DEVICE_JOIN_ONLY", None)
+    else:
+        os.environ["TINYSQL_DEVICE_JOIN_ONLY"] = prev_env
+
+
+def _mesh_of(monkeypatch, n):
+    """Sessions that ask for the mesh get one of the first n devices."""
+    from tinysql_tpu.parallel import dist
+    monkeypatch.setattr(
+        dist, "session_mesh",
+        lambda sv: dist.sized_mesh(n) if sv.get("tidb_mesh_parallel")
+        else None)
+
+
+def _rows_close(got, want, rel=1e-9):
+    """Same rows in the same order: doubles within ``rel``, the rest
+    equal (a digit-rounding canon flips at a rounding boundary)."""
+    if len(got) != len(want):
+        return False
+    for a, b in zip(got, want):
+        if len(a) != len(b):
+            return False
+        for x, y in zip(a, b):
+            if isinstance(y, float) or isinstance(x, float):
+                if abs(float(x) - float(y)) > rel * max(abs(float(y)), 1.0):
+                    return False
+            elif str(x) != str(y):
+                return False
+    return True
+
+
+def _stats_of(s, sql):
+    from tinysql_tpu.ops import kernels
+    before = kernels.stats_snapshot()
+    rows = s.query(sql).rows
+    return rows, kernels.stats_delta(before)
+
+
+@pytest.mark.parametrize("n", [2, 4, 8])
+@pytest.mark.parametrize("name", MESH_QUERIES)
+def test_mesh_statement_equals_one_device_and_sqlite(tpch_mesh, monkeypatch,
+                                                     name, n):
+    s, mirror, queries = tpch_mesh
+    _mesh_of(monkeypatch, n)
+    s.execute("set @@tidb_mesh_parallel = 0")
+    single = s.query(queries[name]).rows
+    s.execute("set @@tidb_mesh_parallel = 1")
+    sharded, delta = _stats_of(s, queries[name])
+    s.execute("set @@tidb_mesh_parallel = 0")
+    want = [list(r) for r in mirror.execute(queries[name]).fetchall()]
+    assert want and _rows_close(sharded, single, rel=1e-12)
+    assert _rows_close(sharded, want)
+    assert delta["dispatches"] == 1 and delta["host_dispatches"] == 0
+
+
+def _device_lanes(s):
+    """{memo key: device array} of every replica of the session's store."""
+    from tinysql_tpu.columnar.store import store_of
+    return {k: v for tbl in store_of(s.storage).tables_snapshot()
+            for k, v in tbl.cache.items() if isinstance(v, jax.Array)}
+
+
+def test_replica_lanes_are_laid_out_over_the_mesh(tpch_mesh):
+    """After the first mesh reads every lane memoized under ``rows`` is
+    cut into equal contiguous parts, one a device, and every one under
+    ``whole`` (Q3's customer build side, the key->row tables, the group
+    keys) is whole on every device."""
+    s, _mirror, queries = tpch_mesh
+    n = len(jax.devices())
+    s.execute("set @@tidb_mesh_parallel = 1")
+    for name in MESH_QUERIES:
+        s.query(queries[name])
+    s.execute("set @@tidb_mesh_parallel = 0")
+    lanes = _device_lanes(s)
+    rows = {k: v for k, v in lanes.items() if k[-2:] == ("rows", n)}
+    whole = {k: v for k, v in lanes.items() if k[-2:] == ("whole", n)}
+    kinds = {k[0] for k in rows}
+    assert {"devv", "devn", "devcodes", "gi_rowgid",
+            "gi_shard_order", "gi_shard_ends"} <= kinds, kinds
+    for key, arr in rows.items():
+        shards = arr.addressable_shards
+        assert len(shards) == n, key
+        assert {sh.data.shape for sh in shards} == \
+            {(arr.shape[0] // n,)}, key
+        assert len({sh.device for sh in shards}) == n, key
+    assert {"devv", "postable_dev", "gi_postable_dev",
+            "gi_gkeys"} <= {k[0] for k in whole}
+    for key, arr in whole.items():
+        assert arr.sharding.is_fully_replicated, key
+        assert len(arr.addressable_shards) == n, key
+        assert all(sh.data.shape == arr.shape
+                   for sh in arr.addressable_shards), key
+
+
+@pytest.mark.parametrize("name", MESH_QUERIES)
+def test_warm_mesh_statement_moves_nothing(tpch_mesh, name):
+    """The second statement: one dispatch over the whole mesh, no input
+    laid out anew, no numpy twin, no program built, nothing uploaded but
+    the parameters."""
+    s, _mirror, queries = tpch_mesh
+    s.execute("set @@tidb_mesh_parallel = 1")
+    s.query(queries[name])
+    _rows, delta = _stats_of(s, queries[name])
+    s.execute("set @@tidb_mesh_parallel = 0")
+    assert delta["reshard_bytes"] == 0
+    assert delta["host_dispatches"] == 0
+    assert delta["progcache_misses"] == 0
+    assert delta["dispatches"] == 1
+    assert delta["mesh_dispatches"] == 1
+    assert delta["h2d_bytes"] < 1024
+    assert delta["mesh_resident_bytes_max"] >= \
+        delta["mesh_resident_bytes_min"] > 0
+
+
+def test_lane_found_in_another_layout_is_moved_and_counted(tpch_mesh):
+    """``dist.settle``: an input that lies otherwise than its program
+    asks is moved, and its bytes are counted."""
+    import numpy as np
+    from tinysql_tpu.ops import kernels
+    from tinysql_tpu.parallel import dist
+    mesh = dist.make_mesh()
+    lane = kernels.h2d(np.arange(1024, dtype=np.int64), dist.whole(mesh))
+    host = np.zeros(4)
+    before = kernels.stats_snapshot()
+    out = dist.settle([lane, host, lane],
+                      [dist.rows(mesh), None, dist.whole(mesh)])
+    delta = kernels.stats_delta(before)
+    assert delta["reshard_bytes"] == lane.nbytes
+    assert out[0].sharding.is_equivalent_to(dist.rows(mesh), 1)
+    assert out[1] is host and out[2] is lane
+    assert np.array_equal(np.asarray(out[0]), np.asarray(lane))
+
+
+def test_one_device_session_is_untouched_by_a_mesh_session(tpch_mesh):
+    """A one-device session before and after a mesh session on the same
+    replica: the same program keys (no layout tag), the very same
+    memoized arrays, nothing uploaded again, nothing built again."""
+    from tinysql_tpu.executor import devpipe
+    s, _mirror, queries = tpch_mesh
+
+    def tagged(key):
+        return any(isinstance(p, tuple) and tagged(p) for p in key) \
+            or any(p in ("rows", "whole") for p in key
+                   if isinstance(p, str))
+    s.execute("set @@tidb_mesh_parallel = 0")
+    first = {}
+    for name in MESH_QUERIES:
+        first[name] = s.query(queries[name]).rows
+    one_dev = {k: v for k, v in _device_lanes(s).items() if not tagged(k)}
+    node_keys = {k for k in devpipe.COMPILED_NODE_KEYS if not tagged(k)}
+    assert {"leaf", "aggdense", "aggindex", "join", "order"} <= \
+        {k[0] for k in node_keys}
+    # the one-device node keys are as long as they were: nothing appended
+    assert {len(k) for k in node_keys if k[0] == "leaf"} == {4}
+    assert {len(k) for k in node_keys
+            if k[0] in ("aggdense", "aggindex")} == {7}
+    s.execute("set @@tidb_mesh_parallel = 1")
+    for name in MESH_QUERIES:
+        s.query(queries[name])
+    s.execute("set @@tidb_mesh_parallel = 0")
+    for name in MESH_QUERIES:
+        rows, delta = _stats_of(s, queries[name])
+        assert rows == first[name]
+        assert delta["progcache_misses"] == 0, name
+        assert delta["h2d_bytes"] < 1024, name
+        assert delta["mesh_dispatches"] == 0 and \
+            delta["reshard_bytes"] == 0, name
+    after = {k: v for k, v in _device_lanes(s).items() if not tagged(k)}
+    assert after.keys() == one_dev.keys()
+    assert all(after[k] is one_dev[k] for k in one_dev)
+    # (the mesh session added keys of its own: its joins and TopN carry
+    # the mesh's size; the one-device statements above built nothing)
+    assert node_keys <= devpipe.COMPILED_NODE_KEYS
+
+
+@pytest.mark.parametrize("n", [2, 4, 8])
+def test_dense_partial_tables_merge_to_the_unsharded_result(n):
+    """The share test: per-shard partial group tables of the dense
+    aggregate (_SegReduce's masked reductions over a uint8 group-id
+    lane), summed — min/max merged — equal the unsharded tables: exactly
+    in counts and min/max, within 1e-12 in float64 sums."""
+    import numpy as np
+    from tinysql_tpu.ops import kernels
+    jn = kernels.jnp()
+    rng = np.random.default_rng(41 + n)
+    nb, ngb = 1 << 14, 16
+    gid = rng.integers(0, 6, nb).astype(np.uint8)
+    gid[-300:] = ngb  # padding rows match no group
+    valid = rng.random(nb) < 0.7
+    x = rng.random(nb) * 1e5
+    k = rng.integers(-50, 50, nb).astype(np.int64)
+
+    def tables(lo, hi):
+        seg = kernels._SegReduce(jax, jn, jn.asarray(gid[lo:hi]),
+                                 jn.asarray(valid[lo:hi]), ngb,
+                                 unroll=True)
+        v, xs, ks = (jn.asarray(a[lo:hi]) for a in (valid, x, k))
+        return [np.asarray(t) for t in (
+            seg.sum(v.astype(jn.int64), v), seg.sum(xs, v),
+            seg.sum(ks, v), seg.minmax(xs, v, True),
+            seg.minmax(ks, v, False))]
+    whole = tables(0, nb)
+    per = nb // n
+    parts = [tables(i * per, (i + 1) * per) for i in range(n)]
+    cnt, fsum, isum = (sum(p[j] for p in parts) for j in range(3))
+    assert np.array_equal(cnt, whole[0]) and cnt.sum() > 0
+    assert np.array_equal(isum, whole[2])
+    assert np.allclose(fsum, whole[1], rtol=1e-12, atol=0)
+    assert np.array_equal(np.min([p[3] for p in parts], axis=0), whole[3])
+    assert np.array_equal(np.max([p[4] for p in parts], axis=0), whole[4])
+
+
+@pytest.mark.parametrize("n", [2, 4, 8])
+def test_group_index_cut_per_shard(n):
+    """GroupIndex.shards: each shard lists its own rows in key order;
+    the boundary differences at its per-group ends are its partial
+    state, and the partial states sum to the whole index's groups."""
+    import numpy as np
+    from tinysql_tpu.executor.devpipe import GroupIndex
+    rng = np.random.default_rng(5 + n)
+    n_rows, per = 1000, 1024 // n
+    keys = rng.integers(0, 90, n_rows).astype(np.int64)
+    x = rng.integers(1, 1000, n_rows).astype(np.int64)
+    gidx = GroupIndex([(keys, np.zeros(n_rows, dtype=bool))])
+    order, ends, sgid, rows = gidx.shards(n, per)
+    assert order.shape == sgid.shape == (n, per)
+    assert ends.shape == (n, gidx.n_groups) and rows.sum() == n_rows
+    total = np.zeros(gidx.n_groups, dtype=np.int64)
+    for s_ in range(n):
+        mine = order[s_, :rows[s_]] + s_ * per      # global rows, key order
+        assert np.all(np.diff(keys[mine]) >= 0)
+        assert np.array_equal(gidx.gkeys[sgid[s_, :rows[s_]]], keys[mine])
+        assert np.all(sgid[s_, rows[s_]:] == gidx.n_groups)
+        c = np.concatenate([[0], np.cumsum(x[mine])])
+        hi = c[ends[s_] + 1]
+        lo = c[np.concatenate([[-1], ends[s_][:-1]]) + 1]
+        want = np.bincount(np.searchsorted(gidx.gkeys, keys[mine]),
+                           weights=x[mine], minlength=gidx.n_groups)
+        assert np.array_equal(hi - lo, want.astype(np.int64))
+        total += hi - lo
+    assert np.array_equal(
+        total, np.bincount(np.searchsorted(gidx.gkeys, keys), weights=x,
+                           minlength=gidx.n_groups).astype(np.int64))

@@ -258,16 +258,24 @@ class _PipeBuilder:
     """Collects the fused program's runtime inputs and structural cache
     key while the node tree prepares.  Input ORDER is deterministic for a
     given key (prepare is a deterministic tree walk), so a cache-hit
-    pipeline can re-bind fresh inputs positionally."""
-    __slots__ = ("inputs", "kparts")
+    pipeline can re-bind fresh inputs positionally.  Under a mesh each
+    input carries the layout its program asks for (parallel/dist.py
+    ``rows`` / ``whole``; None on one device and for host arrays)."""
+    __slots__ = ("inputs", "layouts", "kparts")
 
     def __init__(self):
         self.inputs: List = []
+        self.layouts: List = []
         self.kparts: List = []
 
-    def add(self, arr) -> int:
+    def add(self, arr, layout=None) -> int:
         self.inputs.append(arr)
+        self.layouts.append(layout)
         return len(self.inputs) - 1
+
+    def lane(self, rep, key, build_np, layout=None) -> int:
+        """A replica-memoized upload (:func:`_dev_upload`) as an input."""
+        return self.add(_dev_upload(rep, key, build_np, layout), layout)
 
     def params(self, pt: ParamTable):
         pi, pf = pt.arrays()
@@ -399,6 +407,37 @@ class GroupIndex:
         out[self.order] = self.sorted_gid()
         return out
 
+    def shards(self, n: int, per: int):
+        """The index cut for a mesh of ``n`` shards holding ``per``
+        contiguous rows each: (order [n, per], ends [n, n_groups],
+        sgid [n, per], rows [n]).  order[s] lists shard s's rows in key
+        order as positions WITHIN the shard; ends[s][g] is the last
+        position in that list of a row of group <= g (-1: none yet), so
+        every shard sees every group and its partial state for group g
+        is the boundary difference at (ends[s][g-1], ends[s][g]] — empty
+        where the shard holds no row of g; sgid[s] is the group of each
+        listed row (``n_groups`` past the shard's rows); rows[s] counts
+        the shard's rows.  Summed (min/max merged) over the shards the
+        partial states are the unsharded aggregate."""
+        ng = self.n_groups
+        shard = self.order // per
+        gid = self.sorted_gid()
+        # a stable partition of the sorted positions by shard keeps each
+        # shard's rows in key order
+        perm = np.argsort(shard, kind="stable")
+        rows = np.bincount(shard, minlength=n).astype(np.int64)
+        order = np.zeros((n, per), dtype=np.int64)
+        sgid = np.full((n, per), ng, dtype=np.int64)
+        start = 0
+        for s_ in range(n):
+            mine = perm[start:start + rows[s_]]
+            order[s_, :rows[s_]] = self.order[mine] - s_ * per
+            sgid[s_, :rows[s_]] = gid[mine]
+            start += rows[s_]
+        cnt = np.bincount(shard * ng + gid,
+                          minlength=n * ng).reshape(n, ng)
+        return order, np.cumsum(cnt, axis=1) - 1, sgid, rows
+
 
 def _group_index(rep, sids: tuple, key_cols: List[tuple]) -> GroupIndex:
     """sids: tuple of stable slot ids (one per key column)."""
@@ -454,10 +493,25 @@ def _jn():
     return kernels.jnp()
 
 
-def _dev_upload(rep, key, build_np):
+def _dev_upload(rep, key, build_np, layout=None):
     # counted H2D (kernels.h2d): replica-memoized, so the transfer is
-    # charged once per (replica, key) — to whichever query materializes it
-    return rep.memo(key, lambda: kernels.h2d(build_np()))
+    # charged once per (replica, key) — to whichever query materializes it.
+    # Under a mesh the array is placed in its layout (row-sharded or
+    # whole on every device) and memoized under it, so a one-device
+    # session and a mesh session never hand each other the wrong array
+    if layout is None:
+        return rep.memo(key, lambda: kernels.h2d(build_np()))
+    from ..parallel import dist
+    return rep.memo(key + dist.layout_tag(layout),
+                    lambda: dist.place(build_np(), layout))
+
+
+def _layouts(mesh):
+    """(rows, whole) of ``mesh``, or (None, None) on one device."""
+    if mesh is None:
+        return None, None
+    from ..parallel import dist
+    return dist.rows(mesh), dist.whole(mesh)
 
 
 class _ReplicaLeaf:
@@ -465,11 +519,23 @@ class _ReplicaLeaf:
     version-memoized uploads; scan filters become the validity mask
     (traced inline into the fused program)."""
 
-    def __init__(self, reader_exec, plan):
+    def __init__(self, reader_exec, plan, mesh=None):
         self.ex = reader_exec
         self.plan = plan
         self._rep = None  # set at prepare(): take_raw_replica consumes
         self._chk = None
+        self.mesh = mesh
+        #: under a mesh: lanes row-sharded (a scanned / probe side) —
+        #: a broadcast join clears it on its build side's leaf, whose
+        #: lanes then lie whole on every device
+        self.spread = True
+
+    def rows_mesh(self, nb: int):
+        """The mesh this leaf's lanes are row-sharded over, or None (one
+        device, a bucket too small to shard, a broadcast build side)."""
+        from ..parallel import dist
+        return self.mesh if self.spread \
+            and dist.shardable(nb, self.mesh) else None
 
     @staticmethod
     def compile(plan: PhysicalTableReader, ctx: _Ctx):
@@ -492,7 +558,7 @@ class _ReplicaLeaf:
             # aggregate (partial-state carry) serves it instead
             ex.close()
             return None
-        return _ReplicaLeaf(ex, plan)
+        return _ReplicaLeaf(ex, plan, mesh=ctx.mesh)
 
     def prepare(self, pb: _PipeBuilder) -> Optional[_TView]:
         from .tpu_executors import (_build_device_mask, _rep_string_dict,
@@ -514,29 +580,34 @@ class _ReplicaLeaf:
         slots = []
         meta: List[tuple] = []
         dts = []
+        # under a mesh: row-sharded where rows_mesh says so, else whole
+        # on every device (small tables, broadcast build sides)
+        from ..parallel import dist
+        lrows, lwhole = _layouts(self.mesh)
+        lay = lrows if self.rows_mesh(nb) is not None else lwhole
         for idx, c in enumerate(chk.columns):
             v = c.values()
             m = c.null_mask()
             sid = _slot_id(self.ex, idx)
             dn = _dev_upload(rep, ("devn", sid, nb),
-                             lambda m=m: kernels.pad1(m, nb, True))
+                             lambda m=m: kernels.pad1(m, nb, True), lay)
             if v.dtype == object or v.dtype.kind == "U":
                 got = _rep_string_dict(rep, sid, chk, idx)
                 codes, _card, _, uniques = got
                 dv = _dev_upload(rep, ("devcodes", sid, nb),
-                                 lambda c_=codes: kernels.pad1(c_, nb))
+                                 lambda c_=codes: kernels.pad1(c_, nb), lay)
                 meta.append((c.ft, uniques))
                 dts.append("s")
             else:
                 dv = _dev_upload(rep, ("devv", sid, nb),
-                                 lambda v=v: kernels.pad1(v, nb))
+                                 lambda v=v: kernels.pad1(v, nb), lay)
                 meta.append((c.ft, None))
                 dts.append("f" if v.dtype == np.float64 else "i")
-            slots.append((pb.add(dv), pb.add(dn)))
+            slots.append((pb.add(dv, lay), pb.add(dn, lay)))
         pi, pf = params
         ip = pb.add(np.asarray(pi))
         fp = pb.add(np.asarray(pf))
-        pb.key(("leaf", mask_key, nb, tuple(dts)))
+        pb.key(("leaf", mask_key, nb, tuple(dts)) + dist.layout_tag(lay))
 
         def emit(args):
             pairs = [(args[iv], args[im]) for iv, im in slots]
@@ -873,35 +944,80 @@ class _AggIndexNode:
         dense = ng <= kernels.SEG_UNROLL
         kernels.stats_add("agg_dense" if dense else "agg_sorted", 1)
         need_mm = any(k in ("min", "max") for k, _ in self.specs)
-        ig = io = ie = isg = None
+        # under a mesh whose shards hold the leaf's rows, each shard
+        # reduces its own rows to a partial [ngb] state and the states
+        # merge over the mesh (dist.mesh_sum / mesh_min / mesh_max): the
+        # output is whole on every device, as a parent join's build side
+        # wants it.  Both formulations shard the same way; the sorted
+        # one reads the index cut per shard (GroupIndex.shards)
+        from ..parallel import dist
+        mesh = self.leaf.rows_mesh(nb)
+        n_mesh = dist.mesh_shards(mesh)
+        lrows = _layouts(mesh)[0]
+        lwhole = _layouts(self.leaf.mesh)[1]
+        # one-device index lanes lie as the leaf's own lanes do
+        lidx = lwhole if mesh is None else lrows
+        ROWS, WHOLE = dist.specs()
+        #: the index lanes the reduction reads, in the kernel's order
         if dense:
             # group id per ROW in the narrowest lane, sentinel ngb on
             # padding rows (they match no group)
-            ig = pb.add(_dev_upload(
+            lanes = [pb.lane(
                 rep, ("gi_rowgid", sids, nb),
                 lambda: kernels.pad1(
-                    gidx.row_gid(np.min_scalar_type(ngb)), nb, fill=ngb)))
-        else:
-            io = pb.add(_dev_upload(rep, ("gi_order", sids, nb),
-                                    lambda: kernels.pad1(gidx.order, nb)))
-            ie = pb.add(_dev_upload(rep, ("gi_ends", sids, ngb),
-                                    lambda: kernels.pad1(
-                                        gidx.ends, ngb,
-                                        fill=max(rep.n_rows - 1, 0))))
+                    gidx.row_gid(np.min_scalar_type(ngb)), nb, fill=ngb),
+                lidx)]
+        elif mesh is None:
+            lanes = [
+                pb.lane(rep, ("gi_order", sids, nb),
+                        lambda: kernels.pad1(gidx.order, nb), lidx),
+                pb.lane(rep, ("gi_ends", sids, ngb),
+                        lambda: kernels.pad1(gidx.ends, ngb,
+                                             fill=max(rep.n_rows - 1, 0)),
+                        lidx)]
             if need_mm:
                 # group id per sorted position, sentinel ngb on padding —
                 # the segment-min/max lane
-                isg = pb.add(_dev_upload(
+                lanes.append(pb.lane(
                     rep, ("gi_sgid", sids, nb),
-                    lambda: kernels.pad1(gidx.sorted_gid(), nb, fill=ngb)))
+                    lambda: kernels.pad1(gidx.sorted_gid(), nb, fill=ngb),
+                    lidx))
+        else:
+            per = nb // n_mesh
+            cuts = []
+
+            def cut():
+                # shared by this prepare's lane builders; the host copy
+                # goes once the lanes are on the mesh
+                if not cuts:
+                    cuts.append(gidx.shards(n_mesh, per))
+                return cuts[0]
+
+            def ends_lane():
+                # groups past ng repeat the last boundary: empty ranges
+                ends = cut()[1]
+                out = np.empty((n_mesh, ngb), dtype=np.int64)
+                out[:, :ng] = ends
+                out[:, ng:] = ends[:, -1:] if ng else -1
+                return out.reshape(-1)
+            lanes = [
+                pb.lane(rep, ("gi_shard_order", sids, nb),
+                        lambda: cut()[0].reshape(-1), lrows),
+                pb.lane(rep, ("gi_shard_ends", sids, ngb), ends_lane, lrows),
+                pb.lane(rep, ("gi_shard_rows", sids, nb),
+                        lambda: cut()[3], lrows)]
+            if need_mm:
+                lanes.append(pb.lane(
+                    rep, ("gi_shard_sgid", sids, nb),
+                    lambda: np.where(cut()[2] >= ng, ngb,
+                                     cut()[2]).reshape(-1), lrows))
         gb_slots = []
         for j, (gk, gn) in enumerate(gidx.keycols):
-            ik = pb.add(_dev_upload(rep, ("gi_gkeys", sids, j, ngb),
-                                    lambda gk=gk: kernels.pad1(gk, ngb)))
-            ikn = pb.add(_dev_upload(rep, ("gi_gknull", sids, j, ngb),
-                                     lambda gn=gn: kernels.pad1(gn, ngb,
-                                                                True)))
-            gb_slots.append((ik, ikn))
+            gb_slots.append((
+                pb.lane(rep, ("gi_gkeys", sids, j, ngb),
+                        lambda gk=gk: kernels.pad1(gk, ngb), lwhole),
+                pb.lane(rep, ("gi_gknull", sids, j, ngb),
+                        lambda gn=gn: kernels.pad1(gn, ngb, True), lwhole)))
         pt = ParamTable()
         pt.add_int(ng)
         pt.add_int(rep.n_rows)
@@ -917,52 +1033,76 @@ class _AggIndexNode:
         ip, fp = pb.params(pt)
         # the cache key must pin EVERYTHING the traced closure depends
         # on: the formulation, key column ids + dtypes (int vs float key
-        # lanes retrace), the descriptor->spec slot mapping, and the
-        # output column map
+        # lanes retrace), the descriptor->spec slot mapping, the output
+        # column map, and the mesh the partial states merge over (none
+        # on one device: one-device keys are what they were)
         kdts = tuple((str(s), str(gk.dtype))
                      for s, (gk, _) in zip(sids, gidx.keycols))
         head = "aggdense" if dense else "aggindex"
         pb.key((head, tuple(keys), kdts, tuple(self.slots),
-                tuple(self.out_map), nb, ngb))
+                tuple(self.out_map), nb, ngb) + dist.layout_tag(lrows))
         spec_kinds = [k for k, _ in self.specs]
         slots = self.slots
         out_map = self.out_map
         schema_cols = self.plan.schema.columns
+        if mesh is None:
+            def merge_sum(x):
+                return x
+            merge_mm = {"min": merge_sum, "max": merge_sum}
+        else:
+            merge_sum = dist.mesh_sum
+            merge_mm = {"min": dist.mesh_min, "max": dist.mesh_max}
 
-        def dense_reducers(args, valid, pr):
-            seg = kernels._SegReduce(kernels.jax(), jn, args[ig], valid,
+        def dense_reducers(idx, valid, pr):
+            seg = kernels._SegReduce(kernels.jax(), jn, idx[0], valid,
                                      ngb, unroll=True)
             return dict(
                 gmask=lambda b: b, gvals=lambda v: v,
-                seg_sum=lambda x: seg.sum(x, valid),
-                seg_mm=lambda av, live, kind: seg.minmax(av, live,
-                                                         kind == "min"),
-                presence=seg.sum(valid.astype(jn.int64), valid))
+                seg_sum=lambda x: merge_sum(seg.sum(x, valid)),
+                seg_mm=lambda av, live, kind: merge_mm[kind](
+                    seg.minmax(av, live, kind == "min")),
+                presence=merge_sum(seg.sum(valid.astype(jn.int64), valid)))
 
-        def sorted_reducers(args, valid, pr):
+        def sorted_reducers(idx, valid, pr):
             j = kernels.jax()
-            order, ends = args[io], args[ie]
+            order, ends = idx[0], idx[1]
             # padded sorted positions map to row 0 via the padded order
             # array — they MUST be masked or row 0 is counted once per
-            # padding slot
-            in_table = jn.arange(nb) < pr[0][1]
+            # padding slot.  A shard's own rows end at its row count.
+            if mesh is None:
+                in_table = jn.arange(nb) < pr[0][1]
+                isg = idx[2] if need_mm else None
+            else:
+                in_table = jn.arange(nb // n_mesh) < idx[2][0]
+                isg = idx[3] if need_mm else None
             valid_s = valid[order] & in_table
-            prev = jn.concatenate([jn.full((1,), -1, dtype=jn.int64),
-                                   ends[:-1]])
-            prev_safe = jn.maximum(prev, 0)
+            if mesh is None:
+                prev = jn.concatenate([jn.full((1,), -1, dtype=jn.int64),
+                                       ends[:-1]])
+                prev_safe = jn.maximum(prev, 0)
 
             def seg(x_s):
                 c = kernels.prefix_sum(x_s)
-                hi = c[ends]
-                lo = jn.where(prev >= 0, c[prev_safe],
-                              jn.zeros((), dtype=x_s.dtype))
-                return hi - lo
+                if mesh is None:
+                    hi = c[ends]
+                    lo = jn.where(prev >= 0, c[prev_safe],
+                                  jn.zeros((), dtype=x_s.dtype))
+                else:
+                    # a shard may hold no row up to a group (boundary
+                    # -1); a group's lower boundary is the group
+                    # before's upper one, so ONE [ngb] gather serves
+                    # both (a 2 M-lane gather is 50 ms on a v5e)
+                    zero = jn.zeros((), dtype=x_s.dtype)
+                    hi = jn.where(ends >= 0, c[jn.maximum(ends, 0)], zero)
+                    lo = jn.concatenate([zero[None], hi[:-1]])
+                return merge_sum(hi - lo)
 
             def seg_mm(av_s, live_s, kind):
-                gl = jn.where(live_s, args[isg], ngb)
+                gl = jn.where(live_s, isg, ngb)
                 op = j.ops.segment_min if kind == "min" \
                     else j.ops.segment_max
-                return op(av_s, gl, num_segments=ngb + 1)[:ngb]
+                return merge_mm[kind](
+                    op(av_s, gl, num_segments=ngb + 1)[:ngb])
             return dict(
                 gmask=lambda b: b[order] & in_table,
                 gvals=lambda v: v[order],
@@ -970,14 +1110,28 @@ class _AggIndexNode:
                 presence=seg(valid_s.astype(jn.int64)))
         reducers = dense_reducers if dense else sorted_reducers
 
+        def reduce(idx, valid, pairs, pr):
+            """(rows per group, [(value, null)] per spec), each [ngb]."""
+            red = reducers(idx, valid, pr)
+            res = _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid,
+                                n_out=ngb, **red)
+            return red["presence"], res
+        if mesh is not None:
+            # merged states are whole by construction (psum; min/max
+            # gather and reduce, beyond the static checker)
+            reduce = dist.shard_map_unchecked(
+                reduce, mesh=mesh,
+                in_specs=([ROWS] * len(lanes), ROWS,
+                          [(ROWS, ROWS)] * len(tv.meta), (WHOLE, WHOLE)),
+                out_specs=(WHOLE, [(WHOLE, WHOLE)] * len(spec_kinds)))
+
         def emit(args):
             valid, pairs = tv.emit(args)
             pr = (args[ip], args[fp])
-            red = reducers(args, valid, pr)
-            res = _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid,
-                                n_out=ngb, **red)
+            presence, res = reduce([args[i] for i in lanes], valid,
+                                   list(pairs), pr)
             outs = _slot_outputs(jn, res, slots)
-            gvalid = (jn.arange(ngb) < pr[0][0]) & (red["presence"] > 0)
+            gvalid = (jn.arange(ngb) < pr[0][0]) & (presence > 0)
             cols = []
             for m in out_map:
                 if m[0] == "agg":
@@ -1137,7 +1291,24 @@ class _JoinNode:
                          session_vars=getattr(ctx.exec_ctx,
                                               "session_vars", None))
 
+    def _place_build(self) -> None:
+        """Under a mesh, before the build side uploads: a broadcast
+        join's build leaf lies whole on every device; only a shuffle
+        join, which re-partitions both sides, leaves it row-sharded."""
+        leaf = _leafish(self.build)
+        if self.mesh is None or leaf is None:
+            return
+        from ..parallel import dist
+        rep = leaf.replica()
+        nbb = kernels.bucket(max(rep.n_rows, 1)) if rep is not None else 0
+        leaf.spread = (
+            self.tp not in ("semi", "anti") and not self.mult
+            and self.nk == 1 and self._shuffle_wanted(
+                nbb, nbb,
+                self.mesh if dist.shardable(nbb, self.mesh) else None))
+
     def prepare(self, pb: _PipeBuilder) -> Optional[_TView]:
+        self._place_build()
         btv = self.build.prepare(pb)
         if btv is None:
             return None
@@ -1159,7 +1330,8 @@ class _JoinNode:
         key -> build pos-table -> live?  The probe's pairs pass through
         untouched, so an entire Q5-style join chain with an interleaved
         semijoin stays ONE traced program."""
-        info = _prepare_build_key_info(self.build, self.build_key, pb)
+        info = _prepare_build_key_info(self.build, self.build_key, pb,
+                                       self.mesh)
         if info is None:
             return None
         lo, hi, it, tbl_len = info
@@ -1242,7 +1414,8 @@ class _JoinNode:
             live = ~null_any
             tbl[comp[live]] = np.nonzero(live)[0].astype(np.int32)
             return tbl
-        it = pb.add(_dev_upload(rep, ("postable_multi", sids), mk))
+        lwhole = _layouts(self.mesh)[1]
+        it = pb.lane(rep, ("postable_multi", sids), mk, lwhole)
         pt = ParamTable()
         for lo, hi, st in zip(los, his, strides):
             pt.add_int(lo)
@@ -1449,16 +1622,13 @@ class _JoinNode:
             bcols = [(bv2[brow], bn2[brow] | ~matched) for bv2, bn2 in B_]
             return valid_out, P_, bcols
 
-        from ..parallel.dist import shard_map_fn
-        shard_map, P = shard_map_fn()
-        pspec = [(P("shard"), P("shard"))] * npc
-        bspec = [(P("shard"), P("shard"))] * nbc
+        shard_map, _ = dist.shard_map_fn()
+        ROWS, WHOLE = dist.specs()
         sharded = shard_map(
             kernel, mesh=mesh,
-            in_specs=(pspec, P("shard"), bspec, P("shard"), (P(), P())),
-            out_specs=(P("shard"),
-                       [(P("shard"), P("shard"))] * npc,
-                       [(P("shard"), P("shard"))] * nbc))
+            in_specs=([(ROWS, ROWS)] * npc, ROWS, [(ROWS, ROWS)] * nbc,
+                      ROWS, (WHOLE, WHOLE)),
+            out_specs=(ROWS, [(ROWS, ROWS)] * npc, [(ROWS, ROWS)] * nbc))
 
         def emit(args):
             bvalid, bpairs = btv.emit(args)
@@ -1484,7 +1654,8 @@ class _JoinNode:
             out = self._prepare_unique_shuffle(pb, btv, ptv, self.mesh)
             if out is not None:
                 return out  # else: broadcast below
-        info = _prepare_build_key_info(self.build, self.build_key, pb)
+        info = _prepare_build_key_info(self.build, self.build_key, pb,
+                                       self.mesh)
         if info is None:
             return None
         lo, hi, it, tbl_len = info
@@ -1527,16 +1698,14 @@ class _JoinNode:
             return valid_out, gathered
 
         if mesh is not None:
-            from ..parallel.dist import shard_map_fn
-            shard_map, P = shard_map_fn()
-            pspec = [(P("shard"), P("shard"))] * len(ptv.meta)
-            bspec = [(P(), P())] * len(btv.meta)
+            shard_map, _ = dist.shard_map_fn()
+            ROWS, WHOLE = dist.specs()
             sharded = shard_map(
                 kernel, mesh=mesh,
-                in_specs=(pspec, P("shard"), bspec, P(), P(),
-                          (P(), P())),
-                out_specs=(P("shard"),
-                           [(P("shard"), P("shard"))] * len(btv.meta)))
+                in_specs=([(ROWS, ROWS)] * len(ptv.meta), ROWS,
+                          [(WHOLE, WHOLE)] * len(btv.meta), WHOLE, WHOLE,
+                          (WHOLE, WHOLE)),
+                out_specs=(ROWS, [(ROWS, ROWS)] * len(btv.meta)))
         else:
             sharded = kernel
 
@@ -1624,14 +1793,14 @@ class _JoinNode:
         ngb = kernels.bucket(max(ng, 1))
         tbl_len = int(tbl.shape[0])
         pk_slots = tuple(k.index for k in self.probe_keys)
-        io = pb.add(_dev_upload(rep, ("gi_order", sids, nbb),
-                                lambda: kernels.pad1(gidx.order, nbb)))
-        ie = pb.add(_dev_upload(rep, ("gi_ends", sids, ngb),
-                                lambda: kernels.pad1(
-                                    gidx.ends, ngb,
-                                    fill=max(rep.n_rows - 1, 0))))
-        it = pb.add(_dev_upload(rep, ("gi_postable_dev", sids),
-                                lambda: tbl))
+        lwhole = _layouts(self.mesh)[1]
+        io = pb.lane(rep, ("gi_order", sids, nbb),
+                     lambda: kernels.pad1(gidx.order, nbb), lwhole)
+        ie = pb.lane(rep, ("gi_ends", sids, ngb),
+                     lambda: kernels.pad1(gidx.ends, ngb,
+                                          fill=max(rep.n_rows - 1, 0)),
+                     lwhole)
+        it = pb.lane(rep, ("gi_postable_dev", sids), lambda: tbl, lwhole)
         pt = ParamTable()
         pt.add_int(ng)
         pt.add_int(rep.n_rows)
@@ -1717,16 +1886,15 @@ class _JoinNode:
             # probe side sharded over the mesh, CSR structures broadcast
             # (each shard expands its own probe block into its own
             # per-shard bucket — SURVEY §2.11 P4)
-            from ..parallel.dist import shard_map_fn
-            shard_map, P = shard_map_fn()
+            shard_map, _ = dist.shard_map_fn()
+            ROWS, WHOLE = dist.specs()
             sharded = shard_map(
                 kernel, mesh=mesh,
-                in_specs=([(P("shard"), P("shard"))] * npc, P("shard"),
-                          [(P(), P())] * nbc, P(), P(), P(), P(),
-                          (P(), P())),
-                out_specs=(P("shard"),
-                           [(P("shard"), P("shard"))] * npc,
-                           [(P("shard"), P("shard"))] * nbc))
+                in_specs=([(ROWS, ROWS)] * npc, ROWS,
+                          [(WHOLE, WHOLE)] * nbc, WHOLE, WHOLE, WHOLE,
+                          WHOLE, (WHOLE, WHOLE)),
+                out_specs=(ROWS, [(ROWS, ROWS)] * npc,
+                           [(ROWS, ROWS)] * nbc))
         else:
             sharded = kernel
 
@@ -2053,9 +2221,11 @@ def _has_build_key_info(node, build_key) -> bool:
     return False
 
 
-def _prepare_build_key_info(node, build_key, pb: _PipeBuilder):
+def _prepare_build_key_info(node, build_key, pb: _PipeBuilder, mesh=None):
     """(lo, hi, input index of the device pos-table, table length) mapping
-    build-key value -> build view row."""
+    build-key value -> build view row.  Under a mesh the table lies whole
+    on every device: each shard probes it with its own rows."""
+    lwhole = _layouts(mesh)[1]
     if isinstance(node, _AggIndexNode):
         got = node.build_key_info()
         if got is None:
@@ -2064,15 +2234,15 @@ def _prepare_build_key_info(node, build_key, pb: _PipeBuilder):
         rep = node.leaf.replica()
         from .tpu_executors import _slot_id
         sids = (_slot_id(node.leaf.ex, node.key_cols[0].index),)
-        d = _dev_upload(rep, ("gi_postable_dev", sids), lambda: tbl)
-        return lo, hi, pb.add(d), int(tbl.shape[0])
+        it = pb.lane(rep, ("gi_postable_dev", sids), lambda: tbl, lwhole)
+        return lo, hi, it, int(tbl.shape[0])
     if isinstance(node, _SelNode):
-        return _prepare_build_key_info(node.child, build_key, pb)
+        return _prepare_build_key_info(node.child, build_key, pb, mesh)
     if isinstance(node, _ProjNode):
         e = node.exprs[build_key.index]
         if not isinstance(e, ExprColumn):
             return None
-        return _prepare_build_key_info(node.child, e, pb)
+        return _prepare_build_key_info(node.child, e, pb, mesh)
     if isinstance(node, _ReplicaLeaf):
         rep = node.replica()
         if rep is None:
@@ -2087,8 +2257,8 @@ def _prepare_build_key_info(node, build_key, pb: _PipeBuilder):
         if got is None:
             return None
         lo, hi, tbl = got
-        d = _dev_upload(rep, ("postable_dev", sid), lambda: tbl)
-        return lo, hi, pb.add(d), int(tbl.shape[0])
+        it = pb.lane(rep, ("postable_dev", sid), lambda: tbl, lwhole)
+        return lo, hi, it, int(tbl.shape[0])
     return None
 
 
@@ -2369,10 +2539,10 @@ class _OrderNode:
                 [gidx] + _sort_ops(jn, kvs, descs, valid), kc)
             lanes = ([(kv[0][take], kv[1][take]) for kv in fn_kvs]
                      + [(v[take], m[take]) for v, m in pairs])
-            g_valid = lax.all_gather(valid[take], "shard", tiled=True)
-            g_gidx = lax.all_gather(gidx[take], "shard", tiled=True)
-            g_lanes = [(lax.all_gather(v, "shard", tiled=True),
-                        lax.all_gather(m, "shard", tiled=True))
+            g_valid = dist.mesh_gather(valid[take], tiled=True)
+            g_gidx = dist.mesh_gather(gidx[take], tiled=True)
+            g_lanes = [(dist.mesh_gather(v, tiled=True),
+                        dist.mesh_gather(m, tiled=True))
                        for v, m in lanes]
             g_fn_kvs = g_lanes[:len(fn_kvs)]
             g_pairs = g_lanes[len(fn_kvs):]
@@ -2386,20 +2556,19 @@ class _OrderNode:
             outs = [(v[take2], m[take2]) for v, m in g_pairs]
             return out_valid, outs
 
-        from ..parallel.dist import shard_map_fn, shard_map_unchecked
-        _, P = shard_map_fn()
+        from ..parallel import dist
+        ROWS, WHOLE = dist.specs()
 
         def emit(args):
             valid, pairs = tv.emit(args)
             pr = (args[ip], args[fp])
             fn_kvs = [f(pairs, pr) for kind, f in fns if kind == "fn"]
             npairs = len(pairs)
-            sharded = shard_map_unchecked(
+            sharded = dist.shard_map_unchecked(
                 kernel, mesh=mesh,
-                in_specs=([(P("shard"), P("shard"))] * len(fn_kvs),
-                          P("shard"),
-                          [(P("shard"), P("shard"))] * npairs),
-                out_specs=(P(), [(P(), P())] * npairs))
+                in_specs=([(ROWS, ROWS)] * len(fn_kvs), ROWS,
+                          [(ROWS, ROWS)] * npairs),
+                out_specs=(WHOLE, [(WHOLE, WHOLE)] * npairs))
             return sharded(fn_kvs, valid, list(pairs))
         return _TView(emit, kb - off, tv.meta, "order_mesh")
 
@@ -2486,10 +2655,15 @@ def _contains_join(plan) -> bool:
     return any(_contains_join(c) for c in plan.children)
 
 
-def _contains_grouped_agg(plan) -> bool:
-    if isinstance(plan, PhysicalHashAgg) and plan.group_by:
+def _contains_grouped_agg(plan, above_reader: bool = True) -> bool:
+    """A GROUP BY anywhere in the plan; ``above_reader=False`` leaves out
+    those whose child is a table reader (_AggIndexNode's shape)."""
+    if isinstance(plan, PhysicalHashAgg) and plan.group_by and (
+            above_reader
+            or not isinstance(plan.children[0], PhysicalTableReader)):
         return True
-    return any(_contains_grouped_agg(c) for c in plan.children)
+    return any(_contains_grouped_agg(c, above_reader)
+               for c in plan.children)
 
 
 # =========================================================================
@@ -2531,6 +2705,7 @@ class DevPipeExec:
         self._fallback_builder = fallback_builder
         self._fallback = None
         self._node = None
+        self._mesh = None  # the mesh the node tree was compiled for
         self._done = False
 
     def field_types(self):
@@ -2552,18 +2727,21 @@ class DevPipeExec:
             self._node = None
             self._open_fallback(ctx)
             return
-        if not _contains_join(self.plan) \
-                and _contains_grouped_agg(self.plan) \
-                and mesh_if_enabled(ctx.session_vars) is not None:
-            # agg-only pipelines under tidb_mesh_parallel ride the per-op
-            # SHARDED fused aggregate (psum partial merge over the mesh);
-            # devpipe's agg node is single-device.  Join pipelines and
-            # plain scan+TopN stay here: the join and TopN nodes have
-            # their own mesh (shard_map) paths.
+        mesh = mesh_if_enabled(ctx.session_vars)
+        if mesh is not None and not _contains_join(self.plan) \
+                and _contains_grouped_agg(self.plan, above_reader=False):
+            # under tidb_mesh_parallel a GROUP BY over anything but a
+            # table reader rides the per-op SHARDED fused aggregate (psum
+            # partial merge over the mesh): devpipe's sort-group node is
+            # single-device.  Reader-rooted GROUP BYs (_AggIndexNode
+            # merges per-shard partial states), join pipelines and plain
+            # scan+TopN stay here: those nodes have their own mesh
+            # (shard_map) paths.
             self._node = None
             self._open_fallback(ctx)
             return
-        cctx = _Ctx(ctx, mesh=mesh_if_enabled(ctx.session_vars))
+        self._mesh = mesh
+        cctx = _Ctx(ctx, mesh=mesh)
         try:
             self._node = _compile_device(self.plan, cctx)
         except Exception:
@@ -2679,6 +2857,13 @@ class DevPipeExec:
         tv = self._node.prepare(pb)
         if tv is None:
             return None
+        inputs = pb.inputs
+        if self._mesh is not None:
+            # every input already lies as its program asks, or is moved
+            # (and counted): a warm mesh dispatch moves none
+            from ..parallel import dist
+            inputs = dist.settle(pb.inputs, pb.layouts)
+            dist.note_dispatch(self._mesh)
         jn = _jn()
         nb = tv.nb
         ncols = len(tv.meta)
@@ -2709,7 +2894,7 @@ class DevPipeExec:
                 _note_compiled(pb.kparts)
                 return kernels.counted_jit(mega, name=shape), schema
             fn, schema = progcache.get(key, build_small)
-            vals = kernels.unpack_flat(fn(pb.inputs), schema)
+            vals = kernels.unpack_flat(fn(inputs), schema)
             keep = np.nonzero(vals[0])[0]
             host = [(vals[1 + 2 * i][keep], vals[2 + 2 * i][keep])
                     for i in range(ncols)]
@@ -2723,7 +2908,7 @@ class DevPipeExec:
                 _note_compiled(pb.kparts)
                 return kernels.counted_jit(mega, name=shape)
             fn = progcache.get(key, build_big)
-            res = fn(pb.inputs)
+            res = fn(inputs)
             valid, items = res[0], list(res[1:])
 
             def build_count():

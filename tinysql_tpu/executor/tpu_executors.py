@@ -769,7 +769,11 @@ class TPUHashAggExec(Executor):
             mask_fn, mask_prog_key, mask_needed = dev_mask
             fmask = None
 
-        # ---- device columns (memoized per replica + bucket) -------------
+        # ---- device columns (memoized per replica + bucket; under a mesh
+        # row-sharded over it and memoized under that layout) ------------
+        from .devpipe import _dev_upload, _layouts
+        mesh = self._mesh_if_enabled(nb)
+        lrows, lwhole = _layouts(mesh)
         needed = set(mask_needed)
         for a in arg_exprs:
             if isinstance(a, tuple):
@@ -788,18 +792,18 @@ class TPUHashAggExec(Executor):
                 # the slot carries the code column
                 got = _rep_string_dict(rep, sid, chk, idx)
                 codes = got[0]
-                dv = rep.memo(("devcodes", sid, nb),
-                              lambda c=codes: kernels.h2d_pad(c, nb))
+                dv = _dev_upload(rep, ("devcodes", sid, nb),
+                                 lambda c=codes: kernels.pad1(c, nb), lrows)
             elif v.dtype == object or v.dtype.kind == "U":
                 if kind == "full":
                     child._replica = rep
                     return None  # string values in a compute expr
                 dv = None
             else:
-                dv = rep.memo(("devv", sid, nb),
-                              lambda v=v: kernels.h2d_pad(v, nb))
-            dn = rep.memo(("devn", sid, nb),
-                          lambda m=m: kernels.h2d_pad(m, nb, True))
+                dv = _dev_upload(rep, ("devv", sid, nb),
+                                 lambda v=v: kernels.pad1(v, nb), lrows)
+            dn = _dev_upload(rep, ("devn", sid, nb),
+                             lambda m=m: kernels.pad1(m, nb, True), lrows)
             if dev_cols[idx] is None or dv is not None:
                 dev_cols[idx] = (dv, dn)
 
@@ -814,12 +818,11 @@ class TPUHashAggExec(Executor):
         else:
             mask = np.zeros(nb, dtype=bool)
             mask[:n] = fmask if fmask is not None else True
-            mask_spec = ("host", kernels.h2d(mask))
+            mask_spec = ("host", kernels.h2d(mask, lrows))
 
         # ---- run --------------------------------------------------------
         if not plan.group_by:
             out_keys = []
-            mesh = self._mesh_if_enabled(nb)
             if mesh is not None:
                 # partial->final over the mesh, and STILL batchable: the
                 # stacked variant vmaps B queries over the N-shard
@@ -839,12 +842,11 @@ class TPUHashAggExec(Executor):
                     program_key=program_key, params=params,
                     batchable=True)
         else:
-            gid_dev = rep.memo(
-                ("gid_dev", tuple(slot_ids[e.index]
-                                  for e in plan.group_by), nb),
-                lambda: kernels.h2d_pad(
-                    self._compose_gid(key_layouts, n), nb))
-            mesh = self._mesh_if_enabled(nb)
+            gid_dev = _dev_upload(
+                rep, ("gid_dev", tuple(slot_ids[e.index]
+                                       for e in plan.group_by), nb),
+                lambda: kernels.pad1(self._compose_gid(key_layouts, n), nb),
+                lrows)
             if mesh is not None:
                 present, out_aggs, first_orig = \
                     kernels.fused_segment_aggregate_sharded(

@@ -52,6 +52,19 @@ _DEVICE_METRICS = {
     "agg_sorted": ("tinysql_agg_sorted_total",
                    "Fused GROUP BYs over a replica reduced in sorted "
                    "order (gather + prefix sum)"),
+    "mesh_dispatches": ("tinysql_mesh_dispatches_total",
+                        "Dispatches whose program ran over the whole "
+                        "device mesh (tidb_mesh_parallel)"),
+    "reshard_bytes": ("tinysql_reshard_bytes_total",
+                      "Bytes of inputs a mesh dispatch found laid out "
+                      "otherwise than its program asks, and moved "
+                      "between devices (0 when warm)"),
+    "mesh_resident_bytes_max": ("tinysql_mesh_resident_bytes_max",
+                                "Bytes of replica lanes placed on the "
+                                "fullest device of the mesh"),
+    "mesh_resident_bytes_min": ("tinysql_mesh_resident_bytes_min",
+                                "Bytes of replica lanes placed on the "
+                                "emptiest device of the mesh"),
     "flops": ("tinysql_device_flops_total",
               "XLA cost-analysis FLOPs of dispatched programs"),
     "bytes_accessed": ("tinysql_device_bytes_accessed_total",
@@ -467,12 +480,15 @@ FLIGHT_METRIC_NAMES = (
 #: gauge-vs-counter, and declaring it here keeps this module
 #: importable without jax
 HWM_STATS_KEYS = ("pipe_depth_hwm",)
+#: STATS keys that are gauges set to a value as it stands (not reset by
+#: a snapshot, reported whole by kernels.stats_delta)
+GAUGE_STATS_KEYS = ("mesh_resident_bytes_max", "mesh_resident_bytes_min")
 
 # device-economics names come from the _DEVICE_METRICS map above (one
 # definition of the STATS-key -> prometheus-name mapping)
 for _k, (_name, _help) in _DEVICE_METRICS.items():
-    METRICS[_name] = ("gauge" if _k in HWM_STATS_KEYS else "counter",
-                      _help)
+    METRICS[_name] = ("gauge" if _k in HWM_STATS_KEYS + GAUGE_STATS_KEYS
+                      else "counter", _help)
 # auto-prewarm worker counters (session/prewarm.py PREWARM_STATS keys)
 for _k in ("cycles", "families_warmed", "bucket_programs",
            "stacked_programs", "errors",
@@ -570,7 +586,7 @@ def render_prometheus() -> str:
     try:
         from ..ops import kernels, progcache
         stats = dict(kernels.STATS)
-        hwm_keys = kernels._HWM_KEYS
+        hwm_keys = kernels._HWM_KEYS + GAUGE_STATS_KEYS
         pstats = progcache.stats_snapshot()
         psize = progcache.size()
     except Exception:  # jax import failure must not kill /metrics

@@ -122,6 +122,8 @@ STATS = {"dispatches": 0, "d2h_transfers": 0, "d2h_bytes": 0,
          "h2d_transfers": 0, "h2d_bytes": 0,
          "host_dispatches": 0,
          "agg_dense": 0, "agg_sorted": 0,
+         "mesh_dispatches": 0, "reshard_bytes": 0,
+         "mesh_resident_bytes_max": 0, "mesh_resident_bytes_min": 0,
          "device_s": 0.0, "profiled_dispatches": 0,
          "flops": 0.0, "bytes_accessed": 0.0,
          "pipe_blocks": 0, "pipe_stage_s": 0.0, "pipe_dispatch_s": 0.0,
@@ -130,6 +132,7 @@ STATS = {"dispatches": 0, "d2h_transfers": 0, "d2h_bytes": 0,
 #: STATS keys that are high-water marks, not accumulators — declared in
 #: the central metric registry so the registry's gauge-vs-counter kinds
 #: and the /metrics render share one definition
+from ..obs.metrics import GAUGE_STATS_KEYS as _GAUGE_KEYS  # noqa: E402
 from ..obs.metrics import HWM_STATS_KEYS as _HWM_KEYS  # noqa: E402
 
 #: guards the global STATS read-modify-writes — sessions and devpipe
@@ -156,6 +159,13 @@ def stats_hwm(key: str, n) -> None:
         if n > STATS.get(key, 0):
             STATS[key] = n
     _obs.record_hwm(key, n)
+
+
+def stats_set(key: str, n) -> None:
+    """Gauge write path (obs/metrics GAUGE_STATS_KEYS): the value as it
+    stands, not an increment — stats_delta reports it whole."""
+    with _STATS_MU:
+        STATS[key] = n
 
 
 def host_dispatch(n: int = 1) -> None:
@@ -227,7 +237,8 @@ def stats_snapshot() -> dict:
 
 def stats_delta(since: dict) -> dict:
     now = stats_snapshot()
-    return {k: (v if k in _HWM_KEYS else v - since.get(k, 0))
+    return {k: (v if k in _HWM_KEYS or k in _GAUGE_KEYS
+                else v - since.get(k, 0))
             for k, v in now.items()}
 
 
@@ -387,16 +398,26 @@ def counted_jit(fn, name: str = "", **kw):
     return call
 
 
-def h2d(a):
+def h2d(a, layout=None):
     """Counted host->device upload — the H2D mirror of :func:`d2h`, so
     transfer accounting is symmetric (pre-ISSUE-11, ParamTable pushes
     and column uploads were invisible: d2h had counters, h2d had none).
-    One transfer per array; bytes charged from the HOST buffer."""
+    One transfer per array; bytes charged from the HOST buffer, once,
+    also where a mesh ``layout`` (parallel/dist.py ``rows`` / ``whole``)
+    spreads the array over several devices."""
     host = np.asarray(a)
-    out = jnp().asarray(host)
+    out = jnp().asarray(host) if layout is None \
+        else jax().device_put(host, layout)
     stats_add("h2d_transfers", 1)
     stats_add("h2d_bytes", int(host.nbytes))
     return out
+
+
+def relayout(arr, layout):
+    """Counted device->device move of an array that a mesh dispatch found
+    laid out otherwise than its program asks (``reshard_bytes``)."""
+    stats_add("reshard_bytes", int(arr.nbytes))
+    return jax().device_put(arr, layout)
 
 
 def h2d_pad(a: np.ndarray, n: int, fill=0):
@@ -1039,9 +1060,10 @@ def _mask_parts(mask):
     return mask_fn, ("devmask", key), _EMPTY_MASK
 
 
-def _params_dev(params):
+def _params_dev(params, layout=None):
     """Upload the per-query constant vectors (absent slots ride 0-length
-    arrays so parameterless programs share the call signature)."""
+    arrays so parameterless programs share the call signature); a mesh
+    program's go whole onto every device (``layout``)."""
     global _EMPTY_I64, _EMPTY_F64
     jn = jnp()
     if _EMPTY_I64 is None:
@@ -1050,7 +1072,7 @@ def _params_dev(params):
     if params is None:
         return (_EMPTY_I64, _EMPTY_F64)
     pi, pf = params
-    return (h2d(pi), h2d(pf))
+    return (h2d(pi, layout), h2d(pf, layout))
 
 
 def _lower_arg(e):
@@ -1381,7 +1403,7 @@ def fused_segment_aggregate_sharded(mesh, dev_cols, gid_dev,
     two buckets over power-of-two meshes always are)."""
     from ..parallel import dist
     from . import shardops
-    _, P = dist.shard_map_fn()
+    ROWS, WHOLE = dist.specs()
     j = jax()
     jn = jnp()
     nb = int(gid_dev.shape[0])
@@ -1411,7 +1433,7 @@ def fused_segment_aggregate_sharded(mesh, dev_cols, gid_dev,
                 valid = mask_in
             seg = _SegReduce(j, jn, gid, valid, ns)
             presence_local, first_local = seg.presence_first()
-            presence = j.lax.psum(presence_local, "shard")
+            presence = dist.mesh_sum(presence_local)
             # local first indexes THIS shard; absent segments carry the
             # sentinel rows_local, which must map to the global max (nb-1)
             # or pmin would prefer an empty low shard over a real high one
@@ -1420,21 +1442,21 @@ def fused_segment_aggregate_sharded(mesh, dev_cols, gid_dev,
             first_orig = dist.mesh_min(first_global)
             outs = _fused_agg_outs(
                 j, jn, agg_specs, arg_fns, cols, gid, valid, ns, presence,
-                merge_sum=lambda x: j.lax.psum(x, "shard"),
+                merge_sum=dist.mesh_sum,
                 merge_min=dist.mesh_min, merge_max=dist.mesh_max,
                 seg=seg, pr=pr)
             return presence, first_orig, outs
 
         col_spec = tuple(
-            ((P("shard") if c[0] is not None else None, P("shard"))
+            ((ROWS if c[0] is not None else None, ROWS)
              if c is not None else None)
             for c in dev_cols)
         # outputs are replicated by construction (psum, dist.mesh_min/max);
         # the gather-and-reduce merges are beyond the static checker
         sm = dist.shard_map_unchecked(
             kernel, mesh=mesh,
-            in_specs=(col_spec, P("shard"), P("shard"), (P(), P())),
-            out_specs=(P(), P(), [(P(), P())] * len(agg_specs)))
+            in_specs=(col_spec, ROWS, ROWS, (WHOLE, WHOLE)),
+            out_specs=(WHOLE, WHOLE, [(WHOLE, WHOLE)] * len(agg_specs)))
         kernel_schema: list = []
 
         def packed(cols, gid, mask_in, pr):
@@ -1446,8 +1468,9 @@ def fused_segment_aggregate_sharded(mesh, dev_cols, gid_dev,
         return counted_jit(packed), kernel_schema
     pfn, schema = progcache.get(key, build)
     shardops.note_round(nb // n_dev)
+    dist.note_dispatch(mesh)
     vals = unpack_flat(pfn(tuple(dev_cols), gid_dev, mask_arr,
-                           _params_dev(params)), schema)
+                           _params_dev(params, dist.whole(mesh))), schema)
     presence, first_orig = vals[0], vals[1]
     rest = vals[2:]
     present = np.nonzero(presence > 0)[0]

@@ -515,9 +515,9 @@ def fused_scalar_aggregate_sharded(mesh, dev_cols, agg_specs, arg_exprs,
 
     def build():
         arg_fns = [kernels._lower_arg(e) for e in arg_exprs]
-        _, P = dist.shard_map_fn()
+        ROWS, WHOLE = dist.specs()
         col_spec = tuple(
-            ((P("shard") if c[0] is not None else None, P("shard"))
+            ((ROWS if c[0] is not None else None, ROWS)
              if c is not None else None)
             for c in dev_cols)
 
@@ -539,18 +539,16 @@ def fused_scalar_aggregate_sharded(mesh, dev_cols, agg_specs, arg_exprs,
                     if has_arg and af is not None:
                         av, an = af(cols, pr)
                     if func == "count_star":
-                        c = j.lax.psum(
-                            jn.sum(valid.astype(jn.int64)), "shard")
+                        c = dist.mesh_sum(jn.sum(valid.astype(jn.int64)))
                         outs.append((c[None], jn.zeros(1, dtype=bool)))
                         continue
                     live = valid & ~an
-                    cnt = j.lax.psum(
-                        jn.sum(live.astype(jn.int64)), "shard")
+                    cnt = dist.mesh_sum(jn.sum(live.astype(jn.int64)))
                     if func == "count":
                         outs.append((cnt[None], jn.zeros(1, dtype=bool)))
                     elif func in ("sum", "sum0"):
-                        total = j.lax.psum(
-                            jn.sum(jn.where(live, av, 0)), "shard")
+                        total = dist.mesh_sum(
+                            jn.sum(jn.where(live, av, 0)))
                         outs.append((total[None],
                                      jn.zeros(1, dtype=bool)
                                      if func == "sum0"
@@ -570,8 +568,7 @@ def fused_scalar_aggregate_sharded(mesh, dev_cols, agg_specs, arg_exprs,
                         outs.append((merged[None], (cnt == 0)[None]))
                     else:  # pragma: no cover
                         raise ValueError(func)
-                n_valid = j.lax.psum(
-                    jn.sum(valid.astype(jn.int64)), "shard")
+                n_valid = dist.mesh_sum(jn.sum(valid.astype(jn.int64)))
                 # first valid GLOBAL row index (0 when none — the
                 # single-device argmax convention); the sentinel nb maps
                 # empty shards past every real row before the pmin
@@ -588,8 +585,8 @@ def fused_scalar_aggregate_sharded(mesh, dev_cols, agg_specs, arg_exprs,
             # gather-and-reduce merges are beyond the static checker
             sm = dist.shard_map_unchecked(
                 body, mesh=mesh,
-                in_specs=(col_spec, P("shard"), (P(), P())),
-                out_specs=P())
+                in_specs=(col_spec, ROWS, (WHOLE, WHOLE)),
+                out_specs=WHOLE)
 
             def packed(cols, mask_in, pr):
                 return kernels.pack_arrays(kernel_schema,
@@ -609,9 +606,10 @@ def fused_scalar_aggregate_sharded(mesh, dev_cols, agg_specs, arg_exprs,
                 else kernels.unpack_flat(val, schema)
             return kernels._unpack_scalar_agg(vals)
     t0 = time.perf_counter()
+    dist.note_dispatch(mesh)
     out = kernels._unpack_scalar_agg(kernels.unpack_flat(
-        fn(tuple(dev_cols), mask_arr, kernels._params_dev(params)),
-        schema))
+        fn(tuple(dev_cols), mask_arr,
+           kernels._params_dev(params, dist.whole(mesh))), schema))
     _note_device_region(t0)
     return out
 

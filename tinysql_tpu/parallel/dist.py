@@ -32,6 +32,15 @@ What actually ships on this layer today:
   spill ladder), shards work locally, exact merges (searchsorted rank
   counting, top-k tournaments) happen on-device.
 
+- **layouts** (``rows`` / ``whole`` / ``place`` / ``settle``): THE one
+  place that says how a replica lane (row-sharded: each device holds a
+  contiguous 1/n of the padded lane), a broadcast table and a merged
+  partial state (whole on every device) lie on a mesh.  The replica's
+  uploads place their arrays through ``place``, every shard_map's
+  ``in_specs`` are ``ROWS`` / ``WHOLE``, and ``settle`` checks at
+  dispatch that each input already lies as its program asks — so a
+  warm mesh dispatch moves no input between devices.
+
 Policy lives here too: session_mesh/sized_mesh gate on
 tidb_mesh_parallel and cache Mesh objects; shard_bucket is the
 estRows->shard-count launder the planner annotates plans with;
@@ -43,11 +52,13 @@ through shard_map_fn/shard_map_unchecked (qlint DF805 enforces this).
 """
 from __future__ import annotations
 
+import threading
 from functools import partial
 from typing import List, Optional, Tuple
 
 import numpy as np
 
+from ..obs import context as _obs
 from ..ops import kernels
 
 
@@ -68,20 +79,35 @@ def shard_map_unchecked(fn, mesh, in_specs, out_specs):
                      check_vma=False)
 
 
+def mesh_sum(x, axis: str = "shard"):
+    """Inside shard_map: elementwise sum over the mesh axis — the merge
+    of per-shard partial states.  Named ``mesh.psum`` in a profile."""
+    jax = kernels.jax()
+    with jax.named_scope("mesh.psum"):
+        return jax.lax.psum(x, axis)
+
+
+def mesh_gather(x, axis: str = "shard", tiled: bool = False):
+    """Inside shard_map: every shard's ``x`` on every shard (stacked on
+    a new leading axis, or concatenated along the first when ``tiled``).
+    Named ``mesh.all_gather`` in a profile."""
+    jax = kernels.jax()
+    with jax.named_scope("mesh.all_gather"):
+        return jax.lax.all_gather(x, axis, tiled=tiled)
+
+
 def mesh_min(x, axis: str = "shard"):
     """Inside shard_map: elementwise min over the mesh axis.  The TPU
     lowers an all-reduce of an emulated 64-bit type for Sum only
     (``lax.pmin`` of an int64 is refused as UNIMPLEMENTED), so the
     partials — small per-shard tables — are gathered and reduced on
     every shard."""
-    jax = kernels.jax()
-    return jax.numpy.min(jax.lax.all_gather(x, axis), axis=0)
+    return kernels.jnp().min(mesh_gather(x, axis), axis=0)
 
 
 def mesh_max(x, axis: str = "shard"):
     """Elementwise max over the mesh axis; see :func:`mesh_min`."""
-    jax = kernels.jax()
-    return jax.numpy.max(jax.lax.all_gather(x, axis), axis=0)
+    return kernels.jnp().max(mesh_gather(x, axis), axis=0)
 
 
 def make_mesh(n_devices: Optional[int] = None):
@@ -161,6 +187,96 @@ def shardable(nb: int, mesh) -> bool:
         return False
     n = int(mesh.devices.size)
     return nb % n == 0 and nb >= 16 * n
+
+
+# =========================================================================
+# layouts: where an array lies on a mesh
+# =========================================================================
+# Two layouts cover everything the engine keeps on a mesh.  ROWS: a
+# replica lane (values, null mask, dictionary codes, group ids, a
+# per-shard index) split along its one axis into n contiguous ranges,
+# device i holding range i — upstream's "regions over stores" with a
+# region set = one device's row range.  WHOLE: a broadcast join's build
+# side, a dense key->row table, a group-key table, a parameter vector, a
+# merged partial state — the same array on every device.
+
+def specs() -> tuple:
+    """(ROWS, WHOLE): the two layouts as the PartitionSpecs a
+    shard_map's ``in_specs`` / ``out_specs`` take."""
+    _, P = shard_map_fn()
+    return P("shard"), P()
+
+
+def rows(mesh):
+    """Layout of a row-sharded lane over ``mesh``."""
+    from jax.sharding import NamedSharding
+    return NamedSharding(mesh, specs()[0])
+
+
+def whole(mesh):
+    """Layout of an array held whole on every device of ``mesh``."""
+    from jax.sharding import NamedSharding
+    return NamedSharding(mesh, specs()[1])
+
+
+def layout_tag(layout) -> tuple:
+    """A layout as a key component — for the replica's memo (a one-chip
+    session and a mesh session never hand each other the wrong array)
+    and for program keys.  No layout (one device) tags nothing, so
+    one-device keys are what they were."""
+    if layout is None:
+        return ()
+    return ("rows" if layout.spec == specs()[0] else "whole",
+            mesh_shards(layout.mesh))
+
+
+#: bytes of replica lanes placed on each device since the process began
+#: (a dropped replica version's lanes are not taken off again)
+_PLACED: dict = {}
+_PLACED_MU = threading.Lock()
+
+
+def place(host: np.ndarray, layout):
+    """Counted upload of a host array straight into its layout on the
+    mesh: each device receives only its own part (h2d bytes are charged
+    once, at the host buffer's size).  A ``mesh.place`` span; the
+    per-device tally feeds ``mesh_resident_bytes_max`` / ``_min``."""
+    with _obs.process_span("mesh.place", cat="replica",
+                           layout=layout_tag(layout)[0],
+                           bytes=int(host.nbytes)):
+        out = kernels.h2d(host, layout)
+    with _PLACED_MU:
+        for sh in out.addressable_shards:
+            _PLACED[sh.device.id] = _PLACED.get(sh.device.id, 0) \
+                + int(sh.data.nbytes)
+        per = [_PLACED.get(d.id, 0) for d in layout.mesh.devices.flat]
+    kernels.stats_set("mesh_resident_bytes_max", max(per))
+    kernels.stats_set("mesh_resident_bytes_min", min(per))
+    return out
+
+
+def settle(inputs: list, layouts: list) -> list:
+    """At a mesh dispatch: every input that is already on the device
+    must lie as the program asks (``layouts[i]``; None asks nothing).
+    One that lies otherwise is moved and its bytes counted under
+    ``reshard_bytes`` — 0 when warm, because the replica memoizes a lane
+    under its layout.  Host arrays pass: jit uploads them whole."""
+    jax = kernels.jax()
+    out = list(inputs)
+    for i, (arr, want) in enumerate(zip(inputs, layouts)):
+        if want is None or not isinstance(arr, jax.Array):
+            continue
+        if arr.sharding.is_equivalent_to(want, arr.ndim):
+            continue
+        out[i] = kernels.relayout(arr, want)
+    return out
+
+
+def note_dispatch(mesh) -> None:
+    """Count one dispatch of a program that runs over the whole mesh
+    (every device jax has), as against a one-device or sub-mesh one."""
+    if mesh_shards(mesh) == len(kernels.jax().devices()):
+        kernels.stats_add("mesh_dispatches", 1)
 
 
 # =========================================================================
@@ -289,8 +405,9 @@ def exchange_lanes(jn, lanes, dest_local, cap: int, n_shards: int,
     for arr, fill in lanes:
         buf = jn.full((n_shards, cap), fill, dtype=arr.dtype)
         buf = buf.at[ds, rank].set(arr[order], mode="drop")
-        r = lax.all_to_all(buf, axis, split_axis=0, concat_axis=0,
-                           tiled=True)
+        with kernels.jax().named_scope("mesh.all_to_all"):
+            r = lax.all_to_all(buf, axis, split_axis=0, concat_axis=0,
+                               tiled=True)
         outs.append(r.reshape(n_shards * cap))
     return outs
 
