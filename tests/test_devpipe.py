@@ -83,9 +83,9 @@ def counters(monkeypatch):
         orig = cls.prepare
 
         def mk(orig, k):
-            def prepare(self, pb):
+            def prepare(self, pb, *args):
                 runs[k] += 1
-                return orig(self, pb)
+                return orig(self, pb, *args)
             return prepare
         monkeypatch.setattr(cls, "prepare", mk(orig, k))
     return runs
@@ -470,8 +470,9 @@ def test_dense_row_gid_lane_sentinel_and_single_upload(tk, counters):
     h0 = kernels.STATS["h2d_bytes"]
     rows = tk.query(q).rows
     h1 = kernels.STATS["h2d_bytes"]
-    # row 0 is counted once, not once per padding slot (on the sorted
-    # path the padded order lane maps padding to row 0: its in_table mask)
+    # row 0 is counted once, not once per padding slot (padding carries
+    # the sentinel group here; on the sorted path the leaf's own padding
+    # guard masks it)
     assert rows == [[g, 7, float(x[k == g].sum())] for g in range(3)]
     info = tk.infoschema().table_by_name("d", "pad")
     rep = store_of(tk.storage).get(info.id)
@@ -483,8 +484,11 @@ def test_dense_row_gid_lane_sentinel_and_single_upload(tk, counters):
     assert nb == 32 and host.shape == (32,) and host.dtype == np.uint8
     assert (host[:n] == k).all()
     assert (host[n:] == 16).all()             # ngb: matches no group
+    # none of the sorted formulation's index lanes, and no lane permuted
+    # into an index's order (no formulation uploads gi_order any more)
     assert not [key for key in rep.cache
-                if key[0] in ("gi_order", "gi_sgid", "gi_ends")]
+                if key[0] in ("gi_order", "gi_sgid", "gi_ends")
+                or "by" in key]
     assert h1 - h0 >= nb                      # the lane went up once...
     assert tk.query(q).rows == rows
     h2 = kernels.STATS["h2d_bytes"]

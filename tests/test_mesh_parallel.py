@@ -414,11 +414,15 @@ def _rows_close(got, want, rel=1e-9):
     return True
 
 
-def _stats_of(s, sql):
+def _stats_of_call(call):
     from tinysql_tpu.ops import kernels
     before = kernels.stats_snapshot()
-    rows = s.query(sql).rows
-    return rows, kernels.stats_delta(before)
+    out = call()
+    return out, kernels.stats_delta(before)
+
+
+def _stats_of(s, sql):
+    return _stats_of_call(lambda: s.query(sql).rows)
 
 
 @pytest.mark.parametrize("n", [2, 4, 8])
@@ -461,7 +465,11 @@ def test_replica_lanes_are_laid_out_over_the_mesh(tpch_mesh):
     whole = {k: v for k, v in lanes.items() if k[-2:] == ("whole", n)}
     kinds = {k[0] for k in rows}
     assert {"devv", "devn", "devcodes", "gi_rowgid",
-            "gi_shard_order", "gi_shard_ends"} <= kinds, kinds
+            "gi_shard_ends"} <= kinds, kinds
+    # lineitem is stored in l_orderkey order: Q3's sorted aggregate reads
+    # the scan's own lanes, no index-order lane and no permuted copy
+    assert not kinds & {"gi_shard_order", "gi_shard_rows"}, kinds
+    assert not [k for k in lanes if "by" in k], lanes.keys()
     for key, arr in rows.items():
         shards = arr.addressable_shards
         assert len(shards) == n, key
@@ -475,6 +483,34 @@ def test_replica_lanes_are_laid_out_over_the_mesh(tpch_mesh):
         assert len(arr.addressable_shards) == n, key
         assert all(sh.data.shape == arr.shape
                    for sh in arr.addressable_shards), key
+
+
+@pytest.mark.parametrize("n", [1, 2, 8])
+def test_q3_program_gathers_no_lineitem_lane(tpch_mesh, monkeypatch, n):
+    """Q3's lowered program, on one device and over a mesh: no gather
+    whose result has the shape of a ``lineitem`` lane (a shard's part of
+    one), and its partial aggregate counted as clustered.  (n = 4 would
+    make a shard's part of a lane as long as the [groups] boundary
+    table, which IS gathered.)"""
+    from test_aggindex_order import gather_shapes
+    from tinysql_tpu.columnar.store import store_of
+    from tinysql_tpu.ops import kernels
+    s, _mirror, queries = tpch_mesh
+    _mesh_of(monkeypatch, n)
+    info = s.infoschema().table_by_name("tpch", "lineitem")
+    nb = kernels.bucket(store_of(s.storage).get(info.id).n_rows)
+    s.execute(f"set @@tidb_mesh_parallel = {int(n > 1)}")
+    try:
+        (shapes, sums), delta = _stats_of_call(
+            lambda: gather_shapes(s, queries["Q3"], monkeypatch))
+    finally:
+        s.execute("set @@tidb_mesh_parallel = 0")
+    assert delta["agg_sorted"] == 1 and delta["agg_clustered"] == 1
+    # presence, the count and the revenue: three prefix sums over a lane
+    # (each scans its block totals with a shorter one)
+    assert sums.count((nb // n,)) == 3, sums
+    assert shapes and (nb,) not in shapes and (nb // n,) not in shapes, \
+        shapes
 
 
 @pytest.mark.parametrize("name", MESH_QUERIES)
@@ -614,6 +650,9 @@ def test_group_index_cut_per_shard(n):
     for s_ in range(n):
         mine = order[s_, :rows[s_]] + s_ * per      # global rows, key order
         assert np.all(np.diff(keys[mine]) >= 0)
+        # a permutation of the shard that leaves its padding in place
+        assert np.array_equal(np.sort(order[s_]), np.arange(per))
+        assert np.array_equal(order[s_, rows[s_]:], np.arange(rows[s_], per))
         assert np.array_equal(gidx.gkeys[sgid[s_, :rows[s_]]], keys[mine])
         assert np.all(sgid[s_, rows[s_]:] == gidx.n_groups)
         c = np.concatenate([[0], np.cumsum(x[mine])])
@@ -626,3 +665,10 @@ def test_group_index_cut_per_shard(n):
     assert np.array_equal(
         total, np.bincount(np.searchsorted(gidx.gkeys, keys), weights=x,
                            minlength=gidx.n_groups).astype(np.int64))
+    # stored in key order the index is clustered and every shard's order
+    # is the identity: the lanes need no permuting
+    assert not gidx.clustered
+    by_key = GroupIndex([(np.sort(keys), np.zeros(n_rows, dtype=bool))])
+    assert by_key.clustered
+    assert np.array_equal(by_key.shards(n, per)[0],
+                          np.tile(np.arange(per), (n, 1)))

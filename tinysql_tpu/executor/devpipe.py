@@ -30,8 +30,10 @@ Key design points (why this maps well onto TPU + XLA):
 - **Group index** (sort once per replica version, not per query): the
   high-cardinality GROUP BY path sorts the table by key ONCE, memoizes
   the order/boundaries on the replica (the clustered-index analogue of
-  the reference's index access paths), and then a per-query aggregate is
-  mask -> gather-to-sorted-order -> cumsum -> boundary-diff: exact for
+  the reference's index access paths), and then a per-query aggregate
+  reads its lanes in that order (a table stored in its key's order as
+  it lies, any other permuted on the host once a replica version):
+  mask -> prefix sum -> boundary-diff, no gather to sorted order: exact for
   int64 (mod-2^64 wrap); for float64 the boundary diff folds the running
   prefix-sum's rounding into each group (error ~ eps x running total),
   bounded by the 1e-6-relative result-equality tests.  No per-query sort
@@ -54,6 +56,7 @@ Key design points (why this maps well onto TPU + XLA):
 from __future__ import annotations
 
 import contextvars
+import functools
 import os
 import queue
 import threading
@@ -314,8 +317,17 @@ class GroupIndex:
     keycols[j] = (values[ng], null[ng]) per key column (NULL keys form
     one group; a multi-column key groups by the TUPLE).  The single-int-
     key index additionally exposes gkeys/lo/hi + the dense pos_table the
-    join build sides ride."""
-    __slots__ = ("order", "ends", "keycols", "n_groups", "lo", "hi")
+    join build sides ride.
+
+    ``clustered``: the table is stored in its key's order (the stable
+    sort moved nothing: ``order`` is the identity), observed once when
+    the index is built.  The sorted aggregate reads its lanes IN INDEX
+    ORDER and no program ever gathers by ``order``: over a clustered
+    index those lanes are the replica's row-order lanes as they are;
+    otherwise ``order`` (per shard: ``shards``) permutes each lane on
+    the host, once a replica version, before it is uploaded."""
+    __slots__ = ("order", "ends", "keycols", "n_groups", "lo", "hi",
+                 "clustered")
 
     def __init__(self, key_cols: List[tuple]):
         # lexsort: last key is primary -> feed (vals, nulls) pairs in
@@ -332,6 +344,7 @@ class GroupIndex:
             ops.append(nl)
         order = np.lexsort(tuple(ops))
         n = len(order)
+        self.clustered = bool((order == np.arange(n)).all())
         svs = [(mv[order], nl[order]) for mv, nl in svs]
         if n == 0:
             self.order = order
@@ -411,7 +424,9 @@ class GroupIndex:
         """The index cut for a mesh of ``n`` shards holding ``per``
         contiguous rows each: (order [n, per], ends [n, n_groups],
         sgid [n, per], rows [n]).  order[s] lists shard s's rows in key
-        order as positions WITHIN the shard; ends[s][g] is the last
+        order as positions WITHIN the shard, then the shard's padding
+        positions in place (a permutation of the shard: the identity
+        when the index is clustered); ends[s][g] is the last
         position in that list of a row of group <= g (-1: none yet), so
         every shard sees every group and its partial state for group g
         is the boundary difference at (ends[s][g-1], ends[s][g]] — empty
@@ -426,7 +441,7 @@ class GroupIndex:
         # shard's rows in key order
         perm = np.argsort(shard, kind="stable")
         rows = np.bincount(shard, minlength=n).astype(np.int64)
-        order = np.zeros((n, per), dtype=np.int64)
+        order = np.tile(np.arange(per, dtype=np.int64), (n, 1))
         sgid = np.full((n, per), ng, dtype=np.int64)
         start = 0
         for s_ in range(n):
@@ -517,13 +532,18 @@ def _layouts(mesh):
 class _ReplicaLeaf:
     """Full-table scan from the columnar replica: device columns are
     version-memoized uploads; scan filters become the validity mask
-    (traced inline into the fused program)."""
+    (traced inline into the fused program).  Mask and lanes are
+    elementwise over positions, and the mask's one use of a position is
+    the padding guard ``position < n_rows``: a parent that wants its
+    rows in another order says so and the same program runs over
+    lanes permuted on the host (``prepare``'s ``order``)."""
 
     def __init__(self, reader_exec, plan, mesh=None):
         self.ex = reader_exec
         self.plan = plan
-        self._rep = None  # set at prepare(): take_raw_replica consumes
+        self._rep = None  # set at take(): take_raw_replica consumes
         self._chk = None
+        self._filters = None
         self.mesh = mesh
         #: under a mesh: lanes row-sharded (a scanned / probe side) —
         #: a broadcast join clears it on its build side's leaf, whose
@@ -560,16 +580,31 @@ class _ReplicaLeaf:
             return None
         return _ReplicaLeaf(ex, plan, mesh=ctx.mesh)
 
-    def prepare(self, pb: _PipeBuilder) -> Optional[_TView]:
+    def take(self) -> bool:
+        """Consume the reader's replica (once): replica(), chunk() and
+        nb() answer from here on."""
+        if self._chk is None:
+            self._chk, self._filters, self._rep = \
+                self.ex.take_raw_replica()
+        return self._chk is not None
+
+    def nb(self) -> int:
+        return kernels.bucket(max(self._chk.full_rows(), 1))
+
+    def prepare(self, pb: _PipeBuilder, order=None) -> Optional[_TView]:
+        """``order`` None: lanes in row order, under the memo keys every
+        statement over the table shares (devv / devn / devcodes).  Else
+        (tag, perm): perm() is a host permutation of the padded [nb]
+        lane that keeps padding on padding (under a mesh: within each
+        shard), and each lane goes up permuted, once a replica version,
+        under its key + tag (_AggIndexNode, over an index that is not
+        clustered)."""
         from .tpu_executors import (_build_device_mask, _rep_string_dict,
                                     _slot_id)
-        chk, filters, rep = self.ex.take_raw_replica()
-        if chk is None:
+        if not self.take():
             return None
-        self._rep = rep
-        self._chk = chk
-        n = chk.full_rows()
-        nb = kernels.bucket(max(n, 1))
+        chk, filters, rep = self._chk, self._filters, self._rep
+        nb = self.nb()
         jn = _jn()
         pt = ParamTable()
         dm = _build_device_mask(self.ex, rep, chk, filters, pt)
@@ -585,22 +620,25 @@ class _ReplicaLeaf:
         from ..parallel import dist
         lrows, lwhole = _layouts(self.mesh)
         lay = lrows if self.rows_mesh(nb) is not None else lwhole
+        tag, perm = order or ((), None)
+
+        def lane(kind, sid, host, fill=0):
+            def build():
+                out = kernels.pad1(host, nb, fill)
+                return out if perm is None else out[perm()]
+            return _dev_upload(rep, (kind, sid, nb) + tag, build, lay)
         for idx, c in enumerate(chk.columns):
             v = c.values()
-            m = c.null_mask()
             sid = _slot_id(self.ex, idx)
-            dn = _dev_upload(rep, ("devn", sid, nb),
-                             lambda m=m: kernels.pad1(m, nb, True), lay)
+            dn = lane("devn", sid, c.null_mask(), True)
             if v.dtype == object or v.dtype.kind == "U":
                 got = _rep_string_dict(rep, sid, chk, idx)
                 codes, _card, _, uniques = got
-                dv = _dev_upload(rep, ("devcodes", sid, nb),
-                                 lambda c_=codes: kernels.pad1(c_, nb), lay)
+                dv = lane("devcodes", sid, codes)
                 meta.append((c.ft, uniques))
                 dts.append("s")
             else:
-                dv = _dev_upload(rep, ("devv", sid, nb),
-                                 lambda v=v: kernels.pad1(v, nb), lay)
+                dv = lane("devv", sid, v)
                 meta.append((c.ft, None))
                 dts.append("f" if v.dtype == np.float64 else "i")
             slots.append((pb.add(dv, lay), pb.add(dn, lay)))
@@ -785,12 +823,13 @@ def _mm_fill(jn, dtype, kind: str):
     return jn.inf if kind == "min" else -jn.inf
 
 
-def _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid, gmask,
-                  gvals, seg_sum, seg_mm, presence, n_out):
+def _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid, seg_sum,
+                  seg_mm, presence, n_out, gather=lambda x: x):
     """Shared per-spec aggregation loop for the device group-by nodes
     (the subtle NULL-when-empty / avg-pairing semantics live ONCE here).
-    gmask/gvals take a lane into the order the reductions run in (a
-    gather to sorted order, or the identity in row order); seg_sum
+    gather takes a lane into the order the reductions run in (the
+    identity where the lanes arrive in it: row order for the dense and
+    the scalar aggregate, the index's order for the sorted one); seg_sum
     reduces a lane to [n_out]; seg_mm(av_s, live_s, kind) likewise for
     min/max.  The three stages carry their names into the profile:
     ``args``, ``gather``, ``group_sums``."""
@@ -803,9 +842,9 @@ def _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid, gmask,
         with scope("args"):
             av, an = af(pairs, pr)
         with scope("gather"):
-            live_s = gmask(valid & ~an)
+            live_s = gather(valid & ~an)
             if kind != "count":
-                av_s = gvals(av)
+                av_s = gather(av)
         with scope("group_sums"):
             cnt = seg_sum(live_s.astype(jn.int64))
             if kind == "count":
@@ -861,9 +900,23 @@ class _AggIndexNode:
       stay in row order and each group's sum is a masked reduction over
       the group id of each row (kernels._SegReduce) — a few streaming
       passes, no gather, no prefix sum;
-    - sorted (``aggindex``), more groups: mask -> gather to sorted order
-      -> prefix sum -> boundary diff, whose cost does not grow with the
-      group count."""
+    - sorted (``aggindex``), more groups: the leaf is prepared IN THE
+      INDEX'S ORDER (``_ReplicaLeaf.prepare``'s ``order``), so mask, lanes and
+      argument expressions are evaluated at sorted positions and the
+      program holds no gather to sorted order: mask -> prefix sum ->
+      boundary diff (one [ngb] gather a sum: a group's lower boundary
+      is the group before's upper one), whose cost does not grow with
+      the group count.  Which host arrays go up is decided by what the
+      index observed of its input, never what is traced: over a
+      clustered index (``GroupIndex.clustered``; counter
+      ``agg_clustered``) the leaf's lanes are the replica's row-order
+      lanes under the memo keys every scan shares (``devv`` / ``devn`` /
+      ``devcodes``); otherwise each lane the leaf reads goes up permuted
+      on the host, once a replica version, under its key + ``("by",
+      sids)``.  Under a mesh the shards are contiguous row ranges, each
+      in its own key order (``GroupIndex.shards``).  Index lanes:
+      ``gi_ends`` (``gi_shard_ends`` a shard), ``gi_sgid``
+      (``gi_shard_sgid``) when a min/max reads it."""
 
     def __init__(self, leaf: _ReplicaLeaf, plan, key_cols, specs, slots,
                  out_map):
@@ -926,8 +979,7 @@ class _AggIndexNode:
         return key_cols, tuple(sids), decodes
 
     def prepare(self, pb: _PipeBuilder) -> Optional[_TView]:
-        tv = self.leaf.prepare(pb)
-        if tv is None:
+        if not self.leaf.take():
             return None
         rep = self.leaf.replica()
         got = self._host_key_cols(rep)
@@ -939,7 +991,7 @@ class _AggIndexNode:
         self._sids = sids
         ng = gidx.n_groups
         ngb = kernels.bucket(max(ng, 1))
-        nb = tv.nb
+        nb = self.leaf.nb()
         jn = _jn()
         dense = ng <= kernels.SEG_UNROLL
         kernels.stats_add("agg_dense" if dense else "agg_sorted", 1)
@@ -958,6 +1010,33 @@ class _AggIndexNode:
         # one-device index lanes lie as the leaf's own lanes do
         lidx = lwhole if mesh is None else lrows
         ROWS, WHOLE = dist.specs()
+        per = nb // max(n_mesh, 1)
+
+        # shared by this prepare's lane builders; the host copies go
+        # once the lanes are on the device
+        @functools.lru_cache(maxsize=None)
+        def cut():
+            return gidx.shards(n_mesh, per)
+
+        @functools.lru_cache(maxsize=None)
+        def index_order():
+            # positions of the leaf's padded lanes in the index's order
+            # (within each shard under a mesh), padding left in place
+            if mesh is None:
+                return np.concatenate([gidx.order,
+                                       np.arange(len(gidx.order), nb)])
+            return (cut()[0] + np.arange(n_mesh)[:, None] * per).reshape(-1)
+        # the sorted formulation reads the leaf in the index's order:
+        # over a clustered index that is the row order and the lanes
+        # are the ones every scan of the table shares
+        order = None
+        if not dense and gidx.clustered:
+            kernels.stats_add("agg_clustered", 1)
+        elif not dense:
+            order = (("by", sids), index_order)
+        tv = self.leaf.prepare(pb, order)
+        if tv is None:
+            return None
         #: the index lanes the reduction reads, in the kernel's order
         if dense:
             # group id per ROW in the narrowest lane, sentinel ngb on
@@ -969,8 +1048,6 @@ class _AggIndexNode:
                 lidx)]
         elif mesh is None:
             lanes = [
-                pb.lane(rep, ("gi_order", sids, nb),
-                        lambda: kernels.pad1(gidx.order, nb), lidx),
                 pb.lane(rep, ("gi_ends", sids, ngb),
                         lambda: kernels.pad1(gidx.ends, ngb,
                                              fill=max(rep.n_rows - 1, 0)),
@@ -983,16 +1060,6 @@ class _AggIndexNode:
                     lambda: kernels.pad1(gidx.sorted_gid(), nb, fill=ngb),
                     lidx))
         else:
-            per = nb // n_mesh
-            cuts = []
-
-            def cut():
-                # shared by this prepare's lane builders; the host copy
-                # goes once the lanes are on the mesh
-                if not cuts:
-                    cuts.append(gidx.shards(n_mesh, per))
-                return cuts[0]
-
             def ends_lane():
                 # groups past ng repeat the last boundary: empty ranges
                 ends = cut()[1]
@@ -1001,11 +1068,7 @@ class _AggIndexNode:
                 out[:, ng:] = ends[:, -1:] if ng else -1
                 return out.reshape(-1)
             lanes = [
-                pb.lane(rep, ("gi_shard_order", sids, nb),
-                        lambda: cut()[0].reshape(-1), lrows),
-                pb.lane(rep, ("gi_shard_ends", sids, ngb), ends_lane, lrows),
-                pb.lane(rep, ("gi_shard_rows", sids, nb),
-                        lambda: cut()[3], lrows)]
+                pb.lane(rep, ("gi_shard_ends", sids, ngb), ends_lane, lrows)]
             if need_mm:
                 lanes.append(pb.lane(
                     rep, ("gi_shard_sgid", sids, nb),
@@ -1020,6 +1083,9 @@ class _AggIndexNode:
                         lambda gn=gn: kernels.pad1(gn, ngb, True), lwhole)))
         pt = ParamTable()
         pt.add_int(ng)
+        # read by no formulation since the leaf's own padding guard
+        # serves the sorted one too; the slot stays so that the dense
+        # program and its parameter vector are what they were
         pt.add_int(rep.n_rows)
         arg_fns = []
         keys = []
@@ -1053,48 +1119,34 @@ class _AggIndexNode:
             merge_sum = dist.mesh_sum
             merge_mm = {"min": dist.mesh_min, "max": dist.mesh_max}
 
-        def dense_reducers(idx, valid, pr):
+        def dense_reducers(idx, valid):
             seg = kernels._SegReduce(kernels.jax(), jn, idx[0], valid,
                                      ngb, unroll=True)
             return dict(
-                gmask=lambda b: b, gvals=lambda v: v,
                 seg_sum=lambda x: merge_sum(seg.sum(x, valid)),
                 seg_mm=lambda av, live, kind: merge_mm[kind](
                     seg.minmax(av, live, kind == "min")),
                 presence=merge_sum(seg.sum(valid.astype(jn.int64), valid)))
 
-        def sorted_reducers(idx, valid, pr):
+        def sorted_reducers(idx, valid):
             j = kernels.jax()
-            order, ends = idx[0], idx[1]
-            # padded sorted positions map to row 0 via the padded order
-            # array — they MUST be masked or row 0 is counted once per
-            # padding slot.  A shard's own rows end at its row count.
-            if mesh is None:
-                in_table = jn.arange(nb) < pr[0][1]
-                isg = idx[2] if need_mm else None
-            else:
-                in_table = jn.arange(nb // n_mesh) < idx[2][0]
-                isg = idx[3] if need_mm else None
-            valid_s = valid[order] & in_table
-            if mesh is None:
-                prev = jn.concatenate([jn.full((1,), -1, dtype=jn.int64),
-                                       ends[:-1]])
-                prev_safe = jn.maximum(prev, 0)
+            # the leaf's lanes, mask and arguments are already in the
+            # index's order (each shard's own under a mesh) and the
+            # mask's padding guard holds there as in row order: nothing
+            # is gathered to sorted order here
+            ends = idx[0]
+            isg = idx[1] if need_mm else None
 
             def seg(x_s):
+                # a group's lower boundary is the group before's upper
+                # one, so ONE [ngb] gather serves both (a 2 M-lane
+                # gather is 50 ms on a v5e); a shard may hold no row up
+                # to a group (boundary -1, never on one device); padded
+                # groups repeat the last boundary and read zero
                 c = kernels.prefix_sum(x_s)
-                if mesh is None:
-                    hi = c[ends]
-                    lo = jn.where(prev >= 0, c[prev_safe],
-                                  jn.zeros((), dtype=x_s.dtype))
-                else:
-                    # a shard may hold no row up to a group (boundary
-                    # -1); a group's lower boundary is the group
-                    # before's upper one, so ONE [ngb] gather serves
-                    # both (a 2 M-lane gather is 50 ms on a v5e)
-                    zero = jn.zeros((), dtype=x_s.dtype)
-                    hi = jn.where(ends >= 0, c[jn.maximum(ends, 0)], zero)
-                    lo = jn.concatenate([zero[None], hi[:-1]])
+                zero = jn.zeros((), dtype=x_s.dtype)
+                hi = jn.where(ends >= 0, c[jn.maximum(ends, 0)], zero)
+                lo = jn.concatenate([zero[None], hi[:-1]])
                 return merge_sum(hi - lo)
 
             def seg_mm(av_s, live_s, kind):
@@ -1103,16 +1155,13 @@ class _AggIndexNode:
                     else j.ops.segment_max
                 return merge_mm[kind](
                     op(av_s, gl, num_segments=ngb + 1)[:ngb])
-            return dict(
-                gmask=lambda b: b[order] & in_table,
-                gvals=lambda v: v[order],
-                seg_sum=seg, seg_mm=seg_mm,
-                presence=seg(valid_s.astype(jn.int64)))
+            return dict(seg_sum=seg, seg_mm=seg_mm,
+                        presence=seg(valid.astype(jn.int64)))
         reducers = dense_reducers if dense else sorted_reducers
 
         def reduce(idx, valid, pairs, pr):
             """(rows per group, [(value, null)] per spec), each [ngb]."""
-            red = reducers(idx, valid, pr)
+            red = reducers(idx, valid)
             res = _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid,
                                 n_out=ngb, **red)
             return red["presence"], res
@@ -2092,7 +2141,7 @@ class _SortGroupNode:
             presence = seg(valid_s.astype(jn.int64))
             res = _spec_results(
                 jn, spec_kinds, arg_fns, pairs, pr, valid,
-                gmask=lambda b: b[perm], gvals=lambda v: v[perm],
+                gather=lambda x: x[perm],
                 seg_sum=seg, seg_mm=seg_mm, presence=presence, n_out=nb)
             outs = _slot_outputs(jn, res, slots)
             gvalid = jn.arange(nb) < ng
@@ -2180,7 +2229,6 @@ class _ScalarAggNode:
             # _spec_results)
             res = _spec_results(
                 jn, spec_kinds, arg_fns, pairs, pr, valid,
-                gmask=lambda b: b, gvals=lambda v: v,
                 seg_sum=lambda x_s: at0(jn.sum(x_s)),
                 seg_mm=lambda av_s, live_s, kind: at0(
                     (jn.min if kind == "min" else jn.max)(av_s)),

@@ -50,8 +50,12 @@ _DEVICE_METRICS = {
                   "Fused GROUP BYs over a replica reduced in row order "
                   "with masked reductions (at most SEG_UNROLL groups)"),
     "agg_sorted": ("tinysql_agg_sorted_total",
-                   "Fused GROUP BYs over a replica reduced in sorted "
-                   "order (gather + prefix sum)"),
+                   "Fused GROUP BYs over a replica reduced in the group "
+                   "index's order (prefix sum + boundary difference)"),
+    "agg_clustered": ("tinysql_agg_clustered_total",
+                      "Sorted fused GROUP BYs that found the table "
+                      "stored in the key's order and shared the scan's "
+                      "row-order lanes (no permuted copy)"),
     "mesh_dispatches": ("tinysql_mesh_dispatches_total",
                         "Dispatches whose program ran over the whole "
                         "device mesh (tidb_mesh_parallel)"),

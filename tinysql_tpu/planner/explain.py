@@ -214,8 +214,12 @@ def _device_info(st) -> str:
     if hits or misses:
         parts.append(f"cache:{int(hits)}h/{int(misses)}m")
     if d.get("agg_dense") or d.get("agg_sorted"):
+        # clustered: sorted ones whose index found the table stored in
+        # its key's order (they share the scan's lanes)
+        clustered = f"/{int(d['agg_clustered'])}clustered" \
+            if d.get("agg_clustered") else ""
         parts.append(f"agg:{int(d.get('agg_dense', 0))}dense"
-                     f"/{int(d.get('agg_sorted', 0))}sorted")
+                     f"/{int(d.get('agg_sorted', 0))}sorted{clustered}")
     if d.get("pipe_blocks"):
         from ..ops.kernels import pipe_overlap_frac
         overlap = pipe_overlap_frac(d)
