@@ -219,12 +219,39 @@ def _run_counted(client, sql: str):
          "mesh_dispatches", "reshard_bytes")}
 
 
+def _rows_match(a, b, rel=1e-6) -> bool:
+    if len(a) != len(b):
+        return False
+    def canon(rows):
+        out = []
+        for r in rows:
+            key = []
+            for v in r:
+                if isinstance(v, float):
+                    key.append(f"{(0.0 if v == 0 else v):.9g}")
+                else:
+                    key.append(str(v))
+            out.append(tuple(key))
+        return sorted(out)
+    ca, cb = canon(a), canon(b)
+    for ra, rb in zip(ca, cb):
+        for va, vb in zip(ra, rb):
+            if va == vb:
+                continue
+            try:
+                fa, fb = float(va), float(vb)
+            except ValueError:
+                return False
+            if abs(fa - fb) > rel * max(1.0, abs(fa), abs(fb)):
+                return False
+    return True
+
+
 def _query_runs(client, mirror, name: str, sql: str, runs) -> tuple:
     """EXPLAIN shows device placement; then one run per label in
     ``runs``: rows equal sqlite's, compiled programs dispatched and no
     numpy twin.  Returns (fields of the line, checks, the last run's
     rows)."""
-    from bench import _rows_match
     _cols, plan = client.query("explain " + sql)
     placed = [r[0].strip() for r in plan if r[2] == "tpu"]
     want = [list(r) for r in mirror.execute(sql).fetchall()]
@@ -311,7 +338,6 @@ def phase_mesh(env: _Loaded, watch: _LogWatch) -> None:
     holds afterwards: the replica's lanes are spread over the mesh, so
     the fullest device holds under half of it all."""
     import jax
-    from bench import _rows_match
     from tinysql_tpu.bench import tpch
     c = env.client
     for name in QUERY_NAMES:

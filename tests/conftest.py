@@ -58,10 +58,61 @@ if _XFER_AUDIT:
     _xferaudit.install()
 
 
+import contextlib
 import threading as _threading
 import time as _time
 
 import pytest as _pytest
+
+import _timelimit
+
+_REAL_STDERR = 2
+_RUNNING = None  # under xdist: this worker's _timelimit.Running
+
+
+def pytest_configure(config):
+    """Capture is suspended here, so fd 2 is still the process's own
+    stderr: keep a copy for the hard limit, whose process ends before any
+    captured output could be reported."""
+    global _REAL_STDERR, _RUNNING
+    _REAL_STDERR = os.dup(2)
+    worker = getattr(config, "workerinput", None)
+    if worker is not None:
+        _RUNNING = _timelimit.Running(worker["testrunuid"],
+                                      worker["workerid"])
+
+
+@_pytest.hookimpl(hookwrapper=True, tryfirst=True)
+def pytest_runtest_protocol(item, nextitem):
+    """Every item runs under `_timelimit.LIMIT_S`, its fixtures' set-up
+    and tear-down included (a module fixture's are inside the limit of the
+    item that runs them)."""
+    named = _RUNNING.item(item.nodeid) if _RUNNING is not None \
+        else contextlib.nullcontext()
+    with named, _timelimit.limited(_timelimit.LIMIT_S, item.nodeid,
+                                   log=_REAL_STDERR) as item.limit_fired:
+        yield
+
+
+@_pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_makereport(item, call):
+    """An item whose limit fired fails even where what the alarm raised
+    was swallowed (it interrupted a finalizer's wait, say) and the phase
+    went on to pass: 120 s lost in silence is the fault to report."""
+    rep = (yield).get_result()
+    if rep.passed and getattr(item, "limit_fired", None):
+        rep.outcome = "failed"
+        rep.longrepr = (f"{item.nodeid}: the time limit fired during "
+                        f"{rep.when} and what it raised was swallowed; "
+                        "every thread's stack is on stderr")
+        item.limit_fired.clear()  # one failed report an item
+
+
+@_pytest.hookimpl(tryfirst=True)
+def pytest_runtest_setup(item):
+    if _RUNNING is not None and _RUNNING.ended_a_worker(item.nodeid):
+        _pytest.fail("this item ended an earlier worker (its stacks are on "
+                     "that worker's stderr); not run again", pytrace=False)
 
 
 def pytest_sessionfinish(session, exitstatus):

@@ -15,6 +15,7 @@ import time
 
 import pytest
 
+from _timelimit import hit, in_queue, until
 from test_server import MiniClient
 from tinysql_tpu import fail
 from tinysql_tpu.kv import new_mock_storage
@@ -26,6 +27,7 @@ from tinysql_tpu.session.session import Session
 @pytest.fixture(autouse=True)
 def _clean_failpoints():
     fail.disarm_all()
+    fail.reset_hits()
     yield
     fail.disarm_all()
 
@@ -36,6 +38,10 @@ def server():
     srv = Server(storage, port=0)
     srv.start()
     boot = Session(storage)
+    # the default-on heap profiler (tracemalloc, `tidb_memprof_rate = 1`)
+    # makes this module's Python-heavy statements 10-30x slower and is
+    # nothing it tests (tests/test_memprof.py holds the profiler)
+    boot.execute("set global tidb_memprof_rate = 0")
     boot.execute("set global tidb_wire_mode = 'aio'")
     boot.execute("create database if not exists av")
     boot.execute("use av")
@@ -211,11 +217,11 @@ def test_admission_reject_1041_over_loop(server):
         t1 = threading.Thread(
             target=lambda: box.append(c1.query("select count(*) from t")))
         t1.start()
-        time.sleep(0.2)  # worker wedged with c1's entry claimed
+        hit("admissionDelay")  # worker wedged with c1's entry claimed
         t2 = threading.Thread(
             target=lambda: box.append(c2.query("select count(*) from t")))
         t2.start()
-        time.sleep(0.2)  # c2 occupies the queue (depth 1)
+        in_queue(server.pool)  # c2 occupies the queue (depth 1)
         with pytest.raises(RuntimeError) as ei:
             c3.query("select count(*) from t")
         assert "1041" in str(ei.value) and "retry" in str(ei.value)
@@ -360,7 +366,7 @@ def test_kill_query_running_over_loop(server):
     try:
         t = threading.Thread(target=slow)
         t.start()
-        time.sleep(0.15)
+        hit("execSlowNext")
         c2.query(f"kill query {victim_id}")
         t.join(10)
         assert not t.is_alive()
@@ -389,7 +395,7 @@ def test_kill_queued_statement_over_loop(server):
         t1 = threading.Thread(
             target=lambda: c1.query("select count(*) from t"))
         t1.start()
-        time.sleep(0.2)
+        hit("admissionDelay")
         box = []
 
         def queued_victim():
@@ -399,7 +405,7 @@ def test_kill_queued_statement_over_loop(server):
                 box.append(e)
         t2 = threading.Thread(target=queued_victim)
         t2.start()
-        time.sleep(0.2)
+        in_queue(server.pool)
         killer = MiniClient(server.port)
         killer.query(f"kill query {victim_id}")
         t2.join(10)
@@ -420,19 +426,27 @@ def test_write_backpressure_pauses_and_resumes(server):
     commands, then resumes as the peer drains — every response still
     arrives complete and in order."""
     import struct as _struct
+    from tinysql_tpu.server.aio import WBUF_HWM
     c = MiniClient(server.port, db="av")
+    conn, = [lp.conns[max(server.conns)] for lp in server._aio._loops
+             if max(server.conns) in lp.conns]
+    # loopback's auto-tuned send buffer takes all ~2.4MB by itself and
+    # the mark is never reached: pin this connection's to 16KB
+    conn.sock.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 1 << 14)
     n = 40  # ~60KB per resultset >> WBUF_HWM in aggregate
     sql = b"\x03" + b"select a, b, c from t"
     frame = _struct.pack("<I", len(sql))[:3] + b"\x00" + sql
     c.sock.sendall(frame * n)
-    time.sleep(0.5)  # let the server hit the high-water mark
-    from tinysql_tpu.server.aio import WBUF_HWM
-    fe = server._aio
-    wbufs = [len(conn.wbuf) for lp in fe._loops
-             for conn in list(lp.conns.values())]
-    # the buffer stopped growing near the mark instead of absorbing
-    # all ~2.4MB of pipelined responses (socket buffers add slack)
-    assert max(wbufs) <= WBUF_HWM + (1 << 16), wbufs
+    until(lambda: len(conn.wbuf) > WBUF_HWM,
+          "outbound buffer at the high-water mark")
+    # past the mark the loop neither reads nor executes: the buffer
+    # stops within one resultset of it with commands still unparsed,
+    # instead of absorbing all ~2.4MB of pipelined responses
+    parked = len(conn.rbuf)
+    assert parked >= len(frame), parked
+    time.sleep(0.2)  # a window in which nothing may move
+    assert WBUF_HWM < len(conn.wbuf) <= WBUF_HWM + (1 << 16)
+    assert len(conn.rbuf) == parked
     # now drain: all n responses arrive complete, in order
     from tinysql_tpu.server.packetio import read_lenenc_int
     for i in range(n):
@@ -467,8 +481,9 @@ def test_peer_drop_mid_statement_defers_teardown(server):
         c.query("set @@tidb_max_chunk_size = 8")
         # fire a slow scan, then slam the socket shut mid-execution
         c.io.reset_sequence()
+        fail.reset_hits()
         c.io.write_packet(b"\x03" + b"select * from t")
-        time.sleep(0.15)
+        hit("execSlowNext")
         c.sock.close()
         # the conn deregisters once the worker finishes with the session
         deadline = time.monotonic() + 10
@@ -584,8 +599,8 @@ def test_queue_wait_attribution_crosses_loop_pool_hop(server):
         t1 = threading.Thread(
             target=lambda: c1.query("select count(*) from t"))
         t1.start()
-        time.sleep(0.15)  # c1's worker is inside the wedge
-        c2.query(sql)     # queues behind it, then executes
+        hit("admissionDelay")  # c1's worker is inside the wedge
+        c2.query(sql)           # queues behind it, then executes
         t1.join(30)
         rows = [r for r in stmtsummary.snapshot()
                 if r.get("digest") == digest]

@@ -22,6 +22,8 @@ import time
 
 import pytest
 
+from _timelimit import hit, in_queue, join
+
 from tinysql_tpu import fail
 from tinysql_tpu.codec import tablecodec
 from tinysql_tpu.columnar.store import store_of
@@ -39,6 +41,7 @@ def _chaos_env(monkeypatch):
     monkeypatch.setattr("tinysql_tpu.kv.backoff.SLEEP_SCALE", 0)
     monkeypatch.setattr("tinysql_tpu.kv.txn.DEFAULT_LOCK_TTL_MS", 1)
     fail.disarm_all()
+    fail.reset_hits()
     degrade.reset()
     yield
     fail.disarm_all()
@@ -95,7 +98,7 @@ def test_concurrent_readers_survive_splits(tk):
         s.storage.cluster.split(tablecodec.encode_row_key(info.id, h))
         time.sleep(0.02)
     for t in threads:
-        t.join()
+        join(t)
     assert not errs, errs[:1]
 
 
@@ -116,7 +119,7 @@ def test_parallel_writers_commit_cleanly(tk):
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        join(t)
     assert not errs, errs[:1]
     assert s.query("select count(*) from t").rows == [[580]]
     assert s.query("admin check table t").rows == [["OK"]]
@@ -659,7 +662,7 @@ def _admission_delay(tk):
             t1 = _th.Thread(
                 target=lambda: box.append(c1.query("select count(*) from t")))
             t1.start()
-            time.sleep(0.2)  # the single worker is wedged with c1's entry
+            hit("admissionDelay")  # the one worker wedged with c1's entry
 
             def _queued():
                 try:
@@ -668,7 +671,7 @@ def _admission_delay(tk):
                     box.append(e)
             t2 = _th.Thread(target=_queued)
             t2.start()
-            time.sleep(0.2)
+            in_queue(srv.pool)
             killer = MiniClient(srv.port)  # accept loop alive while wedged
             killer.query(f"kill query {victim_id}")
             t2.join(10)
@@ -791,7 +794,7 @@ def test_kill_query_aborts_running_statement(tk):
         t = threading.Thread(target=_slow_query, args=(s,), kwargs={
             "exc_box": box})
         t.start()
-        time.sleep(0.1)
+        hit("execSlowNext")
         from tinysql_tpu.utils import interrupt
         assert interrupt.kill(s.conn_id, query_only=True)
         t.join(10)
@@ -814,7 +817,7 @@ def test_kill_lands_mid_shard_exchange(tk):
         t = threading.Thread(target=_slow_query, args=(s, _MESH_JOIN),
                              kwargs={"exc_box": box})
         t.start()
-        time.sleep(0.15)  # the exchange is holding the statement
+        hit("shardExchangeStall")  # the exchange is holding the statement
         from tinysql_tpu.utils import interrupt
         assert interrupt.kill(s.conn_id, query_only=True)
         t.join(15)
@@ -822,6 +825,43 @@ def test_kill_lands_mid_shard_exchange(tk):
     assert isinstance(box[0], QueryKilled), box[0]
     assert box[0].mysql_code == 1317
     assert s.query(_MESH_JOIN).rows == base  # healthy, still sharded
+
+
+def test_session_registry_survives_a_collection_inside_its_lock(monkeypatch):
+    """A session that dies in a reference cycle leaves the KILL registry
+    through a weakref callback, and the collector runs that callback on
+    whatever thread it interrupts — also one inside the registry's
+    critical section.  The callback took ``_reg_mu``: the thread
+    deadlocked against itself, and behind it every later connect, KILL,
+    processlist read and conprof tick (what hung tier-1, PERF.md §8)."""
+    import gc
+    from _timelimit import limited
+    from tinysql_tpu.utils import interrupt
+
+    class Cyclic:  # dies only when the collector runs
+        def __init__(self):
+            self.me = self
+
+    class CollectsWhenRead(dict):  # the collector, inside the lock
+        def items(self):
+            gc.collect()
+            return super().items()
+
+    gc.collect()
+    monkeypatch.setattr(interrupt, "_SESSIONS",
+                        CollectsWhenRead(interrupt._SESSIONS))
+    gc.disable()
+    try:
+        cid = interrupt.register_session(Cyclic())
+        with limited(2.0, "interrupt.sessions()") as fired:
+            live = interrupt.sessions()
+        assert not fired, "the registry waited for its own lock"
+    finally:
+        gc.enable()
+    assert cid not in {c for c, _ in live}
+    keep = Cyclic()
+    interrupt.register_session(keep)  # sweeps the dead
+    assert cid not in interrupt._SESSIONS
 
 
 def test_kill_statement_from_second_session(tk):
@@ -833,7 +873,7 @@ def test_kill_statement_from_second_session(tk):
         t = threading.Thread(target=_slow_query, args=(s,), kwargs={
             "exc_box": box})
         t.start()
-        time.sleep(0.1)
+        hit("execSlowNext")
         s2.execute(f"kill query {s.conn_id}")
         t.join(10)
     assert isinstance(box[0], QueryKilled), box[0]
@@ -905,7 +945,7 @@ def test_kill_reaches_distsql_worker_pool(tk):
                              args=(s, "select b, count(*) from t group by b"),
                              kwargs={"exc_box": box})
         t.start()
-        time.sleep(0.06)
+        hit("copTaskError")
         from tinysql_tpu.utils import interrupt
         interrupt.kill(s.conn_id, query_only=True)
         t.join(10)

@@ -255,6 +255,8 @@ def test_fused_mesh_program(topo, chip_branches, monkeypatch, tpch_session,
     mesh = Mesh(np.array(topo.devices), ("shard",))
     monkeypatch.setattr(dist, "make_mesh", lambda n=None: mesh)
     monkeypatch.setattr(dist, "_SESSION_MESH", None)
+    # a mesh file that ran before on this worker left its CPU submeshes
+    monkeypatch.setattr(dist, "_SIZED_MESHES", {})
     monkeypatch.setattr(dist, "MIN_SHARD_ROWS", 16)  # Q6's small estimate
     monkeypatch.setattr(
         dist, "place", lambda host, layout: jax.ShapeDtypeStruct(

@@ -11,6 +11,8 @@ import urllib.request
 
 import pytest
 
+from _timelimit import hit, in_wait, join
+
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from tinysql_tpu import fail
@@ -74,7 +76,7 @@ def test_fold_stack_shape_and_idle():
 
     t = threading.Thread(target=parked, daemon=True)
     t.start()
-    time.sleep(0.05)
+    in_wait(t)
     # sample the PARKED thread's live frame: leaf is Event.wait ->
     # idle, but the stack still folds (visible in /debug/conprof)
     live = sys._current_frames().get(t.ident)
@@ -84,7 +86,7 @@ def test_fold_stack_shape_and_idle():
         assert "parked" in folded
     finally:
         ev.set()
-        t.join()
+        join(t)
 
 
 # ---- window rotation / retention / eviction ------------------------------
@@ -307,7 +309,7 @@ def test_attribution_only_on_statement_thread(session):
 
     t = threading.Thread(target=bystander, daemon=True)
     t.start()
-    time.sleep(0.02)
+    in_wait(t)
     try:
         frames = sys._current_frames()
         assert t.ident in frames
@@ -315,7 +317,7 @@ def test_attribution_only_on_statement_thread(session):
         assert prof.stats_snapshot()["attributed"] == 0
     finally:
         ev.set()
-        t.join()
+        join(t)
 
 
 # ---- overhead backoff -----------------------------------------------------
@@ -359,12 +361,13 @@ def test_measure_overhead_never_attributes(session):
         seen["qobs"] = session.last_query_stats
         done.set()
 
+    fail.reset_hits()
     t = threading.Thread(target=run_stmt, daemon=True)
     t.start()
-    time.sleep(0.05)  # statement provably mid-flight
+    hit("execSlowNext")  # statement provably mid-flight
     conprof.measure_overhead(n=10, rate_hz=10)
     assert done.wait(10)
-    t.join()
+    join(t)
     dev = seen["qobs"].device_totals()
     assert dev.get("cpu_samples", 0) == 0, dev
     assert dev.get("cpu_s", 0.0) == 0.0, dev

@@ -12,11 +12,14 @@ from tinysql_tpu.kv import (
 from tinysql_tpu.kv.txn import TwoPhaseCommitter
 from tinysql_tpu.utils import failpoint
 
-backoff_mod.SLEEP_SCALE = 0  # run full retry ladders without wall-clock sleeps
-
 
 @pytest.fixture(autouse=True)
-def _clean_failpoints():
+def _clean_failpoints(monkeypatch):
+    # run full retry ladders without wall-clock sleeps — in THIS module's
+    # tests only: set at import it held for every test of every worker
+    # (each collects this file), and a reader that met a committer's live
+    # lock spent its 20 s budget in microseconds and failed
+    monkeypatch.setattr(backoff_mod, "SLEEP_SCALE", 0)
     yield
     failpoint.disable_all()
 

@@ -16,6 +16,8 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
+from _timelimit import hit, in_wait, join
+
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from tinysql_tpu import fail
@@ -90,7 +92,7 @@ def test_live_frame_roles_from_thread_names():
 
     t = threading.Thread(target=parked, name="conn-test", daemon=True)
     t.start()
-    time.sleep(0.05)
+    in_wait(t)
     try:
         key = (os.path.basename(got["frame"].f_code.co_filename),
                got["frame"].f_lineno)
@@ -102,7 +104,7 @@ def test_live_frame_roles_from_thread_names():
             skip_idents=(t.ident,))
     finally:
         ev.set()
-        t.join()
+        join(t)
 
 
 # ---- window rotation / retention / eviction ------------------------------
@@ -272,7 +274,7 @@ def test_attribution_splits_delta_and_reaches_statements_summary(
     prof.sample_once(0.1, now=1001.0, stats=[], frames={},
                      traced_kb=164.0, hbm_bytes=2048.0)
     assert done.wait(30)
-    t.join()
+    join(t)
     assert prof.stats_snapshot()["attributed"] >= 1
     dev = seen["qobs"].device_totals()
     # THE invariant: the statement's claimed heap can never exceed the
@@ -318,7 +320,7 @@ def test_negative_delta_and_idle_process_attribute_nothing(session):
     prof.sample_once(0.1, now=1002.0, stats=[], frames={},
                      traced_kb=150.0, hbm_bytes=0.0)
     assert done.wait(30)
-    t.join()
+    join(t)
     assert prof.stats_snapshot()["attributed"] == 0
     assert seen["qobs"].device_totals().get("heap_kb", 0.0) == 0.0
 
@@ -442,12 +444,13 @@ def test_measure_overhead_never_attributes(session):
         seen["qobs"] = session.last_query_stats
         done.set()
 
+    fail.reset_hits()
     t = threading.Thread(target=run_stmt, daemon=True)
     t.start()
-    time.sleep(0.05)  # statement provably mid-flight
+    hit("execSlowNext")  # statement provably mid-flight
     memprof.measure_overhead(n=5, rate_hz=10)
     assert done.wait(30)
-    t.join()
+    join(t)
     dev = seen["qobs"].device_totals()
     assert dev.get("heap_kb", 0.0) == 0.0, dev
     assert dev.get("heap_peak_kb", 0.0) == 0.0, dev

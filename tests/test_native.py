@@ -6,6 +6,11 @@ Skipped wholesale when no C++ toolchain is available (the python paths
 remain the semantic reference).
 """
 import ctypes
+import os
+import shutil
+import subprocess
+import sys
+import time
 
 import numpy as np
 import pytest
@@ -120,3 +125,37 @@ def test_join_uses_native_path(monkeypatch):
     rows = s.query("select a.x, b.v from a join b on a.k = b.k "
                    "and b.y <= 2 where a.x <= 2 order by a.x, b.v").rows
     assert rows == [["1", "v1"], ["2", "v2"]] or rows == [[1, "v1"], [2, "v2"]]
+
+
+_RACER = """
+import sys, time
+sys.path.insert(0, {repo!r})
+import numpy as np
+from tinysql_tpu import native
+native._SO = {so!r}
+while time.time() < {go!r}:
+    pass
+assert native.lib() is not None, "came up without the library"
+enc = native.mc_encode_column(np.array([-1, 0, 7], dtype=np.int64), "int")
+assert enc.shape == (3, 9) and bytes(enc[1]) == b"\\x03\\x80" + bytes(7)
+ids, counts = native.I64HashTable(np.array([5, 6, 5], dtype=np.int64)).probe(
+    np.array([5, 9], dtype=np.int64))
+assert sorted(ids.tolist()) == [0, 2] and counts.tolist() == [2, 0]
+"""
+
+
+def test_six_processes_on_a_tree_without_the_library_all_get_it(tmp_path):
+    """Every xdist worker of a fresh checkout calls lib() at collection:
+    the build must be one, and whole when another process loads it."""
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for name in ("build.py", "tinysql_native.cpp"):
+        shutil.copy(os.path.join(here, "native", name), tmp_path / name)
+    so = str(tmp_path / "libtinysql_native.so")
+    code = _RACER.format(repo=here, so=so, go=time.time() + 2.0)
+    procs = [subprocess.Popen([sys.executable, "-c", code],
+                              stderr=subprocess.PIPE, text=True)
+             for _ in range(6)]
+    errs = [p.communicate(timeout=60)[1] for p in procs]
+    assert [p.returncode for p in procs] == [0] * 6, errs
+    assert [n for n in os.listdir(tmp_path) if n.endswith(".so")] == [
+        "libtinysql_native.so"]  # no builder's temporary name is left

@@ -10,6 +10,8 @@ import urllib.request
 
 import pytest
 
+from _timelimit import until
+
 from tinysql_tpu.executor.devpipe import BlockPipeline
 from tinysql_tpu.obs import context as obs_context
 from tinysql_tpu.obs import metrics as obs_metrics
@@ -380,8 +382,8 @@ def test_metrics_render_without_server():
 # ---- bench wiring --------------------------------------------------------
 
 def test_q6_transfer_invariant_from_query_scope():
-    """bench.py's Q6 accounting invariant, now sourced from the
-    per-query scope: packed D2H pulls never exceed dispatches + 1."""
+    """Q6's accounting invariant, sourced from the per-query scope:
+    packed D2H pulls never exceed dispatches + 1."""
     from tinysql_tpu.bench import tpch
     from tinysql_tpu.session.session import new_session
     s = new_session()
@@ -528,8 +530,14 @@ def test_process_span_parents_statement_across_pool_handoff(span_server):
         c.query("select count(*), sum(c) from t where b < 7")
     finally:
         c.close()
-    proc = {s["name"]: s for s in _proc_spans(mark)
-            if s["args"].get("cmd") != 2}  # COM_INIT_DB aside
+    def proc_spans():
+        return {s["name"]: s for s in _proc_spans(mark)
+                if s["args"].get("cmd") != 2}  # COM_INIT_DB aside
+    # a span is recorded when it ENDS, and the connection thread ends
+    # wire.write and wire.command after the client has its answer
+    until(lambda: "wire.command" in proc_spans(),
+          "the connection thread has ended the command's span")
+    proc = proc_spans()
     for name in ("wire.command", "wire.parse", "pool.wait", "wire.write",
                  "solo"):
         assert name in proc, sorted(proc)

@@ -12,6 +12,7 @@ import time
 
 import pytest
 
+from _timelimit import hit, in_queue, until
 from test_server import MiniClient
 from tinysql_tpu import fail
 from tinysql_tpu.kv import new_mock_storage
@@ -28,6 +29,7 @@ from tinysql_tpu.session.session import Session
 @pytest.fixture(autouse=True)
 def _clean_failpoints():
     fail.disarm_all()
+    fail.reset_hits()
     yield
     fail.disarm_all()
 
@@ -38,6 +40,10 @@ def server():
     srv = Server(storage, port=0)
     srv.start()
     boot = Session(storage)
+    # the default-on heap profiler (tracemalloc, `tidb_memprof_rate = 1`)
+    # makes this module's Python-heavy statements 10-30x slower and is
+    # nothing it tests (tests/test_memprof.py holds the profiler)
+    boot.execute("set global tidb_memprof_rate = 0")
     boot.execute("create database if not exists sv")
     boot.execute("use sv")
     boot.execute("create table t (a int primary key, b int, c double)")
@@ -110,7 +116,7 @@ def test_processlist_queued_state_roundtrip(server):
         t1 = threading.Thread(target=run, args=(c1, box))
         t2 = threading.Thread(target=run, args=(c2, box))
         t1.start()
-        time.sleep(0.15)  # c1's worker is inside the wedge
+        hit("admissionDelay")  # c1's worker is inside the wedge
         t2.start()
         # poll (not a fixed sleep): thread start can be starved under
         # suite load, and the queued window closes when the wedge lifts
@@ -156,11 +162,11 @@ def test_admission_reject_typed_error_with_retry_hint(server):
         t1 = threading.Thread(
             target=lambda: box.append(c1.query("select count(*) from t")))
         t1.start()
-        time.sleep(0.2)  # worker wedged with c1's entry claimed
+        hit("admissionDelay")  # worker wedged with c1's entry claimed
         t2 = threading.Thread(
             target=lambda: box.append(c2.query("select count(*) from t")))
         t2.start()
-        time.sleep(0.2)  # c2 occupies the queue (depth 1)
+        in_queue(server.pool)  # c2 occupies the queue (depth 1)
         with pytest.raises(RuntimeError) as ei:
             c3.query("select count(*) from t")
         assert "1041" in str(ei.value) and "retry" in str(ei.value)
@@ -192,7 +198,7 @@ def test_kill_queued_statement(server):
         t1 = threading.Thread(
             target=lambda: c1.query("select count(*) from t"))
         t1.start()
-        time.sleep(0.2)
+        hit("admissionDelay")
         box = []
 
         def queued_victim():
@@ -202,7 +208,7 @@ def test_kill_queued_statement(server):
                 box.append(e)
         t2 = threading.Thread(target=queued_victim)
         t2.start()
-        time.sleep(0.2)
+        in_queue(server.pool)
         killer = MiniClient(server.port)
         killer.query(f"kill query {victim_id}")
         t2.join(10)
@@ -235,7 +241,7 @@ def test_connection_cap_1040(server):
         s.close()
         # capacity released -> connects succeed again
         keep.pop().close()
-        time.sleep(0.2)
+        until(lambda: len(server.conns) < cap, "the closed connection reaped")
         MiniClient(server.port).close()
     finally:
         boot.execute("set global tidb_max_server_connections = 0")
@@ -318,8 +324,11 @@ def test_storm_coalesces_over_wire(server):
     solo = {q: _sess(server).query(q).rows for q in qs}
     boot.execute("set global tidb_batch_window_ms = 25")
     boot.execute("set global tidb_stmt_pool_size = 2")
+    from tinysql_tpu.ops import kernels, progcache
+    kernels.prewarm_stacked()  # B-bucket variants warm, like the worker
     try:
         st0 = batching.stats_snapshot()
+        miss0 = progcache.stats_snapshot()["misses"]
         errs = []
 
         def client(jobs):
@@ -354,6 +363,7 @@ def test_storm_coalesces_over_wire(server):
         assert st["batches"] > st0["batches"], (st0, st)
         assert st["occupancy_sum"] - st0["occupancy_sum"] \
             > st["batches"] - st0["batches"], "no occupancy > 1"
+        assert progcache.stats_snapshot()["misses"] == miss0  # zero compiles
     finally:
         boot.execute("set global tidb_batch_window_ms = 2")
         boot.execute("set global tidb_stmt_pool_size = 4")
@@ -502,10 +512,10 @@ def test_pool_size_zero_drains_queued_entries(server):
             box.append(c.query("select count(*) from t"))
         t1 = threading.Thread(target=run, args=(c1,))
         t1.start()
-        time.sleep(0.15)  # worker wedged with c1's entry
+        hit("admissionDelay")  # worker wedged with c1's entry
         t2 = threading.Thread(target=run, args=(c2,))
         t2.start()
-        time.sleep(0.1)   # c2 queued
+        in_queue(server.pool)    # c2 queued
         boot.execute("set global tidb_stmt_pool_size = 0")
         t1.join(30)
         t2.join(30)

@@ -25,6 +25,7 @@ import time
 import numpy as np
 import pytest
 
+from _timelimit import hit
 from tinysql_tpu import fail
 from tinysql_tpu.bench import tpch
 from tinysql_tpu.chunk.column import Column
@@ -615,9 +616,10 @@ def test_kill_lands_mid_spill(tq):
         except Exception as e:
             box.append(e)
 
+    fail.reset_hits()
     t = threading.Thread(target=run)
     t.start()
-    time.sleep(0.3)
+    hit("spillReloadError")  # partitions are reloading
     from tinysql_tpu.utils import interrupt
     interrupt.kill(s.conn_id, query_only=True)
     t.join(20)

@@ -9,6 +9,8 @@ import time
 
 import pytest
 
+from _timelimit import join
+
 from tinysql_tpu.obs import metrics as obs_metrics
 from tinysql_tpu.obs import slowlog as obs_slowlog
 from tinysql_tpu.obs import stmtsummary
@@ -142,7 +144,7 @@ def test_concurrent_sessions_aggregate_one_row():
     for t in ts:
         t.start()
     for t in ts:
-        t.join()
+        join(t)
     assert not errs, errs
     digest, _ = stmtsummary.normalize(sql)
     recs = [r for r in stmtsummary.snapshot() if r["digest"] == digest]
@@ -272,7 +274,7 @@ def test_processlist_live_statement_and_explain_for_connection():
                     break
                 time.sleep(0.01)
         finally:
-            th.join()
+            join(th)
     assert not errs, errs
     assert live is not None, "running statement never seen in processlist"
     assert live[2] > 0  # live memory bytes

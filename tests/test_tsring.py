@@ -22,6 +22,7 @@ import time
 
 import pytest
 
+from _timelimit import hit, in_queue
 from tinysql_tpu import fail
 from tinysql_tpu.kv import new_mock_storage
 from tinysql_tpu.obs import inspect as oinspect
@@ -251,15 +252,20 @@ def test_metrics_summary_over_sql(storage):
 def _wedged_pool_run(storage, pool, sqls, wedge_s=0.5):
     """Run sqls[0] into an armed admissionDelay wedge, queue the rest
     behind it; returns the per-statement sessions (drained)."""
+    fail.reset_hits()
     fail.arm("admissionDelay", sleep=wedge_s, times=1)
     sessions = [_sess(storage) for _ in sqls]
     threads = []
     for s, q in zip(sessions, sqls):
         t = threading.Thread(target=pool.run,
                              args=(s, parse(q)[0], q), daemon=True)
-        threads.append(t)
         t.start()
-        time.sleep(0.12)  # deterministic order: one wedged, rest queued
+        # deterministic order: one wedged, the rest queued one by one
+        if threads:
+            in_queue(pool, len(threads))
+        else:
+            hit("admissionDelay")
+        threads.append(t)
     for t in threads:
         t.join(30)
         assert not t.is_alive()
@@ -324,6 +330,7 @@ def test_processlist_queued_time_is_wait_so_far(storage):
     boot.execute("set global tidb_stmt_pool_size = 1")
     pool = StatementPool(storage)
     try:
+        fail.reset_hits()
         fail.arm("admissionDelay", sleep=1.0, times=1)
         s1, s2 = _sess(storage), _sess(storage)
         t1 = threading.Thread(
@@ -331,7 +338,7 @@ def test_processlist_queued_time_is_wait_so_far(storage):
             args=(s1, parse("select count(*) from t")[0], "q1"),
             daemon=True)
         t1.start()
-        time.sleep(0.2)  # s1's worker is inside the wedge
+        hit("admissionDelay")  # s1's worker is inside the wedge
         submit_ts = time.monotonic()
         t2 = threading.Thread(
             target=pool.run,
@@ -410,15 +417,19 @@ def test_slow_query_carries_wait_fields(storage):
         sessions = [_sess(storage) for _ in range(2)]
         for s in sessions:
             s.sysvars["tidb_slow_log_threshold"] = 0  # everything is slow
+        fail.reset_hits()
         fail.arm("admissionDelay", sleep=0.4, times=1)
         threads = []
         for s, q in zip(sessions, ["select count(*) from t",
                                    "select count(*) from t where b < 1"]):
             t = threading.Thread(target=pool.run,
                                  args=(s, parse(q)[0], q), daemon=True)
-            threads.append(t)
             t.start()
-            time.sleep(0.1)
+            if threads:
+                in_queue(pool)
+            else:
+                hit("admissionDelay")
+            threads.append(t)
         for t in threads:
             t.join(30)
         rows = _sess(storage, db="").query(

@@ -15,6 +15,8 @@ import time
 
 import pytest
 
+from _timelimit import join
+
 from tinysql_tpu.catalog.meta import Meta
 from tinysql_tpu.catalog.model import SchemaState
 from tinysql_tpu.ddl.owner import OwnerManager
@@ -80,7 +82,7 @@ def test_syncer_barrier_staged_states_observed():
             a.reload()
             prev = ver
         time.sleep(0.001)
-    th.join()
+    join(th)
     assert not err, err
     b.reload()
     assert seen[-1] == SchemaState.PUBLIC, seen
@@ -129,7 +131,7 @@ def test_stale_server_dml_during_add_index_loses_nothing():
         sa.execute("create index iy on t (y)")
     finally:
         stop.set()
-        wt.join()
+        join(wt)
     assert not errs, errs
     assert wrote, "writer made no progress"
     # no missed index maintenance: index rows == table rows, consistent

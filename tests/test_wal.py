@@ -15,6 +15,7 @@ import time
 
 import pytest
 
+from _timelimit import hit
 from tinysql_tpu import fail
 from tinysql_tpu.kv import new_mock_storage
 from tinysql_tpu.kv import wal as walmod
@@ -435,14 +436,17 @@ def test_aio_close_drains_inflight_then_checkpoints(tmp_path):
 
     def slow_insert():
         try:
-            with fail.armed("execSlowNext", sleep=0.3, times=1):
+            # (an INSERT never passes execSlowNext: the wedge that holds
+            # it on its pool worker, entry claimed, is admissionDelay)
+            with fail.armed("admissionDelay", sleep=0.3, times=1):
                 box.append(c.query("insert into t values (7)"))
         except Exception as e:  # pragma: no cover - failure capture
             box.append(e)
 
+    fail.reset_hits()
     th = threading.Thread(target=slow_insert)
     th.start()
-    time.sleep(0.1)          # statement is mid-flight on the pool
+    hit("admissionDelay")    # statement is mid-flight on the pool
     srv.close()              # shutdown drain must let it complete
     th.join(timeout=5)
     assert not th.is_alive()

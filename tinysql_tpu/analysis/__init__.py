@@ -7,7 +7,7 @@ Three passes over the invariants nothing else checks mechanically:
   scalar coercion on traced values, Python branches on tracers, per-call
   `jax.jit` wrappers that defeat the dispatch cache, unhashable jit-cache
   keys.  A host sync inside a fused program costs a whole extra dispatch
-  (~40-70ms on the device link, PROFILE.md §1), which is exactly the bug
+  (~40-70ms on the device link, PERF.md §6), which is exactly the bug
   class "Premature Dimensional Collapse" (PAPERS.md) says silently
   destroys tensor-backend wins.
 - **plan-device** (`plan_device.py`, PD2xx): walks PHYSICAL plans after

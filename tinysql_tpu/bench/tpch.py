@@ -277,10 +277,10 @@ def canon_rows(rows):
     significant digits (covers float64 noise and -0.0), NULL tagged
     unambiguously, everything else stringified.  Row ORDER is kept —
     the workload queries all have deterministic ORDER BY.  This is the
-    STRICT equality tests and the CI smoke share; bench.py deliberately
-    keeps its looser `_rows_match` (sorted, 1e-6 relative) for ALL its
-    sections because real-TPU reductions reorder float sums beyond 9
-    significant digits at SF>=0.1."""
+    STRICT equality tests and the CI smoke share; chip_smoke.py keeps a
+    looser `_rows_match` (sorted, 1e-6 relative) because real-TPU
+    reductions reorder float sums beyond 9 significant digits at
+    SF>=0.1."""
     out = []
     for r in rows:
         key = []

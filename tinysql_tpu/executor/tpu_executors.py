@@ -737,9 +737,8 @@ class TPUHashAggExec(Executor):
         # XLA:CPU runs serially, while np.bincount with REPLICA-MEMOIZED
         # argument columns is the host-optimal kernel.  Below that
         # threshold the fused device program still wins ON THIS BACKEND
-        # (measured: Q1 0.73s fused vs 1.47s host — its on-device
-        # args/mask avoid numpy's materialized temporaries; PROFILE.md
-        # §6).  Runs BEFORE the device-mask build so the twin never pays
+        # (measured on XLA:CPU: Q1 0.73s fused vs 1.47s host — its
+        # on-device args/mask avoid numpy's materialized temporaries).  Runs BEFORE the device-mask build so the twin never pays
         # for a device filter program it would discard.
         if (plan.group_by and n_segments > kernels.SEG_UNROLL
                 and kernels.host_kernels_ok()
@@ -2227,8 +2226,8 @@ class TPUProjectionExec(Executor):
         if self._fn is None:
             # shared params-compiled program (ops/progcache): executors
             # are rebuilt per query, so a per-instance @jit wrapper would
-            # retrace EVERY query — qlint TS104, the ~40-70ms-per-
-            # dispatch bug class PROFILE.md §1 prices
+            # retrace EVERY query — qlint TS104, the extra-dispatch
+            # bug class
             from ..ops.exprjit import (ParamTable, compile_expr_params,
                                        stable_shape_key)
             key = ("proj",) + tuple(stable_shape_key(e)

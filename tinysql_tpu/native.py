@@ -2,13 +2,14 @@
 memcomparable batch codec + the int64 join hash table.
 
 Loads native/libtinysql_native.so, building it with g++ on first use if
-missing.  Every caller must handle `lib() is None` (no toolchain): the
-pure-python paths remain the semantic reference.
+missing (native/build.py).  Every caller must handle `lib() is None` (no
+toolchain): the pure-python paths remain the semantic reference.
 """
 from __future__ import annotations
 
 import ctypes
 import os
+import subprocess
 import threading
 from typing import Optional
 
@@ -22,6 +23,17 @@ _SO = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                    "native", "libtinysql_native.so")
 
 
+def _ensure_built() -> None:
+    """native/build.py builds the library if it is missing or older than
+    its source, one process at a time and onto its final name whole."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "tsnative_build", os.path.join(os.path.dirname(_SO), "build.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    mod.ensure()
+
+
 def lib() -> Optional[ctypes.CDLL]:
     global _lib, _tried
     if _lib is not None or _tried:
@@ -30,17 +42,10 @@ def lib() -> Optional[ctypes.CDLL]:
         if _tried:
             return _lib
         try:
-            src = os.path.join(os.path.dirname(_SO), "tinysql_native.cpp")
-            stale = (os.path.exists(_SO) and os.path.exists(src)
-                     and os.path.getmtime(src) > os.path.getmtime(_SO))
-            if not os.path.exists(_SO) or stale:
-                import importlib.util
-                spec = importlib.util.spec_from_file_location(
-                    "tsnative_build",
-                    os.path.join(os.path.dirname(_SO), "build.py"))
-                mod = importlib.util.module_from_spec(spec)
-                spec.loader.exec_module(mod)
-                mod.build()
+            _ensure_built()
+        except (OSError, subprocess.CalledProcessError):
+            pass  # no toolchain or no source: a library built before still loads
+        try:
             l = ctypes.CDLL(_SO)
             l.mc_encode_batch.restype = ctypes.c_int
             l.mc_encode_bytes.restype = ctypes.c_int64
@@ -49,7 +54,7 @@ def lib() -> Optional[ctypes.CDLL]:
             l.i64ht_probe.restype = ctypes.c_int64
             l.i64ht_free.restype = None
             _lib = l
-        except Exception:
+        except (OSError, AttributeError):
             _lib = None
         _tried = True
         return _lib

@@ -552,8 +552,8 @@ def live_overhead_frac(stats_before: Dict[str, float],
                        stats_after: Dict[str, float],
                        wall_s: float) -> float:
     """Sampler self-cost over a measured live window: the delta of the
-    profiler's own accumulated tick wall divided by the elapsed wall —
-    what bench_serve.py hard-gates against the 3% budget."""
+    profiler's own accumulated tick wall divided by the elapsed wall,
+    to hold against the 3% budget."""
     d = float(stats_after.get("self_s", 0.0)) \
         - float(stats_before.get("self_s", 0.0))
     return round(d / max(wall_s, 1e-9), 6)

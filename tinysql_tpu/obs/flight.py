@@ -115,8 +115,8 @@ def live_overhead_frac(stats_before: Dict[str, float],
                        stats_after: Dict[str, float],
                        wall_s: float) -> float:
     """Writer self-cost over a measured live window — same definition
-    as conprof/memprof.live_overhead_frac, so bench_serve can gate the
-    three samplers' combined live fraction under one budget."""
+    as conprof/memprof.live_overhead_frac, so the three samplers'
+    combined live fraction can be held under one budget."""
     if wall_s <= 0:
         return 0.0
     d = stats_after.get("self_s", 0.0) - stats_before.get("self_s", 0.0)

@@ -542,9 +542,8 @@ def live_overhead_frac(stats_before: Dict[str, float],
                        stats_after: Dict[str, float],
                        wall_s: float) -> float:
     """Sampler self-cost over a measured live window: the delta of the
-    profiler's own accumulated tick wall divided by the elapsed wall —
-    what bench_serve.py hard-gates against the 3% budget (alongside the
-    conprof gate)."""
+    profiler's own accumulated tick wall divided by the elapsed wall,
+    to hold against the 3% budget (as conprof's)."""
     d = float(stats_after.get("self_s", 0.0)) \
         - float(stats_before.get("self_s", 0.0))
     return round(d / max(wall_s, 1e-9), 6)
@@ -801,12 +800,11 @@ def memory_usage_rows() -> List[list]:
     return rows
 
 
-# ---- per-query probe (bench detail) ---------------------------------------
+# ---- per-query probe ------------------------------------------------------
 
 class QueryMemProbe:
-    """Bracket one query with measured memory detail (bench.py's
-    per-query ``peak_heap_kb`` / ``peak_hbm_bytes`` /
-    ``mem_untracked_frac``).  Uses tracemalloc's resettable peak where
+    """Bracket one query with measured memory detail (``peak_heap_kb`` /
+    ``peak_hbm_bytes`` / ``mem_untracked_frac``).  Uses tracemalloc's resettable peak where
     available, so the probe measures THIS query's heap high water, not
     the process's history.  All writes stay inside this module
     (qlint OB407)."""

@@ -291,8 +291,7 @@ METRICS: Dict[str, Tuple[str, str]] = {
                     "(statements_summary sum_heap_alloc_kb)"),
     "tinysql_memprof_self_seconds_total":
         ("counter", "Wall seconds the heap profiler spent snapshotting "
-                    "and folding (its own overhead; the bench_serve "
-                    "memprof gate's evidence)"),
+                    "and folding (its own overhead)"),
     "tinysql_memprof_evicted_total":
         ("counter", "Allocation sites evicted into the (evicted) "
                     "tombstone by the per-window tidb_memprof_max_sites "

@@ -302,11 +302,10 @@ def summary_rows() -> List[list]:
 
 def drain_pending_costs() -> None:
     """Resolve deferred XLA cost analyses (kernels._PENDING_COSTS) —
-    called every Sampler tick.  Before ISSUE 11 only bench.py ever
-    drained the queue, so serving mode with cost tracking enabled
-    accumulated pending analyses forever and flops/bytes undercounted;
-    the sampler is the natural steady-state drainer (off the query
-    path, already paced).  Exception-isolated: a broken backend must
+    called every Sampler tick.  Without a drainer, serving mode with
+    cost tracking enabled accumulates pending analyses forever and
+    flops/bytes undercount; the sampler is the natural steady-state
+    drainer (off the query path, already paced).  Exception-isolated: a broken backend must
     not kill the sampler thread."""
     try:
         from ..ops import kernels

@@ -175,8 +175,8 @@ def host_dispatch(n: int = 1) -> None:
     device lowerings.  Without this counter a query served entirely by
     twins reports dispatches=0 and is indistinguishable from one that
     silently fell off the accelerated paths (the BENCH_r05 Q3 mystery);
-    bench.py asserts dispatches + host_dispatches > 0 per device-tier
-    query."""
+    chip_smoke.py and benchmark/deployments/ require host_dispatches == 0
+    on the chip, tools/workload_smoke.py dispatches + host_dispatches > 0."""
     stats_add("host_dispatches", n)
 
 
@@ -261,14 +261,14 @@ def _abstractify(tree):
 
 
 # (costs dict, spec, jitted fn, abstract args) awaiting cost analysis —
-# resolved OUTSIDE the timed region (resolve_pending_costs: the bench
-# between timed runs, the tsring Sampler every tick in serving mode), so
-# the AOT retrace never inflates the walls the MFU is computed from.
+# resolved OUTSIDE the timed region (resolve_pending_costs: the tsring
+# Sampler every tick in serving mode), so the AOT retrace never inflates
+# the walls the MFU is computed from.
 # BOUNDED: beyond the cap a new spec records (0, 0) instead of queueing
 # — with cost tracking on and no drainer the list must not grow forever
 # (the pre-ISSUE-11 serving-mode leak).  GUARDED (_PENDING_MU, qlint
-# CC701): query threads append while the tsring Sampler AND bench.py can
-# drain concurrently — an unguarded pop raced against another drainer
+# CC701): query threads append while the tsring Sampler and any other
+# caller drain concurrently — an unguarded pop raced against another drainer
 # raises IndexError out of whichever caller loses, and the cap check
 # raced against a concurrent append overshoots the bound
 _PENDING_COSTS: list = []
@@ -1169,7 +1169,7 @@ def stacked_variant(key: tuple, base_fn, b: int):
 def prewarm_stacked(buckets=(2, 4, 8, 16)) -> int:
     """AOT-build the B-bucketed stacked variants of every registered
     batchable fused program (the auto-prewarm worker calls this inside
-    its prewarm scope; bench_serve/batch_smoke call it so the storm's
+    its prewarm scope; tools/batch_smoke.py calls it so the storm's
     first stacked round is a plain cache hit).  Returns the number of
     variants now registered."""
     n = 0

@@ -71,13 +71,11 @@ STATS: Dict[str, float] = {
 
 #: wall seconds of the most recent sharded DEVICE REGION — partition-block
 #: upload, the shard_map dispatch, and result download — set by every
-#: sharded entry point right after its dispatch.  The multichip bench
-#: (bench/operators.run_sharded) reads it to split a measurement into the
-#: shard-parallel region and the serial host sections (partition scatter,
-#: probe-order re-assembly): a forced host mesh timeshares its N virtual
-#: devices onto the physical cores, so raw wall alone cannot show the
-#: concurrency a real mesh provides.  A point sample, not a cumulative
-#: counter — deliberately NOT part of STATS / the metrics registry.
+#: sharded entry point right after its dispatch, to split a measurement
+#: into the shard-parallel region and the serial host sections (partition
+#: scatter, probe-order re-assembly).  A point sample, not a cumulative
+#: counter — deliberately NOT part of STATS / the metrics registry.  It
+#: has had no reader since PR 31 (ROADMAP.md Design 7).
 LAST_DEVICE_REGION_S: float = 0.0
 
 

@@ -35,11 +35,6 @@ DEFAULT_SYSVARS: Dict[str, Datum] = {
     # reference: sessionctx/variable/tidb_vars.go defaults
     "autocommit": 1,
     "tidb_max_chunk_size": 1024,
-    "tidb_init_chunk_size": 32,
-    "tidb_hash_join_concurrency": 5,
-    "tidb_projection_concurrency": 4,
-    "tidb_hashagg_partial_concurrency": 4,
-    "tidb_hashagg_final_concurrency": 4,
     "tidb_distsql_scan_concurrency": 15,
     "tidb_index_lookup_concurrency": 4,
     "tidb_use_tpu": 1,           # device enforcer master switch

@@ -22,6 +22,7 @@ deadline -> 3024 ER_QUERY_TIMEOUT.
 """
 from __future__ import annotations
 
+import collections
 import contextvars
 import itertools
 import threading
@@ -112,19 +113,25 @@ _next_conn_id = itertools.count(1)
 _SESSIONS: Dict[int, "weakref.ref"] = {}
 
 
+#: ids of sessions that died, waiting to leave the index.  A weakref
+#: callback runs wherever the collector does — also on a thread that is
+#: inside one of the critical sections below (``sessions()`` allocates a
+#: list there) — so it takes no lock and touches no dict: taking
+#: ``_reg_mu`` deadlocked that thread against itself, and behind it every
+#: later connect, KILL, processlist read and profiler tick.
+_DEAD: "collections.deque[int]" = collections.deque()
+
+
 def register_session(session) -> int:
     """Assign a process-unique connection id and index the session for
     KILL resolution.  Dead entries are swept opportunistically."""
     cid = next(_next_conn_id)
-    ref = weakref.ref(session, lambda _r, cid=cid: _drop(cid))
+    ref = weakref.ref(session, lambda _r, cid=cid: _DEAD.append(cid))
     with _reg_mu:
+        while _DEAD:
+            _SESSIONS.pop(_DEAD.popleft(), None)
         _SESSIONS[cid] = ref
     return cid
-
-
-def _drop(cid: int) -> None:
-    with _reg_mu:
-        _SESSIONS.pop(cid, None)
 
 
 def lookup(conn_id: int):

@@ -4,7 +4,8 @@
 End-to-end assertion chain over a tiny TPC-H load:
 
 1. run Q6 on the device tier — the per-query scope must report nonzero
-   program dispatches and `bench.py`'s transfer invariant must hold;
+   program dispatches and the transfer invariant (packed D2H pulls never
+   exceed dispatches + 1) must hold;
 2. ``EXPLAIN ANALYZE`` Q6 and Q1 — the ROOT operator's actRows must
    equal the executed result cardinality;
 3. a ``StatusServer`` must serve ``/metrics`` exposing a nonzero

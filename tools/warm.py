@@ -17,7 +17,7 @@ Two warming layers per query:
    persistent XLA compilation cache on disk, so later PROCESSES skip the
    compiles too (JAX_COMPILATION_CACHE_DIR, else tidb_compile_cache_dir).
 
-Usage (standalone; bench.py --warm calls warm_queries on its session):
+Usage:
 
     python tools/warm.py [--sf 0.05] [--queries Q1,Q3,Q6] [--cache-dir D]
 """
