@@ -38,10 +38,6 @@ def server():
     srv = Server(storage, port=0)
     srv.start()
     boot = Session(storage)
-    # the default-on heap profiler (tracemalloc, `tidb_memprof_rate = 1`)
-    # makes this module's Python-heavy statements 10-30x slower and is
-    # nothing it tests (tests/test_memprof.py holds the profiler)
-    boot.execute("set global tidb_memprof_rate = 0")
     boot.execute("set global tidb_wire_mode = 'aio'")
     boot.execute("create database if not exists av")
     boot.execute("use av")

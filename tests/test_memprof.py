@@ -16,7 +16,7 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
-from _timelimit import hit, in_wait, join
+from _timelimit import hit, in_wait, join, until
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
@@ -267,12 +267,10 @@ def test_attribution_splits_delta_and_reaches_statements_summary(
             and time.monotonic() < deadline:
         time.sleep(0.005)
     assert HeapProfiler._statement_scopes(), "statement never registered"
-    # two injected ticks while the statement provably executes: the
-    # first anchors the traced baseline, the second carries +64 KB
-    prof.sample_once(0.1, now=1000.0, stats=[], frames={},
-                     traced_kb=100.0, hbm_bytes=0.0)
+    # an injected fold while the statement provably executes: a site
+    # window that read 164 KB at its end, 64 KB of them its growth
     prof.sample_once(0.1, now=1001.0, stats=[], frames={},
-                     traced_kb=164.0, hbm_bytes=2048.0)
+                     traced_kb=164.0, growth_kb=64.0, hbm_bytes=2048.0)
     assert done.wait(30)
     join(t)
     assert prof.stats_snapshot()["attributed"] >= 1
@@ -296,10 +294,8 @@ def test_attribution_splits_delta_and_reaches_statements_summary(
 def test_negative_delta_and_idle_process_attribute_nothing(session):
     prof = HeapProfiler()
     # no statement executing: a positive delta has no one to claim it
-    prof.sample_once(0.1, now=1000.0, stats=[], frames={},
-                     traced_kb=100.0, hbm_bytes=0.0)
     prof.sample_once(0.1, now=1001.0, stats=[], frames={},
-                     traced_kb=200.0, hbm_bytes=0.0)
+                     traced_kb=200.0, growth_kb=100.0, hbm_bytes=0.0)
     assert prof.stats_snapshot()["attributed"] == 0
     # a shrinking heap (negative delta) never attributes either
     done = threading.Event()
@@ -318,7 +314,7 @@ def test_negative_delta_and_idle_process_attribute_nothing(session):
             and time.monotonic() < deadline:
         time.sleep(0.005)
     prof.sample_once(0.1, now=1002.0, stats=[], frames={},
-                     traced_kb=150.0, hbm_bytes=0.0)
+                     traced_kb=150.0, growth_kb=-50.0, hbm_bytes=0.0)
     assert done.wait(30)
     join(t)
     assert prof.stats_snapshot()["attributed"] == 0
@@ -327,58 +323,312 @@ def test_negative_delta_and_idle_process_attribute_nothing(session):
 
 # ---- sampler lifecycle / rate 0 ------------------------------------------
 
-def test_sampler_lifecycle_restart_and_rate0_stops_tracing():
-    pre_tracing = tracemalloc.is_tracing()
+class _Windows:
+    """A ``wait`` for ``HeapProfiler.sample_window`` that returns at
+    once, records whether the window was tracing, and runs ``inside``
+    in it."""
+
+    def __init__(self, inside=None):
+        self.inside = inside
+        self.tracing = []
+
+    def __call__(self, seconds):
+        self.tracing.append(tracemalloc.is_tracing())
+        if self.inside is not None:
+            self.inside()
+        return False
+
+
+@pytest.fixture
+def not_tracing():
+    """The contract below is about a process nobody else traces."""
+    assert not tracemalloc.is_tracing(), "another test left tracing on"
+    yield
+    assert not tracemalloc.is_tracing()
+
+
+def test_sampler_lifecycle_restart_and_rate0_stops_tracing(not_tracing):
     storage = new_mock_storage()
     storage._global_vars = {"tidb_memprof_rate": 50}
     prof = HeapProfiler()
     sampler = MemprofSampler(storage, profiler=prof)
+    seen = []
+    real_window = prof.sample_window
+
+    def watched(period_s, wait, **fold):
+        # the live sampler's own window, tracing observed from inside
+        # its wait and again once it has returned
+        def inside(seconds):
+            seen.append(("in", tracemalloc.is_tracing()))
+            return wait(seconds)
+        try:
+            return real_window(period_s, inside, **fold)
+        finally:
+            seen.append(("out", tracemalloc.is_tracing()))
+
+    prof.sample_window = watched
     sampler.start()
     sampler.start()  # idempotent: no second thread
     try:
-        deadline = time.monotonic() + 20
-        while prof.stats_snapshot()["ticks"] < 2 \
-                and time.monotonic() < deadline:
-            time.sleep(0.02)
+        until(lambda: prof.stats_snapshot()["site_windows"] >= 2,
+               "no site window opened")
         assert prof.stats_snapshot()["ticks"] >= 2
-        assert tracemalloc.is_tracing()
-        # rate 0 pauses sampling AND stops the tracemalloc tax (off
-        # must mean OFF — tracing costs every allocation in the
-        # process); the traced baseline resets with it
+        # rate > 0: tracing is on inside a window and off between two
+        assert ("in", True) in seen and ("in", False) not in seen
+        assert ("out", False) in seen and ("out", True) not in seen
+        # rate 0 pauses sampling and opens no window (off must mean
+        # OFF — tracing costs every allocation in the process)
         storage._global_vars["tidb_memprof_rate"] = 0
-        deadline = time.monotonic() + 10
-        while (prof._last_traced_kb is not None
-               or (not pre_tracing and tracemalloc.is_tracing())) \
-                and time.monotonic() < deadline:
-            time.sleep(0.02)
-        if not pre_tracing:
-            assert not tracemalloc.is_tracing()
-        assert prof._last_traced_kb is None
+        time.sleep(0.3)                 # one idle slice at the least
         t0 = prof.stats_snapshot()["ticks"]
+        w0 = prof.stats_snapshot()["site_windows"]
+        assert not tracemalloc.is_tracing()
         time.sleep(0.4)
         assert prof.stats_snapshot()["ticks"] == t0
+        assert prof.stats_snapshot()["site_windows"] == w0
+        assert not tracemalloc.is_tracing()
         # re-enable: resumes on the live sysvar
         storage._global_vars["tidb_memprof_rate"] = 50
-        deadline = time.monotonic() + 20
-        while prof.stats_snapshot()["ticks"] <= t0 \
-                and time.monotonic() < deadline:
-            time.sleep(0.02)
-        assert prof.stats_snapshot()["ticks"] > t0
+        until(lambda: prof.stats_snapshot()["site_windows"] > w0,
+               "no window after the rate came back")
     finally:
         sampler.close()
-    if not pre_tracing:
-        assert not tracemalloc.is_tracing()
+    assert not tracemalloc.is_tracing()
     # restartable after close (the tsring Sampler contract)
-    t1 = prof.stats_snapshot()["ticks"]
+    w1 = prof.stats_snapshot()["site_windows"]
     sampler.start()
     try:
-        deadline = time.monotonic() + 20
-        while prof.stats_snapshot()["ticks"] <= t1 \
-                and time.monotonic() < deadline:
-            time.sleep(0.02)
-        assert prof.stats_snapshot()["ticks"] > t1
+        until(lambda: prof.stats_snapshot()["site_windows"] > w1,
+               "no window after a restart")
     finally:
         sampler.close()
+    assert not tracemalloc.is_tracing()
+
+
+def test_close_inside_a_window_stops_tracing(not_tracing):
+    # close() while a window is open: the wait is the stop event's, so
+    # the window ends at once, folds nothing and leaves tracing off
+    storage = new_mock_storage()
+    storage._global_vars = {"tidb_memprof_rate": 50}
+    prof = HeapProfiler()
+    sampler = MemprofSampler(storage, profiler=prof)
+    opened = threading.Event()
+    real_window = prof.sample_window
+
+    def long_window(period_s, wait, **fold):
+        def inside(seconds):
+            opened.set()
+            return wait(30.0)           # only close() ends this one
+        return real_window(period_s, inside, **fold)
+
+    prof.sample_window = long_window
+    sampler.start()
+    try:
+        assert opened.wait(20)
+        assert tracemalloc.is_tracing()
+    finally:
+        sampler.close()
+    assert not tracemalloc.is_tracing()
+    assert prof.stats_snapshot()["sites"] == 0
+    assert prof.stats_snapshot()["site_windows"] == 1
+
+
+def test_window_leaves_a_foreign_tracing_state_alone():
+    # tracing the test started itself (or QueryMemProbe, or
+    # PYTHONTRACEMALLOC) is read by a window and left on
+    pre = tracemalloc.is_tracing()
+    if not pre:
+        tracemalloc.start(1)
+    try:
+        prof = HeapProfiler()
+        w = _Windows()
+        prof.sample_window(1.0, w, frames={})
+        assert w.tracing == [True]
+        assert tracemalloc.is_tracing()
+        assert prof.stats_snapshot()["site_windows"] == 1
+    finally:
+        if not pre:
+            tracemalloc.stop()
+    assert tracemalloc.is_tracing() == pre
+
+
+# ---- site windows: fold, attribution, errors, budget ----------------------
+
+def test_window_folds_sites_allocated_inside_it(not_tracing):
+    prof = HeapProfiler()
+    before = bytearray(3 << 20)     # older than the window: never seen
+    held = []
+
+    def allocate():
+        held.append(bytearray(2 << 20))         # lives past the window
+        bytearray(1 << 20)                      # dies inside it
+
+    w = _Windows(inside=allocate)
+    n = prof.sample_window(1.0, w, frames={})
+    assert w.tracing == [True] and not tracemalloc.is_tracing()
+    assert n >= 1
+    snap = prof.stats_snapshot()
+    # one window's reading: what was allocated inside and still lives
+    # (2 MiB), the most that lived at once (3 MiB) — not the process's
+    assert 2048 <= snap["traced_kb"] < 3072, snap
+    assert 3072 <= snap["traced_peak_kb"] < 4096, snap
+    assert snap["site_windows"] == 1 and snap["traced_s"] > 0
+    assert snap["ticks"] == 1
+    sites = conprof.parse_collapsed(prof.collapsed())
+    mine = {s: kb for s, kb in sites.items()
+            if "test_memprof.py" in s.rsplit(";", 1)[-1]}
+    assert max(mine.values()) == 2048, sites
+    del before, held
+
+
+def test_window_attributes_its_growth_with_the_invariant(session,
+                                                         not_tracing):
+    prof = HeapProfiler()
+    done = threading.Event()
+    seen = {}
+
+    def run_stmt():
+        with fail.armed("execSlowNext", sleep=0.3):
+            session.query("select count(*) from t where b < 4")
+        seen["qobs"] = session.last_query_stats
+        done.set()
+
+    fail.reset_hits()
+    t = threading.Thread(target=run_stmt, daemon=True)
+    t.start()
+    hit("execSlowNext")  # statement provably mid-flight
+    held = []
+    prof.sample_window(
+        1.0, _Windows(inside=lambda: held.append(bytearray(1 << 20))),
+        frames={})
+    assert done.wait(30)
+    join(t)
+    snap = prof.stats_snapshot()
+    assert snap["attributed"] == 1
+    dev = seen["qobs"].device_totals()
+    # THE invariant, per window: what the statements claim never
+    # exceeds what the window measured (sole executor -> all of it)
+    assert 1024 <= dev["heap_kb"] <= snap["traced_kb"] + 1e-6, (dev, snap)
+    assert dev["heap_peak_kb"] == pytest.approx(snap["traced_kb"])
+    # growth is a window's own: a second window with nothing allocated
+    # and nothing executing attributes nothing
+    prof.sample_window(1.0, _Windows(), frames={})
+    assert prof.stats_snapshot()["attributed"] == 1
+
+
+def test_error_inside_a_window_still_stops_tracing(not_tracing):
+    # an armed memprofSampleError on a live sampler's tick: counted,
+    # the thread lives on, and tracing is off afterwards
+    storage = new_mock_storage()
+    storage._global_vars = {"tidb_memprof_rate": 50}
+    prof = HeapProfiler()
+    sampler = MemprofSampler(storage, profiler=prof)
+    with fail.armed("memprofSampleError", exc=RuntimeError("injected"),
+                    times=1):
+        sampler.start()
+        try:
+            until(lambda: prof.stats_snapshot()["errors"] >= 1,
+                   "the failpoint never fired")
+            until(lambda: prof.stats_snapshot()["ticks"] >= 1,
+                   "the sampler died with the error")
+        finally:
+            sampler.close()
+    assert not tracemalloc.is_tracing()
+    # whatever is raised while tracing is ON (a torn snapshot, the wait
+    # itself) leaves it off too, and the window is still paid for
+    prof = HeapProfiler()
+
+    def torn(seconds):
+        assert tracemalloc.is_tracing()
+        raise MemoryError("torn")
+
+    with pytest.raises(MemoryError):
+        prof.sample_window(1.0, torn, frames={})
+    assert not tracemalloc.is_tracing()
+    snap = prof.stats_snapshot()
+    assert snap["site_windows"] == 1 and snap["ticks"] == 0
+    assert prof._window_due > 0
+
+
+class _Clock:
+    def __init__(self):
+        self.now = 1000.0
+
+    def __call__(self):
+        return self.now
+
+
+def test_traced_share_stays_within_the_budget(not_tracing):
+    # a sampler asked for 50 ticks a second whose windows cost 25 ms
+    # each (the wait and a wake-up late by 10 ms), on a clock of its
+    # own: however often it ticks, traced seconds / wall seconds stays
+    # at or under the budget
+    clock = _Clock()
+    prof = HeapProfiler(clock=clock)
+
+    def wait(seconds):
+        assert tracemalloc.is_tracing()
+        clock.now += seconds + 0.010
+        return False
+
+    t_first = clock.now
+    windows_at = []
+    for _ in range(3000):
+        period = prof.backoff / 50.0
+        clock.now += period
+        w0 = prof.stats_snapshot()["site_windows"]
+        prof.tick(period, wait, frames={})
+        if prof.stats_snapshot()["site_windows"] > w0:
+            windows_at.append(clock.now)
+    snap = prof.stats_snapshot()
+    wall = clock.now - t_first
+    assert snap["site_windows"] == len(windows_at) >= 3
+    assert snap["ticks"] > 2 * snap["site_windows"]   # most ticks are bare
+    # the last window is paid for by the wall after it: leave it out
+    paid = snap["traced_s"] - (memprof.WINDOW_S + 0.010)
+    assert paid / (windows_at[-1] - t_first) \
+        <= memprof.OVERHEAD_BUDGET_FRAC + 1e-9
+    assert snap["traced_s"] / wall <= memprof.OVERHEAD_BUDGET_FRAC * 1.05
+    # and it is used, not merely kept: over half the budget runs traced
+    assert snap["traced_s"] / wall >= 0.5 * memprof.OVERHEAD_BUDGET_FRAC
+    # charged in full, the folds beside the traced seconds and the
+    # snapshots, which are both, once
+    assert memprof.live_overhead_frac({}, snap, wall) == pytest.approx(
+        (snap["traced_s"] + snap["self_s"] - snap["snapshot_s"]) / wall,
+        abs=1e-6)
+    assert 0 < snap["snapshot_s"] < snap["self_s"]
+
+
+def test_live_sampler_traced_share_within_budget(not_tracing):
+    # the same on the real clock: a live sampler asked for 50 ticks a
+    # second spends at most the budget traced, window for window
+    storage = new_mock_storage()
+    storage._global_vars = {"tidb_memprof_rate": 50}
+    prof = HeapProfiler()
+    sampler = MemprofSampler(storage, profiler=prof)
+    opened, traced_after = [], []
+    real_window = prof.sample_window
+
+    def watched(period_s, wait, **fold):
+        opened.append(time.perf_counter())
+        try:
+            return real_window(period_s, wait, **fold)
+        finally:
+            traced_after.append(prof.stats_snapshot()["traced_s"])
+
+    prof.sample_window = watched
+    sampler.start()
+    try:
+        until(lambda: len(traced_after) >= 4, "four site windows",
+              timeout=20.0)
+    finally:
+        sampler.close()
+    # every window is followed by its cost / budget of untraced wall
+    for k in range(1, len(traced_after)):
+        assert traced_after[k - 1] / (opened[k] - opened[0]) \
+            <= memprof.OVERHEAD_BUDGET_FRAC * 1.001, (k, opened,
+                                                      traced_after)
+    assert traced_after[-1] >= len(traced_after) * memprof.WINDOW_S
 
 
 def test_rate0_query_results_byte_identical(session):
@@ -413,6 +663,41 @@ def test_overhead_backoff_doubles_and_recovers():
     for _ in range(200):
         p._note_cost(0.00001, 0.1 * high)
     assert p.backoff < high
+
+
+def test_pacing_follows_a_windows_whole_cost(not_tracing):
+    # what paces the windows is a window's whole traced wall plus its
+    # fold, not the fold alone, and nothing but that: at 10 ticks a
+    # second a window of 50 ms is paid for by 1.67 s of wall, so the
+    # next opens on the 17th tick after it; once windows cost 11 ms the
+    # next is 4 ticks away, at once (the budget needs no hysteresis)
+    clock = _Clock()
+    prof = HeapProfiler(clock=clock)
+    late = [0.040]
+
+    def wait(seconds):
+        clock.now += seconds + late[0]
+        return False
+
+    def ticks_to_the_next_window():
+        w0 = prof.stats_snapshot()["site_windows"]
+        for n in range(1, 100):
+            clock.now += 0.1
+            prof.tick(0.1, wait, frames={})
+            if prof.stats_snapshot()["site_windows"] > w0:
+                return n
+
+    assert ticks_to_the_next_window() == 1      # nothing to pay for yet
+    assert ticks_to_the_next_window() == 17
+    assert ticks_to_the_next_window() == 17
+    late[0] = 0.001
+    assert ticks_to_the_next_window() == 17     # the last dear one's price
+    assert ticks_to_the_next_window() == 4
+    assert ticks_to_the_next_window() == 4
+    snap = prof.stats_snapshot()
+    assert snap["site_windows"] == 6
+    assert snap["ticks"] == 1 + 17 * 3 + 4 * 2  # the bare ticks count too
+    assert snap["traced_s"] == pytest.approx(3 * 0.050 + 3 * 0.011)
 
 
 def test_live_overhead_frac_definition():
@@ -637,6 +922,232 @@ def test_memory_state_keys_all_registered_metrics():
         assert key in metrics.METRICS, key
 
 
+def test_windows_and_traced_seconds_on_every_surface(not_tracing):
+    from tinysql_tpu.obs import metrics, trace
+    memprof.reset()
+    try:
+        before = trace.totals()
+        held = []
+        memprof.PROF.sample_window(
+            1.0, _Windows(inside=lambda: held.append(bytearray(1 << 20))),
+            frames={})
+        snap = memprof.stats_snapshot()
+        assert snap["site_windows"] == 1 and snap["traced_s"] > 0
+        assert snap["windows"] == 1        # the aggregation window, as ever
+        state = memprof.memory_state()
+        assert state["tinysql_memprof_windows_total"] == 1
+        assert state["tinysql_memprof_traced_seconds_total"] \
+            == pytest.approx(snap["traced_s"])
+        # the traced gauges are the last window's reading, tracing off
+        assert state["tinysql_mem_traced_bytes"] >= 1 << 20
+        assert state["tinysql_mem_traced_peak_bytes"] \
+            >= state["tinysql_mem_traced_bytes"]
+        text = metrics.render_prometheus()
+        assert "tinysql_memprof_windows_total 1" in text
+        assert "tinysql_memprof_traced_seconds_total " in text
+        for name in ("tinysql_mem_traced_bytes",
+                     "tinysql_mem_traced_peak_bytes",
+                     "tinysql_memprof_windows_total",
+                     "tinysql_memprof_traced_seconds_total"):
+            assert "window" in metrics.METRICS[name][1], name
+        # the span the benchmark reads: one memprof.window; the
+        # sampler's own work is two bg.memprof spans, the snapshot
+        # inside the window and the fold after it (the window's sleep
+        # is nobody's work, and in no bg. span)
+        after = trace.totals()
+
+        def grew(name, key):
+            return after[name][key] - before.get(name, {}).get(key, 0)
+
+        assert grew("memprof.window", "count") == 1
+        assert grew("memprof.window", "sum_s") \
+            == pytest.approx(snap["traced_s"], rel=0.2)
+        assert grew("memprof.window", "sum_s") >= 0
+        assert grew("bg.memprof", "count") == 2
+        assert grew("bg.memprof", "sum_s") \
+            == pytest.approx(snap["self_s"], rel=0.5, abs=2e-3)
+    finally:
+        memprof.reset()
+
+
+def test_totals_reads_the_collectors_row_last():
+    """``obs.trace.totals()`` allocates a row a span name; a collection
+    that its own allocations set off, whatever the allocator's phase,
+    is in the copy it returns: one explicit collection reads as one."""
+    import gc
+    from tinysql_tpu.obs import context, trace
+    trace.watch_collector()
+    for i in range(30):                 # a table of a realistic length
+        with context.process_span(f"t32.name{i}"):
+            pass
+    keep = []
+    was = gc.isenabled()
+    gc.enable()
+    try:
+        for phase in range(400, 700, 10):
+            gc.collect()
+            while gc.get_count()[0] < phase:
+                keep.append([])
+            n0 = trace.totals().get("gc", {"count": 0})["count"]
+            gc.collect()
+            assert trace.totals()["gc"]["count"] == n0 + 1, phase
+            keep.clear()
+    finally:
+        if not was:
+            gc.disable()
+
+
+def test_server_at_defaults_traces_only_inside_windows(not_tracing):
+    """A real server, every sysvar at its default (`tidb_memprof_rate`
+    1): tracing is off except inside the sampler's site windows, every
+    surface is still served with sites once a window has run, and rate
+    0 still stops everything."""
+    from test_server import MiniClient
+    from tinysql_tpu.obs import metrics
+    from tinysql_tpu.server.http_status import StatusServer
+    from tinysql_tpu.server.server import Server
+    storage = new_mock_storage()
+    boot = Session(storage)
+    boot.execute("create database mpd")
+    boot.execute("use mpd")
+    boot.execute("create table t (a int primary key, b int)")
+    boot.execute("insert into t values " + ", ".join(
+        f"({i}, {i % 7})" for i in range(300)))
+    memprof.reset()
+    stmtsummary.STORE.reset()
+    srv = Server(storage, port=0)
+    srv.start()
+    status = StatusServer(srv)
+    sport = status.start()
+    sql = "select b, count(*), sum(a) from t group by b order by b"
+    try:
+        c = MiniClient(srv.port, db="mpd")
+        tracing = []
+
+        def two_windows():
+            c.query(sql)
+            tracing.append(tracemalloc.is_tracing())
+            return memprof.stats_snapshot()["site_windows"] >= 2
+
+        until(two_windows, "two site windows at the default rate",
+              timeout=30.0)
+        snap = memprof.stats_snapshot()
+        # the client saw tracing on for a sliver of its statements
+        assert sum(tracing) <= 0.2 * len(tracing), (sum(tracing),
+                                                    len(tracing))
+        assert snap["traced_s"] < 0.5 and snap["errors"] == 0
+        assert snap["sites"] > 0
+        body = urllib.request.urlopen(
+            f"http://127.0.0.1:{sport}/debug/heap", timeout=5
+        ).read().decode()
+        assert conprof.parse_collapsed(body)
+        _, rows = c.query("select source, item, bytes from "
+                          "information_schema.memory_usage")
+        by_item = {(r[0], r[1]): int(r[2]) for r in rows}
+        assert 0 < by_item[("measured", "traced_heap")] \
+            <= by_item[("measured", "rss")]
+        _, rows = c.query(
+            "select sum_heap_alloc_kb, max_heap_kb from "
+            "information_schema.statements_summary")
+        assert rows and all(float(r[0]) >= 0 for r in rows)
+        text = metrics.render_prometheus()
+        for name in ("tinysql_memprof_ticks_total",
+                     "tinysql_memprof_windows_total",
+                     "tinysql_memprof_traced_seconds_total"):
+            assert name + " " in text, name
+        assert memprof.memory_state()["tinysql_mem_traced_bytes"] > 0
+        # off is still off: no tick, no window, tracing never on
+        c.query("set global tidb_memprof_rate = 0")
+        time.sleep(0.6)                 # two idle slices
+        off = memprof.stats_snapshot()
+        for _ in range(20):
+            c.query(sql)
+            assert not tracemalloc.is_tracing()
+            time.sleep(0.02)
+        now = memprof.stats_snapshot()
+        assert (now["ticks"], now["site_windows"]) \
+            == (off["ticks"], off["site_windows"])
+        c.close()
+    finally:
+        status.close()
+        srv.close()
+        memprof.reset()
+        stmtsummary.STORE.reset()
+    assert not tracemalloc.is_tracing()
+
+
+def _bench_module(*parts):
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", *parts)
+    spec = importlib.util.spec_from_file_location(
+        "bench_" + parts[-1][:-3], path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_benchmark_reads_the_window_span(not_tracing):
+    """``memprof_traced_ms_per_query``: the data file loads, the reader
+    and source it names are the accepted ones, the key is one the spans
+    source produces once a ``memprof.window`` span has ended (and a
+    program without the span leaves the metric out), and the two
+    entries in BENCHMARK.json name accepted cells."""
+    import json
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           "memprof_traced_ms_per_query.json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "counter" and spec["sources"] == ["spans"]
+    args = spec["args"]
+    assert args == {"source": "spans", "key": "memprof.window.ms",
+                    "per_statement": True}
+    spans = _bench_module("sources", "spans.py")
+    counter = _bench_module("readers", "counter.py")
+    before = spans.snapshot()
+    prof = HeapProfiler()
+    prof.sample_window(1.0, time.sleep, frames={})   # the real wait
+    after = spans.snapshot()
+    assert args["key"] in after
+    grown = after[args["key"]] - before.get(args["key"], 0.0)
+    assert grown == pytest.approx(
+        prof.stats_snapshot()["traced_s"] * 1e3, rel=0.2)
+    assert grown >= memprof.WINDOW_S * 1e3
+    # the samplers' sum does not hold the window's sleep
+    assert after["bg.ms"] - before["bg.ms"] < grown
+    run = SimpleNamespace(deltas={"spans": {args["key"]: grown}},
+                          answered=[object()] * 4)
+    assert counter.read(run, **args) == pytest.approx(grown / 4)
+    # the parent has no such span: nothing to read, nothing raised
+    run.deltas = {"spans": {"bg.ms": 1.0}}
+    assert counter.read(run, **args) is None
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cells = {w["name"]: w for w in bench["workloads"]}
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    mine = [m for m in bench["per_layer"]
+            if m["name"].startswith("memprof_traced_ms_per_query.")]
+    assert [m["name"] for m in mine] == [
+        "memprof_traced_ms_per_query.stream",
+        "memprof_traced_ms_per_query.serve"]
+    assert bench["per_layer"][-2:] == mine      # appended, not inserted
+    for m, cell, moves in zip(
+            mine, ("tpch_sf1.power_stream", "tpch_sf1.q6_dash_16c"),
+            ("stream_queries_per_s", "serve_queries_per_s")):
+        assert m["workloads"] == [cell] and cell in cells
+        assert m["moves"] == moves and cell in e2e[moves]["workloads"]
+        assert (m["unit"], m["better"], m["source"], m["layer"]) == (
+            "ms", "lower", "program_span", "samplers")
+        # the cell runs the profiler at its default: its configuration
+        # does not turn it off (the four-chip one does, and is no cell
+        # of this metric)
+        config = {c["name"]: c for c in bench["configs"]}[
+            cells[cell]["config"]]
+        with open(os.path.join(root, config["file"])) as f:
+            assert "tidb_memprof_rate" not in json.dumps(
+                json.load(f).get("sysvars", {}))
+
+
 # ---- the inspection rules -------------------------------------------------
 
 def _ring_with(points):
@@ -655,20 +1166,65 @@ def _findings(ring, rule):
 
 
 def test_rule_heap_growth():
+    # the rule reads the resident set: it needs no tracing and sees
+    # numpy's and XLA's host buffers too
     mib = 1 << 20
-    rise = [i * 16 * mib for i in range(5)]  # +64 MiB, monotone
-    f = _findings(_ring_with({"tinysql_mem_traced_bytes": rise}),
+    base = 900 * mib                # a process is never 0 bytes resident
+    floor = oinspect.HEAP_GROWTH_MIN_BYTES
+    rise = [base + i * floor // 2 for i in range(5)]  # 2x floor, monotone
+    f = _findings(_ring_with({"tinysql_mem_rss_bytes": rise}),
                   "heap-growth")
     assert len(f) == 1 and f[0].severity == "warning"
-    assert f[0].metric == "tinysql_mem_traced_bytes"
+    assert f[0].metric == "tinysql_mem_rss_bytes"
+    assert f"{2 * floor / mib:.1f} MiB" in f[0].details
     # a sawtooth of the same amplitude is a cache, not a leak
-    saw = [0, 64 * mib, 8 * mib, 72 * mib, 16 * mib]
-    assert not _findings(_ring_with({"tinysql_mem_traced_bytes": saw}),
+    saw = [base, base + 2 * floor, base + floor // 4,
+           base + 2 * floor + floor // 4, base + floor // 2]
+    assert not _findings(_ring_with({"tinysql_mem_rss_bytes": saw}),
                          "heap-growth")
     # a monotone rise under the floor is noise
-    small = [i * mib for i in range(5)]
+    small = [base + i * mib for i in range(5)]
     assert not _findings(
-        _ring_with({"tinysql_mem_traced_bytes": small}), "heap-growth")
+        _ring_with({"tinysql_mem_rss_bytes": small}), "heap-growth")
+    # the traced heap is one site window's reading, not a level: its
+    # rise alone is nobody's leak
+    assert not _findings(
+        _ring_with({"tinysql_mem_traced_bytes": rise}), "heap-growth")
+    # a process that is warming up (replicas prepared, programs
+    # compiled) grows by gigabytes and is no leak: the rule judges the
+    # samples from the window's last program load on
+    gib = 1 << 30
+    warm = [base, base + gib, base + 2 * gib, base + 3 * gib]
+    flat = [warm[-1] + i * mib for i in range(5)]
+    compiled = [0.0, 5.0, 9.0, 12.0] + [12.0] * 5
+    assert not _findings(
+        _ring_with({"tinysql_mem_rss_bytes": warm + flat,
+                    "tinysql_program_load_seconds_total": compiled}),
+        "heap-growth")
+    # and what rises after it is judged as ever
+    f = _findings(
+        _ring_with({"tinysql_mem_rss_bytes": warm + [
+            warm[-1] + i * floor // 2 for i in range(5)],
+            "tinysql_program_load_seconds_total": compiled}), "heap-growth")
+    assert len(f) == 1 and f[0].first_value == warm[-1]
+    assert f"{2 * floor / mib:.1f} MiB" in f[0].details
+
+
+def test_program_load_seconds_rise_when_a_program_has_loaded():
+    """The series the memory rules start from: jax's own trace, lower
+    and compile durations, which end when the program is loaded (the
+    registry's build wall rises before a jitted function's first call
+    has compiled anything)."""
+    from tinysql_tpu.obs import metrics, trace, tsring
+    from tinysql_tpu.ops import kernels
+    jax = kernels.jax()         # registers the duration listener
+    name = "tinysql_program_load_seconds_total"
+    before = tsring._src_progcache()[name]
+    assert before == trace.program_load_s()
+    jax.jit(lambda x: x * 3 + 1)(jax.numpy.arange(7)).block_until_ready()
+    after = tsring._src_progcache()[name]
+    assert after > before
+    assert name in metrics.METRICS and name in metrics.render_prometheus()
 
 
 def test_rule_hbm_pressure():
@@ -690,13 +1246,40 @@ def test_rule_mem_untracked():
     mib = 1 << 20
     band = memprof.UNTRACKED_BAND_BYTES
     # measured growth a full band beyond everything the ledger held
+    base = 900 * mib
     ring = _ring_with({
-        "tinysql_mem_traced_bytes": [0, band + 20 * mib],
+        "tinysql_mem_rss_bytes": [base, base + band + 20 * mib],
         "tinysql_mem_tracked_bytes": [0, 10 * mib]})
     f = _findings(ring, "mem-untracked")
     assert len(f) == 1 and f[0].severity == "warning"
+    assert f[0].metric == "tinysql_mem_rss_bytes"
     # divergence inside the documented band: silent
     ring = _ring_with({
-        "tinysql_mem_traced_bytes": [0, band - mib],
+        "tinysql_mem_rss_bytes": [base, base + band - mib],
         "tinysql_mem_tracked_bytes": [0, 0]})
     assert not _findings(ring, "mem-untracked")
+    # growth the ledger held is tracked, whatever its size
+    ring = _ring_with({
+        "tinysql_mem_rss_bytes": [base, base + 2 * band],
+        "tinysql_mem_tracked_bytes": [0, band + mib]})
+    assert not _findings(ring, "mem-untracked")
+    # first answers (replicas prepared, programs compiled) are no
+    # statement's to answer for: the baseline is the window's last
+    # program load, and growth after it is judged as ever
+    gib = 1 << 30
+    compiled = [0.0, 30.0, 55.0, 55.0, 55.0]
+    ring = _ring_with({
+        "tinysql_mem_rss_bytes": [base, base + gib, base + 3 * gib,
+                                  base + 3 * gib + mib,
+                                  base + 3 * gib + 2 * mib],
+        "tinysql_mem_tracked_bytes": [0, 0, 0, mib, 0],
+        "tinysql_program_load_seconds_total": compiled})
+    assert not _findings(ring, "mem-untracked")
+    ring = _ring_with({
+        "tinysql_mem_rss_bytes": [base, base + gib, base + 3 * gib,
+                                  base + 3 * gib + mib,
+                                  base + 3 * gib + band + 20 * mib],
+        "tinysql_mem_tracked_bytes": [0, 0, 0, mib, 0],
+        "tinysql_program_load_seconds_total": compiled})
+    f = _findings(ring, "mem-untracked")
+    assert len(f) == 1 and f[0].first_value == base + 3 * gib

@@ -33,10 +33,6 @@ def server():
     srv = Server(storage, port=0)
     srv.start()
     boot = Session(storage)
-    # the default-on heap profiler (tracemalloc, `tidb_memprof_rate = 1`)
-    # makes this module's Python-heavy statements 10-30x slower and is
-    # nothing it tests (tests/test_memprof.py holds the profiler)
-    boot.execute("set global tidb_memprof_rate = 0")
     # these tests count the process's progcache misses around a round:
     # the auto-prewarm worker (first cycle 60 s after start, well inside
     # this module on a loaded machine) must not build beside them

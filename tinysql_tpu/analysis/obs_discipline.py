@@ -47,10 +47,10 @@ Rules:
   accounting behind ``information_schema.continuous_profiling``.
 - **OB407**: heap/HBM accumulator writes outside ``obs/memprof.py``.
   The memory keys (``heap_kb`` / ``heap_peak_kb`` / ``hbm_bytes``) are
-  MEASURED truth: ``heap_kb`` is the sampler tick's traced-delta split
-  across executing statements (so the per-statement sum stays ≤ the
-  process's measured growth), ``heap_peak_kb`` is the tracemalloc
-  high-water mark, and ``hbm_bytes`` is the live device-buffer census.
+  MEASURED truth: ``heap_kb`` is a site window's traced growth split
+  across executing statements (so the per-statement sum stays ≤ what
+  the window measured), ``heap_peak_kb`` is the largest window
+  reading, and ``hbm_bytes`` is the live device-buffer census.
   Any other writer would publish a guess as measurement and break the
   ≤-growth invariant behind ``statements_summary.sum_heap_alloc_kb``;
   and any out-of-module mutation of the heap profiler's window store

@@ -70,14 +70,16 @@ tests/test_tsring.py):
 - **profiler-overhead** (ISSUE 13): the continuous profiler's own
   sampling cost ran past its budget share of one core — the rule
   reports it while the sampler's backoff divisor absorbs it;
-- **heap-growth** (ISSUE 18): the MEASURED python heap
-  (obs/memprof.py) rose monotonically across the window past the
-  threshold — leak-shaped growth, with /debug/heap holding the sites;
+- **heap-growth** (ISSUE 18): the process's MEASURED resident set
+  (obs/memprof.py; it needs no tracing and sees numpy's and XLA's host
+  buffers too) rose monotonically across the window past the
+  threshold — leak-shaped growth, with /debug/heap holding the python
+  sites its site windows sampled;
 - **hbm-pressure** (ISSUE 18): the HBM census approaches the backend's
   exposed device-memory capacity (silent on CPU, which exposes none);
-- **mem-untracked** (ISSUE 18): measured heap growth diverged from the
-  MemTracker ledger beyond the documented band — allocation the
-  spill/admission gates cannot see.
+- **mem-untracked** (ISSUE 18): measured resident-set growth diverged
+  from the MemTracker ledger beyond the documented band — allocation
+  the spill/admission gates cannot see.
 
 Thresholds are module-level constants, deliberately conservative: an
 inspection finding is a diagnosis, so false positives cost trust.
@@ -175,14 +177,19 @@ WAL_STALL_MEAN_CRIT_S = 0.050
 #: against a deliberately small cap, not pressure
 CONN_SHEDS_WARN = 2
 
-#: heap-growth (ISSUE 18): minimum sampled points of the traced-heap
+#: heap-growth (ISSUE 18): minimum sampled points of the resident-set
 #: gauge before monotone-rise leak detection may judge, the fraction of
 #: point-to-point steps that must be rises (a sawtooth heap is a cache,
-#: not a leak), and the total windowed rise in bytes that makes the
-#: pattern worth reporting
+#: not a leak), and the total rise in bytes, from the window's last
+#: program load on, that makes the pattern worth reporting.  Read at
+#: TPC-H SF=1 on a v5e host (PERF.md, PR 32): a settled server's RSS
+#: moves 1-2 MiB in 40 s of the dash or the stream, the largest step
+#: that is no program load is 16 connections opening or closing, 52-90
+#: MiB; first answers swing it by 2.5 GB, which is why the rule starts
+#: after them
 HEAP_GROWTH_MIN_POINTS = 4
 HEAP_GROWTH_RISE_FRAC = 0.9
-HEAP_GROWTH_MIN_BYTES = 32 << 20
+HEAP_GROWTH_MIN_BYTES = 128 << 20
 #: hbm-pressure: census share of the backend's exposed device-memory
 #: capacity at which the finding fires (never on backends that expose
 #: no limit — CPU reads bytes_limit 0)
@@ -267,6 +274,28 @@ class InspectionContext:
         pts = self.series(metric)
         return pts[-1][1] - pts[0][1] if len(pts) >= 2 else 0.0
 
+    def settled_since(self) -> Optional[float]:
+        """Timestamp of the window's last sample by which jax had just
+        loaded a program (``tinysql_program_load_seconds_total`` rose: a
+        trace, a lowering or a compile ended): up to there the process
+        was still warming up — replicas prepared, programs traced,
+        compiled and loaded — and its resident set grew by gigabytes no
+        statement should answer for.  None when no program was loaded
+        in the window."""
+        pts = self.series("tinysql_program_load_seconds_total")
+        for i in range(len(pts) - 1, 0, -1):
+            if pts[i][1] > pts[i - 1][1]:
+                return pts[i][0]
+        return None
+
+    def settled_series(self, metric: str) -> List[tuple]:
+        """The metric's points from :meth:`settled_since` on (the whole
+        window when no program was loaded in it): what the memory rules
+        judge."""
+        since = self.settled_since()
+        pts = self.series(metric)
+        return pts if since is None else [p for p in pts if p[0] >= since]
+
     def max_value(self, metric: str) -> float:
         pts = self.series(metric)
         return max(v for _, v in pts) if pts else 0.0
@@ -276,10 +305,12 @@ class InspectionContext:
         return pts[-1][1] if pts else 0.0
 
     def evidence(self, rule: str, item: str, severity: str, details: str,
-                 metric: str) -> Finding:
+                 metric: str,
+                 pts: Optional[List[tuple]] = None) -> Finding:
         """Build a finding whose evidence window is the metric's sampled
-        span."""
-        pts = self.series(metric)
+        span (``pts``: the part of it the rule judged)."""
+        if pts is None:
+            pts = self.series(metric)
         return Finding(
             rule, item, severity, details, metric,
             start_ts=pts[0][0] if pts else 0.0,
@@ -786,11 +817,12 @@ def _rule_slo_burn(ctx: InspectionContext) -> List[Finding]:
 
 @rule("heap-growth")
 def _rule_heap_growth(ctx: InspectionContext) -> List[Finding]:
-    # monotone-rise leak detection over the MEASURED python heap
-    # (obs/memprof.py memory_state): a heap that only goes up, window
-    # after window, is a leak — a working set breathes back down
-    metric = "tinysql_mem_traced_bytes"
-    pts = ctx.series(metric)
+    # monotone-rise leak detection over the MEASURED resident set
+    # (obs/memprof.py memory_state; the traced heap is one site
+    # window's reading, not a level): a process that only goes up,
+    # sample after sample, is a leak — a working set breathes back down
+    metric = "tinysql_mem_rss_bytes"
+    pts = ctx.settled_series(metric)
     if len(pts) < HEAP_GROWTH_MIN_POINTS:
         return []
     rise = pts[-1][1] - pts[0][1]
@@ -802,11 +834,13 @@ def _rule_heap_growth(ctx: InspectionContext) -> List[Finding]:
         return []
     return [ctx.evidence(
         "heap-growth", "heap", "warning",
-        f"traced python heap rose {rise / 1048576.0:.1f} MiB "
-        f"monotonically across {len(pts)} samples in the window "
+        f"resident set rose {rise / 1048576.0:.1f} MiB "
+        f"monotonically across {len(pts)} samples since the last "
+        "program load in the window "
         f"({rises}/{steps} rising steps): leak-shaped growth — "
-        "/debug/heap has the allocation sites holding the bytes",
-        metric)]
+        "/debug/heap has the python allocation sites its site windows "
+        "sampled; numpy's and XLA's host buffers show only in RSS",
+        metric, pts)]
 
 
 @rule("hbm-pressure")
@@ -832,27 +866,30 @@ def _rule_hbm_pressure(ctx: InspectionContext) -> List[Finding]:
 
 @rule("mem-untracked")
 def _rule_mem_untracked(ctx: InspectionContext) -> List[Finding]:
-    # measured-vs-tracked divergence: windowed MEASURED heap growth
-    # beyond everything the MemTracker ledger ever held in the window.
-    # Deltas, not absolutes — the absolute traced number includes the
-    # interpreter baseline no statement should answer for.  The band
-    # (obs/memprof.UNTRACKED_BAND_BYTES) is the documented tolerance.
+    # measured-vs-tracked divergence: windowed MEASURED resident-set
+    # growth beyond everything the MemTracker ledger ever held in the
+    # window.  Deltas, not absolutes — the absolute number includes the
+    # interpreter and the runtime no statement should answer for.  The
+    # band (obs/memprof.UNTRACKED_BAND_BYTES) is the documented tolerance.
     from .memprof import UNTRACKED_BAND_BYTES
-    metric = "tinysql_mem_traced_bytes"
-    d_traced = ctx.delta(metric)
-    tracked_peak = ctx.max_value("tinysql_mem_tracked_bytes")
-    over = d_traced - tracked_peak - UNTRACKED_BAND_BYTES
+    metric = "tinysql_mem_rss_bytes"
+    pts = ctx.settled_series(metric)
+    d_rss = pts[-1][1] - pts[0][1] if len(pts) >= 2 else 0.0
+    tracked_peak = max((v for _, v in ctx.settled_series(
+        "tinysql_mem_tracked_bytes")), default=0.0)
+    over = d_rss - tracked_peak - UNTRACKED_BAND_BYTES
     if over <= 0:
         return []
     return [ctx.evidence(
         "mem-untracked", "ledger", "warning",
-        f"measured heap grew {d_traced / 1048576.0:.1f} MiB in the "
-        "window while the statement MemTracker ledger peaked at "
+        f"resident set grew {d_rss / 1048576.0:.1f} MiB since the "
+        "last program load in the window while the statement MemTracker "
+        "ledger peaked at "
         f"{tracked_peak / 1048576.0:.1f} MiB — "
         f"{over / 1048576.0:.1f} MiB past the "
         f"{UNTRACKED_BAND_BYTES >> 20} MiB band is allocation the "
         "spill/admission gates cannot see (operator working state "
-        "missing its tracker charge)", metric)]
+        "missing its tracker charge)", metric, pts)]
 
 
 # ---- evaluation -----------------------------------------------------------

@@ -14,17 +14,20 @@ plus one reconciler:
 1. **Host heap** (:class:`HeapProfiler` + :class:`MemprofSampler`): a
    tracemalloc-based sampling profiler following conprof's exact design
    — a background sampler on the server lifecycle paced by the GLOBAL
-   ``tidb_memprof_rate`` sysvar (Hz, 0 = off, re-read live every tick),
-   folding the top allocation SITES (``file:lineno`` chains) into
+   ``tidb_memprof_rate`` sysvar (Hz, 0 = off, re-read live every tick).
+   tracemalloc is ON ONLY INSIDE SHORT SITE WINDOWS (``WINDOW_S`` of
+   tracing, one snapshot, tracing off again) and never between them: a
+   window folds the top allocation SITES (``file:lineno`` chains) of
+   what was allocated inside it and still lives at its end into
    bounded per-window aggregates with the stmtsummary/conprof
-   rotation/eviction/tombstone semantics, classifying each site by
+   rotation/eviction/tombstone semantics, classifies each site by
    serving ROLE (matched against live thread stacks through the
-   conprof thread-name vocabulary), and attributing each tick's
-   positive traced-heap delta to the statements currently EXECUTING
+   conprof thread-name vocabulary), and attributes the window's
+   positive traced growth to the statements currently EXECUTING
    (resolved through the interrupt registry) — so
    ``statements_summary`` gains ``sum_heap_alloc_kb`` / ``max_heap_kb``
-   columns, all under the same hard <3% self-cost budget and backoff
-   divisor conprof runs under.
+   columns, all under the same hard <3% budget conprof runs under,
+   with every traced second charged to it.
 2. **Device HBM census** (:func:`hbm_census`): a
    ``jax.live_arrays()``-walking snapshot classifier that attributes
    every live device buffer to its birth site — replica-memoized
@@ -46,11 +49,17 @@ plus one reconciler:
 Semantics and honesty notes (the blind-spot contract, documented like
 ISSUE 16's ``np.ascontiguousarray`` caveat):
 
+- the heap profile is SAMPLED IN TIME, as conprof's CPU profile is: a
+  site, a traced byte or a statement's heap growth is seen only if it
+  happened inside a site window (at most ``OVERHEAD_BUDGET_FRAC`` of
+  the wall), and the traced numbers are ONE WINDOW'S — what was
+  allocated inside it and still lived at its end — not a census of the
+  process's history.  What the process holds in all is RSS's to say.
 - tracemalloc sees PYTHON allocations only.  XLA's C++ device arena,
-  numpy buffers allocated before ``tracemalloc.start()``, and any
-  malloc outside the CPython allocator are invisible to the traced
-  number — that is exactly why RSS and the HBM census ride alongside
-  it in ``memory_state`` instead of one number pretending to be truth.
+  numpy buffers allocated outside a window, and any malloc outside the
+  CPython allocator are invisible to the traced number — that is
+  exactly why RSS and the HBM census ride alongside it in
+  ``memory_state`` instead of one number pretending to be truth.
 - allocation sites carry ``file:lineno`` chains, NOT thread identity —
   tracemalloc drops the allocating thread.  Role classification is
   therefore best-effort: a site is attributed to a role when one of
@@ -58,18 +67,23 @@ ISSUE 16's ``np.ascontiguousarray`` caveat):
   (call-site ``(file, lineno)`` pairs match exactly between a
   traceback and a suspended frame); sites whose allocation path is no
   longer on any stack read ``other``.
-- statement attribution splits each tick's POSITIVE traced-heap delta
-  evenly among the statements executing at that instant, so the sum of
+- statement attribution splits each window's POSITIVE traced growth
+  evenly among the statements executing at its end, so the sum of
   ``sum_heap_alloc_kb`` across concurrent statements can never exceed
-  the process's measured heap growth (the heap analogue of conprof's
-  ``cpu <= wall`` cap); ``max_heap_kb`` is the traced-heap high water
-  observed while the statement ran — an upper bound, process-wide by
-  construction.
-- the sampler's self-cost is measured every tick; past
-  ``OVERHEAD_BUDGET_FRAC`` of one core the ``backoff`` divisor doubles
-  (conprof's exact hysteresis) — the profiler may get coarser under
-  load, never expensive.  ``tidb_memprof_rate = 0`` costs one sysvar
-  read per idle slice and leaves every surface byte-identical.
+  the growth measured in that window (the heap analogue of conprof's
+  ``cpu <= wall`` cap); ``max_heap_kb`` is the largest window reading
+  observed while the statement ran — process-wide by construction.
+- the budget is charged IN FULL: while a window is open every Python
+  allocation of every thread records a traceback, so a window costs
+  its whole traced wall (everybody pays) plus the sampler's fold.  No
+  window opens before the last one's cost is ``OVERHEAD_BUDGET_FRAC``
+  of the wall since it opened: that one rule paces the windows, and
+  holds the share by construction.  The ``backoff`` divisor is
+  conprof's, on the sampler's own work (snapshot and fold) against the
+  tick period, and only thins the ticks — the profiler may get coarser
+  under load, never expensive.
+  ``tidb_memprof_rate = 0`` costs one sysvar read per idle slice,
+  opens no window and leaves every surface byte-identical.
 
 WRITE DISCIPLINE (qlint OB407): the fold/attribution state here — and
 the statement heap/HBM counters (``heap_kb`` / ``heap_peak_kb`` /
@@ -100,27 +114,39 @@ DEFAULT_MAX_SITES = 256
 MAX_RATE_HZ = 50
 
 #: tracemalloc frames kept per allocation site (tracemalloc.start
-#: depth; deeper costs every allocation in the process, not just ticks)
+#: depth; deeper costs every allocation made while a window is open)
 MAX_SITE_DEPTH = 12
+
+#: seconds a site window waits, tracing on, while the serving threads
+#: allocate under it.  A statement that falls into a window is late by
+#: at most the window; with the sampler's wake-up (it waits for the GIL:
+#: 5 ms and more under load) and the snapshot a window costs 16-25 ms,
+#: so at the default 1 Hz every tick can afford one, with room
+WINDOW_S = 0.010
 
 #: top allocation sites (by live size) folded per tick — the window
 #: aggregates the union across ticks, so the cap bounds tick cost, not
 #: coverage
 TOP_SITES_PER_TICK = 64
 
-#: the sampler's self-cost budget as a fraction of one core; past it
-#: the backoff divisor doubles (mem analogue of conprof's rule)
+#: the profiler's budget: the share of the wall that may run traced
+#: (every thread pays the tax then) or folding.  No window opens before
+#: the last one's cost is this share of the wall since it opened; the
+#: backoff divisor doubles when the sampler's own work (snapshot and
+#: fold) passes this share of the tick period (conprof's rule verbatim)
 OVERHEAD_BUDGET_FRAC = 0.03
 BACKOFF_MAX = 16
 
 EVICTED_SITE = "(evicted)"
 
-#: band for the mem-untracked reconciliation (obs/inspect.py): windowed
-#: traced-heap growth may run this far past the MemTracker ledger
-#: before the divergence is a finding — interpreter caches, compiled
-#: program metadata, and obs stores all legitimately allocate outside
-#: the statement ledger
-UNTRACKED_BAND_BYTES = 64 << 20
+#: band for the mem-untracked reconciliation (obs/inspect.py): RSS
+#: growth since the window's last program load may run this far past
+#: the MemTracker ledger before the divergence is a finding —
+#: connections, interpreter caches and obs stores all legitimately
+#: allocate outside the statement ledger, and RSS counts them by the
+#: page (16 connections are 52-90 MiB at TPC-H SF=1; a settled server
+#: otherwise moves 1-2 MiB in 40 s: PERF.md, PR 32)
+UNTRACKED_BAND_BYTES = 256 << 20
 
 
 def fold_site(frames: Iterable[Tuple[str, int]],
@@ -192,15 +218,25 @@ class _SiteAgg:
         self.last_seen = max(self.last_seen, other.last_seen)
 
 
+def _fresh_stats() -> Dict[str, float]:
+    return {"ticks": 0, "sites": 0, "attributed": 0, "self_s": 0.0,
+            "evicted": 0, "errors": 0, "traced_kb": 0.0,
+            "traced_peak_kb": 0.0, "site_windows": 0, "traced_s": 0.0,
+            "snapshot_s": 0.0}
+
+
 class HeapProfiler:
     """The fold/attribution store: current window + bounded rotated
     history, conprof-style.  Written from the sampler thread; read from
     any session scanning ``memory_usage`` or hitting ``/debug/heap`` —
-    all paths take the lock."""
+    all paths take the lock.  ("Window" alone is the AGGREGATION window
+    of ``tidb_memprof_window`` seconds, as in conprof; a SITE window is
+    the ``WINDOW_S`` for which tracemalloc is on.)"""
 
     def __init__(self, window_s: float = DEFAULT_WINDOW_S,
                  history: int = DEFAULT_HISTORY,
-                 max_sites: int = DEFAULT_MAX_SITES):
+                 max_sites: int = DEFAULT_MAX_SITES,
+                 clock: Callable[[], float] = time.perf_counter):
         self.window_s = float(window_s)
         self.max_history = int(history)
         self.max_sites = int(max_sites)
@@ -214,48 +250,106 @@ class HeapProfiler:
         #: adaptive rate divisor: effective period = backoff / rate
         self.backoff = 1
         self._cost_ewma = 0.0
-        #: traced-heap KB at the previous tick (attribution baseline);
-        #: None = no baseline (first tick / tracing restarted)
-        self._last_traced_kb: Optional[float] = None
-        self._stats = {"ticks": 0, "sites": 0, "attributed": 0,
-                       "self_s": 0.0, "evicted": 0, "errors": 0,
-                       "traced_kb": 0.0, "traced_peak_kb": 0.0}
+        #: what the budget is timed on (injectable: the pacing tests
+        #: run on a clock of their own)
+        self._clock = clock
+        #: no site window opens before this reading of the clock: the
+        #: last one's opening plus its cost / OVERHEAD_BUDGET_FRAC
+        self._window_due = 0.0
+        self._stats = _fresh_stats()
 
     # ---- the designated write path (sampler thread ONLY) ----------------
+    def tick(self, period_s: float,
+             wait: Callable[[float], object] = time.sleep,
+             **fold) -> int:
+        """One sampler tick.  A tick by itself counts itself and no
+        more; when the budget has paid for the last site window it opens
+        the next one.  Returns the number of sites folded."""
+        if self._clock() >= self._window_due:
+            return self.sample_window(period_s, wait, **fold)
+        with self._mu:
+            self._stats["ticks"] += 1
+        return 0
+
+    def sample_window(self, period_s: float,
+                      wait: Callable[[float], object] = time.sleep,
+                      **fold) -> int:
+        """One site window: tracing on, ``wait(WINDOW_S)`` while the
+        serving threads allocate under it (truthy = shutting down: fold
+        nothing), one snapshot of what was allocated inside the window
+        and still lives, tracing OFF — whatever was raised — and only
+        then the fold and the attribution of the window's growth
+        (:meth:`sample_once`).  The whole traced wall plus the fold is
+        charged to the budget, which alone decides when the next window
+        may open.  Tracing someone else started (a test,
+        ``QueryMemProbe``) is read and left on."""
+        t_open = self._clock()
+        ours = not tracemalloc.is_tracing()
+        snap, traced_s, snapshot_s = None, 0.0, 0.0
+        try:
+            with process_span("memprof.window", cat="background"):
+                if ours:
+                    tracemalloc.start(MAX_SITE_DEPTH)
+                try:
+                    base = tracemalloc.get_traced_memory()[0]
+                    if not wait(WINDOW_S):
+                        t0 = time.perf_counter()
+                        # the sampler's own work, as the fold is: the
+                        # rest of the window is its sleep
+                        with process_span("bg.memprof", cat="background"):
+                            snap = tracemalloc.take_snapshot()
+                            cur, peak = tracemalloc.get_traced_memory()
+                        snapshot_s = time.perf_counter() - t0
+                finally:
+                    if ours:
+                        tracemalloc.stop()
+                    traced_s = self._clock() - t_open
+            if snap is None:
+                return 0
+            with process_span("bg.memprof", cat="background"):
+                return self.sample_once(
+                    period_s, stats=self._snapshot_sites(snap),
+                    traced_kb=cur / 1024.0, traced_peak_kb=peak / 1024.0,
+                    growth_kb=(cur - base) / 1024.0,
+                    snapshot_s=snapshot_s, **fold)
+        finally:
+            with self._mu:
+                self._stats["site_windows"] += 1
+                self._stats["traced_s"] += traced_s
+            # whatever was raised, the window is paid for before the next
+            self._window_due = t_open \
+                + (self._clock() - t_open) / OVERHEAD_BUDGET_FRAC
+
     def sample_once(self, period_s: float, now: Optional[float] = None,
-                    stats: Optional[List[tuple]] = None,
+                    stats: Iterable[tuple] = (),
                     frames: Optional[Dict[int, object]] = None,
-                    traced_kb: Optional[float] = None,
+                    traced_kb: float = 0.0,
                     traced_peak_kb: Optional[float] = None,
+                    growth_kb: float = 0.0,
                     hbm_bytes: Optional[float] = None,
                     window_s: Optional[float] = None,
                     history: Optional[int] = None,
                     max_sites: Optional[int] = None,
                     skip_idents: Tuple[int, ...] = (),
-                    attribute: bool = True) -> int:
-        """One sampling tick: snapshot the traced heap, fold the top
-        allocation sites, attribute the tick's positive traced-heap
-        delta to executing statements.  ``now``/``stats``/``frames``/
-        ``traced_kb`` are injectable for deterministic tests (``stats``
-        is ``[(frames root->leaf as (file, lineno) tuples, size_bytes),
-        ...]``); the ``window_s``/``history``/``max_sites`` overrides
-        carry the live sysvars.  ``attribute=False`` folds only — the
-        overhead probe's back-to-back ticks must never write statement
-        heap.  Returns the number of sites folded."""
+                    attribute: bool = True,
+                    snapshot_s: float = 0.0) -> int:
+        """One fold: a site window's top allocation sites (``stats``:
+        ``[(frames root->leaf as (file, lineno) tuples, size_bytes),
+        ...]``) into the store, its positive traced growth
+        (``growth_kb``: what was allocated inside it and lived at its
+        end) to the executing statements, ``traced_kb`` riding along as
+        their high-water mark.  ``now`` and ``frames`` are injectable
+        for deterministic tests; the ``window_s``/``history``/
+        ``max_sites`` overrides carry the live sysvars.
+        ``attribute=False`` folds only — the overhead probe's
+        back-to-back folds must never write statement heap.
+        ``snapshot_s`` is what the window's snapshot took: the
+        sampler's own work with the fold.  Returns the number of sites
+        folded."""
         t0 = time.perf_counter()
         fail.inject("memprofSampleError")
         if now is None:
             now = time.time()
-        if stats is None:
-            stats = self._snapshot_sites()
-        if traced_kb is None:
-            if tracemalloc.is_tracing():
-                cur, peak = tracemalloc.get_traced_memory()
-                traced_kb = cur / 1024.0
-                if traced_peak_kb is None:
-                    traced_peak_kb = peak / 1024.0
-            else:
-                traced_kb = 0.0
         if traced_peak_kb is None:
             traced_peak_kb = traced_kb
         if hbm_bytes is None:
@@ -272,31 +366,23 @@ class HeapProfiler:
                        window_s=window_s, history=history,
                        max_sites=max_sites)
             n += 1
-        delta_kb = 0.0
-        if self._last_traced_kb is not None:
-            delta_kb = traced_kb - self._last_traced_kb
-        self._last_traced_kb = traced_kb
-        if attribute and delta_kb > 0:
-            self._attribute(delta_kb, traced_kb, hbm_bytes)
+        if attribute and growth_kb > 0:
+            self._attribute(growth_kb, traced_kb, hbm_bytes)
         wall = time.perf_counter() - t0
         with self._mu:
             self._stats["ticks"] += 1
-            self._stats["self_s"] += wall
+            self._stats["self_s"] += snapshot_s + wall
+            self._stats["snapshot_s"] += snapshot_s
+            # the last reading, not a high water: one window's
             self._stats["traced_kb"] = traced_kb
-            if traced_peak_kb > self._stats["traced_peak_kb"]:
-                self._stats["traced_peak_kb"] = traced_peak_kb
-        self._note_cost(wall, period_s)
+            self._stats["traced_peak_kb"] = traced_peak_kb
+        self._note_cost(snapshot_s + wall, period_s)
         return n
 
     @staticmethod
-    def _snapshot_sites() -> List[tuple]:
-        """Live top-N allocation sites as ``[(frames root->leaf,
-        size_bytes), ...]`` — empty when tracemalloc is off (the
-        sampler starts it; a bare profiler without tracing still ticks,
-        it just has no sites to fold)."""
-        if not tracemalloc.is_tracing():
-            return []
-        snap = tracemalloc.take_snapshot()
+    def _snapshot_sites(snap: tracemalloc.Snapshot) -> List[tuple]:
+        """Top-N allocation sites of a site window's snapshot as
+        ``[(frames root->leaf, size_bytes), ...]``."""
         try:
             snap = snap.filter_traces((
                 tracemalloc.Filter(False, tracemalloc.__file__),))
@@ -357,12 +443,12 @@ class HeapProfiler:
 
     def _attribute(self, delta_kb: float, traced_kb: float,
                    hbm_bytes: float) -> None:
-        """Split this tick's positive traced-heap growth evenly among
-        the executing statements — each share is <= the total growth,
-        so the sum of per-statement heap attribution can never exceed
-        the process's measured allocation (the <=-growth invariant,
-        tested).  The traced high water and the HBM census total ride
-        along as high-water marks."""
+        """Split this window's positive traced growth evenly among the
+        executing statements — each share is <= the total growth, so
+        the sum of per-statement heap attribution can never exceed what
+        the window measured (the <=-growth invariant, tested).  The
+        window's traced reading and the HBM census total ride along as
+        high-water marks."""
         try:
             scopes = self._statement_scopes()
             if not scopes:
@@ -415,10 +501,11 @@ class HeapProfiler:
             self._stats["errors"] += 1
 
     def _note_cost(self, tick_wall_s: float, period_s: float) -> None:
-        """conprof's adaptive overhead control verbatim: EWMA the
-        per-tick self cost; past the budget share of one core the
-        backoff divisor doubles, stepping back down only with
-        hysteresis."""
+        """conprof's adaptive overhead control verbatim, on the
+        sampler's own work (a window's snapshot and fold; when a window
+        opens is the budget's, in :meth:`sample_window`): EWMA it; past
+        the budget share of the tick period the backoff divisor
+        doubles, stepping back down only with hysteresis."""
         with self._mu:
             self._cost_ewma = tick_wall_s if self._cost_ewma == 0.0 \
                 else 0.8 * self._cost_ewma + 0.2 * tick_wall_s
@@ -486,10 +573,8 @@ class HeapProfiler:
             self.window_begin = None
             self.backoff = 1
             self._cost_ewma = 0.0
-            self._last_traced_kb = None
-            self._stats = {"ticks": 0, "sites": 0, "attributed": 0,
-                           "self_s": 0.0, "evicted": 0, "errors": 0,
-                           "traced_kb": 0.0, "traced_peak_kb": 0.0}
+            self._window_due = 0.0
+            self._stats = _fresh_stats()
 
 
 #: the process-global profiler every surface reads
@@ -511,41 +596,40 @@ def reset() -> None:
 
 def measure_overhead(n: int = 20,
                      rate_hz: int = DEFAULT_RATE_HZ) -> Dict[str, float]:
-    """The heap profiler's steady-state cost, THE definition both
-    benches publish as ``memprof_overhead_frac`` when no live sampler
-    ran: one tick's wall (averaged over ``n`` live snapshots of THIS
-    process) times the ticks-per-second at ``rate_hz``.  Probes a
-    PRIVATE HeapProfiler so the measurement never pollutes the live
-    store; starts tracemalloc only if it was off, and stops it again."""
+    """The heap profiler's steady-state cost, charged in full: one site
+    window's traced wall plus its fold (averaged over ``n`` windows of
+    THIS process) times the windows a second ``rate_hz`` asks for.
+    Probes a PRIVATE HeapProfiler so the measurement never pollutes the
+    live store, and never attributes: back-to-back probe windows must
+    not fabricate statement heap growth."""
     prof = HeapProfiler()
     period = 1.0 / max(rate_hz, 1)
-    started = False
-    if not tracemalloc.is_tracing():
-        tracemalloc.start(MAX_SITE_DEPTH)
-        started = True
-    try:
-        # attribute=False: back-to-back probe ticks must not fabricate
-        # statement heap growth
-        prof.sample_once(period, attribute=False)  # warm lazy imports
-        t0 = time.perf_counter()
-        for _ in range(n):
-            prof.sample_once(period, attribute=False)
-        per_tick_s = (time.perf_counter() - t0) / n
-    finally:
-        if started:
-            tracemalloc.stop()
+    prof.sample_window(period, attribute=False)  # warm lazy imports
+    before = _charged_s(prof.stats_snapshot())
+    for _ in range(n):
+        prof.sample_window(period, attribute=False)
+    per_tick_s = (_charged_s(prof.stats_snapshot()) - before) / n
     return {"tick_wall_s": round(per_tick_s, 6), "rate_hz": rate_hz,
             "memprof_overhead_frac": round(per_tick_s * rate_hz, 6)}
+
+
+def _charged_s(stats: Dict[str, float]) -> float:
+    """What the budget has been charged: the traced seconds, which
+    every thread pays, and the sampler's own work — whose snapshots
+    are inside the traced seconds, and count once."""
+    return float(stats.get("traced_s", 0.0)) \
+        + float(stats.get("self_s", 0.0)) \
+        - float(stats.get("snapshot_s", 0.0))
 
 
 def live_overhead_frac(stats_before: Dict[str, float],
                        stats_after: Dict[str, float],
                        wall_s: float) -> float:
-    """Sampler self-cost over a measured live window: the delta of the
-    profiler's own accumulated tick wall divided by the elapsed wall,
-    to hold against the 3% budget (as conprof's)."""
-    d = float(stats_after.get("self_s", 0.0)) \
-        - float(stats_before.get("self_s", 0.0))
+    """The profiler's cost over a measured live window, charged in
+    full: the growth of the traced seconds (everybody's tax) and of the
+    sampler's own fold wall, divided by the elapsed wall, to hold
+    against the 3% budget (as conprof's)."""
+    d = _charged_s(stats_after) - _charged_s(stats_before)
     return round(d / max(wall_s, 1e-9), 6)
 
 
@@ -722,16 +806,13 @@ def tracked_bytes() -> float:
 
 def memory_state() -> Dict[str, float]:
     """The ``memory_state`` time-series source: tracked-ledger bytes vs
-    measured heap (tracemalloc) / RSS vs the HBM census, plus the
-    sampler's self-accounting — everything the heap-growth /
+    RSS vs the last site window's traced reading vs the HBM census,
+    plus the sampler's self-accounting — everything the heap-growth /
     hbm-pressure / mem-untracked inspection rules judge."""
-    if tracemalloc.is_tracing():
-        cur, peak = tracemalloc.get_traced_memory()
-    else:
-        cur, peak = 0, 0
     tracked = tracked_bytes()
     census = hbm_census()
     s = PROF.stats_snapshot()
+    cur, peak = _last_traced_bytes(s)
     return {
         "tinysql_mem_tracked_bytes": tracked,
         "tinysql_mem_traced_bytes": float(cur),
@@ -750,7 +831,17 @@ def memory_state() -> Dict[str, float]:
         "tinysql_memprof_evicted_total": s.get("evicted", 0),
         "tinysql_memprof_errors_total": s.get("errors", 0),
         "tinysql_memprof_backoff": s.get("backoff", 1),
+        "tinysql_memprof_windows_total": s.get("site_windows", 0),
+        "tinysql_memprof_traced_seconds_total": s.get("traced_s", 0.0),
     }
+
+
+def _last_traced_bytes(stats: Dict[str, float]) -> Tuple[int, int]:
+    """(current, peak) traced bytes of the last site window: what was
+    allocated inside it and lived at its end, and the most that lived
+    at once inside it.  0 until a window has run."""
+    return (int(stats.get("traced_kb", 0.0) * 1024),
+            int(stats.get("traced_peak_kb", 0.0) * 1024))
 
 
 #: information_schema.memory_usage column order — MUST match
@@ -766,10 +857,7 @@ def memory_usage_rows() -> List[list]:
     measurement / census bucket, reconciliation last — so ``SELECT *
     FROM information_schema.memory_usage`` answers "where is the
     memory, and does the ledger agree" in one scan."""
-    if tracemalloc.is_tracing():
-        cur, peak = tracemalloc.get_traced_memory()
-    else:
-        cur, peak = 0, 0
+    cur, peak = _last_traced_bytes(PROF.stats_snapshot())
     tracked = int(tracked_bytes())
     census = hbm_census()
     rows: List[list] = [
@@ -777,10 +865,11 @@ def memory_usage_rows() -> List[list]:
          "sum of live statement MemTracker bytes (the ledger; "
          "processlist mem_bytes)"],
         ["measured", "traced_heap", int(cur),
-         "tracemalloc current traced bytes (python allocations only — "
-         "XLA's C++ arena is invisible here)"],
+         "python bytes allocated inside the last site window and live "
+         "at its end (tracemalloc; sampled in time — XLA's C++ arena "
+         "and everything older than the window are invisible here)"],
         ["measured", "traced_peak", int(peak),
-         "tracemalloc peak traced bytes since tracing started"],
+         "most python bytes live at once inside the last site window"],
         ["measured", "rss", int(_rss_bytes()),
          "resident set size (/proc/self/statm)"],
     ]
@@ -794,9 +883,11 @@ def memory_usage_rows() -> List[list]:
                  "buffer(s) no registered owner claims — the leak "
                  "bucket"])
     rows.append(["recon", "untracked", max(0, int(cur) - tracked),
-                 "traced heap beyond the MemTracker ledger; the "
-                 f"mem-untracked rule fires past a {UNTRACKED_BAND_BYTES >> 20}"
-                 " MiB windowed band"])
+                 "the last site window's traced heap beyond the "
+                 "MemTracker ledger: one 10 ms window's reading, near 0 "
+                 "at rest; the mem-untracked rule judges RSS growth "
+                 "less the ledger past a "
+                 f"{UNTRACKED_BAND_BYTES >> 20} MiB band"])
     return rows
 
 
@@ -844,21 +935,22 @@ class QueryMemProbe:
 # ---- the background sampler (server lifecycle) ---------------------------
 
 class MemprofSampler:
-    """Background thread pacing ``PROF.sample_once`` by the GLOBAL
+    """Background thread pacing ``PROF.tick`` by the GLOBAL
     ``tidb_memprof_rate`` sysvar (Hz; re-read every tick like the
     conprof/tsring samplers — 0 pauses sampling at the cost of ONE
-    sysvar read per idle slice).  Starts tracemalloc on first demand
-    and stops it again when the rate drops to 0 (tracing taxes every
-    allocation in the process, so off must mean OFF).  The effective
-    period is ``backoff / rate``: the profiler's own overhead control
-    stretches it when a snapshot costs too much."""
+    sysvar read per idle slice).  tracemalloc taxes every allocation in
+    the process, so it is on only inside a site window
+    (:meth:`HeapProfiler.sample_window`) and never between two: off
+    means OFF, and on means at most ``OVERHEAD_BUDGET_FRAC`` of the
+    wall.  The effective period is ``backoff / rate``: the profiler's
+    own overhead control stretches it when a tick's own work (snapshot
+    and fold) costs too much; when a window opens is the budget's."""
 
     def __init__(self, storage, profiler: Optional[HeapProfiler] = None):
         self.storage = storage
         self.profiler = profiler if profiler is not None else PROF
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
-        self._started_tracing = False
         #: start/close lifecycle lock (the tsring Sampler discipline)
         self._mu = threading.Lock()
 
@@ -880,6 +972,8 @@ class MemprofSampler:
             self._thread.start()
 
     def close(self) -> None:
+        # a window that is open cuts its wait short on the event and
+        # stops tracing on its way out
         with self._mu:
             self._stop.set()
             t = self._thread
@@ -888,16 +982,6 @@ class MemprofSampler:
         with self._mu:
             if self._thread is t:
                 self._thread = None
-        self._stop_tracing()
-
-    def _stop_tracing(self) -> None:
-        with self._mu:
-            started, self._started_tracing = self._started_tracing, False
-        if started and tracemalloc.is_tracing():
-            tracemalloc.stop()
-            # baseline is gone with the traces: the next tick must not
-            # read a restart as a huge negative (or positive) delta
-            self.profiler._last_traced_kb = None
 
     def _loop(self) -> None:
         elapsed = 0.0
@@ -905,16 +989,11 @@ class MemprofSampler:
             rate = self.rate_hz()
             if rate <= 0:
                 # disabled: ONE sysvar read per slice, nothing else —
-                # and no tracemalloc tax on the allocator
-                self._stop_tracing()
+                # no window opens, so no tracemalloc tax on the allocator
                 if self._stop.wait(0.25):
                     return
                 elapsed = 0.0
                 continue
-            if not tracemalloc.is_tracing():
-                tracemalloc.start(MAX_SITE_DEPTH)
-                with self._mu:
-                    self._started_tracing = True
             rate = min(rate, MAX_RATE_HZ)
             period = self.profiler.backoff / rate
             slice_s = min(period, 0.25)
@@ -925,20 +1004,19 @@ class MemprofSampler:
                 continue
             elapsed = 0.0
             try:
-                with process_span("bg.memprof", cat="background"):
-                    self.profiler.sample_once(
-                        period,
-                        window_s=self._int_sysvar("tidb_memprof_window",
-                                                  DEFAULT_WINDOW_S),
-                        history=self._int_sysvar("tidb_memprof_history",
-                                                 DEFAULT_HISTORY),
-                        max_sites=self._int_sysvar(
-                            "tidb_memprof_max_sites", DEFAULT_MAX_SITES),
-                        skip_idents=(threading.get_ident(),))
+                self.profiler.tick(
+                    period, wait=self._stop.wait,
+                    window_s=self._int_sysvar("tidb_memprof_window",
+                                              DEFAULT_WINDOW_S),
+                    history=self._int_sysvar("tidb_memprof_history",
+                                             DEFAULT_HISTORY),
+                    max_sites=self._int_sysvar(
+                        "tidb_memprof_max_sites", DEFAULT_MAX_SITES),
+                    skip_idents=(threading.get_ident(),))
             except Exception:
                 # a torn snapshot (or an armed memprofSampleError) must
                 # never kill the sampler thread — counted, logged, the
-                # next tick runs clean
+                # next tick runs clean, and tracing is already off
                 self.profiler.note_error()
                 import logging
                 logging.getLogger("tinysql_tpu.memprof").warning(

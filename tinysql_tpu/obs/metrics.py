@@ -119,6 +119,10 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "tinysql_compile_seconds_total":
         ("counter", "Summed program-build wall seconds (inclusive of "
                     "nested builds, like the compile spans)"),
+    "tinysql_program_load_seconds_total":
+        ("counter", "Seconds jax spent tracing, lowering and compiling "
+                    "programs or loading them from its cache (its own "
+                    "duration events; rises when a phase ends)"),
     "tinysql_pending_cost_analyses":
         ("gauge", "Deferred XLA cost analyses awaiting resolution "
                   "(drained by the tsring sampler tick / bench; "
@@ -282,16 +286,23 @@ METRICS: Dict[str, Tuple[str, str]] = {
         ("gauge", "Retained profile windows (current + rotated)"),
     # continuous heap profiler (obs/memprof.py)
     "tinysql_memprof_ticks_total":
-        ("counter", "Heap-profiler sampling ticks (tracemalloc "
-                    "snapshots taken)"),
+        ("counter", "Heap-profiler sampler ticks (with or without a "
+                    "site window)"),
     "tinysql_memprof_sites_total":
         ("counter", "Allocation sites folded by the heap profiler"),
     "tinysql_memprof_attributed_total":
-        ("counter", "Statement attributions of traced-heap growth "
-                    "(statements_summary sum_heap_alloc_kb)"),
+        ("counter", "Statement attributions of a site window's traced "
+                    "growth (statements_summary sum_heap_alloc_kb)"),
     "tinysql_memprof_self_seconds_total":
         ("counter", "Wall seconds the heap profiler spent snapshotting "
-                    "and folding (its own overhead)"),
+                    "and folding site windows (its own work)"),
+    "tinysql_memprof_windows_total":
+        ("counter", "Site windows opened: tracemalloc on for a few "
+                    "milliseconds, one snapshot, off again"),
+    "tinysql_memprof_traced_seconds_total":
+        ("counter", "Wall seconds tracemalloc was on (inside site "
+                    "windows): every allocation of every thread is "
+                    "taxed then; held to 3% of the wall"),
     "tinysql_memprof_evicted_total":
         ("counter", "Allocation sites evicted into the (evicted) "
                     "tombstone by the per-window tidb_memprof_max_sites "
@@ -309,16 +320,19 @@ METRICS: Dict[str, Tuple[str, str]] = {
         ("gauge", "Live statement MemTracker bytes (the ledger the "
                   "spill/admission gates act on)"),
     "tinysql_mem_traced_bytes":
-        ("gauge", "Measured python heap (tracemalloc current traced "
-                  "bytes; 0 when tracing is off)"),
+        ("gauge", "Python bytes allocated inside the last site window "
+                  "and live at its end (tracemalloc, sampled in time; "
+                  "0 until a window has run)"),
     "tinysql_mem_traced_peak_bytes":
-        ("gauge", "Measured python heap high water since tracing "
-                  "started"),
+        ("gauge", "Most python bytes live at once inside the last "
+                  "site window"),
     "tinysql_mem_rss_bytes":
         ("gauge", "Process resident set size (/proc/self/statm)"),
     "tinysql_mem_untracked_bytes":
-        ("gauge", "Measured heap beyond the MemTracker ledger (the "
-                  "mem-untracked rule's divergence)"),
+        ("gauge", "The last site window's traced heap beyond the "
+                  "MemTracker ledger: one 10 ms window's reading, near "
+                  "0 at rest, and not the divergence the mem-untracked "
+                  "rule judges (RSS growth less the ledger)"),
     "tinysql_hbm_live_bytes":
         ("gauge", "Total bytes of live device buffers (HBM census)"),
     "tinysql_hbm_buffers":
@@ -614,6 +628,10 @@ def render_prometheus() -> str:
         emit("tinysql_compile_seconds_total",
              METRICS["tinysql_compile_seconds_total"][1], "counter",
              [((), pstats.get("compile_wall_s", 0.0))])
+        from .trace import program_load_s
+        emit("tinysql_program_load_seconds_total",
+             METRICS["tinysql_program_load_seconds_total"][1], "counter",
+             [((), program_load_s())])
         emit("tinysql_progcache_misses_total",
              "In-process program-registry misses (program builds)",
              "counter", [((), pstats.get("misses", 0))])
@@ -873,7 +891,11 @@ def render_prometheus() -> str:
                           ("self_s",
                            "tinysql_memprof_self_seconds_total"),
                           ("evicted", "tinysql_memprof_evicted_total"),
-                          ("errors", "tinysql_memprof_errors_total")):
+                          ("errors", "tinysql_memprof_errors_total"),
+                          ("site_windows",
+                           "tinysql_memprof_windows_total"),
+                          ("traced_s",
+                           "tinysql_memprof_traced_seconds_total")):
             emit(name, METRICS[name][1], "counter", [((), mp.get(key, 0))])
         emit("tinysql_memprof_backoff",
              METRICS["tinysql_memprof_backoff"][1], "gauge",

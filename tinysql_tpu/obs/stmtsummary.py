@@ -249,10 +249,11 @@ class StmtRecord:
             # with tidb_conprof_rate=0 or no sampler running)
             round(float(d.get("cpu_s", 0.0)) * 1e3, 3),
             int(d.get("cpu_samples", 0)),
-            # heap truth (obs/memprof.py): traced-heap growth attributed
-            # to these executions (the sum across concurrent statements
-            # never exceeds measured process growth) and the traced high
-            # water while any of them ran (0 with tidb_memprof_rate=0)
+            # heap truth (obs/memprof.py), sampled in time: traced growth
+            # inside site windows attributed to these executions (the sum
+            # across concurrent statements never exceeds what a window
+            # measured) and the largest window reading while any of
+            # them ran (0 with tidb_memprof_rate=0)
             round(float(d.get("heap_kb", 0.0)), 1),
             round(self.max_heap_kb, 1),
             int(d.get("pipe_blocks", 0)), self._overlap_frac(),

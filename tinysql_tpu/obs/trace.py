@@ -177,11 +177,26 @@ def totals() -> Dict[str, dict]:
                 row[1] += sum_s
                 row[2] += self_s
                 row[3] = max(row[3], max_s)
+    out = {name: {"count": t[0], "sum_s": t[1], "self_s": t[2],
+                  "max_s": t[3]} for name, t in rows.items()}
+    # the collector's row last: a collection that this copy's own
+    # allocations set off is in it
     n, sum_s, max_s = _GC
     if n:
-        rows["gc"] = [n, sum_s, sum_s, max_s]
-    return {name: {"count": t[0], "sum_s": t[1], "self_s": t[2],
-                   "max_s": t[3]} for name, t in rows.items()}
+        out["gc"] = {"count": n, "sum_s": sum_s, "self_s": sum_s,
+                     "max_s": max_s}
+    return out
+
+
+def program_load_s() -> float:
+    """Seconds jax has spent tracing, lowering and compiling programs
+    (or loading them from its cache) since the process began: the self
+    times of its three phases.  It rises when a phase ENDS, where
+    ``progcache``'s build wall rises before a jitted function's first
+    call has compiled anything."""
+    rows = totals()
+    return sum(rows[name]["self_s"] for name in JAX_PHASES.values()
+               if name in rows)
 
 
 class Tracer:

@@ -446,8 +446,10 @@ def _src_kernels() -> Dict[str, float]:
 
 def _src_progcache() -> Dict[str, float]:
     from ..ops import progcache
+    from . import trace
     p = progcache.stats_snapshot()
-    return {"tinysql_progcache_hits_total": p.get("hits", 0),
+    return {"tinysql_program_load_seconds_total": trace.program_load_s(),
+            "tinysql_progcache_hits_total": p.get("hits", 0),
             "tinysql_progcache_misses_total": p.get("misses", 0),
             "tinysql_prewarm_seeded_total": p.get("prewarm_seeded", 0),
             "tinysql_prewarm_hits_total": p.get("prewarm_hits", 0),

@@ -206,9 +206,14 @@ DEFAULT_SYSVARS: Dict[str, Datum] = {
     "tidb_conprof_max_stacks": 512,
     # ---- continuous heap profiler (obs/memprof.py; GLOBAL scope — the
     # server's background memory sampler re-reads all four every tick) --
-    # sampling rate in Hz (0 = off AND tracemalloc stopped — tracing
-    # taxes every allocation, so off must mean off; a tracemalloc
-    # snapshot is far pricier than a stack walk, hence the low default)
+    # ticks a second (0 = off: no window, tracing never on).  A tick by
+    # itself is a clock read; when the profiler's 3 % budget has paid for
+    # the last one it opens a SITE WINDOW: tracemalloc on for 10 ms, one
+    # snapshot, off again.  Tracing taxes every allocation of every
+    # thread, so it is off between windows and the budget counts a
+    # window's whole traced wall; every surface it feeds is sampled in
+    # time.  A window is far pricier than a stack walk, hence the low
+    # default
     "tidb_memprof_rate": 1,
     # seconds per aggregation window of the /debug/heap site store
     "tidb_memprof_window": 60,

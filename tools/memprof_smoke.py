@@ -5,24 +5,25 @@ The ISSUE 18 memory-truth loop end to end against a REAL server
 lifecycle:
 
 1. start a Server — its background heap sampler (obs/memprof.py) must
-   tick at the GLOBAL ``tidb_memprof_rate`` and fold non-empty
-   allocation sites while wire clients drive TPC-H load;
+   tick at the GLOBAL ``tidb_memprof_rate``, open site windows inside
+   its budget (tracing off between them) and fold non-empty allocation
+   sites while wire clients drive TPC-H load;
 2. ``/debug/heap`` returns collapsed text the shared parser
    (conprof.parse_collapsed / flamegraph.pl) ingests, covering >= 3
    thread roles from the closed vocabulary;
 3. ``information_schema.memory_usage`` serves the three-source
    reconciliation over SQL (tracked ledger vs measured heap vs HBM
-   census), with the measured invariants intact (traced <= rss;
-   recon/untracked == max(0, traced - tracked));
+   census), with the measured invariants intact (the last window's
+   traced <= rss; recon/untracked == max(0, traced - tracked));
 4. statement heap attribution reaches SQL: at least one of the Q1/Q3/Q6
    digest families shows ``sum_heap_alloc_kb > 0`` in
-   ``statements_summary``, digest-joined, with the per-family sum
-   bounded by the process's measured growth;
+   ``statements_summary``, digest-joined, with each family's sum
+   bounded by what the windows measured;
 5. the device-buffer census attributes every live buffer after the full
    workload — the ``unattributed`` leak bucket reads 0 bytes;
 6. an induced ``heap-growth`` finding: a deliberately leaked list of
-   big allocations across bracketing ring samples must surface the
-   rule in ``information_schema.inspection_result``.
+   big resident allocations across bracketing ring samples must
+   surface the rule in ``information_schema.inspection_result``.
 
 Exit 0 on success; prints one line per check.
 """
@@ -33,6 +34,7 @@ import os
 import sys
 import threading
 import time
+import tracemalloc
 from urllib.request import urlopen
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -74,6 +76,7 @@ def main() -> int:
 
     srv = Server(storage, port=0)
     srv.start()
+    t_served = time.monotonic()
     status = StatusServer(srv)
     sport = status.start()
     try:
@@ -89,7 +92,7 @@ def main() -> int:
         def client(cid: int) -> None:
             try:
                 c = MiniClient(srv.port, db="tpch")
-                for i in range(8):
+                for i in range(24):
                     c.query(queries[(cid + i) % 3])
                 c.close()
             except Exception as e:
@@ -124,6 +127,23 @@ def main() -> int:
               snap["ticks"] > 0 and snap["sites"] > 0,
               f"ticks={snap['ticks']} sites={snap['sites']} "
               f"backoff={snap['backoff']}")
+        served_s = time.monotonic() - t_served
+        # a window may be open at any one instant: look for a second
+        polls = traced_polls = 0
+        poll_dl = time.monotonic() + 1.0
+        while time.monotonic() < poll_dl:
+            polls += 1
+            traced_polls += tracemalloc.is_tracing()
+            pause.wait(0.005)
+        check("site windows opened, inside the budget, tracing off "
+              "between them",
+              snap["site_windows"] > 0
+              and snap["traced_s"] <= memprof.OVERHEAD_BUDGET_FRAC
+              * served_s + 0.1
+              and traced_polls <= 0.1 * polls,
+              f"windows={snap['site_windows']} "
+              f"traced_s={snap['traced_s']:.3f} of {served_s:.1f}s, "
+              f"tracing in {traced_polls} of {polls} polls")
         check("sampler never wedged on errors", snap["errors"] == 0,
               f"errors={snap['errors']}")
 
@@ -153,7 +173,8 @@ def main() -> int:
         rss = by_item[("measured", "rss")]
         tracked = by_item[("tracked", "statements")]
         untracked = by_item[("recon", "untracked")]
-        check("traced python heap <= resident set (blind-spot order)",
+        check("a window's traced python heap <= resident set "
+              "(blind-spot order)",
               0 < traced <= rss, f"traced={traced} rss={rss}")
         check("recon/untracked == max(0, traced - tracked)",
               untracked == max(0, traced - tracked),
@@ -161,9 +182,10 @@ def main() -> int:
               f"tracked={tracked}")
 
         # 4. per-statement heap attribution over SQL, digest-joined:
-        # the sampler splits each tick's measured growth across the
-        # executing statements, so the summed columns stay bounded by
-        # process truth — and at least one hot family caught a tick
+        # the sampler splits each site window's measured growth across
+        # the executing statements, so the summed columns stay bounded
+        # by what the windows read — and at least one hot family
+        # caught a window
         digests = {sql: stmtsummary.normalize(sql)[0]
                    for sql in queries}
         in_list = ", ".join(f"'{d}'" for d in digests.values())
@@ -176,10 +198,13 @@ def main() -> int:
         total_alloc_kb = sum(float(r[1]) for r in rows)
         check("a Q1/Q3/Q6 family carries heap attribution",
               total_alloc_kb > 0, str(rows))
-        traced_peak = by_item[("measured", "traced_peak")]
-        check("summed heap attribution <= measured peak heap",
-              total_alloc_kb <= traced_peak / 1024.0 + 1,
-              f"sum={total_alloc_kb}kb peak={traced_peak}B")
+        # a window's share is at most its reading, and max_heap_kb is
+        # the largest reading a family ran under
+        windows = memprof.stats_snapshot()["site_windows"]
+        check("each family's heap attribution <= windows x its "
+              "largest window reading",
+              all(float(r[1]) <= windows * float(r[2]) + 1
+                  for r in rows), f"windows={windows} rows={rows}")
 
         # 5. the census attributes every live device buffer: after the
         # full workload the leak bucket must be empty (gc first — the
@@ -193,11 +218,16 @@ def main() -> int:
               f"{census['unattributed_buffers']} buffers / "
               f"{census['unattributed_bytes']}B unattributed")
 
-        # 6. induce heap-growth: a leaked list of big allocations across
-        # bracketing ring samples — monotone rise past the rule floor
+        # 6. induce heap-growth: a leaked list of big resident
+        # allocations (written to: untouched pages are not resident)
+        # across bracketing ring samples of their own — a monotone rise
+        # of the resident set past the rule floor
+        from tinysql_tpu.obs import inspect as oinspect
+        step = oinspect.HEAP_GROWTH_MIN_BYTES // 3
+        tsring.RING.reset()
         leak = []
         for _ in range(5):
-            leak.append(bytearray(12 << 20))  # 12 MiB per step
+            leak.append(bytearray(b"\x01") * step)
             tsring.RING.sample_once()
         _, rows = c.query(
             "select rule, item, severity from "
