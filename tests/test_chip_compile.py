@@ -242,15 +242,14 @@ def test_fused_devpipe_program(one_chip, chip_branches, monkeypatch,
 
 # ---- the same three over the four-chip mesh --------------------------------
 
-@pytest.mark.parametrize("name", ["Q1", "Q3", "Q6"])
-def test_fused_mesh_program(topo, chip_branches, monkeypatch, tpch_session,
-                            name):
+def _compile_mesh_statement(topo, monkeypatch, session, sql):
     """Under ``tidb_mesh_parallel = 1`` with the session's mesh built from
     the four described devices: the statement runs up to its first
     dispatch, its lanes "placed" as shapes with their layouts (nothing
     can be put on a described device), and the one fused program —
     row-sharded lanes, per-shard partial states, their merge — compiles
-    for the v5e:2x2 with its collectives in it."""
+    for the v5e:2x2 with its collectives in it.  Returns the compiled
+    program's text."""
     from tinysql_tpu.parallel import dist
     mesh = Mesh(np.array(topo.devices), ("shard",))
     monkeypatch.setattr(dist, "make_mesh", lambda n=None: mesh)
@@ -274,14 +273,14 @@ def test_fused_mesh_program(topo, chip_branches, monkeypatch, tpch_session,
     monkeypatch.setattr(kernels, "counted_jit", capturing_jit)
     monkeypatch.setattr(kernels, "_stackable_jit",
                         lambda fn, *a, **kw: capturing_jit(fn))
-    tpch_session.execute("set @@tidb_mesh_parallel = 1")
-    tpch_session.execute("set @@tidb_tpu_min_rows = 64")
+    session.execute("set @@tidb_mesh_parallel = 1")
+    session.execute("set @@tidb_tpu_min_rows = 64")
     try:
         with pytest.raises(_Captured) as got:
-            tpch_session.query(tpch.QUERIES[name])
+            session.query(sql)
     finally:
-        tpch_session.execute("set @@tidb_mesh_parallel = 0")
-        tpch_session.execute("set @@tidb_tpu_min_rows = 0")
+        session.execute("set @@tidb_mesh_parallel = 0")
+        session.execute("set @@tidb_tpu_min_rows = 0")
         progcache.clear()  # the registry now holds the capturing stand-in
     rows, whole = dist.rows(mesh), dist.whole(mesh)
     leaves = jax.tree_util.tree_leaves(got.value.args)
@@ -291,5 +290,88 @@ def test_fused_mesh_program(topo, chip_branches, monkeypatch, tpch_session,
         lambda x: x if isinstance(x, jax.ShapeDtypeStruct)
         else jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype,
                                   sharding=whole), got.value.args)
-    compiled = _compile(got.value.fn, *abstract)
-    assert re.search(r"all-(gather|reduce)", compiled.as_text())
+    return _compile(got.value.fn, *abstract).as_text()
+
+
+@pytest.mark.parametrize("name", ["Q1", "Q3", "Q6"])
+def test_fused_mesh_program(topo, chip_branches, monkeypatch, tpch_session,
+                            name):
+    text = _compile_mesh_statement(topo, monkeypatch, tpch_session,
+                                   tpch.QUERIES[name])
+    assert re.search(r"all-(gather|reduce)", text)
+
+
+# ---- the same at the SF=10 shapes ------------------------------------------
+
+@pytest.fixture(scope="module")
+def sf10_session():
+    """The columns Q1/Q3/Q6 read at TPC-H SF=10 (60 M ``lineitem`` rows:
+    about 7 GB of host arrays and 12 GB at the peak, minutes of host
+    preparation), for the SF=10 shapes of ``tpch_sf10_mesh4``."""
+    sf = 10.0
+    r = np.random.default_rng(7)
+    n_cust, n_ord = int(150_000 * sf), int(1_500_000 * sf)
+    per = r.integers(1, 8, n_ord)
+    n_li = int(per.sum())
+    o_days = r.integers(0, 2405, n_ord)
+    l_days = np.repeat(o_days, per) + r.integers(1, 122, n_li)
+    days = (np.datetime64("1992-01-01") + np.arange(2405 + 122)
+            .astype("timedelta64[D]")).astype("<U10")
+    okey = np.arange(1, n_ord + 1, dtype=np.int64)
+    tables = {
+        "customer": ("c_custkey bigint primary key, c_mktsegment char(10)", {
+            "c_custkey": np.arange(1, n_cust + 1, dtype=np.int64),
+            "c_mktsegment": np.array(["AUTOMOBILE", "BUILDING", "FURNITURE",
+                                      "MACHINERY", "HOUSEHOLD"])[
+                r.integers(0, 5, n_cust)]}),
+        "orders": ("o_orderkey bigint primary key, o_custkey bigint, "
+                   "o_orderdate varchar(10), o_shippriority int", {
+                       "o_orderkey": okey,
+                       "o_custkey": r.integers(1, n_cust + 1, n_ord),
+                       "o_orderdate": days[o_days],
+                       "o_shippriority": np.zeros(n_ord, dtype=np.int64)}),
+        "lineitem": (
+            "l_id bigint primary key, l_orderkey bigint, l_quantity double, "
+            "l_extendedprice double, l_discount double, l_tax double, "
+            "l_returnflag char(1), l_linestatus char(1), "
+            "l_shipdate varchar(10)", {
+                "l_id": np.arange(1, n_li + 1, dtype=np.int64),
+                "l_orderkey": np.repeat(okey, per),
+                "l_quantity": r.integers(1, 51, n_li).astype(np.float64),
+                "l_extendedprice": np.round(
+                    r.uniform(900.0, 105000.0, n_li), 2),
+                "l_discount": np.round(r.integers(0, 11, n_li) * 0.01, 2),
+                "l_tax": np.round(r.integers(0, 9, n_li) * 0.01, 2),
+                "l_returnflag": np.array(["A", "N", "R"])[
+                    r.integers(0, 3, n_li)],
+                "l_linestatus": np.array(["O", "F"])[r.integers(0, 2, n_li)],
+                "l_shipdate": days[l_days]})}
+    from tinysql_tpu.columnar.store import bulk_load
+    s = new_session()
+    s.execute("create database tpch10")
+    s.execute("use tpch10")
+    for table, (columns, data) in tables.items():
+        s.execute(f"create table {table} ({columns})")
+        bulk_load(s.storage, s.infoschema().table_by_name("tpch10", table),
+                  data)
+    s.execute("set @@tidb_devpipe = 1")
+    return s
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name", ["Q1", "Q3", "Q6"])
+def test_fused_mesh_program_at_the_sf10_shapes(topo, chip_branches,
+                                               monkeypatch, sf10_session,
+                                               name):
+    """The three programs of ``tpch_sf10_mesh4.power_stream`` (lanes of
+    2^26 and 2^24 rows, 2^24 groups) fit a chip and compile in seconds
+    (PERF.md section 5 has them: 5.1, 10.5, 1.5 s), every join a
+    broadcast; slow for the minutes of host preparation, not the
+    compiles."""
+    from tinysql_tpu.parallel import dist
+    # the budget as a chip reports its memory, not the CPU's fixed one
+    monkeypatch.setattr(dist, "broadcast_budget_bytes",
+                        lambda: HBM_BYTES * dist.BROADCAST_MEMORY_SHARE)
+    text = _compile_mesh_statement(topo, monkeypatch, sf10_session,
+                                   tpch.QUERIES[name])
+    assert "all-to-all" not in text

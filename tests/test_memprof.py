@@ -1129,18 +1129,23 @@ def test_benchmark_reads_the_window_span(not_tracing):
             if m["name"].startswith("memprof_traced_ms_per_query.")]
     assert [m["name"] for m in mine] == [
         "memprof_traced_ms_per_query.stream",
-        "memprof_traced_ms_per_query.serve"]
-    assert bench["per_layer"][-2:] == mine      # appended, not inserted
+        "memprof_traced_ms_per_query.serve",
+        "memprof_traced_ms_per_query.mesh10"]   # PR 33's cell
+    at = bench["per_layer"].index(mine[0])
+    assert bench["per_layer"][at:at + 2] == mine[:2]  # PR 32's: adjacent
+    assert bench["per_layer"].index(mine[2]) > at + 1  # later ones after
     for m, cell, moves in zip(
-            mine, ("tpch_sf1.power_stream", "tpch_sf1.q6_dash_16c"),
-            ("stream_queries_per_s", "serve_queries_per_s")):
+            mine, ("tpch_sf1.power_stream", "tpch_sf1.q6_dash_16c",
+                   "tpch_sf10_mesh4.power_stream"),
+            ("stream_queries_per_s", "serve_queries_per_s",
+             "stream_queries_per_s")):
         assert m["workloads"] == [cell] and cell in cells
         assert m["moves"] == moves and cell in e2e[moves]["workloads"]
         assert (m["unit"], m["better"], m["source"], m["layer"]) == (
             "ms", "lower", "program_span", "samplers")
         # the cell runs the profiler at its default: its configuration
-        # does not turn it off (the four-chip one does, and is no cell
-        # of this metric)
+        # does not turn it off (the four-chip SF=1 one does, and is no
+        # cell of this metric)
         config = {c["name"]: c for c in bench["configs"]}[
             cells[cell]["config"]]
         with open(os.path.join(root, config["file"])) as f:

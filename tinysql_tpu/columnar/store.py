@@ -199,7 +199,14 @@ def bulk_load(storage, info: TableInfo,
             if v.dtype.kind != "U":
                 v = v.astype(str)
         else:
-            v = v.astype(dt)
+            # a column handed over in the replica's dtype is held, not
+            # copied (a 60 M-row column is 0.5 GB)
+            v = v.astype(dt, copy=False)
+        # ... through a read-only view: the caller may go on reading its
+        # arrays (the benchmark's reference does), and a write in place
+        # to a replica lane raises where it would change both alike
+        v = v.view()
+        v.flags.writeable = False
         m = np.asarray(nulls.get(c.name, np.zeros(len(v), dtype=bool)),
                        dtype=bool)
         if n is None:
@@ -212,7 +219,7 @@ def bulk_load(storage, info: TableInfo,
         # otherwise PK predicates (handle ranges) select the wrong rows
         pk = info.get_pk_handle_col()
         if pk is not None and pk.name in data:
-            handles = np.asarray(data[pk.name], dtype=np.int64)
+            handles = cols[pk.id][0]
         else:
             handles = np.arange(1, (n or 0) + 1, dtype=np.int64)
     ver = table_data_version(storage, info.id)

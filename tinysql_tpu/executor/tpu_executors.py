@@ -11,7 +11,9 @@ host (np.unique), so TPC-H-style char keys still hit the device path.
 """
 from __future__ import annotations
 
+import os
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 from typing import Dict, List, Optional, Tuple
 
@@ -344,11 +346,45 @@ def rep_string_codes(rep, sid, v, null):
     memo slot (TPU group keys, device masks, CPU string filters) so the
     cached tuple shape can never drift between tiers."""
     def build():
-        safe = np.where(null, "", v)
-        uniques, codes = np.unique(safe.astype(str), return_inverse=True)
-        codes = np.where(null, len(uniques), codes).astype(np.int64)
-        return codes, len(uniques), 0, uniques
+        has_null = bool(null.any())
+        safe = np.where(null, "", v) if has_null else v
+        if safe.dtype.kind != "U":
+            safe = safe.astype(str)
+        uniques, codes = _ordered_codes(safe)
+        if has_null:
+            codes = np.where(null, len(uniques), codes)
+        return codes.astype(np.int64, copy=False), len(uniques), 0, uniques
     return rep.memo(("keycodes", sid, True, False), build)
+
+
+#: rows a block of _ordered_codes; below two blocks np.unique does it whole
+_CODE_BLOCK = 1 << 18
+
+
+def _ordered_codes(v):
+    """(sorted uniques, code of each row) of a fixed-width string lane:
+    ``np.unique(v, return_inverse=True)``, which sorts every row, done
+    in row blocks on the host's cores for a lane of millions of rows —
+    each block's own uniques, their union sorted, then each row's place
+    in it by binary search (numpy's sorts and searches of fixed-width
+    strings release the interpreter).  A 60 M-row date column has 2.5 k
+    values: 12 compares a row, not a 26-level sort (PERF.md section 6,
+    PR 33)."""
+    n = len(v)
+    if n < 2 * _CODE_BLOCK:
+        return np.unique(v, return_inverse=True)
+    blocks = [slice(i, min(i + _CODE_BLOCK, n))
+              for i in range(0, n, _CODE_BLOCK)]
+    codes = np.empty(n, dtype=np.int64)
+    with ThreadPoolExecutor(max_workers=min(
+            len(blocks), len(os.sched_getaffinity(0)))) as pool:
+        uniques = np.unique(np.concatenate(
+            list(pool.map(lambda b: np.unique(v[b]), blocks))))
+
+        def fill(b):
+            codes[b] = np.searchsorted(uniques, v[b])
+        list(pool.map(fill, blocks))
+    return uniques, codes
 
 
 def _rep_string_dict(rep, sid, chk, idx):

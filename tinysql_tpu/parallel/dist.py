@@ -52,6 +52,7 @@ through shard_map_fn/shard_map_unchecked (qlint DF805 enforces this).
 """
 from __future__ import annotations
 
+import logging
 import threading
 from functools import partial
 from typing import List, Optional, Tuple
@@ -115,6 +116,7 @@ def make_mesh(n_devices: Optional[int] = None):
     jax = kernels.jax()
     devs = jax.devices()
     n = n_devices or len(devs)
+    device_limit_bytes()
     from jax.sharding import Mesh
     return Mesh(np.array(devs[:n]), ("shard",))
 
@@ -178,6 +180,70 @@ def shard_bucket(est_rows: float, n_devices: int) -> int:
     while n * 2 <= n_devices and est >= MIN_SHARD_ROWS * (n * 2):
         n *= 2
     return n
+
+
+#: A broadcast join holds its build side whole on every device where a
+#: partitioned one holds a shard's share of it: what the copies ADD to a
+#: device, (n - 1) / n of the build side, may take this share of the
+#: device's memory.  The guard is about memory alone (which plan is
+#: faster is the cost compare's business, planner/device.py), so it lies
+#: between the largest build side that ran broadcast and the smallest
+#: that cannot be held: TPC-H SF=10's Q3 joins a [2^24]-row view of 2 or
+#: 3 columns whole on every chip, at most 384 MiB, 2.3 % of a v5e, at a
+#: peak of 11.2 % (my chip runs, PR 33; flipped to the exchange, that Q3
+#: is 1.87 times slower), and the same view at SF=100, [2^28] rows, 6
+#: GiB, 38 %, cannot lie beside the lanes.  1/8 is 5 times over the one
+#: and 3 times under the other (PERF.md section 6, PR 33).
+BROADCAST_MEMORY_SHARE = 1.0 / 8
+#: bytes the cost model counts for one column of one row (planner/device
+#: _mesh_join_strategy and the executor's run-time check share it)
+COST_COLUMN_BYTES = 8.0
+#: the budget where a device reports no memory limit (the CPU backend)
+NO_LIMIT_BUDGET_BYTES = float(64 << 20)
+
+_DEVICE_LIMIT_BYTES: Optional[float] = None
+
+
+def device_limit_bytes() -> float:
+    """The memory the runtime reports for one device (``bytes_limit``),
+    read once a process: ``make_mesh`` asks as it builds the first mesh,
+    so no statement's prepare does.  0 where the backend reports none —
+    the CPU's devices, quietly; any other platform's with a WARNING,
+    because the join budget then falls to NO_LIMIT_BUDGET_BYTES and
+    plans differ from a healthy chip's."""
+    global _DEVICE_LIMIT_BYTES
+    if _DEVICE_LIMIT_BYTES is None:
+        dev = kernels.jax().devices()[0]
+        try:
+            stats = dev.memory_stats() or {}
+        except (NotImplementedError, RuntimeError):
+            stats = {}
+        limit = float(stats.get("bytes_limit", 0) or 0)
+        if limit <= 0 and dev.platform != "cpu":
+            logging.getLogger("tinysql_tpu").warning(
+                "%s reports no memory limit: mesh joins broadcast build "
+                "sides up to %d bytes only", dev, NO_LIMIT_BUDGET_BYTES)
+        _DEVICE_LIMIT_BYTES = limit
+    return _DEVICE_LIMIT_BYTES
+
+
+def broadcast_budget_bytes() -> float:
+    """The most bytes a broadcast mesh join may add to every device:
+    BROADCAST_MEMORY_SHARE of a device's memory.  In bytes because the
+    cost it guards is: a 2 M-row, two-column build side is 32 MB a chip,
+    and a budget counted in rows sent ten times its rows over the mesh
+    to spare that (PERF.md section 6, PR 33)."""
+    limit = device_limit_bytes()
+    return limit * BROADCAST_MEMORY_SHARE if limit > 0 \
+        else NO_LIMIT_BUDGET_BYTES
+
+
+def broadcast_over_budget(build_bytes: float, n_shards: int) -> bool:
+    """Whether replicating a build side of ``build_bytes`` (rows x
+    columns x COST_COLUMN_BYTES) to ``n_shards`` devices adds more to a
+    device than the budget: the planner's estimate and the executor's
+    run-time bucket are both held to this one line."""
+    return build_bytes * (n_shards - 1) / n_shards > broadcast_budget_bytes()
 
 
 def shardable(nb: int, mesh) -> bool:

@@ -178,7 +178,7 @@ def _mesh_join_strategy(p: PhysicalHashJoin, n_shards: int) -> None:
     (bytes x n_shards over ICI), shuffling moves each row of BOTH sides
     exactly once (all_to_all).  ANALYZE stats feed the row estimates
     through derive_stats; tidb_broadcast_build_max_rows remains a manual
-    override at execution time.
+    override at execution time (in rows, as an operator counts).
 
     The build side mirrors the EXECUTOR's choice (devpipe _JoinNode
     compile / tpu_executors probe_side): left only when left-unique inner
@@ -190,10 +190,11 @@ def _mesh_join_strategy(p: PhysicalHashJoin, n_shards: int) -> None:
                   else 1)
     build = p.children[build_side]
     probe = p.children[1 - build_side]
+    from ..parallel import dist
     rb = max(getattr(build, "stats_row_count", 0.0), 1.0)
     rp = max(getattr(probe, "stats_row_count", 0.0), 1.0)
-    wb = 8.0 * max(len(build.schema.columns), 1)
-    wp = 8.0 * max(len(probe.schema.columns), 1)
+    wb = dist.COST_COLUMN_BYTES * max(len(build.schema.columns), 1)
+    wp = dist.COST_COLUMN_BYTES * max(len(probe.schema.columns), 1)
     broadcast_bytes = rb * wb * n_shards
     shuffle_bytes = rb * wb + rp * wp
     p.mesh_cost = {"broadcast_bytes": broadcast_bytes,
@@ -201,11 +202,9 @@ def _mesh_join_strategy(p: PhysicalHashJoin, n_shards: int) -> None:
     # a build side estimated above the per-device broadcast budget never
     # broadcasts regardless of relative cost — replicating it to every
     # shard is the memory blow-up the budget exists to prevent (and the
-    # executor re-checks against the ACTUAL runtime row count).  One
-    # definition of the budget: the sysvar default.
-    from ..session.session import DEFAULT_SYSVARS
-    over_budget = rb > float(
-        DEFAULT_SYSVARS["tidb_broadcast_build_max_rows"])
+    # executor re-checks against the ACTUAL runtime bucket).  The budget
+    # is bytes, as the costs beside it are (dist.broadcast_over_budget).
+    over_budget = dist.broadcast_over_budget(rb * wb, n_shards)
     p.mesh_strategy = ("shuffle" if over_budget
                        or shuffle_bytes < broadcast_bytes
                        else "broadcast")
