@@ -321,8 +321,47 @@ def test_lowered_program_gathers_only_boundaries(tk, monkeypatch, layout,
     shapes, sums = gather_shapes(tk, sql, monkeypatch)
     per = NB // 4 if layout == "mesh4" else NB
     ngb = 256
-    # presence; count(x): its count; sum(x), sum(b): a count (the NULL
-    # flag) and the sum each
-    assert sums == [(per,)] * 6, sums
+    # presence; count(x): its count; sum(x): a count (the NULL flag)
+    # and the sum; sum(b): the sum alone (b is NULL on no row: its count
+    # is presence)
+    assert sums == [(per,)] * 5, sums
     assert (NB,) not in shapes and (per,) not in shapes, shapes
     assert shapes.count((ngb,)) == len(sums), shapes
+
+
+# ---- a never-NULL argument's count is presence ------------------------------
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_never_null_arguments_take_presence_for_their_count(tk, monkeypatch,
+                                                           layout):
+    """In the sorted formulation a count costs a 64-bit prefix sum and
+    two boundary gathers; a spec whose argument the replica proves NULL
+    on no row (``b`` holds none; ``+ - *`` make none) reduces none and
+    reads ``presence``.  ``x`` holds NULLs and a division makes them (by
+    zero): those keep their counts.  Answers equal the host's, empty
+    groups and NULL sums included."""
+    _two_tables(tk)
+    _use(tk, layout)
+    seen = []
+    results = devpipe._spec_results
+
+    def spy(*a, never_null=frozenset(), **kw):
+        seen.append(set(never_null))
+        return results(*a, never_null=never_null, **kw)
+    monkeypatch.setattr(devpipe, "_spec_results", spy)
+    sql = ("select k, sum(b), sum(x), min(b), sum(b / 2), count(x), "
+           "avg(b * (1 - b)), count(b) from c where b > -40 group by k")
+    got, delta = _device(tk, sql)
+    assert delta["agg_sorted"] == 1 and delta["dispatches"] == 1
+    # specs: sum b, sum x, min b, sum b/2, count x, avg -> (sum, count)
+    # of b * (1 - b), count b
+    assert seen and all(s == {0, 2, 5, 6, 7} for s in seen), seen
+    assert _close(got, _host(tk, sql)) and len(got) > 5
+    # a group whose every x is NULL: sum(x) NULL beside a sum(b) that
+    # took presence for its count
+    assert any(r[2] is None and r[1] is not None for r in _device(
+        tk, "select k, sum(b), sum(x) from c where x is null group by k")[0])
+    # the dense formulation's counts are cheap masked reductions: as before
+    del seen[:]
+    got, delta = _device(tk, "select s, sum(b), count(b) from c group by s")
+    assert delta["agg_dense"] == 1 and seen and not any(seen), seen

@@ -24,6 +24,8 @@ by hand and are in CHANGES.md (PR 22).
 Everything that touches the topology lives in fixtures of THIS file: the
 worker that is handed the file loads the TPU's library, and no other.
 """
+import hashlib
+import json
 import os
 import re
 
@@ -218,12 +220,9 @@ def tpch_session():
     return s
 
 
-@pytest.mark.parametrize("name", ["Q1", "Q3", "Q6"])
-def test_fused_devpipe_program(one_chip, chip_branches, monkeypatch,
-                               tpch_session, name):
-    """Run the statement up to its first dispatch, capture the fused
-    program with its inputs, and compile THAT for the described device
-    instead of running it here."""
+def _capture(monkeypatch, session, sql, one_chip):
+    """(the statement's first device program, its inputs as shapes on
+    the described chip): the statement runs up to its first dispatch."""
     def capturing_jit(fn, name="", **kw):
         def call(*args):
             raise _Captured(jax.jit(fn, **kw), args)
@@ -231,25 +230,42 @@ def test_fused_devpipe_program(one_chip, chip_branches, monkeypatch,
     monkeypatch.setattr(kernels, "counted_jit", capturing_jit)
     try:
         with pytest.raises(_Captured) as got:
-            tpch_session.query(tpch.QUERIES[name])
+            session.query(sql)
     finally:
         progcache.clear()  # the registry now holds the capturing stand-in
-    abstract = jax.tree_util.tree_map(
+    return got.value.fn, jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
         got.value.args)
-    _compile(got.value.fn, *abstract)
+
+
+@pytest.mark.parametrize("name", ["Q1", "Q3", "Q6"])
+def test_fused_devpipe_program(one_chip, chip_branches, monkeypatch,
+                               tpch_session, name):
+    """Run the statement up to its first dispatch, capture the fused
+    program with its inputs, and compile THAT for the described device
+    instead of running it here."""
+    fn, abstract = _capture(monkeypatch, tpch_session, tpch.QUERIES[name],
+                            one_chip)
+    _compile(fn, *abstract)
 
 
 # ---- the same three over the four-chip mesh --------------------------------
 
 def _compile_mesh_statement(topo, monkeypatch, session, sql):
+    """The one fused program of :func:`_capture_mesh` — row-sharded
+    lanes, per-shard partial states, their merge — compiles for the
+    v5e:2x2 with its collectives in it.  Returns the compiled program's
+    text."""
+    fn, abstract = _capture_mesh(topo, monkeypatch, session, sql)
+    return _compile(fn, *abstract).as_text()
+
+
+def _capture_mesh(topo, monkeypatch, session, sql):
     """Under ``tidb_mesh_parallel = 1`` with the session's mesh built from
     the four described devices: the statement runs up to its first
     dispatch, its lanes "placed" as shapes with their layouts (nothing
-    can be put on a described device), and the one fused program —
-    row-sharded lanes, per-shard partial states, their merge — compiles
-    for the v5e:2x2 with its collectives in it.  Returns the compiled
-    program's text."""
+    can be put on a described device).  Returns (the program, its inputs
+    as shapes with their layouts)."""
     from tinysql_tpu.parallel import dist
     mesh = Mesh(np.array(topo.devices), ("shard",))
     monkeypatch.setattr(dist, "make_mesh", lambda n=None: mesh)
@@ -286,11 +302,10 @@ def _compile_mesh_statement(topo, monkeypatch, session, sql):
     leaves = jax.tree_util.tree_leaves(got.value.args)
     placed = [x for x in leaves if isinstance(x, jax.ShapeDtypeStruct)]
     assert any(x.sharding == rows for x in placed)
-    abstract = jax.tree_util.tree_map(
+    return got.value.fn, jax.tree_util.tree_map(
         lambda x: x if isinstance(x, jax.ShapeDtypeStruct)
         else jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype,
                                   sharding=whole), got.value.args)
-    return _compile(got.value.fn, *abstract).as_text()
 
 
 @pytest.mark.parametrize("name", ["Q1", "Q3", "Q6"])
@@ -299,6 +314,101 @@ def test_fused_mesh_program(topo, chip_branches, monkeypatch, tpch_session,
     text = _compile_mesh_statement(topo, monkeypatch, tpch_session,
                                    tpch.QUERIES[name])
     assert re.search(r"all-(gather|reduce)", text)
+
+
+# ---- column liveness: what the compiled text holds --------------------------
+
+def _all_live(monkeypatch):
+    """Every fused program computes every slot of its root, whatever the
+    operator above it reads: the program of the commit before liveness."""
+    from tinysql_tpu.executor import devpipe
+    run = devpipe.DevPipeExec._run_pipeline
+
+    def run_all(self):
+        self.live = None
+        return run(self)
+    monkeypatch.setattr(devpipe.DevPipeExec, "_run_pipeline", run_all)
+
+
+def _gathers(text, rows):
+    """The compiled text's gathers whose result is a lane of ``rows``
+    elements, counted by the pipeline node that traced them (the scopes
+    of ``_TView`` in the op_name: ``aggindex``, ``join/join`` for the
+    inner join, ``join`` for the outer)."""
+    import collections
+    count = collections.Counter()
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[(\d+)\]\S* gather\(", line)
+        if not m or int(m.group(1)) != rows:
+            continue
+        scopes = re.search(r'op_name="([^"]*)"', line).group(1).split("/")
+        node = "aggindex" if "aggindex" in scopes else \
+            "/".join(x for x in scopes if x == "join")
+        count[node] += 1
+    return dict(count)
+
+
+#: Q3 at SF=0.05: ``orders`` and the group table share the 2^17 bucket
+Q3_BUCKET = 1 << 17
+
+
+@pytest.mark.parametrize("live, expect", [
+    # the benchmark's statement: the projection above the TopN reads
+    # revenue, l_orderkey, o_orderdate, o_shippriority of eight slots;
+    # the inner join keeps tbl[pos0] and bvalid[pos] and gathers nothing
+    # of customer's two columns (four u32 and two pred gathers gone).
+    # The aggregate's boundary gathers are presence's and revenue's:
+    # l_extendedprice * (1 - l_discount) is NULL on no row, so its count
+    # is presence (the parent held six: a count's two more)
+    ("consumer", {"aggindex": 4, "join/join": 2, "join": 8}),
+    ("all", {"aggindex": 4, "join/join": 8, "join": 8}),
+])
+def test_q3_gathers_at_the_bucket(one_chip, chip_branches, monkeypatch,
+                                  tpch_session, live, expect):
+    if live == "all":
+        _all_live(monkeypatch)
+    fn, abstract = _capture(monkeypatch, tpch_session, tpch.QUERIES["Q3"],
+                            one_chip)
+    assert _gathers(_compile(fn, *abstract).as_text(), Q3_BUCKET) == expect
+
+
+@pytest.mark.parametrize("live, whole, quarter", [
+    ("consumer", {"aggindex": 4}, {"join/join": 2, "join": 8}),
+    ("all", {"aggindex": 4}, {"join/join": 8, "join": 8}),
+])
+def test_q3_mesh_gathers_a_chip(topo, chip_branches, monkeypatch,
+                                tpch_session, live, whole, quarter):
+    """Under the mesh every chip bounds the whole merged table (four
+    gathers) and joins its quarter of ``orders``."""
+    if live == "all":
+        _all_live(monkeypatch)
+    text = _compile_mesh_statement(topo, monkeypatch, tpch_session,
+                                   tpch.QUERIES["Q3"])
+    assert _gathers(text, Q3_BUCKET) == whole
+    assert _gathers(text, Q3_BUCKET // 4) == quarter
+
+
+@pytest.mark.parametrize("where", ["one", "mesh"])
+@pytest.mark.parametrize("name", ["Q1", "Q6"])
+def test_lowered_text_is_the_parents(topo, one_chip, chip_branches,
+                                     monkeypatch, tpch_session, name, where):
+    """A program whose consumer reads every slot of its root, and whose
+    sorted aggregates (if any) count an argument that may be NULL,
+    lowers to the text of the parent commit, byte for byte: Q1 (the pipe
+    is the statement's root, its GROUP BY the dense formulation) and Q6
+    (no fused pipeline)."""
+    with open(os.path.join(os.path.dirname(__file__), "testdata",
+                           "lowered_at_decce78.json")) as f:
+        pinned = json.load(f)
+    if pinned["jax"] != jax.__version__:
+        pytest.skip(f"pinned under jax {pinned['jax']}")
+    sql = tpch.QUERIES[name]
+    fn, abstract = _capture(monkeypatch, tpch_session, sql, one_chip) \
+        if where == "one" \
+        else _capture_mesh(topo, monkeypatch, tpch_session, sql)
+    text = fn.lower(*abstract).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == pinned["sha256"][f"{name}.{where}"]
 
 
 # ---- the same at the SF=10 shapes ------------------------------------------

@@ -83,9 +83,9 @@ def counters(monkeypatch):
         orig = cls.prepare
 
         def mk(orig, k):
-            def prepare(self, pb, *args):
+            def prepare(self, pb, *args, **kw):
                 runs[k] += 1
-                return orig(self, pb, *args)
+                return orig(self, pb, *args, **kw)
             return prepare
         monkeypatch.setattr(cls, "prepare", mk(orig, k))
     return runs
@@ -659,3 +659,307 @@ def test_scalar_agg_above_join(tk, counters):
     assert_match(tk, "select count(*), sum(t.c), min(t.b) from t join u "
                      "on t.fk = u.k where t.b > 10000")
     assert counters["join"] >= 1
+
+
+# ---- column liveness: a program computes only what its consumer reads ------
+
+def _live_tables(tk, n=2000, seed=13):
+    """A fact table and three dimensions with a string column each, so a
+    projection that reads one stays on the host (exprjit lowers no
+    string) and is the fused program's CONSUMER: ``dm`` keyed uniquely,
+    ``dd`` with duplicate and NULL keys (the CSR join), ``wz`` small."""
+    rng = np.random.default_rng(seed)
+    tags = np.array(["red", "green", "blue", "grey"], dtype=object)
+    _load(tk, "f", "a bigint primary key, b bigint, c double, fk bigint, "
+                   "tag varchar(8)",
+          {"a": (np.arange(1, n + 1, dtype=np.int64), None),
+           "b": (rng.integers(-50, 50, n).astype(np.int64), None),
+           "c": (rng.random(n) * 100, rng.random(n) < 0.1),
+           "fk": (rng.integers(1, 400, n).astype(np.int64),
+                  rng.random(n) < 0.05),
+           "tag": (tags[rng.integers(0, 4, n)], rng.random(n) < 0.1)})
+    names = np.array([f"n{i:03d}" for i in range(300)], dtype=object)
+    _load(tk, "dm", "k bigint primary key, v bigint, w double, "
+                    "name varchar(8)",
+          {"k": (np.arange(1, 301, dtype=np.int64), None),
+           "v": (rng.integers(0, 1000, 300).astype(np.int64), None),
+           "w": (rng.random(300) * 10, rng.random(300) < 0.1),
+           "name": (names, rng.random(300) < 0.1)})
+    _load(tk, "wz", "g bigint primary key, z bigint",
+          {"g": (np.arange(1, 51, dtype=np.int64), None),
+           "z": (rng.integers(0, 5, 50).astype(np.int64), None)})
+    m = 500
+    _load(tk, "dd", "k bigint, v bigint, label varchar(8)",
+          {"k": (rng.integers(1, 100, m).astype(np.int64),
+                 rng.random(m) < 0.05),
+           "v": (rng.integers(0, 1000, m).astype(np.int64), None),
+           "label": (tags[rng.integers(0, 4, m)], rng.random(m) < 0.1)})
+
+
+@pytest.fixture
+def lives(monkeypatch):
+    """[(node kind, its live slots, its slot count)] of every node that
+    prepared with a slot dead."""
+    seen = []
+    key = devpipe._PipeBuilder.key
+
+    def spy(self, part, live=None, n=0):
+        if live is not None and len(live) < n:
+            seen.append((part[0], sorted(live), n))
+        return key(self, part, live, n)
+    monkeypatch.setattr(devpipe._PipeBuilder, "key", spy)
+    return seen
+
+
+def _dead_cols(tk, sql):
+    """(rows of the forced pipe, rows of the CPU executors, growth of
+    pipe_dead_cols and dispatches over the pipe's run)."""
+    before = kernels.stats_snapshot()
+    tk.execute("set @@tidb_use_tpu = 1")
+    got = tk.query(sql).rows
+    delta = kernels.stats_delta(before)
+    tk.execute("set @@tidb_use_tpu = 0")
+    want = tk.query(sql).rows
+    tk.execute("set @@tidb_use_tpu = 1")
+    return got, want, delta
+
+
+#: name -> (statement, root slots dead, the nodes that left a slot dead)
+LIVE_CASES = {
+    # every slot of a three-way join read: the program of before
+    "star3": ("select * from f join dm on f.fk = dm.k "
+              "join wz on f.b + 51 = wz.g", 0, []),
+    # only build columns: the probe's a/fk pass no further
+    "build_only": ("select dm.name, dm.v from f join dm on f.fk = dm.k "
+                   "where f.b > 0", 3, [("join", [3, 4], 5)]),
+    # only probe columns: nothing of dm is gathered
+    "probe_only": ("select f.a, f.tag from f join dm on f.fk = dm.k "
+                   "where dm.v > 100", 3, [("join", [0, 2], 5)]),
+    # the dead build key would carry the NULL extension; the live w does
+    "left_ext": ("select f.a, f.tag, dm.w from f left join dm "
+                 "on f.fk = dm.k", 2, [("join", [0, 2, 4], 5)]),
+    "semi": ("select f.a, f.tag from f where f.fk in "
+             "(select k from dm where v > 300)", 1,
+             [("proj", [], 1), ("semijoin", [0, 2], 3)]),
+    "csr": ("select f.a, dd.label from f join dd on f.fk = dd.k "
+            "where f.c < 60", 3, [("joinm", [0, 4], 5)]),
+    "csr_left": ("select f.tag, dd.v from f left join dd on f.fk = dd.k",
+                 2, [("joinm", [1, 3], 4)]),
+    # a TopN reads its keys and carries only the two strings
+    "topn": ("select f.tag, dm.name from f join dm on f.fk = dm.k "
+             "order by f.c desc, f.a limit 7", 2,
+             [("join", [0, 1, 3, 5], 6), ("order", [0, 1, 3, 5], 6)]),
+    # the consumer is a projection INSIDE the program: its root is whole
+    "inner_proj": ("select dm.v + 1, f.c from f join dm on f.fk = dm.k "
+                   "order by f.c desc, f.a limit 7", 0,
+                   [("join", [0, 1, 4], 5), ("order", [1, 4], 5),
+                    ("proj", [0, 1], 3)]),
+    # a scalar aggregate reads no column of the join below it
+    "scalar": ("select count(*) from f join dm on f.fk = dm.k", 0,
+               [("join", [], 2)]),
+    # the probe side is a host leaf (a reader with a pushed-down limit)
+    "host_probe": ("select x.a, dm.name from (select a, fk from f "
+                   "limit 1500) x join dm on x.fk = dm.k", None, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LIVE_CASES))
+def test_liveness_rows_equal_the_cpu_executors(tk, counters, lives, case):
+    _live_tables(tk)
+    sql, dead, nodes = LIVE_CASES[case]
+    got, want, delta = _dead_cols(tk, sql)
+    assert _canon(got) == _canon(want) and got, sql
+    assert delta["dispatches"] == 1 and counters["join"] >= 1
+    if dead is not None:
+        assert delta["pipe_dead_cols"] == dead
+        assert lives == nodes
+    if case == "host_probe":
+        assert counters["host"] >= 1 and delta["pipe_dead_cols"] >= 1
+
+
+@pytest.fixture(scope="module")
+def tpch_tk():
+    from tinysql_tpu.bench import tpch
+    s = new_session()
+    tpch.load(s, data=tpch.generate(0.01))
+    s.execute("set @@tidb_tpu_min_rows = 0")
+    s.execute("set @@tidb_devpipe = 1")
+    return s, tpch.QUERIES
+
+
+@pytest.mark.parametrize("name, dead", [("Q1", 0), ("Q3", 4)])
+def test_pipe_dead_cols_of_the_benchmarks_statements(tpch_tk, name, dead):
+    """Q3's consumer reads four of the TopN's eight slots; Q1's pipe is
+    the statement's root."""
+    s, queries = tpch_tk
+    got, want, delta = _dead_cols(s, queries[name])
+    assert len(got) == len(want) and got
+    for a, b in zip(got, want):
+        assert all(x == pytest.approx(y, rel=1e-9) if isinstance(y, float)
+                   else x == y for x, y in zip(a, b)), (a, b)
+    assert delta["pipe_dead_cols"] == dead
+    info = s.query("explain analyze " + queries[name]).rows
+    assert any(f"dead_cols:{dead}" in str(r) for r in info) == bool(dead)
+
+
+def test_a_dead_slot_raises_when_read(tk, monkeypatch):
+    """The placeholder fails the trace: a node that reads a slot it did
+    not ask its child for is a failed statement, never a wrong answer."""
+    from tinysql_tpu.ops import exprjit
+    with pytest.raises(LookupError):
+        _v, _m = exprjit.DEAD
+    with pytest.raises(LookupError):
+        exprjit.DEAD[0]
+    assert devpipe._only([(1, 2), (3, 4)], {1}) == [exprjit.DEAD, (3, 4)]
+    assert exprjit._broadcast_len([exprjit.DEAD, None]) == 1
+    _live_tables(tk)
+    # the nodes forget what their own expressions read
+    monkeypatch.setattr(devpipe, "_slots_read", lambda exprs: set())
+    with pytest.raises(LookupError):
+        tk.query(LIVE_CASES["inner_proj"][0])
+
+
+def test_two_consumers_of_one_plan_shape_are_two_programs(tk):
+    """One join, one pruned schema (fk, tag, k, name), three consumers:
+    three programs, each with its own pack schema, and a warm one of
+    any of them builds nothing and answers as the CPU executors do."""
+    from tinysql_tpu.ops import progcache
+    _live_tables(tk)
+    on = "from f join dm on f.fk = dm.k where f.fk < 200"
+    sqls = [f"select f.tag, dm.name {on}",
+            f"select f.fk, f.tag, dm.name {on}",
+            f"select f.fk, f.tag, dm.k, dm.name {on}"]
+    progcache.clear()
+    for dead, sql in zip((2, 1, 0), sqls):
+        got, want, delta = _dead_cols(tk, sql)
+        assert _canon(got) == _canon(want) and got
+        assert delta["pipe_dead_cols"] == dead
+        assert delta["progcache_misses"] == 1
+    pipes = progcache.keys("pipe")
+    assert len(pipes) == 3
+    assert len({k[:4] for k in pipes}) == 1  # one shape, one signature
+    assert sorted(len(k[4][1]) for k in pipes if len(k) > 4) == [2, 3]
+    for sql in sqls + sqls[::-1]:
+        got, want, delta = _dead_cols(tk, sql)
+        assert _canon(got) == _canon(want)
+        assert delta.get("progcache_misses", 0) == 0
+
+
+def test_fallback_after_a_run_time_bail_returns_every_column(
+        tk, monkeypatch):
+    """The per-operator executors know nothing of liveness: after a
+    bail at run time the consumer gets the rows it would have got."""
+    _live_tables(tk)
+    sql = LIVE_CASES["topn"][0]
+    want = tk.query(sql).rows
+    monkeypatch.setattr(devpipe._JoinNode, "prepare",
+                        lambda self, pb, live=None: None)
+    before = kernels.stats_snapshot()
+    got = tk.query(sql).rows
+    assert got == want and got
+    assert kernels.stats_delta(before)["pipe_dead_cols"] == 0
+
+
+#: statement, the slots its consumer is made to read, the aggregate specs
+#: the program then computes
+DEAD_AGGS = {
+    "aggindex": ("select fk, count(*), sum(c), avg(c), min(a) from f "
+                 "group by fk", {0, 2}, {1}),
+    "aggdense": ("select count(*), sum(c), max(b), tag from f group by tag",
+                 {2, 3}, {2}),
+    "aggindex_avg": ("select fk, count(*), sum(c), avg(c), min(a) from f "
+                     "group by fk", {3}, {2, 3}),
+    "sortgroup": ("select dm.v, count(*), sum(f.c), max(f.b) from f "
+                  "join dm on f.fk = dm.k group by dm.v", {0, 3}, None),
+    "scalaragg": ("select count(*), sum(f.c), min(dm.w) from f join dm "
+                  "on f.fk = dm.k", {2}, {2}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DEAD_AGGS))
+def test_dead_aggregate_slots_are_not_computed(tk, monkeypatch, case):
+    """An aggregate whose consumer reads some of its slots computes the
+    specs those read and no other; a dead slot comes back all NULL."""
+    _live_tables(tk)
+    sql, live, specs = DEAD_AGGS[case]
+    tk.execute("set @@tidb_use_tpu = 0")
+    want = tk.query(sql).rows
+    tk.execute("set @@tidb_use_tpu = 1")
+    run = devpipe.DevPipeExec._run_pipeline
+
+    def run_some(self):
+        self.live = frozenset(live)
+        return run(self)
+    monkeypatch.setattr(devpipe.DevPipeExec, "_run_pipeline", run_some)
+    computed = []
+    results = devpipe._spec_results
+
+    def spy(*a, needed, **kw):
+        computed.append(set(needed))
+        return results(*a, needed=needed, **kw)
+    monkeypatch.setattr(devpipe, "_spec_results", spy)
+    from tinysql_tpu.ops import progcache
+    progcache.clear()  # a warm program traces nothing
+    got = tk.query(sql).rows
+    ncols = len(want[0])
+    masked = [[v if i in live else None for i, v in enumerate(r)]
+              for r in want]
+    assert _canon(got) == _canon(masked) and got
+    assert all(r[i] is None for r in got
+               for i in range(ncols) if i not in live)
+    if specs is not None:
+        assert computed and all(n == specs for n in computed), computed
+
+
+def test_pipe_dead_cols_on_metrics_and_in_the_benchmark(tpch_tk):
+    """The counter's other readers: ``/metrics`` and the benchmark's
+    ``pipe_dead_cols_per_query.*`` (a data file over the accepted
+    ``counter`` reader; a program without the counter, as the parent,
+    leaves the metric out)."""
+    import importlib.util
+    import json
+    import os
+    from types import SimpleNamespace
+    from tinysql_tpu.obs import metrics
+    s, queries = tpch_tk
+    s.execute("set @@tidb_use_tpu = 1")
+    before = kernels.stats_snapshot()
+    for name in ("Q1", "Q3", "Q6"):
+        s.query(queries[name])
+    delta = kernels.stats_delta(before)
+    assert delta["pipe_dead_cols"] == 4
+    text = metrics.render_prometheus()
+    total = [line for line in text.splitlines()
+             if line.startswith("tinysql_pipe_dead_cols_total ")]
+    assert total and float(total[0].split()[1]) >= 4
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           "pipe_dead_cols_per_query.json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "counter" and spec["sources"] == ["kernels"]
+    assert spec["args"] == {"source": "kernels", "key": "pipe_dead_cols",
+                            "per_statement": True}
+    path = os.path.join(root, "benchmark", "readers", "counter.py")
+    mod_spec = importlib.util.spec_from_file_location("bench_counter", path)
+    counter = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(counter)
+    run = SimpleNamespace(deltas={"kernels": delta},
+                          answered=[object()] * 3)
+    assert counter.read(run, **spec["args"]) == pytest.approx(4 / 3)
+    run.deltas = {"kernels": {"dispatches": 3}}   # the parent's counters
+    assert counter.read(run, **spec["args"]) is None
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    mine = [m for m in bench["per_layer"]
+            if m["name"].startswith("pipe_dead_cols_per_query.")]
+    assert [(m["name"], m["workloads"]) for m in mine] \
+        == [("pipe_dead_cols_per_query.stream", ["tpch_sf1.power_stream"]),
+            ("pipe_dead_cols_per_query.mesh",
+             ["tpch_sf1_mesh4.power_stream"]),
+            ("pipe_dead_cols_per_query.mesh10",
+             ["tpch_sf10_mesh4.power_stream"])]
+    for m in mine:
+        assert (m["unit"], m["better"], m["source"], m["layer"],
+                m["moves"]) == ("count", "higher", "program_counter",
+                                "executor: fused pipeline",
+                                "stream_queries_per_s")

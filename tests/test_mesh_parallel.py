@@ -506,9 +506,10 @@ def test_q3_program_gathers_no_lineitem_lane(tpch_mesh, monkeypatch, n):
     finally:
         s.execute("set @@tidb_mesh_parallel = 0")
     assert delta["agg_sorted"] == 1 and delta["agg_clustered"] == 1
-    # presence, the count and the revenue: three prefix sums over a lane
-    # (each scans its block totals with a shorter one)
-    assert sums.count((nb // n,)) == 3, sums
+    # presence and the revenue: two prefix sums over a lane (each scans
+    # its block totals with a shorter one); the revenue's count is
+    # presence, its argument being NULL on no row
+    assert sums.count((nb // n,)) == 2, sums
     assert shapes and (nb,) not in shapes and (nb // n,) not in shapes, \
         shapes
 
@@ -672,3 +673,134 @@ def test_group_index_cut_per_shard(n):
     assert by_key.clustered
     assert np.array_equal(by_key.shards(n, per)[0],
                           np.tile(np.arange(per), (n, 1)))
+
+
+# ---- column liveness under the mesh -----------------------------------------
+# A fused program gathers, carries over its TopN's all-gather and
+# exchanges only the columns its consumer reads (executor/devpipe.py).
+
+@pytest.fixture
+def live_tk(join_tk):
+    """``join_tk`` with a string column on each side, so that a
+    projection reading one stays on the host and is the program's
+    consumer, and a dimension with duplicate keys (the CSR join)."""
+    import numpy as np
+    from tinysql_tpu.columnar.store import bulk_load
+    rng = np.random.default_rng(29)
+    tags = np.array(["red", "green", "blue", "grey"], dtype=object)
+    s = join_tk
+    for name, cols, data in [
+        ("fact", "a bigint primary key, fk bigint, x double, "
+                 "tag varchar(8)",
+         {"a": np.arange(1, 4097, dtype=np.int64),
+          "fk": rng.integers(1, 200, 4096).astype(np.int64),
+          "x": rng.random(4096) * 10,
+          "tag": tags[rng.integers(0, 4, 4096)]}),
+        ("dimn", "k bigint primary key, v bigint, name varchar(8)",
+         {"k": np.arange(1, 151, dtype=np.int64),
+          "v": rng.integers(0, 50, 150).astype(np.int64),
+          "name": np.array([f"n{i:03d}" for i in range(150)],
+                           dtype=object)}),
+        ("dupn", "id bigint primary key, k bigint, label varchar(8)",
+         {"id": np.arange(1, 161, dtype=np.int64),
+          "k": np.tile(np.arange(1, 41, dtype=np.int64), 4),
+          "label": tags[rng.integers(0, 4, 160)]})]:
+        s.execute(f"create table {name} ({cols})")
+        bulk_load(s.storage, s.infoschema().table_by_name("jm", name), data)
+    return s
+
+
+#: name -> (statement, root slots dead, forced to partition)
+MESH_LIVE_CASES = {
+    "build_only": ("select dimn.name, dimn.v from fact join dimn "
+                   "on fact.fk = dimn.k where fact.x < 5", 3, False),
+    "probe_only": ("select fact.a, fact.tag from fact join dimn "
+                   "on fact.fk = dimn.k where dimn.v > 10", 3, False),
+    "left_ext": ("select fact.a, fact.tag, dimn.v from fact left join dimn "
+                 "on fact.fk = dimn.k", 2, False),
+    "semi": ("select fact.a, fact.tag from fact where fact.fk in "
+             "(select k from dimn where v > 25)", 1, False),
+    "csr": ("select fact.a, dupn.label from fact join dupn "
+            "on fact.fk = dupn.k where fact.x < 5", 3, False),
+    "topn": ("select fact.tag, dimn.name from fact join dimn "
+             "on fact.fk = dimn.k order by fact.x desc, fact.a limit 9",
+             2, False),
+    "shuffle": ("select fact.tag, dimn.name from fact join dimn "
+                "on fact.fk = dimn.k where fact.x >= 9", 3, True),
+    "shuffle_left": ("select fact.a, fact.tag, dimn.v from fact "
+                     "left join dimn on fact.fk = dimn.k", 2, True),
+    "star": ("select * from fact join dimn on fact.fk = dimn.k "
+             "where fact.x < 1", 0, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MESH_LIVE_CASES))
+def test_mesh_liveness_rows_equal_one_device_and_cpu(live_tk, case):
+    sql, dead, partition = MESH_LIVE_CASES[case]
+    s = live_tk
+    s.execute("set @@tidb_use_tpu = 0")
+    cpu = s.query(sql).rows
+    s.execute("set @@tidb_use_tpu = 1")
+    single = s.query(sql).rows
+    sharded, delta, moved = _mesh_stats_of(s, sql, partition)
+    assert cpu and sorted(map(str, _canon(sharded))) \
+        == sorted(map(str, _canon(single))) \
+        == sorted(map(str, _canon(cpu)))
+    assert delta["pipe_dead_cols"] == dead
+    assert delta["dispatches"] == delta["mesh_dispatches"] == 1
+    # (left alone, the planner's costs choose; some of these partition)
+    assert moved > 0 or not partition
+
+
+def test_partitioned_join_exchanges_only_live_columns(live_tk):
+    """The exchange's volume follows the live set: a consumer that reads
+    two of the five columns moves the two and the keys."""
+    s = live_tk
+    on = "from fact join dimn on fact.fk = dimn.k where fact.fk < 150"
+    # one pruned schema (fk, tag | k, name): four lanes and a validity
+    # lane a side when every slot is read, the key and one more when the
+    # consumer reads the two strings
+    _rows, some, moved_some = _mesh_stats_of(
+        s, f"select fact.tag, dimn.name {on}", True)
+    _rows, every, moved_every = _mesh_stats_of(
+        s, f"select fact.fk, fact.tag, dimn.k, dimn.name {on}", True)
+    assert some["pipe_dead_cols"] == 2 and every["pipe_dead_cols"] == 0
+    assert 0 < moved_some == moved_every
+    # the same join under a consumer of four columns of six moves more
+    _rows, _delta, moved_more = _mesh_stats_of(
+        s, "select fact.tag, fact.x, dimn.name, dimn.v from fact "
+           f"join dimn on fact.fk = dimn.k where fact.fk < 150", True)
+    assert moved_more > moved_some
+
+
+def _mesh_stats_of(s, sql, partition=False):
+    """(rows, kernels' counters' growth, bytes sized for an exchange) of
+    ``sql`` over the mesh, its join forced to partition or left to
+    broadcast."""
+    from tinysql_tpu.ops import shardops
+    moved = shardops.stats_snapshot()["shard_exchange_bytes"]
+    s.execute("set @@tidb_mesh_parallel = 1")
+    if partition:
+        s.execute("set @@tidb_broadcast_build_max_rows = 0")
+    try:
+        rows, delta = _stats_of(s, sql)
+    finally:
+        s.execute("set @@tidb_broadcast_build_max_rows = 1048576")
+        s.execute("set @@tidb_mesh_parallel = 0")
+    return rows, delta, \
+        shardops.stats_snapshot()["shard_exchange_bytes"] - moved
+
+
+@pytest.mark.parametrize("n", [2, 4])
+def test_q3_over_the_mesh_leaves_four_slots_dead(tpch_mesh, monkeypatch, n):
+    s, _mirror, queries = tpch_mesh
+    _mesh_of(monkeypatch, n)
+    s.execute("set @@tidb_mesh_parallel = 1")
+    try:
+        _rows, q3 = _stats_of(s, queries["Q3"])
+        _rows, q1 = _stats_of(s, queries["Q1"])
+        _rows, q6 = _stats_of(s, queries["Q6"])
+    finally:
+        s.execute("set @@tidb_mesh_parallel = 0")
+    assert q3["pipe_dead_cols"] == 4
+    assert q1["pipe_dead_cols"] == 0 and q6["pipe_dead_cols"] == 0

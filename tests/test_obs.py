@@ -487,12 +487,17 @@ def test_outside_a_statement_plain_spans_still_record_nothing():
     """dispatch / drain / compile from a warm-up or a test must not
     start filling the process ring: only process_span says so."""
     mark = _last_id()
+
+    def recorded():
+        # the collector's own process span (a collection of 1 ms or
+        # more, on whichever thread it interrupts) is not a plain span
+        return [x["name"] for x in _proc_spans(mark) if x["name"] != "gc"]
     with obs_context.span("dispatch", cat="device") as s:
         assert s is None
-    assert not _proc_spans(mark)
+    assert not recorded()
     with obs_context.process_span("t27.owned") as s:
         assert s is not None
-    assert [x["name"] for x in _proc_spans(mark)] == ["t27.owned"]
+    assert recorded() == ["t27.owned"]
 
 
 @pytest.fixture(scope="module")

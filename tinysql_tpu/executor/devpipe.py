@@ -71,12 +71,13 @@ from ..obs import context as _obs
 from ..utils import interrupt as _interrupt
 
 from ..chunk import Chunk, Column as CCol
+from ..chunk.column import _np_dtype
 from ..expression import Column as ExprColumn, Constant
 from ..expression.aggregation import AGG_COUNT, AGG_SUM
 from ..mytypes import EvalType
 from ..ops import kernels, progcache
-from ..ops.exprjit import (ParamTable, compile_expr_params, is_jittable,
-                           stable_shape_key)
+from ..ops.exprjit import (DEAD, ParamTable, compile_expr_params,
+                           is_jittable, stable_shape_key)
 from ..planner.physical import (PhysicalHashAgg, PhysicalHashJoin,
                                 PhysicalLimit, PhysicalMergeJoin,
                                 PhysicalProjection, PhysicalSelection,
@@ -263,13 +264,17 @@ class _PipeBuilder:
     given key (prepare is a deterministic tree walk), so a cache-hit
     pipeline can re-bind fresh inputs positionally.  Under a mesh each
     input carries the layout its program asks for (parallel/dist.py
-    ``rows`` / ``whole``; None on one device and for host arrays)."""
-    __slots__ = ("inputs", "layouts", "kparts")
+    ``rows`` / ``whole``; None on one device and for host arrays).
+    ``lparts``: the live set of every node that leaves a slot dead
+    (column liveness, below), beside the node keys and part of the
+    program's: one plan shape with two consumers is two programs."""
+    __slots__ = ("inputs", "layouts", "kparts", "lparts")
 
     def __init__(self):
         self.inputs: List = []
         self.layouts: List = []
         self.kparts: List = []
+        self.lparts: List = []
 
     def add(self, arr, layout=None) -> int:
         self.inputs.append(arr)
@@ -284,14 +289,22 @@ class _PipeBuilder:
         pi, pf = pt.arrays()
         return self.add(pi), self.add(pf)
 
-    def key(self, part) -> None:
+    def key(self, part, live=None, n: int = 0) -> None:
+        """``live``: the node's live slots of its ``n``.  Noted only where
+        some slot is dead, and beside the node keys: those, and the key
+        of a program whose consumer reads everything, stay what they
+        were."""
+        if live is not None and len(live) < n:
+            self.lparts.append((len(self.kparts), tuple(sorted(live))))
         self.kparts.append(part)
 
 
 class _TView:
     """Trace-time view: ``emit(args) -> (valid, [(vals, null), ...])``
     over the fused program's positional inputs, plus the host-side
-    column metadata (ret_type, string decode table) and bucket size."""
+    column metadata (ret_type, string decode table) and bucket size.
+    A slot its consumer did not ask for (``prepare``'s ``live``) holds
+    ``exprjit.DEAD`` in place of a pair; ``meta`` lists every slot."""
     __slots__ = ("emit", "nb", "meta")
 
     def __init__(self, emit: Callable, nb: int, meta: List[tuple],
@@ -304,6 +317,50 @@ class _TView:
         self.emit = scoped
         self.nb = nb
         self.meta = meta
+
+
+# =========================================================================
+# column liveness: a program computes a column only if its consumer reads it
+# =========================================================================
+#
+# Every node's ``prepare(pb, live)`` takes the slots of ITS output view
+# that its parent reads (None: all of them) and asks its children for what
+# it reads itself: a join gathers only live build columns, a TopN carries
+# only live ones through its window, an aggregate computes only live
+# sums.  The set comes from the statement (``DevPipeExec.live``: what the
+# operator above the fused program references), never from an option.
+# Nodes whose columns are the program's inputs as they lie (the leaves)
+# hand every one over: an input nobody reads costs no operation.
+
+def _live_set(live, n: int) -> frozenset:
+    """``live`` over a view of ``n`` slots as a set (None: every slot)."""
+    return frozenset(range(n)) if live is None else frozenset(live)
+
+
+def _slots_read(exprs) -> set:
+    """The child slots a node's own expressions read.  An expression over
+    no column takes its length from the first lane it finds
+    (exprjit._broadcast_len): slot 0 stays live for it."""
+    out = set()
+    for e in exprs:
+        cols = e.collect_columns()
+        out.update(c.index for c in cols)
+        if not cols:
+            out.add(0)
+    return out
+
+
+def _only(pairs, live) -> list:
+    """``pairs`` with every slot outside ``live`` dead."""
+    return [p if i in live else DEAD for i, p in enumerate(pairs)]
+
+
+def _spread(n: int, slots, pairs) -> list:
+    """An ``n``-slot view holding ``pairs`` at ``slots``, dead elsewhere."""
+    out = [DEAD] * n
+    for i, p in zip(slots, pairs):
+        out[i] = p
+    return out
 
 
 # =========================================================================
@@ -635,8 +692,11 @@ class _ReplicaLeaf:
     def nb(self) -> int:
         return kernels.bucket(max(self._chk.full_rows(), 1))
 
-    def prepare(self, pb: _PipeBuilder, order=None) -> Optional[_TView]:
-        """``order`` None: lanes in row order, under the memo keys every
+    def prepare(self, pb: _PipeBuilder, live=None,
+                order=None) -> Optional[_TView]:
+        """``live`` is not followed: the lanes are the program's inputs
+        as they lie, and one nobody reads costs no operation.
+        ``order`` None: lanes in row order, under the memo keys every
         statement over the table shares (devv / devn / devcodes).  Else
         (tag, perm): perm() is a host permutation of the padded [nb]
         lane that keeps padding on padding (under a mesh: within each
@@ -737,7 +797,9 @@ class _HostLeaf:
         ex.open(ctx.exec_ctx)
         return _HostLeaf(ex, plan)
 
-    def prepare(self, pb: _PipeBuilder) -> Optional[_TView]:
+    def prepare(self, pb: _PipeBuilder, live=None) -> Optional[_TView]:
+        # ``live`` is not followed: the columns are inputs, as a
+        # replica leaf's
         from .tpu_executors import _drain_chunk
         chk = _drain_chunk(self.ex, self.ex.field_types()).compact()
         self._chk = chk
@@ -867,10 +929,50 @@ def _mm_fill(jn, dtype, kind: str):
     return jn.inf if kind == "min" else -jn.inf
 
 
+def _needed_specs(slots, out_map, live) -> frozenset:
+    """The specs the live output slots read (an avg reads two); a spec
+    outside it is not computed: a dead sum costs a prefix sum and two
+    gathers."""
+    need = set()
+    for i, m in enumerate(out_map):
+        if i in live and m[0] == "agg":
+            need.update(slots[m[1]][1:])
+    return frozenset(need)
+
+
+def _spec_slots_read(specs, needed) -> set:
+    """The child slots the needed specs' arguments read."""
+    return _slots_read(specs[k][1] for k in needed
+                       if specs[k][1] is not None)
+
+
+#: functions whose result is NULL only where an argument is
+_NULL_PRESERVING = frozenset(
+    ("+", "-", "*", "unaryminus", "abs", "cast_real", "cast_int"))
+
+
+def _never_null(e, column_never_null) -> bool:
+    """``e`` is NULL on no row: it reads, through functions that make no
+    NULL of their own (a division does, by zero), only constants and
+    columns that ``column_never_null(index)`` proves free of NULLs."""
+    if isinstance(e, ExprColumn):
+        return column_never_null(e.index)
+    if isinstance(e, Constant):
+        return e.value is not None
+    return getattr(e, "name", None) in _NULL_PRESERVING \
+        and all(_never_null(a, column_never_null) for a in e.children())
+
+
 def _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid, seg_sum,
-                  seg_mm, presence, n_out, gather=lambda x: x):
+                  seg_mm, presence, n_out, needed, gather=lambda x: x,
+                  never_null=frozenset()):
     """Shared per-spec aggregation loop for the device group-by nodes
     (the subtle NULL-when-empty / avg-pairing semantics live ONCE here).
+    A spec outside ``needed`` (:func:`_needed_specs`) is left dead.  A
+    spec in ``never_null`` has an argument that is NULL on no row: its
+    live rows are the view's and its count IS ``presence``, so no count
+    of its own is reduced (in the sorted formulation a 64-bit prefix sum
+    and two boundary gathers).
     gather takes a lane into the order the reductions run in (the
     identity where the lanes arrive in it: row order for the dense and
     the scalar aggregate, the index's order for the sorted one); seg_sum
@@ -879,18 +981,22 @@ def _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid, seg_sum,
     ``args``, ``gather``, ``group_sums``."""
     scope = kernels.jax().named_scope
     res = []
-    for kind, af in zip(spec_kinds, arg_fns):
+    for k, (kind, af) in enumerate(zip(spec_kinds, arg_fns)):
+        if k not in needed:
+            res.append(DEAD)
+            continue
         if kind == "count_star":
             res.append((presence, jn.zeros(n_out, dtype=bool)))
             continue
         with scope("args"):
             av, an = af(pairs, pr)
         with scope("gather"):
-            live_s = gather(valid & ~an)
+            live_s = gather(valid if k in never_null else valid & ~an)
             if kind != "count":
                 av_s = gather(av)
         with scope("group_sums"):
-            cnt = seg_sum(live_s.astype(jn.int64))
+            cnt = presence if k in never_null \
+                else seg_sum(live_s.astype(jn.int64))
             if kind == "count":
                 res.append((cnt, jn.zeros(n_out, dtype=bool)))
             elif kind in ("sum", "sum0"):
@@ -906,10 +1012,12 @@ def _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid, seg_sum,
 
 def _slot_outputs(jn, res, slots):
     """Descriptor outputs from spec results: direct, or the avg quotient
-    (NULL when the count is zero)."""
+    (NULL when the count is zero); dead where its specs are."""
     outs = []
     for slot in slots:
-        if slot[0] == "one":
+        if any(res[k] is DEAD for k in slot[1:]):
+            outs.append(DEAD)
+        elif slot[0] == "one":
             outs.append(res[slot[1]])
         else:
             sv, _ = res[slot[1]]
@@ -1022,9 +1130,12 @@ class _AggIndexNode:
             decodes.append(decode)
         return key_cols, tuple(sids), decodes
 
-    def prepare(self, pb: _PipeBuilder) -> Optional[_TView]:
+    def prepare(self, pb: _PipeBuilder, live=None) -> Optional[_TView]:
         if not self.leaf.take():
             return None
+        live = _live_set(live, len(self.out_map))
+        needed = _needed_specs(self.slots, self.out_map, live)
+        need = sorted(needed)
         rep = self.leaf.replica()
         got = self._host_key_cols(rep)
         if got is None:
@@ -1039,7 +1150,7 @@ class _AggIndexNode:
         jn = _jn()
         dense = ng <= kernels.SEG_UNROLL
         kernels.stats_add("agg_dense" if dense else "agg_sorted", 1)
-        need_mm = any(k in ("min", "max") for k, _ in self.specs)
+        need_mm = any(self.specs[k][0] in ("min", "max") for k in need)
         # under a mesh whose shards hold the leaf's rows, each shard
         # reduces its own rows to a partial [ngb] state and the states
         # merge over the mesh (dist.mesh_sum / mesh_min / mesh_max): the
@@ -1078,7 +1189,7 @@ class _AggIndexNode:
             kernels.stats_add("agg_clustered", 1)
         elif not dense:
             order = (("by", sids), index_order)
-        tv = self.leaf.prepare(pb, order)
+        tv = self.leaf.prepare(pb, order=order)
         if tv is None:
             return None
         #: the index lanes the reduction reads, in the kernel's order
@@ -1131,15 +1242,30 @@ class _AggIndexNode:
         # serves the sorted one too; the slot stays so that the dense
         # program and its parameter vector are what they were
         pt.add_int(rep.n_rows)
+        # in the sorted formulation a count costs a 64-bit prefix sum and
+        # two boundary gathers: a spec whose argument the replica proves
+        # NULL on no row takes ``presence`` for its count (the dense
+        # one's counts are masked reductions of a few passes and stay)
+        from .tpu_executors import _slot_id
+        chk = self.leaf.chunk()
+
+        def column_never_null(idx):
+            return rep.memo(
+                ("nevernull", _slot_id(self.leaf.ex, idx)),
+                lambda: not chk.columns[idx].null_mask().any())
+        never_null = frozenset() if dense else frozenset(
+            k for k in need if self.specs[k][1] is not None
+            and _never_null(self.specs[k][1], column_never_null))
         arg_fns = []
         keys = []
-        for kind, a in self.specs:
+        for k, (kind, a) in enumerate(self.specs):
             if a is None:
                 arg_fns.append(None)
                 keys.append(kind)
             else:
                 arg_fns.append(compile_expr_params(a, pt))
-                keys.append(f"{kind}:{stable_shape_key(a)}")
+                keys.append(f"{kind}:{stable_shape_key(a)}"
+                            + ("!" if k in never_null else ""))
         ip, fp = pb.params(pt)
         # the cache key must pin EVERYTHING the traced closure depends
         # on: the formulation, key column ids + dtypes (int vs float key
@@ -1150,7 +1276,8 @@ class _AggIndexNode:
                      for s, (gk, _) in zip(sids, gidx.keycols))
         head = "aggdense" if dense else "aggindex"
         pb.key((head, tuple(keys), kdts, tuple(self.slots),
-                tuple(self.out_map), nb, ngb) + dist.layout_tag(lrows))
+                tuple(self.out_map), nb, ngb) + dist.layout_tag(lrows),
+               live, len(self.out_map))
         spec_kinds = [k for k, _ in self.specs]
         slots = self.slots
         out_map = self.out_map
@@ -1204,11 +1331,13 @@ class _AggIndexNode:
         reducers = dense_reducers if dense else sorted_reducers
 
         def reduce(idx, valid, pairs, pr):
-            """(rows per group, [(value, null)] per spec), each [ngb]."""
+            """(rows per group, [(value, null)] per needed spec), each
+            [ngb]."""
             red = reducers(idx, valid)
             res = _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid,
-                                n_out=ngb, **red)
-            return red["presence"], res
+                                n_out=ngb, needed=needed,
+                                never_null=never_null, **red)
+            return red["presence"], [res[k] for k in need]
         if mesh is not None:
             # merged states are whole by construction (psum; min/max
             # gather and reduce, beyond the static checker)
@@ -1216,18 +1345,21 @@ class _AggIndexNode:
                 reduce, mesh=mesh,
                 in_specs=([ROWS] * len(lanes), ROWS,
                           [(ROWS, ROWS)] * len(tv.meta), (WHOLE, WHOLE)),
-                out_specs=(WHOLE, [(WHOLE, WHOLE)] * len(spec_kinds)))
+                out_specs=(WHOLE, [(WHOLE, WHOLE)] * len(need)))
 
         def emit(args):
             valid, pairs = tv.emit(args)
             pr = (args[ip], args[fp])
             presence, res = reduce([args[i] for i in lanes], valid,
                                    list(pairs), pr)
-            outs = _slot_outputs(jn, res, slots)
+            outs = _slot_outputs(
+                jn, _spread(len(spec_kinds), need, res), slots)
             gvalid = (jn.arange(ngb) < pr[0][0]) & (presence > 0)
             cols = []
-            for m in out_map:
-                if m[0] == "agg":
+            for i, m in enumerate(out_map):
+                if i not in live:
+                    cols.append(DEAD)
+                elif m[0] == "agg":
                     cols.append(outs[m[1]])
                 else:
                     cols.append((args[gb_slots[m[1]][0]],
@@ -1400,21 +1532,53 @@ class _JoinNode:
                 nbb, nbb, len(leaf.plan.schema.columns),
                 self.mesh if dist.shardable(nbb, self.mesh) else None))
 
-    def prepare(self, pb: _PipeBuilder) -> Optional[_TView]:
+    def prepare(self, pb: _PipeBuilder, live=None) -> Optional[_TView]:
+        """Asks the probe for its live columns and the probe keys, the
+        build for its live columns (and, where the join may partition,
+        its key): a build column nobody reads is not gathered."""
         self._place_build()
-        btv = self.build.prepare(pb)
+        semi = self.tp in ("semi", "anti")
+        probe_side = 0 if self.probe_is_left else 1
+        npc = len(self.plan.children[probe_side].schema.columns)
+        nbc = len(self.plan.children[1 - probe_side].schema.columns)
+        # the output view: a semi join's is the probe's; else left, right
+        p0, b0 = (0, npc) if self.probe_is_left else (nbc, 0)
+        self.n_out = npc if semi else npc + nbc
+        self.live = _live_set(live, self.n_out)
+        #: live output columns by side, as slots of that side's view
+        self.pl = sorted(i - p0 for i in self.live if p0 <= i < p0 + npc)
+        self.bl = [] if semi else sorted(
+            i - b0 for i in self.live if b0 <= i < b0 + nbc)
+        plive = set(self.pl) | {k.index for k in self.probe_keys}
+        blive = set(self.bl)
+        if self.mesh is not None and self.nk == 1 and not self.mult \
+                and not semi:
+            blive.add(self.build_key.index)  # a partitioned join reads it
+        btv = self.build.prepare(pb, frozenset(blive))
         if btv is None:
             return None
-        ptv = self.probe.prepare(pb)
+        ptv = self.probe.prepare(pb, frozenset(plive))
         if ptv is None:
             return None
-        if self.tp in ("semi", "anti"):
+        assert (len(ptv.meta), len(btv.meta)) == (npc, nbc)
+        if semi:
             return self._prepare_semi(pb, btv, ptv)
         if self.mult:
             return self._prepare_mult(pb, btv, ptv)
         if self.nk > 1:
             return self._prepare_unique_multi(pb, btv, ptv)
         return self._prepare_unique(pb, btv, ptv)
+
+    def _key(self, pb, part) -> None:
+        pb.key(part, self.live, self.n_out)
+
+    def _out(self, ppairs, bcols, nbc: int) -> list:
+        """The output view: the probe's live columns as they are, the
+        build's live ones (``bcols``, in ``self.bl``'s order), every
+        other slot dead."""
+        pcols = _only(ppairs, set(self.pl))
+        bcols = _spread(nbc, self.bl, bcols)
+        return pcols + bcols if self.probe_is_left else bcols + pcols
 
     # ---- semi / anti: membership folds into probe validity -------------
 
@@ -1437,8 +1601,9 @@ class _JoinNode:
         pt.add_int(lo)
         pt.add_int(hi)
         ip, fp = pb.params(pt)
-        pb.key(("semijoin", anti, nb, nbb, tbl_len, pk_slot,
-                len(ptv.meta), len(btv.meta)))
+        self._key(pb, ("semijoin", anti, nb, nbb, tbl_len, pk_slot,
+                       len(ptv.meta), len(btv.meta)))
+        live = self.live
 
         def emit(args):
             bvalid, _bpairs = btv.emit(args)
@@ -1453,7 +1618,7 @@ class _JoinNode:
             # anti (NOT EXISTS shape, never null-aware here): a NULL
             # probe key matches nothing and therefore SURVIVES
             valid_out = pvalid & (~match if anti else match)
-            return valid_out, list(ppairs)
+            return valid_out, _only(ppairs, live)
         return _TView(emit, nb, ptv.meta, "semijoin")
 
     # ---- multi-key unique build: composite lane + dense table ----------
@@ -1515,8 +1680,9 @@ class _JoinNode:
             pt.add_int(hi)
             pt.add_int(st)
         ip, fp = pb.params(pt)
-        pb.key(("joinmk", nb, nbb, total, pk_slots, outer, probe_is_left,
-                len(btv.meta), len(ptv.meta)))
+        self._key(pb, ("joinmk", nb, nbb, total, pk_slots, outer,
+                       probe_is_left, len(btv.meta), len(ptv.meta)))
+        bl, nbc = self.bl, len(btv.meta)
 
         def emit(args):
             bvalid, bpairs = btv.emit(args)
@@ -1537,10 +1703,8 @@ class _JoinNode:
             match = (pos >= 0) & bvalid[pos_safe]
             valid_out = pvalid if outer else (pvalid & match)
             gathered = [(bv[pos_safe], bn[pos_safe] | ~match)
-                        for bv, bn in bpairs]
-            if probe_is_left:
-                return valid_out, list(ppairs) + gathered
-            return valid_out, gathered + list(ppairs)
+                        for bv, bn in (bpairs[i] for i in bl)]
+            return valid_out, self._out(ppairs, gathered, nbc)
         if probe_is_left:
             meta = ptv.meta + btv.meta
         else:
@@ -1675,14 +1839,21 @@ class _JoinNode:
         bk_slot = self.build_key.index
         outer = self.tp == "left"
         probe_is_left = self.probe_is_left
-        npc, nbc = len(ptv.meta), len(btv.meta)
-        pb.key(("joinshuf", nb, nbb, capp, capb, pk_slot, bk_slot, outer,
-                probe_is_left, nbc, npc, n))
+        nbc = len(btv.meta)
+        # only the live columns and the two keys are exchanged
+        px = sorted(set(self.pl) | {pk_slot})
+        bx = sorted(set(self.bl) | {bk_slot})
+        pl_at = [px.index(i) for i in self.pl]
+        bl_at = [bx.index(i) for i in self.bl]
+        pk_at, bk_at = px.index(pk_slot), bx.index(bk_slot)
+        npx, nbx = len(px), len(bx)
+        self._key(pb, ("joinshuf", nb, nbb, capp, capb, pk_slot, bk_slot,
+                       outer, probe_is_left, nbc, len(ptv.meta), n))
         # shard-exchange economics: the all_to_all lane volume this
         # program moves per dispatch (value+null byte per slot, plus the
         # validity lane) and one round at the receive-buffer HWM
-        shardops.record_exchange(n * capp * (9 * npc + 1)
-                                 + n * capb * (9 * nbc + 1))
+        shardops.record_exchange(n * capp * (9 * npx + 1)
+                                 + n * capb * (9 * nbx + 1))
         shardops.note_round(max(n * capp, n * capb))
 
         def kernel(ppairs, pvalid, bpairs, bvalid, pr):
@@ -1691,9 +1862,9 @@ class _JoinNode:
             si = lax.axis_index("shard").astype(jn.int64)
             gp = si * mp + jn.arange(mp)
             gb_ = si * mb + jn.arange(mb)
-            dp = dist.hash_dest_traced(jn, ppairs[pk_slot][0], n, gp,
+            dp = dist.hash_dest_traced(jn, ppairs[pk_at][0], n, gp,
                                        pr[0][0])
-            db = dist.hash_dest_traced(jn, bpairs[bk_slot][0], n, gb_,
+            db = dist.hash_dest_traced(jn, bpairs[bk_at][0], n, gb_,
                                        pr[0][1])
             p_lanes = []
             for v, m_ in ppairs:
@@ -1705,37 +1876,38 @@ class _JoinNode:
                 b_lanes += [(v, jn.zeros((), dtype=v.dtype)), (m_, True)]
             b_lanes.append((bvalid, False))
             b_recv = dist.exchange_lanes(jn, b_lanes, db, capb, n)
-            P_ = [(p_recv[2 * i], p_recv[2 * i + 1]) for i in range(npc)]
+            P_ = [(p_recv[2 * i], p_recv[2 * i + 1]) for i in range(npx)]
             pv_r = p_recv[-1]
-            B_ = [(b_recv[2 * i], b_recv[2 * i + 1]) for i in range(nbc)]
+            B_ = [(b_recv[2 * i], b_recv[2 * i + 1]) for i in range(nbx)]
             bv_r = b_recv[-1]
             BN = n * capb
-            bk_r, bkn_r = B_[bk_slot]
-            pk_r, pkn_r = P_[pk_slot]
+            bk_r, bkn_r = B_[bk_at]
+            pk_r, pkn_r = P_[pk_at]
             hit, brow = dist.local_unique_join(
                 jn, bk_r, bv_r & ~bkn_r, pk_r, BN)
             matched = hit & ~pkn_r & pv_r
             valid_out = pv_r if outer else matched
-            bcols = [(bv2[brow], bn2[brow] | ~matched) for bv2, bn2 in B_]
-            return valid_out, P_, bcols
+            bcols = [(bv2[brow], bn2[brow] | ~matched)
+                     for bv2, bn2 in (B_[i] for i in bl_at)]
+            return valid_out, [P_[i] for i in pl_at], bcols
 
         shard_map, _ = dist.shard_map_fn()
         ROWS, WHOLE = dist.specs()
         sharded = shard_map(
             kernel, mesh=mesh,
-            in_specs=([(ROWS, ROWS)] * npc, ROWS, [(ROWS, ROWS)] * nbc,
+            in_specs=([(ROWS, ROWS)] * npx, ROWS, [(ROWS, ROWS)] * nbx,
                       ROWS, (WHOLE, WHOLE)),
-            out_specs=(ROWS, [(ROWS, ROWS)] * npc, [(ROWS, ROWS)] * nbc))
+            out_specs=(ROWS, [(ROWS, ROWS)] * len(pl_at),
+                       [(ROWS, ROWS)] * len(bl_at)))
 
         def emit(args):
             bvalid, bpairs = btv.emit(args)
             pvalid, ppairs = ptv.emit(args)
-            valid_out, pcols, bcols = sharded(ppairs, pvalid, bpairs,
-                                              bvalid,
-                                              (args[ip], args[fp]))
-            if probe_is_left:
-                return valid_out, list(pcols) + list(bcols)
-            return valid_out, list(bcols) + list(pcols)
+            valid_out, pcols, bcols = sharded(
+                [ppairs[i] for i in px], pvalid,
+                [bpairs[i] for i in bx], bvalid, (args[ip], args[fp]))
+            return valid_out, self._out(
+                _spread(len(ptv.meta), self.pl, pcols), bcols, nbc)
         if probe_is_left:
             meta = ptv.meta + btv.meta
         else:
@@ -1772,11 +1944,14 @@ class _JoinNode:
         mesh = self.mesh if dist.shardable(nb, self.mesh) else None
         n_mesh = self.n_mesh if mesh is not None else 0
         probe_is_left = self.probe_is_left
-        pb.key(("join", nb, nbb, tbl_len, pk_slot, outer, probe_is_left,
-                len(btv.meta), len(ptv.meta), n_mesh))
+        self._key(pb, ("join", nb, nbb, tbl_len, pk_slot, outer,
+                       probe_is_left, len(btv.meta), len(ptv.meta),
+                       n_mesh))
+        bl, nbc = self.bl, len(btv.meta)
 
-        def kernel(ppairs, pvalid, bpairs, bvalid, tbl, pr):
-            kp, knull = ppairs[pk_slot]
+        def kernel(pkey, pvalid, bpairs, bvalid, tbl, pr):
+            # ``bpairs``: the build's live columns alone
+            kp, knull = pkey
             lo_p, hi_p = pr[0][0], pr[0][1]
             inr = (kp >= lo_p) & (kp <= hi_p) & ~knull
             pos0 = jn.clip(kp - lo_p, 0, tbl_len - 1)
@@ -1799,22 +1974,19 @@ class _JoinNode:
             ROWS, WHOLE = dist.specs()
             sharded = shard_map(
                 kernel, mesh=mesh,
-                in_specs=([(ROWS, ROWS)] * len(ptv.meta), ROWS,
-                          [(WHOLE, WHOLE)] * len(btv.meta), WHOLE, WHOLE,
-                          (WHOLE, WHOLE)),
-                out_specs=(ROWS, [(ROWS, ROWS)] * len(btv.meta)))
+                in_specs=((ROWS, ROWS), ROWS, [(WHOLE, WHOLE)] * len(bl),
+                          WHOLE, WHOLE, (WHOLE, WHOLE)),
+                out_specs=(ROWS, [(ROWS, ROWS)] * len(bl)))
         else:
             sharded = kernel
 
         def emit(args):
             bvalid, bpairs = btv.emit(args)
             pvalid, ppairs = ptv.emit(args)
-            valid_out, gathered = sharded(ppairs, pvalid, bpairs, bvalid,
-                                          args[it],
-                                          (args[ip], args[fp]))
-            if probe_is_left:
-                return valid_out, list(ppairs) + gathered
-            return valid_out, gathered + list(ppairs)
+            valid_out, gathered = sharded(
+                ppairs[pk_slot], pvalid, [bpairs[i] for i in bl], bvalid,
+                args[it], (args[ip], args[fp]))
+            return valid_out, self._out(ppairs, gathered, nbc)
         if probe_is_left:
             meta = ptv.meta + btv.meta
         else:
@@ -1913,8 +2085,14 @@ class _JoinNode:
         nk = self.nk
         npc, nbc = len(ptv.meta), len(btv.meta)
         nb_loc = nb // n_mesh if n_mesh else nb
-        pb.key(("joinm", nb, nbb, ngb, ob, tbl_len, pk_slots, outer,
-                probe_is_left, nbc, npc, n_mesh))
+        self._key(pb, ("joinm", nb, nbb, ngb, ob, tbl_len, pk_slots, outer,
+                       probe_is_left, nbc, npc, n_mesh))
+        # the expansion reads the probe's keys and carries its live
+        # columns; of the build's it gathers the live ones
+        px = sorted(set(self.pl) | set(pk_slots))
+        pl_at = [px.index(i) for i in self.pl]
+        pk_at = [px.index(i) for i in pk_slots]
+        bl = self.bl
 
         def kernel(ppairs, pvalid, bpairs, bvalid, order, ends, tbl_d,
                    pr):
@@ -1940,8 +2118,8 @@ class _JoinNode:
             if nk > 1:
                 ok = pvalid
                 kp = jn.zeros(nb, dtype=jn.int64)
-                for j, slot in enumerate(pk_slots):
-                    kvj, knj = ppairs[slot]
+                for j, at in enumerate(pk_at):
+                    kvj, knj = ppairs[at]
                     klo = pr[0][4 + 3 * j]
                     khi = pr[0][4 + 3 * j + 1]
                     kst = pr[0][4 + 3 * j + 2]
@@ -1950,7 +2128,7 @@ class _JoinNode:
                 inr = ok & (kp >= lo_p) & (kp <= hi_p)
                 kp = jn.clip(kp, lo_p, hi_p)
             else:
-                kp, knull = ppairs[pk_slots[0]]
+                kp, knull = ppairs[pk_at[0]]
                 inr = (kp >= lo_p) & (kp <= hi_p) & ~knull & pvalid
             pos0 = jn.clip(kp - lo_p, 0, tbl_len - 1)
             g = jn.where(inr, tbl_d[pos0].astype(jn.int64), -1)
@@ -1975,7 +2153,8 @@ class _JoinNode:
             gjs = jn.clip(gj, 0, ngb - 1)
             matched = (gj >= 0) & (k < m[ps]) & valid_out
             brow = comp[jn.clip(start_c[gjs] + k, 0, nbb - 1)]
-            pcols = [(pv[ps], pn[ps]) for pv, pn in ppairs]
+            pcols = [(pv[ps], pn[ps])
+                     for pv, pn in (ppairs[i] for i in pl_at)]
             bcols = [(bv[brow], bn[brow] | ~matched) for bv, bn in bpairs]
             return valid_out, pcols, bcols
 
@@ -1987,11 +2166,11 @@ class _JoinNode:
             ROWS, WHOLE = dist.specs()
             sharded = shard_map(
                 kernel, mesh=mesh,
-                in_specs=([(ROWS, ROWS)] * npc, ROWS,
-                          [(WHOLE, WHOLE)] * nbc, WHOLE, WHOLE, WHOLE,
+                in_specs=([(ROWS, ROWS)] * len(px), ROWS,
+                          [(WHOLE, WHOLE)] * len(bl), WHOLE, WHOLE, WHOLE,
                           WHOLE, (WHOLE, WHOLE)),
-                out_specs=(ROWS, [(ROWS, ROWS)] * npc,
-                           [(ROWS, ROWS)] * nbc))
+                out_specs=(ROWS, [(ROWS, ROWS)] * len(pl_at),
+                           [(ROWS, ROWS)] * len(bl)))
         else:
             sharded = kernel
 
@@ -1999,11 +2178,11 @@ class _JoinNode:
             bvalid, bpairs = btv.emit(args)
             pvalid, ppairs = ptv.emit(args)
             valid_out, pcols, bcols = sharded(
-                ppairs, pvalid, bpairs, bvalid, args[io], args[ie],
-                args[it], (args[ip], args[fp]))
-            if probe_is_left:
-                return valid_out, list(pcols) + list(bcols)
-            return valid_out, list(bcols) + list(pcols)
+                [ppairs[i] for i in px], pvalid, [bpairs[i] for i in bl],
+                bvalid, args[io], args[ie], args[it],
+                (args[ip], args[fp]))
+            return valid_out, self._out(
+                _spread(npc, self.pl, pcols), bcols, nbc)
         if probe_is_left:
             meta = ptv.meta + btv.meta
         else:
@@ -2105,8 +2284,12 @@ class _SortGroupNode:
         return _SortGroupNode(child, list(plan.group_by), specs, slots,
                               out_map, plan)
 
-    def prepare(self, pb: _PipeBuilder) -> Optional[_TView]:
-        tv = self.child.prepare(pb)
+    def prepare(self, pb: _PipeBuilder, live=None) -> Optional[_TView]:
+        live = _live_set(live, len(self.out_map))
+        needed = _needed_specs(self.slots, self.out_map, live)
+        tv = self.child.prepare(pb, frozenset(
+            {e.index for e in self.key_cols}
+            | _spec_slots_read(self.specs, needed)))
         if tv is None:
             return None
         jn = _jn()
@@ -2134,7 +2317,7 @@ class _SortGroupNode:
         ip, fp = pb.params(pt)
         pb.key(("sortgroup", tuple(keys), tuple(key_idx),
                 tuple(self.slots), tuple(self.out_map), nb,
-                len(tv.meta)))
+                len(tv.meta)), live, len(self.out_map))
         spec_kinds = [k for k, _ in self.specs]
         slots = self.slots
         out_map = self.out_map
@@ -2189,13 +2372,15 @@ class _SortGroupNode:
             presence = seg(valid_s.astype(jn.int64))
             res = _spec_results(
                 jn, spec_kinds, arg_fns, pairs, pr, valid,
-                gather=lambda x: x[perm],
+                gather=lambda x: x[perm], needed=needed,
                 seg_sum=seg, seg_mm=seg_mm, presence=presence, n_out=nb)
             outs = _slot_outputs(jn, res, slots)
             gvalid = jn.arange(nb) < ng
             cols = []
-            for m in out_map:
-                if m[0] == "agg":
+            for i, m in enumerate(out_map):
+                if i not in live:
+                    cols.append(DEAD)
+                elif m[0] == "agg":
                     cols.append(outs[m[1]])
                 else:
                     sv, sn = skeys[m[1]]
@@ -2242,8 +2427,11 @@ class _ScalarAggNode:
         node.out_map = out_map
         return node
 
-    def prepare(self, pb: _PipeBuilder) -> Optional[_TView]:
-        tv = self.child.prepare(pb)
+    def prepare(self, pb: _PipeBuilder, live=None) -> Optional[_TView]:
+        live = _live_set(live, len(self.out_map))
+        needed = _needed_specs(self.slots, self.out_map, live)
+        tv = self.child.prepare(
+            pb, frozenset(_spec_slots_read(self.specs, needed)))
         if tv is None:
             return None
         jn = _jn()
@@ -2260,7 +2448,8 @@ class _ScalarAggNode:
                 keys.append(f"{kind}:{stable_shape_key(a)}")
         ip, fp = pb.params(pt)
         pb.key(("scalaragg", tuple(keys), tuple(self.slots),
-                tuple(self.out_map), tv.nb, len(tv.meta)))
+                tuple(self.out_map), tv.nb, len(tv.meta)),
+               live, len(self.out_map))
         spec_kinds = [k for k, _ in self.specs]
         slots = self.slots
         out_map = self.out_map
@@ -2280,10 +2469,11 @@ class _ScalarAggNode:
                 seg_sum=lambda x_s: at0(jn.sum(x_s)),
                 seg_mm=lambda av_s, live_s, kind: at0(
                     (jn.min if kind == "min" else jn.max)(av_s)),
-                presence=at0(jn.sum(valid.astype(jn.int64))), n_out=ob)
+                presence=at0(jn.sum(valid.astype(jn.int64))), n_out=ob,
+                needed=needed)
             outs = _slot_outputs(jn, res, slots)
             gvalid = jn.arange(ob) == 0  # exactly one result row
-            return gvalid, [outs[m[1]] for m in out_map]
+            return gvalid, _only([outs[m[1]] for m in out_map], live)
         meta = [(oc.ret_type, None) for oc in schema_cols]
         return _TView(emit, ob, meta, "scalaragg")
 
@@ -2409,15 +2599,17 @@ class _SelNode:
             return None
         return _SelNode(child, plan.conditions, plan)
 
-    def prepare(self, pb: _PipeBuilder) -> Optional[_TView]:
-        tv = self.child.prepare(pb)
+    def prepare(self, pb: _PipeBuilder, live=None) -> Optional[_TView]:
+        live = _live_set(live, len(self.plan.schema.columns))
+        tv = self.child.prepare(
+            pb, frozenset(live | _slots_read(self.conds)))
         if tv is None:
             return None
         pt = ParamTable()
         fns = [compile_expr_params(c, pt) for c in self.conds]
         keys = tuple(stable_shape_key(c) for c in self.conds)
         ip, fp = pb.params(pt)
-        pb.key(("sel", keys, tv.nb, len(tv.meta)))
+        pb.key(("sel", keys, tv.nb, len(tv.meta)), live, len(tv.meta))
 
         def emit(args):
             valid, pairs = tv.emit(args)
@@ -2426,7 +2618,7 @@ class _SelNode:
             for f in fns:
                 v, null = f(pairs, pr)
                 m = m & (v != 0) & ~null
-            return m, pairs
+            return m, _only(pairs, live)
         return _TView(emit, tv.nb, tv.meta, "sel")
 
     def close(self):
@@ -2455,8 +2647,10 @@ class _ProjNode:
             return None
         return _ProjNode(child, plan.exprs, plan)
 
-    def prepare(self, pb: _PipeBuilder) -> Optional[_TView]:
-        tv = self.child.prepare(pb)
+    def prepare(self, pb: _PipeBuilder, live=None) -> Optional[_TView]:
+        live = _live_set(live, len(self.exprs))
+        tv = self.child.prepare(pb, frozenset(_slots_read(
+            e for i, e in enumerate(self.exprs) if i in live)))
         if tv is None:
             return None
         pt = ParamTable()
@@ -2473,14 +2667,17 @@ class _ProjNode:
                 keys.append(stable_shape_key(e))
                 meta.append((oc.ret_type, None))
         ip, fp = pb.params(pt)
-        pb.key(("proj", tuple(keys), tv.nb, len(tv.meta)))
+        pb.key(("proj", tuple(keys), tv.nb, len(tv.meta)),
+               live, len(self.exprs))
 
         def emit(args):
             valid, pairs = tv.emit(args)
             pr = (args[ip], args[fp])
             outs = []
-            for kind, f in fns:
-                if kind == "col":
+            for i, (kind, f) in enumerate(fns):
+                if i not in live:
+                    outs.append(DEAD)
+                elif kind == "col":
                     outs.append(pairs[f])
                 else:
                     outs.append(f(pairs, pr))
@@ -2550,8 +2747,12 @@ class _OrderNode:
             off, count = plan.offset, plan.count
         return _OrderNode(child, by, off, count, plan, mesh=ctx.mesh)
 
-    def prepare(self, pb: _PipeBuilder) -> Optional[_TView]:
-        tv = self.child.prepare(pb)
+    def prepare(self, pb: _PipeBuilder, live=None) -> Optional[_TView]:
+        """Reads the sort keys and carries only the live columns through
+        its window (under a mesh: over the all-gather)."""
+        live = _live_set(live, len(self.plan.schema.columns))
+        tv = self.child.prepare(pb, frozenset(
+            live | _slots_read(e for e, _ in self.by)))
         if tv is None:
             return None
         jn = _jn()
@@ -2580,9 +2781,9 @@ class _OrderNode:
                              ) else None
         if mesh is not None:
             return self._prepare_mesh(pb, tv, fns, tuple(keys), descs, off,
-                                      kb, count, ip, fp, mesh)
+                                      kb, count, ip, fp, mesh, live)
         pb.key(("order", tuple(keys), off, kb, count, tv.nb,
-                len(tv.meta)))
+                len(tv.meta)), live, len(tv.meta))
 
         def emit(args):
             valid, pairs = tv.emit(args)
@@ -2600,29 +2801,36 @@ class _OrderNode:
                 # valid rows sort first, so the taken valid rows are a
                 # prefix; cap it at `count`
                 out_valid = out_valid & (jn.arange(kb - off) < count)
-            outs = [(v[take], m[take]) for v, m in pairs]
+            outs = [(p[0][take], p[1][take]) if i in live else DEAD
+                    for i, p in enumerate(pairs)]
             return out_valid, outs
         return _TView(emit, kb - off, tv.meta, "order")
 
     def _prepare_mesh(self, pb, tv, fns, key_ids, descs, off, kb, count,
-                      ip, fp, mesh):
+                      ip, fp, mesh, live):
         """Distributed TopN: per-shard top-(off+count) + all_gather merge.
         Column sort keys alias the payload lanes, so only computed ('fn')
         keys travel as extra lanes — the merge re-reads column keys from
-        the gathered payload instead of gathering them twice."""
+        the gathered payload instead of gathering them twice.  The
+        payload is the live columns and the column sort keys."""
         jn = _jn()
         from jax import lax
         n = int(mesh.devices.size)
         per = tv.nb // n
         kc = min(kb, per)  # per-shard candidate count
         pb.key(("order_mesh", key_ids, off, kb,
-                count, tv.nb, len(tv.meta), n, kc))
+                count, tv.nb, len(tv.meta), n, kc), live, len(tv.meta))
+        out_slots = sorted(live)
+        carried = sorted(live | {f for kind, f in fns if kind == "col"})
+        out_at = [carried.index(i) for i in out_slots]
 
         def pick_kvs(fn_kvs, pairs):
+            # ``pairs``: the carried columns
             out = []
             it = iter(fn_kvs)
             for kind, f in fns:
-                out.append(pairs[f] if kind == "col" else next(it))
+                out.append(pairs[carried.index(f)] if kind == "col"
+                           else next(it))
             return out
 
         def kernel(fn_kvs, valid, pairs):
@@ -2649,7 +2857,8 @@ class _OrderNode:
             out_valid = g_valid[take2]
             if count is not None:
                 out_valid = out_valid & (jn.arange(kb - off) < count)
-            outs = [(v[take2], m[take2]) for v, m in g_pairs]
+            outs = [(v[take2], m[take2])
+                    for v, m in (g_pairs[i] for i in out_at)]
             return out_valid, outs
 
         from ..parallel import dist
@@ -2659,13 +2868,14 @@ class _OrderNode:
             valid, pairs = tv.emit(args)
             pr = (args[ip], args[fp])
             fn_kvs = [f(pairs, pr) for kind, f in fns if kind == "fn"]
-            npairs = len(pairs)
             sharded = dist.shard_map_unchecked(
                 kernel, mesh=mesh,
                 in_specs=([(ROWS, ROWS)] * len(fn_kvs), ROWS,
-                          [(ROWS, ROWS)] * npairs),
-                out_specs=(WHOLE, [(WHOLE, WHOLE)] * npairs))
-            return sharded(fn_kvs, valid, list(pairs))
+                          [(ROWS, ROWS)] * len(carried)),
+                out_specs=(WHOLE, [(WHOLE, WHOLE)] * len(out_slots)))
+            out_valid, outs = sharded(fn_kvs, valid,
+                                      [pairs[i] for i in carried])
+            return out_valid, _spread(len(pairs), out_slots, outs)
         return _TView(emit, kb - off, tv.meta, "order_mesh")
 
     def close(self):
@@ -2684,8 +2894,8 @@ class _LimitNode:
             return None
         return _LimitNode(child, plan)
 
-    def prepare(self, pb: _PipeBuilder) -> Optional[_TView]:
-        tv = self.child.prepare(pb)
+    def prepare(self, pb: _PipeBuilder, live=None) -> Optional[_TView]:
+        tv = self.child.prepare(pb, live)
         if tv is None:
             return None
         jn = _jn()
@@ -2766,9 +2976,18 @@ def _contains_grouped_agg(plan, above_reader: bool = True) -> bool:
 # materialization: host chunk from the packed download
 # =========================================================================
 
-def _to_chunk(host_pairs, meta) -> Chunk:
+def _to_chunk(host_pairs, meta, n_rows: int) -> Chunk:
+    """A dead slot (the program did not compute it: its consumer does
+    not read it) becomes an all-NULL column of the slot's type, so the
+    chunk's width and every parent's column indices stay."""
     cols = []
-    for (v, m), (ret_type, decode) in zip(host_pairs, meta):
+    for pair, (ret_type, decode) in zip(host_pairs, meta):
+        if pair is DEAD:
+            cols.append(CCol.from_numpy(
+                ret_type, np.zeros(n_rows, dtype=_np_dtype(
+                    ret_type.eval_type)), np.ones(n_rows, dtype=bool)))
+            continue
+        v, m = pair
         if decode is not None:
             card = len(decode)
             safe = np.where(m | (v < 0) | (v >= card), 0, v)
@@ -2803,6 +3022,18 @@ class DevPipeExec:
         self._node = None
         self._mesh = None  # the mesh the node tree was compiled for
         self._done = False
+        #: the schema slots the operator above reads (``consumer_reads``),
+        #: None: every slot (any other parent, or the statement's root).
+        #: The fused program computes, packs and downloads only these;
+        #: the fallback returns them all
+        self.live = None
+
+    def consumer_reads(self, exprs) -> None:
+        """The operator above evaluates ``exprs`` over this pipe's chunks
+        and nothing else (a projection; executors._build_executor says
+        so): only the slots they reference are live."""
+        self.live = frozenset(
+            c.index for e in exprs for c in e.collect_columns())
 
     def field_types(self):
         return [c.ret_type for c in self.plan.schema.columns]
@@ -2950,7 +3181,7 @@ class DevPipeExec:
         the WHOLE pipeline as one jitted program.  Small outputs fold the
         result packing into the same program: one dispatch, one D2H."""
         pb = _PipeBuilder()
-        tv = self._node.prepare(pb)
+        tv = self._node.prepare(pb, self.live)
         if tv is None:
             return None
         inputs = pb.inputs
@@ -2963,6 +3194,7 @@ class DevPipeExec:
         jn = _jn()
         nb = tv.nb
         ncols = len(tv.meta)
+        out_slots = sorted(_live_set(self.live, ncols))
         small = nb <= kernels.SMALL_PACK
         # the input dtype/shape signature joins the key as a structural
         # backstop: a node key that under-pins its closure could otherwise
@@ -2973,6 +3205,11 @@ class DevPipeExec:
                      tuple(getattr(a, "shape", ())))
                     for a in pb.inputs)
         key = ("pipe", small, tuple(pb.kparts), sig)
+        if pb.lparts or len(out_slots) < ncols:
+            # what the pack below holds, and what each node computes
+            key += (("live", tuple(out_slots)) + tuple(pb.lparts),)
+        if len(out_slots) < ncols:
+            kernels.stats_add("pipe_dead_cols", ncols - len(out_slots))
         # the program's name in a profile: its node kinds, leaves first
         shape = "_".join(str(part[0]) for part in pb.kparts)
         if small:
@@ -2983,7 +3220,7 @@ class DevPipeExec:
                 def mega(args):
                     valid, cols = emit(args)
                     flat = [valid]
-                    for v, m in cols:
+                    for v, m in (cols[i] for i in out_slots):
                         flat.append(v)
                         flat.append(m)
                     return kernels.pack_arrays(schema, flat)
@@ -2992,15 +3229,17 @@ class DevPipeExec:
             fn, schema = progcache.get(key, build_small)
             vals = kernels.unpack_flat(fn(inputs), schema)
             keep = np.nonzero(vals[0])[0]
+            n_valid = len(keep)
             host = [(vals[1 + 2 * i][keep], vals[2 + 2 * i][keep])
-                    for i in range(ncols)]
+                    for i in range(len(out_slots))]
         else:
             def build_big():
                 emit = tv.emit
 
                 def mega(args):
                     valid, cols = emit(args)
-                    return [valid] + [x for vm in cols for x in vm]
+                    return [valid] + [x for i in out_slots
+                                      for x in cols[i]]
                 _note_compiled(pb.kparts)
                 return kernels.counted_jit(mega, name=shape)
             fn = progcache.get(key, build_big)
@@ -3014,14 +3253,14 @@ class DevPipeExec:
             n_valid = int(kernels.d2h(cfn(valid)))
             if n_valid == 0:
                 host = [(np.empty(0, dtype=np.int64),
-                         np.empty(0, dtype=bool))] * ncols
+                         np.empty(0, dtype=bool))] * len(out_slots)
             else:
                 ob = min(kernels.bucket(n_valid), nb)
                 _ids, vals = kernels._present_pack(
                     valid.astype(jn.int64), items, ob)
                 host = [(vals[2 * i][:n_valid], vals[2 * i + 1][:n_valid])
-                        for i in range(ncols)]
-        return _to_chunk(host, tv.meta)
+                        for i in range(len(out_slots))]
+        return _to_chunk(_spread(ncols, out_slots, host), tv.meta, n_valid)
 
     def drain(self) -> List[list]:
         rows = []

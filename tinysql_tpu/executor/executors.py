@@ -1706,7 +1706,15 @@ def _build_executor(plan: PhysicalPlan, use_tpu: bool = False) -> Executor:
     if isinstance(plan, PhysicalSelection):
         return SelectionExec(plan, build_executor(plan.children[0], use_tpu))
     if isinstance(plan, PhysicalProjection):
-        return ProjectionExec(plan, build_executor(plan.children[0], use_tpu))
+        child = build_executor(plan.children[0], use_tpu)
+        if use_tpu:
+            from .devpipe import DevPipeExec
+            if isinstance(child, DevPipeExec):
+                # the fused program below computes, packs and downloads
+                # only the slots these expressions reference (devpipe's
+                # column liveness); any other parent reads every slot
+                child.consumer_reads(plan.exprs)
+        return ProjectionExec(plan, child)
     if isinstance(plan, PhysicalHashAgg):
         return HashAggExec(plan, build_executor(plan.children[0], use_tpu))
     if isinstance(plan, PhysicalMergeJoin):

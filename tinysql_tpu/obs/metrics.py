@@ -56,6 +56,10 @@ _DEVICE_METRICS = {
                       "Sorted fused GROUP BYs that found the table "
                       "stored in the key's order and shared the scan's "
                       "row-order lanes (no permuted copy)"),
+    "pipe_dead_cols": ("tinysql_pipe_dead_cols_total",
+                       "Columns of fused programs' root views that no "
+                       "consumer reads and the programs therefore did "
+                       "not compute, pack or download"),
     "mesh_dispatches": ("tinysql_mesh_dispatches_total",
                         "Dispatches whose program ran over the whole "
                         "device mesh (tidb_mesh_parallel)"),

@@ -418,9 +418,23 @@ def compile_expr_params(e: Expression, pt: ParamTable) \
     return fn
 
 
+class _DeadColumn:
+    """A slot of a column vector that nothing above reads (the fused
+    pipeline's liveness, executor/devpipe.py): it holds no lanes, and
+    reading it fails the trace instead of answering wrongly."""
+
+    def _read(self, *_):
+        raise LookupError("a dead column was read: its consumer did not "
+                          "name the slot as live")
+    __iter__ = __getitem__ = __len__ = _read
+
+
+DEAD = _DeadColumn()
+
+
 def _broadcast_len(cols) -> int:
     for c in cols:
-        if c is None:
+        if c is None or c is DEAD:
             continue
         arr = c[0] if c[0] is not None else c[1]
         if arr is not None:

@@ -220,6 +220,10 @@ def _device_info(st) -> str:
             if d.get("agg_clustered") else ""
         parts.append(f"agg:{int(d.get('agg_dense', 0))}dense"
                      f"/{int(d.get('agg_sorted', 0))}sorted{clustered}")
+    if d.get("pipe_dead_cols"):
+        # columns of the fused program's root no consumer reads: not
+        # computed, packed or downloaded
+        parts.append(f"dead_cols:{int(d['pipe_dead_cols'])}")
     if d.get("pipe_blocks"):
         from ..ops.kernels import pipe_overlap_frac
         overlap = pipe_overlap_frac(d)
