@@ -389,16 +389,18 @@ def test_q3_mesh_gathers_a_chip(topo, chip_branches, monkeypatch,
 
 
 @pytest.mark.parametrize("where", ["one", "mesh"])
-@pytest.mark.parametrize("name", ["Q1", "Q6"])
+@pytest.mark.parametrize("name", ["Q1", "Q3", "Q6"])
 def test_lowered_text_is_the_parents(topo, one_chip, chip_branches,
                                      monkeypatch, tpch_session, name, where):
-    """A program whose consumer reads every slot of its root, and whose
-    sorted aggregates (if any) count an argument that may be NULL,
-    lowers to the text of the parent commit, byte for byte: Q1 (the pipe
-    is the statement's root, its GROUP BY the dense formulation) and Q6
-    (no fused pipeline)."""
+    """The programs of the statements the benchmark already had lower to
+    the text of the parent commit, byte for byte, on one chip and on the
+    mesh: what PR 35 adds for the join chains of Q5, Q10 and Q18 (the
+    planner's key-aware join order, view builds, the keyed GROUP BY, a
+    longer selected TopN head) follows the plan's shape and leaves Q1
+    (a dense GROUP BY at the statement's root), Q3 (two joins under a
+    sorted aggregate, 14 gathers) and Q6 (no fused pipeline) alone."""
     with open(os.path.join(os.path.dirname(__file__), "testdata",
-                           "lowered_at_decce78.json")) as f:
+                           "lowered_at_129247b.json")) as f:
         pinned = json.load(f)
     if pinned["jax"] != jax.__version__:
         pytest.skip(f"pinned under jax {pinned['jax']}")
@@ -409,6 +411,51 @@ def test_lowered_text_is_the_parents(topo, one_chip, chip_branches,
     text = fn.lower(*abstract).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() \
         == pinned["sha256"][f"{name}.{where}"]
+
+
+# ---- the join chains: Q5, Q10, Q18 as one fused program each ---------------
+
+#: seconds to capture and compile one of the three for the described v5e
+#: at the SF=0.05 shapes (read here: 4 to 6 s each; a 64-bit sort of a
+#: bucket-wide lane alone takes minutes)
+JOIN_COMPILE_BUDGET_S = 60
+#: ``orders`` at SF=0.05 (75 k rows); ``lineitem``'s bucket is 2^19
+ORDERS_BUCKET = 1 << 17
+
+
+def _sorted_lanes(text):
+    """The lane lengths of every sort in a compiled program's text."""
+    out = []
+    for line in text.splitlines():
+        head, found, _ = line.partition(" sort(")
+        if found:
+            out += [int(n) for n in re.findall(r"\[(\d+)\]", head)]
+    return out
+
+
+@pytest.mark.parametrize("name", ["Q5", "Q10", "Q18"])
+def test_fused_join_chain_program(one_chip, chip_branches, monkeypatch,
+                                  tpch_session, name):
+    """Each of the three is ONE program (the capture raises at the first
+    dispatch: a statement that fell to the per-operator tier would show
+    a join's or an aggregate's kernel here, not a pipe), compiles inside
+    the budget, fits the chip, and sorts no lane of the scan's or the
+    orders' bucket: the GROUP BY above the chain is keyed, Q18's TopN
+    head of 128 is selected."""
+    import time
+    from tinysql_tpu.executor import devpipe
+    t0 = time.time()
+    progcache.clear()
+    monkeypatch.setattr(devpipe, "COMPILED_NODE_KEYS", set())
+    fn, abstract = _capture(monkeypatch, tpch_session, tpch.WORKLOAD[name],
+                            one_chip)
+    text = _compile(fn, *abstract).as_text()
+    assert time.time() - t0 < JOIN_COMPILE_BUDGET_S
+    kinds = {k[0] for k in devpipe.COMPILED_NODE_KEYS}
+    assert "join" in kinds and "order" in kinds, kinds
+    assert "sortgroup" not in kinds, kinds
+    assert all(n < ORDERS_BUCKET for n in _sorted_lanes(text)), \
+        sorted(set(_sorted_lanes(text)))
 
 
 # ---- the same at the SF=10 shapes ------------------------------------------

@@ -73,13 +73,14 @@ def assert_match(s, sql, ordered=False):
 @pytest.fixture
 def counters(monkeypatch):
     runs = {"join": 0, "agg": 0, "leaf": 0, "host": 0, "order": 0,
-            "sortgroup": 0}
+            "sortgroup": 0, "keygroup": 0}
     for cls, k in [(devpipe._JoinNode, "join"),
                    (devpipe._AggIndexNode, "agg"),
                    (devpipe._ReplicaLeaf, "leaf"),
                    (devpipe._HostLeaf, "host"),
                    (devpipe._OrderNode, "order"),
-                   (devpipe._SortGroupNode, "sortgroup")]:
+                   (devpipe._SortGroupNode, "sortgroup"),
+                   (devpipe._KeyGroupNode, "keygroup")]:
         orig = cls.prepare
 
         def mk(orig, k):
@@ -518,10 +519,11 @@ def test_group_by_above_join_sortgroup_final(tk, counters):
 def test_group_by_above_join_sortgroup_raw(tk, counters):
     _fixture_tables(tk)
     # agg args from BOTH sides defeat pushdown: the above-join agg stays
-    # in raw mode and must still run in-kernel
+    # in raw mode and must still run in-kernel (one int key of a bounded
+    # range: the keyed formulation, no sort, since PR 35)
     assert_match(tk, "select u.v, sum(t.c * u.w), avg(t.c), min(u.w) "
                      "from t join u on t.fk = u.k group by u.v")
-    assert counters["sortgroup"] >= 1
+    assert counters["keygroup"] >= 1 and counters["sortgroup"] == 0
 
 
 def test_group_by_above_join_multikey(tk, counters):
@@ -957,7 +959,9 @@ def test_pipe_dead_cols_on_metrics_and_in_the_benchmark(tpch_tk):
             ("pipe_dead_cols_per_query.mesh",
              ["tpch_sf1_mesh4.power_stream"]),
             ("pipe_dead_cols_per_query.mesh10",
-             ["tpch_sf10_mesh4.power_stream"])]
+             ["tpch_sf10_mesh4.power_stream"]),
+            ("pipe_dead_cols_per_query.joins",
+             ["tpch_sf1_joins.join_stream"])]
     for m in mine:
         assert (m["unit"], m["better"], m["source"], m["layer"],
                 m["moves"]) == ("count", "higher", "program_counter",

@@ -1130,15 +1130,19 @@ def test_benchmark_reads_the_window_span(not_tracing):
     assert [m["name"] for m in mine] == [
         "memprof_traced_ms_per_query.stream",
         "memprof_traced_ms_per_query.serve",
-        "memprof_traced_ms_per_query.mesh10"]   # PR 33's cell
+        "memprof_traced_ms_per_query.mesh10",   # PR 33's cell
+        "memprof_traced_ms_per_query.joins"]    # PR 35's
     at = bench["per_layer"].index(mine[0])
     assert bench["per_layer"][at:at + 2] == mine[:2]  # PR 32's: adjacent
     assert bench["per_layer"].index(mine[2]) > at + 1  # later ones after
+    assert bench["per_layer"].index(mine[3]) \
+        > bench["per_layer"].index(mine[2])
     for m, cell, moves in zip(
             mine, ("tpch_sf1.power_stream", "tpch_sf1.q6_dash_16c",
-                   "tpch_sf10_mesh4.power_stream"),
+                   "tpch_sf10_mesh4.power_stream",
+                   "tpch_sf1_joins.join_stream"),
             ("stream_queries_per_s", "serve_queries_per_s",
-             "stream_queries_per_s")):
+             "stream_queries_per_s", "stream_queries_per_s")):
         assert m["workloads"] == [cell] and cell in cells
         assert m["moves"] == moves and cell in e2e[moves]["workloads"]
         assert (m["unit"], m["better"], m["source"], m["layer"]) == (

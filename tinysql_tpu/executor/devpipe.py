@@ -49,6 +49,18 @@ Key design points (why this maps well onto TPU + XLA):
   two-phase expansion (scatter row starts + running-max fill) lands the
   variable-multiplicity output in a static bucket sized by a host-side
   upper bound.
+- **A join's view as a build side.**  A unique single-key join (and a
+  semi join) leaves its probe side's rows where they were, so its output
+  can be the build side of the join above it: that join probes the
+  key -> row table of the view's OWN probe side and reads the view's
+  validity (``_view_build_key``).  TPC-H Q5 probes ``lineitem`` into
+  ``(orders join customer)`` and ``supplier`` into ``(nation semi
+  region)`` this way, one program.
+- **GROUP BY above a chain without a sort** (``_KeyGroupNode``): on one
+  key of a bounded range a group is a slot, and of several GROUP BY
+  columns that are one table's, its primary key among them, the key
+  alone forms the groups and the rest are fetched for the groups that
+  exist — never gathered at the chain's bucket.
 - Strings ride order-preserving dictionary codes on device (decode on
   materialize only), so string group keys, sort keys, and equality
   filters all stay on the TPU.
@@ -622,6 +634,19 @@ def _dev_upload(rep, key, build_np, layout=None):
                     lambda: dist.place(build_np(), layout))
 
 
+def _leaf_lane(rep, kind: str, sid, nb: int, host, fill=0, tag=(),
+               perm=None, layout=None):
+    """A replica column's lane on the device, padded to its leaf's bucket
+    ``nb``: ``kind`` is ``devv`` (values), ``devn`` (the null mask) or
+    ``devcodes`` (dictionary codes), and (kind, sid, nb) the memo key
+    every statement over the table shares.  ``tag`` + ``perm``: the lane
+    permuted on the host (:meth:`_ReplicaLeaf.prepare`'s ``order``)."""
+    def build():
+        out = kernels.pad1(host, nb, fill)
+        return out if perm is None else out[perm()]
+    return _dev_upload(rep, (kind, sid, nb) + tag, build, layout)
+
+
 def _layouts(mesh):
     """(rows, whole) of ``mesh``, or (None, None) on one device."""
     if mesh is None:
@@ -727,10 +752,8 @@ class _ReplicaLeaf:
         tag, perm = order or ((), None)
 
         def lane(kind, sid, host, fill=0):
-            def build():
-                out = kernels.pad1(host, nb, fill)
-                return out if perm is None else out[perm()]
-            return _dev_upload(rep, (kind, sid, nb) + tag, build, lay)
+            return _leaf_lane(rep, kind, sid, nb, host, fill, tag, perm,
+                              lay)
         for idx, c in enumerate(chk.columns):
             v = c.values()
             sid = _slot_id(self.ex, idx)
@@ -1008,6 +1031,21 @@ def _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid, seg_sum,
                 res.append((seg_mm(jn.where(live_s, av_s, fill),
                                    live_s, kind), cnt == 0))
     return res
+
+
+def _spec_fns(specs, pt: ParamTable):
+    """(argument closures over ``pt``'s parameter slots, structural key
+    parts) of an aggregate's specs; None for a spec without an argument."""
+    arg_fns = []
+    keys = []
+    for kind, a in specs:
+        if a is None:
+            arg_fns.append(None)
+            keys.append(kind)
+        else:
+            arg_fns.append(compile_expr_params(a, pt))
+            keys.append(f"{kind}:{stable_shape_key(a)}")
+    return arg_fns, keys
 
 
 def _slot_outputs(jn, res, slots):
@@ -1537,6 +1575,11 @@ class _JoinNode:
         build for its live columns (and, where the join may partition,
         its key): a build column nobody reads is not gathered."""
         self._place_build()
+        kernels.stats_add("pipe_joins", 1)
+        if _leafish(self.build) is not self.build:
+            # the build side is a view: a selection's, a join's, an
+            # aggregate's (counted once a fused dispatch, like the rest)
+            kernels.stats_add("pipe_view_builds", 1)
         semi = self.tp in ("semi", "anti")
         probe_side = 0 if self.probe_is_left else 1
         npc = len(self.plan.children[probe_side].schema.columns)
@@ -2281,6 +2324,10 @@ class _SortGroupNode:
         child = _compile_node(plan.children[0], ctx)
         if child is None:
             return None
+        cut = _KeyGroupNode.cut_of(child, list(plan.group_by), ctx)
+        if cut is not None:
+            return _KeyGroupNode(child, list(plan.group_by), cut, specs,
+                                 slots, out_map, plan)
         return _SortGroupNode(child, list(plan.group_by), specs, slots,
                               out_map, plan)
 
@@ -2305,15 +2352,7 @@ class _SortGroupNode:
             key_idx.append(e.index)
             decodes.append(decode)
         pt = ParamTable()
-        arg_fns = []
-        keys = []
-        for kind, a in self.specs:
-            if a is None:
-                arg_fns.append(None)
-                keys.append(kind)
-            else:
-                arg_fns.append(compile_expr_params(a, pt))
-                keys.append(f"{kind}:{stable_shape_key(a)}")
+        arg_fns, keys = _spec_fns(self.specs, pt)
         ip, fp = pb.params(pt)
         pb.key(("sortgroup", tuple(keys), tuple(key_idx),
                 tuple(self.slots), tuple(self.out_map), nb,
@@ -2396,6 +2435,212 @@ class _SortGroupNode:
         _close_node(self.child)
 
 
+def _origin(node, slot: int):
+    """(replica leaf, its slot) that slot ``slot`` of ``node``'s view is
+    a plain copy of, or None: followed through the nodes that hand a
+    column on as it is."""
+    if isinstance(node, _ReplicaLeaf):
+        return node, slot
+    if isinstance(node, (_SelNode, _OrderNode, _LimitNode)):
+        return _origin(node.child, slot)
+    if isinstance(node, _ProjNode):
+        e = node.exprs[slot]
+        return _origin(node.child, e.index) \
+            if isinstance(e, ExprColumn) else None
+    if isinstance(node, _JoinNode):
+        return _origin(*_join_side_slot(node, slot))
+    if isinstance(node, _KeyGroupNode):
+        m = node.out_map[slot]
+        return _origin(node.child, node.key_cols[m[1]].index) \
+            if m[0] == "gb" else None
+    return None
+
+
+class _KeyGroupNode:
+    """GROUP BY above any device view (a join chain's) on ONE key whose
+    values lie in a bounded range, without a sort: group ``g`` is key
+    ``lo + g`` (code ``g`` of a string key), one slot more for the NULL
+    key, and every sum is a segment reduction over ``key - lo`` — masked
+    reductions in row order up to kernels.SEG_UNROLL groups (TPC-H Q5:
+    25 nations), scatter-adds beyond (Q10: 150 k customers).  The range
+    is the key's column's own, read where the view took the column from
+    (:func:`_origin`): the replica's bounds or dictionary.
+
+    **The key cut**: of several GROUP BY columns that are all plain
+    columns of one table, one of them its primary key (the handle), the
+    key alone decides the group — each row of a join's output holds one
+    row of the table, whatever was joined to it — and groups are formed
+    on it (counter ``agg_key_cut``).  The other columns are not asked of
+    the view at all (column liveness: a join chain gathers none of them
+    at its probe's bucket); each group fetches them from the table's own
+    lanes by its key, through the table's key -> row table, at the
+    group bucket.  A GROUP BY whose columns come from several tables, or
+    none of which is the key, is not cut and sorts (_SortGroupNode).
+
+    Output view: group ``g`` at slot ``g`` of the bucket of the range,
+    valid where some row fell in it."""
+
+    def __init__(self, child, key_cols, cut: int, specs, slots, out_map,
+                 plan):
+        self.child = child
+        self.key_cols = key_cols
+        self.cut = cut              # index into key_cols of the key
+        self.specs = specs
+        self.slots = slots
+        self.out_map = out_map
+        self.plan = plan
+
+    @staticmethod
+    def cut_of(child, key_cols, ctx: _Ctx) -> Optional[int]:
+        """Which GROUP BY column the groups can be formed on, or None
+        (the mesh keeps the formulation it had)."""
+        if ctx.mesh is not None:
+            return None
+        from .tpu_executors import _slot_id
+        origins = [_origin(child, e.index) for e in key_cols]
+        if any(o is None for o in origins):
+            return None
+        key = key_cols[0]
+        if len(key_cols) == 1:
+            ok = key.eval_type in (EvalType.INT, EvalType.STRING)
+            return 0 if ok else None
+        if any(o[0] is not origins[0][0] for o in origins):
+            return None
+        pk = origins[0][0].plan.scan.table_info.get_pk_handle_col()
+        for i, (leaf, at) in enumerate(origins):
+            if _slot_id(leaf.ex, at) in ("handle",
+                                         pk.id if pk is not None else None):
+                return i
+        return None
+
+    def prepare(self, pb: _PipeBuilder, live=None) -> Optional[_TView]:
+        from .tpu_executors import _rep_string_dict, _slot_id
+        live = _live_set(live, len(self.out_map))
+        needed = _needed_specs(self.slots, self.out_map, live)
+        key = self.key_cols[self.cut]
+        tv = self.child.prepare(pb, frozenset(
+            {key.index} | _spec_slots_read(self.specs, needed)))
+        if tv is None:
+            return None
+        jn = _jn()
+        nb = tv.nb
+        leaf, at = _origin(self.child, key.index)
+        rep, chk = leaf.replica(), leaf.chunk()
+        if rep is None or chk is None:
+            return None
+        nbl = leaf.nb()
+
+        def column(idx):
+            """(values or codes, nulls, decode, dtype tag) of the leaf's
+            column ``idx`` on the host."""
+            sid = _slot_id(leaf.ex, idx)
+            col = chk.columns[idx]
+            v = col.values()
+            if v.dtype == object or v.dtype.kind == "U":
+                codes, _card, _, uniques = _rep_string_dict(rep, sid, chk,
+                                                            idx)
+                return sid, codes, col.null_mask(), uniques, "s"
+            return sid, v, col.null_mask(), None, \
+                "f" if v.dtype == np.float64 else "i"
+        ksid, kv, km, kdecode, kdt = column(at)
+        if kdt == "s":
+            lo, rng = 0, max(len(kdecode), 1)
+        elif kdt == "i":
+            b = _col_bounds(rep, ksid, kv, km)
+            lo, hi = b if b is not None else (0, 0)
+            rng = hi - lo + 1
+            if rng > MAX_DENSE_RANGE:
+                return None
+        else:
+            return None
+        # one slot past the range holds the NULL key's group
+        ngb = kernels.bucket(rng + 1)
+        dense = ngb <= kernels.SEG_UNROLL
+        # the columns the key determines, fetched for each group from
+        # the table's own lanes (the memo keys of its scans)
+        carried = {}
+        it = tbl_len = None
+        for j, e in enumerate(self.key_cols):
+            if j == self.cut:
+                continue
+            if it is None:
+                got = _rep_pos_table(rep, ksid, kv, km)
+                if got is None:
+                    return None
+                tbl = got[2]
+                it = pb.lane(rep, ("postable_dev", ksid), lambda: tbl)
+                tbl_len = int(tbl.shape[0])
+            sid, v, m, decode, dt = column(_origin(self.child, e.index)[1])
+            carried[j] = (
+                pb.add(_leaf_lane(rep, "devcodes" if dt == "s" else "devv",
+                                  sid, nbl, v)),
+                pb.add(_leaf_lane(rep, "devn", sid, nbl, m, True)),
+                decode, dt)
+        if carried:
+            kernels.stats_add("agg_key_cut", 1)
+        if dense:
+            kernels.stats_add("agg_dense", 1)
+        pt = ParamTable()
+        pt.add_int(lo)
+        pt.add_int(rng)
+        arg_fns, keys = _spec_fns(self.specs, pt)
+        ip, fp = pb.params(pt)
+        pb.key(("keygroup", tuple(keys), key.index, kdt, self.cut,
+                tuple((j, c[3]) for j, c in sorted(carried.items())),
+                tuple(self.slots), tuple(self.out_map), nb, ngb, nbl,
+                tbl_len, len(tv.meta)), live, len(self.out_map))
+        spec_kinds = [k for k, _ in self.specs]
+        slots, out_map, cut = self.slots, self.out_map, self.cut
+        kslot = key.index
+
+        def emit(args):
+            valid, pairs = tv.emit(args)
+            pr = (args[ip], args[fp])
+            lo_p, rng_p = pr[0][0], pr[0][1]
+            kval, knull = pairs[kslot]
+            gid = jn.where(knull, rng_p,
+                           jn.clip(kval - lo_p, 0, rng_p - 1)
+                           ).astype(jn.int32)
+            seg = kernels._SegReduce(kernels.jax(), jn, gid, valid, ngb,
+                                     unroll=dense)
+            presence = seg.sum(valid.astype(jn.int64), valid)
+            res = _spec_results(
+                jn, spec_kinds, arg_fns, pairs, pr, valid,
+                seg_sum=lambda x: seg.sum(x, valid),
+                seg_mm=lambda av, live_s, kind: seg.minmax(
+                    av, live_s, kind == "min"),
+                presence=presence, n_out=ngb, needed=needed)
+            outs = _slot_outputs(jn, res, slots)
+            g = jn.arange(ngb, dtype=jn.int64)
+            gnull = g >= rng_p
+            if carried:
+                row = args[it][jn.clip(g, 0, tbl_len - 1)]
+                miss = gnull | (row < 0)
+                row = jn.clip(row, 0, nbl - 1)
+            cols = []
+            for i, m in enumerate(out_map):
+                if i not in live:
+                    cols.append(DEAD)
+                elif m[0] == "agg":
+                    cols.append(outs[m[1]])
+                elif m[1] == cut:
+                    cols.append((g if kdt == "s" else g + lo_p, gnull))
+                else:
+                    iv, im = carried[m[1]][:2]
+                    cols.append((args[iv][row], args[im][row] | miss))
+            return presence > 0, cols
+        meta = []
+        for oc, m in zip(self.plan.schema.columns, out_map):
+            decode = None
+            if m[0] == "gb":
+                decode = kdecode if m[1] == cut else carried[m[1]][2]
+            meta.append((oc.ret_type, decode))
+        return _TView(emit, ngb, meta, "keygroup")
+
+    def close(self):
+        _close_node(self.child)
+
+
 class _ScalarAggNode:
     """Global (no GROUP BY) aggregation over any device view — masked
     reductions, one output row at slot 0 of a minimal bucket.  Keeps
@@ -2437,15 +2682,7 @@ class _ScalarAggNode:
         jn = _jn()
         ob = 16  # minimal bucket; the one result row sits at slot 0
         pt = ParamTable()
-        arg_fns = []
-        keys = []
-        for kind, a in self.specs:
-            if a is None:
-                arg_fns.append(None)
-                keys.append(kind)
-            else:
-                arg_fns.append(compile_expr_params(a, pt))
-                keys.append(f"{kind}:{stable_shape_key(a)}")
+        arg_fns, keys = _spec_fns(self.specs, pt)
         ip, fp = pb.params(pt)
         pb.key(("scalaragg", tuple(keys), tuple(self.slots),
                 tuple(self.out_map), tv.nb, len(tv.meta)),
@@ -2491,9 +2728,56 @@ def _leafish(node) -> Optional[_ReplicaLeaf]:
     return None
 
 
+class _Slot:
+    """A column of a view by its slot alone: what the build-key walk
+    below reads of an expression column."""
+    __slots__ = ("index",)
+
+    def __init__(self, index: int):
+        self.index = index
+
+
+def _join_side_slot(node: "_JoinNode", idx: int):
+    """Slot ``idx`` of a join's output view as (node of the side it is a
+    column of, its slot there).  A semi join's view is its probe's."""
+    if node.tp in ("semi", "anti"):
+        return node.probe, idx
+    sides = node.plan.children
+    probe_side = 0 if node.probe_is_left else 1
+    nleft = len(sides[0].schema.columns)
+    side, at = (0, idx) if idx < nleft else (1, idx - nleft)
+    return (node.probe if side == probe_side else node.build), at
+
+
+def _probe_shaped(node) -> bool:
+    """A join whose output view has its probe side's rows, one for one:
+    the single-key unique join and the semi join on one device (the
+    partitioned mesh join lays its output out by key).  The probe side's
+    key -> row table then still finds a row of the view, and the view's
+    own validity says whether the row survived the join."""
+    return isinstance(node, _JoinNode) and not node.mult \
+        and node.nk == 1 and node.mesh is None
+
+
+def _view_build_key(node: "_JoinNode", build_key):
+    """The build key of a parent join as a column of ``node``'s probe
+    side, or None: ``node`` is not probe-shaped or the key is its build
+    side's."""
+    if not _probe_shaped(node):
+        return None
+    side, at = _join_side_slot(node, build_key.index)
+    return _Slot(at) if side is node.probe else None
+
+
 def _has_build_key_info(node, build_key) -> bool:
     if isinstance(node, _AggIndexNode):
         return node.key_slot() == build_key.index
+    if isinstance(node, _JoinNode):
+        # a view build: the parent probes the probe side's table and
+        # reads this join's validity (the planner proved the key unique
+        # among the join's rows)
+        key = _view_build_key(node, build_key)
+        return key is not None and _has_build_key_info(node.probe, key)
     if isinstance(node, (_ReplicaLeaf,)):
         return True  # bounds checked at prepare time
     if isinstance(node, (_SelNode,)):
@@ -2524,6 +2808,11 @@ def _prepare_build_key_info(node, build_key, pb: _PipeBuilder, mesh=None):
         return lo, hi, it, int(tbl.shape[0])
     if isinstance(node, _SelNode):
         return _prepare_build_key_info(node.child, build_key, pb, mesh)
+    if isinstance(node, _JoinNode):
+        key = _view_build_key(node, build_key)
+        if key is None:
+            return None
+        return _prepare_build_key_info(node.probe, key, pb, mesh)
     if isinstance(node, _ProjNode):
         e = node.exprs[build_key.index]
         if not isinstance(e, ExprColumn):

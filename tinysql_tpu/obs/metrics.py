@@ -47,8 +47,10 @@ _DEVICE_METRICS = {
                         "Host-twin kernel invocations (numpy twins "
                         "serving the XLA:CPU backend)"),
     "agg_dense": ("tinysql_agg_dense_total",
-                  "Fused GROUP BYs over a replica reduced in row order "
-                  "with masked reductions (at most SEG_UNROLL groups)"),
+                  "Fused GROUP BYs reduced in row order with masked "
+                  "reductions (at most SEG_UNROLL groups): over a replica "
+                  "by its group index, above a join chain by the key's "
+                  "own range"),
     "agg_sorted": ("tinysql_agg_sorted_total",
                    "Fused GROUP BYs over a replica reduced in the group "
                    "index's order (prefix sum + boundary difference)"),
@@ -60,6 +62,18 @@ _DEVICE_METRICS = {
                        "Columns of fused programs' root views that no "
                        "consumer reads and the programs therefore did "
                        "not compute, pack or download"),
+    "pipe_joins": ("tinysql_pipe_joins_total",
+                   "Join nodes traced into fused programs, counted once "
+                   "a fused dispatch"),
+    "pipe_view_builds": ("tinysql_pipe_view_builds_total",
+                         "Of those joins, the ones whose build side is a "
+                         "view (a selection's, a join's, an aggregate's) "
+                         "and not a base table"),
+    "agg_key_cut": ("tinysql_agg_key_cut_total",
+                    "Fused GROUP BYs above a join chain formed on the one "
+                    "key (a table's primary key) that determines the "
+                    "other GROUP BY columns, which are fetched for the "
+                    "groups and gathered nowhere in the chain"),
     "mesh_dispatches": ("tinysql_mesh_dispatches_total",
                         "Dispatches whose program ran over the whole "
                         "device mesh (tidb_mesh_parallel)"),

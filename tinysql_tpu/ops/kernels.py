@@ -123,6 +123,7 @@ STATS = {"dispatches": 0, "d2h_transfers": 0, "d2h_bytes": 0,
          "host_dispatches": 0,
          "agg_dense": 0, "agg_sorted": 0, "agg_clustered": 0,
          "pipe_dead_cols": 0,
+         "pipe_joins": 0, "pipe_view_builds": 0, "agg_key_cut": 0,
          "mesh_dispatches": 0, "reshard_bytes": 0,
          "mesh_resident_bytes_max": 0, "mesh_resident_bytes_min": 0,
          "device_s": 0.0, "profiled_dispatches": 0,
@@ -2086,7 +2087,7 @@ def sort_permutation(key_cols: List[Tuple[np.ndarray, np.ndarray]],
 
 #: up to this many leading rows, :func:`lex_head` selects them one by one
 #: instead of sorting every row
-LEX_SELECT_MAX = 64
+LEX_SELECT_MAX = 128
 
 
 def lex_head(ops, k: int):

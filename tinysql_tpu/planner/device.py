@@ -28,7 +28,8 @@ from ..mytypes import EvalType
 from ..ops.exprjit import is_jittable
 from .physical import (PhysicalHashAgg, PhysicalHashJoin,
                        PhysicalMergeJoin, PhysicalPlan, PhysicalProjection,
-                       PhysicalSelection, PhysicalSort, PhysicalTopN)
+                       PhysicalSelection, PhysicalSort, PhysicalTableReader,
+                       PhysicalTopN)
 
 _TPU_AGGS = {AGG_COUNT, AGG_SUM, AGG_AVG, AGG_MAX, AGG_MIN, AGG_FIRST_ROW}
 
@@ -58,7 +59,18 @@ def _input_rows(p: PhysicalPlan) -> float:
     before placement, so children carry estimates)."""
     if not p.children:
         return 0.0
-    return max(c.stats_row_count for c in p.children)
+    rows = max(c.stats_row_count for c in p.children)
+    child = p.children[0]
+    if isinstance(p, PhysicalHashAgg) \
+            and isinstance(child, PhysicalTableReader) \
+            and child.scan.ranges is None:
+        # an aggregate over a full scan reads every row of the table,
+        # whatever the scan's filters keep (on the device they are its
+        # mask): TPC-H Q10's partial sums over ``lineitem`` where
+        # ``l_returnflag = 'R'`` are a 6 M-row pass, not the 6 k rows an
+        # equality is guessed to keep
+        rows = max(rows, child.scan.stats_row_count)
+    return rows
 
 
 def tpu_admissibility(p: PhysicalPlan) -> Optional[str]:

@@ -224,6 +224,13 @@ def _device_info(st) -> str:
         # columns of the fused program's root no consumer reads: not
         # computed, packed or downloaded
         parts.append(f"dead_cols:{int(d['pipe_dead_cols'])}")
+    if d.get("pipe_joins"):
+        # joins traced into the fused program / those whose build side
+        # is a view; GROUP BYs cut to the key that determines the rest
+        parts.append(f"joins:{int(d['pipe_joins'])}"
+                     f"/{int(d.get('pipe_view_builds', 0))}view")
+    if d.get("agg_key_cut"):
+        parts.append(f"key_cut:{int(d['agg_key_cut'])}")
     if d.get("pipe_blocks"):
         from ..ops.kernels import pipe_overlap_frac
         overlap = pipe_overlap_frac(d)

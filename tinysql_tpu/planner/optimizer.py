@@ -494,7 +494,7 @@ def normalize_logical(logical: LogicalPlan,
     from .rules_extra import (eliminate_aggregation, eliminate_max_min,
                               eliminate_outer_joins, eliminate_projections,
                               join_reorder, push_agg_through_join,
-                              push_semi_joins_down)
+                              push_semi_joins_down, split_lookup_keys)
     root_needed = {c.unique_id for c in logical.schema.columns}
     _propagate_constants_in_plan(logical)
     logical = eliminate_outer_joins(logical, root_needed)
@@ -502,6 +502,11 @@ def normalize_logical(logical: LogicalPlan,
         retained, logical = predicate_pushdown(logical, [])
         if retained:
             logical = LogicalSelection(retained, logical)
+    # a semi/anti join above the FROM list's joins sinks to the table
+    # its keys come from before anything else looks at the chain (TPC-H
+    # Q18: the aggregate then stands on the inner joins and its partial
+    # sums go below them, to the table stored in the key's order)
+    logical = push_semi_joins_down(logical)
     logical = push_agg_through_join(logical)
     column_pruning(logical, root_needed)
     logical = eliminate_aggregation(logical)
@@ -510,7 +515,7 @@ def normalize_logical(logical: LogicalPlan,
     logical = join_reorder(logical, stats_of=_ds_row_count)
     # after reorder: the left-deep inner chain is in place, sink each
     # semi/anti join next to the side its keys come from
-    return push_semi_joins_down(logical)
+    return split_lookup_keys(push_semi_joins_down(logical))
 
 
 def optimize(logical: LogicalPlan, tpu: bool = True,

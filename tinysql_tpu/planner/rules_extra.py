@@ -138,6 +138,9 @@ def unique_key_sets(p: LogicalPlan) -> List[Set[int]]:
     if isinstance(p, LogicalAggregation):
         gb_outs = getattr(p, "gb_out_cols", [])
         if p.group_by and len(gb_outs) == len(p.group_by):
+            cut = _determining_key(p)
+            if cut is not None:
+                return [{gb_outs[cut].unique_id}]
             return [{c.unique_id for c in gb_outs}]
         return []
     if isinstance(p, LogicalJoin) and p.tp in ("semi", "anti"):
@@ -159,6 +162,60 @@ def unique_key_sets(p: LogicalPlan) -> List[Set[int]]:
             out += rkeys
         return out
     return []
+
+
+def _column_source(p: LogicalPlan, uid: int):
+    """(data source, its column) that the column ``uid`` of ``p``'s
+    schema is a plain copy of, or None: followed through the operators
+    that pass a column on as it is (selections, sorts, joins of every
+    kind, identity projections, group-by columns)."""
+    if isinstance(p, LogicalDataSource):
+        for c in p.schema.columns:
+            if c.unique_id == uid:
+                return p, c
+        return None
+    if isinstance(p, LogicalProjection):
+        for e, oc in zip(p.exprs, p.schema.columns):
+            if oc.unique_id == uid:
+                return _column_source(p.child(0), e.unique_id) \
+                    if isinstance(e, Column) else None
+        return None
+    if isinstance(p, LogicalAggregation):
+        for oc, e in zip(getattr(p, "gb_out_cols", []), p.group_by):
+            if oc.unique_id == uid:
+                return _column_source(p.child(0), e.unique_id) \
+                    if isinstance(e, Column) else None
+        return None
+    if isinstance(p, (LogicalSelection, LogicalSort, LogicalTopN,
+                      LogicalJoin)):
+        for c in p.children:
+            got = _column_source(c, uid)
+            if got is not None:
+                return got
+    return None
+
+
+def _determining_key(agg: LogicalAggregation) -> Optional[int]:
+    """The index of the group-by column that determines all the others,
+    where the catalog proves it: every group-by column is a plain column
+    of ONE table below and one of them is that table's primary key (each
+    row of a join's output holds one row of each of its tables, so the
+    key fixes the rest whatever the joins between).  None: no such
+    column, or a single key (nothing to cut)."""
+    if len(agg.group_by) < 2 \
+            or not all(isinstance(e, Column) for e in agg.group_by):
+        return None
+    srcs = [_column_source(agg.child(0), e.unique_id) for e in agg.group_by]
+    if any(s is None for s in srcs) \
+            or any(s[0] is not srcs[0][0] for s in srcs):
+        return None
+    pk = srcs[0][0].table_info.get_pk_handle_col()
+    if pk is None:
+        return None
+    for i, (_, c) in enumerate(srcs):
+        if c.name == pk.name:
+            return i
+    return None
 
 
 def _covers_unique_key(child: LogicalPlan, gb_uids: Set[int]) -> bool:
@@ -456,45 +513,10 @@ def push_agg_through_join(p: LogicalPlan) -> LogicalPlan:
     if not isinstance(p, LogicalAggregation) or not p.children:
         return p
     j = p.child(0)
-    if not isinstance(j, LogicalJoin) or j.tp != JOIN_INNER:
+    got = _push_side(p, j)
+    if got is None:
         return p
-    if j.other_conditions or not j.eq_conditions:
-        return p
-    if any(d.distinct for d in p.agg_funcs) or not p.agg_funcs:
-        return p
-    lsch, rsch = j.children[0].schema, j.children[1].schema
-    sides = []
-    for d in p.agg_funcs:
-        cols = [c for a in d.args for c in a.collect_columns()]
-        if not cols:
-            sides.append(None)
-        elif all(lsch.contains(c) for c in cols):
-            sides.append(0)
-        elif all(rsch.contains(c) for c in cols):
-            sides.append(1)
-        else:
-            return p
-    picked = {s for s in sides if s is not None}
-    if len(picked) != 1:
-        return p
-    side = picked.pop()
-    if (j.left_conditions if side == 0 else j.right_conditions):
-        return p
-    side_schema = lsch if side == 0 else rsch
-    keys = [(a if side == 0 else b) for a, b in j.eq_conditions]
-    if not all(isinstance(k, Column) for k in keys):
-        return p
-    # partial group keys: push-side group-by columns + push-side join keys
-    part_keys: List[Column] = []
-    for e in p.group_by:
-        cols = e.collect_columns()
-        if any(side_schema.contains(c) for c in cols):
-            if not isinstance(e, Column):
-                return p
-            part_keys.append(e)
-    for k in keys:
-        if not any(k.unique_id == c.unique_id for c in part_keys):
-            part_keys.append(k)
+    side, part_keys = got
 
     partial_descs: List[AggFuncDesc] = []
     partial_cols: List[Column] = []
@@ -514,10 +536,93 @@ def push_agg_through_join(p: LogicalPlan) -> LogicalPlan:
                                  part_schema, j.children[side])
     partial.output_cols = partial_cols
     partial.gb_out_cols = list(part_keys)  # pass-through identity
-    j.children[side] = partial
+    j.children[side] = _push_partial_further(partial)
     j.schema = j.children[0].schema.merge(j.children[1].schema)
     p.agg_funcs = final_descs
     return p
+
+
+def _push_side(p: LogicalAggregation, j: LogicalPlan):
+    """(side of the inner join ``j`` that the aggregation ``p`` above it
+    can be pre-aggregated on, the partial's group keys), or None: the
+    requirements of :func:`push_agg_through_join`."""
+    if not isinstance(j, LogicalJoin) or j.tp != JOIN_INNER:
+        return None
+    if j.other_conditions or not j.eq_conditions:
+        return None
+    if any(d.distinct for d in p.agg_funcs) or not p.agg_funcs:
+        return None
+    lsch, rsch = j.children[0].schema, j.children[1].schema
+    sides = []
+    for d in p.agg_funcs:
+        cols = [c for a in d.args for c in a.collect_columns()]
+        if not cols:
+            sides.append(None)
+        elif all(lsch.contains(c) for c in cols):
+            sides.append(0)
+        elif all(rsch.contains(c) for c in cols):
+            sides.append(1)
+        else:
+            return None
+    picked = {s for s in sides if s is not None}
+    if len(picked) != 1:
+        return None
+    side = picked.pop()
+    if (j.left_conditions if side == 0 else j.right_conditions):
+        return None
+    side_schema = lsch if side == 0 else rsch
+    keys = [(a if side == 0 else b) for a, b in j.eq_conditions]
+    if not all(isinstance(k, Column) for k in keys):
+        return None
+    # partial group keys: push-side group-by columns + push-side join keys
+    part_keys: List[Column] = []
+    for e in p.group_by:
+        cols = e.collect_columns()
+        if any(side_schema.contains(c) for c in cols):
+            if not isinstance(e, Column):
+                return None
+            part_keys.append(e)
+    for k in keys:
+        if not any(k.unique_id == c.unique_id for c in part_keys):
+            part_keys.append(k)
+    return side, part_keys
+
+
+def _push_partial_further(agg: LogicalAggregation) -> LogicalPlan:
+    """The partial aggregate the rule has just made may stand on a join
+    itself (TPC-H Q10: customer's columns over ``(customer join orders)
+    join lineitem``).  Where its arguments read one side of THAT join
+    and that side would group by the join's own key and nothing else
+    (``lineitem`` by ``l_orderkey``: the many lines of an order become
+    one row before the join meets them), the sums are made there and the
+    aggregate here merges them: a sum of sums, a sum of counts, a min of
+    mins.  Two keys or more are left alone: such a partial seldom
+    shrinks its input.  An AVG is a sum and a count by now."""
+    j = agg.child(0)
+    got = _push_side(agg, j)
+    if got is None or len(got[1]) != 1:
+        return agg
+    side, part_keys = got
+    merged = {AGG_SUM: AGG_SUM, AGG_COUNT: AGG_SUM, AGG_MIN: AGG_MIN,
+              AGG_MAX: AGG_MAX}
+    if any(d.name not in merged for d in agg.agg_funcs):
+        return agg
+    below_cols = [Column(d.partial_result_types()[0],
+                         name=f"partial2_{d.name}#{i}")
+                  for i, d in enumerate(agg.agg_funcs)]
+    below = LogicalAggregation(list(part_keys), list(agg.agg_funcs),
+                               Schema(below_cols + part_keys),
+                               j.children[side])
+    below.output_cols = below_cols
+    below.gb_out_cols = list(part_keys)
+    j.children[side] = _push_partial_further(below)
+    j.schema = j.children[0].schema.merge(j.children[1].schema)
+    from ..expression.aggregation import AggMode
+    agg.agg_funcs = [
+        AggFuncDesc(merged[d.name], [c], AggMode.PARTIAL1, False,
+                    d.partial_result_types()[0])
+        for d, c in zip(agg.agg_funcs, below_cols)]
+    return agg
 
 
 # ===== DP join reorder =====================================================
@@ -535,7 +640,14 @@ def _dp_best_tree(nodes, eqs, est):
     max(|L|,|R|) rows, a cartesian product |L|*|R|; plan cost = sum of
     intermediate result sizes.  Cartesian cost dominance makes the DP
     prefer any connected order before a product, which is the practical
-    win over the greedy's local choice."""
+    win over the greedy's local choice.  An equi-connection whose key
+    columns hold a unique key of NEITHER side, where both sides have
+    one (TPC-H Q5's ``c_nationkey = s_nationkey``: every customer beside
+    every supplier of its nation), is many-to-many and priced as the
+    product too: with no
+    distinct-value statistics the catalog's keys are what tells a
+    lookup from a blow-up, and an order of foreign key -> primary key
+    joins exists wherever the statement has one."""
     n = len(nodes)
     uids = [frozenset(c.unique_id for c in nd.schema.columns)
             for nd in nodes]
@@ -544,6 +656,11 @@ def _dp_best_tree(nodes, eqs, est):
         au = frozenset(c.unique_id for c in a.collect_columns())
         bu = frozenset(c.unique_id for c in b.collect_columns())
         edge_sides.append((au, bu))
+    #: keys[mask]: column-id sets unique among the rows of the join of
+    #: ``mask``'s nodes, as the cheapest tree found for them proves
+    #: (empty: nothing is known, not "nothing is unique")
+    keys = {1 << i: [frozenset(k) for k in unique_key_sets(nodes[i])]
+            for i in range(n)}
 
     def mask_uids(mask):
         out = set()
@@ -555,11 +672,26 @@ def _dp_best_tree(nodes, eqs, est):
     mu = {1 << i: set(uids[i]) for i in range(n)}
 
     def connected(lmask, rmask):
+        """None: no equi condition joins the two; else (left is unique
+        on its key columns, right is on its)."""
         lu, ru = mu[lmask], mu[rmask]
+        leq, req = set(), set()
         for au, bu in edge_sides:
-            if (au <= lu and bu <= ru) or (bu <= lu and au <= ru):
-                return True
-        return False
+            if au <= lu and bu <= ru:
+                leq |= au
+                req |= bu
+            elif bu <= lu and au <= ru:
+                leq |= bu
+                req |= au
+        if not leq:
+            return None
+        lkeys, rkeys = keys.get(lmask, ()), keys.get(rmask, ())
+        if not lkeys or not rkeys:
+            # a side whose keys are not known (a table without a primary
+            # key, an operator nothing is proved of): priced as a lookup,
+            # as before keys were read at all
+            return True, True
+        return (any(k <= leq for k in lkeys), any(k <= req for k in rkeys))
 
     # best[mask] = (cost, rows, tree)
     best = {1 << i: (0.0, max(est(nodes[i]), 1.0), i) for i in range(n)}
@@ -578,11 +710,19 @@ def _dp_best_tree(nodes, eqs, est):
                 if l in best and r in best:
                     cl, rl, tl = best[l]
                     cr, rr, tr = best[r]
-                    rows = (max(rl, rr) if connected(l, r)
+                    uniq = connected(l, r)
+                    rows = (max(rl, rr) if uniq is not None and any(uniq)
                             else rl * rr)
                     cost = cl + cr + rows
                     if cand is None or cost < cand[0]:
                         cand = (cost, rows, (tl, tr))
+                        # a side's keys survive where the other side
+                        # matches each of its rows at most once
+                        known = uniq is not None \
+                            and keys.get(l) and keys.get(r)
+                        keys[mask] = [] if not known else (
+                            (keys[l] if uniq[1] else [])
+                            + (keys[r] if uniq[0] else []))
             sub = (sub - 1) & mask
         if cand is not None:
             best[mask] = cand
@@ -603,3 +743,28 @@ def _build_join_tree(tree, nodes, pending_eqs):
     j = LogicalJoin(JOIN_INNER, lplan, rplan)
     still = _attach_eqs(j, luids, ruids, pending_eqs)
     return j, luids | ruids, still
+
+
+# ===== lookup joins on one key ==============================================
+
+def split_lookup_keys(p: LogicalPlan) -> LogicalPlan:
+    """An inner join on several equalities, one of which alone is a
+    unique key of its side (TPC-H Q5: ``l_suppkey = s_suppkey and
+    c_nationkey = s_nationkey`` against supplier by its primary key),
+    is a lookup by that key and a filter on the rest: the join keeps
+    the one equality, a selection above it the others.  The lookup then
+    needs no composite key, on any tier; NULLs match neither way."""
+    p.children = [split_lookup_keys(c) for c in p.children]
+    if not (isinstance(p, LogicalJoin) and p.tp == JOIN_INNER
+            and len(p.eq_conditions) > 1):
+        return p
+    for side in (1, 0):
+        keys = unique_key_sets(p.children[side])
+        for i, pair in enumerate(p.eq_conditions):
+            k = pair[side]
+            if isinstance(k, Column) and {k.unique_id} in keys:
+                rest = p.eq_conditions[:i] + p.eq_conditions[i + 1:]
+                p.eq_conditions = [pair]
+                return LogicalSelection(
+                    [new_function("=", [a, b]) for a, b in rest], p)
+    return p

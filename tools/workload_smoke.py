@@ -15,7 +15,10 @@ TPC-H Q5/Q10/Q18 end-to-end through the SQL front door at SF=0.02:
    the nation/region subtree (the semi-join sink rule), and ``EXPLAIN
    ANALYZE`` must carry device counters on the join chain;
 5. UPDATE must round-trip through the same front door (the read path
-   shares the decorrelated planner).
+   shares the decorrelated planner);
+6. with the chip's branches forced (``TINYSQL_DEVICE_JOIN_ONLY=1``,
+   ``tidb_devpipe = 1``) every query is ONE fused device program: one
+   dispatch, no host twin, the same rows.
 
 Exit 0 on success; prints one line per check.
 """
@@ -70,6 +73,29 @@ def main() -> int:
         check(f"{q} second run compiles nothing",
               d2.get("progcache_misses", 0) == 0,
               f"misses={d2.get('progcache_misses', 0)}")
+
+    # the chip's branches forced on the CPU: each of the three is ONE
+    # fused device program, no host twin, still sqlite's answer
+    os.environ["TINYSQL_DEVICE_JOIN_ONLY"] = "1"
+    s.execute("set @@tidb_devpipe = 1")
+    try:
+        for q, sql in tpch.WORKLOAD.items():
+            want = _canon(lite.execute(sql).fetchall())
+            snap = kernels.stats_snapshot()
+            got = _canon(s.query(sql).rows)
+            d = kernels.stats_delta(snap)
+            check(f"{q} fused matches sqlite", got == want,
+                  f"{len(got)} rows vs {len(want)}")
+            check(f"{q} is one fused program",
+                  d.get("dispatches", 0) == 1
+                  and d.get("host_dispatches", 0) == 0
+                  and d.get("pipe_joins", 0) >= 3,
+                  f"dispatches={d.get('dispatches', 0)} "
+                  f"host={d.get('host_dispatches', 0)} "
+                  f"joins={d.get('pipe_joins', 0)}")
+    finally:
+        s.execute("set @@tidb_devpipe = -1")
+        del os.environ["TINYSQL_DEVICE_JOIN_ONLY"]
 
     plan = s.query("explain " + tpch.Q5).rows
     flat = "\n".join(str(r) for r in plan)
