@@ -359,9 +359,14 @@ Q3_BUCKET = 1 << 17
     # of customer's two columns (four u32 and two pred gathers gone).
     # The aggregate's boundary gathers are presence's and revenue's:
     # l_extendedprice * (1 - l_discount) is NULL on no row, so its count
-    # is presence (the parent held six: a count's two more)
-    ("consumer", {"aggindex": 4, "join/join": 2, "join": 8}),
-    ("all", {"aggindex": 4, "join/join": 8, "join": 8}),
+    # is presence (the parent held six: a count's two more).  The outer
+    # join reads revenue and l_orderkey off the group table, whose view
+    # proves both free of NULLs in a group that exists: tbl[pos0],
+    # bvalid[pos], two u32 a value and no null lane (PR 35: 8); with
+    # every slot live the inner join reads customer's two columns the
+    # same way (PR 35: 8)
+    ("consumer", {"aggindex": 4, "join/join": 2, "join": 6}),
+    ("all", {"aggindex": 4, "join/join": 6, "join": 6}),
 ])
 def test_q3_gathers_at_the_bucket(one_chip, chip_branches, monkeypatch,
                                   tpch_session, live, expect):
@@ -373,8 +378,8 @@ def test_q3_gathers_at_the_bucket(one_chip, chip_branches, monkeypatch,
 
 
 @pytest.mark.parametrize("live, whole, quarter", [
-    ("consumer", {"aggindex": 4}, {"join/join": 2, "join": 8}),
-    ("all", {"aggindex": 4}, {"join/join": 8, "join": 8}),
+    ("consumer", {"aggindex": 4}, {"join/join": 2, "join": 6}),
+    ("all", {"aggindex": 4}, {"join/join": 6, "join": 6}),
 ])
 def test_q3_mesh_gathers_a_chip(topo, chip_branches, monkeypatch,
                                 tpch_session, live, whole, quarter):
@@ -388,29 +393,51 @@ def test_q3_mesh_gathers_a_chip(topo, chip_branches, monkeypatch,
     assert _gathers(text, Q3_BUCKET // 4) == quarter
 
 
-@pytest.mark.parametrize("where", ["one", "mesh"])
-@pytest.mark.parametrize("name", ["Q1", "Q3", "Q6"])
-def test_lowered_text_is_the_parents(topo, one_chip, chip_branches,
-                                     monkeypatch, tpch_session, name, where):
-    """The programs of the statements the benchmark already had lower to
-    the text of the parent commit, byte for byte, on one chip and on the
-    mesh: what PR 35 adds for the join chains of Q5, Q10 and Q18 (the
-    planner's key-aware join order, view builds, the keyed GROUP BY, a
-    longer selected TopN head) follows the plan's shape and leaves Q1
-    (a dense GROUP BY at the statement's root), Q3 (two joins under a
-    sorted aggregate, 14 gathers) and Q6 (no fused pipeline) alone."""
+def _no_flags(monkeypatch):
+    """Every view is built with no slot proved free of NULLs: the
+    programs of the commit before the flags."""
+    from tinysql_tpu.executor import devpipe
+    init = devpipe._TView.__init__
+
+    def init_empty(self, emit, nb, meta, scope, nonnull=frozenset()):
+        init(self, emit, nb, meta, scope)
+    monkeypatch.setattr(devpipe._TView, "__init__", init_empty)
+
+
+def _pinned(name):
     with open(os.path.join(os.path.dirname(__file__), "testdata",
-                           "lowered_at_129247b.json")) as f:
+                           name)) as f:
         pinned = json.load(f)
     if pinned["jax"] != jax.__version__:
         pytest.skip(f"pinned under jax {pinned['jax']}")
+    return pinned["sha256"]
+
+
+@pytest.mark.parametrize("flags", ["off", "on"])
+@pytest.mark.parametrize("where", ["one", "mesh"])
+@pytest.mark.parametrize("name", ["Q1", "Q3", "Q6"])
+def test_lowered_text_is_the_parents(topo, one_chip, chip_branches,
+                                     monkeypatch, tpch_session, name, where,
+                                     flags):
+    """With no view saying which of its slots hold no NULL (``off``),
+    the programs of Q1, Q3 and Q6 lower to the text pinned at 129247b,
+    which PR 35 left as it was, byte for byte, on one chip and on the
+    mesh: a join gathers every null lane and an aggregate reduces every
+    count, as before.  With the flags, Q1 (a dense GROUP BY at the
+    statement's root: no reader of a flag) and Q6 (no fused pipeline)
+    still do, and Q3, whose outer join gathers two null lanes fewer,
+    lowers to the text pinned with PR 36."""
+    if flags == "off":
+        _no_flags(monkeypatch)
+    pinned = _pinned("lowered_at_pr36.json" if (name, flags) == ("Q3", "on")
+                     else "lowered_at_129247b.json")
     sql = tpch.QUERIES[name]
     fn, abstract = _capture(monkeypatch, tpch_session, sql, one_chip) \
         if where == "one" \
         else _capture_mesh(topo, monkeypatch, tpch_session, sql)
     text = fn.lower(*abstract).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() \
-        == pinned["sha256"][f"{name}.{where}"]
+        == pinned[f"{name}.{where}"]
 
 
 # ---- the join chains: Q5, Q10, Q18 as one fused program each ---------------
@@ -456,6 +483,49 @@ def test_fused_join_chain_program(one_chip, chip_branches, monkeypatch,
     assert "sortgroup" not in kinds, kinds
     assert all(n < ORDERS_BUCKET for n in _sorted_lanes(text)), \
         sorted(set(_sorted_lanes(text)))
+
+
+def _scatters(text, scope):
+    """The compiled text's scatters traced under a node of ``scope``."""
+    return sum(1 for line in text.splitlines() if " scatter(" in line
+               and scope in re.search(r'op_name="([^"]*)"',
+                                      line).group(1).split("/"))
+
+
+#: ``lineitem``'s bucket at SF=0.05
+LINEITEM_BUCKET = 1 << 19
+
+
+@pytest.mark.parametrize("name, rows, gathers, scatters", [
+    # the two joins at the lineitem bucket gather (orders join customer)'s
+    # c_nationkey and supplier's s_nationkey: tbl[pos0], bvalid[pos] and
+    # two u32 of the value each, and no null lane (the parent: 5 and 5)
+    ("Q5", LINEITEM_BUCKET, {"join/join": 4, "join/join/join": 4}, 0),
+    # at the orders bucket: the aggregate's four boundary gathers; the
+    # join into the group table reads revenue, the join below it
+    # c_custkey's side, each without its null lane (the parent: 5 and
+    # 5); the keyed GROUP BY on c_custkey scatter-adds presence and the
+    # sum, whose own count is presence (the parent: 3 scatters)
+    ("Q10", ORDERS_BUCKET, {"aggindex": 4, "join/join": 4,
+                            "join/join/join": 4}, 2),
+    # two aggregates of four boundary gathers; sum(l_quantity) off the
+    # group table, and two columns under the join below (the semi join's
+    # two gathers count there), without null lanes (the parent: 5, 10)
+    ("Q18", ORDERS_BUCKET, {"aggindex": 8, "join": 4, "join/join": 8}, 0),
+])
+def test_join_chains_gather_no_constant_null_lane(
+        one_chip, chip_branches, monkeypatch, tpch_session, name, rows,
+        gathers, scatters):
+    """The chains' gathers at the probes' buckets and the keyed GROUP
+    BY's scatters, counted by the node that traced them: a build column
+    whose view holds no NULL on a valid row (``_TView.nonnull``) is
+    gathered without its null lane, and a sum of such an argument
+    reduces no count of its own."""
+    fn, abstract = _capture(monkeypatch, tpch_session, tpch.WORKLOAD[name],
+                            one_chip)
+    text = _compile(fn, *abstract).as_text()
+    assert _gathers(text, rows) == gathers
+    assert _scatters(text, "keygroup") == scatters
 
 
 # ---- the same at the SF=10 shapes ------------------------------------------

@@ -705,10 +705,10 @@ def lives(monkeypatch):
     seen = []
     key = devpipe._PipeBuilder.key
 
-    def spy(self, part, live=None, n=0):
+    def spy(self, part, live=None, n=0, *args):
         if live is not None and len(live) < n:
             seen.append((part[0], sorted(live), n))
-        return key(self, part, live, n)
+        return key(self, part, live, n, *args)
     monkeypatch.setattr(devpipe._PipeBuilder, "key", spy)
     return seen
 
@@ -789,19 +789,30 @@ def tpch_tk():
     return s, tpch.QUERIES
 
 
-@pytest.mark.parametrize("name, dead", [("Q1", 0), ("Q3", 4)])
-def test_pipe_dead_cols_of_the_benchmarks_statements(tpch_tk, name, dead):
-    """Q3's consumer reads four of the TopN's eight slots; Q1's pipe is
-    the statement's root."""
-    s, queries = tpch_tk
-    got, want, delta = _dead_cols(s, queries[name])
+@pytest.mark.parametrize("name, dead, skipped", [
+    ("Q1", 0, 0), ("Q3", 4, 2), ("Q6", 0, 0), ("Q5", 0, 6), ("Q10", 2, 4),
+    ("Q18", 2, 3)])
+def test_pipe_counters_of_the_benchmarks_statements(tpch_tk, name, dead,
+                                                    skipped):
+    """Q3's consumer reads four of the TopN's eight slots, and its outer
+    join reads revenue and l_orderkey off the group table without their
+    null lanes; Q10 leaves out three lanes and its sum's count; Q1's
+    pipe is the statement's root and has no join, Q6 builds no fused
+    pipeline."""
+    from tinysql_tpu.bench import tpch
+    s, _queries = tpch_tk
+    sql = {**tpch.QUERIES, **tpch.WORKLOAD}[name]
+    got, want, delta = _dead_cols(s, sql)
     assert len(got) == len(want) and got
     for a, b in zip(got, want):
         assert all(x == pytest.approx(y, rel=1e-9) if isinstance(y, float)
                    else x == y for x, y in zip(a, b)), (a, b)
-    assert delta["pipe_dead_cols"] == dead
-    info = s.query("explain analyze " + queries[name]).rows
+    assert delta.get("pipe_dead_cols", 0) == dead
+    assert delta.get("pipe_const_nulls", 0) == skipped
+    info = s.query("explain analyze " + sql).rows
     assert any(f"dead_cols:{dead}" in str(r) for r in info) == bool(dead)
+    assert any(f"const_nulls:{skipped}" in str(r) for r in info) \
+        == bool(skipped)
 
 
 def test_a_dead_slot_raises_when_read(tk, monkeypatch):
@@ -840,7 +851,11 @@ def test_two_consumers_of_one_plan_shape_are_two_programs(tk):
     pipes = progcache.keys("pipe")
     assert len(pipes) == 3
     assert len({k[:4] for k in pipes}) == 1  # one shape, one signature
-    assert sorted(len(k[4][1]) for k in pipes if len(k) > 4) == [2, 3]
+    # the third reads every slot, dm.k among them, whose null lane its
+    # join leaves out: said beside the node keys, as a live set is
+    assert sorted(k[4][1:] for k in pipes) == [
+        ((0, 1, 2, 3), (2, "nonnull", (0,))),
+        ((0, 1, 3), (2, (0, 1, 3))), ((1, 3), (2, (1, 3)))]
     for sql in sqls + sqls[::-1]:
         got, want, delta = _dead_cols(tk, sql)
         assert _canon(got) == _canon(want)
@@ -913,11 +928,13 @@ def test_dead_aggregate_slots_are_not_computed(tk, monkeypatch, case):
         assert computed and all(n == specs for n in computed), computed
 
 
-def test_pipe_dead_cols_on_metrics_and_in_the_benchmark(tpch_tk):
-    """The counter's other readers: ``/metrics`` and the benchmark's
-    ``pipe_dead_cols_per_query.*`` (a data file over the accepted
-    ``counter`` reader; a program without the counter, as the parent,
-    leaves the metric out)."""
+@pytest.mark.parametrize("key, grown", [("pipe_dead_cols", 4),
+                                        ("pipe_const_nulls", 2)])
+def test_pipe_counters_on_metrics_and_in_the_benchmark(tpch_tk, key, grown):
+    """The counters' other readers: ``/metrics`` and the benchmark's
+    ``<key>_per_query.*`` (a data file over the accepted ``counter``
+    reader; a program without the counter, as the parent, leaves the
+    metric out)."""
     import importlib.util
     import json
     import os
@@ -929,17 +946,17 @@ def test_pipe_dead_cols_on_metrics_and_in_the_benchmark(tpch_tk):
     for name in ("Q1", "Q3", "Q6"):
         s.query(queries[name])
     delta = kernels.stats_delta(before)
-    assert delta["pipe_dead_cols"] == 4
+    assert delta[key] == grown
     text = metrics.render_prometheus()
     total = [line for line in text.splitlines()
-             if line.startswith("tinysql_pipe_dead_cols_total ")]
-    assert total and float(total[0].split()[1]) >= 4
+             if line.startswith(f"tinysql_{key}_total ")]
+    assert total and float(total[0].split()[1]) >= grown
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "layer_metrics",
-                           "pipe_dead_cols_per_query.json")) as f:
+                           f"{key}_per_query.json")) as f:
         spec = json.load(f)
     assert spec["reader"] == "counter" and spec["sources"] == ["kernels"]
-    assert spec["args"] == {"source": "kernels", "key": "pipe_dead_cols",
+    assert spec["args"] == {"source": "kernels", "key": key,
                             "per_statement": True}
     path = os.path.join(root, "benchmark", "readers", "counter.py")
     mod_spec = importlib.util.spec_from_file_location("bench_counter", path)
@@ -947,23 +964,223 @@ def test_pipe_dead_cols_on_metrics_and_in_the_benchmark(tpch_tk):
     mod_spec.loader.exec_module(counter)
     run = SimpleNamespace(deltas={"kernels": delta},
                           answered=[object()] * 3)
-    assert counter.read(run, **spec["args"]) == pytest.approx(4 / 3)
+    assert counter.read(run, **spec["args"]) == pytest.approx(grown / 3)
     run.deltas = {"kernels": {"dispatches": 3}}   # the parent's counters
     assert counter.read(run, **spec["args"]) is None
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     mine = [m for m in bench["per_layer"]
-            if m["name"].startswith("pipe_dead_cols_per_query.")]
+            if m["name"].startswith(f"{key}_per_query.")]
     assert [(m["name"], m["workloads"]) for m in mine] \
-        == [("pipe_dead_cols_per_query.stream", ["tpch_sf1.power_stream"]),
-            ("pipe_dead_cols_per_query.mesh",
-             ["tpch_sf1_mesh4.power_stream"]),
-            ("pipe_dead_cols_per_query.mesh10",
-             ["tpch_sf10_mesh4.power_stream"]),
-            ("pipe_dead_cols_per_query.joins",
-             ["tpch_sf1_joins.join_stream"])]
+        == [(f"{key}_per_query.stream", ["tpch_sf1.power_stream"]),
+            (f"{key}_per_query.mesh", ["tpch_sf1_mesh4.power_stream"]),
+            (f"{key}_per_query.mesh10", ["tpch_sf10_mesh4.power_stream"]),
+            (f"{key}_per_query.joins", ["tpch_sf1_joins.join_stream"])]
     for m in mine:
         assert (m["unit"], m["better"], m["source"], m["layer"],
                 m["moves"]) == ("count", "higher", "program_counter",
                                 "executor: fused pipeline",
                                 "stream_queries_per_s")
+
+
+# ---- NULL-freedom: a view says which slots hold no NULL on a valid row -----
+
+_NODE_KINDS = [(devpipe._ReplicaLeaf, "leaf"), (devpipe._HostLeaf, "host"),
+               (devpipe._AggIndexNode, "agg"), (devpipe._JoinNode, "join"),
+               (devpipe._SortGroupNode, "sortgroup"),
+               (devpipe._KeyGroupNode, "keygroup"),
+               (devpipe._ScalarAggNode, "scalaragg"),
+               (devpipe._SelNode, "sel"), (devpipe._ProjNode, "proj"),
+               (devpipe._OrderNode, "order"), (devpipe._LimitNode, "limit")]
+
+
+@pytest.fixture
+def flags(monkeypatch):
+    """[(node kind, its view's NULL-free slots, its slot count)] of every
+    node that prepared, children before parents."""
+    seen = []
+    for cls, kind in _NODE_KINDS:
+        def mk(orig, kind):
+            def prepare(self, pb, *args, **kw):
+                tv = orig(self, pb, *args, **kw)
+                if tv is not None:
+                    seen.append((kind, sorted(tv.nonnull), len(tv.meta)))
+                return tv
+            return prepare
+        monkeypatch.setattr(cls, "prepare", mk(cls.prepare, kind))
+    return seen
+
+
+#: name -> (statement, every node's view flags, pipe_const_nulls).  Over
+#: ``_live_tables``: f(a, b | c, fk, tag hold NULLs), dm(k, v | w, name
+#: hold NULLs), dd(v | k, label hold NULLs)
+FLAG_CASES = {
+    # a leaf says what its replica's null masks show, of the columns the
+    # plan kept; a TopN hands the flags on; a projection proves a column,
+    # a sum and a negation of NULL-free columns, not a division (by
+    # zero) nor anything over a column with NULLs
+    "proj": ("select f.a, f.a + f.b, f.a / f.b, f.c + 1, -f.b from f "
+             "join dm on f.fk = dm.k order by f.a limit 5",
+             [("leaf", [0], 1), ("leaf", [0, 1], 4), ("join", [0, 1, 4], 5),
+              ("order", [0, 1, 4], 5), ("proj", [0, 1, 4], 5)], 0),
+    # an inner join keeps both sides' flags: its valid rows all matched.
+    # dm.k and dm.v go up without their null lanes, dm.w with its own
+    "inner": ("select f.a, f.c, dm.k, dm.v, dm.w from f join dm "
+              "on f.fk = dm.k where f.b > 0",
+              [("leaf", [0, 1], 3), ("leaf", [0, 1], 4),
+               ("join", [0, 1, 4, 5], 7), ("proj", [0, 2, 3], 5)], 2),
+    # a left outer join gathers the same way (NULL exactly where nothing
+    # matched) but its unmatched rows are valid: no build slot is proved
+    "left": ("select f.a, f.c, dm.k, dm.v, dm.w from f left join dm "
+             "on f.fk = dm.k",
+             [("leaf", [0, 1], 3), ("leaf", [0], 3), ("join", [0], 6),
+              ("proj", [0], 5)], 2),
+    # a semi join's view is its probe's
+    "semi": ("select f.a, f.b from f where f.fk in "
+             "(select k from dm where v > 300)",
+             [("leaf", [0, 1], 2), ("proj", [0], 1), ("leaf", [0, 1], 3),
+              ("join", [0, 1], 3), ("proj", [0, 1], 2)], 0),
+    # the sorted aggregate: counts always; sum(b), avg(b), min(a) over
+    # NULL-free arguments (their count is presence); not sum(c), avg(c);
+    # not the key, which has a NULL group
+    "sorted": ("select fk, count(*), count(c), sum(c), sum(b), avg(b), "
+               "avg(c), min(a) from f group by fk",
+               [("leaf", [0, 1], 4), ("agg", [0, 1, 3, 4, 6], 8),
+                ("proj", [1, 2, 4, 5, 7], 8)], 0),
+    # the dense one proves its counts alone: its sums keep their counts
+    "dense": ("select tag, count(*), sum(b), max(c) from f group by tag",
+              [("leaf", [0], 3), ("agg", [0], 4)], 0),
+    # a keyed GROUP BY above a join: the partial sum(b), count and
+    # max(b) arrive NULL-free through the inner join, so their merges
+    # reduce no count of their own (3) and dm.v's null lane is not
+    # gathered (1); sum(c) keeps everything; the key dm.v holds no NULL
+    "keyed": ("select dm.v, count(*), sum(f.b), sum(f.c), max(f.b) from f "
+              "join dm on f.fk = dm.k group by dm.v",
+              [("leaf", [0, 1], 2), ("leaf", [0], 3),
+               ("agg", [0, 1, 3], 5), ("join", [0, 1, 3, 5, 6], 7),
+               ("keygroup", [0, 1, 3, 4], 5), ("proj", [0, 1, 2, 4], 5)],
+              4),
+    # a scalar aggregate's one row is valid over an empty input, where a
+    # sum is NULL whatever its argument: counts alone are proved, and
+    # sum(f.b) still takes the rows' count for its own
+    "scalar": ("select count(*), sum(f.b), min(dm.w), count(f.c) from f "
+               "join dm on f.fk = dm.k",
+               [("leaf", [0], 2), ("leaf", [0], 3), ("join", [0, 3], 5),
+                ("scalaragg", [0, 3], 4)], 1),
+    # the CSR join (duplicate and NULL build keys): a matched slot reads
+    # a valid build row, so dd.v goes up without its null lane, inner
+    # and outer
+    "csr": ("select f.a, dd.v, dd.label from f join dd on f.fk = dd.k "
+            "where f.c < 60",
+            [("leaf", [1], 3), ("leaf", [0], 3), ("join", [0, 4], 6)], 1),
+    "csr_left": ("select f.a, dd.v from f left join dd on f.fk = dd.k",
+                 [("leaf", [1], 2), ("leaf", [0], 2), ("join", [0], 4),
+                  ("proj", [0], 2)], 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FLAG_CASES))
+def test_views_say_which_slots_hold_no_null(tk, flags, case):
+    _live_tables(tk)
+    sql, nodes, skipped = FLAG_CASES[case]
+    got, want, delta = _dead_cols(tk, sql)
+    assert _canon(got) == _canon(want) and got, sql
+    assert delta["dispatches"] == 1
+    assert flags == nodes
+    assert delta.get("pipe_const_nulls", 0) == skipped
+
+
+def test_sort_group_and_selection_hand_the_flags_on(tk, flags, monkeypatch):
+    """The sorted GROUP BY above a view (the keyed one's range refused)
+    and a selection above it: a key is NULL in a group only if it was on
+    a row, and a filter only drops rows."""
+    _live_tables(tk)
+    monkeypatch.setattr(devpipe._KeyGroupNode, "cut_of",
+                        staticmethod(lambda child, key_cols, ctx: None))
+    sql = ("select dm.v, count(*), sum(f.b), sum(f.c) from f join dm "
+           "on f.fk = dm.k group by dm.v having sum(f.b) > 0")
+    got, want, delta = _dead_cols(tk, sql)
+    assert _canon(got) == _canon(want) and got
+    kinds = [k for k, _, _ in flags]
+    assert "sortgroup" in kinds and "keygroup" not in kinds
+    group = flags[kinds.index("sortgroup")]
+    assert group == ("sortgroup", [0, 1, 3], 4)
+    assert flags[kinds.index("sel")] == ("sel", [0, 1, 3], 4)
+    assert delta["pipe_const_nulls"] == 3  # dm.v's lane, two counts
+
+
+def _null_build_tables(tk):
+    """``dn``: a unique build side whose column ``x`` holds NULLs on rows
+    a filter on ``v`` keeps, ``y`` only on rows it drops, ``z`` none."""
+    _live_tables(tk)
+    rng = np.random.default_rng(17)
+    k = np.arange(1, 301, dtype=np.int64)
+    v = rng.integers(0, 1000, 300).astype(np.int64)
+    _load(tk, "dn", "k bigint primary key, v bigint, x bigint, y bigint, "
+                    "z bigint",
+          {"k": (k, None), "v": (v, None),
+           "x": (rng.integers(0, 9, 300).astype(np.int64),
+                 rng.random(300) < 0.3),
+           "y": (rng.integers(0, 9, 300).astype(np.int64), v <= 500),
+           "z": (rng.integers(0, 9, 300).astype(np.int64), None)})
+
+
+#: join type -> (a two-join chain over ``dn``, null lanes left out)
+NULL_BUILD_CASES = {
+    # (dn join wz) is the build view of the join f probes: x and y keep
+    # their lanes; wz.z's goes below, dn.z's and wz.z's above
+    "inner": ("select f.a, f.tag, dn.x, dn.y, dn.z, wz.z from f join dn "
+              "on f.fk = dn.k join wz on dn.z = wz.g where dn.v > 500", 3),
+    # z's lane off dn and wz.z's off wz; the second join's probe key
+    # dn.z is NULL on the first's unmatched rows
+    "left": ("select f.a, f.tag, dn.x, dn.y, dn.z, wz.z from f left join "
+             "dn on f.fk = dn.k and dn.v > 500 left join wz "
+             "on dn.z = wz.g", 2),
+}
+
+
+@pytest.mark.parametrize("tp", sorted(NULL_BUILD_CASES))
+def test_a_build_column_with_nulls_keeps_its_lane(tk, tp):
+    """A chain whose build columns DO hold NULLs, on a valid row (x) and
+    on a filtered-out row alone (y: the leaf sees the column's mask, not
+    the filter): both lanes are gathered and the answers are the CPU
+    executors', NULLs and all; the lanes of z and wz.z are not."""
+    _null_build_tables(tk)
+    sql, skipped = NULL_BUILD_CASES[tp]
+    got, want, delta = _dead_cols(tk, sql)
+    assert _canon(got) == _canon(want) and got
+    assert any(r[2] is None and r[4] is not None for r in got)
+    assert any(r[2] is not None for r in got)
+    assert (tp == "left") == any(r[3] is None for r in got)
+    assert delta["dispatches"] == 1
+    assert delta["pipe_const_nulls"] == skipped
+
+
+def test_a_null_written_after_the_program_is_cached(tk):
+    """The skipped lanes are part of the program's key: once a NULL is
+    written into the build column, the next replica version's statement
+    builds another program (it never meets the cached one that skipped
+    the lane) and its answer shows the NULL."""
+    from tinysql_tpu.ops import progcache
+    _live_tables(tk)
+    sql = ("select f.a, f.tag, dm.v from f join dm on f.fk = dm.k "
+           "where f.b > 40")
+    progcache.clear()
+    got, want, delta = _dead_cols(tk, sql)
+    assert _canon(got) == _canon(want) and got
+    assert delta["pipe_const_nulls"] == 1 and delta["progcache_misses"] == 1
+    assert not any(r[2] is None for r in got)
+    (skipping,) = progcache.keys("pipe")
+    assert (2, "nonnull", (1,)) in skipping[4]
+    tk.execute("update dm set v = null where k < 200")
+    for _ in range(3):  # the first may read past a replica being rebuilt
+        got, want, delta = _dead_cols(tk, sql)
+        assert _canon(got) == _canon(want)
+        assert any(r[2] is None for r in got)
+        if delta.get("dispatches"):
+            break
+    assert delta["dispatches"] == 1 and delta["progcache_misses"] == 1
+    assert delta.get("pipe_const_nulls", 0) == 0
+    (keeping,) = [k for k in progcache.keys("pipe") if k != skipping]
+    assert keeping[:4] == skipping[:4]
+    assert not any("nonnull" in part for part in keeping[4][2:])

@@ -804,3 +804,88 @@ def test_q3_over_the_mesh_leaves_four_slots_dead(tpch_mesh, monkeypatch, n):
         s.execute("set @@tidb_mesh_parallel = 0")
     assert q3["pipe_dead_cols"] == 4
     assert q1["pipe_dead_cols"] == 0 and q6["pipe_dead_cols"] == 0
+
+
+# ---- NULL-freedom under the mesh ---------------------------------------------
+# A view says which slots hold no NULL on a valid row; a join gathers no
+# such null lane, broadcast or partitioned (executor/devpipe.py).
+
+@pytest.fixture
+def null_tk(live_tk):
+    """``live_tk`` with ``dimx``: a unique build side whose ``x`` holds
+    NULLs on rows a filter on ``v`` keeps, ``y`` only on rows it drops,
+    ``z`` none."""
+    import numpy as np
+    from tinysql_tpu.columnar.store import bulk_load
+    rng = np.random.default_rng(31)
+    v = rng.integers(0, 50, 150).astype(np.int64)
+    s = live_tk
+    s.execute("create table dimx (k bigint primary key, v bigint, "
+              "x bigint, y bigint, z bigint)")
+    bulk_load(s.storage, s.infoschema().table_by_name("jm", "dimx"),
+              {"k": np.arange(1, 151, dtype=np.int64), "v": v,
+               "x": rng.integers(0, 9, 150).astype(np.int64),
+               "y": rng.integers(0, 9, 150).astype(np.int64),
+               "z": rng.integers(0, 9, 150).astype(np.int64)},
+              {"x": rng.random(150) < 0.3, "y": v <= 25})
+    return s
+
+
+#: name -> (statement, null lanes left out (over the mesh, on one device),
+#: forced to partition)
+MESH_NULL_CASES = {
+    # x and y keep their lanes (NULL on a valid row; on a filtered-out
+    # row alone: the leaf sees the column's mask, not the filter), z's
+    # and (under the mesh, where the join may partition) the key's go
+    "inner": ("select fact.a, fact.tag, dimx.x, dimx.y, dimx.z from fact "
+              "join dimx on fact.fk = dimx.k where dimx.v > 25", (1, 1), False),
+    "left": ("select fact.a, fact.tag, dimx.x, dimx.y, dimx.z from fact "
+             "left join dimx on fact.fk = dimx.k and dimx.v > 25", (1, 1),
+             False),
+    "inner_partitioned": (
+        "select fact.a, fact.tag, dimx.x, dimx.y, dimx.z from fact "
+        "join dimx on fact.fk = dimx.k where dimx.v > 25", (1, 1), True),
+    "left_partitioned": (
+        "select fact.a, fact.tag, dimx.x, dimx.y, dimx.z from fact "
+        "left join dimx on fact.fk = dimx.k and dimx.v > 25", (1, 1),
+        True),
+    # two joins off one probe (Q3's shape): dimn's v off the lower, z
+    # off the upper, x with its lane
+    "chain": ("select fact.a, fact.tag, dimn.v, dimx.x, dimx.z from fact "
+              "join dimn on fact.fk = dimn.k join dimx on fact.a = dimx.k",
+              (2, 2), False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MESH_NULL_CASES))
+def test_mesh_join_over_a_build_column_with_nulls(null_tk, case):
+    sql, skipped, partition = MESH_NULL_CASES[case]
+    s = null_tk
+    s.execute("set @@tidb_use_tpu = 0")
+    cpu = s.query(sql).rows
+    s.execute("set @@tidb_use_tpu = 1")
+    single, one = _stats_of(s, sql)
+    sharded, delta, moved = _mesh_stats_of(s, sql, partition)
+    assert cpu and sorted(map(str, _canon(sharded))) \
+        == sorted(map(str, _canon(single))) \
+        == sorted(map(str, _canon(cpu)))
+    assert any(None in r for r in cpu) and any(None not in r for r in cpu)
+    assert delta["dispatches"] == delta["mesh_dispatches"] == 1
+    assert moved > 0 or not partition
+    assert (delta["pipe_const_nulls"], one["pipe_const_nulls"]) == skipped
+
+
+@pytest.mark.parametrize("n", [2, 4])
+def test_q3_over_the_mesh_gathers_two_null_lanes_fewer(tpch_mesh,
+                                                       monkeypatch, n):
+    s, _mirror, queries = tpch_mesh
+    _mesh_of(monkeypatch, n)
+    s.execute("set @@tidb_mesh_parallel = 1")
+    try:
+        _rows, q3 = _stats_of(s, queries["Q3"])
+        _rows, q1 = _stats_of(s, queries["Q1"])
+        _rows, q6 = _stats_of(s, queries["Q6"])
+    finally:
+        s.execute("set @@tidb_mesh_parallel = 0")
+    assert q3["pipe_const_nulls"] == 2
+    assert not q1.get("pipe_const_nulls") and not q6.get("pipe_const_nulls")

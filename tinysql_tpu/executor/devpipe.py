@@ -278,15 +278,19 @@ class _PipeBuilder:
     input carries the layout its program asks for (parallel/dist.py
     ``rows`` / ``whole``; None on one device and for host arrays).
     ``lparts``: the live set of every node that leaves a slot dead
-    (column liveness, below), beside the node keys and part of the
-    program's: one plan shape with two consumers is two programs."""
-    __slots__ = ("inputs", "layouts", "kparts", "lparts")
+    (column liveness, below) and the null lanes a join leaves out
+    (NULL-freedom, below), beside the node keys and part of the
+    program's: one plan shape with two consumers is two programs.
+    ``const_nulls``: the null-lane gathers and argument counts the
+    program leaves out."""
+    __slots__ = ("inputs", "layouts", "kparts", "lparts", "const_nulls")
 
     def __init__(self):
         self.inputs: List = []
         self.layouts: List = []
         self.kparts: List = []
         self.lparts: List = []
+        self.const_nulls = 0
 
     def add(self, arr, layout=None) -> int:
         self.inputs.append(arr)
@@ -301,13 +305,18 @@ class _PipeBuilder:
         pi, pf = pt.arrays()
         return self.add(pi), self.add(pf)
 
-    def key(self, part, live=None, n: int = 0) -> None:
+    def key(self, part, live=None, n: int = 0, nonnull=()) -> None:
         """``live``: the node's live slots of its ``n``.  Noted only where
         some slot is dead, and beside the node keys: those, and the key
         of a program whose consumer reads everything, stay what they
-        were."""
+        were.  ``nonnull``: the build slots whose null lanes a join does
+        not gather, noted (and counted) the same way."""
         if live is not None and len(live) < n:
             self.lparts.append((len(self.kparts), tuple(sorted(live))))
+        if nonnull:
+            self.lparts.append((len(self.kparts), "nonnull",
+                                tuple(sorted(nonnull))))
+            self.const_nulls += len(nonnull)
         self.kparts.append(part)
 
 
@@ -316,11 +325,13 @@ class _TView:
     over the fused program's positional inputs, plus the host-side
     column metadata (ret_type, string decode table) and bucket size.
     A slot its consumer did not ask for (``prepare``'s ``live``) holds
-    ``exprjit.DEAD`` in place of a pair; ``meta`` lists every slot."""
-    __slots__ = ("emit", "nb", "meta")
+    ``exprjit.DEAD`` in place of a pair; ``meta`` lists every slot.
+    ``nonnull``: the slots that hold no NULL on any row ``valid`` keeps
+    (NULL-freedom, below); a node that proves nothing says none."""
+    __slots__ = ("emit", "nb", "meta", "nonnull")
 
     def __init__(self, emit: Callable, nb: int, meta: List[tuple],
-                 scope: str):
+                 scope: str, nonnull=frozenset()):
         # every operation a node traces carries the node's kind in its
         # op_name (a child's emit runs inside its parent's: scopes nest)
         def scoped(args):
@@ -329,6 +340,7 @@ class _TView:
         self.emit = scoped
         self.nb = nb
         self.meta = meta
+        self.nonnull = frozenset(nonnull)
 
 
 # =========================================================================
@@ -372,6 +384,78 @@ def _spread(n: int, slots, pairs) -> list:
     out = [DEAD] * n
     for i, p in zip(slots, pairs):
         out[i] = p
+    return out
+
+
+# =========================================================================
+# NULL-freedom: a null lane that holds no information is not gathered
+# =========================================================================
+#
+# Every view says which of its slots hold no NULL on any row its
+# ``valid`` keeps (``_TView.nonnull``).  The property is of valid rows
+# only, so padding (a leaf's null lane is padded with True) and inner
+# joins keep it.  A leaf observes it of its replica version's null
+# masks; every other node derives it from its children's sets and what
+# it computes itself, never from a schema's NOT NULL or an option.  Two
+# readers: a join gathers no null lane of such a build column (where
+# the probe row matched, the build row is valid: the lane is
+# ``~match``), and a GROUP BY above a view reduces no count of such an
+# argument (its count is the rows': ``presence``).  What a node left
+# out is part of the program's key (``_PipeBuilder.key``'s ``nonnull``,
+# a ``!`` on an aggregate's spec): a replica version in which the column
+# holds a NULL builds another program.
+
+#: functions whose result is NULL only where an argument is
+_NULL_PRESERVING = frozenset(
+    ("+", "-", "*", "unaryminus", "abs", "cast_real", "cast_int"))
+
+
+def _never_null(e, column_never_null) -> bool:
+    """``e`` is NULL on no row: it reads, through functions that make no
+    NULL of their own (a division does, by zero), only constants and
+    columns that ``column_never_null(index)`` proves free of NULLs."""
+    if isinstance(e, ExprColumn):
+        return column_never_null(e.index)
+    if isinstance(e, Constant):
+        return e.value is not None
+    return getattr(e, "name", None) in _NULL_PRESERVING \
+        and all(_never_null(a, column_never_null) for a in e.children())
+
+
+def _column_never_null(rep, sid, nulls) -> bool:
+    """The replica's column ``sid`` holds no NULL in this version
+    (``nulls``: its host null mask), observed once a version."""
+    return rep.memo(("nevernull", sid), lambda: not nulls.any())
+
+
+def _never_null_specs(specs, needed, column_never_null) -> frozenset:
+    """The needed specs whose argument is NULL on no row the aggregate
+    reads: :func:`_spec_results` takes ``presence`` for their counts."""
+    return frozenset(
+        k for k in needed if specs[k][1] is not None
+        and _never_null(specs[k][1], column_never_null))
+
+
+def _agg_nonnull(specs, slots, out_map, never_null, key_nonnull) -> set:
+    """The slots of an aggregate's view that hold no NULL in any group
+    some row fell in (``presence > 0``: the view's valid groups): a
+    count always, a sum / min / max whose count is ``presence``
+    (``never_null``), an avg over such a count, and the group keys that
+    ``key_nonnull(j)`` proves."""
+    def spec_nonnull(k):
+        return specs[k][0] in ("count_star", "count", "sum0") \
+            or k in never_null
+    out = set()
+    for i, m in enumerate(out_map):
+        if m[0] == "gb":
+            ok = key_nonnull(m[1])
+        elif slots[m[1]][0] == "one":
+            ok = spec_nonnull(slots[m[1]][1])
+        else:  # avg: NULL where its count is 0
+            k = slots[m[1]][2]
+            ok = specs[k][0] == "count" and k in never_null
+        if ok:
+            out.add(i)
     return out
 
 
@@ -720,7 +804,8 @@ class _ReplicaLeaf:
     def prepare(self, pb: _PipeBuilder, live=None,
                 order=None) -> Optional[_TView]:
         """``live`` is not followed: the lanes are the program's inputs
-        as they lie, and one nobody reads costs no operation.
+        as they lie, and one nobody reads costs no operation (so the null
+        lane of a column the view says holds no NULL is handed over too).
         ``order`` None: lanes in row order, under the memo keys every
         statement over the table shares (devv / devn / devcodes).  Else
         (tag, perm): perm() is a host permutation of the padded [nb]
@@ -754,10 +839,14 @@ class _ReplicaLeaf:
         def lane(kind, sid, host, fill=0):
             return _leaf_lane(rep, kind, sid, nb, host, fill, tag, perm,
                               lay)
+        nonnull = set()
         for idx, c in enumerate(chk.columns):
             v = c.values()
             sid = _slot_id(self.ex, idx)
-            dn = lane("devn", sid, c.null_mask(), True)
+            nulls = c.null_mask()
+            if _column_never_null(rep, sid, nulls):
+                nonnull.add(idx)
+            dn = lane("devn", sid, nulls, True)
             if v.dtype == object or v.dtype.kind == "U":
                 got = _rep_string_dict(rep, sid, chk, idx)
                 codes, _card, _, uniques = got
@@ -778,7 +867,7 @@ class _ReplicaLeaf:
             pairs = [(args[iv], args[im]) for iv, im in slots]
             valid = mask_fn(pairs, (args[ip], args[fp]), jn.arange(nb))
             return valid, pairs
-        return _TView(emit, nb, meta, "leaf")
+        return _TView(emit, nb, meta, "leaf", nonnull)
 
     # host info the parent join/agg stages need (valid after prepare())
     def replica(self):
@@ -969,23 +1058,6 @@ def _spec_slots_read(specs, needed) -> set:
                        if specs[k][1] is not None)
 
 
-#: functions whose result is NULL only where an argument is
-_NULL_PRESERVING = frozenset(
-    ("+", "-", "*", "unaryminus", "abs", "cast_real", "cast_int"))
-
-
-def _never_null(e, column_never_null) -> bool:
-    """``e`` is NULL on no row: it reads, through functions that make no
-    NULL of their own (a division does, by zero), only constants and
-    columns that ``column_never_null(index)`` proves free of NULLs."""
-    if isinstance(e, ExprColumn):
-        return column_never_null(e.index)
-    if isinstance(e, Constant):
-        return e.value is not None
-    return getattr(e, "name", None) in _NULL_PRESERVING \
-        and all(_never_null(a, column_never_null) for a in e.children())
-
-
 def _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid, seg_sum,
                   seg_mm, presence, n_out, needed, gather=lambda x: x,
                   never_null=frozenset()):
@@ -1033,18 +1105,30 @@ def _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid, seg_sum,
     return res
 
 
-def _spec_fns(specs, pt: ParamTable):
+def _view_spec_fns(pb: _PipeBuilder, pt: ParamTable, specs, needed,
+                   tv: _TView):
+    """:func:`_spec_fns` of an aggregate above the view ``tv``, whose
+    flags prove which arguments are NULL on no valid row; (argument
+    closures, key parts, those specs), their counts left out counted."""
+    never_null = _never_null_specs(specs, needed, tv.nonnull.__contains__)
+    pb.const_nulls += len(never_null)
+    return _spec_fns(specs, pt, never_null) + (never_null,)
+
+
+def _spec_fns(specs, pt: ParamTable, never_null=frozenset()):
     """(argument closures over ``pt``'s parameter slots, structural key
-    parts) of an aggregate's specs; None for a spec without an argument."""
+    parts) of an aggregate's specs; None for a spec without an argument,
+    a ``!`` on the key of one whose count is ``presence``."""
     arg_fns = []
     keys = []
-    for kind, a in specs:
+    for k, (kind, a) in enumerate(specs):
         if a is None:
             arg_fns.append(None)
             keys.append(kind)
         else:
             arg_fns.append(compile_expr_params(a, pt))
-            keys.append(f"{kind}:{stable_shape_key(a)}")
+            keys.append(f"{kind}:{stable_shape_key(a)}"
+                        + ("!" if k in never_null else ""))
     return arg_fns, keys
 
 
@@ -1286,24 +1370,11 @@ class _AggIndexNode:
         # one's counts are masked reductions of a few passes and stay)
         from .tpu_executors import _slot_id
         chk = self.leaf.chunk()
-
-        def column_never_null(idx):
-            return rep.memo(
-                ("nevernull", _slot_id(self.leaf.ex, idx)),
-                lambda: not chk.columns[idx].null_mask().any())
-        never_null = frozenset() if dense else frozenset(
-            k for k in need if self.specs[k][1] is not None
-            and _never_null(self.specs[k][1], column_never_null))
-        arg_fns = []
-        keys = []
-        for k, (kind, a) in enumerate(self.specs):
-            if a is None:
-                arg_fns.append(None)
-                keys.append(kind)
-            else:
-                arg_fns.append(compile_expr_params(a, pt))
-                keys.append(f"{kind}:{stable_shape_key(a)}"
-                            + ("!" if k in never_null else ""))
+        never_null = frozenset() if dense else _never_null_specs(
+            self.specs, need, lambda idx: _column_never_null(
+                rep, _slot_id(self.leaf.ex, idx),
+                chk.columns[idx].null_mask()))
+        arg_fns, keys = _spec_fns(self.specs, pt, never_null)
         ip, fp = pb.params(pt)
         # the cache key must pin EVERYTHING the traced closure depends
         # on: the formulation, key column ids + dtypes (int vs float key
@@ -1407,7 +1478,10 @@ class _AggIndexNode:
         for oc, m in zip(schema_cols, out_map):
             decode = decodes[m[1]] if m[0] == "gb" else None
             meta.append((oc.ret_type, decode))
-        return _TView(emit, ngb, meta, head)
+        return _TView(emit, ngb, meta, head, _agg_nonnull(
+            self.specs, slots, out_map, never_null,
+            lambda j: rep.memo(("gi_gknonnull", sids, j),
+                               lambda: not gidx.keycols[j][1].any())))
 
     def build_key_info(self):
         """(lo, hi, pos_table np) for the parent join — static per
@@ -1604,6 +1678,8 @@ class _JoinNode:
         if ptv is None:
             return None
         assert (len(ptv.meta), len(btv.meta)) == (npc, nbc)
+        #: the live build columns whose null lane is not gathered
+        self.bskip = frozenset(self.bl) & btv.nonnull
         if semi:
             return self._prepare_semi(pb, btv, ptv)
         if self.mult:
@@ -1613,7 +1689,31 @@ class _JoinNode:
         return self._prepare_unique(pb, btv, ptv)
 
     def _key(self, pb, part) -> None:
-        pb.key(part, self.live, self.n_out)
+        pb.key(part, self.live, self.n_out, self.bskip)
+
+    def _view(self, emit, nb: int, scope: str, ptv, btv) -> _TView:
+        """The output view: the left side's slots, then the right's (a
+        semi join's: the probe's).  NULL-free in it: the probe's slots
+        as they are, and the build's under an inner join, whose valid
+        rows all matched (an outer join's unmatched rows are valid, and
+        NULL there)."""
+        if self.tp in ("semi", "anti"):
+            return _TView(emit, nb, ptv.meta, scope, ptv.nonnull)
+        left, right = (ptv, btv) if self.probe_is_left else (btv, ptv)
+        lnn, rnn = (tv.nonnull if tv is ptv or self.tp == "inner"
+                    else frozenset() for tv in (left, right))
+        return _TView(emit, nb, left.meta + right.meta, scope,
+                      lnn | {len(left.meta) + i for i in rnn})
+
+    def _gather_build(self, bpairs, at, match) -> list:
+        """The build's live columns (``bpairs``, in ``self.bl``'s order)
+        at the build rows ``at``, NULL where ``match`` is not: ``match``
+        holds only where ``at`` is a valid row of the build view, so a
+        column that view proves free of NULLs (``self.bskip``) is NULL
+        exactly where nothing matched and its null lane is not
+        gathered."""
+        return [(bv[at], ~match if i in self.bskip else bn[at] | ~match)
+                for i, (bv, bn) in zip(self.bl, bpairs)]
 
     def _out(self, ppairs, bcols, nbc: int) -> list:
         """The output view: the probe's live columns as they are, the
@@ -1662,7 +1762,7 @@ class _JoinNode:
             # probe key matches nothing and therefore SURVIVES
             valid_out = pvalid & (~match if anti else match)
             return valid_out, _only(ppairs, live)
-        return _TView(emit, nb, ptv.meta, "semijoin")
+        return self._view(emit, nb, "semijoin", ptv, btv)
 
     # ---- multi-key unique build: composite lane + dense table ----------
 
@@ -1745,14 +1845,10 @@ class _JoinNode:
             pos_safe = jn.clip(pos, 0, nbb - 1)
             match = (pos >= 0) & bvalid[pos_safe]
             valid_out = pvalid if outer else (pvalid & match)
-            gathered = [(bv[pos_safe], bn[pos_safe] | ~match)
-                        for bv, bn in (bpairs[i] for i in bl)]
+            gathered = self._gather_build([bpairs[i] for i in bl],
+                                          pos_safe, match)
             return valid_out, self._out(ppairs, gathered, nbc)
-        if probe_is_left:
-            meta = ptv.meta + btv.meta
-        else:
-            meta = btv.meta + ptv.meta
-        return _TView(emit, nb, meta, "joinmk")
+        return self._view(emit, nb, "joinmk", ptv, btv)
 
     # ---- unique build side: dense pos table + gather -------------------
 
@@ -1930,8 +2026,10 @@ class _JoinNode:
                 jn, bk_r, bv_r & ~bkn_r, pk_r, BN)
             matched = hit & ~pkn_r & pv_r
             valid_out = pv_r if outer else matched
-            bcols = [(bv2[brow], bn2[brow] | ~matched)
-                     for bv2, bn2 in (B_[i] for i in bl_at)]
+            # ``hit`` holds only on a received build row that is valid
+            # (local_unique_join matches among ``bv_r & ~bkn_r``)
+            bcols = self._gather_build([B_[i] for i in bl_at], brow,
+                                       matched)
             return valid_out, [P_[i] for i in pl_at], bcols
 
         shard_map, _ = dist.shard_map_fn()
@@ -1951,11 +2049,7 @@ class _JoinNode:
                 [bpairs[i] for i in bx], bvalid, (args[ip], args[fp]))
             return valid_out, self._out(
                 _spread(len(ptv.meta), self.pl, pcols), bcols, nbc)
-        if probe_is_left:
-            meta = ptv.meta + btv.meta
-        else:
-            meta = btv.meta + ptv.meta
-        return _TView(emit, n * n * capp, meta, "joinshuf")
+        return self._view(emit, n * n * capp, "joinshuf", ptv, btv)
 
     def _prepare_unique(self, pb, btv, ptv) -> Optional[_TView]:
         from ..parallel import dist as _dist
@@ -2005,12 +2099,7 @@ class _JoinNode:
                 valid_out = pvalid
             else:
                 valid_out = pvalid & match
-            gathered = []
-            for bv, bn in bpairs:
-                gv = bv[pos_safe]
-                gn = bn[pos_safe] | ~match
-                gathered.append((gv, gn))
-            return valid_out, gathered
+            return valid_out, self._gather_build(bpairs, pos_safe, match)
 
         if mesh is not None:
             shard_map, _ = dist.shard_map_fn()
@@ -2030,11 +2119,7 @@ class _JoinNode:
                 ppairs[pk_slot], pvalid, [bpairs[i] for i in bl], bvalid,
                 args[it], (args[ip], args[fp]))
             return valid_out, self._out(ppairs, gathered, nbc)
-        if probe_is_left:
-            meta = ptv.meta + btv.meta
-        else:
-            meta = btv.meta + ptv.meta
-        return _TView(emit, nb, meta, "join")
+        return self._view(emit, nb, "join", ptv, btv)
 
     # ---- general multiplicity: CSR over the build group index ----------
 
@@ -2198,7 +2283,9 @@ class _JoinNode:
             brow = comp[jn.clip(start_c[gjs] + k, 0, nbb - 1)]
             pcols = [(pv[ps], pn[ps])
                      for pv, pn in (ppairs[i] for i in pl_at)]
-            bcols = [(bv[brow], bn[brow] | ~matched) for bv, bn in bpairs]
+            # ``brow`` of a matched slot is the k-th VALID row of its
+            # group (``comp`` lists valid rows, ``k < m``)
+            bcols = self._gather_build(bpairs, brow, matched)
             return valid_out, pcols, bcols
 
         if mesh is not None:
@@ -2226,11 +2313,7 @@ class _JoinNode:
                 (args[ip], args[fp]))
             return valid_out, self._out(
                 _spread(npc, self.pl, pcols), bcols, nbc)
-        if probe_is_left:
-            meta = ptv.meta + btv.meta
-        else:
-            meta = btv.meta + ptv.meta
-        return _TView(emit, ob * max(n_mesh, 1), meta, "joinm")
+        return self._view(emit, ob * max(n_mesh, 1), "joinm", ptv, btv)
 
     def _per_probe_counts(self, raw, tbl, lo, hi, ptv, outer, cspec=None):
         """Host per-probe-row match-count UPPER bounds (pre-filter group
@@ -2352,7 +2435,8 @@ class _SortGroupNode:
             key_idx.append(e.index)
             decodes.append(decode)
         pt = ParamTable()
-        arg_fns, keys = _spec_fns(self.specs, pt)
+        arg_fns, keys, never_null = _view_spec_fns(pb, pt, self.specs,
+                                                   needed, tv)
         ip, fp = pb.params(pt)
         pb.key(("sortgroup", tuple(keys), tuple(key_idx),
                 tuple(self.slots), tuple(self.out_map), nb,
@@ -2412,7 +2496,8 @@ class _SortGroupNode:
             res = _spec_results(
                 jn, spec_kinds, arg_fns, pairs, pr, valid,
                 gather=lambda x: x[perm], needed=needed,
-                seg_sum=seg, seg_mm=seg_mm, presence=presence, n_out=nb)
+                seg_sum=seg, seg_mm=seg_mm, presence=presence, n_out=nb,
+                never_null=never_null)
             outs = _slot_outputs(jn, res, slots)
             gvalid = jn.arange(nb) < ng
             cols = []
@@ -2429,7 +2514,11 @@ class _SortGroupNode:
         for oc, m in zip(schema_cols, out_map):
             decode = decodes[m[1]] if m[0] == "gb" else None
             meta.append((oc.ret_type, decode))
-        return _TView(emit, nb, meta, "sortgroup")
+        # a group here is a run of valid rows: a key is NULL in one only
+        # if it was on a row
+        return _TView(emit, nb, meta, "sortgroup", _agg_nonnull(
+            self.specs, slots, out_map, never_null,
+            lambda j: key_idx[j] in tv.nonnull))
 
     def close(self):
         _close_node(self.child)
@@ -2575,7 +2664,7 @@ class _KeyGroupNode:
                 pb.add(_leaf_lane(rep, "devcodes" if dt == "s" else "devv",
                                   sid, nbl, v)),
                 pb.add(_leaf_lane(rep, "devn", sid, nbl, m, True)),
-                decode, dt)
+                decode, dt, _column_never_null(rep, sid, m))
         if carried:
             kernels.stats_add("agg_key_cut", 1)
         if dense:
@@ -2583,7 +2672,8 @@ class _KeyGroupNode:
         pt = ParamTable()
         pt.add_int(lo)
         pt.add_int(rng)
-        arg_fns, keys = _spec_fns(self.specs, pt)
+        arg_fns, keys, never_null = _view_spec_fns(pb, pt, self.specs,
+                                                   needed, tv)
         ip, fp = pb.params(pt)
         pb.key(("keygroup", tuple(keys), key.index, kdt, self.cut,
                 tuple((j, c[3]) for j, c in sorted(carried.items())),
@@ -2609,7 +2699,8 @@ class _KeyGroupNode:
                 seg_sum=lambda x: seg.sum(x, valid),
                 seg_mm=lambda av, live_s, kind: seg.minmax(
                     av, live_s, kind == "min"),
-                presence=presence, n_out=ngb, needed=needed)
+                presence=presence, n_out=ngb, needed=needed,
+                never_null=never_null)
             outs = _slot_outputs(jn, res, slots)
             g = jn.arange(ngb, dtype=jn.int64)
             gnull = g >= rng_p
@@ -2635,7 +2726,12 @@ class _KeyGroupNode:
             if m[0] == "gb":
                 decode = kdecode if m[1] == cut else carried[m[1]][2]
             meta.append((oc.ret_type, decode))
-        return _TView(emit, ngb, meta, "keygroup")
+        # the slot past the range is valid only if a NULL key arrived;
+        # a carried column is read of the table's own row of the key
+        key_nonnull = kslot in tv.nonnull
+        return _TView(emit, ngb, meta, "keygroup", _agg_nonnull(
+            self.specs, slots, out_map, never_null,
+            lambda j: key_nonnull and (j == cut or carried[j][4])))
 
     def close(self):
         _close_node(self.child)
@@ -2682,7 +2778,8 @@ class _ScalarAggNode:
         jn = _jn()
         ob = 16  # minimal bucket; the one result row sits at slot 0
         pt = ParamTable()
-        arg_fns, keys = _spec_fns(self.specs, pt)
+        arg_fns, keys, never_null = _view_spec_fns(pb, pt, self.specs,
+                                                   needed, tv)
         ip, fp = pb.params(pt)
         pb.key(("scalaragg", tuple(keys), tuple(self.slots),
                 tuple(self.out_map), tv.nb, len(tv.meta)),
@@ -2707,12 +2804,15 @@ class _ScalarAggNode:
                 seg_mm=lambda av_s, live_s, kind: at0(
                     (jn.min if kind == "min" else jn.max)(av_s)),
                 presence=at0(jn.sum(valid.astype(jn.int64))), n_out=ob,
-                needed=needed)
+                needed=needed, never_null=never_null)
             outs = _slot_outputs(jn, res, slots)
             gvalid = jn.arange(ob) == 0  # exactly one result row
             return gvalid, _only([outs[m[1]] for m in out_map], live)
         meta = [(oc.ret_type, None) for oc in schema_cols]
-        return _TView(emit, ob, meta, "scalaragg")
+        # the one row is valid over an empty input too, where a sum is
+        # NULL whatever its argument: only the counts are proved
+        return _TView(emit, ob, meta, "scalaragg", _agg_nonnull(
+            self.specs, slots, out_map, frozenset(), lambda j: False))
 
     def close(self):
         _close_node(self.child)
@@ -2908,7 +3008,7 @@ class _SelNode:
                 v, null = f(pairs, pr)
                 m = m & (v != 0) & ~null
             return m, _only(pairs, live)
-        return _TView(emit, tv.nb, tv.meta, "sel")
+        return _TView(emit, tv.nb, tv.meta, "sel", tv.nonnull)
 
     def close(self):
         _close_node(self.child)
@@ -2971,7 +3071,9 @@ class _ProjNode:
                 else:
                     outs.append(f(pairs, pr))
             return valid, outs
-        return _TView(emit, tv.nb, meta, "proj")
+        return _TView(emit, tv.nb, meta, "proj", (
+            j for j, e in enumerate(self.exprs)
+            if _never_null(e, tv.nonnull.__contains__)))
 
     def close(self):
         _close_node(self.child)
@@ -3093,7 +3195,7 @@ class _OrderNode:
             outs = [(p[0][take], p[1][take]) if i in live else DEAD
                     for i, p in enumerate(pairs)]
             return out_valid, outs
-        return _TView(emit, kb - off, tv.meta, "order")
+        return _TView(emit, kb - off, tv.meta, "order", tv.nonnull)
 
     def _prepare_mesh(self, pb, tv, fns, key_ids, descs, off, kb, count,
                       ip, fp, mesh, live):
@@ -3165,7 +3267,7 @@ class _OrderNode:
             out_valid, outs = sharded(fn_kvs, valid,
                                       [pairs[i] for i in carried])
             return out_valid, _spread(len(pairs), out_slots, outs)
-        return _TView(emit, kb - off, tv.meta, "order_mesh")
+        return _TView(emit, kb - off, tv.meta, "order_mesh", tv.nonnull)
 
     def close(self):
         _close_node(self.child)
@@ -3199,7 +3301,7 @@ class _LimitNode:
             pr = (args[ip], args[fp])
             rank = jn.cumsum(valid.astype(jn.int64))
             return valid & (rank > pr[0][0]) & (rank <= pr[0][1]), pairs
-        return _TView(emit, tv.nb, tv.meta, "limit")
+        return _TView(emit, tv.nb, tv.meta, "limit", tv.nonnull)
 
     def close(self):
         _close_node(self.child)
@@ -3499,6 +3601,8 @@ class DevPipeExec:
             key += (("live", tuple(out_slots)) + tuple(pb.lparts),)
         if len(out_slots) < ncols:
             kernels.stats_add("pipe_dead_cols", ncols - len(out_slots))
+        if pb.const_nulls:
+            kernels.stats_add("pipe_const_nulls", pb.const_nulls)
         # the program's name in a profile: its node kinds, leaves first
         shape = "_".join(str(part[0]) for part in pb.kparts)
         if small:

@@ -62,6 +62,11 @@ _DEVICE_METRICS = {
                        "Columns of fused programs' root views that no "
                        "consumer reads and the programs therefore did "
                        "not compute, pack or download"),
+    "pipe_const_nulls": ("tinysql_pipe_const_nulls_total",
+                         "Null-lane gathers that fused programs' joins "
+                         "left out and argument counts their GROUP BYs "
+                         "did not reduce, because the view below holds "
+                         "no NULL in the column on a valid row"),
     "pipe_joins": ("tinysql_pipe_joins_total",
                    "Join nodes traced into fused programs, counted once "
                    "a fused dispatch"),

@@ -224,6 +224,11 @@ def _device_info(st) -> str:
         # columns of the fused program's root no consumer reads: not
         # computed, packed or downloaded
         parts.append(f"dead_cols:{int(d['pipe_dead_cols'])}")
+    if d.get("pipe_const_nulls"):
+        # null lanes the fused program's joins did not gather and
+        # argument counts its GROUP BYs did not reduce: the view below
+        # proved the column free of NULLs
+        parts.append(f"const_nulls:{int(d['pipe_const_nulls'])}")
     if d.get("pipe_joins"):
         # joins traced into the fused program / those whose build side
         # is a view; GROUP BYs cut to the key that determines the rest
