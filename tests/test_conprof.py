@@ -335,44 +335,6 @@ def test_overhead_backoff_doubles_and_recovers():
     assert p.backoff < high
 
 
-def test_live_overhead_frac_definition():
-    before = {"self_s": 1.0}
-    after = {"self_s": 1.5}
-    assert conprof.live_overhead_frac(before, after, 50.0) == 0.01
-
-
-def test_measure_overhead_probe_is_private():
-    conprof.reset()
-    out = conprof.measure_overhead(n=5, rate_hz=10)
-    assert out["conprof_overhead_frac"] >= 0
-    # probed a PRIVATE profiler: the live store saw nothing
-    assert conprof.stats_snapshot()["ticks"] == 0
-
-
-def test_measure_overhead_never_attributes(session):
-    # regression: the probe's back-to-back ticks used to attribute
-    # fabricated CPU time to any statement live in the process
-    done = threading.Event()
-    seen = {}
-
-    def run_stmt():
-        with fail.armed("execSlowNext", sleep=0.05):
-            session.query("select count(*) from t where b < 6")
-        seen["qobs"] = session.last_query_stats
-        done.set()
-
-    fail.reset_hits()
-    t = threading.Thread(target=run_stmt, daemon=True)
-    t.start()
-    hit("execSlowNext")  # statement provably mid-flight
-    conprof.measure_overhead(n=10, rate_hz=10)
-    assert done.wait(10)
-    join(t)
-    dev = seen["qobs"].device_totals()
-    assert dev.get("cpu_samples", 0) == 0, dev
-    assert dev.get("cpu_s", 0.0) == 0.0, dev
-
-
 # ---- continuous_profiling over SQL ---------------------------------------
 
 def test_continuous_profiling_memtable_over_sql(session):

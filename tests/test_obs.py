@@ -475,6 +475,9 @@ def test_totals_grow_by_one_per_span():
     recorded = {}
     for s in spans:
         recorded[s["name"]] = recorded.get(s["name"], 0) + 1
+    # the fan-out after the statement's scope has closed is the
+    # process's span, not the statement's
+    assert grew.pop("stmt.finish") == 1
     assert grew == recorded, (grew, recorded)
     # a backdated interval counts once too, and self == sum for it
     n = after.get("t27.backdated", 0)
@@ -525,8 +528,9 @@ def span_server():
 
 def test_process_span_parents_statement_across_pool_handoff(span_server):
     """wire.command -> pool.wait on the connection thread; the
-    statement's own spans, recorded on a pool worker, parent into
-    pool.wait; the worker's solo span names the same wait."""
+    worker's solo span names that wait and adopts the statement's own
+    spans, recorded on its thread, as a round's legs do: its self time
+    is what no child of it names."""
     from test_server import MiniClient
     clear_traces()
     mark = _last_id()
@@ -559,8 +563,9 @@ def test_process_span_parents_statement_across_pool_handoff(span_server):
     stmt = [t for t in recent_traces() if "count(*), sum(c)" in t["sql"]]
     assert stmt, [t["sql"] for t in recent_traces()]
     execute = [s for s in stmt[-1]["spans"] if s["name"] == "execute"][0]
-    assert execute["parent"] == wait["id"]
+    assert execute["parent"] == proc["solo"]["id"]
     assert execute["tid"] == proc["solo"]["tid"]
+    assert proc["solo"]["dur_us"] >= execute["dur_us"]
 
 
 def _drive_round27(server, qs):

@@ -372,10 +372,11 @@ def test_processlist_queued_time_is_wait_so_far(storage):
 
 
 def test_pool_worker_spans_parent_to_submitting_thread(storage):
-    """Satellite fix: statements executed on pool workers run inside a
-    contextvars COPY of the submitting thread's context, so their
-    parse→plan→execute span chain parents to the span live at submit
-    time instead of starting an orphan chain on the worker thread."""
+    """Statements executed on pool workers run inside a contextvars
+    COPY of the submitting thread's context, under the worker's ``solo``
+    span: their parse→plan→execute span chain parents to it, and its
+    ``wait`` argument names the span live at submit time, so the chain
+    is no orphan on the worker thread."""
     from tinysql_tpu.obs import context as obs_context
     pool = StatementPool(storage)
     try:
@@ -396,7 +397,10 @@ def test_pool_worker_spans_parent_to_submitting_thread(storage):
         wait = [sp for sp in outer.tracer.spans()
                 if sp["name"] == "pool.wait"]
         assert wait and wait[0]["parent"] == root.sid, outer.tracer.spans()
-        assert execute[0]["parent"] == wait[0]["id"]
+        solo = [sp for sp in obs_context.PROCESS.spans()
+                if sp["id"] == execute[0]["parent"]]
+        assert solo and solo[0]["name"] == "solo", execute
+        assert solo[0]["args"]["wait"] == wait[0]["id"]
         # and the chain below it is intact: plan/place parent to execute
         children = {sp["name"] for sp in spans
                     if sp["parent"] == execute[0]["id"]}

@@ -3394,6 +3394,14 @@ def _to_chunk(host_pairs, meta, n_rows: int) -> Chunk:
     return Chunk.from_columns(cols)
 
 
+def _chunk_of(sp, meta, out_slots, host, n_valid: int) -> Chunk:
+    """The downloaded pairs of the live slots as the pipe's chunk, the
+    live ``exec.rows`` span (None outside a statement) told its rows."""
+    if sp is not None:
+        sp.args["rows"] = n_valid
+    return _to_chunk(_spread(len(meta), out_slots, host), meta, n_valid)
+
+
 # =========================================================================
 # executor wrapper
 # =========================================================================
@@ -3570,7 +3578,51 @@ class DevPipeExec:
     def _run_pipeline(self) -> Optional[Chunk]:
         """Prepare the node tree (host work + input collection), then run
         the WHOLE pipeline as one jitted program.  Small outputs fold the
-        result packing into the same program: one dispatch, one D2H."""
+        result packing into the same program: one dispatch, one D2H.
+        The host's work before the launch is the ``pipe.prepare`` span
+        (``replica.memo`` and ``compile`` its children where they
+        occur), the downloaded buffers' way into a chunk ``exec.rows``."""
+        with _obs.span("pipe.prepare", cat="pipeline"):
+            prepared = self._prepare_program()
+        if prepared is None:
+            return None
+        fn, schema, inputs, tv, out_slots = prepared
+        nb = tv.nb
+        if schema is not None:  # small: packed by the program itself
+            vals = kernels.unpack_flat(fn(inputs), schema)
+            with _obs.span("exec.rows") as sp:
+                keep = np.nonzero(vals[0])[0]
+                host = [(vals[1 + 2 * i][keep], vals[2 + 2 * i][keep])
+                        for i in range(len(out_slots))]
+                return _chunk_of(sp, tv.meta, out_slots, host, len(keep))
+        jn = _jn()
+        res = fn(inputs)
+        valid, items = res[0], list(res[1:])
+
+        def build_count():
+            return kernels.counted_jit(
+                lambda v: jn.sum(v.astype(jn.int64)))
+        cfn = progcache.get(("nvalid", nb), build_count)
+        n_valid = int(kernels.d2h(cfn(valid)))
+        if n_valid:
+            ob = min(kernels.bucket(n_valid), nb)
+            _ids, vals = kernels._present_pack(
+                valid.astype(jn.int64), items, ob)
+        with _obs.span("exec.rows") as sp:
+            if n_valid == 0:
+                host = [(np.empty(0, dtype=np.int64),
+                         np.empty(0, dtype=bool))] * len(out_slots)
+            else:
+                host = [(vals[2 * i][:n_valid], vals[2 * i + 1][:n_valid])
+                        for i in range(len(out_slots))]
+            return _chunk_of(sp, tv.meta, out_slots, host, n_valid)
+
+    def _prepare_program(self):
+        """``_run_pipeline``'s host work before the launch: the node
+        tree prepared, its inputs settled, the program found (or built)
+        by its key.  ``(fn, schema, inputs, tv, out_slots)``, ``schema``
+        None where the output is too large to pack in the program; None
+        where a node bails."""
         pb = _PipeBuilder()
         tv = self._node.prepare(pb, self.live)
         if tv is None:
@@ -3582,7 +3634,6 @@ class DevPipeExec:
             from ..parallel import dist
             inputs = dist.settle(pb.inputs, pb.layouts)
             dist.note_dispatch(self._mesh)
-        jn = _jn()
         nb = tv.nb
         ncols = len(tv.meta)
         out_slots = sorted(_live_set(self.live, ncols))
@@ -3620,11 +3671,6 @@ class DevPipeExec:
                 _note_compiled(pb.kparts)
                 return kernels.counted_jit(mega, name=shape), schema
             fn, schema = progcache.get(key, build_small)
-            vals = kernels.unpack_flat(fn(inputs), schema)
-            keep = np.nonzero(vals[0])[0]
-            n_valid = len(keep)
-            host = [(vals[1 + 2 * i][keep], vals[2 + 2 * i][keep])
-                    for i in range(len(out_slots))]
         else:
             def build_big():
                 emit = tv.emit
@@ -3635,25 +3681,8 @@ class DevPipeExec:
                                       for x in cols[i]]
                 _note_compiled(pb.kparts)
                 return kernels.counted_jit(mega, name=shape)
-            fn = progcache.get(key, build_big)
-            res = fn(inputs)
-            valid, items = res[0], list(res[1:])
-
-            def build_count():
-                return kernels.counted_jit(
-                    lambda v: jn.sum(v.astype(jn.int64)))
-            cfn = progcache.get(("nvalid", nb), build_count)
-            n_valid = int(kernels.d2h(cfn(valid)))
-            if n_valid == 0:
-                host = [(np.empty(0, dtype=np.int64),
-                         np.empty(0, dtype=bool))] * len(out_slots)
-            else:
-                ob = min(kernels.bucket(n_valid), nb)
-                _ids, vals = kernels._present_pack(
-                    valid.astype(jn.int64), items, ob)
-                host = [(vals[2 * i][:n_valid], vals[2 * i + 1][:n_valid])
-                        for i in range(len(out_slots))]
-        return _to_chunk(_spread(ncols, out_slots, host), tv.meta, n_valid)
+            fn, schema = progcache.get(key, build_big), None
+        return fn, schema, inputs, tv, out_slots
 
     def drain(self) -> List[list]:
         rows = []
@@ -3663,7 +3692,8 @@ class DevPipeExec:
             chk = self.next()
             if chk is None:
                 break
-            rows.extend(chk.to_rows())
+            with _obs.span("exec.rows", rows=chk.num_rows()):
+                rows.extend(chk.to_rows())
         return rows
 
     def close(self):

@@ -19,6 +19,7 @@ import numpy as np
 
 from .. import fail
 from ..catalog.table import Table
+from ..obs import context as obs_context
 from ..utils import interrupt
 from ..chunk import Chunk, MAX_CHUNK_SIZE
 from ..expression import Schema, vectorized_filter
@@ -80,7 +81,9 @@ class Executor:
             chk = self.next()
             if chk is None:
                 break
-            rows.extend(chk.to_rows())
+            # the answer's way back: a chunk's columns to Python rows
+            with obs_context.span("exec.rows", rows=chk.num_rows()):
+                rows.extend(chk.to_rows())
         return rows
 
 

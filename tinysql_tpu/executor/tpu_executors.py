@@ -24,6 +24,7 @@ from ..expression import vectorized_filter
 from ..expression.aggregation import (AGG_AVG, AGG_COUNT, AGG_FIRST_ROW,
                                       AGG_MAX, AGG_MIN, AGG_SUM)
 from ..mytypes import EvalType, new_real_type
+from ..obs import context as _obs
 from ..ops import kernels, progcache
 from ..planner.physical import (PhysicalHashAgg, PhysicalHashJoin,
                                 PhysicalProjection, PhysicalSelection,
@@ -628,7 +629,18 @@ class TPUHashAggExec(Executor):
         (memoized on the replica), ON-DEVICE argument evaluation via the
         exprjit lowering, host filter mask as the only per-query upload,
         one XLA program end to end.  Returns an output Chunk or None to
-        fall back."""
+        fall back.  The host's work before the launch is the
+        ``agg.prepare`` span (the per-operator tier's ``pipe.prepare``),
+        the downloaded aggregates' way into a chunk ``exec.rows``."""
+        with _obs.span("agg.prepare"):
+            run = self._fused_prepare()
+        return run() if callable(run) else run
+
+    def _fused_prepare(self):
+        """Everything of ``_try_fused_device`` up to the launch: a
+        closure that runs the one program and assembles its output, or
+        what a path that does not come to that returned (None to fall
+        back; the block-wise and host twins' finished chunk)."""
         from .executors import TableReaderExec
         from ..ops.exprjit import is_jittable
         plan = self.plan
@@ -855,62 +867,74 @@ class TPUHashAggExec(Executor):
             mask[:n] = fmask if fmask is not None else True
             mask_spec = ("host", kernels.h2d(mask, lrows))
 
-        # ---- run --------------------------------------------------------
-        if not plan.group_by:
-            out_keys = []
-            if mesh is not None:
-                # partial->final over the mesh, and STILL batchable: the
-                # stacked variant vmaps B queries over the N-shard
-                # program (B x N in one dispatch)
-                from ..ops import shardops
-                out_aggs, first_orig = \
-                    shardops.fused_scalar_aggregate_sharded(
-                        mesh, dev_cols, specs, progs, n, nb, mask_spec,
-                        program_key=program_key, params=params,
-                        batchable=True)
-            else:
-                # batchable: THE single-shot dispatch cross-query
-                # micro-batching coalesces (ops/batching.py) — blockwise
-                # / passthrough variants stay solo
-                out_aggs, first_orig = kernels.fused_scalar_aggregate(
-                    dev_cols, specs, progs, n, nb, mask_spec,
-                    program_key=program_key, params=params,
-                    batchable=True)
-        else:
+        gid_dev = None
+        if plan.group_by:
             gid_dev = _dev_upload(
                 rep, ("gid_dev", tuple(slot_ids[e.index]
                                        for e in plan.group_by), nb),
                 lambda: kernels.pad1(self._compose_gid(key_layouts, n), nb),
                 lrows)
-            if mesh is not None:
-                present, out_aggs, first_orig = \
-                    kernels.fused_segment_aggregate_sharded(
-                        mesh, dev_cols, gid_dev, n_segments, specs, progs,
-                        n, mask_spec, program_key=program_key,
-                        params=params)
-            elif self._can_device_passthrough(plan, slots, key_layouts) \
-                    and not _batching_active():
-                # a live batch round prefers the batchable fused path
-                # below: members must park (collect) and consume
-                # (replay) along the SAME route, and the keep variant's
-                # per-member device assembly cannot ride a stacked
-                # dispatch
-                ids, live, out_aggs_d, np_, ob = \
-                    kernels.fused_segment_aggregate_keep(
-                        dev_cols, gid_dev, n_segments, specs, progs,
-                        mask_spec, program_key=program_key, params=params)
-                return self._assemble_device_output(
-                    plan, slots, key_layouts, ids, live, out_aggs_d, np_)
-            else:
-                present, out_aggs, first_orig = \
-                    kernels.fused_segment_aggregate(
-                        dev_cols, gid_dev, n_segments, specs, progs, n,
-                        mask_spec, program_key=program_key, params=params,
+
+        # ---- run --------------------------------------------------------
+        def run():
+            if not plan.group_by:
+                out_keys = []
+                if mesh is not None:
+                    # partial->final over the mesh, and STILL batchable:
+                    # the stacked variant vmaps B queries over the N-shard
+                    # program (B x N in one dispatch)
+                    from ..ops import shardops
+                    out_aggs, first_orig = \
+                        shardops.fused_scalar_aggregate_sharded(
+                            mesh, dev_cols, specs, progs, n, nb,
+                            mask_spec, program_key=program_key,
+                            params=params, batchable=True)
+                else:
+                    # batchable: THE single-shot dispatch cross-query
+                    # micro-batching coalesces (ops/batching.py) —
+                    # blockwise / passthrough variants stay solo
+                    out_aggs, first_orig = kernels.fused_scalar_aggregate(
+                        dev_cols, specs, progs, n, nb, mask_spec,
+                        program_key=program_key, params=params,
                         batchable=True)
-            out_keys = self._decode_present(present, key_layouts)
-        return self._assemble_output(chk, plan, slots, out_keys, out_aggs,
-                                     first_orig,
-                                     [l[3] for l in key_layouts])
+            else:
+                if mesh is not None:
+                    present, out_aggs, first_orig = \
+                        kernels.fused_segment_aggregate_sharded(
+                            mesh, dev_cols, gid_dev, n_segments, specs,
+                            progs, n, mask_spec, program_key=program_key,
+                            params=params)
+                elif self._can_device_passthrough(plan, slots,
+                                                  key_layouts) \
+                        and not _batching_active():
+                    # a live batch round prefers the batchable fused path
+                    # below: members must park (collect) and consume
+                    # (replay) along the SAME route, and the keep variant's
+                    # per-member device assembly cannot ride a stacked
+                    # dispatch
+                    ids, live, out_aggs_d, np_, ob = \
+                        kernels.fused_segment_aggregate_keep(
+                            dev_cols, gid_dev, n_segments, specs, progs,
+                            mask_spec, program_key=program_key,
+                            params=params)
+                    return self._assemble_device_output(
+                        plan, slots, key_layouts, ids, live, out_aggs_d,
+                        np_)
+                else:
+                    present, out_aggs, first_orig = \
+                        kernels.fused_segment_aggregate(
+                            dev_cols, gid_dev, n_segments, specs, progs, n,
+                            mask_spec, program_key=program_key,
+                            params=params, batchable=True)
+                out_keys = self._decode_present(present, key_layouts)
+            with _obs.span("exec.rows") as sp:
+                out = self._assemble_output(chk, plan, slots, out_keys,
+                                            out_aggs, first_orig,
+                                            [l[3] for l in key_layouts])
+                if sp is not None:
+                    sp.args["rows"] = out.num_rows()
+                return out
+        return run
 
     def _fused_blockwise(self, chk, rep, child, filters, specs,
                          arg_exprs, slots, key_layouts, n_segments: int,

@@ -527,38 +527,6 @@ def reset() -> None:
     PROF.reset()
 
 
-def measure_overhead(n: int = 50,
-                     rate_hz: int = DEFAULT_RATE_HZ) -> Dict[str, float]:
-    """The profiler's steady-state cost, THE definition both benches
-    publish as ``conprof_overhead_frac`` when no live sampler ran: one
-    tick's wall (averaged over ``n`` live frame walks against THIS
-    process) times the ticks-per-second at ``rate_hz``.  Probes a
-    PRIVATE Profiler so the measurement never pollutes the live store.
-    """
-    prof = Profiler()
-    period = 1.0 / max(rate_hz, 1)
-    # attribute=False: the probe's ticks are back-to-back, and a live
-    # statement in this process must not collect fabricated CPU time
-    prof.sample_once(period, attribute=False)  # warm lazy imports
-    t0 = time.perf_counter()
-    for _ in range(n):
-        prof.sample_once(period, attribute=False)
-    per_tick_s = (time.perf_counter() - t0) / n
-    return {"tick_wall_s": round(per_tick_s, 6), "rate_hz": rate_hz,
-            "conprof_overhead_frac": round(per_tick_s * rate_hz, 6)}
-
-
-def live_overhead_frac(stats_before: Dict[str, float],
-                       stats_after: Dict[str, float],
-                       wall_s: float) -> float:
-    """Sampler self-cost over a measured live window: the delta of the
-    profiler's own accumulated tick wall divided by the elapsed wall,
-    to hold against the 3% budget."""
-    d = float(stats_after.get("self_s", 0.0)) \
-        - float(stats_before.get("self_s", 0.0))
-    return round(d / max(wall_s, 1e-9), 6)
-
-
 # ---- the background sampler (server lifecycle) ---------------------------
 
 class ConprofSampler:

@@ -66,7 +66,6 @@ __all__ = [
     "DEFAULT_RETENTION", "INCARNATION_COLUMNS", "current_incarnation",
     "server_start_ts", "active_store", "prior_tier_rows",
     "incarnation_rows", "stats_snapshot", "reset_stats",
-    "live_overhead_frac",
 ]
 
 #: GLOBAL sysvar defaults (session.DEFAULT_SYSVARS mirrors these)
@@ -109,18 +108,6 @@ def reset_stats() -> None:
     with _STATS_MU:
         for k in STATS:
             STATS[k] = 0
-
-
-def live_overhead_frac(stats_before: Dict[str, float],
-                       stats_after: Dict[str, float],
-                       wall_s: float) -> float:
-    """Writer self-cost over a measured live window — same definition
-    as conprof/memprof.live_overhead_frac, so the three samplers'
-    combined live fraction can be held under one budget."""
-    if wall_s <= 0:
-        return 0.0
-    d = stats_after.get("self_s", 0.0) - stats_before.get("self_s", 0.0)
-    return max(0.0, d) / wall_s
 
 
 # ---- incarnation identity --------------------------------------------------

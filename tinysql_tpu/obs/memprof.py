@@ -281,8 +281,8 @@ class HeapProfiler:
         then the fold and the attribution of the window's growth
         (:meth:`sample_once`).  The whole traced wall plus the fold is
         charged to the budget, which alone decides when the next window
-        may open.  Tracing someone else started (a test,
-        ``QueryMemProbe``) is read and left on."""
+        may open.  Tracing someone else started (a test) is read and
+        left on."""
         t_open = self._clock()
         ours = not tracemalloc.is_tracing()
         snap, traced_s, snapshot_s = None, 0.0, 0.0
@@ -594,45 +594,6 @@ def reset() -> None:
     PROF.reset()
 
 
-def measure_overhead(n: int = 20,
-                     rate_hz: int = DEFAULT_RATE_HZ) -> Dict[str, float]:
-    """The heap profiler's steady-state cost, charged in full: one site
-    window's traced wall plus its fold (averaged over ``n`` windows of
-    THIS process) times the windows a second ``rate_hz`` asks for.
-    Probes a PRIVATE HeapProfiler so the measurement never pollutes the
-    live store, and never attributes: back-to-back probe windows must
-    not fabricate statement heap growth."""
-    prof = HeapProfiler()
-    period = 1.0 / max(rate_hz, 1)
-    prof.sample_window(period, attribute=False)  # warm lazy imports
-    before = _charged_s(prof.stats_snapshot())
-    for _ in range(n):
-        prof.sample_window(period, attribute=False)
-    per_tick_s = (_charged_s(prof.stats_snapshot()) - before) / n
-    return {"tick_wall_s": round(per_tick_s, 6), "rate_hz": rate_hz,
-            "memprof_overhead_frac": round(per_tick_s * rate_hz, 6)}
-
-
-def _charged_s(stats: Dict[str, float]) -> float:
-    """What the budget has been charged: the traced seconds, which
-    every thread pays, and the sampler's own work — whose snapshots
-    are inside the traced seconds, and count once."""
-    return float(stats.get("traced_s", 0.0)) \
-        + float(stats.get("self_s", 0.0)) \
-        - float(stats.get("snapshot_s", 0.0))
-
-
-def live_overhead_frac(stats_before: Dict[str, float],
-                       stats_after: Dict[str, float],
-                       wall_s: float) -> float:
-    """The profiler's cost over a measured live window, charged in
-    full: the growth of the traced seconds (everybody's tax) and of the
-    sampler's own fold wall, divided by the elapsed wall, to hold
-    against the 3% budget (as conprof's)."""
-    d = _charged_s(stats_after) - _charged_s(stats_before)
-    return round(d / max(wall_s, 1e-9), 6)
-
-
 # ---- the device HBM census ------------------------------------------------
 
 #: census category -> walker yielding candidate owner objects (arrays,
@@ -889,47 +850,6 @@ def memory_usage_rows() -> List[list]:
                  "less the ledger past a "
                  f"{UNTRACKED_BAND_BYTES >> 20} MiB band"])
     return rows
-
-
-# ---- per-query probe ------------------------------------------------------
-
-class QueryMemProbe:
-    """Bracket one query with measured memory detail (``peak_heap_kb`` /
-    ``peak_hbm_bytes`` / ``mem_untracked_frac``).  Uses tracemalloc's resettable peak where
-    available, so the probe measures THIS query's heap high water, not
-    the process's history.  All writes stay inside this module
-    (qlint OB407)."""
-
-    def __init__(self):
-        self._started = False
-        self._base_kb = 0.0
-
-    def start(self) -> None:
-        if not tracemalloc.is_tracing():
-            tracemalloc.start(MAX_SITE_DEPTH)
-            self._started = True
-        try:
-            tracemalloc.reset_peak()
-        except AttributeError:
-            pass
-        self._base_kb = tracemalloc.get_traced_memory()[0] / 1024.0
-
-    def finish(self, tracked_peak_bytes: int = 0) -> Dict[str, float]:
-        cur, peak = tracemalloc.get_traced_memory()
-        peak_kb = max(0.0, peak / 1024.0 - self._base_kb)
-        alloc_bytes = peak_kb * 1024.0
-        untracked = max(0.0, alloc_bytes - float(tracked_peak_bytes))
-        out = {
-            "peak_heap_kb": round(peak_kb, 1),
-            "peak_hbm_bytes": _hbm_total_fast(),
-            "mem_untracked_frac":
-                round(untracked / alloc_bytes, 4) if alloc_bytes > 0
-                else 0.0,
-        }
-        if self._started:
-            tracemalloc.stop()
-            self._started = False
-        return out
 
 
 # ---- the background sampler (server lifecycle) ---------------------------

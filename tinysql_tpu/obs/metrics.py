@@ -146,6 +146,15 @@ METRICS: Dict[str, Tuple[str, str]] = {
         ("counter", "Seconds jax spent tracing, lowering and compiling "
                     "programs or loading them from its cache (its own "
                     "duration events; rises when a phase ends)"),
+    "tinysql_span_seconds_total":
+        ("counter", "Seconds inside ended spans and measured intervals, "
+                    "by span name (obs.trace.totals(): what the "
+                    "benchmark's per-layer span metrics read)"),
+    "tinysql_span_count_total":
+        ("counter", "Ended spans and measured intervals, by span name"),
+    "tinysql_span_max_seconds":
+        ("gauge", "The longest single span or interval since the process "
+                  "began, by span name"),
     "tinysql_pending_cost_analyses":
         ("gauge", "Deferred XLA cost analyses awaiting resolution "
                   "(drained by the tsring sampler tick / bench; "
@@ -993,7 +1002,16 @@ def render_prometheus() -> str:
         lines.append(f'{name}_sum {_fmt_value(float(ph["sum"]))}')
         lines.append(f'{name}_count {ph["count"]}')
 
-    from .trace import recent_traces
+    # the span totals, rendered at scrape (nothing on a statement's
+    # path): the numbers the benchmark judges by are the ones graphed
+    from .trace import ring_len, totals
+    rows = sorted(totals().items())
+    for name, key in (("tinysql_span_seconds_total", "sum_s"),
+                      ("tinysql_span_count_total", "count"),
+                      ("tinysql_span_max_seconds", "max_s")):
+        if rows:
+            emit(name, METRICS[name][1], METRICS[name][0],
+                 [((("span", span),), t[key]) for span, t in rows])
     emit("tinysql_trace_ring_entries", "Query traces buffered for "
-         "/debug/trace", "gauge", [((), len(recent_traces()))])
+         "/debug/trace", "gauge", [((), ring_len())])
     return "\n".join(lines) + "\n"

@@ -126,6 +126,11 @@ class Span:
         self.tracer.end(self)
         return False
 
+    @property
+    def end_s(self) -> float:
+        """Where it ended, on ``perf_counter``'s clock (once it has)."""
+        return self.start_s + self.dur_s
+
     def to_dict(self) -> dict:
         return {"id": self.sid, "name": self.name, "cat": self.cat,
                 "ts_us": round(self.start_s * 1e6, 1),
@@ -256,6 +261,11 @@ class Tracer:
     def spans(self) -> List[dict]:
         return [s.to_dict() for s in list(self._spans)]
 
+    def ended(self) -> List[Span]:
+        """The ended spans themselves: what ``publish_trace`` keeps, so
+        that a statement pays no rendering for a trace nobody reads."""
+        return list(self._spans)
+
     def chrome_trace(self, pid: int = 0,
                      label: str = "") -> Dict[str, list]:
         """chrome://tracing / Perfetto ``traceEvents`` JSON (via the
@@ -360,7 +370,10 @@ _RING: deque = deque(maxlen=_ring_cap())
 
 def publish_trace(entry: dict) -> None:
     """Append one finished statement's trace record:
-    ``{"sql", "ts", "total_ms", "spans", "chrome"}``."""
+    ``{"sql", "ts", "total_ms", "error", "spans"}``.  ``spans`` may be
+    the ended ``Span`` objects (``Tracer.ended``): they are rendered to
+    dicts when the ring is read, once, and most traces leave the ring
+    unread."""
     with _ring_mu:
         _RING.append(entry)
 
@@ -368,7 +381,16 @@ def publish_trace(entry: dict) -> None:
 def recent_traces(n: Optional[int] = None) -> List[dict]:
     with _ring_mu:
         out = list(_RING)
-    return out[-n:] if n else out
+    out = out[-n:] if n else out
+    for entry in out:
+        spans = entry["spans"]
+        if spans and isinstance(spans[0], Span):
+            entry["spans"] = [s.to_dict() for s in spans]
+    return out
+
+
+def ring_len() -> int:
+    return len(_RING)
 
 
 def clear_traces() -> None:
@@ -378,8 +400,11 @@ def clear_traces() -> None:
 
 # ---- the process's own spans ----------------------------------------------
 
-#: process spans kept for each statement trace the ring keeps
-PROCESS_SPANS_PER_TRACE = 8
+#: process spans kept for each statement trace the ring keeps: a wire
+#: statement leaves nine (``wire.idle``, ``wire.command``, ``wire.parse``,
+#: ``pool.wait``, ``pool.submit``, ``pool.wake``, ``wire.write``,
+#: ``solo``, ``stmt.finish``), a round member its share of the legs
+PROCESS_SPANS_PER_TRACE = 12
 
 #: the sink of spans that no statement owns
 PROCESS = Tracer(keep=PROCESS_SPANS_PER_TRACE * _ring_cap())

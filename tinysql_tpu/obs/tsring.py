@@ -315,24 +315,6 @@ def drain_pending_costs() -> None:
         pass
 
 
-def measure_overhead(n: int = 50) -> Dict[str, float]:
-    """The sampler's steady-state cost, THE definition both benches
-    publish as ``obs_overhead_frac``: one sample's wall (averaged over
-    ``n`` live collections, lazy imports warmed outside the timed loop)
-    over the default sampling interval.  Probes a PRIVATE ring, so the
-    measurement never pollutes the live ring or its self-accounting."""
-    ring = MetricsRing()
-    ring.sample_once()
-    t0 = time.perf_counter()
-    for _ in range(n):
-        ring.sample_once()
-    per_sample_s = (time.perf_counter() - t0) / n
-    return {"sample_wall_s": round(per_sample_s, 6),
-            "interval_s": DEFAULT_INTERVAL_S,
-            "obs_overhead_frac": round(
-                per_sample_s / DEFAULT_INTERVAL_S, 6)}
-
-
 # ---- the background sampler (server lifecycle) ---------------------------
 
 class Sampler:

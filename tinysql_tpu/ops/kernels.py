@@ -352,6 +352,13 @@ def counted_jit(fn, name: str = "", **kw):
     costs: Dict[tuple, Optional[tuple]] = {}
 
     def call(*a, **k):
+        # the host's whole part of a launch: the counters, the cost
+        # look-up, the enqueue (asynchronous: the device's time shows in
+        # ``drain``) and the catalog's note
+        with _obs.span("dispatch", cat="device"):
+            return launch(a, k)
+
+    def launch(a, k):
         fail.inject("kernelDispatchError")
         stats_add("dispatches", 1)
         cost = None
@@ -379,13 +386,11 @@ def counted_jit(fn, name: str = "", **kw):
                                  prog_key))
         sampled = profiler.should_sample()
         t0 = time.perf_counter() if sampled else 0.0
-        with _obs.span("dispatch", cat="device"):
-            res = w(*a, **k)
-            if sampled:
-                # close the async enqueue: the span and the recorded
-                # wall now cover true device busy time for this dispatch
-                jax().block_until_ready(res)
+        res = w(*a, **k)
         if sampled:
+            # close the async enqueue: the span and the recorded wall
+            # now cover true device busy time for this dispatch
+            jax().block_until_ready(res)
             dt = time.perf_counter() - t0
             stats_add("device_s", dt)
             stats_add("profiled_dispatches", 1)
@@ -408,8 +413,9 @@ def h2d(a, layout=None):
     also where a mesh ``layout`` (parallel/dist.py ``rows`` / ``whole``)
     spreads the array over several devices."""
     host = np.asarray(a)
-    out = jnp().asarray(host) if layout is None \
-        else jax().device_put(host, layout)
+    with _obs.span("h2d", cat="device", bytes=int(host.nbytes)):
+        out = jnp().asarray(host) if layout is None \
+            else jax().device_put(host, layout)
     stats_add("h2d_transfers", 1)
     stats_add("h2d_bytes", int(host.nbytes))
     return out

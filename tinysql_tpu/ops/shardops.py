@@ -43,7 +43,6 @@ exchanges feed ``record_skew_retry`` / ``record_exchange``.
 from __future__ import annotations
 
 import threading
-import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -68,21 +67,6 @@ STATS: Dict[str, float] = {
     "shard_rounds": 0, "shard_rows_hwm": 0, "shard_exchange_bytes": 0,
     "shard_skew_retries": 0, "shard_stacked_rounds": 0,
 }
-
-#: wall seconds of the most recent sharded DEVICE REGION — partition-block
-#: upload, the shard_map dispatch, and result download — set by every
-#: sharded entry point right after its dispatch, to split a measurement
-#: into the shard-parallel region and the serial host sections (partition
-#: scatter, probe-order re-assembly).  A point sample, not a cumulative
-#: counter — deliberately NOT part of STATS / the metrics registry.  It
-#: has had no reader since PR 31 (ROADMAP.md Design 7).
-LAST_DEVICE_REGION_S: float = 0.0
-
-
-def _note_device_region(t0: float) -> None:
-    global LAST_DEVICE_REGION_S
-    LAST_DEVICE_REGION_S = time.perf_counter() - t0
-
 
 def _record(key: str, n: float = 1) -> None:
     """Accumulator write path (the kernels.stats_add double-entry
@@ -360,11 +344,9 @@ def unique_join_match_sharded(mesh, lkey, n_left: int, rkey, n_right: int,
     bk_h, bi_h = pb.scatter(rk, 0), pb.scatter_ids()
     record_exchange(pp.nbytes + pb.nbytes)
     note_round(max(pp.cap, pb.cap))
-    t0 = time.perf_counter()
     pkb, pib = kernels.h2d(pk_h), kernels.h2d(pi_h)
     bkb, bib = kernels.h2d(bk_h), kernels.h2d(bi_h)
     hit, brow = kernels.d2h_many(fn(pkb, pib, bkb, bib))
-    _note_device_region(t0)
     hit = hit.reshape(-1)
     brow = brow.reshape(-1)
     flat_ids = pp.scatter_ids().reshape(-1)
@@ -462,11 +444,9 @@ def semi_join_match_sharded(mesh, lkey, n_left: int, rkey, n_right: int,
     bk_h, bi_h = pb.scatter(rk, 0), pb.scatter_ids()
     record_exchange(pp.nbytes + pb.nbytes)
     note_round(max(pp.cap, pb.cap))
-    t0 = time.perf_counter()
     pkb, pib = kernels.h2d(pk_h), kernels.h2d(pi_h)
     bkb, bib = kernels.h2d(bk_h), kernels.h2d(bi_h)
     mem_flat = kernels.d2h(fn(pkb, pib, bkb, bib)).reshape(-1)
-    _note_device_region(t0)
     flat_ids = pp.scatter_ids().reshape(-1)
     sel = flat_ids >= 0
     member = np.zeros(n_left, dtype=bool)
@@ -603,13 +583,10 @@ def fused_scalar_aggregate_sharded(mesh, dev_cols, agg_specs, arg_exprs,
             vals = kernels.unpack_host(val, schema) if tag == "host" \
                 else kernels.unpack_flat(val, schema)
             return kernels._unpack_scalar_agg(vals)
-    t0 = time.perf_counter()
     dist.note_dispatch(mesh)
-    out = kernels._unpack_scalar_agg(kernels.unpack_flat(
+    return kernels._unpack_scalar_agg(kernels.unpack_flat(
         fn(tuple(dev_cols), mask_arr,
            kernels._params_dev(params, dist.whole(mesh))), schema))
-    _note_device_region(t0)
-    return out
 
 
 # ---- sharded sort / top-k --------------------------------------------------
@@ -679,9 +656,7 @@ def sort_permutation_sharded(mesh, key_cols, descs, n_rows: int):
     fn = progcache.get(key, lambda: _sort_rank_kernel(mesh, n, sdtype))
     note_round(nb // n)
     sp = _score_pad(score, nb)
-    t0 = time.perf_counter()
     rank = kernels.d2h(fn(kernels.h2d(sp)))
-    _note_device_region(t0)
     perm = np.empty(nb, dtype=np.int64)
     perm[rank] = np.arange(nb, dtype=np.int64)
     return perm[:n_rows]
@@ -734,7 +709,5 @@ def top_k_sharded(mesh, key_cols, descs, n_rows: int, k: int):
         key, lambda: _topk_merge_kernel(mesh, n, kb, m, sdtype))
     note_round(m)
     sp = _score_pad(score, nb)
-    t0 = time.perf_counter()
     ids = kernels.d2h(fn(kernels.h2d(sp)))[:k]
-    _note_device_region(t0)
     return ids[ids < n_rows]

@@ -66,7 +66,8 @@ from ..utils import interrupt
 from ..utils.interrupt import QueryKilled
 from . import protocol as p
 from .packetio import MAX_PAYLOAD, PacketIO
-from .server import ClientConn, _err_packet_for, parse_sql
+from .pool import record_wake
+from .server import ClientConn, _err_packet_for, parse_sql, record_idle
 
 log = logging.getLogger("tinysql_tpu.aio")
 
@@ -108,7 +109,7 @@ class _AioConn:
 
     __slots__ = ("cc", "sock", "salt", "state", "rbuf", "wbuf", "parts",
                  "last_rx", "stmts", "idx", "sql", "entry", "events",
-                 "pumping", "span", "submitted")
+                 "pumping", "span", "submitted", "flushed")
 
     def __init__(self, cc: ClientConn):
         self.cc = cc
@@ -131,6 +132,9 @@ class _AioConn:
         #: and not on the thread's span stack
         self.span = None
         self.submitted = 0.0  # perf_counter at the in-flight submit
+        #: where the last command's span ended (``wire.idle`` runs from
+        #: there to the next command's packet), None before the first
+        self.flushed = None
 
 
 class _Loop:
@@ -516,11 +520,14 @@ class _Loop:
             conn.span = obs_context.PROCESS.begin(
                 "wire.command", cat="wire", annotate=False,
                 args={"cmd": cmd, "conn": cc.conn_id})
+            record_idle(conn.flushed, conn.span, cc.conn_id)
             with obs_context.under(conn.span):
                 self._start_query(conn, body.decode("utf-8", "replace"))
             return
-        with obs_context.process_span("wire.command", cat="wire",
-                                      cmd=cmd, conn=cc.conn_id):
+        command = obs_context.process_span("wire.command", cat="wire",
+                                           cmd=cmd, conn=cc.conn_id)
+        record_idle(conn.flushed, command, cc.conn_id)
+        with command:
             try:
                 cc.dispatch_command(cmd, body)
             except Exception as e:  # one bad command != dead conn
@@ -528,6 +535,7 @@ class _Loop:
                             cc.conn_id, e)
                 cc.io.write_packet(_err_packet_for(e))
             self._after_command(conn)
+        conn.flushed = command.end_s
 
     def _after_command(self, conn: _AioConn) -> None:
         if conn.state != "closed" and conn.cc.session.killed:
@@ -601,10 +609,11 @@ class _Loop:
         conn.entry = None
         cc = conn.cc
         # submit -> done was no thread's wait here: measured, not live
-        obs_context.PROCESS.add_complete(
-            "pool.wait", conn.submitted,
-            time.perf_counter() - conn.submitted, cat="serving",
-            up=conn.span, args={"verdict": entry.verdict})
+        now = time.perf_counter()
+        record_wake(entry, obs_context.PROCESS.add_complete(
+            "pool.wait", conn.submitted, now - conn.submitted,
+            cat="serving", up=conn.span,
+            args={"verdict": entry.verdict}), now)
         with obs_context.under(conn.span):
             if entry.error is not None:
                 log.debug("query error: %s", entry.error)
@@ -621,6 +630,7 @@ class _Loop:
         sp, conn.span = conn.span, None
         if sp is not None:
             obs_context.PROCESS.end(sp)
+            conn.flushed = sp.end_s
 
     def _finish_command(self, conn: _AioConn) -> None:
         conn.stmts = []

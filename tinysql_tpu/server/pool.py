@@ -23,8 +23,12 @@ the per-statement measurement on the session (``pending_wait``) right
 before invoking it — the statement scope turns it into ``queue_wait``
 / ``batch_wait`` trace spans, ``statements_summary`` columns, and
 ``slow_query`` fields.  Workers run each statement inside a
-``contextvars`` copy of the submitting thread's context, so the span
-chain parents across the thread hop (the PR 3 devpipe idiom).
+``contextvars`` copy of the submitting thread's context (the PR 3
+devpipe idiom), under the worker's own span (``solo``, a round's leg),
+which names the submitter's ``pool.wait`` as its ``wait``.  The way
+back is measured too: ``_Entry.complete`` stamps the clock once the
+worker's span has ended, and the submitter records ``pool.wake`` when it
+runs again.
 
 Coalescing: when a worker dequeues a SELECT whose normalized-SQL digest
 belongs to a learned batchable family (ops/batching.py — statements
@@ -121,7 +125,7 @@ class _Entry:
     __slots__ = ("session", "stmt", "label", "digest", "done", "result",
                  "error", "state", "queued_at", "batchable", "ctx",
                  "queued_mono", "claimed_at", "queue_wait_s", "verdict",
-                 "on_done")
+                 "on_done", "done_at")
 
     def __init__(self, session, stmt, label: str, digest: str,
                  batchable: bool, on_done=None):
@@ -153,6 +157,9 @@ class _Entry:
         self.claimed_at = self.queued_mono
         self.queue_wait_s = 0.0
         self.verdict = "admitted"
+        #: ``perf_counter`` where ``complete`` ran (0.0 until it has):
+        #: the start of the submitter's ``pool.wake``
+        self.done_at = 0.0
 
     def claim(self) -> None:
         """A worker took this entry off the queue: freeze its measured
@@ -182,12 +189,29 @@ class _Entry:
         self.result = result
         self.error = error
         self.state = "done"
+        self.done_at = time.perf_counter()
         self.done.set()
         if self.on_done is not None:
             try:
                 self.on_done(self)
             except Exception:  # a callback bug must not kill the worker
                 log.warning("entry on_done callback failed", exc_info=True)
+
+
+def record_wake(entry: _Entry, wait_span,
+                now: Optional[float] = None) -> None:
+    """``pool.wake`` under the submitter's ``pool.wait``: from
+    ``complete`` on whatever thread ran it to the submitter running
+    again (``now``, else here): the thread's wake-up and its turn at the
+    interpreter's lock; the event loop's self-pipe.  A wait, so measured
+    and never live: no profiler annotation (docs/OBSERVABILITY.md)."""
+    if not entry.done_at:
+        return  # the wait was broken off before the entry completed
+    if now is None:
+        now = time.perf_counter()
+    obs_context.PROCESS.add_complete(
+        "pool.wake", entry.done_at, now - entry.done_at, cat="serving",
+        up=wait_span)
 
 
 class StatementPool:
@@ -222,11 +246,14 @@ class StatementPool:
         if not self.routes_to_pool(stmt):
             return session.execute_stmt(stmt, label)
         # submit -> done, on the connection thread; the entry copies the
-        # context inside it, so the statement's spans parent here
+        # context inside it, so the worker's span names this one
         with obs_context.process_span("pool.wait", cat="serving") as sp:
             entry = self.submit(session, stmt, label)
             sp.args["verdict"] = entry.verdict
-            return self._wait(entry)
+            try:
+                return self._wait(entry)
+            finally:
+                record_wake(entry, sp)
 
     def submit(self, session, stmt, label: str, on_done=None) -> _Entry:
         """Enqueue one POOLED statement and return its entry without
@@ -238,19 +265,22 @@ class StatementPool:
         size = self._gvar("tidb_stmt_pool_size", 4)
         digest = ""
         batchable = False
-        if isinstance(stmt, ast.SelectStmt) \
-                and self._gvar("tidb_batch_max_size", 16) >= 2 \
-                and not session.in_txn() \
-                and bool(session.get_sysvar("autocommit")):
-            from ..ops import batching
-            # normalize only once families exist: a cold server (or one
-            # whose workload never takes a batchable fused path) skips
-            # the per-statement tokenize entirely
-            if batching.have_families():
-                from ..obs import stmtsummary
-                digest, _ = stmtsummary.normalize(
-                    getattr(stmt, "src", "") or label)
-                batchable = batching.family_batchable(digest)
+        # the submitter's own work before the entry exists (the entry's
+        # queue wait runs from its creation): is this a batchable family?
+        with obs_context.process_span("pool.submit", cat="serving"):
+            if isinstance(stmt, ast.SelectStmt) \
+                    and self._gvar("tidb_batch_max_size", 16) >= 2 \
+                    and not session.in_txn() \
+                    and bool(session.get_sysvar("autocommit")):
+                from ..ops import batching
+                # normalize only once families exist: a cold server (or
+                # one whose workload never takes a batchable fused path)
+                # skips the per-statement tokenize entirely
+                if batching.have_families():
+                    from ..obs import stmtsummary
+                    digest, _ = stmtsummary.normalize(
+                        getattr(stmt, "src", "") or label)
+                    batchable = batching.family_batchable(digest)
         entry = _Entry(session, stmt, label, digest, batchable,
                        on_done=on_done)
         with self._cv:
@@ -433,35 +463,35 @@ class StatementPool:
     @staticmethod
     def _exec_entry(entry: _Entry, rnd=None):
         """Run the entry's statement INSIDE the context captured at
-        submit time (cross-thread span parenting, the PR 3 devpipe
-        idiom): a solo statement's parse→plan→execute span chain parents
-        to whatever span was live on the submitting thread
-        (``pool.wait``) instead of starting an orphan chain on the
-        worker.  A batch round (when given) is activated inside that
-        copied context — activating it on the worker's own context would
-        be invisible there — and the round's leg that is live on the
-        worker (``round.collect`` / ``round.replay``) adopts the
-        member's spans: a round's legs account for what ran in them
-        (the leg's parent is the round: its id rides the wait info into
-        the member's ``batch_wait`` span)."""
+        submit time (whatever the submitting thread had set rides
+        along, the PR 3 devpipe idiom), under the span that is live on
+        the worker: ``solo``, or the round's leg (``round.collect`` /
+        ``round.replay``).  That span adopts the statement's spans, so
+        it accounts for what ran in it on its own thread (its self time
+        is what no child names); its ``wait`` argument leads back to the
+        submitter's ``pool.wait``, and a leg's parent is the round,
+        whose id rides the wait info into the member's ``batch_wait``
+        span.  A batch round (when given) is activated inside the copied
+        context — activating it on the worker's own context would be
+        invisible there."""
+        leg = obs_context.live_span()
         if rnd is None:
             entry.session.pending_wait = entry.wait_info()
-            return entry.ctx.run(entry.session.execute_stmt, entry.stmt,
-                                 entry.label)
-        leg = obs_context.live_span()
-        entry.session.pending_wait = entry.wait_info(
-            batch_wait_s=time.monotonic() - entry.claimed_at,
-            round_id=leg.parent if leg is not None else None)
+        else:
+            entry.session.pending_wait = entry.wait_info(
+                batch_wait_s=time.monotonic() - entry.claimed_at,
+                round_id=leg.parent if leg is not None else None)
 
         def _invoke():
             from ..ops import batching
-            tok = batching.activate(rnd)
+            tok = batching.activate(rnd) if rnd is not None else None
             try:
                 with obs_context.under(leg):
                     return entry.session.execute_stmt(entry.stmt,
                                                       entry.label)
             finally:
-                batching.deactivate(tok)
+                if tok is not None:
+                    batching.deactivate(tok)
         return entry.ctx.run(_invoke)
 
     def _run_one(self, entry: _Entry) -> None:
@@ -473,12 +503,16 @@ class StatementPool:
             return
         admission.count_admitted()
         admission.record_queue_wait(entry.queue_wait_s)
+        # the entry completes once its span has ended: the submitter's
+        # ``pool.wake`` begins where the worker's span ends
+        result = error = None
         with obs_context.process_span("solo", cat="serving",
                                       wait=entry.waiter()):
             try:
-                entry.complete(result=self._exec_entry(entry))
+                result = self._exec_entry(entry)
             except BaseException as e:
-                entry.complete(error=e)
+                error = e
+        entry.complete(result, error)
 
     def _run_batch(self, group: List[_Entry]) -> None:
         """Drive one coalesced group through collect / dispatch / replay
@@ -505,10 +539,12 @@ class StatementPool:
                 continue
             admission.count_admitted()
             rnd.collecting = True
+            result = error = None
             with obs_context.process_span("round.collect", cat="serving",
                                           wait=e.waiter()) as leg:
                 try:
                     result = self._exec_entry(e, rnd)
+                    leg.args["outcome"] = "completed"
                 except batching.Parked:
                     # wait accounting deferred to the replay leg: a
                     # parked member can still be killed before it ever
@@ -518,14 +554,12 @@ class StatementPool:
                     pending.append(e)
                 except BaseException as ex:
                     leg.args["outcome"] = "error"
-                    admission.record_queue_wait(e.queue_wait_s)
-                    e.complete(error=ex)
-                else:
-                    leg.args["outcome"] = "completed"
-                    admission.record_queue_wait(e.queue_wait_s)
-                    e.complete(result=result)
+                    error = ex
                 finally:
                     rnd.collecting = False
+            if leg.args["outcome"] != "parked":
+                admission.record_queue_wait(e.queue_wait_s)
+                e.complete(result, error)
         round_span.args["parked"] = len(pending)
         if not pending:
             return
@@ -544,6 +578,7 @@ class StatementPool:
             admission.record_queue_wait(e.queue_wait_s)
             rnd.replaying = True
             rnd.consumed = "none"
+            result = error = None
             with obs_context.process_span("round.replay", cat="serving",
                                           wait=e.waiter()) as leg:
                 try:
@@ -552,12 +587,13 @@ class StatementPool:
                     # batch_wait now spans claim -> replay, i.e. the
                     # time spent waiting on the round's other members +
                     # the shared dispatch
-                    e.complete(result=self._exec_entry(e, rnd))
+                    result = self._exec_entry(e, rnd)
                 except BaseException as ex:
-                    e.complete(error=ex)
+                    error = ex
                 finally:
                     rnd.replaying = False
                     leg.args["consume"] = rnd.consumed
+            e.complete(result, error)
 
     # ---- introspection / lifecycle --------------------------------------
     def snapshot(self) -> dict:

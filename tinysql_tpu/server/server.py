@@ -64,6 +64,19 @@ def parse_sql(sql: str) -> list:
     return stmts
 
 
+def record_idle(flushed: Optional[float], command, conn_id: int) -> None:
+    """``wire.idle``: the connection between the last command's span
+    (ended at ``flushed``) and ``command``, whose packet has just been
+    read whole: the client's turn and the socket.  Nothing before a
+    connection's first command.  A wait, so measured and never live: the
+    totals and the process ring hold it, the profiler's clock does not
+    (docs/OBSERVABILITY.md)."""
+    if flushed is not None:
+        obs_context.PROCESS.add_complete(
+            "wire.idle", flushed, command.start_s - flushed, cat="wire",
+            args={"conn": conn_id})
+
+
 def _err_packet_for(e: Exception) -> bytes:
     """Map a statement error onto the wire: typed errors carry their own
     MySQL code/sqlstate (QueryKilled 1317, QueryTimeout 3024,
@@ -196,6 +209,7 @@ class ClientConn:
                 else self.handshake()
             if not ok:
                 return
+            flushed = None  # where the last command's span ended
             while self.alive:
                 self.io.reset_sequence()
                 try:
@@ -208,8 +222,10 @@ class ClientConn:
                 if cmd == p.COM_QUIT:
                     return
                 # packet read complete -> response flushed
-                with obs_context.process_span("wire.command", cat="wire",
-                                              cmd=cmd, conn=self.conn_id):
+                command = obs_context.process_span(
+                    "wire.command", cat="wire", cmd=cmd, conn=self.conn_id)
+                record_idle(flushed, command, self.conn_id)
+                with command:
                     try:
                         self.dispatch_command(cmd, payload)
                     except ConnectionError:
@@ -221,6 +237,7 @@ class ClientConn:
                             self.io.write_packet(_err_packet_for(e))
                         except OSError:
                             return
+                flushed = command.end_s
                 if self.session.killed:
                     # plain KILL <id>: the connection drops after the
                     # current command's response went out

@@ -541,8 +541,13 @@ class Session:
             qobs.info = info
             if parked:
                 obs_context.PROCESS.adopt(qobs.tracer)
-            else:
-                self._finish_obs(s, qobs, info, err, n_rows)
+            elif not self.internal:
+                # on the worker, before the submitter is woken: no
+                # statement scope is live any more, so the span is the
+                # process's, under the worker's ``solo`` / ``round.replay``
+                with obs_context.process_span("stmt.finish",
+                                              cat="session"):
+                    self._finish_obs(s, qobs, info, err, n_rows)
 
     def _finish_obs(self, stmt: ast.StmtNode, qobs, info: Dict[str, float],
                     err: bool, rows_returned: int = 0) -> None:
@@ -550,12 +555,11 @@ class Session:
         ring (/debug/trace), the structured slow-query log, the
         statement-summary store (THE designated stmtsummary write hook —
         qlint OB403), and the bucket-prewarm feedback file.  Never
-        raises.  INTERNAL sessions (the auto-prewarm worker) skip the
-        fan-out entirely: their warming executions must not inflate
-        statements_summary (the worker ranks from it — feeding its own
-        runs back in would self-amplify), the slow log, or /metrics."""
-        if self.internal:
-            return
+        raises.  INTERNAL sessions (the auto-prewarm worker) never come
+        here (``_execute_one``): their warming executions must not
+        inflate statements_summary (the worker ranks from it — feeding
+        its own runs back in would self-amplify), the slow log, or
+        /metrics."""
         from ..obs import metrics as obs_metrics
         from ..obs import slowlog as obs_slowlog
         from ..obs import stmtsummary
@@ -588,7 +592,7 @@ class Session:
                 publish_trace({
                     "sql": qobs.sql[:512], "ts": qobs.started_at,
                     "total_ms": round(total_ms, 3), "error": err,
-                    "spans": qobs.tracer.spans(),
+                    "spans": qobs.tracer.ended(),
                 })
             # digest/sample from the statement's OWN source slice: a
             # batch label ("... [stmt 2/3]") would fall back to raw-text
@@ -807,20 +811,21 @@ class Session:
         qobs = obs_context.current()
         t0 = time.perf_counter()
         builder = PlanBuilder(self)
+        use_tpu = self._use_tpu()
         with obs_context.span("plan"):
             logical = builder.build_select(stmt)
-        columns = [c.name for c in logical.schema.columns]
-        use_tpu = self._use_tpu()
         with obs_context.span("place", tpu=use_tpu):
             phys = self._optimize(logical, use_tpu)
         t_plan = time.perf_counter() - t0
-        from ..planner.explain import explain_text, plan_digest
         # published BEFORE execution: a concurrently-running statement's
         # plan is readable via EXPLAIN FOR CONNECTION <id> / processlist
-        self.last_plan_rows = explain_text(phys)
-        if qobs is not None:
-            qobs.plan_digest = plan_digest(phys)
-            qobs.plan_rows = self.last_plan_rows
+        with obs_context.span("plan.publish"):
+            from ..planner.explain import explain_text, plan_digest
+            columns = [c.name for c in logical.schema.columns]
+            self.last_plan_rows = explain_text(phys)
+            if qobs is not None:
+                qobs.plan_digest = plan_digest(phys)
+                qobs.plan_rows = self.last_plan_rows
         try:
             rows = self._run_phys(phys, use_tpu, qobs)
         except Exception as e:
@@ -837,11 +842,13 @@ class Session:
                          [c.ret_type for c in logical.schema.columns])
 
     def _run_phys(self, phys, use_tpu: bool, qobs) -> List[list]:
+        from ..obs import context as obs_context
         from ..obs.runtime_stats import instrument_tree
-        ex = build_executor(phys, use_tpu=use_tpu)
-        instrument_tree(ex, qobs)
-        ex.open(ExecContext(self.get_txn(), self.sysvars,
-                            self.infoschema(), self.storage))
+        with obs_context.span("exec.build"):
+            ex = build_executor(phys, use_tpu=use_tpu)
+            instrument_tree(ex, qobs)
+            ex.open(ExecContext(self.get_txn(), self.sysvars,
+                                self.infoschema(), self.storage))
         try:
             return ex.drain()
         finally:
