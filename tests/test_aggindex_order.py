@@ -219,9 +219,14 @@ def test_memo_keys_by_observed_order(tk, layout):
     scan = "select count(*), sum(x) from {t} where b > 0 and k >= 0"
     for t in "cu":
         _, delta = _device(tk, q.format(t=t))
-        # one traced program for both: the shuffled table's statement
-        # finds the one the clustered table's built
-        assert delta["progcache_misses"] == int(t == "c"), (t, delta)
+        # one traced program for both on one device: the shuffled
+        # table's statement finds the one the clustered table's built.
+        # Under the mesh a program is also by the span of groups a shard
+        # bounds (``_span_pad``): 96 of 256 slots over the clustered
+        # table, 224 over the shuffled one, whose shards each hold rows
+        # of nearly every key
+        assert delta["progcache_misses"] == \
+            int(t == "c" or layout == "mesh4"), (t, delta)
     for rep in (rep_c, rep_u):
         assert not _lane_keys(rep, "gi_order", "gi_shard_order",
                               "gi_shard_rows")
@@ -313,14 +318,19 @@ def gather_shapes(s, sql, monkeypatch):
 def test_lowered_program_gathers_only_boundaries(tk, monkeypatch, layout,
                                                  table):
     """No gather with the leaf's [nb] (a shard: [nb / n]) result; one
-    [ngb] gather a ``seg`` call; the same for both tables."""
+    boundary gather a ``seg`` call, over the [ngb] groups on one device
+    and over the pad of a shard's span of them under the mesh; the same
+    for both tables but for that pad."""
     _two_tables(tk)
     _use(tk, layout)
     sql = (f"select k, count(*), count(x), sum(x), sum(b) from {table} "
            "where b > -40 group by k")
     shapes, sums = gather_shapes(tk, sql, monkeypatch)
     per = NB // 4 if layout == "mesh4" else NB
-    ngb = 256
+    # a quarter of the clustered table's rows hold up to 75 of its 201
+    # groups, a quarter of the shuffled one's all of them: 75 + 8 and
+    # 201 + 8 rounded up to a multiple of 16
+    ngb = 256 if layout == "one" else {"c": 96, "u": 224}[table]
     # presence; count(x): its count; sum(x): a count (the NULL flag)
     # and the sum; sum(b): the sum alone (b is NULL on no row: its count
     # is presence)
@@ -365,3 +375,158 @@ def test_never_null_arguments_take_presence_for_their_count(tk, monkeypatch,
     del seen[:]
     got, delta = _device(tk, "select s, sum(b), count(b) from c group by s")
     assert delta["agg_dense"] == 1 and seen and not any(seen), seen
+
+
+# ---- under the mesh a shard bounds only its own span of groups --------------
+
+def _load_keys(s, name, k, knull=None, seed=3):
+    """A table of the given keys in the given order (``knull``: the rows
+    whose key is NULL), an int and a double argument beside them."""
+    n = len(k)
+    rng = np.random.default_rng(seed)
+    cols = {"k": (np.asarray(k, dtype=np.int64), knull),
+            "s": (np.array(["AA"] * n), None),
+            "b": (rng.integers(-50, 50, n).astype(np.int64), None),
+            "x": (rng.random(n) * 100, rng.random(n) < 0.1)}
+    return _load(s, name, cols, np.arange(n))
+
+
+def _runs(sizes):
+    """Keys 0, 1, ... in order, ``sizes[g]`` rows of key g."""
+    return np.repeat(np.arange(len(sizes)), sizes)
+
+
+def _span_tables():
+    """{case: (keys, null mask or None, spans observed [(g_lo, g_hi)] a
+    shard of 1024 rows, the pad ``qb`` or None where the spans are whole
+    and the [ngb] tables are summed)}."""
+    rng = np.random.default_rng(11)
+    cases = {}
+    # stored by its key: 200 groups of 15 rows, four shards of 1024
+    # rows; 1024 / 15 is no whole number, so every shard's last group is
+    # the next one's first (68 and 136 are shared by two shards), and
+    # the fourth shard holds no row (3000 rows in a bucket of 4096)
+    cases["clustered"] = (_runs([15] * 200), None,
+                          [(0, 68), (68, 136), (136, 199), (0, -1)], 80)
+    # one group of 2100 rows lies over three shards, 100 groups after it
+    cases["group_over_three_shards"] = (
+        _runs([2100] + [9] * 100), None,
+        [(0, 0), (0, 0), (0, 100), (0, -1)], 112)
+    # shuffled: every shard holds rows of (nearly) every one of the 250
+    # keys, the spans are whole and the path is the psum of [256] tables
+    shuffled = rng.permutation(_runs([12] * 250))
+    cases["unclustered_whole_spans"] = (shuffled, None, None, None)
+    # the NULL keys form the last group, all of it in the third shard
+    k = _runs([14] * 210)
+    cases["null_key_group"] = (k, k >= 205, None, 96)
+    # two shards of ONE group each beside a shard of 190: the pieces
+    # are as long as the widest span, and the narrow ones' tails (past
+    # their one group) add zeros into the next shards' places
+    cases["one_group_shards"] = (_runs([1024, 1024] + [5] * 190), None,
+                                 [(0, 0), (1, 1), (2, 191), (0, -1)], 208)
+    return cases
+
+
+SPAN_CASES = _span_tables()
+
+
+@pytest.mark.parametrize("case", sorted(SPAN_CASES))
+def test_span_path_equals_the_unsharded_aggregate(tk, case):
+    """Each shard bounds its own span of groups and the pieces are added
+    into place; the answer is the one-device program's row for row
+    (counts, keys, min/max equal; sums to the rounding of a difference
+    of running totals taken over a quarter of the rows) and the host's.
+    The path follows the spans the index observed: the counter says
+    which ran, the boundary lane's length what was bounded."""
+    k, knull, spans, qb = SPAN_CASES[case]
+    rep = _load_keys(tk, "t", k, knull)
+    sql = ("select k, count(*), count(x), sum(x), sum(b), min(x), max(b) "
+           "from t where b > -45 group by k order by k")
+    _use(tk, "one")
+    single, delta = _device(tk, sql)
+    assert delta["agg_sorted"] == 1 and delta["agg_span_cut"] == 0
+    _use(tk, "mesh4")
+    got, delta = _device(tk, sql)
+    assert delta["agg_sorted"] == 1 and delta["dispatches"] == 1
+    assert delta["host_dispatches"] == 0
+    assert delta["agg_span_cut"] == int(qb is not None)
+    assert _close(got, single, rel=1e-12) and _close(got, _host(tk, sql))
+    assert len(got) == len(np.unique(k[~knull] if knull is not None
+                                     else k)) + int(knull is not None)
+    sids = next(key for key in rep.cache if key[0] == "groupindex")[1]
+    gidx = rep.cache[("groupindex", sids)]
+    ngb = kernels.bucket(gidx.n_groups)
+    lane = [key for key in _lane_keys(rep, "gi_shard_ends")
+            if key[-2:] == ("rows", 4)]
+    assert [key[2] for key in lane] == [ngb if qb is None else qb]
+    g_lo, g_hi = gidx.spans(4, NB // 4)
+    if spans is not None:
+        assert list(zip(g_lo, g_hi)) == spans
+    if qb is None:
+        assert not _lane_keys(rep, "gi_span_lo")
+        assert (g_hi - g_lo).max() + 1 + ngb // 32 > ngb - ngb // 16
+    else:
+        lo_lane = rep.cache[_lane_keys(rep, "gi_span_lo")[0]]
+        assert np.array_equal(np.asarray(lo_lane), g_lo)
+        assert qb % (ngb // 16) == 0 and qb < ngb
+        # a shard's boundaries: the last row of each group of its span,
+        # then its last row again
+        ends = np.asarray(rep.cache[lane[0]]).reshape(4, qb)
+        rows = np.clip(len(k) - np.arange(4) * (NB // 4), 0, NB // 4)
+        for s_ in range(4):
+            assert np.all(np.diff(ends[s_]) >= 0)
+            assert ends[s_, -1] == rows[s_] - 1
+            width = g_hi[s_] - g_lo[s_] + 1
+            assert np.all(ends[s_, width - 1:] == rows[s_] - 1)
+    # warm: parameters alone go up, nothing is laid out anew
+    _, warm = _device(tk, sql)
+    assert warm["h2d_bytes"] < 1024 and warm["reshard_bytes"] == 0
+    assert warm["progcache_misses"] == 0
+
+
+def test_spans_on_both_sides_of_a_quarter_share_one_program(tk):
+    """A shard of 2^k rows of a table stored by its key spans a quarter
+    of the group slots give or take the data's draw (PERF.md section 6,
+    PR 38): 1024 rows hold 62 groups of 17 rows and 70 of 15, on either
+    side of 256 / 4.  Both pad to 80 — five steps of 256 // 16 — and run
+    ONE program, where the next power of two would be 64 and 128."""
+    reps = {}
+    for name, size, ng in (("under", 17, 212), ("over", 15, 240)):
+        reps[name] = _load_keys(tk, name, _runs([size] * ng))
+    sql = "select k, count(*), sum(x), sum(b) from {t} group by k order by k"
+    _use(tk, "mesh4")
+    widest = {}
+    for i, name in enumerate(("under", "over")):
+        got, delta = _device(tk, sql.format(t=name))
+        assert delta["agg_span_cut"] == 1
+        assert delta["progcache_misses"] == int(i == 0), (name, delta)
+        assert _close(got, _host(tk, sql.format(t=name)))
+        rep = reps[name]
+        sids = next(key for key in rep.cache if key[0] == "groupindex")[1]
+        g_lo, g_hi = rep.cache[("groupindex", sids)].spans(4, NB // 4)
+        widest[name] = int((g_hi - g_lo).max()) + 1
+        assert [key[2] for key in _lane_keys(rep, "gi_shard_ends")] == [80]
+    assert widest["under"] < 256 // 4 < widest["over"], widest
+    assert kernels.bucket(widest["under"]) != kernels.bucket(widest["over"])
+
+
+def test_span_pad_is_the_widest_span_and_half_a_step():
+    """``_span_pad``: a multiple of ``ngb // 16``, at least the widest
+    span, the same on both sides of every power of two, ``ngb`` (the
+    spans are whole) from fifteen and a half steps on."""
+    lo = np.zeros(4, dtype=np.int64)
+
+    def qb(widest, ngb):
+        hi = np.array([widest - 1, 3, -1, 0], dtype=np.int64)
+        return devpipe._span_pad(lo, hi, ngb)
+    ngb = 1 << 21
+    step = ngb // 16
+    for widest in (524_119, 524_288, 524_612):       # ISSUE 38's draws
+        assert qb(widest, ngb) == 5 * step
+    assert qb(1, ngb) == step and qb(step // 2, ngb) == step
+    assert qb(step // 2 + 1, ngb) == 2 * step
+    assert qb(15 * step, ngb) == ngb and qb(ngb, ngb) == ngb
+    assert qb(14 * step + step // 2, ngb) == 15 * step
+    for widest in range(1, 129):
+        got = qb(widest, 128)
+        assert got % 8 == 0 and widest <= got <= 128

@@ -377,20 +377,45 @@ def test_q3_gathers_at_the_bucket(one_chip, chip_branches, monkeypatch,
     assert _gathers(_compile(fn, *abstract).as_text(), Q3_BUCKET) == expect
 
 
-@pytest.mark.parametrize("live, whole, quarter", [
-    ("consumer", {"aggindex": 4}, {"join/join": 2, "join": 6}),
-    ("all", {"aggindex": 4}, {"join/join": 6, "join": 6}),
+#: a chip's quarter of ``lineitem``'s bucket holds a quarter of the
+#: orders give or take the data's draw; its span of groups pads to five
+#: sixteenths of the group table (``devpipe._span_pad``)
+Q3_SPAN_PAD = 5 * Q3_BUCKET // 16
+
+
+def _collectives(text, kind, rows):
+    """The compiled text's ``kind`` collectives whose result is, or
+    holds, a lane of ``rows`` elements (an all-gather's: every chip's
+    part)."""
+    out = []
+    for line in text.splitlines():
+        head, found, _ = line.partition(f" {kind}(")
+        if found and f"[{rows}]" in head.partition(" = ")[2]:
+            out.append(line)
+    return out
+
+
+@pytest.mark.parametrize("live, quarter", [
+    ("consumer", {"join/join": 2, "join": 6}),
+    ("all", {"join/join": 6, "join": 6}),
 ])
 def test_q3_mesh_gathers_a_chip(topo, chip_branches, monkeypatch,
-                                tpch_session, live, whole, quarter):
-    """Under the mesh every chip bounds the whole merged table (four
-    gathers) and joins its quarter of ``orders``."""
+                                tpch_session, live, quarter):
+    """Under the mesh every chip bounds its own span of the groups (four
+    gathers at the span's pad, none at the whole table's bucket), the
+    pieces cross as all-gathers and no whole table is summed over the
+    chips; and it joins its quarter of ``orders``."""
     if live == "all":
         _all_live(monkeypatch)
     text = _compile_mesh_statement(topo, monkeypatch, tpch_session,
                                    tpch.QUERIES["Q3"])
-    assert _gathers(text, Q3_BUCKET) == whole
+    assert _gathers(text, Q3_BUCKET) == {}
+    assert _gathers(text, Q3_SPAN_PAD) == {"aggindex": 4}
     assert _gathers(text, Q3_BUCKET // 4) == quarter
+    # presence and revenue: two 32-bit halves each, a piece a chip
+    assert len(_collectives(text, "all-gather", 4 * Q3_SPAN_PAD)) == 4
+    assert not _collectives(text, "all-gather", 4 * Q3_BUCKET)
+    assert not _collectives(text, "all-reduce", Q3_BUCKET)
 
 
 def _no_flags(monkeypatch):
@@ -426,18 +451,24 @@ def test_lowered_text_is_the_parents(topo, one_chip, chip_branches,
     count, as before.  With the flags, Q1 (a dense GROUP BY at the
     statement's root: no reader of a flag) and Q6 (no fused pipeline)
     still do, and Q3, whose outer join gathers two null lanes fewer,
-    lowers to the text pinned with PR 36."""
+    lowers to the text pinned with PR 36.  PR 38 left those ten as they
+    were and pinned Q3's mesh program anew, with the flags and without:
+    a chip bounds its own span of the groups, and the pieces are
+    gathered and added into place where whole tables were summed."""
     if flags == "off":
         _no_flags(monkeypatch)
-    pinned = _pinned("lowered_at_pr36.json" if (name, flags) == ("Q3", "on")
-                     else "lowered_at_129247b.json")
+    case = f"{name}.{where}"
+    if case == "Q3.mesh":
+        pinned = _pinned("lowered_at_pr38.json")[f"{case}.{flags}"]
+    else:
+        pinned = _pinned("lowered_at_pr36.json" if (name, flags)
+                         == ("Q3", "on") else "lowered_at_129247b.json")[case]
     sql = tpch.QUERIES[name]
     fn, abstract = _capture(monkeypatch, tpch_session, sql, one_chip) \
         if where == "one" \
         else _capture_mesh(topo, monkeypatch, tpch_session, sql)
     text = fn.lower(*abstract).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() \
-        == pinned[f"{name}.{where}"]
+    assert hashlib.sha256(text.encode()).hexdigest() == pinned
 
 
 # ---- the join chains: Q5, Q10, Q18 as one fused program each ---------------
@@ -602,3 +633,12 @@ def test_fused_mesh_program_at_the_sf10_shapes(topo, chip_branches,
     text = _compile_mesh_statement(topo, monkeypatch, sf10_session,
                                    tpch.QUERIES[name])
     assert "all-to-all" not in text
+    if name == "Q3":
+        # as test_q3_mesh_gathers_a_chip, at 2^24 groups: a chip bounds
+        # its span (2^22 groups give or take 1,024: five sixteenths)
+        groups, pad = 1 << 24, 5 << 20
+        assert _gathers(text, groups) == {}
+        assert _gathers(text, pad) == {"aggindex": 4}
+        assert _gathers(text, groups // 4) == {"join/join": 2, "join": 6}
+        assert len(_collectives(text, "all-gather", 4 * pad)) == 4
+        assert not _collectives(text, "all-reduce", groups)

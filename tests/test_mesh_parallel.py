@@ -675,6 +675,143 @@ def test_group_index_cut_per_shard(n):
                           np.tile(np.arange(per), (n, 1)))
 
 
+# ---- a shard bounds only its own span of groups ------------------------------
+
+@pytest.mark.parametrize("n", [2, 4, 8])
+@pytest.mark.parametrize("stored", ["by_key", "shuffled", "by_key_nulls"])
+def test_group_index_spans_per_shard(n, stored):
+    """GroupIndex.spans: the least and the greatest group id among each
+    shard's rows, against a numpy min/max of the shard's group ids; and
+    span_ends: the columns of ``shards``' ends from each shard's first
+    group on, whose boundary differences are the shard's partial states
+    of its span and sum, added into place, to the whole index's."""
+    import numpy as np
+    from tinysql_tpu.executor.devpipe import GroupIndex, _span_pad
+    rng = np.random.default_rng(17 + n)
+    n_rows, per = 1000, 1024 // n          # the last shard is part padding
+    keys = rng.integers(0, 90, n_rows).astype(np.int64)
+    nulls = np.zeros(n_rows, dtype=bool)
+    if stored != "shuffled":
+        keys = np.sort(keys)
+    if stored == "by_key_nulls":
+        nulls[-40:] = True                 # the NULL group sorts last
+    x = rng.integers(1, 1000, n_rows).astype(np.int64)
+    gidx = GroupIndex([(keys, nulls)])
+    assert gidx.clustered == (stored != "shuffled")
+    ng = gidx.n_groups
+    gid = gidx.row_gid(np.int64)
+    g_lo, g_hi = gidx.spans(n, per)
+    for s_ in range(n):
+        mine = gid[s_ * per:(s_ + 1) * per]
+        assert (g_lo[s_], g_hi[s_]) == (mine.min(), mine.max())
+    assert gidx.spans(2 * n, 1024)[1][1:].tolist() == [-1] * (2 * n - 1)
+    qb = _span_pad(g_lo, g_hi, 128)
+    assert qb >= (g_hi - g_lo).max() + 1
+    order, ends, _sgid, rows = gidx.shards(n, per)
+    cut = gidx.span_ends(n, per, g_lo, qb)
+    assert cut.shape == (n, qb)
+    total = np.zeros(128 + qb, dtype=np.int64)
+    for s_ in range(n):
+        width = min(qb, ng - g_lo[s_])
+        assert np.array_equal(cut[s_, :width],
+                              ends[s_, g_lo[s_]:g_lo[s_] + width])
+        assert np.all(cut[s_, width:] == rows[s_] - 1)
+        assert g_lo[s_] == 0 or ends[s_, g_lo[s_] - 1] == -1
+        mine = order[s_, :rows[s_]] + s_ * per
+        c = np.concatenate([[0], np.cumsum(x[mine])])
+        hi = c[cut[s_] + 1]
+        total[g_lo[s_]:g_lo[s_] + qb] += hi - np.concatenate([[0], hi[:-1]])
+    assert np.array_equal(total[:ng], np.bincount(gid, weights=x,
+                                                  minlength=ng))
+    assert not total[ng:].any()
+
+
+@pytest.mark.parametrize("dtype", ["int64", "float64"])
+def test_mesh_sum_spans_equals_the_sum_of_whole_tables(dtype):
+    """dist.mesh_sum_spans: each shard's run added at its start — runs
+    that share an entry, a run that reaches past the table, an empty
+    one — is mesh_sum of the whole tables the runs stand for."""
+    import numpy as np
+    from tinysql_tpu.ops import kernels
+    from tinysql_tpu.parallel import dist
+    jn = kernels.jnp()
+    n, q, size = 4, 24, 64
+    mesh = dist.sized_mesh(n)
+    rng = np.random.default_rng(3)
+    parts = (rng.random((n, q)) * 1e6).astype(dtype)
+    parts[2] = 0                                   # a shard with no row
+    starts = np.array([0, 23, 5, 60], dtype=np.int32)
+    parts[3, size - 60:] = 0     # past the table a run holds padding only
+    ROWS, WHOLE = dist.specs()
+
+    def both(part, at):
+        whole = jn.zeros(size + q, dtype=part.dtype)
+        mine = at[kernels.jax().lax.axis_index("shard")]
+        whole = kernels.jax().lax.dynamic_update_slice(whole, part, (mine,))
+        return dist.mesh_sum_spans(part, at, size), \
+            dist.mesh_sum(whole[:size])
+    fn = dist.shard_map_unchecked(both, mesh=mesh, in_specs=(ROWS, WHOLE),
+                                  out_specs=(WHOLE, WHOLE))
+    spans, whole = fn(jn.asarray(parts.reshape(-1)), jn.asarray(starts))
+    want = np.zeros(size + q, dtype=dtype)
+    for s_ in range(n):
+        want[starts[s_]:starts[s_] + q] += parts[s_]
+    assert np.array_equal(np.asarray(spans), want[:size])
+    assert np.allclose(np.asarray(whole), want[:size], rtol=1e-15, atol=0)
+    assert np.asarray(spans)[23] == parts[0, 23] + parts[1, 0]
+
+
+@pytest.mark.parametrize("n", [2, 4, 8])
+def test_agg_span_cut_counts_and_moves_what_the_sum_of_tables_moved(
+        tpch_mesh, monkeypatch, n):
+    """``agg_span_cut``: once a fused dispatch of Q3 over the mesh
+    (``lineitem`` is stored by ``l_orderkey``: a shard holds one piece of
+    the orders), 0 on one device and for Q1's dense GROUP BY; on
+    ``/metrics`` and in the statement's own counters.  A warm Q3 uploads
+    the bytes it uploaded when every shard bounded every group (the
+    shards' first groups are a replica lane, not a parameter) and lays
+    nothing out anew; the rows are the same."""
+    from tinysql_tpu.executor import devpipe
+    from tinysql_tpu.obs import metrics
+    s, _mirror, queries = tpch_mesh
+    _mesh_of(monkeypatch, n)
+    _rows, one = _stats_of(s, queries["Q3"])
+    assert one["agg_sorted"] == 1 and one["agg_span_cut"] == 0
+    with monkeypatch.context() as m:
+        # the spans read as whole: the parent's program
+        m.setattr(devpipe, "_span_pad", lambda g_lo, g_hi, ngb: ngb)
+        _mesh_stats_of(s, queries["Q3"])
+        whole_rows, whole, _ = _mesh_stats_of(s, queries["Q3"])
+    # (a dispatch counts as the mesh's where the mesh is every device)
+    over_all = int(n == len(jax.devices()))
+    assert whole["agg_span_cut"] == 0
+    assert whole["mesh_dispatches"] == over_all
+    before = metrics.render_prometheus()
+    _mesh_stats_of(s, queries["Q3"])
+    rows, warm, _ = _mesh_stats_of(s, queries["Q3"])
+    assert warm["agg_span_cut"] == 1 and warm["agg_clustered"] == 1
+    assert warm["dispatches"] == 1 and warm["mesh_dispatches"] == over_all
+    assert warm["progcache_misses"] == 0
+    assert warm["h2d_bytes"] == whole["h2d_bytes"] < 1024
+    assert warm["reshard_bytes"] == whole["reshard_bytes"] == 0
+    assert _rows_close(rows, whole_rows, rel=1e-12) and rows
+    _rows, q1, _ = _mesh_stats_of(s, queries["Q1"])
+    assert q1["agg_dense"] == 1 and q1["agg_span_cut"] == 0
+
+    def total(text):
+        line = [ln for ln in text.splitlines()
+                if ln.startswith("tinysql_agg_span_cut_total ")]
+        return float(line[0].split()[1])
+    assert total(metrics.render_prometheus()) == total(before) + 2
+    s.execute("set @@tidb_mesh_parallel = 1")
+    try:
+        info = s.query("explain analyze " + queries["Q3"]).rows
+    finally:
+        s.execute("set @@tidb_mesh_parallel = 0")
+    assert any("agg:0dense/1sorted/1clustered/1span_cut" in str(c)
+               for r in info for c in r), info
+
+
 # ---- column liveness under the mesh -----------------------------------------
 # A fused program gathers, carries over its TopN's all-gather and
 # exchanges only the columns its consumer reads (executor/devpipe.py).

@@ -584,10 +584,54 @@ class GroupIndex:
         boundaries (no pass over the rows)."""
         if not self.clustered:
             return self.shards(n, per)[1]
-        total = len(self.order)
+        return self._clustered_ends(n, per, self.ends[None, :])
+
+    def _clustered_ends(self, n: int, per: int, ends) -> np.ndarray:
+        """Over a clustered index: the last position within shard s of
+        a row at or before the global boundary ``ends[s]`` (-1: none)."""
         first = np.arange(n, dtype=np.int64)[:, None] * per
-        rows = np.clip(total - first, 0, per)
-        return np.clip(self.ends[None, :] + 1 - first, 0, rows) - 1
+        rows = np.clip(len(self.order) - first, 0, per)
+        return np.clip(ends + 1 - first, 0, rows) - 1
+
+    def spans(self, n: int, per: int):
+        """(g_lo [n], g_hi [n]): the least and the greatest group id
+        among the rows of each of ``n`` shards of ``per`` contiguous
+        rows; (0, -1) for a shard that holds no row.  A shard's rows in
+        key order are a run of sorted positions' groups, so both follow
+        from ``ends`` by a ``searchsorted`` at the shard's first and
+        last sorted position (over a clustered index those are its
+        first and last row: no pass over the rows)."""
+        g_lo = np.zeros(n, dtype=np.int64)
+        g_hi = np.full(n, -1, dtype=np.int64)
+        total = len(self.order)
+        shard = None if self.clustered else self.order // per
+        for s_ in range(n):
+            if shard is None:
+                pos = (s_ * per, min((s_ + 1) * per, total) - 1)
+            else:
+                mine = np.flatnonzero(shard == s_)
+                pos = (mine[0], mine[-1]) if len(mine) else (0, -1)
+            if pos[1] >= pos[0]:
+                g_lo[s_], g_hi[s_] = np.searchsorted(self.ends, pos)
+        return g_lo, g_hi
+
+    def span_ends(self, n: int, per: int, g_lo: np.ndarray, qb: int,
+                  shard_ends=None) -> np.ndarray:
+        """The ends of ``shards`` cut to each shard's own span of groups
+        [n, qb]: entry k of shard s is the last position in the shard's
+        key-ordered list of a row of group <= g_lo[s] + k (-1 all along
+        a shard without rows).  ``qb`` covers the widest span
+        (``spans``); entries past a shard's span repeat its last
+        boundary — empty ranges, as groups past ``n_groups`` do.  Over a
+        clustered index they follow from ``ends``; otherwise they are
+        columns of ``shard_ends`` (``shards``' [n, n_groups] table)."""
+        g = np.minimum(g_lo[:, None] + np.arange(qb, dtype=np.int64),
+                       self.n_groups - 1)
+        if self.clustered:
+            return self._clustered_ends(n, per, self.ends[g])
+        if shard_ends is None:
+            shard_ends = self.shards(n, per)[1]
+        return np.take_along_axis(shard_ends, g, axis=1)
 
     def shards(self, n: int, per: int):
         """The index cut for a mesh of ``n`` shards holding ``per``
@@ -597,11 +641,14 @@ class GroupIndex:
         positions in place (a permutation of the shard: the identity
         when the index is clustered); ends[s][g] is the last
         position in that list of a row of group <= g (-1: none yet), so
-        every shard sees every group and its partial state for group g
-        is the boundary difference at (ends[s][g-1], ends[s][g]] — empty
-        where the shard holds no row of g; sgid[s] is the group of each
-        listed row (``n_groups`` past the shard's rows); rows[s] counts
-        the shard's rows.  Summed (min/max merged) over the shards the
+        a shard's partial state for group g is the boundary difference
+        at (ends[s][g-1], ends[s][g]] — empty where the shard holds no
+        row of g, which is every group outside [g_lo[s], g_hi[s]]
+        (``spans``): the sorted aggregate reads this table whole only
+        where the shards' spans are (nearly) whole, and ``span_ends``'
+        cut of it otherwise; sgid[s] is the group of each listed row
+        (``n_groups`` past the shard's rows); rows[s] counts the
+        shard's rows.  Summed (min/max merged) over the shards the
         partial states are the unsharded aggregate."""
         ng = self.n_groups
         shard = self.order // per
@@ -649,6 +696,20 @@ def _stable_key_order(svs: List[tuple]) -> Optional[np.ndarray]:
         comp = part if comp is None else comp * width + part
     dtype = np.uint8 if span <= 1 << 8 else np.uint16
     return np.argsort(comp.astype(dtype), kind="stable")
+
+
+def _span_pad(g_lo, g_hi, ngb: int) -> int:
+    """The common length ``qb`` of the shards' pieces of a table of
+    ``ngb`` group slots, from ``GroupIndex.spans``: the widest span and
+    half a step more, rounded up to a multiple of the step ``ngb // 16``
+    (at most ``ngb``: the spans are whole).  Not ``kernels.bucket``: a
+    table stored by its key in 2^k-row shards spans ``ngb / n`` groups
+    give or take the data's draw, an edge of every power of two; the
+    half step keeps that draw off the steps' own edges too.  Sixteen
+    shapes a bucket bound the recompiles as buckets do."""
+    step = max(ngb // 16, 1)
+    widest = int((g_hi - g_lo).max()) + 1
+    return min(-(-(widest + step // 2) // step) * step, ngb)
 
 
 def _group_index(rep, sids: tuple, key_cols: List[tuple]) -> GroupIndex:
@@ -1188,8 +1249,18 @@ class _AggIndexNode:
       ``devcodes``); otherwise each lane the leaf reads goes up permuted
       on the host, once a replica version, under its key + ``("by",
       sids)``.  Under a mesh the shards are contiguous row ranges, each
-      in its own key order (``GroupIndex.shards``).  Index lanes:
-      ``gi_ends`` (``gi_shard_ends`` a shard), ``gi_sgid``
+      in its own key order (``GroupIndex.shards``), and a shard bounds
+      only the span of groups its rows hold (``GroupIndex.spans``; a
+      quarter of them on four chips over a clustered index): a [qb]
+      piece a shard, ``qb`` the widest span padded by ``_span_pad``,
+      gathered and added into place at each shard's first group
+      (``dist.mesh_sum_spans``; counter ``agg_span_cut``).  Where the
+      spans are whole (``qb == ngb``: an unclustered index whose shards
+      each hold rows of every key) every shard bounds every group and
+      the [ngb] tables are summed (``dist.mesh_sum``); min/max merge
+      whole tables either way.  Index lanes: ``gi_ends``
+      (``gi_shard_ends`` a shard: [ngb] or [qb], with ``gi_span_lo``,
+      the shards' first groups, beside the latter), ``gi_sgid``
       (``gi_shard_sgid``) when a min/max reads it."""
 
     def __init__(self, leaf: _ReplicaLeaf, plan, key_cols, specs, slots,
@@ -1274,11 +1345,13 @@ class _AggIndexNode:
         kernels.stats_add("agg_dense" if dense else "agg_sorted", 1)
         need_mm = any(self.specs[k][0] in ("min", "max") for k in need)
         # under a mesh whose shards hold the leaf's rows, each shard
-        # reduces its own rows to a partial [ngb] state and the states
-        # merge over the mesh (dist.mesh_sum / mesh_min / mesh_max): the
-        # output is whole on every device, as a parent join's build side
-        # wants it.  Both formulations shard the same way; the sorted
-        # one reads the index cut per shard (GroupIndex.shards)
+        # reduces its own rows to a partial state and the states merge
+        # over the mesh (dist.mesh_sum / mesh_sum_spans / mesh_min /
+        # mesh_max): the output is whole on every device, as a parent
+        # join's build side wants it.  Both formulations shard the same
+        # way; the sorted one reads the index cut per shard
+        # (GroupIndex.shards), to the shard's own span of groups where
+        # that is less than all of them
         from ..parallel import dist
         mesh = self.leaf.rows_mesh(nb)
         n_mesh = dist.mesh_shards(mesh)
@@ -1311,6 +1384,18 @@ class _AggIndexNode:
             kernels.stats_add("agg_clustered", 1)
         elif not dense:
             order = (("by", sids), index_order)
+        # what a shard of the sorted formulation bounds: its own span
+        # of the groups, [qb] of them from g_lo[shard] on, or (one
+        # device; spans that are whole) all [ngb].  From what the index
+        # observed, and part of the program key
+        g_lo, qb = None, ngb
+        if mesh is not None and not dense:
+            g_lo, g_hi = rep.memo(("gi_spans", sids, n_mesh, per),
+                                  lambda: gidx.spans(n_mesh, per))
+            qb = _span_pad(g_lo, g_hi, ngb)
+        span = qb < ngb
+        if span:
+            kernels.stats_add("agg_span_cut", 1)
         tv = self.leaf.prepare(pb, order=order)
         if tv is None:
             return None
@@ -1338,19 +1423,28 @@ class _AggIndexNode:
                     lidx))
         else:
             def ends_lane():
+                if span:
+                    return gidx.span_ends(
+                        n_mesh, per, g_lo, qb,
+                        None if gidx.clustered else cut()[1]).reshape(-1)
                 # groups past ng repeat the last boundary: empty ranges
                 ends = gidx.shard_ends(n_mesh, per)
                 out = np.empty((n_mesh, ngb), dtype=np.int64)
                 out[:, :ng] = ends
                 out[:, ng:] = ends[:, -1:] if ng else -1
                 return out.reshape(-1)
-            lanes = [
-                pb.lane(rep, ("gi_shard_ends", sids, ngb), ends_lane, lrows)]
+            lanes = [pb.lane(rep, ("gi_shard_ends", sids, qb), ends_lane,
+                             lrows)]
             if need_mm:
                 lanes.append(pb.lane(
                     rep, ("gi_shard_sgid", sids, nb),
                     lambda: np.where(cut()[2] >= ng, ngb,
                                      cut()[2]).reshape(-1), lrows))
+            if span:
+                # where each shard's piece begins in the merged table:
+                # whole on every device, a replica lane and no parameter
+                lanes.append(pb.lane(rep, ("gi_span_lo", sids, qb),
+                                     lambda: g_lo.astype(np.int32), lwhole))
         gb_slots = []
         for j, (gk, gn) in enumerate(gidx.keycols):
             gb_slots.append((
@@ -1385,7 +1479,8 @@ class _AggIndexNode:
                      for s, (gk, _) in zip(sids, gidx.keycols))
         head = "aggdense" if dense else "aggindex"
         pb.key((head, tuple(keys), kdts, tuple(self.slots),
-                tuple(self.out_map), nb, ngb) + dist.layout_tag(lrows),
+                tuple(self.out_map), nb, ngb) + dist.layout_tag(lrows)
+               + ((("span", qb),) if span else ()),
                live, len(self.out_map))
         spec_kinds = [k for k, _ in self.specs]
         slots = self.slots
@@ -1416,18 +1511,26 @@ class _AggIndexNode:
             # is gathered to sorted order here
             ends = idx[0]
             isg = idx[1] if need_mm else None
+            # a shard that bounds its own span alone holds a [qb] piece
+            # of the table, from group idx[-1][shard] on
+            merge = functools.partial(
+                dist.mesh_sum_spans, starts=idx[-1], size=ngb) \
+                if span else merge_sum
 
             def seg(x_s):
                 # a group's lower boundary is the group before's upper
-                # one, so ONE [ngb] gather serves both (a 2 M-lane
-                # gather is 50 ms on a v5e); a shard may hold no row up
-                # to a group (boundary -1, never on one device); padded
-                # groups repeat the last boundary and read zero
+                # one, so ONE gather serves both (a 2 M-lane gather is
+                # 50 ms on a v5e: it costs its indices), over [ngb]
+                # boundaries or the [qb] of the shard's span, before
+                # whose first group the shard holds no row; a shard may
+                # hold no row up to a group (boundary -1, never on one
+                # device); padded groups and entries past the span
+                # repeat the last boundary and read zero
                 c = kernels.prefix_sum(x_s)
                 zero = jn.zeros((), dtype=x_s.dtype)
                 hi = jn.where(ends >= 0, c[jn.maximum(ends, 0)], zero)
                 lo = jn.concatenate([zero[None], hi[:-1]])
-                return merge_sum(hi - lo)
+                return merge(hi - lo)
 
             def seg_mm(av_s, live_s, kind):
                 gl = jn.where(live_s, isg, ngb)
@@ -1448,11 +1551,15 @@ class _AggIndexNode:
                                 never_null=never_null, **red)
             return red["presence"], [res[k] for k in need]
         if mesh is not None:
-            # merged states are whole by construction (psum; min/max
-            # gather and reduce, beyond the static checker)
+            # merged states are whole by construction (psum; the spans'
+            # pieces, and min/max, are gathered and put together alike
+            # on every shard, beyond the static checker)
+            lane_specs = [ROWS] * len(lanes)
+            if span:
+                lane_specs[-1] = WHOLE
             reduce = dist.shard_map_unchecked(
                 reduce, mesh=mesh,
-                in_specs=([ROWS] * len(lanes), ROWS,
+                in_specs=(lane_specs, ROWS,
                           [(ROWS, ROWS)] * len(tv.meta), (WHOLE, WHOLE)),
                 out_specs=(WHOLE, [(WHOLE, WHOLE)] * len(need)))
 

@@ -58,6 +58,11 @@ _DEVICE_METRICS = {
                       "Sorted fused GROUP BYs that found the table "
                       "stored in the key's order and shared the scan's "
                       "row-order lanes (no permuted copy)"),
+    "agg_span_cut": ("tinysql_agg_span_cut_total",
+                     "Sorted fused GROUP BYs under a mesh whose shards "
+                     "each bounded only the span of groups their rows "
+                     "hold (the pieces gathered and added into place, "
+                     "no sum of whole tables)"),
     "pipe_dead_cols": ("tinysql_pipe_dead_cols_total",
                        "Columns of fused programs' root views that no "
                        "consumer reads and the programs therefore did "

@@ -97,6 +97,26 @@ def mesh_gather(x, axis: str = "shard", tiled: bool = False):
         return jax.lax.all_gather(x, axis, tiled=tiled)
 
 
+def mesh_sum_spans(part, starts, size: int, axis: str = "shard"):
+    """Inside shard_map: :func:`mesh_sum` of [size] tables of which
+    shard s holds only the run ``part`` [q] that begins at
+    ``starts[s]`` (whole on every shard), zero elsewhere.  The runs
+    are gathered — n x q elements cross the interconnect, not n x size —
+    and added into place one after another: contiguous read-add-writes,
+    no scatter; the add carries an entry that two runs share.  A run
+    may reach past ``size`` (its tail then adds into padding that is
+    cut off), never begin past it."""
+    from jax import lax
+    parts = mesh_gather(part, axis)
+    n, q = parts.shape
+    out = kernels.jnp().zeros(size + q, dtype=part.dtype)
+    for s_ in range(n):
+        at = (starts[s_],)
+        out = lax.dynamic_update_slice(
+            out, lax.dynamic_slice(out, at, (q,)) + parts[s_], at)
+    return out[:size]
+
+
 def mesh_min(x, axis: str = "shard"):
     """Inside shard_map: elementwise min over the mesh axis.  The TPU
     lowers an all-reduce of an emulated 64-bit type for Sum only

@@ -218,8 +218,13 @@ def _device_info(st) -> str:
         # its key's order (they share the scan's lanes)
         clustered = f"/{int(d['agg_clustered'])}clustered" \
             if d.get("agg_clustered") else ""
+        # span_cut: sorted ones under a mesh whose shards each bounded
+        # their own span of groups alone
+        span_cut = f"/{int(d['agg_span_cut'])}span_cut" \
+            if d.get("agg_span_cut") else ""
         parts.append(f"agg:{int(d.get('agg_dense', 0))}dense"
-                     f"/{int(d.get('agg_sorted', 0))}sorted{clustered}")
+                     f"/{int(d.get('agg_sorted', 0))}sorted{clustered}"
+                     f"{span_cut}")
     if d.get("pipe_dead_cols"):
         # columns of the fused program's root no consumer reads: not
         # computed, packed or downloaded
