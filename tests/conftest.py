@@ -148,3 +148,16 @@ def _no_thread_leak_per_module():
         extra = [t for t in live() if t not in base]
     assert not extra, \
         f"module leaked non-daemon threads: {sorted(t.name for t in extra)}"
+
+
+@_pytest.fixture
+def four_devices(monkeypatch):
+    """A host of four devices, as the four-chip cells' is: the session's
+    mesh, the planner's price of a broadcast (four copies) and the count
+    of whole-mesh dispatches all see the first four of the eight host
+    devices the tests force."""
+    from tinysql_tpu.parallel import dist
+    found = jax.devices
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a, **kw: found(*a, **kw)[:4])
+    monkeypatch.setattr(dist, "_SESSION_MESH", None)

@@ -559,16 +559,99 @@ def test_join_chains_gather_no_constant_null_lane(
     assert _scatters(text, "keygroup") == scatters
 
 
+@pytest.mark.parametrize("flags", ["off", "on"])
+@pytest.mark.parametrize("name", ["Q5", "Q10", "Q18"])
+def test_join_chains_lower_to_the_parents_text_on_one_chip(
+        one_chip, chip_branches, monkeypatch, tpch_session, name, flags):
+    """PR 39 taught the join's view build and the keyed GROUP BY the
+    mesh; on one device the three programs lower to the text pinned at
+    f6b51c3 (PR 38), byte for byte, with the NULL-freedom flags and
+    without: the one-chip joins cell runs the programs it ran."""
+    if flags == "off":
+        _no_flags(monkeypatch)
+    fn, abstract = _capture(monkeypatch, tpch_session, tpch.WORKLOAD[name],
+                            one_chip)
+    text = fn.lower(*abstract).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _pinned("lowered_at_pr38_joins.json")[f"{name}.one.{flags}"]
+
+
+# ---- the three join chains over the four-chip mesh -------------------------
+
+def _all_gathers(text, scope):
+    """The lane lengths of the compiled text's all-gathers traced under
+    a node of ``scope`` and the ``mesh.all_gather`` scope."""
+    out = []
+    for line in text.splitlines():
+        head, found, _ = line.partition(" all-gather(")
+        name = re.search(r'op_name="([^"]*)"', line)
+        if found and name and "mesh.all_gather" in name.group(1) \
+                and scope in name.group(1).split("/"):
+            out += [int(n) for n in re.findall(r"\[(\d+)\]",
+                                               head.partition(" = ")[2])]
+    return out
+
+
+@pytest.mark.parametrize("name, expect", [
+    # (orders join customer) is computed a quarter of orders' bucket a
+    # chip and brought whole under lineitem's join: its validity and
+    # c_nationkey's two 32-bit halves (no null lane: the view proves it
+    # free of NULLs); the 25 nations' masked reductions a chip, merged
+    # by all-reduce
+    ("Q5", {"view_lanes": 3, "keygroup_reduce": True}),
+    # the view builds are aggregates, whole as they merge; customer's
+    # 2^13 slots (7,500 keys and the NULL's) scatter-added a chip
+    ("Q10", {"view_lanes": 0, "keygroup_reduce": True}),
+    # aggregates below the joins: nothing keyed above them
+    ("Q18", {"view_lanes": 0, "keygroup_reduce": False}),
+])
+def test_fused_join_chain_mesh_program(topo, chip_branches, monkeypatch,
+                                       four_devices, tpch_session, name,
+                                       expect):
+    """Under ``tidb_mesh_parallel`` each of the three is ONE program
+    over the described v5e:2x2 (the capture raises at the first
+    dispatch: a statement that left the fused pipeline would show a
+    join's or an aggregate's kernel, or a sort-group node, here; the
+    planner prices four copies of a broadcast: ``four_devices``),
+    compiles inside the budget and sorts no lane of a chip's share of
+    the scan's or the orders' bucket: no join partitions, the GROUP BY
+    above a chain is keyed and reduced a chip at a time, the TopN heads
+    are selected."""
+    import time
+    from tinysql_tpu.executor import devpipe
+    t0 = time.time()
+    progcache.clear()
+    monkeypatch.setattr(devpipe, "COMPILED_NODE_KEYS", set())
+    text = _compile_mesh_statement(topo, monkeypatch, tpch_session,
+                                   tpch.WORKLOAD[name])
+    assert time.time() - t0 < JOIN_COMPILE_BUDGET_S
+    kinds = {k[0] for k in devpipe.COMPILED_NODE_KEYS}
+    assert "join" in kinds and kinds & {"order", "order_mesh"}, kinds
+    assert not kinds & {"sortgroup", "joinshuf"}, kinds
+    assert all(n < ORDERS_BUCKET // 4 for n in _sorted_lanes(text)), \
+        sorted(set(_sorted_lanes(text)))
+    assert "all-to-all" not in text
+    lanes = _all_gathers(text, "join")
+    assert len([n for n in lanes if n == ORDERS_BUCKET]) \
+        == expect["view_lanes"], lanes
+    reduced = any("keygroup" in line and "mesh.psum" in line
+                  for line in text.splitlines() if " all-reduce(" in line)
+    assert reduced == expect["keygroup_reduce"]
+
+
 # ---- the same at the SF=10 shapes ------------------------------------------
 
 @pytest.fixture(scope="module")
 def sf10_session():
-    """The columns Q1/Q3/Q6 read at TPC-H SF=10 (60 M ``lineitem`` rows:
-    about 7 GB of host arrays and 12 GB at the peak, minutes of host
-    preparation), for the SF=10 shapes of ``tpch_sf10_mesh4``."""
+    """The columns Q1/Q3/Q6 and Q5/Q10/Q18 read at TPC-H SF=10 (60 M
+    ``lineitem`` rows: about 8 GB of host arrays and 13 GB at the peak,
+    minutes of host preparation), for the SF=10 shapes of
+    ``tpch_sf10_mesh4`` and ``tpch_sf10_joins_mesh4``."""
     sf = 10.0
     r = np.random.default_rng(7)
     n_cust, n_ord = int(150_000 * sf), int(1_500_000 * sf)
+    n_supp = int(10_000 * sf)
+    texts = np.array([f"text {i:05d}" for i in range(16384)])
     per = r.integers(1, 8, n_ord)
     n_li = int(per.sum())
     o_days = r.integers(0, 2405, n_ord)
@@ -577,24 +660,51 @@ def sf10_session():
             .astype("timedelta64[D]")).astype("<U10")
     okey = np.arange(1, n_ord + 1, dtype=np.int64)
     tables = {
-        "customer": ("c_custkey bigint primary key, c_mktsegment char(10)", {
-            "c_custkey": np.arange(1, n_cust + 1, dtype=np.int64),
-            "c_mktsegment": np.array(["AUTOMOBILE", "BUILDING", "FURNITURE",
-                                      "MACHINERY", "HOUSEHOLD"])[
-                r.integers(0, 5, n_cust)]}),
+        "region": ("r_regionkey bigint primary key, r_name char(25)", {
+            "r_regionkey": np.arange(5, dtype=np.int64),
+            "r_name": np.array(tpch._REGIONS)}),
+        "nation": ("n_nationkey bigint primary key, n_name char(25), "
+                   "n_regionkey bigint", {
+                       "n_nationkey": np.arange(25, dtype=np.int64),
+                       "n_name": np.array([n for n, _ in tpch._NATIONS]),
+                       "n_regionkey": np.array(
+                           [g for _, g in tpch._NATIONS], dtype=np.int64)}),
+        "supplier": ("s_suppkey bigint primary key, s_nationkey bigint", {
+            "s_suppkey": np.arange(1, n_supp + 1, dtype=np.int64),
+            "s_nationkey": r.integers(0, 25, n_supp)}),
+        "customer": (
+            "c_custkey bigint primary key, c_name varchar(25), "
+            "c_address varchar(40), c_nationkey bigint, c_phone char(15), "
+            "c_acctbal double, c_mktsegment char(10), "
+            "c_comment varchar(117)", {
+                "c_custkey": np.arange(1, n_cust + 1, dtype=np.int64),
+                "c_name": texts[r.integers(0, len(texts), n_cust)],
+                "c_address": texts[r.integers(0, len(texts), n_cust)],
+                "c_nationkey": r.integers(0, 25, n_cust),
+                "c_phone": texts[r.integers(0, len(texts), n_cust)],
+                "c_acctbal": np.round(r.uniform(-999.99, 9999.99, n_cust), 2),
+                "c_mktsegment": np.array(
+                    ["AUTOMOBILE", "BUILDING", "FURNITURE", "MACHINERY",
+                     "HOUSEHOLD"])[r.integers(0, 5, n_cust)],
+                "c_comment": texts[r.integers(0, len(texts), n_cust)]}),
         "orders": ("o_orderkey bigint primary key, o_custkey bigint, "
-                   "o_orderdate varchar(10), o_shippriority int", {
+                   "o_totalprice double, o_orderdate varchar(10), "
+                   "o_shippriority int", {
                        "o_orderkey": okey,
                        "o_custkey": r.integers(1, n_cust + 1, n_ord),
+                       "o_totalprice": np.round(
+                           r.uniform(900.0, 500000.0, n_ord), 2),
                        "o_orderdate": days[o_days],
                        "o_shippriority": np.zeros(n_ord, dtype=np.int64)}),
         "lineitem": (
-            "l_id bigint primary key, l_orderkey bigint, l_quantity double, "
+            "l_id bigint primary key, l_orderkey bigint, l_suppkey bigint, "
+            "l_quantity double, "
             "l_extendedprice double, l_discount double, l_tax double, "
             "l_returnflag char(1), l_linestatus char(1), "
             "l_shipdate varchar(10)", {
                 "l_id": np.arange(1, n_li + 1, dtype=np.int64),
                 "l_orderkey": np.repeat(okey, per),
+                "l_suppkey": r.integers(1, n_supp + 1, n_li),
                 "l_quantity": r.integers(1, 51, n_li).astype(np.float64),
                 "l_extendedprice": np.round(
                     r.uniform(900.0, 105000.0, n_li), 2),
@@ -642,3 +752,32 @@ def test_fused_mesh_program_at_the_sf10_shapes(topo, chip_branches,
         assert _gathers(text, groups // 4) == {"join/join": 2, "join": 6}
         assert len(_collectives(text, "all-gather", 4 * pad)) == 4
         assert not _collectives(text, "all-reduce", groups)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name", ["Q5", "Q10", "Q18"])
+def test_fused_join_chain_mesh_program_at_the_sf10_shapes(
+        topo, chip_branches, monkeypatch, four_devices, sf10_session, name):
+    """The three programs of ``tpch_sf10_joins_mesh4.join_stream``
+    (``lineitem``'s lanes at 2^26, ``orders``' and the order keys'
+    groups at 2^24, ``customer``'s at 2^21): ONE program each over the
+    v5e:2x2, no join partitioned (the planner prices a reader's side by
+    its scan's rows), no GROUP BY sorted, no sort of a chip's share of
+    a bucket, and it fits a chip beside what the other two leave."""
+    from tinysql_tpu.executor import devpipe
+    from tinysql_tpu.parallel import dist
+    monkeypatch.setattr(dist, "broadcast_budget_bytes",
+                        lambda: HBM_BYTES * dist.BROADCAST_MEMORY_SHARE)
+    progcache.clear()
+    monkeypatch.setattr(devpipe, "COMPILED_NODE_KEYS", set())
+    text = _compile_mesh_statement(topo, monkeypatch, sf10_session,
+                                   tpch.WORKLOAD[name])
+    kinds = {k[0] for k in devpipe.COMPILED_NODE_KEYS}
+    assert not kinds & {"sortgroup", "joinshuf"}, kinds
+    assert "all-to-all" not in text
+    assert all(n < (1 << 22) for n in _sorted_lanes(text)), \
+        sorted(set(_sorted_lanes(text)))
+    if name == "Q5":
+        # the view's validity and c_nationkey's halves, at orders' bucket
+        assert len([n for n in _all_gathers(text, "join")
+                    if n == 1 << 24]) == 3

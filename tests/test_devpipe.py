@@ -975,7 +975,10 @@ def test_pipe_counters_on_metrics_and_in_the_benchmark(tpch_tk, key, grown):
         == [(f"{key}_per_query.stream", ["tpch_sf1.power_stream"]),
             (f"{key}_per_query.mesh", ["tpch_sf1_mesh4.power_stream"]),
             (f"{key}_per_query.mesh10", ["tpch_sf10_mesh4.power_stream"]),
-            (f"{key}_per_query.joins", ["tpch_sf1_joins.join_stream"])]
+            # the four-chip joins cell reports under the joins' names
+            # (PR 39: appended to every ``.joins`` list)
+            (f"{key}_per_query.joins", ["tpch_sf1_joins.join_stream",
+                                        "tpch_sf10_joins_mesh4.join_stream"])]
     for m in mine:
         assert (m["unit"], m["better"], m["source"], m["layer"],
                 m["moves"]) == ("count", "higher", "program_counter",
@@ -1096,7 +1099,7 @@ def test_sort_group_and_selection_hand_the_flags_on(tk, flags, monkeypatch):
     a row, and a filter only drops rows."""
     _live_tables(tk)
     monkeypatch.setattr(devpipe._KeyGroupNode, "cut_of",
-                        staticmethod(lambda child, key_cols, ctx: None))
+                        staticmethod(lambda child, key_cols: None))
     sql = ("select dm.v, count(*), sum(f.b), sum(f.c) from f join dm "
            "on f.fk = dm.k group by dm.v having sum(f.b) > 0")
     got, want, delta = _dead_cols(tk, sql)

@@ -12,7 +12,11 @@
 - the fused pipeline's part: a join's view as a build side (NULL keys, an
   empty build side), a GROUP BY cut to the key that determines the rest,
   and one that must NOT be cut;
-- under a forced four-device mesh the three still answer right.
+- under a forced four-device mesh each of the three is still ONE fused
+  program (ISSUE 39): a join's view as a build side (computed a row range
+  a device, its live lanes all-gathered), the keyed GROUP BY reduced a
+  shard at a time and merged, the same answers; a view over the byte
+  budget leaves the fused pipeline.
 """
 import importlib.util
 import math
@@ -315,20 +319,226 @@ def test_group_by_one_column_above_the_chain(tk, key, dense):
 
 # ---- the mesh ---------------------------------------------------------------
 
-@pytest.mark.parametrize("kind", KINDS)
-def test_answers_right_under_a_forced_mesh(joins, kind, monkeypatch):
-    """``tidb_mesh_parallel`` with four host devices: the new shapes keep
-    the formulations the mesh had (no view build, no keyed GROUP BY) and
-    the answers are the reference's."""
-    s, want = joins
+@pytest.fixture
+def four_chips(four_devices, monkeypatch):
+    """``conftest.four_devices`` with the planner's rows-per-shard floor
+    lowered to the small tables."""
     monkeypatch.setattr(dist, "MIN_SHARD_ROWS", 16)
-    monkeypatch.setattr(
-        dist, "session_mesh",
-        lambda sv: dist.sized_mesh(4) if sv.get("tidb_mesh_parallel")
-        else None)
-    sql, ref = want[kind]
+
+
+def _mesh_counted(s, sql):
+    """(rows, counters) of ``sql`` under ``tidb_mesh_parallel``, warm."""
     s.execute("set @@tidb_mesh_parallel = 1")
     try:
-        _same(s.query(sql).rows, [list(r) for r in ref])
+        s.query(sql)
+        return _counted(s, sql)
     finally:
         s.execute("set @@tidb_mesh_parallel = 0")
+
+
+@pytest.mark.parametrize("kind, key_mesh", [("q5", 1), ("q10", 1),
+                                            ("q18", 0)])
+def test_answers_right_under_a_forced_mesh(joins, kind, key_mesh,
+                                           four_chips):
+    """``tidb_mesh_parallel`` with four host devices: each of the three
+    is ONE fused program over the whole mesh, no host twin, with the
+    joins, view builds and key cut of the one-device program (the views
+    traced under the mesh, the keyed GROUP BY above a chain reduced a
+    shard at a time), nothing downloaded but the final rows, and the
+    answers are the reference's."""
+    s, want = joins
+    sql, ref = want[kind]
+    s.query(sql)
+    _rows, one = _counted(s, sql)
+    rows, d = _mesh_counted(s, sql)
+    _same(rows, [list(r) for r in ref])
+    assert d["dispatches"] == 1 == d["mesh_dispatches"]
+    assert d.get("host_dispatches", 0) == 0
+    assert d.get("progcache_misses", 0) == 0
+    assert d["d2h_transfers"] == 1 and d["d2h_bytes"] < 64 << 10
+    assert d.get("h2d_bytes", 0) == 0
+    for k in ("pipe_joins", "pipe_view_builds", "agg_key_cut", "agg_dense",
+              "agg_sorted"):
+        assert d.get(k, 0) == one.get(k, 0), (k, d, one)
+    assert d["pipe_mesh_views"] == d["pipe_view_builds"] > 0
+    assert d.get("agg_key_mesh", 0) == key_mesh
+    assert one.get("pipe_mesh_views", 0) == one.get("agg_key_mesh", 0) == 0
+    # only Q5 builds on a join's view: its lanes cross the mesh whole
+    assert (d.get("reshard_bytes", 0) > 0) == (kind == "q5")
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_mesh_answer_is_the_one_devices(joins, kind, four_chips):
+    """Q10's sums are differences of prefix sums below the joins: a
+    shard's run over its own rows, so they round otherwise than one
+    device's (both within 1e-9 of the reference)."""
+    s, want = joins
+    sql = want[kind][0]
+    one = s.query(sql).rows
+    rows, _d = _mesh_counted(s, sql)
+    _same(rows, one, rel=1e-8 if kind == "q10" else 1e-12)
+
+
+@pytest.mark.parametrize("kind", ["q5", "q10"])
+def test_no_join_of_two_tables_partitions_at_four_copies(joins, kind,
+                                                         four_chips):
+    """``orders`` joins ``customer``: the date filter is a validity mask
+    over orders' lanes, so an exchange would move every order, and the
+    planner prices that side by its scan's rows (priced by the estimate
+    after the filter the exchange looked cheaper than four copies of
+    ``customer``)."""
+    s, want = joins
+    s.execute("set @@tidb_mesh_parallel = 1")
+    try:
+        plan = _plan(s, want[kind][0])
+    finally:
+        s.execute("set @@tidb_mesh_parallel = 0")
+    joined = [info for op, info in plan if op.startswith("HashJoin")
+              and "mesh:" in info]
+    assert joined and all("mesh:broadcast" in info for info in joined), plan
+
+
+def test_the_sf10_dataset_refuses_a_program_without_the_counters(
+        monkeypatch):
+    """``tpch_joins_blocks.generate`` asks before any data is made."""
+    data = _bench_file("datasets", "tpch_joins_blocks.py")
+    assert set(data.REFERENCES) >= {"q5", "q10", "q18"}
+    assert set(data.MESH_COUNTERS) <= set(kernels.STATS)
+    monkeypatch.setattr(kernels, "STATS", {
+        k: v for k, v in kernels.STATS.items()
+        if k not in data.MESH_COUNTERS})
+    made = []
+    monkeypatch.setattr(data.blocks, "generate",
+                        lambda sf, seed: made.append(sf))
+    with pytest.raises(RuntimeError, match="pipe_mesh_views"):
+        data.generate(10.0, 1)
+    assert not made
+
+
+# ---- the mesh on small tables: view builds and the keyed GROUP BY ------------
+
+def _mesh_and_plain(s, sql):
+    """(fused rows under the mesh, the host tier's rows, counters)."""
+    fused, d = _mesh_counted(s, sql)
+    s.execute("set @@tidb_devpipe = 0")
+    s.execute("set @@tidb_use_tpu = 0")
+    try:
+        plain = s.query(sql).rows
+    finally:
+        s.execute("set @@tidb_use_tpu = 1")
+        s.execute("set @@tidb_devpipe = 1")
+    return fused, plain, d
+
+
+@pytest.mark.parametrize("where, some", [
+    ("", True), (" and dim.v >= 5", True), (" and dim.v > 1000", False),
+    (" and mid.flag = 7", False)])
+def test_a_joins_view_as_a_build_side_under_the_mesh(tk, four_chips, where,
+                                                     some):
+    """``fact`` probes ``(mid join dim)`` a shard at a time: the view is
+    computed a row range a device and its validity and ``dim.v`` cross
+    the mesh whole (``reshard_bytes``: mid's 512-row bucket, a byte and
+    a value and a null lane: ``dim.v`` may be NULL for a row the inner
+    join dropped, never for a valid one)."""
+    sql = f"select count(*), sum(fact.x), min(dim.v) {CHAIN}{where}"
+    fused, plain, d = _mesh_and_plain(tk, sql)
+    _same(fused, plain)
+    assert (fused[0][0] > 0) == some
+    assert d["dispatches"] == 1 == d["mesh_dispatches"]
+    assert d["pipe_joins"] == 2 and d["pipe_view_builds"] == 1
+    assert d["pipe_mesh_views"] == 1 and d["reshard_bytes"] == 512 * 9
+
+
+@pytest.mark.parametrize("sql, joins_, views", [
+    # a semi join whose build side is a join's view
+    ("select count(*), sum(fact.x) from fact where fact.mid_id in "
+     "(select mid.id from mid, dim where mid.dim_id = dim.id "
+     "and dim.v >= 5)", 2, 1),
+    # a left join onto a join's view: unmatched rows stay, NULL there
+    ("select count(*), count(dim.v), sum(fact.x), max(dim.v) from fact "
+     "left join (mid join dim on mid.dim_id = dim.id) "
+     "on fact.mid_id = mid.id", 2, 1),
+])
+def test_semi_and_left_joins_build_on_a_view_under_the_mesh(
+        tk, four_chips, sql, joins_, views):
+    fused, plain, d = _mesh_and_plain(tk, sql)
+    _same(fused, plain)
+    assert fused[0][0] > 0
+    assert d["dispatches"] == 1 == d["mesh_dispatches"]
+    assert d["pipe_joins"] == joins_ and d["pipe_mesh_views"] == views
+    assert d["reshard_bytes"] > 0
+
+
+def test_a_view_over_the_byte_budget_leaves_the_fused_pipeline(
+        tk, four_chips, monkeypatch):
+    """What every device would hold whole of a view is priced as a
+    leaf's copies are: over the budget the statement answers right on
+    the per-operator tier and counts no view build under the mesh.  (The
+    budget here lies between ``dim``'s three columns at its 64-row
+    bucket, 1,152 bytes more a device, and the view's one at mid's
+    512-row bucket, 3,072.)"""
+    sql = f"select count(*), sum(fact.x), min(dim.v) {CHAIN}"
+    monkeypatch.setattr(dist, "broadcast_budget_bytes", lambda: 2000.0)
+    fused, plain, d = _mesh_and_plain(tk, sql)
+    _same(fused, plain)
+    assert fused[0][0] > 0
+    assert d.get("pipe_mesh_views", 0) == 0 and d["dispatches"] > 1
+
+
+@pytest.mark.parametrize("key, dense", [("dim.name", 1), ("mid.dim_id", 1),
+                                        ("mid.id", 0)])
+def test_keyed_group_by_reduces_a_shard_at_a_time(tk, four_chips, key,
+                                                  dense):
+    """The keyed GROUP BY's two sides under the mesh (masked reductions
+    up to 64 groups, scatter-adds beyond: mid's 500 keys), sum, count,
+    min and max arguments merged over the shards, the NULL key's group
+    among them."""
+    sql = (f"select {key}, sum(fact.x), count(*), count(mid.flag), "
+           f"max(fact.x), min(fact.x) from fact "
+           f"left join mid on fact.mid_id = mid.id "
+           f"left join dim on mid.dim_id = dim.id "
+           f"group by {key} order by {key}")
+    fused, plain, d = _mesh_and_plain(tk, sql)
+    _same(fused, plain)
+    assert fused[0][0] is None and len(fused) > 3
+    assert d["dispatches"] == 1 == d["mesh_dispatches"]
+    assert d["agg_key_mesh"] == 1 and d.get("agg_dense", 0) == dense
+
+
+@pytest.mark.parametrize("key", ["mid.dim_id", "mid.id"])
+def test_a_shard_with_no_row_gives_the_identity(tk, four_chips, key):
+    """Every valid row of ``fact`` lies in the first shard's range (ids
+    up to 900 of a 4,096-row bucket, 1,024 a shard): three shards reduce
+    nothing and the merged sums, counts, minima and maxima are the first
+    shard's."""
+    # an argument over both ends of the chain keeps the aggregate
+    # above its joins
+    sql = (f"select {key}, sum(fact.x * dim.v), count(*), max(fact.x), "
+           f"min(fact.x) {CHAIN} and fact.id <= 900 "
+           f"group by {key} order by {key}")
+    fused, plain, d = _mesh_and_plain(tk, sql)
+    _same(fused, plain)
+    assert len(fused) > 3 and d["agg_key_mesh"] == 1
+
+
+def test_the_shards_parts_add_up_to_the_one_device_table(tk, four_chips):
+    """The one-device program over each shard's row range (a quarter of
+    fact's 4,096-row bucket) gives that shard's partial table; summed by
+    key the four are the one-device table, and the mesh's."""
+    def sql(where):
+        return (f"select mid.id, sum(fact.x * dim.v), count(*) {CHAIN}"
+                f"{where} group by mid.id order by mid.id")
+    whole = tk.query(sql("")).rows
+    parts = {}
+    for i in range(4):
+        lo, hi = i * 1024, (i + 1) * 1024
+        for k, v, c in tk.query(
+                sql(f" and fact.id > {lo} and fact.id <= {hi}")).rows:
+            got = parts.setdefault(k, [0.0, 0])
+            got[0] += v
+            got[1] += c
+    _same([[k, v, c] for k, (v, c) in sorted(parts.items())], whole,
+          rel=1e-12)
+    mesh, d = _mesh_counted(tk, sql(""))
+    _same(mesh, whole, rel=1e-12)
+    assert d["agg_key_mesh"] == 1 and d["dispatches"] == 1

@@ -1075,7 +1075,11 @@ def test_benchmark_reads_the_window_span(not_tracing):
                    "tpch_sf1_joins.join_stream"),
             ("stream_queries_per_s", "serve_queries_per_s",
              "stream_queries_per_s", "stream_queries_per_s")):
-        assert m["workloads"] == [cell] and cell in cells
+        # (the four-chip joins cell, PR 39, reports under ``.joins``)
+        assert m["workloads"][0] == cell and cell in cells
+        assert m["workloads"][1:] == (
+            ["tpch_sf10_joins_mesh4.join_stream"]
+            if m["name"].endswith(".joins") else [])
         assert m["moves"] == moves and cell in e2e[moves]["workloads"]
         assert (m["unit"], m["better"], m["source"], m["layer"]) == (
             "ms", "lower", "program_span", "samplers")

@@ -351,6 +351,50 @@ def test_mesh_join_strategy_cost_based(join_tk, monkeypatch):
     assert forced == single
 
 
+def test_a_readers_side_is_priced_by_its_scans_rows(join_tk):
+    """A table reader's filters are a validity mask over the replica's
+    lanes: a broadcast copies, and an exchange moves, every row of the
+    table whatever the filters keep, so both sides of the cost compare
+    count a reader (under selections too) by its scan's rows and not by
+    the estimate after its filters.  A side that is no reader keeps its
+    estimate."""
+    from tinysql_tpu.parallel import dist
+    from tinysql_tpu.parser import parse
+    from tinysql_tpu.planner import device
+    from tinysql_tpu.planner.builder import PlanBuilder
+
+    def join_of(sql):
+        s = join_tk
+        s.execute("set @@tidb_mesh_parallel = 1")
+        try:
+            p = s._optimize(PlanBuilder(s).build_select(parse(sql)[0]), True)
+        finally:
+            s._pinned_is = None
+            s.execute("set @@tidb_mesh_parallel = 0")
+        while p.op_name() != "HashJoin":
+            p = p.children[0]
+        return p
+    n = len(jax.devices())
+    w = dist.COST_COLUMN_BYTES
+    plain = join_of("select big.a, dim.v from big join dim "
+                    "on big.fk = dim.k")
+    masked = join_of("select big.a, dim.v from big join dim "
+                     "on big.fk = dim.k where big.x < 1 and dim.v < 3")
+    probe, build = masked.children
+    assert probe.stats_row_count < 4096 and build.stats_row_count < 150
+    assert device._lane_rows(probe) == 4096.0
+    assert device._lane_rows(build) == 150.0
+    assert masked.mesh_cost["broadcast_bytes"] \
+        == plain.mesh_cost["broadcast_bytes"] == 150 * 2 * w * n
+    assert masked.mesh_cost["shuffle_bytes"] \
+        == 150 * 2 * w + 4096 * len(probe.schema.columns) * w
+    # an aggregate's side is no lane of a replica: its estimate stands
+    agg = join_of("select big.a, t.c from big join (select fk, count(*) c "
+                  "from big group by fk) t on big.a = t.fk")
+    side = next(c for c in agg.children if c.op_name() != "TableReader")
+    assert device._lane_rows(side) == side.stats_row_count
+
+
 # ---- the mesh deployment: the replica laid out over the mesh ---------------
 # TPC-H's Q1 / Q3 / Q6 under tidb_mesh_parallel = 1 with the chip's
 # branches on (the fused pipeline forced, no numpy twin): the replica's
@@ -810,6 +854,48 @@ def test_agg_span_cut_counts_and_moves_what_the_sum_of_tables_moved(
         s.execute("set @@tidb_mesh_parallel = 0")
     assert any("agg:0dense/1sorted/1clustered/1span_cut" in str(c)
                for r in info for c in r), info
+
+
+def test_mesh_views_and_key_mesh_on_metrics_and_explain_analyze(
+        tpch_mesh, four_devices):
+    """``pipe_mesh_views`` and ``agg_key_mesh``: once a fused dispatch
+    of TPC-H Q5 over the mesh (its three view builds, of them (orders
+    join customer) all-gathered; the 25 nations reduced a shard at a
+    time), 0 on one device; on ``/metrics`` and in ``EXPLAIN ANALYZE``
+    beside the joins and the key cut."""
+    from tinysql_tpu.bench import tpch
+    from tinysql_tpu.obs import metrics
+    s, mirror, _queries = tpch_mesh
+    sql = tpch.WORKLOAD["Q5"]
+    single, one = _stats_of(s, sql)
+    assert one["pipe_view_builds"] == 3
+    assert one.get("pipe_mesh_views", 0) == one.get("agg_key_mesh", 0) == 0
+    before = metrics.render_prometheus()
+    _mesh_stats_of(s, sql)
+    rows, warm, _ = _mesh_stats_of(s, sql)
+    assert _rows_close(rows, single, rel=1e-12) and rows
+    assert _rows_close(rows, [list(r) for r in
+                              mirror.execute(sql).fetchall()])
+    assert warm["dispatches"] == 1 == warm["mesh_dispatches"]
+    assert warm["pipe_mesh_views"] == 3 and warm["agg_key_mesh"] == 1
+    assert warm["reshard_bytes"] > 0 and warm["progcache_misses"] == 0
+
+    def total(text, name):
+        line = [ln for ln in text.splitlines()
+                if ln.startswith(f"tinysql_{name}_total ")]
+        return float(line[0].split()[1])
+    after = metrics.render_prometheus()
+    assert total(after, "pipe_mesh_views") \
+        == total(before, "pipe_mesh_views") + 6
+    assert total(after, "agg_key_mesh") == total(before, "agg_key_mesh") + 2
+    s.execute("set @@tidb_mesh_parallel = 1")
+    try:
+        info = s.query("explain analyze " + sql).rows
+    finally:
+        s.execute("set @@tidb_mesh_parallel = 0")
+    shown = [str(c) for r in info for c in r]
+    assert any("joins:5/3view/3mesh" in c and "key_mesh:1" in c
+               for c in shown), info
 
 
 # ---- column liveness under the mesh -----------------------------------------

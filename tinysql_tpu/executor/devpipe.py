@@ -1193,6 +1193,18 @@ def _spec_fns(specs, pt: ParamTable, never_null=frozenset()):
     return arg_fns, keys
 
 
+def _mesh_merges(mesh):
+    """(sum, {"min": .., "max": ..}): how per-shard partial tables merge
+    over ``mesh`` inside a shard_map (dist.mesh_sum / mesh_min /
+    mesh_max); the identity on one device."""
+    if mesh is None:
+        def same(x):
+            return x
+        return same, {"min": same, "max": same}
+    from ..parallel import dist
+    return dist.mesh_sum, {"min": dist.mesh_min, "max": dist.mesh_max}
+
+
 def _slot_outputs(jn, res, slots):
     """Descriptor outputs from spec results: direct, or the avg quotient
     (NULL when the count is zero); dead where its specs are."""
@@ -1486,13 +1498,7 @@ class _AggIndexNode:
         slots = self.slots
         out_map = self.out_map
         schema_cols = self.plan.schema.columns
-        if mesh is None:
-            def merge_sum(x):
-                return x
-            merge_mm = {"min": merge_sum, "max": merge_sum}
-        else:
-            merge_sum = dist.mesh_sum
-            merge_mm = {"min": dist.mesh_min, "max": dist.mesh_max}
+        merge_sum, merge_mm = _mesh_merges(mesh)
 
         def dense_reducers(idx, valid):
             seg = kernels._SegReduce(kernels.jax(), jn, idx[0], valid,
@@ -1651,6 +1657,10 @@ class _JoinNode:
         self.mult = mult
         self.session_vars = session_vars or {}
         self.n_mesh = int(mesh.devices.size) if mesh is not None else 0
+        #: set by a prepare that re-partitioned both sides by key hash:
+        #: the output then lies by key and is no view a parent can build
+        #: on (:func:`_probe_shaped`)
+        self.partitioned = False
 
     @staticmethod
     def compile(plan: PhysicalHashJoin, ctx: _Ctx):
@@ -1756,6 +1766,7 @@ class _JoinNode:
         build for its live columns (and, where the join may partition,
         its key): a build column nobody reads is not gathered."""
         self._place_build()
+        self.partitioned = False
         kernels.stats_add("pipe_joins", 1)
         if _leafish(self.build) is not self.build:
             # the build side is a view: a selection's, a join's, an
@@ -1830,6 +1841,61 @@ class _JoinNode:
         bcols = _spread(nbc, self.bl, bcols)
         return pcols + bcols if self.probe_is_left else bcols + pcols
 
+    # ---- a join's view as a build side under a mesh --------------------
+
+    def _mesh_view(self, nbb: int) -> tuple:
+        """(ok, vmesh) of a build side that is a view, under a mesh.
+        ``vmesh``: the mesh over which the view lies by rows, or None:
+        the view of a join (through selections and projections, which
+        keep the rows' places) at a bucket that shards is computed a row
+        range a device, a quarter of the work on four; a leaf was placed
+        whole by :meth:`_place_build`, an aggregate merged its partial
+        states whole, a bucket too small to shard lies whole.  What
+        every device will hold whole of a view by rows — the validity,
+        the live columns' value lanes, the null lanes that are gathered
+        — is priced as a broadcast leaf's copies are
+        (dist.broadcast_over_budget): ``ok`` False is over the budget,
+        the statement leaves the fused pipeline.  Counts the view build
+        (``pipe_mesh_views``, whichever layout) and what the all-gathers
+        move (``reshard_bytes``: 8 bytes a value or dictionary code, 1 a
+        null or validity)."""
+        if self.mesh is None or _leafish(self.build) is self.build:
+            return True, None
+        from ..parallel import dist
+        node = self.build
+        while isinstance(node, (_SelNode, _ProjNode)):
+            node = node.child
+        vmesh = self.mesh if isinstance(node, _JoinNode) \
+            and dist.shardable(nbb, self.mesh) else None
+        if vmesh is not None:
+            lanes = len(self.bl)
+            if dist.broadcast_over_budget(
+                    nbb * max(lanes, 1) * dist.COST_COLUMN_BYTES,
+                    self.n_mesh):
+                return False, None
+            kernels.stats_add("reshard_bytes", nbb * (
+                1 + 8 * lanes + len(set(self.bl) - self.bskip)))
+        kernels.stats_add("pipe_mesh_views", 1)
+        return True, vmesh
+
+    def _whole_view(self, vmesh, bvalid, bpairs):
+        """(validity, live build columns in ``self.bl``'s order) of a
+        view that lies by rows over ``vmesh``, whole on every device: one
+        all-gather a lane (``dist.gather_rows``).  The null lane of a
+        column the view proves free of NULLs (``self.bskip``) is read by
+        nobody and stays where it is: its place holds the value lane."""
+        if vmesh is None:
+            return bvalid, bpairs
+        from ..parallel import dist
+        keep = [i not in self.bskip for i in self.bl]
+        got = dist.gather_rows(
+            vmesh, [bvalid] + [bv for bv, _ in bpairs]
+            + [bn for (_, bn), k in zip(bpairs, keep) if k])
+        vals = got[1:1 + len(bpairs)]
+        nulls = iter(got[1 + len(bpairs):])
+        return got[0], [(v, next(nulls) if k else v)
+                        for v, k in zip(vals, keep)]
+
     # ---- semi / anti: membership folds into probe validity -------------
 
     def _prepare_semi(self, pb, btv, ptv) -> Optional[_TView]:
@@ -1845,29 +1911,49 @@ class _JoinNode:
         jn = _jn()
         nb = ptv.nb
         nbb = btv.nb
+        ok, vmesh = self._mesh_view(nbb)
+        if not ok:
+            return None
         pk_slot = self.probe_key.index
         anti = self.tp == "anti"
         pt = ParamTable()
         pt.add_int(lo)
         pt.add_int(hi)
         ip, fp = pb.params(pt)
+        # under a mesh each shard filters its own probe rows against the
+        # table and the build's validity, both whole on every device
+        from ..parallel import dist
+        mesh = self.mesh if dist.shardable(nb, self.mesh) else None
         self._key(pb, ("semijoin", anti, nb, nbb, tbl_len, pk_slot,
-                       len(ptv.meta), len(btv.meta)))
+                       len(ptv.meta), len(btv.meta))
+                  + dist.layout_tag(_layouts(mesh)[0]))
         live = self.live
+
+        def kernel(pkey, pvalid, bvalid, tbl, pr):
+            kp, knull = pkey
+            lo_p, hi_p = pr[0][0], pr[0][1]
+            inr = (kp >= lo_p) & (kp <= hi_p) & ~knull
+            pos0 = jn.clip(kp - lo_p, 0, tbl_len - 1)
+            pos = jn.where(inr, tbl[pos0].astype(jn.int64), -1)
+            match = (pos >= 0) & bvalid[jn.clip(pos, 0, nbb - 1)]
+            # anti (NOT EXISTS shape, never null-aware here): a NULL
+            # probe key matches nothing and therefore SURVIVES
+            return pvalid & (~match if anti else match)
+
+        if mesh is not None:
+            shard_map, _ = dist.shard_map_fn()
+            ROWS, WHOLE = dist.specs()
+            kernel = shard_map(
+                kernel, mesh=mesh,
+                in_specs=((ROWS, ROWS), ROWS, WHOLE, WHOLE, (WHOLE, WHOLE)),
+                out_specs=ROWS)
 
         def emit(args):
             bvalid, _bpairs = btv.emit(args)
             pvalid, ppairs = ptv.emit(args)
-            kp, knull = ppairs[pk_slot]
-            pr = (args[ip], args[fp])
-            lo_p, hi_p = pr[0][0], pr[0][1]
-            inr = (kp >= lo_p) & (kp <= hi_p) & ~knull
-            pos0 = jn.clip(kp - lo_p, 0, tbl_len - 1)
-            pos = jn.where(inr, args[it][pos0].astype(jn.int64), -1)
-            match = (pos >= 0) & bvalid[jn.clip(pos, 0, nbb - 1)]
-            # anti (NOT EXISTS shape, never null-aware here): a NULL
-            # probe key matches nothing and therefore SURVIVES
-            valid_out = pvalid & (~match if anti else match)
+            bvalid, _ = self._whole_view(vmesh, bvalid, [])
+            valid_out = kernel(ppairs[pk_slot], pvalid, bvalid, args[it],
+                               (args[ip], args[fp]))
             return valid_out, _only(ppairs, live)
         return self._view(emit, nb, "semijoin", ptv, btv)
 
@@ -2156,6 +2242,7 @@ class _JoinNode:
                 [bpairs[i] for i in bx], bvalid, (args[ip], args[fp]))
             return valid_out, self._out(
                 _spread(len(ptv.meta), self.pl, pcols), bcols, nbc)
+        self.partitioned = True
         return self._view(emit, n * n * capp, "joinshuf", ptv, btv)
 
     def _prepare_unique(self, pb, btv, ptv) -> Optional[_TView]:
@@ -2175,6 +2262,9 @@ class _JoinNode:
         jn = _jn()
         nb = ptv.nb
         nbb = btv.nb
+        ok, vmesh = self._mesh_view(nbb)
+        if not ok:
+            return None
         pk_slot = self.probe_key.index
         pt = ParamTable()
         pt.add_int(lo)
@@ -2222,8 +2312,10 @@ class _JoinNode:
         def emit(args):
             bvalid, bpairs = btv.emit(args)
             pvalid, ppairs = ptv.emit(args)
+            bvalid, blive = self._whole_view(vmesh, bvalid,
+                                             [bpairs[i] for i in bl])
             valid_out, gathered = sharded(
-                ppairs[pk_slot], pvalid, [bpairs[i] for i in bl], bvalid,
+                ppairs[pk_slot], pvalid, blive, bvalid,
                 args[it], (args[ip], args[fp]))
             return valid_out, self._out(ppairs, gathered, nbc)
         return self._view(emit, nb, "join", ptv, btv)
@@ -2514,10 +2606,10 @@ class _SortGroupNode:
         child = _compile_node(plan.children[0], ctx)
         if child is None:
             return None
-        cut = _KeyGroupNode.cut_of(child, list(plan.group_by), ctx)
+        cut = _KeyGroupNode.cut_of(child, list(plan.group_by))
         if cut is not None:
             return _KeyGroupNode(child, list(plan.group_by), cut, specs,
-                                 slots, out_map, plan)
+                                 slots, out_map, plan, mesh=ctx.mesh)
         return _SortGroupNode(child, list(plan.group_by), specs, slots,
                               out_map, plan)
 
@@ -2673,12 +2765,24 @@ class _KeyGroupNode:
     group bucket.  A GROUP BY whose columns come from several tables, or
     none of which is the key, is not cut and sorts (_SortGroupNode).
 
+    **Under a mesh** whose shards hold the view's rows (the bucket
+    shards: a join chain's probe) each shard reduces its own rows to
+    the [groups] tables and the tables merge over the mesh as
+    _AggIndexNode's do (``dist.mesh_sum``; min / max by ``mesh_min`` /
+    ``mesh_max``; "some row fell in it" is a sum of counts; counter
+    ``agg_key_mesh``); a shard that holds no valid row gives each
+    merge's identity.  The merged tables lie whole on every device, as
+    do the key -> row table and the lanes the carried columns are
+    fetched from (a broadcast build leaf's own lanes, by their memo
+    keys).
+
     Output view: group ``g`` at slot ``g`` of the bucket of the range,
     valid where some row fell in it."""
 
     def __init__(self, child, key_cols, cut: int, specs, slots, out_map,
-                 plan):
+                 plan, mesh=None):
         self.child = child
+        self.mesh = mesh
         self.key_cols = key_cols
         self.cut = cut              # index into key_cols of the key
         self.specs = specs
@@ -2687,11 +2791,8 @@ class _KeyGroupNode:
         self.plan = plan
 
     @staticmethod
-    def cut_of(child, key_cols, ctx: _Ctx) -> Optional[int]:
-        """Which GROUP BY column the groups can be formed on, or None
-        (the mesh keeps the formulation it had)."""
-        if ctx.mesh is not None:
-            return None
+    def cut_of(child, key_cols) -> Optional[int]:
+        """Which GROUP BY column the groups can be formed on, or None."""
         from .tpu_executors import _slot_id
         origins = [_origin(child, e.index) for e in key_cols]
         if any(o is None for o in origins):
@@ -2752,6 +2853,13 @@ class _KeyGroupNode:
         # one slot past the range holds the NULL key's group
         ngb = kernels.bucket(rng + 1)
         dense = ngb <= kernels.SEG_UNROLL
+        # under a mesh whose shards hold the view's rows each shard
+        # reduces its own; what the groups read afterwards lies whole
+        from ..parallel import dist
+        mesh = self.mesh if dist.shardable(nb, self.mesh) else None
+        lwhole = _layouts(self.mesh)[1]
+        if mesh is not None:
+            kernels.stats_add("agg_key_mesh", 1)
         # the columns the key determines, fetched for each group from
         # the table's own lanes (the memo keys of its scans)
         carried = {}
@@ -2764,13 +2872,15 @@ class _KeyGroupNode:
                 if got is None:
                     return None
                 tbl = got[2]
-                it = pb.lane(rep, ("postable_dev", ksid), lambda: tbl)
+                it = pb.lane(rep, ("postable_dev", ksid), lambda: tbl,
+                             lwhole)
                 tbl_len = int(tbl.shape[0])
             sid, v, m, decode, dt = column(_origin(self.child, e.index)[1])
             carried[j] = (
                 pb.add(_leaf_lane(rep, "devcodes" if dt == "s" else "devv",
-                                  sid, nbl, v)),
-                pb.add(_leaf_lane(rep, "devn", sid, nbl, m, True)),
+                                  sid, nbl, v, layout=lwhole), lwhole),
+                pb.add(_leaf_lane(rep, "devn", sid, nbl, m, True,
+                                  layout=lwhole), lwhole),
                 decode, dt, _column_never_null(rep, sid, m))
         if carried:
             kernels.stats_add("agg_key_cut", 1)
@@ -2785,14 +2895,25 @@ class _KeyGroupNode:
         pb.key(("keygroup", tuple(keys), key.index, kdt, self.cut,
                 tuple((j, c[3]) for j, c in sorted(carried.items())),
                 tuple(self.slots), tuple(self.out_map), nb, ngb, nbl,
-                tbl_len, len(tv.meta)), live, len(self.out_map))
+                tbl_len, len(tv.meta))
+               + dist.layout_tag(_layouts(mesh)[0]),
+               live, len(self.out_map))
         spec_kinds = [k for k, _ in self.specs]
         slots, out_map, cut = self.slots, self.out_map, self.cut
         kslot = key.index
+        need = sorted(needed)
+        #: the view's slots the reduction reads (all live in ``tv``)
+        read = sorted({kslot} | _spec_slots_read(self.specs, needed))
+        merge_sum, merge_mm = _mesh_merges(mesh)
 
-        def emit(args):
-            valid, pairs = tv.emit(args)
-            pr = (args[ip], args[fp])
+        def reduce(valid, cols, pr):
+            """(the key's first value, its range, rows per group,
+            [(value, null)] per needed spec), the last two each [ngb],
+            of the rows at hand: the view's, or a shard's with the
+            tables merged over the mesh.  (The two parameters are read
+            once and handed on, so that the one-device program is the
+            one it was.)"""
+            pairs = _spread(len(tv.meta), read, cols)
             lo_p, rng_p = pr[0][0], pr[0][1]
             kval, knull = pairs[kslot]
             gid = jn.where(knull, rng_p,
@@ -2800,15 +2921,33 @@ class _KeyGroupNode:
                            ).astype(jn.int32)
             seg = kernels._SegReduce(kernels.jax(), jn, gid, valid, ngb,
                                      unroll=dense)
-            presence = seg.sum(valid.astype(jn.int64), valid)
+            presence = merge_sum(seg.sum(valid.astype(jn.int64), valid))
             res = _spec_results(
                 jn, spec_kinds, arg_fns, pairs, pr, valid,
-                seg_sum=lambda x: seg.sum(x, valid),
-                seg_mm=lambda av, live_s, kind: seg.minmax(
-                    av, live_s, kind == "min"),
+                seg_sum=lambda x: merge_sum(seg.sum(x, valid)),
+                seg_mm=lambda av, live_s, kind: merge_mm[kind](seg.minmax(
+                    av, live_s, kind == "min")),
                 presence=presence, n_out=ngb, needed=needed,
                 never_null=never_null)
-            outs = _slot_outputs(jn, res, slots)
+            return lo_p, rng_p, presence, [res[k] for k in need]
+        if mesh is not None:
+            # merged states are whole by construction (psum; min / max
+            # gathered and reduced alike on every shard, beyond the
+            # static checker)
+            ROWS, WHOLE = dist.specs()
+            reduce = dist.shard_map_unchecked(
+                reduce, mesh=mesh,
+                in_specs=(ROWS, [(ROWS, ROWS)] * len(read), (WHOLE, WHOLE)),
+                out_specs=(WHOLE, WHOLE, WHOLE,
+                           [(WHOLE, WHOLE)] * len(need)))
+
+        def emit(args):
+            valid, pairs = tv.emit(args)
+            pr = (args[ip], args[fp])
+            lo_p, rng_p, presence, res = reduce(
+                valid, [pairs[i] for i in read], pr)
+            outs = _slot_outputs(
+                jn, _spread(len(spec_kinds), need, res), slots)
             g = jn.arange(ngb, dtype=jn.int64)
             gnull = g >= rng_p
             if carried:
@@ -2958,12 +3097,15 @@ def _join_side_slot(node: "_JoinNode", idx: int):
 
 def _probe_shaped(node) -> bool:
     """A join whose output view has its probe side's rows, one for one:
-    the single-key unique join and the semi join on one device (the
-    partitioned mesh join lays its output out by key).  The probe side's
-    key -> row table then still finds a row of the view, and the view's
-    own validity says whether the row survived the join."""
+    the single-key unique join and the semi join, on one device or
+    broadcast under a mesh (a join that partitioned lays its output out
+    by key: known once it has prepared, so a tree compiles on the
+    promise and a parent's prepare bails where it was not kept).  The
+    probe side's key -> row table then still finds a row of the view,
+    and the view's own validity says whether the row survived the
+    join."""
     return isinstance(node, _JoinNode) and not node.mult \
-        and node.nk == 1 and node.mesh is None
+        and node.nk == 1 and not node.partitioned
 
 
 def _view_build_key(node: "_JoinNode", build_key):

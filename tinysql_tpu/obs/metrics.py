@@ -84,13 +84,25 @@ _DEVICE_METRICS = {
                     "key (a table's primary key) that determines the "
                     "other GROUP BY columns, which are fetched for the "
                     "groups and gathered nowhere in the chain"),
+    "pipe_mesh_views": ("tinysql_pipe_mesh_views_total",
+                        "Of the view builds, those traced under a mesh: "
+                        "a join's view computed a row range a device "
+                        "and its live lanes all-gathered whole (the "
+                        "bytes under reshard_bytes), or an aggregate's "
+                        "merged tables, whole as they are"),
+    "agg_key_mesh": ("tinysql_agg_key_mesh_total",
+                     "Fused GROUP BYs above a join chain that each "
+                     "device of a mesh reduced over its own rows, the "
+                     "partial tables merged over the mesh"),
     "mesh_dispatches": ("tinysql_mesh_dispatches_total",
                         "Dispatches whose program ran over the whole "
                         "device mesh (tidb_mesh_parallel)"),
     "reshard_bytes": ("tinysql_reshard_bytes_total",
                       "Bytes of inputs a mesh dispatch found laid out "
                       "otherwise than its program asks, and moved "
-                      "between devices (0 when warm)"),
+                      "between devices (0 when warm), and of the lanes "
+                      "of joins' views that fused mesh programs "
+                      "all-gather whole as build sides (every dispatch)"),
     "mesh_resident_bytes_max": ("tinysql_mesh_resident_bytes_max",
                                 "Bytes of replica lanes placed on the "
                                 "fullest device of the mesh"),

@@ -125,6 +125,7 @@ STATS = {"dispatches": 0, "d2h_transfers": 0, "d2h_bytes": 0,
          "agg_span_cut": 0,
          "pipe_dead_cols": 0, "pipe_const_nulls": 0,
          "pipe_joins": 0, "pipe_view_builds": 0, "agg_key_cut": 0,
+         "pipe_mesh_views": 0, "agg_key_mesh": 0,
          "mesh_dispatches": 0, "reshard_bytes": 0,
          "mesh_resident_bytes_max": 0, "mesh_resident_bytes_min": 0,
          "device_s": 0.0, "profiled_dispatches": 0,

@@ -97,6 +97,18 @@ def mesh_gather(x, axis: str = "shard", tiled: bool = False):
         return jax.lax.all_gather(x, axis, tiled=tiled)
 
 
+def gather_rows(mesh, lanes: list) -> list:
+    """In a program over ``mesh``, outside shard_map: each of ``lanes``,
+    which lie by rows, whole on every device — one ``mesh.all_gather``
+    a lane.  How a view that was computed a row range a device becomes a
+    broadcast join's build side."""
+    rows_spec, whole_spec = specs()
+    return shard_map_unchecked(
+        lambda xs: [mesh_gather(x, tiled=True) for x in xs], mesh,
+        in_specs=([rows_spec] * len(lanes),),
+        out_specs=[whole_spec] * len(lanes))(list(lanes))
+
+
 def mesh_sum_spans(part, starts, size: int, axis: str = "shard"):
     """Inside shard_map: :func:`mesh_sum` of [size] tables of which
     shard s holds only the run ``part`` [q] that begins at

@@ -237,10 +237,18 @@ def _device_info(st) -> str:
     if d.get("pipe_joins"):
         # joins traced into the fused program / those whose build side
         # is a view; GROUP BYs cut to the key that determines the rest
+        # mesh: of the view builds, those traced under a mesh
+        mesh_views = f"/{int(d['pipe_mesh_views'])}mesh" \
+            if d.get("pipe_mesh_views") else ""
         parts.append(f"joins:{int(d['pipe_joins'])}"
-                     f"/{int(d.get('pipe_view_builds', 0))}view")
+                     f"/{int(d.get('pipe_view_builds', 0))}view"
+                     f"{mesh_views}")
     if d.get("agg_key_cut"):
         parts.append(f"key_cut:{int(d['agg_key_cut'])}")
+    if d.get("agg_key_mesh"):
+        # keyed GROUP BYs above a chain reduced a shard at a time and
+        # merged over the mesh
+        parts.append(f"key_mesh:{int(d['agg_key_mesh'])}")
     if d.get("pipe_blocks"):
         from ..ops.kernels import pipe_overlap_frac
         overlap = pipe_overlap_frac(d)

@@ -18,7 +18,11 @@ TPC-H Q5/Q10/Q18 end-to-end through the SQL front door at SF=0.02:
    shares the decorrelated planner);
 6. with the chip's branches forced (``TINYSQL_DEVICE_JOIN_ONLY=1``,
    ``tidb_devpipe = 1``) every query is ONE fused device program: one
-   dispatch, no host twin, the same rows.
+   dispatch, no host twin, the same rows;
+7. the same under a forced mesh (``tidb_mesh_parallel = 1`` over four
+   host devices): still one dispatch each, launched over the whole
+   mesh, the views traced under it and Q5's and Q10's keyed GROUP BYs
+   reduced a shard at a time.
 
 Exit 0 on success; prints one line per check.
 """
@@ -31,6 +35,12 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# four host devices for the forced mesh (jax reads the flag once)
+if "xla_force_host_platform_device_count" not in os.environ.get(
+        "XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=4").strip()
 
 
 def check(name: str, ok: bool, detail: str = "") -> None:
@@ -38,6 +48,22 @@ def check(name: str, ok: bool, detail: str = "") -> None:
           f"{' — ' + detail if detail else ''}")
     if not ok:
         sys.exit(1)
+
+
+def rows_close(got, want, rel: float = 1e-8) -> bool:
+    """Same rows in the same order, doubles within ``rel``: a shard's
+    prefix sums run over its own rows, so Q10's sums round in the ninth
+    digit otherwise than one device's (a 9-digit canon flips there)."""
+    if len(got) != len(want):
+        return False
+    for a, b in zip(got, want):
+        for x, y in zip(a, b):
+            if isinstance(y, float):
+                if abs(float(x) - y) > rel * max(abs(y), 1.0):
+                    return False
+            elif x != y:
+                return False
+    return True
 
 
 def main() -> int:
@@ -93,6 +119,33 @@ def main() -> int:
                   f"dispatches={d.get('dispatches', 0)} "
                   f"host={d.get('host_dispatches', 0)} "
                   f"joins={d.get('pipe_joins', 0)}")
+        # and under a forced mesh of the host's devices
+        from tinysql_tpu.parallel import dist
+        floor, dist.MIN_SHARD_ROWS = dist.MIN_SHARD_ROWS, 16
+        s.execute("set @@tidb_mesh_parallel = 1")
+        try:
+            for q, sql in tpch.WORKLOAD.items():
+                want = [list(r) for r in lite.execute(sql).fetchall()]
+                snap = kernels.stats_snapshot()
+                got = s.query(sql).rows
+                d = kernels.stats_delta(snap)
+                check(f"{q} over the mesh matches sqlite",
+                      rows_close(got, want),
+                      f"{len(got)} rows vs {len(want)}")
+                check(f"{q} is one fused program over the mesh",
+                      d.get("dispatches", 0) == 1
+                      and d.get("mesh_dispatches", 0) == 1
+                      and d.get("host_dispatches", 0) == 0
+                      and d.get("pipe_mesh_views", 0) >= 1
+                      and d.get("agg_key_mesh", 0) == (q != "Q18"),
+                      f"dispatches={d.get('dispatches', 0)} "
+                      f"mesh={d.get('mesh_dispatches', 0)} "
+                      f"host={d.get('host_dispatches', 0)} "
+                      f"mesh_views={d.get('pipe_mesh_views', 0)} "
+                      f"key_mesh={d.get('agg_key_mesh', 0)}")
+        finally:
+            s.execute("set @@tidb_mesh_parallel = 0")
+            dist.MIN_SHARD_ROWS = floor
     finally:
         s.execute("set @@tidb_devpipe = -1")
         del os.environ["TINYSQL_DEVICE_JOIN_ONLY"]
