@@ -352,6 +352,16 @@ def _gathers(text, rows):
 Q3_BUCKET = 1 << 17
 
 
+def _no_alignment(monkeypatch):
+    """No join reads its build's rows in place: every one looks them up
+    by its key and gathers them, the programs of the commit before
+    PR 40."""
+    from tinysql_tpu.executor import devpipe
+    monkeypatch.setattr(devpipe._JoinNode, "_aligned",
+                        lambda self, ptv, btv: False)
+
+
+@pytest.mark.parametrize("aligned", ["on", "off"])
 @pytest.mark.parametrize("live, expect", [
     # the benchmark's statement: the projection above the TopN reads
     # revenue, l_orderkey, o_orderdate, o_shippriority of eight slots;
@@ -360,18 +370,23 @@ Q3_BUCKET = 1 << 17
     # The aggregate's boundary gathers are presence's and revenue's:
     # l_extendedprice * (1 - l_discount) is NULL on no row, so its count
     # is presence (the parent held six: a count's two more).  The outer
-    # join reads revenue and l_orderkey off the group table, whose view
-    # proves both free of NULLs in a group that exists: tbl[pos0],
-    # bvalid[pos], two u32 a value and no null lane (PR 35: 8); with
-    # every slot live the inner join reads customer's two columns the
-    # same way (PR 35: 8)
-    ("consumer", {"aggindex": 4, "join/join": 2, "join": 6}),
-    ("all", {"aggindex": 4, "join/join": 6, "join": 6}),
+    # join reads revenue and l_orderkey off the group table, whose group
+    # g is orders' row g (both stored by the order key, every order with
+    # lines): aligned, it reads them in place and gathers nothing; the
+    # gathering join (PR 39) takes tbl[pos0], bvalid[pos] and two u32 a
+    # value, no null lane (the view proves both free of NULLs in a group
+    # that exists; PR 35: 8); with every slot live the inner join reads
+    # customer's two columns the same way (PR 35: 8)
+    ("consumer", {"aggindex": 4, "join/join": 2}),
+    ("all", {"aggindex": 4, "join/join": 6}),
 ])
 def test_q3_gathers_at_the_bucket(one_chip, chip_branches, monkeypatch,
-                                  tpch_session, live, expect):
+                                  tpch_session, live, expect, aligned):
     if live == "all":
         _all_live(monkeypatch)
+    if aligned == "off":
+        _no_alignment(monkeypatch)
+        expect = dict(expect, join=6)
     fn, abstract = _capture(monkeypatch, tpch_session, tpch.QUERIES["Q3"],
                             one_chip)
     assert _gathers(_compile(fn, *abstract).as_text(), Q3_BUCKET) == expect
@@ -395,18 +410,24 @@ def _collectives(text, kind, rows):
     return out
 
 
+@pytest.mark.parametrize("aligned", ["on", "off"])
 @pytest.mark.parametrize("live, quarter", [
-    ("consumer", {"join/join": 2, "join": 6}),
-    ("all", {"join/join": 6, "join": 6}),
+    ("consumer", {"join/join": 2}),
+    ("all", {"join/join": 6}),
 ])
 def test_q3_mesh_gathers_a_chip(topo, chip_branches, monkeypatch,
-                                tpch_session, live, quarter):
+                                tpch_session, live, quarter, aligned):
     """Under the mesh every chip bounds its own span of the groups (four
     gathers at the span's pad, none at the whole table's bucket), the
     pieces cross as all-gathers and no whole table is summed over the
-    chips; and it joins its quarter of ``orders``."""
+    chips; and it joins its quarter of ``orders``: the outer join reads
+    its quarter of the merged group table in place, where the gathering
+    join (``off``) takes six gathers of a quarter lane."""
     if live == "all":
         _all_live(monkeypatch)
+    if aligned == "off":
+        _no_alignment(monkeypatch)
+        quarter = dict(quarter, join=6)
     text = _compile_mesh_statement(topo, monkeypatch, tpch_session,
                                    tpch.QUERIES["Q3"])
     assert _gathers(text, Q3_BUCKET) == {}
@@ -438,6 +459,16 @@ def _pinned(name):
     return pinned["sha256"]
 
 
+def _lowered(topo, one_chip, monkeypatch, session, sql, where):
+    """The sha256 of the StableHLO ``sql``'s fused program lowers to, on
+    one described chip or over the v5e:2x2."""
+    fn, abstract = _capture(monkeypatch, session, sql, one_chip) \
+        if where == "one" \
+        else _capture_mesh(topo, monkeypatch, session, sql)
+    return hashlib.sha256(fn.lower(*abstract).as_text().encode()) \
+        .hexdigest()
+
+
 @pytest.mark.parametrize("flags", ["off", "on"])
 @pytest.mark.parametrize("where", ["one", "mesh"])
 @pytest.mark.parametrize("name", ["Q1", "Q3", "Q6"])
@@ -454,21 +485,44 @@ def test_lowered_text_is_the_parents(topo, one_chip, chip_branches,
     lowers to the text pinned with PR 36.  PR 38 left those ten as they
     were and pinned Q3's mesh program anew, with the flags and without:
     a chip bounds its own span of the groups, and the pieces are
-    gathered and added into place where whole tables were summed."""
+    gathered and added into place where whole tables were summed.  With
+    no join aligned (PR 40's fallback) all twelve still do."""
     if flags == "off":
         _no_flags(monkeypatch)
+    _no_alignment(monkeypatch)
     case = f"{name}.{where}"
     if case == "Q3.mesh":
         pinned = _pinned("lowered_at_pr38.json")[f"{case}.{flags}"]
     else:
         pinned = _pinned("lowered_at_pr36.json" if (name, flags)
                          == ("Q3", "on") else "lowered_at_129247b.json")[case]
-    sql = tpch.QUERIES[name]
-    fn, abstract = _capture(monkeypatch, tpch_session, sql, one_chip) \
-        if where == "one" \
-        else _capture_mesh(topo, monkeypatch, tpch_session, sql)
-    text = fn.lower(*abstract).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == pinned
+    assert _lowered(topo, one_chip, monkeypatch, tpch_session,
+                    tpch.QUERIES[name], where) == pinned
+
+
+@pytest.mark.parametrize("flags", ["off", "on"])
+@pytest.mark.parametrize("name, where", [
+    ("Q3", "one"), ("Q3", "mesh"), ("Q10", "one"), ("Q18", "one"),
+    ("Q5", "one"), ("Q1", "one"), ("Q6", "mesh")])
+def test_aligned_programs_lower_to_the_text_pinned_at_pr40(
+        topo, one_chip, chip_branches, monkeypatch, tpch_session, name,
+        where, flags):
+    """With the aligned joins (PR 40) Q3's, Q10's and Q18's programs
+    lower to the text pinned with PR 40, with the NULL-freedom flags and
+    without; Q5, whose joins are many-to-one or into another table's
+    order, and Q1 and Q6, which have none, to their parents' text."""
+    if flags == "off":
+        _no_flags(monkeypatch)
+    case = f"{name}.{where}.{flags}"
+    if name in ("Q3", "Q10", "Q18"):
+        pinned = _pinned("lowered_at_pr40.json")[case]
+    elif name == "Q5":
+        pinned = _pinned("lowered_at_pr38_joins.json")[case]
+    else:
+        pinned = _pinned("lowered_at_129247b.json")[f"{name}.{where}"]
+    sql = tpch.ALL_QUERIES[name]
+    assert _lowered(topo, one_chip, monkeypatch, tpch_session, sql,
+                    where) == pinned
 
 
 # ---- the join chains: Q5, Q10, Q18 as one fused program each ---------------
@@ -527,31 +581,43 @@ def _scatters(text, scope):
 LINEITEM_BUCKET = 1 << 19
 
 
-@pytest.mark.parametrize("name, rows, gathers, scatters", [
+@pytest.mark.parametrize("aligned", ["on", "off"])
+@pytest.mark.parametrize("name, rows, gathers, scatters, gathering", [
     # the two joins at the lineitem bucket gather (orders join customer)'s
     # c_nationkey and supplier's s_nationkey: tbl[pos0], bvalid[pos] and
-    # two u32 of the value each, and no null lane (the parent: 5 and 5)
-    ("Q5", LINEITEM_BUCKET, {"join/join": 4, "join/join/join": 4}, 0),
+    # two u32 of the value each, and no null lane (the parent: 5 and 5);
+    # both are many-to-one: nothing is aligned
+    ("Q5", LINEITEM_BUCKET, {"join/join": 4, "join/join/join": 4}, 0, {}),
     # at the orders bucket: the aggregate's four boundary gathers; the
-    # join into the group table reads revenue, the join below it
+    # join into the group table reads revenue in place (the gathering
+    # join: tbl[pos0], bvalid[pos], two u32), the join below it
     # c_custkey's side, each without its null lane (the parent: 5 and
     # 5); the keyed GROUP BY on c_custkey scatter-adds presence and the
     # sum, whose own count is presence (the parent: 3 scatters)
-    ("Q10", ORDERS_BUCKET, {"aggindex": 4, "join/join": 4,
-                            "join/join/join": 4}, 2),
+    ("Q10", ORDERS_BUCKET, {"aggindex": 4, "join/join/join": 4}, 2,
+     {"join/join": 4}),
     # two aggregates of four boundary gathers; sum(l_quantity) off the
-    # group table, and two columns under the join below (the semi join's
-    # two gathers count there), without null lanes (the parent: 5, 10)
-    ("Q18", ORDERS_BUCKET, {"aggindex": 8, "join": 4, "join/join": 8}, 0),
+    # group table in place (the gathering join: 4), and two columns
+    # under the join below, whose semi join on the HAVING view reads
+    # that view's validity in place (its two gathers count there),
+    # without null lanes (the parent: 5, 10)
+    ("Q18", ORDERS_BUCKET, {"aggindex": 8, "join/join": 6}, 0,
+     {"join": 4, "join/join": 2}),
 ])
 def test_join_chains_gather_no_constant_null_lane(
         one_chip, chip_branches, monkeypatch, tpch_session, name, rows,
-        gathers, scatters):
+        gathers, scatters, gathering, aligned):
     """The chains' gathers at the probes' buckets and the keyed GROUP
     BY's scatters, counted by the node that traced them: a build column
     whose view holds no NULL on a valid row (``_TView.nonnull``) is
     gathered without its null lane, and a sum of such an argument
-    reduces no count of its own."""
+    reduces no count of its own.  A join into a view whose rows are
+    the probe's own (``aligned``) gathers nothing; ``off``: the
+    gathering join's (``gathering`` more)."""
+    if aligned == "off":
+        _no_alignment(monkeypatch)
+        gathers = {k: gathers.get(k, 0) + gathering.get(k, 0)
+                   for k in gathers.keys() | gathering.keys()}
     fn, abstract = _capture(monkeypatch, tpch_session, tpch.WORKLOAD[name],
                             one_chip)
     text = _compile(fn, *abstract).as_text()
@@ -566,9 +632,11 @@ def test_join_chains_lower_to_the_parents_text_on_one_chip(
     """PR 39 taught the join's view build and the keyed GROUP BY the
     mesh; on one device the three programs lower to the text pinned at
     f6b51c3 (PR 38), byte for byte, with the NULL-freedom flags and
-    without: the one-chip joins cell runs the programs it ran."""
+    without: the one-chip joins cell runs the programs it ran.  So they
+    do with no join aligned (PR 40's fallback)."""
     if flags == "off":
         _no_flags(monkeypatch)
+    _no_alignment(monkeypatch)
     fn, abstract = _capture(monkeypatch, tpch_session, tpch.WORKLOAD[name],
                             one_chip)
     text = fn.lower(*abstract).as_text()
@@ -749,7 +817,7 @@ def test_fused_mesh_program_at_the_sf10_shapes(topo, chip_branches,
         groups, pad = 1 << 24, 5 << 20
         assert _gathers(text, groups) == {}
         assert _gathers(text, pad) == {"aggindex": 4}
-        assert _gathers(text, groups // 4) == {"join/join": 2, "join": 6}
+        assert _gathers(text, groups // 4) == {"join/join": 2}
         assert len(_collectives(text, "all-gather", 4 * pad)) == 4
         assert not _collectives(text, "all-reduce", groups)
 

@@ -152,7 +152,8 @@ def test_counters_read_what_the_plan_says(joins, kind, expect):
 
 
 @pytest.mark.parametrize("kind, shown", [
-    ("q5", "joins:5/3view"), ("q10", "key_cut:1"), ("q18", "joins:3/")])
+    ("q5", "joins:5/3view"), ("q10", "key_cut:1"), ("q18", "joins:3/"),
+    ("q18", "aligned:2")])
 def test_explain_analyze_shows_the_counters(joins, kind, shown):
     s, want = joins
     rows = s.query("explain analyze " + want[kind][0]).rows
@@ -542,3 +543,261 @@ def test_the_shards_parts_add_up_to_the_one_device_table(tk, four_chips):
     mesh, d = _mesh_counted(tk, sql(""))
     _same(mesh, whole, rel=1e-12)
     assert d["agg_key_mesh"] == 1 and d["dispatches"] == 1
+
+
+# ---- aligned joins: the build's rows are the probe's own (ISSUE 40) ---------
+
+def _gathering(monkeypatch):
+    """No join reads its build's rows in place: the parent's program."""
+    from tinysql_tpu.executor import devpipe
+    monkeypatch.setattr(devpipe._JoinNode, "_aligned",
+                        lambda self, ptv, btv: False)
+
+
+def _aligned_and_gathering(s, sql, monkeypatch):
+    """(rows, counters) of ``sql`` warm as the program finds its input,
+    and again with alignment forced off."""
+    s.query(sql)
+    rows, d = _counted(s, sql)
+    with monkeypatch.context() as m:
+        _gathering(m)
+        s.query(sql)
+        off, d_off = _counted(s, sql)
+    return rows, d, off, d_off
+
+
+@pytest.mark.parametrize("where", ["one", "mesh"])
+@pytest.mark.parametrize("kind, aligned", [("q5", 0), ("q10", 1),
+                                           ("q18", 2)])
+def test_aligned_joins_answer_bit_for_bit_as_the_gathering_ones(
+        joins, request, monkeypatch, kind, aligned, where):
+    """``orders`` and ``lineitem`` are stored by the order key and every
+    order has lines: the group table of ``lineitem`` by ``l_orderkey``
+    holds order r's group at row r, and Q10's join into it, Q18's and
+    Q18's semi join on its HAVING view read it in place.  The answers
+    are the gathering joins', bit for bit (the same values from the same
+    rows), on one device and over four; Q5's joins are many-to-one."""
+    s, want = joins
+    sql, ref = want[kind]
+    if where == "mesh":
+        request.getfixturevalue("four_chips")
+        s.execute("set @@tidb_mesh_parallel = 1")
+    try:
+        rows, d, off, d_off = _aligned_and_gathering(s, sql, monkeypatch)
+    finally:
+        s.execute("set @@tidb_mesh_parallel = 0")
+    assert rows == off
+    _same(rows, [list(r) for r in ref])
+    assert d.get("pipe_joins_aligned", 0) == aligned
+    assert d_off.get("pipe_joins_aligned", 0) == 0
+    assert d["pipe_joins"] == d_off["pipe_joins"]
+    assert d["dispatches"] == d_off["dispatches"] == 1
+    # an aligned join uploads no key -> row table and moves nothing
+    assert d.get("reshard_bytes", 0) == d_off.get("reshard_bytes", 0)
+
+
+@pytest.fixture(scope="module")
+def shuffled_orders(joins):
+    """The joins cell's tables with ``orders`` loaded in a random order:
+    the group table's row r is no longer order r's."""
+    data = _bench_file("datasets", "tpch_joins.py")
+    ds = data.generate(SF, SEED)
+    perm = np.random.default_rng(40).permutation(
+        len(ds.tables["orders"]["o_orderkey"]))
+    s = new_session()
+    s.execute(f"create database {data.DATABASE}")
+    s.execute(f"use {data.DATABASE}")
+    for table, ddl in data.SCHEMAS.items():
+        s.execute(ddl)
+        cols = ds.tables[table]
+        if table == "orders":
+            cols = {c: v[perm] for c, v in cols.items()}
+        bulk_load(s.storage,
+                  s.infoschema().table_by_name(data.DATABASE, table), cols)
+    s.execute("set @@tidb_devpipe = 1")
+    s.execute("set @@tidb_tpu_min_rows = 64")
+    return s
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_orders_out_of_key_order_take_the_gathering_join(
+        joins, shuffled_orders, monkeypatch, kind):
+    """The same rows stored in another order: no join is aligned, the
+    programs are the gathering ones and the answers the reference's."""
+    _s, want = joins
+    sql, ref = want[kind]
+    rows, d, off, _ = _aligned_and_gathering(shuffled_orders, sql,
+                                             monkeypatch)
+    assert rows == off and d["dispatches"] == 1
+    _same(rows, [list(r) for r in ref])
+    assert d.get("pipe_joins_aligned", 0) == 0
+
+
+def test_an_order_with_no_lines_takes_the_gathering_join(joins,
+                                                         monkeypatch):
+    """An order inserted with no lines: ``orders`` is a new replica
+    version with one row more than ``lineitem`` has groups, the new
+    key finds no group, and Q18's joins gather again; no answer moves
+    (the order has no lines to sum)."""
+    from tinysql_tpu.columnar.store import store_of
+    data = _bench_file("datasets", "tpch_joins.py")
+    ds = data.generate(SF, SEED)
+    s = new_session()
+    s.execute(f"create database {data.DATABASE}")
+    s.execute(f"use {data.DATABASE}")
+    for table, ddl in data.SCHEMAS.items():
+        s.execute(ddl)
+        bulk_load(s.storage,
+                  s.infoschema().table_by_name(data.DATABASE, table),
+                  ds.tables[table])
+    s.execute("set @@tidb_devpipe = 1")
+    s.execute("set @@tidb_tpu_min_rows = 64")
+    _s, want = joins
+    sql, ref = want["q18"]
+    rows, d = _counted(s, sql)
+    assert d["pipe_joins_aligned"] == 2
+    info = s.infoschema().table_by_name(data.DATABASE, "orders")
+    before = store_of(s.storage).get(info.id)
+    key = int(ds.tables["orders"]["o_orderkey"].max()) + 1
+    s.execute("insert into orders (o_orderkey, o_custkey, o_orderstatus, "
+              "o_totalprice, o_orderdate, o_orderpriority, o_clerk, "
+              f"o_shippriority, o_comment) values ({key}, 1, 'O', 1.5, "
+              "'1995-01-01', '1-URGENT', 'Clerk#1', 0, 'none')")
+    rows, d, off, _ = _aligned_and_gathering(s, sql, monkeypatch)
+    after = store_of(s.storage).get(info.id)
+    assert after is not before and after.n_rows == before.n_rows + 1
+    assert rows == off and d["dispatches"] == 1
+    _same(rows, [list(r) for r in ref])
+    assert d.get("pipe_joins_aligned", 0) == 0
+
+
+@pytest.fixture
+def atk(monkeypatch):
+    """Small tables for the aligned join's cases: ``a`` and ``b`` stored
+    by their keys 1..300, ``a.k`` a unique copy of ``a.id``, ``l`` three
+    lines for every ``a`` row, ``m`` lines for every third one, ``l3``
+    one line for each of 800 keys (a group bucket wider than ``a``'s),
+    ``s`` a small table ``b`` joins by a key with dangling values."""
+    monkeypatch.setenv("TINYSQL_DEVICE_JOIN_ONLY", "1")
+    monkeypatch.setattr(dist, "MIN_SHARD_ROWS", 16)
+    monkeypatch.setattr(
+        dist, "session_mesh",
+        lambda sv: dist.sized_mesh(4) if sv.get("tidb_mesh_parallel")
+        else None)
+    s = new_session()
+    s.execute("create database d")
+    s.execute("use d")
+    s.execute("set @@tidb_tpu_min_rows = 0")
+    s.execute("set @@tidb_devpipe = 1")
+    rng = np.random.default_rng(40)
+    n = 300
+    ids = np.arange(1, n + 1, dtype=np.int64)
+    tables = {
+        "a": ("id bigint primary key, x bigint, k bigint, unique key uk (k)",
+              {"id": ids, "x": rng.integers(0, 9, n), "k": ids}),
+        "b": ("id bigint primary key, aid bigint, y double, sid bigint",
+              {"id": ids, "aid": rng.integers(1, n + 1, n),
+               "y": np.round(rng.random(n) * 100, 2),
+               "sid": rng.integers(1, 46, n)}),
+        "s": ("id bigint primary key, w bigint",
+              {"id": np.arange(1, 41), "w": rng.integers(0, 9, 40)}),
+        "l": ("lid bigint primary key, aid bigint, q double",
+              {"lid": np.arange(1, 3 * n + 1), "aid": np.repeat(ids, 3),
+               "q": np.round(rng.random(3 * n), 3)}),
+        "m": ("lid bigint primary key, aid bigint, q double",
+              {"lid": np.arange(1, n + 1), "aid": np.repeat(ids[::3], 3),
+               "q": np.round(rng.random(n), 3)}),
+        "l3": ("lid bigint primary key, aid bigint, q double",
+               {"lid": np.arange(1, 801), "aid": np.arange(1, 801),
+                "q": np.round(rng.random(800), 3)}),
+    }
+    for table, (columns, data) in tables.items():
+        s.execute(f"create table {table} ({columns})")
+        bulk_load(s.storage, s.infoschema().table_by_name("d", table),
+                  {c: np.asarray(v) for c, v in data.items()})
+    yield s
+
+
+AGG_L = "(select aid, sum(q) qs from l group by aid"
+VIEW_B = "(select b.id bid, s.w from b join s on b.sid = s.id"
+
+
+@pytest.mark.parametrize("where", ["one", "mesh"])
+@pytest.mark.parametrize("sql, aligned", [
+    # an inner join into a group table in its probe's order
+    (f"select count(*), sum(a.x), sum(t.qs) from a join {AGG_L}) t "
+     "on a.id = t.aid", 1),
+    # an outer one whose build view is filtered: unmatched rows NULL
+    (f"select a.id, a.x, t.qs from a left join {AGG_L} "
+     "having sum(q) > 1.5) t on a.id = t.aid order by a.id", 1),
+    # semi and anti joins on the HAVING view
+    ("select count(*), sum(a.x) from a where a.id in (select aid from l "
+     "group by aid having sum(q) > 1.5)", 1),
+    (f"select count(*), sum(a.x) from a where not exists (select * from "
+     f"{AGG_L}) t where t.aid = a.id and t.qs > 1.5)", 1),
+    # a semi join into a join's view: under the mesh the view lies by
+    # rows and is read as each shard's own, nothing all-gathered
+    ("select count(*), sum(a.x) from a where a.id in (select b.id from b "
+     "join s on b.sid = s.id where s.w > 3)", 1),
+    # the negative cases: a group bucket wider than the probe's ...
+    ("select count(*), sum(a.x), sum(t.qs) from a join (select aid, "
+     "sum(q) qs from l3 group by aid) t on a.id = t.aid", 0),
+    # ... a LEFT join into a group table that misses two keys in three,
+    # whose build columns come out NULL there ...
+    ("select a.id, t.qs from a left join (select aid, sum(q) qs from m "
+     "group by aid) t on a.id = t.aid order by a.id", 0),
+    # ... and a probe key taken from a build side: the view's rows are
+    # b's, the key a.k (a's rows would read as aligned)
+    (f"select count(*), sum(b.y), sum(t.qs) from b join a on b.aid = a.id "
+     f"join {AGG_L}) t on a.k = t.aid", 0),
+])
+def test_aligned_join_cases(atk, request, monkeypatch, sql, aligned, where):
+    """Each statement ONE fused program whose answers equal the host
+    tier's and, bit for bit, the gathering join's; ``aligned`` of its
+    joins read the build in place."""
+    if where == "mesh":
+        request.getfixturevalue("four_chips")
+        atk.execute("set @@tidb_mesh_parallel = 1")
+    try:
+        rows, d, off, _ = _aligned_and_gathering(atk, sql, monkeypatch)
+    finally:
+        atk.execute("set @@tidb_mesh_parallel = 0")
+    atk.execute("set @@tidb_devpipe = 0")
+    atk.execute("set @@tidb_use_tpu = 0")
+    try:
+        plain = atk.query(sql).rows
+    finally:
+        atk.execute("set @@tidb_use_tpu = 1")
+        atk.execute("set @@tidb_devpipe = 1")
+    assert rows == off and rows and d["dispatches"] == 1
+    _same(rows, plain)
+    assert d.get("pipe_joins_aligned", 0) == aligned
+    if "left join" in sql:
+        assert any(r[-1] is None for r in rows)
+    if where == "mesh" and "join s" in sql:
+        assert d.get("reshard_bytes", 0) == 0 and d["pipe_mesh_views"] == 1
+
+
+def test_a_key_from_a_build_side_would_read_the_wrong_rows(atk,
+                                                           monkeypatch):
+    """Why the walk to the probe's rows follows the probe side alone: a
+    walk that took ``a.k``'s own table for the view's rows finds it
+    aligned (a.k is 1..300 in a's order) and reads the group table at
+    b's rows: another answer."""
+    from tinysql_tpu.executor import devpipe
+    sql = (f"select count(*), sum(b.y), sum(t.qs) from b join a "
+           f"on b.aid = a.id join {AGG_L}) t on a.k = t.aid")
+    right = atk.query(sql).rows
+    walk = devpipe._probe_rows
+
+    def either_side(node, key):
+        if isinstance(node, devpipe._JoinNode):
+            side, at = devpipe._join_side_slot(node, key.index)
+            return walk(side, devpipe._Slot(at))
+        return walk(node, key)
+    monkeypatch.setattr(devpipe, "_probe_rows", either_side)
+    from tinysql_tpu.ops import progcache
+    progcache.clear()
+    wrong, d = _counted(atk, sql)
+    progcache.clear()
+    assert d["pipe_joins_aligned"] == 1 and wrong != right

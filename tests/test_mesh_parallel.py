@@ -520,8 +520,11 @@ def test_replica_lanes_are_laid_out_over_the_mesh(tpch_mesh):
         assert {sh.data.shape for sh in shards} == \
             {(arr.shape[0] // n,)}, key
         assert len({sh.device for sh in shards}) == n, key
-    assert {"devv", "postable_dev", "gi_postable_dev",
-            "gi_gkeys"} <= {k[0] for k in whole}
+    assert {"devv", "postable_dev", "gi_gkeys"} <= {k[0] for k in whole}
+    # Q3's join into the group table is aligned (orders and lineitem are
+    # both stored by the order key): it reads the merged table in place
+    # and the group index's key -> row table never goes up
+    assert "gi_postable_dev" not in {k[0] for k in lanes}
     for key, arr in whole.items():
         assert arr.sharding.is_fully_replicated, key
         assert len(arr.addressable_shards) == n, key
@@ -1096,6 +1099,39 @@ def test_mesh_join_over_a_build_column_with_nulls(null_tk, case):
     assert delta["dispatches"] == delta["mesh_dispatches"] == 1
     assert moved > 0 or not partition
     assert (delta["pipe_const_nulls"], one["pipe_const_nulls"]) == skipped
+
+
+@pytest.mark.parametrize("n", [0, 2, 4])
+@pytest.mark.parametrize("name, aligned", [("Q1", 0), ("Q3", 1), ("Q6", 0)])
+def test_aligned_join_answers_bit_for_bit_as_the_gathering_one(
+        tpch_mesh, monkeypatch, name, aligned, n):
+    """ISSUE 40: ``orders`` and ``lineitem`` are stored by the order key
+    and every order has lines, so Q3's join into the group table reads
+    it in place (over ``n`` devices each its own row range of the merged
+    table; 0: one device).  The rows are the gathering join's, bit for
+    bit, and nothing crosses the mesh for it; Q1 and Q6 join nothing."""
+    from tinysql_tpu.executor import devpipe
+    s, _mirror, queries = tpch_mesh
+    sql = queries[name]
+    if n:
+        _mesh_of(monkeypatch, n)
+        s.execute("set @@tidb_mesh_parallel = 1")
+    try:
+        s.query(sql)
+        rows, d = _stats_of(s, sql)
+        with monkeypatch.context() as m:
+            m.setattr(devpipe._JoinNode, "_aligned",
+                      lambda self, ptv, btv: False)
+            s.query(sql)
+            off, d_off = _stats_of(s, sql)
+    finally:
+        s.execute("set @@tidb_mesh_parallel = 0")
+    assert rows and rows == off
+    assert d.get("pipe_joins_aligned", 0) == aligned
+    assert d_off.get("pipe_joins_aligned", 0) == 0
+    assert d["dispatches"] == d_off["dispatches"]
+    assert d.get("reshard_bytes", 0) == d_off.get("reshard_bytes", 0) == 0
+    assert d.get("progcache_misses", 0) == 0
 
 
 @pytest.mark.parametrize("n", [2, 4])

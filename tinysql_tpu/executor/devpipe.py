@@ -1632,7 +1632,10 @@ class _JoinNode:
 
     - unique build (planner-proven pk/unique, or a group-index partial
       agg): dense key -> row position table + one gather per build
-      column — probe-shaped output, no expansion.
+      column — probe-shaped output, no expansion.  Where the build's
+      rows are the probe's own, in the same order (``_aligned``: a table
+      stored in key order and an aggregate of another by that key, say),
+      neither the table nor any gather: each build lane is read in place.
     - general multiplicity (reference join.go:244 / util/mvmap): the
       build replica's group index is a CSR layout; probe maps key ->
       group through the dense table, per-group VALID counts come from a
@@ -1825,13 +1828,37 @@ class _JoinNode:
 
     def _gather_build(self, bpairs, at, match) -> list:
         """The build's live columns (``bpairs``, in ``self.bl``'s order)
-        at the build rows ``at``, NULL where ``match`` is not: ``match``
-        holds only where ``at`` is a valid row of the build view, so a
-        column that view proves free of NULLs (``self.bskip``) is NULL
-        exactly where nothing matched and its null lane is not
-        gathered."""
-        return [(bv[at], ~match if i in self.bskip else bn[at] | ~match)
+        at the build rows ``at`` (None: an aligned join's own rows, read
+        in place), NULL where ``match`` is not: ``match`` holds only
+        where ``at`` is a valid row of the build view, so a column that
+        view proves free of NULLs (``self.bskip``) is NULL exactly where
+        nothing matched and its null lane is not gathered."""
+        def read(x):
+            return x if at is None else x[at]
+        return [(read(bv), ~match if i in self.bskip else read(bn) | ~match)
                 for i, (bv, bn) in zip(self.bl, bpairs)]
+
+    def _aligned(self, ptv, btv) -> bool:
+        """The build view's row r is the row the probe's key at row r
+        finds, for every row: the probe view holds a replica leaf's rows
+        in place (``_probe_rows``), its key is that leaf's own column
+        without a NULL, the build's key -> row table read at it is the
+        identity (``_aligned_rows``, once a replica version) and the two
+        buckets are one.  Such a join reads the build's lanes in place:
+        no key lookup, no gather (counter ``pipe_joins_aligned``)."""
+        if ptv.nb != btv.nb:
+            return False
+        got = _probe_rows(self.probe, self.probe_key)
+        table = _build_key_table(self.build, self.build_key)
+        if got is None or table is None:
+            return False
+        from .tpu_executors import _slot_id
+        leaf, slot = got
+        if not _aligned_rows(leaf.replica(), _slot_id(leaf.ex, slot),
+                             table):
+            return False
+        kernels.stats_add("pipe_joins_aligned", 1)
+        return True
 
     def _out(self, ppairs, bcols, nbc: int) -> list:
         """The output view: the probe's live columns as they are, the
@@ -1843,7 +1870,7 @@ class _JoinNode:
 
     # ---- a join's view as a build side under a mesh --------------------
 
-    def _mesh_view(self, nbb: int) -> tuple:
+    def _mesh_view(self, nbb: int, aligned: bool = False) -> tuple:
         """(ok, vmesh) of a build side that is a view, under a mesh.
         ``vmesh``: the mesh over which the view lies by rows, or None:
         the view of a join (through selections and projections, which
@@ -1858,7 +1885,8 @@ class _JoinNode:
         the statement leaves the fused pipeline.  Counts the view build
         (``pipe_mesh_views``, whichever layout) and what the all-gathers
         move (``reshard_bytes``: 8 bytes a value or dictionary code, 1 a
-        null or validity)."""
+        null or validity).  An ``aligned`` join reads a view by rows as
+        its own shard: nothing is held whole or moved."""
         if self.mesh is None or _leafish(self.build) is self.build:
             return True, None
         from ..parallel import dist
@@ -1867,7 +1895,7 @@ class _JoinNode:
             node = node.child
         vmesh = self.mesh if isinstance(node, _JoinNode) \
             and dist.shardable(nbb, self.mesh) else None
-        if vmesh is not None:
+        if vmesh is not None and not aligned:
             lanes = len(self.bl)
             if dist.broadcast_over_budget(
                     nbb * max(lanes, 1) * dist.COST_COLUMN_BYTES,
@@ -1903,6 +1931,8 @@ class _JoinNode:
         key -> build pos-table -> live?  The probe's pairs pass through
         untouched, so an entire Q5-style join chain with an interleaved
         semijoin stays ONE traced program."""
+        if self._aligned(ptv, btv):
+            return self._prepare_aligned(pb, btv, ptv)
         info = _prepare_build_key_info(self.build, self.build_key, pb,
                                        self.mesh)
         if info is None:
@@ -1956,6 +1986,60 @@ class _JoinNode:
                                (args[ip], args[fp]))
             return valid_out, _only(ppairs, live)
         return self._view(emit, nb, "semijoin", ptv, btv)
+
+    # ---- aligned: the build's rows are the probe's own, read in place ---
+
+    def _prepare_aligned(self, pb, btv, ptv) -> Optional[_TView]:
+        """A unique or semi join that :meth:`_aligned` proved aligned:
+        the build's validity and live lanes are read at the probe's own
+        rows.  A row matches where the build's row is valid and the
+        probe's is a real one (its key, NULL on no row, is NULL on the
+        padding), which is where the gathering join's lookup finds a
+        valid build row; the rest is that join's: a semi join keeps the
+        matched probe rows (an anti join the others), an inner join its
+        matched rows with the build's columns, an outer join every row
+        with them NULL where nothing matched.  Under a mesh each shard
+        reads its own row range of the build's lanes: a view by rows is
+        its own shard, one that lies whole is cut where it lies."""
+        from ..parallel import dist
+        nb = ptv.nb
+        self._mesh_view(nb, aligned=True)
+        mesh = self.mesh if dist.shardable(nb, self.mesh) else None
+        semi = self.tp in ("semi", "anti")
+        anti = self.tp == "anti"
+        outer = self.tp == "left"
+        scope = "semijoin" if semi else "join"
+        pk_slot = self.probe_key.index
+        self._key(pb, (scope, "aligned", self.tp, nb, pk_slot,
+                       self.probe_is_left, len(btv.meta), len(ptv.meta))
+                  + dist.layout_tag(_layouts(mesh)[0]))
+        bl, nbc, live = self.bl, len(btv.meta), self.live
+
+        def kernel(pkey, pvalid, bpairs, bvalid):
+            match = bvalid & ~pkey[1]
+            if semi:
+                return pvalid & (~match if anti else match), []
+            return (pvalid if outer else pvalid & match), \
+                self._gather_build(bpairs, None, match)
+
+        if mesh is not None:
+            shard_map, _ = dist.shard_map_fn()
+            ROWS, _whole = dist.specs()
+            kernel = shard_map(
+                kernel, mesh=mesh,
+                in_specs=((ROWS, ROWS), ROWS, [(ROWS, ROWS)] * len(bl),
+                          ROWS),
+                out_specs=(ROWS, [(ROWS, ROWS)] * len(bl)))
+
+        def emit(args):
+            bvalid, bpairs = btv.emit(args)
+            pvalid, ppairs = ptv.emit(args)
+            valid_out, bcols = kernel(ppairs[pk_slot], pvalid,
+                                      [bpairs[i] for i in bl], bvalid)
+            if semi:
+                return valid_out, _only(ppairs, live)
+            return valid_out, self._out(ppairs, bcols, nbc)
+        return self._view(emit, nb, scope, ptv, btv)
 
     # ---- multi-key unique build: composite lane + dense table ----------
 
@@ -2246,6 +2330,8 @@ class _JoinNode:
         return self._view(emit, n * n * capp, "joinshuf", ptv, btv)
 
     def _prepare_unique(self, pb, btv, ptv) -> Optional[_TView]:
+        if self._aligned(ptv, btv):
+            return self._prepare_aligned(pb, btv, ptv)
         from ..parallel import dist as _dist
         if self._shuffle_wanted(ptv.nb, btv.nb, len(btv.meta),
                                 self.mesh if _dist.shardable(ptv.nb,
@@ -3140,38 +3226,36 @@ def _has_build_key_info(node, build_key) -> bool:
     return False
 
 
-def _prepare_build_key_info(node, build_key, pb: _PipeBuilder, mesh=None):
-    """(lo, hi, input index of the device pos-table, table length) mapping
-    build-key value -> build view row.  Under a mesh the table lies whole
-    on every device: each shard probes it with its own rows."""
-    lwhole = _layouts(mesh)[1]
+def _build_key_table(node, build_key):
+    """(replica, memo key of its device copy, lo, hi, host pos-table)
+    mapping build-key value -> build view row, or None: the table of the
+    replica whose rows the view's rows are (an aggregate's groups, a
+    leaf's rows, through selections, projections and the probe side of
+    probe-shaped joins)."""
+    from .tpu_executors import _slot_id
     if isinstance(node, _AggIndexNode):
         got = node.build_key_info()
         if got is None:
             return None
         lo, hi, tbl = got
-        rep = node.leaf.replica()
-        from .tpu_executors import _slot_id
         sids = (_slot_id(node.leaf.ex, node.key_cols[0].index),)
-        it = pb.lane(rep, ("gi_postable_dev", sids), lambda: tbl, lwhole)
-        return lo, hi, it, int(tbl.shape[0])
+        return node.leaf.replica(), ("gi_postable_dev", sids), lo, hi, tbl
     if isinstance(node, _SelNode):
-        return _prepare_build_key_info(node.child, build_key, pb, mesh)
+        return _build_key_table(node.child, build_key)
     if isinstance(node, _JoinNode):
         key = _view_build_key(node, build_key)
         if key is None:
             return None
-        return _prepare_build_key_info(node.probe, key, pb, mesh)
+        return _build_key_table(node.probe, key)
     if isinstance(node, _ProjNode):
         e = node.exprs[build_key.index]
         if not isinstance(e, ExprColumn):
             return None
-        return _prepare_build_key_info(node.child, e, pb, mesh)
+        return _build_key_table(node.child, e)
     if isinstance(node, _ReplicaLeaf):
         rep = node.replica()
         if rep is None:
             return None
-        from .tpu_executors import _slot_id
         sid = _slot_id(node.ex, build_key.index)
         if sid == "handle":
             kv, km = rep.handles, np.zeros(rep.n_rows, dtype=bool)
@@ -3180,10 +3264,60 @@ def _prepare_build_key_info(node, build_key, pb: _PipeBuilder, mesh=None):
         got = _rep_pos_table(rep, sid, kv, km)
         if got is None:
             return None
-        lo, hi, tbl = got
-        it = pb.lane(rep, ("postable_dev", sid), lambda: tbl, lwhole)
-        return lo, hi, it, int(tbl.shape[0])
+        return (rep, ("postable_dev", sid)) + got
     return None
+
+
+def _prepare_build_key_info(node, build_key, pb: _PipeBuilder, mesh=None):
+    """(lo, hi, input index of the device pos-table, table length) mapping
+    build-key value -> build view row.  Under a mesh the table lies whole
+    on every device: each shard probes it with its own rows."""
+    got = _build_key_table(node, build_key)
+    if got is None:
+        return None
+    rep, key, lo, hi, tbl = got
+    it = pb.lane(rep, key, lambda: tbl, _layouts(mesh)[1])
+    return lo, hi, it, int(tbl.shape[0])
+
+
+def _probe_rows(node, key):
+    """(replica leaf, its slot) where ``node``'s view holds the leaf's
+    rows in place, one for one, and ``key`` is the leaf's own column
+    there: through selections, projections that read it as it is and
+    the probe side of probe-shaped joins.  None otherwise."""
+    if isinstance(node, _ReplicaLeaf):
+        return node, key.index
+    if isinstance(node, _SelNode):
+        return _probe_rows(node.child, key)
+    if isinstance(node, _ProjNode):
+        e = node.exprs[key.index]
+        return _probe_rows(node.child, e) if isinstance(e, ExprColumn) \
+            else None
+    if isinstance(node, _JoinNode) and _probe_shaped(node):
+        side, at = _join_side_slot(node, key.index)
+        return _probe_rows(node.probe, _Slot(at)) if side is node.probe \
+            else None
+    return None
+
+
+def _aligned_rows(rep, sid, table) -> bool:
+    """The replica's key column ``sid`` (or its handle) holds no NULL and
+    the build's key -> row table (``_build_key_table``) read at row r's
+    key is r, for every row: observed once a version of both replicas."""
+    brep, bkey, lo, hi, tbl = table
+
+    def check():
+        if sid == "handle":
+            kv = rep.handles
+        else:
+            kv, km = rep.columns[sid]
+            if km.any():
+                return False
+        if not bool(((kv >= lo) & (kv <= hi)).all()):
+            return False
+        return bool((tbl[kv - lo] == np.arange(len(kv))).all())
+    return rep.memo(("aligned", sid, brep.table_id, brep.data_version)
+                    + bkey, check)
 
 
 def _composite_spec(cols):

@@ -79,6 +79,12 @@ _DEVICE_METRICS = {
                          "Of those joins, the ones whose build side is a "
                          "view (a selection's, a join's, an aggregate's) "
                          "and not a base table"),
+    "pipe_joins_aligned": ("tinysql_pipe_joins_aligned_total",
+                           "Of those joins, the ones whose build rows are "
+                           "the probe's own rows in the same order (a "
+                           "unique or semi join the replicas prove "
+                           "aligned), traced to read the build's lanes "
+                           "in place: no key lookup, no gather"),
     "agg_key_cut": ("tinysql_agg_key_cut_total",
                     "Fused GROUP BYs above a join chain formed on the one "
                     "key (a table's primary key) that determines the "

@@ -243,6 +243,10 @@ def _device_info(st) -> str:
         parts.append(f"joins:{int(d['pipe_joins'])}"
                      f"/{int(d.get('pipe_view_builds', 0))}view"
                      f"{mesh_views}")
+    if d.get("pipe_joins_aligned"):
+        # joins whose build rows are the probe's own rows in the same
+        # order: the build's lanes read in place, no lookup or gather
+        parts.append(f"aligned:{int(d['pipe_joins_aligned'])}")
     if d.get("agg_key_cut"):
         parts.append(f"key_cut:{int(d['agg_key_cut'])}")
     if d.get("agg_key_mesh"):
