@@ -672,3 +672,179 @@ def test_tls_handoff_to_legacy_thread(tmp_path):
         pc.close()
     finally:
         srv.close()
+
+
+# =========================================================================
+# a burst over many parked connections (the benchmark's idle_1k_burst,
+# small): every answer equals legacy's and numpy's; the queue's depth
+# decides between a wait and 1041; the loop's counters and aio.tick
+# =========================================================================
+
+BURST = 256
+Q6_SHAPED = ("select sum(price * disc) from li where ship >= '{y}-01-01' "
+             "and ship < '{y1}-01-01' and disc between {lo} and {hi} "
+             "and qty < 24")
+
+
+@pytest.fixture(scope="module")
+def burst_server():
+    """A server of its own (its front end's counters start at 0) over a
+    small Q6-shaped table, and the table as numpy arrays."""
+    import numpy as np
+    rng = np.random.default_rng(42)
+    n = 2000
+    price = np.round(rng.uniform(900, 100000, n), 2)
+    disc = rng.integers(0, 11, n) / 100
+    qty = rng.integers(1, 51, n)
+    day = rng.integers(0, 7 * 365, n)
+    ship = (np.datetime64("1992-01-01") + day).astype(str)
+    storage = new_mock_storage()
+    srv = Server(storage, port=0)
+    srv.start()
+    boot = Session(storage)
+    boot.execute("create database if not exists bq")
+    boot.execute("use bq")
+    boot.execute("create table li (id int primary key, price double, "
+                 "disc double, qty int, ship varchar(10))")
+    boot.execute("insert into li values " + ", ".join(
+        f"({i}, {float(price[i])!r}, {float(disc[i])!r}, {int(qty[i])}, "
+        f"'{ship[i]}')"
+        for i in range(n)))
+    boot.execute("set global tidb_tpu_min_rows = 16")
+    boot.execute("select sum(price) from li")  # hydrate the replica
+    yield srv, {"price": price, "disc": disc, "qty": qty, "ship": ship}
+    srv.close()
+
+
+def _q6(i):
+    """The i-th of 16 variants and its parameters."""
+    y, lo = 1993 + i % 2, (i // 2) % 8 / 100
+    return Q6_SHAPED.format(y=y, y1=y + 1, lo=f"{lo:.2f}",
+                            hi=f"{lo + 0.02:.2f}"), (y, lo)
+
+
+def _numpy_q6(t, y, lo):
+    m = ((t["ship"] >= f"{y}-01-01") & (t["ship"] < f"{y + 1}-01-01")
+         & (t["disc"] >= lo - 1e-9) & (t["disc"] <= lo + 0.02 + 1e-9)
+         & (t["qty"] < 24))
+    return float((t["price"][m] * t["disc"][m]).sum())
+
+
+def _burst(port, sqls):
+    """One burst: a connection for each statement, opened and parked,
+    then every statement sent before any answer is read.  Each answer's
+    rows, or the error's text."""
+    clients = [MiniClient(port, db="bq") for _ in sqls]
+    try:
+        for c, sql in zip(clients, sqls):
+            c.sock.settimeout(60)
+            c.send_query(sql)
+        out = []
+        for c in clients:
+            try:
+                out.append(c.read_result()[1])
+            except RuntimeError as e:
+                out.append(str(e))
+        return out
+    finally:
+        for c in clients:
+            c.close()
+
+
+def test_burst_over_parked_connections_equals_legacy_and_numpy(
+        burst_server):
+    from tinysql_tpu.obs import trace
+    srv, table = burst_server
+    boot = _sess(srv, db="bq")
+    boot.execute(f"set global tidb_stmt_pool_queue_depth = {BURST}")
+    sqls, params = zip(*(_q6(i) for i in range(BURST)))
+    boot.execute("set global tidb_wire_mode = 'legacy'")
+    got = {"legacy": _burst(srv.port, sqls)}
+    boot.execute("set global tidb_wire_mode = 'aio'")
+    before = trace.totals()
+    got["aio"] = _burst(srv.port, sqls)
+    after = trace.totals()
+    assert got["aio"] == got["legacy"]
+    for rows, (y, lo) in zip(got["aio"], params):
+        assert rows == [[rows[0][0]]], rows  # an answer, not an error
+        assert float(rows[0][0]) == pytest.approx(_numpy_q6(table, y, lo),
+                                                  rel=1e-12)
+    snap = srv._aio.snapshot()
+    assert snap["submits"] == BURST and snap["conns_max"] >= BURST
+
+    def grew(name, key="count"):
+        return after.get(name, {}).get(key, 0) \
+            - before.get(name, {}).get(key, 0)
+    assert grew("aio_submits") == BURST and grew("aio_conns_max") >= BURST
+    assert BURST <= grew("aio_events") <= snap["events"]
+    # the loop's passes: their children's time is not their own
+    assert grew("aio.tick") > 0
+    assert 0 < grew("aio.tick", "self_s") < grew("aio.tick", "sum_s")
+    from tinysql_tpu.obs.metrics import render_prometheus
+    scraped = render_prometheus()
+    for name in ("tinysql_aio_events_total", "tinysql_aio_submits_total",
+                 "tinysql_aio_flush_waits_total", "tinysql_aio_conns_max"):
+        assert f"\n{name} " in scraped, name
+
+
+def test_a_parked_connections_command_is_a_tick_s_child(burst_server):
+    """A command the loop handles inline is a child of the pass that
+    handled it."""
+    from tinysql_tpu.obs.trace import PROCESS
+    srv, _ = burst_server
+    _sess(srv, db="").execute("set global tidb_wire_mode = 'aio'")
+    c = MiniClient(srv.port, db="bq")
+    c.io.reset_sequence()
+    c.io.write_packet(b"\x0e")  # COM_PING
+    assert c.io.read_packet()[0] == 0x00
+    cid = max(srv.conns)
+    c.close()
+
+    def pings():
+        return [s for s in PROCESS.spans() if s["name"] == "wire.command"
+                and s["args"] == {"cmd": 0x0e, "conn": cid}]
+    until(pings, "the ping's command span ended")
+    (ping,) = pings()
+    # a span reaches the ring as it ends, the tick after its command
+    until(lambda: any(s["id"] == ping["parent"] and s["name"] == "aio.tick"
+                      for s in PROCESS.spans()),
+          "the ping's parent is the aio.tick that handled it")
+
+
+@pytest.mark.parametrize("depth, shed", [(BURST, 0), (64, BURST - 64)])
+def test_burst_waits_in_a_queue_as_deep_as_itself(burst_server, depth,
+                                                  shed):
+    """With the one worker wedged, a burst waits whole in a queue at
+    least as deep as itself and is answered whole; in a shallower one
+    every statement past its depth is shed with 1041."""
+    srv, table = burst_server
+    boot = _sess(srv, db="bq")
+    boot.execute("set global tidb_wire_mode = 'aio'")
+    boot.execute(f"set global tidb_stmt_pool_queue_depth = {depth}")
+    boot.execute("set global tidb_stmt_pool_size = 1")
+    wedge = MiniClient(srv.port, db="bq")
+    try:
+        fail.arm("admissionDelay", sleep=2.0, times=1)
+        box = []
+        t = threading.Thread(target=lambda: box.append(
+            wedge.query(_q6(0)[0])))
+        t.start()
+        hit("admissionDelay")  # the worker holds the wedge's statement
+        sqls = [_q6(i)[0] for i in range(BURST)]
+        got = _burst(srv.port, sqls)
+        t.join(60)
+        assert len(box) == 1
+    finally:
+        fail.disarm("admissionDelay")
+        boot.execute("set global tidb_stmt_pool_size = 4")
+        boot.execute(f"set global tidb_stmt_pool_queue_depth = {BURST}")
+        wedge.close()
+    errors = [g for g in got if isinstance(g, str)]
+    assert len(errors) == shed
+    assert all("1041" in e for e in errors)
+    answered = [(g, _q6(i)[1]) for i, g in enumerate(got)
+                if not isinstance(g, str)]
+    assert len(answered) == BURST - shed
+    for rows, (y, lo) in answered:
+        assert float(rows[0][0]) == pytest.approx(_numpy_q6(table, y, lo),
+                                                  rel=1e-12)

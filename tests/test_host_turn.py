@@ -193,6 +193,8 @@ def test_a_connections_time_is_idle_plus_commands(tpch_server, mode,
     # neither of them
     seen = set(profiler_stub.names)
     assert "tinysql/wire.write" in seen and "tinysql/solo" in seen
+    # (an event loop's COM_QUERY in its two stretches on the loop)
+    assert "tinysql/wire.command" in seen
     assert "tinysql/wire.idle" not in seen
     assert "tinysql/pool.wake" not in seen
     # the totals grew by what the ring holds
@@ -211,16 +213,12 @@ def test_a_commands_time_is_its_children_and_its_self_time(tpch_server,
         return _grown(before, after, name, key)
     # wire.command = wire.parse + pool.wait + wire.write + its self time
     # (an event loop's pool.wait is backdated at completion: no thread
-    # waited, and the command's self time keeps it)
-    children = grew("wire.parse") + grew("wire.write")
-    if mode == "legacy":
-        children += grew("pool.wait")
-    else:  # ... less the loop's own pool.submit, a child, inside it
-        children += grew("pool.submit")
-        assert grew("wire.command", "self_s") + grew("pool.submit") \
-            >= grew("pool.wait")
+    # waited, but it is the command's child time as the live one of a
+    # connection thread is, the loop's own pool.submit inside it)
+    children = grew("wire.parse") + grew("wire.write") + grew("pool.wait")
     assert grew("wire.command") == pytest.approx(
         children + grew("wire.command", "self_s"), abs=1e-9)
+    assert 0 <= grew("wire.command", "self_s") < grew("pool.wait")
     # pool.wait >= pool.submit + queue_wait + the worker's span +
     # pool.wake, statement by statement: they follow one another inside
     # it.  A batchable family's leader runs in a round of one
@@ -229,8 +227,10 @@ def test_a_commands_time_is_its_children_and_its_self_time(tpch_server,
     assert len(solos) == N
     traces = {}
     for t in recent_traces():
+        # a worker's statements (the handshake's USE runs under the
+        # event loop's aio.tick)
         execute = [s for s in t["spans"] if s["name"] == "execute"]
-        if execute and execute[0]["parent"] in by_id:
+        if execute and execute[0]["parent"] in {s["id"] for s in solos}:
             traces[execute[0]["parent"]] = t["spans"]
     rounds = 0
     for solo in solos:
@@ -520,9 +520,10 @@ def test_the_new_entries_are_the_files_last(bench):
         # the cells PR 37 listed come first and as they were; a later
         # cell that reports the quantity is appended (PR 39's four-chip
         # joins cell to the twelve entries that list the joins cell)
+        # and the burst on the event-loop front end to every ``.serve``
         assert m["workloads"][:len(pinned)] == pinned
         if m["name"].endswith(".serve"):
-            assert m["workloads"] == pinned
+            assert m["workloads"] == pinned + ["tpch_sf1_aio.idle_1k_burst"]
     cells = {w["name"] for w in bench["workloads"]}
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     layers = {m["layer"] for m in bench["per_layer"][:-len(NEW_ENTRIES)]}

@@ -67,8 +67,15 @@ class MiniClient:
 
     def query(self, sql):
         """Returns (columns, rows) for resultsets, or affected count."""
+        self.send_query(sql)
+        return self.read_result()
+
+    def send_query(self, sql):
+        """The COM_QUERY alone: its answer is ``read_result``'s."""
         self.io.reset_sequence()
         self.io.write_packet(b"\x03" + sql.encode())
+
+    def read_result(self):
         first = self.io.read_packet()
         if first[0] == 0x00:  # OK
             affected, _ = read_lenenc_int(first, 1)
@@ -708,3 +715,73 @@ def test_plain_kill_drops_connection(server):
             dead = True
     assert dead, "plain KILL did not drop the victim connection"
     c2.close()
+
+
+# ---- open files: the soft limit at start, EMFILE at accept ---------------
+
+@pytest.mark.parametrize("soft, hard, want", [
+    (256, 4096, [(4096, 4096)]),
+    (1024, -1, [(1 << 20, -1)]),  # RLIM_INFINITY: the kernel's ceiling
+    (4096, 4096, []),
+])
+def test_start_raises_the_soft_open_file_limit(monkeypatch, soft, hard,
+                                               want):
+    """A server meant for a thousand connections lifts its soft
+    open-file limit to the hard one at start, and leaves a limit that is
+    already there alone."""
+    import resource
+    hard = resource.RLIM_INFINITY if hard == -1 else hard
+    want = [(w, resource.RLIM_INFINITY if h == -1 else h) for w, h in want]
+    calls = []
+    monkeypatch.setattr(resource, "getrlimit", lambda which: (soft, hard))
+    monkeypatch.setattr(resource, "setrlimit",
+                        lambda which, limits: calls.append(limits))
+    srv = Server(new_mock_storage(), port=0)
+    srv.start()
+    srv.close()
+    assert calls == want
+
+
+def test_accept_loop_survives_emfile():
+    """Out of files at accept is transient: the loop backs off and
+    accepts again, and the next connection is served; it ends only when
+    the server closes its socket."""
+    import errno
+    import threading
+    srv = Server(new_mock_storage(), port=0)
+    others = set(threading.enumerate())
+    srv.start()
+    (accept,) = [t for t in threading.enumerate()
+                 if t.name == "mysql-accept" and t not in others]
+    real = srv.sock
+
+    class Flaky:
+        """The listening socket, whose next accept fails once."""
+        raised = 0
+
+        def accept(self):
+            if not self.raised:
+                self.raised += 1
+                raise OSError(errno.EMFILE, "Too many open files")
+            return real.accept()
+
+        def fileno(self):
+            return real.fileno()
+
+        def shutdown(self, how):
+            real.shutdown(how)
+
+        def close(self):
+            real.close()
+
+    flaky = srv.sock = Flaky()
+    first = MiniClient(srv.port)   # the accept in flight was the real one
+    second = MiniClient(srv.port)  # after the EMFILE
+    assert flaky.raised == 1
+    assert second.query("select 1 + 1")[1] == [["2"]]
+    first.close()
+    second.close()
+    srv.close()
+    from _timelimit import until
+    until(lambda: not accept.is_alive(),
+          "the accept loop ended with its socket")

@@ -304,6 +304,17 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "tinysql_conn_sheds_total":
         ("counter", "Connects refused with MySQL 1040 at accept "
                     "(tidb_max_server_connections)"),
+    "tinysql_aio_events_total":
+        ("counter", "Ready socket events the aio event loops handled"),
+    "tinysql_aio_submits_total":
+        ("counter", "Statements the aio event loops handed to the "
+                    "statement pool"),
+    "tinysql_aio_flush_waits_total":
+        ("counter", "aio flushes that met EAGAIN and waited for the "
+                    "socket to be writable"),
+    "tinysql_aio_conns_max":
+        ("gauge", "The most connections parked at once on an aio event "
+                  "loop, summed over the loops"),
     # histograms / debug surfaces
     "tinysql_stmt_phase_seconds":
         ("histogram", "Statement latency by phase (statement summary "
@@ -862,6 +873,16 @@ def render_prometheus() -> str:
         for key in ("open", "idle", "active"):
             name = f"tinysql_conn_{key}"
             emit(name, METRICS[name][1], "gauge", [((), cg[key])])
+    try:
+        from ..server.server import aio_counters
+        ac = aio_counters()
+    except Exception:
+        ac = None
+    if ac is not None:  # a server has started its event-loop front end
+        for key, value in ac.items():
+            name = f"tinysql_aio_{key}" + ("" if key == "conns_max"
+                                           else "_total")
+            emit(name, METRICS[name][1], METRICS[name][0], [((), value)])
     try:
         from ..server.pool import gauges as pool_gauges
         pg = pool_gauges()

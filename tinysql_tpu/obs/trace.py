@@ -39,6 +39,7 @@ N, default 32); the ``PROCESS`` tracer keeps the last
 """
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import gc
 import itertools
@@ -72,6 +73,20 @@ def _annotate(name: str, **stats):
     live = ann("tinysql/" + name, **stats)
     live.__enter__()
     return live
+
+
+@contextlib.contextmanager
+def stretch(span: "Span"):
+    """One stretch of a held span (one begun with ``annotate=False``)
+    that this thread runs whole: annotated on the profiler's clock under
+    the span's own id, as a live span is (an event loop's command, in
+    the ticks that do its work).  With no session open, one flag test."""
+    ann = _annotate(span.name, span=span.sid, parent=span.parent or 0)
+    try:
+        yield span
+    finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
 
 #: the live span of this context: the parent of the next one begun in
@@ -151,14 +166,22 @@ _TOTALS: Dict[int, Dict[str, list]] = {}
 _GC = [0, 0.0, 0.0]
 
 
-def _count(tid: int, name: str, dur_s: float, self_s: float) -> None:
-    """On the thread ``tid`` only."""
+def _row(tid: int, name: str) -> list:
+    """The row of ``name`` in the table of the thread ``tid``, which
+    only that thread writes."""
     mine = _TOTALS.get(tid)
     if mine is None:
+        # qlint: disable=CC701 -- one GIL-atomic insert of the thread's own table under its own ident; no other thread writes that table
         mine = _TOTALS.setdefault(tid, {})
     t = mine.get(name)
     if t is None:
         t = mine[name] = [0, 0.0, 0.0, 0.0]
+    return t
+
+
+def _count(tid: int, name: str, dur_s: float, self_s: float) -> None:
+    """On the thread ``tid`` only."""
+    t = _row(tid, name)
     t[0] += 1
     t[1] += dur_s
     t[2] += self_s
@@ -166,10 +189,18 @@ def _count(tid: int, name: str, dur_s: float, self_s: float) -> None:
         t[3] = dur_s
 
 
+def count(name: str, n: int = 1) -> None:
+    """A counter in the totals table: ``n`` more of ``name``, no time
+    (the event loop's ``aio_*`` counts).  On the calling thread's table,
+    as a span's end is."""
+    _row(threading.get_ident(), name)[0] += n
+
+
 def totals() -> Dict[str, dict]:
     """A copy of the table: every span that has ended since the process
-    began, by name, plus jax's own program-load phases (``jax.trace``,
-    ``jax.lower``, ``jax.backend_compile``) and the collector (``gc``)."""
+    began, by name, the counters ``count`` adds (a count and no time),
+    plus jax's own program-load phases (``jax.trace``, ``jax.lower``,
+    ``jax.backend_compile``) and the collector (``gc``)."""
     rows: Dict[str, list] = {}
     for mine in list(_TOTALS.values()):
         for name, t in list(mine.items()):

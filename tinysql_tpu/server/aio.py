@@ -44,6 +44,13 @@ loop-thread-confined — no per-connection locks):
   connection drops after the in-flight command's response (plain-KILL
   parity with the legacy loop).
 
+What the loop does is traced (docs/OBSERVABILITY.md): each pass that
+``select`` woke is an ``aio.tick`` span, the commands it handled its
+children, so its self time is the loop's own work; an async COM_QUERY's
+two stretches on the loop are annotated as its ``wire.command`` on the
+profiler's clock; the totals count ``aio_events``, ``aio_submits``,
+``aio_flush_waits`` and ``aio_conns_max``.
+
 Every serving invariant survives the hop: sessions register in the
 conn-id/process registries at adoption (``processlist`` shows parked
 connections as Sleep rows), ``server.conns`` carries the ClientConn for
@@ -52,6 +59,7 @@ thread-per-connection path (tests/test_aio.py).
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import selectors
@@ -62,6 +70,7 @@ from collections import deque
 from typing import Dict, List, Optional
 
 from ..obs import context as obs_context
+from ..obs import trace as obs_trace
 from ..utils import interrupt
 from ..utils.interrupt import QueryKilled
 from . import protocol as p
@@ -83,6 +92,10 @@ _TICK_S = 0.1
 #: this, one slow-reading client pipelining large resultsets grows
 #: server memory without bound
 WBUF_HWM = 1 << 20
+
+#: a loop's counters, summed over the loops by ``AioFrontEnd.snapshot``
+#: (``tinysql_aio_*`` on /metrics)
+COUNTERS = ("events", "submits", "flush_waits", "conns_max")
 
 
 class _ConnWriter:
@@ -109,7 +122,7 @@ class _AioConn:
 
     __slots__ = ("cc", "sock", "salt", "state", "rbuf", "wbuf", "parts",
                  "last_rx", "stmts", "idx", "sql", "entry", "events",
-                 "pumping", "span", "submitted", "flushed")
+                 "pumping", "span", "submitted", "waited", "flushed")
 
     def __init__(self, cc: ClientConn):
         self.cc = cc
@@ -132,6 +145,8 @@ class _AioConn:
         #: and not on the thread's span stack
         self.span = None
         self.submitted = 0.0  # perf_counter at the in-flight submit
+        #: the command span's child time at that submit
+        self.waited = 0.0
         #: where the last command's span ended (``wire.idle`` runs from
         #: there to the next command's packet), None before the first
         self.flushed = None
@@ -155,6 +170,18 @@ class _Loop:
         self.conns: Dict[int, _AioConn] = {}
         self._closed = False
         self._last_tick = 0.0
+        #: the ``aio.tick`` span of the pass under way, None between
+        self.tick = None
+        # the counters (loop-thread writes only; ``AioFrontEnd.snapshot``
+        # reads them): ready socket events handled, statements handed to
+        # the pool, flushes that met EAGAIN and waited for writability,
+        # the most connections parked at once
+        self.events = 0
+        self.submits = 0
+        self.flush_waits = 0
+        self.conns_max = 0
+        #: the most parked at once since the loop last stood empty
+        self._peak = 0
         self.thread = threading.Thread(target=self._run, daemon=True,
                                        name=f"aio-loop-{idx}")
 
@@ -196,20 +223,16 @@ class _Loop:
                 break
             if self._closed:
                 break
-            self._drain_inbox()
-            for key, mask in events:
-                if key.data is None:
-                    try:
-                        os.read(self._wake_r, 4096)
-                    except (BlockingIOError, OSError):
-                        pass
-                    continue
-                conn = key.data
-                if mask & selectors.EVENT_WRITE and conn.state != "closed":
-                    self._flush(conn)
-                if mask & selectors.EVENT_READ and conn.state != "closed":
-                    self._on_readable(conn)
-            self._tick()
+            if not events and not self._inbox:  # the paced sweep alone
+                self._tick()
+                continue
+            # a pass that select woke: one ``aio.tick``, whose children
+            # are the commands it handled, so its self time is the
+            # loop's own work (readiness, framing, adoption, the sweep)
+            self.tick = obs_context.PROCESS.begin("aio.tick", cat="wire")
+            with self.tick:
+                self._pass(events)
+            self.tick = None
         # drain: handle completions already posted, close every parked
         # connection (rollback + deregister), then drain once more —
         # closing a connection with an in-flight entry cancels it, and
@@ -228,6 +251,45 @@ class _Loop:
                 os.close(fd)
             except OSError:
                 pass
+
+    def _pass(self, events) -> None:
+        ready = [(key.data, mask) for key, mask in events
+                 if key.data is not None]
+        if len(ready) < len(events):
+            # the wake pipe is read before the inbox is drained: an item
+            # posted after the read leaves its own wake for the next
+            # select, and none waits in the inbox for a timeout
+            try:
+                os.read(self._wake_r, 4096)
+            except (BlockingIOError, OSError):
+                pass
+        self._drain_inbox()
+        for conn, mask in ready:
+            if mask & selectors.EVENT_WRITE and conn.state != "closed":
+                self._flush(conn)
+            if mask & selectors.EVENT_READ and conn.state != "closed":
+                self._on_readable(conn)
+        if ready:
+            self.events += len(ready)
+            obs_trace.count("aio_events", len(ready))
+        self._tick()
+
+    @contextlib.contextmanager
+    def _leg(self, conn: _AioConn, t0: float):
+        """One stretch of an async COM_QUERY on the loop (its packet's
+        parse and submit; its answer's encode and flush), begun at
+        ``t0``: under the command's span, annotated as it on the
+        profiler's clock, and the current tick's child time, as a
+        nested span's would be (the command itself outlives the tick)."""
+        tick = self.tick
+        child_s = tick.child_s if tick is not None else 0.0
+        try:
+            with obs_context.under(conn.span), \
+                    obs_trace.stretch(conn.span):
+                yield
+        finally:
+            if tick is not None:
+                tick.child_s = child_s + time.perf_counter() - t0
 
     def _drain_inbox(self) -> None:
         while True:
@@ -298,6 +360,14 @@ class _Loop:
             return
         conn.events = selectors.EVENT_READ
         self.conns[cc.conn_id] = conn
+        n = len(self.conns)
+        self.conns_max = max(self.conns_max, n)
+        if n > self._peak:
+            # in the totals the gauge's count rises with each new high
+            # since the loop last stood empty: over a window that opens
+            # on an empty loop it grows by the most parked at once
+            self._peak = n
+            obs_trace.count("aio_conns_max")
         self._flush(conn)
 
     def _discard(self, conn: _AioConn) -> None:
@@ -317,7 +387,7 @@ class _Loop:
             self.sel.unregister(conn.sock)
         except (KeyError, ValueError, OSError):
             pass
-        self.conns.pop(conn.cc.conn_id, None)
+        self._forget(conn)
         # deregister BEFORE the peer-visible close: the moment close()
         # sends FIN a client can observe the drop and ask the server
         # about this conn_id (processlist, the KILL-idle acceptance
@@ -343,6 +413,11 @@ class _Loop:
             return
         self._finalize_session(conn)
 
+    def _forget(self, conn: _AioConn) -> None:
+        self.conns.pop(conn.cc.conn_id, None)
+        if not self.conns:
+            self._peak = 0
+
     def _finalize_session(self, conn: _AioConn) -> None:
         try:
             conn.cc.session.rollback_txn()
@@ -359,7 +434,10 @@ class _Loop:
         except (KeyError, ValueError, OSError):
             self._close_conn(conn)
 
-    def _flush(self, conn: _AioConn) -> None:
+    def _flush(self, conn: _AioConn, pump: bool = True) -> None:
+        """Send what the buffer holds, as far as the socket takes it;
+        once it is empty, resume the commands parked behind the
+        high-water mark (``pump``)."""
         if conn.state == "closed":
             return
         while conn.wbuf:
@@ -374,6 +452,9 @@ class _Loop:
                 break
             del conn.wbuf[:n]
         if conn.wbuf:
+            if not conn.events & selectors.EVENT_WRITE:
+                self.flush_waits += 1  # EAGAIN: wait for writability
+                obs_trace.count("aio_flush_waits")
             # backpressure: past the high-water mark stop READING from
             # the peer too — a client that won't drain its responses
             # must not keep feeding the server new commands
@@ -384,7 +465,7 @@ class _Loop:
             self._set_events(conn, selectors.EVENT_READ)
             if conn.state == "closing":
                 self._close_conn(conn)
-            elif conn.state == "ready" and conn.rbuf \
+            elif pump and conn.state == "ready" and conn.rbuf \
                     and not conn.pumping:
                 # commands parked behind the high-water mark resume
                 # once the peer drained the buffer
@@ -490,7 +571,7 @@ class _Loop:
             self.sel.unregister(conn.sock)
         except (KeyError, ValueError, OSError):
             pass
-        self.conns.pop(cc.conn_id, None)
+        self._forget(conn)
         conn.state = "closed"  # off the loop; the thread owns it now
         try:
             conn.sock.setblocking(True)
@@ -517,11 +598,12 @@ class _Loop:
             self._close_conn(conn)
             return
         if cmd == p.COM_QUERY:
+            t0 = time.perf_counter()
             conn.span = obs_context.PROCESS.begin(
                 "wire.command", cat="wire", annotate=False,
                 args={"cmd": cmd, "conn": cc.conn_id})
             record_idle(conn.flushed, conn.span, cc.conn_id)
-            with obs_context.under(conn.span):
+            with self._leg(conn, t0):
                 self._start_query(conn, body.decode("utf-8", "replace"))
             return
         command = obs_context.process_span("wire.command", cat="wire",
@@ -574,6 +656,7 @@ class _Loop:
             if pool.routes_to_pool(stmt):
                 try:
                     conn.submitted = time.perf_counter()
+                    conn.waited = conn.span.child_s
                     conn.entry = pool.submit(cc.session, stmt, label,
                                              on_done=self._done_cb(conn))
                 except Exception as e:  # 1041 shed / pool shutdown
@@ -581,6 +664,8 @@ class _Loop:
                     cc.io.write_packet(_err_packet_for(e))
                     self._finish_command(conn)
                     return
+                self.submits += 1
+                obs_trace.count("aio_submits")
                 return  # parked: _on_stmt_done resumes this driver
             try:
                 rs = pool.run(cc.session, stmt, label)
@@ -608,13 +693,17 @@ class _Loop:
             return  # connection closed mid-statement: drop the result
         conn.entry = None
         cc = conn.cc
-        # submit -> done was no thread's wait here: measured, not live
+        # submit -> done was no thread's wait here: measured, not live;
+        # the command's child time all the same (pool.submit's inside
+        # it), as a connection thread's live one is: ``wire.command``'s
+        # self time means one thing in both front ends
         now = time.perf_counter()
         record_wake(entry, obs_context.PROCESS.add_complete(
             "pool.wait", conn.submitted, now - conn.submitted,
             cat="serving", up=conn.span,
             args={"verdict": entry.verdict}), now)
-        with obs_context.under(conn.span):
+        conn.span.child_s = conn.waited + now - conn.submitted
+        with self._leg(conn, now):
             if entry.error is not None:
                 log.debug("query error: %s", entry.error)
                 cc.io.write_packet(_err_packet_for(entry.error))
@@ -639,10 +728,13 @@ class _Loop:
         if conn.state == "running":
             conn.state = "ready"
         self._after_command(conn)
-        self._flush(conn)
+        # the command's span ends once its answer is out, before the
+        # commands pipelined behind it begin theirs
+        self._flush(conn, pump=False)
         self._end_command_span(conn)
         if conn.state == "ready" and conn.rbuf:
-            with obs_context.under(None):  # the next command is no child
+            # the next command is the tick's child, not this one's
+            with obs_context.under(self.tick):
                 self._pump(conn)  # commands pipelined during execution
 
     def _on_kill(self, conn_id: int) -> None:
@@ -717,11 +809,17 @@ class AioFrontEnd:
             lp.post(("kill", conn_id))
 
     def snapshot(self) -> dict:
+        """Loops and parked connections, and the loops' counters summed:
+        ``events``, ``submits``, ``flush_waits`` and ``conns_max`` (the
+        most parked at once on each loop)."""
         with self._mu:
             loops = list(self._loops)
-        return {"loops": len(loops),
-                "conns": sum(len(lp.conns) for lp in loops),
-                "closed": self._closed}
+        out = {"loops": len(loops),
+               "conns": sum(len(lp.conns) for lp in loops),
+               "closed": self._closed}
+        for key in COUNTERS:
+            out[key] = sum(getattr(lp, key) for lp in loops)
+        return out
 
     def close(self) -> None:
         with self._mu:

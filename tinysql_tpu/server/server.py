@@ -8,7 +8,9 @@ One thread per connection (the per-connection-goroutine analogue, SURVEY
 """
 from __future__ import annotations
 
+import errno
 import logging
+import resource
 import socket
 import threading
 import time
@@ -50,6 +52,36 @@ def conn_gauges() -> dict:
             else:
                 out["idle"] += 1
     return out
+
+
+def aio_counters() -> Optional[dict]:
+    """The event-loop front ends' counters summed across live servers
+    (the ``tinysql_aio_*`` feed): None where no server has started one."""
+    with _SERVERS_MU:
+        servers = list(_SERVERS)
+    snaps = [fe.snapshot() for fe in (srv._aio for srv in servers)
+             if fe is not None]
+    if not snaps:
+        return None
+    from .aio import COUNTERS
+    return {key: sum(s[key] for s in snaps) for key in COUNTERS}
+
+
+def raise_open_files() -> None:
+    """Lift the soft open-file limit to the hard one, as mysqld does for
+    ``open_files_limit``: a server meant for a thousand connections
+    holds a socket for each, beside the files of its runtime, against a
+    soft limit that is 1024 by default.  Where the hard limit is
+    unlimited, to the kernel's usual ceiling of 2^20."""
+    soft, hard = resource.getrlimit(resource.RLIMIT_NOFILE)
+    want = hard if hard != resource.RLIM_INFINITY else 1 << 20
+    if soft != resource.RLIM_INFINITY and soft < want:
+        try:
+            resource.setrlimit(resource.RLIMIT_NOFILE, (want, hard))
+        except (ValueError, OSError) as e:
+            log.info("open-file limit left at %d: %s", soft, e)
+            return
+        log.info("open-file limit raised from %d to %d", soft, want)
 
 
 def parse_sql(sql: str) -> list:
@@ -497,6 +529,7 @@ class Server:
         """Bind + accept loop in a background thread; returns bound port."""
         from .auth import ensure_user_table
         ensure_user_table(self.storage)  # idempotent system-table bootstrap
+        raise_open_files()
         self.sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         self.sock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         self.sock.bind((self.host, self.port))
@@ -557,12 +590,27 @@ class Server:
         return fe
 
     def _accept_loop(self) -> None:
+        """Accepts until the server closes its socket.  Out of files
+        (``EMFILE`` / ``ENFILE``) or any other failed accept is
+        transient: the loop backs off and accepts again, and the
+        connection waits in the listen backlog meanwhile."""
         from . import admission
+        backoff = 0.0
         while not self._closed.is_set():
             try:
                 conn, addr = self.sock.accept()
-            except OSError:
-                return
+            except OSError as e:
+                if self._closed.is_set() or self.sock.fileno() < 0:
+                    return
+                if e.errno in (errno.EMFILE, errno.ENFILE) and not backoff:
+                    with self._mu:
+                        n_open = len(self.conns)
+                    log.warning("accept: %s; %d connections open, backing "
+                                "off", e, n_open)
+                backoff = min(max(2 * backoff, 0.01), 1.0)
+                self._closed.wait(backoff)
+                continue
+            backoff = 0.0
             cap = self._max_connections()
             with self._mu:
                 n_open = len(self.conns)
@@ -633,6 +681,12 @@ class Server:
         self.flight_writer.close()
         self.domain.close()
         if self.sock is not None:
+            # shutdown first: a close alone does not wake the accept
+            # blocked on the socket, and the accept loop ends with it
+            try:
+                self.sock.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass
             try:
                 self.sock.close()
             except OSError:
