@@ -330,15 +330,15 @@ def _all_live(monkeypatch):
     monkeypatch.setattr(devpipe.DevPipeExec, "_run_pipeline", run_all)
 
 
-def _gathers(text, rows):
+def _gathers(text, rows, dtype=r"\w+"):
     """The compiled text's gathers whose result is a lane of ``rows``
-    elements, counted by the pipeline node that traced them (the scopes
-    of ``_TView`` in the op_name: ``aggindex``, ``join/join`` for the
-    inner join, ``join`` for the outer)."""
+    elements (of ``dtype``: any), counted by the pipeline node that
+    traced them (the scopes of ``_TView`` in the op_name: ``aggindex``,
+    ``join/join`` for the inner join, ``join`` for the outer)."""
     import collections
     count = collections.Counter()
     for line in text.splitlines():
-        m = re.search(r"= \w+\[(\d+)\]\S* gather\(", line)
+        m = re.search(rf"= {dtype}\[(\d+)\]\S* gather\(", line)
         if not m or int(m.group(1)) != rows:
             continue
         scopes = re.search(r'op_name="([^"]*)"', line).group(1).split("/")
@@ -361,6 +361,20 @@ def _no_alignment(monkeypatch):
                         lambda self, ptv, btv: False)
 
 
+def _no_fold(monkeypatch):
+    """No join folds its build's validity into its key -> row table:
+    every one reads the validity at each of its probe's rows, as the
+    programs did before the fold."""
+    from tinysql_tpu.executor import devpipe
+    monkeypatch.setattr(devpipe._JoinNode, "_folded",
+                        lambda self, tbl_len, nb, mesh: False)
+
+
+#: ``customer``'s key -> row table at SF=0.05 (``c_custkey`` 1 to 7,500)
+CUSTOMER_TABLE = 7500
+
+
+@pytest.mark.parametrize("fold", ["on", "off"])
 @pytest.mark.parametrize("aligned", ["on", "off"])
 @pytest.mark.parametrize("live, expect", [
     # the benchmark's statement: the projection above the TopN reads
@@ -381,15 +395,28 @@ def _no_alignment(monkeypatch):
     ("all", {"aggindex": 4, "join/join": 6}),
 ])
 def test_q3_gathers_at_the_bucket(one_chip, chip_branches, monkeypatch,
-                                  tpch_session, live, expect, aligned):
+                                  tpch_session, live, expect, aligned, fold):
+    """The inner join's key -> row table (``customer``'s 7,500 keys)
+    holds under half of ``orders``' 2^17 rows: the join folds the
+    view's validity into it, and its ``bvalid[pos]`` at the bucket
+    becomes one ``pred`` gather of the table's length (``off``: as
+    before the fold).  The outer join's group table (75,000 keys) holds
+    more than half: its gathering form (``aligned`` off) does not fold."""
     if live == "all":
         _all_live(monkeypatch)
     if aligned == "off":
         _no_alignment(monkeypatch)
         expect = dict(expect, join=6)
+    if fold == "off":
+        _no_fold(monkeypatch)
+    else:
+        expect = dict(expect, **{"join/join": expect["join/join"] - 1})
     fn, abstract = _capture(monkeypatch, tpch_session, tpch.QUERIES["Q3"],
                             one_chip)
-    assert _gathers(_compile(fn, *abstract).as_text(), Q3_BUCKET) == expect
+    text = _compile(fn, *abstract).as_text()
+    assert _gathers(text, Q3_BUCKET) == expect
+    assert _gathers(text, CUSTOMER_TABLE, "pred") == \
+        ({"join/join": 1} if fold == "on" else {})
 
 
 #: a chip's quarter of ``lineitem``'s bucket holds a quarter of the
@@ -410,13 +437,14 @@ def _collectives(text, kind, rows):
     return out
 
 
+@pytest.mark.parametrize("fold", ["on", "off"])
 @pytest.mark.parametrize("aligned", ["on", "off"])
 @pytest.mark.parametrize("live, quarter", [
     ("consumer", {"join/join": 2}),
     ("all", {"join/join": 6}),
 ])
 def test_q3_mesh_gathers_a_chip(topo, chip_branches, monkeypatch,
-                                tpch_session, live, quarter, aligned):
+                                tpch_session, live, quarter, aligned, fold):
     """Under the mesh every chip bounds its own span of the groups (four
     gathers at the span's pad, none at the whole table's bucket), the
     pieces cross as all-gathers and no whole table is summed over the
@@ -428,11 +456,19 @@ def test_q3_mesh_gathers_a_chip(topo, chip_branches, monkeypatch,
     if aligned == "off":
         _no_alignment(monkeypatch)
         quarter = dict(quarter, join=6)
+    # a chip's 2^15 rows of orders against customer's 7,500 keys: the
+    # inner join folds, each chip its own copy of the whole table
+    if fold == "off":
+        _no_fold(monkeypatch)
+    else:
+        quarter = dict(quarter, **{"join/join": quarter["join/join"] - 1})
     text = _compile_mesh_statement(topo, monkeypatch, tpch_session,
                                    tpch.QUERIES["Q3"])
     assert _gathers(text, Q3_BUCKET) == {}
     assert _gathers(text, Q3_SPAN_PAD) == {"aggindex": 4}
     assert _gathers(text, Q3_BUCKET // 4) == quarter
+    assert _gathers(text, CUSTOMER_TABLE, "pred") == \
+        ({"join/join": 1} if fold == "on" else {})
     # presence and revenue: two 32-bit halves each, a piece a chip
     assert len(_collectives(text, "all-gather", 4 * Q3_SPAN_PAD)) == 4
     assert not _collectives(text, "all-gather", 4 * Q3_BUCKET)
@@ -486,10 +522,12 @@ def test_lowered_text_is_the_parents(topo, one_chip, chip_branches,
     were and pinned Q3's mesh program anew, with the flags and without:
     a chip bounds its own span of the groups, and the pieces are
     gathered and added into place where whole tables were summed.  With
-    no join aligned (PR 40's fallback) all twelve still do."""
+    no join aligned (the gathering join's path) all twelve still do.
+    So they do with no join folded."""
     if flags == "off":
         _no_flags(monkeypatch)
     _no_alignment(monkeypatch)
+    _no_fold(monkeypatch)
     case = f"{name}.{where}"
     if case == "Q3.mesh":
         pinned = _pinned("lowered_at_pr38.json")[f"{case}.{flags}"]
@@ -510,9 +548,11 @@ def test_aligned_programs_lower_to_the_text_pinned_at_pr40(
     """With the aligned joins (PR 40) Q3's, Q10's and Q18's programs
     lower to the text pinned with PR 40, with the NULL-freedom flags and
     without; Q5, whose joins are many-to-one or into another table's
-    order, and Q1 and Q6, which have none, to their parents' text."""
+    order, and Q1 and Q6, which have none, to their parents' text.
+    That holds with no join folded."""
     if flags == "off":
         _no_flags(monkeypatch)
+    _no_fold(monkeypatch)
     case = f"{name}.{where}.{flags}"
     if name in ("Q3", "Q10", "Q18"):
         pinned = _pinned("lowered_at_pr40.json")[case]
@@ -523,6 +563,31 @@ def test_aligned_programs_lower_to_the_text_pinned_at_pr40(
     sql = tpch.ALL_QUERIES[name]
     assert _lowered(topo, one_chip, monkeypatch, tpch_session, sql,
                     where) == pinned
+
+
+@pytest.mark.parametrize("flags", ["off", "on"])
+@pytest.mark.parametrize("name, where", [
+    ("Q3", "one"), ("Q3", "mesh"), ("Q5", "one"), ("Q5", "mesh"),
+    ("Q10", "one"), ("Q18", "one"), ("Q1", "one"), ("Q6", "mesh")])
+def test_folded_programs_lower_to_the_pinned_text(
+        topo, one_chip, chip_branches, monkeypatch, request, tpch_session,
+        name, where, flags):
+    """With the joins whose key -> row table holds at most half the
+    probe's rows on a device folding the build's validity into it, the
+    programs of Q3, Q5, Q10 and Q18 lower to the text pinned in
+    ``lowered_folded.json``, with the NULL-freedom flags and without
+    (over the mesh as the four-chip cells price it); Q1 and Q6, which
+    join nothing, to their parents' text."""
+    if where == "mesh":
+        request.getfixturevalue("four_devices")
+    if flags == "off":
+        _no_flags(monkeypatch)
+    if name in ("Q1", "Q6"):
+        pinned = _pinned("lowered_at_129247b.json")[f"{name}.{where}"]
+    else:
+        pinned = _pinned("lowered_folded.json")[f"{name}.{where}.{flags}"]
+    assert _lowered(topo, one_chip, monkeypatch, tpch_session,
+                    tpch.ALL_QUERIES[name], where) == pinned
 
 
 # ---- the join chains: Q5, Q10, Q18 as one fused program each ---------------
@@ -581,6 +646,22 @@ def _scatters(text, scope):
 LINEITEM_BUCKET = 1 << 19
 
 
+#: (the joins whose ``bvalid[pos]`` at the probe's bucket the fold takes
+#: away, the fold's ``pred`` gathers by the table's length) at SF=0.05:
+#: Q5's into supplier (500 keys) and into (orders join customer) (75,000
+#: order keys) at lineitem's 2^19 rows, and orders' into customer (7,500
+#: keys) at 2^17; Q10's and Q18's into customer
+FOLDED = {
+    "Q5": ({"join/join": 1, "join/join/join": 1},
+           {500: {"join/join": 1}, 75000: {"join/join/join": 1},
+            CUSTOMER_TABLE: {"join/join/join/join": 1}}),
+    "Q10": ({"join/join/join": 1},
+            {CUSTOMER_TABLE: {"join/join/join": 1}}),
+    "Q18": ({"join/join": 1}, {CUSTOMER_TABLE: {"join/join": 1}}),
+}
+
+
+@pytest.mark.parametrize("fold", ["on", "off"])
 @pytest.mark.parametrize("aligned", ["on", "off"])
 @pytest.mark.parametrize("name, rows, gathers, scatters, gathering", [
     # the two joins at the lineitem bucket gather (orders join customer)'s
@@ -606,23 +687,33 @@ LINEITEM_BUCKET = 1 << 19
 ])
 def test_join_chains_gather_no_constant_null_lane(
         one_chip, chip_branches, monkeypatch, tpch_session, name, rows,
-        gathers, scatters, gathering, aligned):
+        gathers, scatters, gathering, aligned, fold):
     """The chains' gathers at the probes' buckets and the keyed GROUP
     BY's scatters, counted by the node that traced them: a build column
     whose view holds no NULL on a valid row (``_TView.nonnull``) is
     gathered without its null lane, and a sum of such an argument
     reduces no count of its own.  A join into a view whose rows are
     the probe's own (``aligned``) gathers nothing; ``off``: the
-    gathering join's (``gathering`` more)."""
+    gathering join's (``gathering`` more).  A join whose key -> row
+    table holds at most half its probe's rows reads the build's
+    validity once a key (``FOLDED``); ``fold`` off: once a probe row."""
     if aligned == "off":
         _no_alignment(monkeypatch)
         gathers = {k: gathers.get(k, 0) + gathering.get(k, 0)
                    for k in gathers.keys() | gathering.keys()}
+    gone, tables = FOLDED[name]
+    if fold == "off":
+        _no_fold(monkeypatch)
+    else:
+        gathers = {k: n - gone.get(k, 0) for k, n in gathers.items()}
     fn, abstract = _capture(monkeypatch, tpch_session, tpch.WORKLOAD[name],
                             one_chip)
     text = _compile(fn, *abstract).as_text()
     assert _gathers(text, rows) == gathers
     assert _scatters(text, "keygroup") == scatters
+    for length, by_node in tables.items():
+        assert _gathers(text, length, "pred") == \
+            (by_node if fold == "on" else {}), length
 
 
 @pytest.mark.parametrize("flags", ["off", "on"])
@@ -633,10 +724,12 @@ def test_join_chains_lower_to_the_parents_text_on_one_chip(
     mesh; on one device the three programs lower to the text pinned at
     f6b51c3 (PR 38), byte for byte, with the NULL-freedom flags and
     without: the one-chip joins cell runs the programs it ran.  So they
-    do with no join aligned (PR 40's fallback)."""
+    do with no join aligned (the gathering join's path) and none
+    folded."""
     if flags == "off":
         _no_flags(monkeypatch)
     _no_alignment(monkeypatch)
+    _no_fold(monkeypatch)
     fn, abstract = _capture(monkeypatch, tpch_session, tpch.WORKLOAD[name],
                             one_chip)
     text = fn.lower(*abstract).as_text()
@@ -705,6 +798,32 @@ def test_fused_join_chain_mesh_program(topo, chip_branches, monkeypatch,
     reduced = any("keygroup" in line and "mesh.psum" in line
                   for line in text.splitlines() if " all-reduce(" in line)
     assert reduced == expect["keygroup_reduce"]
+
+
+@pytest.mark.parametrize("fold", ["on", "off"])
+def test_q5_over_the_mesh_folds_by_a_chips_rows(
+        topo, chip_branches, monkeypatch, four_devices, tpch_session, fold):
+    """Over four chips each probes with its 2^17 of ``lineitem``'s rows.
+    ``supplier``'s 500 keys fold; the table of (orders join customer),
+    75,000 order keys, holds more than half a chip's rows and keeps its
+    ``bvalid[pos]`` at them, as at SF=10, where 15 M keys meet 2^24 rows
+    a chip.  Each chip folds its own copy of a whole table: nothing more
+    crosses the mesh."""
+    if fold == "off":
+        _no_fold(monkeypatch)
+    text = _compile_mesh_statement(topo, monkeypatch, tpch_session,
+                                   tpch.WORKLOAD["Q5"])
+    on = fold == "on"
+    a_chip = LINEITEM_BUCKET // 4
+    assert _gathers(text, a_chip, "pred") == \
+        ({"join/join/join": 1} if on else {"join/join": 1,
+                                           "join/join/join": 1})
+    assert _gathers(text, 500, "pred") == ({"join/join": 1} if on else {})
+    assert _gathers(text, 75000, "pred") == {}
+    assert _gathers(text, CUSTOMER_TABLE, "pred") == \
+        ({"join/join/join/join": 1} if on else {})
+    assert len([n for n in _all_gathers(text, "join")
+                if n == ORDERS_BUCKET]) == 3
 
 
 # ---- the same at the SF=10 shapes ------------------------------------------
@@ -817,7 +936,10 @@ def test_fused_mesh_program_at_the_sf10_shapes(topo, chip_branches,
         groups, pad = 1 << 24, 5 << 20
         assert _gathers(text, groups) == {}
         assert _gathers(text, pad) == {"aggindex": 4}
-        assert _gathers(text, groups // 4) == {"join/join": 2}
+        # the inner join folds: customer's 1.5 M keys against 2^22 rows
+        # of orders a chip
+        assert _gathers(text, groups // 4) == {"join/join": 1}
+        assert _gathers(text, 1_500_000, "pred") == {"join/join": 1}
         assert len(_collectives(text, "all-gather", 4 * pad)) == 4
         assert not _collectives(text, "all-reduce", groups)
 
@@ -849,3 +971,10 @@ def test_fused_join_chain_mesh_program_at_the_sf10_shapes(
         # the view's validity and c_nationkey's halves, at orders' bucket
         assert len([n for n in _all_gathers(text, "join")
                     if n == 1 << 24]) == 3
+        # the view's 15 M order keys hold more than half of a chip's
+        # 2^24 rows of lineitem: its bvalid[pos] stays; supplier's 100 k
+        # keys and, at 2^22 rows of orders a chip, customer's 1.5 M fold
+        assert _gathers(text, 1 << 24, "pred") == {"join/join/join": 1}
+        assert _gathers(text, 100_000, "pred") == {"join/join": 1}
+        assert _gathers(text, 1_500_000, "pred") == \
+            {"join/join/join/join": 1}

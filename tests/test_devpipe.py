@@ -789,11 +789,12 @@ def tpch_tk():
     return s, tpch.QUERIES
 
 
-@pytest.mark.parametrize("name, dead, skipped, aligned", [
-    ("Q1", 0, 0, 0), ("Q3", 4, 2, 1), ("Q6", 0, 0, 0), ("Q5", 0, 6, 0),
-    ("Q10", 2, 4, 1), ("Q18", 2, 3, 2)])
+@pytest.mark.parametrize("name, dead, skipped, aligned, folded", [
+    ("Q1", 0, 0, 0, 0), ("Q3", 4, 2, 1, 1), ("Q6", 0, 0, 0, 0),
+    ("Q5", 0, 6, 0, 4), ("Q10", 2, 4, 1, 2), ("Q18", 2, 3, 2, 1)])
 def test_pipe_counters_of_the_benchmarks_statements(tpch_tk, name, dead,
-                                                    skipped, aligned):
+                                                    skipped, aligned,
+                                                    folded):
     """Q3's consumer reads four of the TopN's eight slots, and its outer
     join reads revenue and l_orderkey off the group table without their
     null lanes; Q10 leaves out three lanes and its sum's count; Q1's
@@ -801,7 +802,12 @@ def test_pipe_counters_of_the_benchmarks_statements(tpch_tk, name, dead,
     pipeline.  Q3's and Q10's joins into ``lineitem``'s group table, and
     Q18's and its semi join on the HAVING view, read the group table in
     place (``orders`` and ``lineitem`` are both stored by the order key):
-    aligned; Q5's joins are many-to-one or into another table's order."""
+    aligned; Q5's joins are many-to-one or into another table's order.
+    Folded: the joins whose key -> row table holds at most half their
+    probe's rows (Q3's, Q10's and Q18's into ``customer``; Q5's four
+    into ``customer``, the orders view, ``supplier`` and ``region``;
+    Q10's into ``nation``); Q5's join of its 25 groups into ``nation``
+    probes 32 rows with 25 keys and does not fold."""
     from tinysql_tpu.bench import tpch
     s, _queries = tpch_tk
     sql = {**tpch.QUERIES, **tpch.WORKLOAD}[name]
@@ -819,6 +825,8 @@ def test_pipe_counters_of_the_benchmarks_statements(tpch_tk, name, dead,
     assert delta.get("pipe_joins_aligned", 0) == aligned
     assert any(f"aligned:{aligned}" in str(r) for r in info) \
         == bool(aligned)
+    assert delta.get("pipe_joins_folded", 0) == folded
+    assert any(f"folded:{folded}" in str(r) for r in info) == bool(folded)
 
 
 def test_a_dead_slot_raises_when_read(tk, monkeypatch):
@@ -936,14 +944,16 @@ def test_dead_aggregate_slots_are_not_computed(tk, monkeypatch, case):
 
 @pytest.mark.parametrize("key, grown", [("pipe_dead_cols", 4),
                                         ("pipe_const_nulls", 2),
-                                        ("pipe_joins_aligned", 1)])
+                                        ("pipe_joins_aligned", 1),
+                                        ("pipe_joins_folded", 1)])
 def test_pipe_counters_on_metrics_and_in_the_benchmark(tpch_tk, key, grown):
     """The counters' other readers: ``/metrics`` and the benchmark's
     ``<key>_per_query.*`` (a data file over the accepted ``counter``
     reader; a program without the counter, as the parent, leaves the
     metric out).  ``pipe_joins_aligned`` (PR 40) has no entry, the
     benchmark's ``per_layer`` being full: the ``window`` line's
-    ``kernels`` reads it."""
+    ``kernels`` reads it, as it reads ``pipe_joins_folded``: the growth
+    of the benchmark's source ``kernels`` over the window."""
     import importlib.util
     import json
     import os
@@ -951,19 +961,27 @@ def test_pipe_counters_on_metrics_and_in_the_benchmark(tpch_tk, key, grown):
     from tinysql_tpu.obs import metrics
     s, queries = tpch_tk
     s.execute("set @@tidb_use_tpu = 1")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    src_spec = importlib.util.spec_from_file_location(
+        "bench_source_kernels",
+        os.path.join(root, "benchmark", "sources", "kernels.py"))
+    source = importlib.util.module_from_spec(src_spec)
+    src_spec.loader.exec_module(source)
+    window_from = source.snapshot()
     before = kernels.stats_snapshot()
     for name in ("Q1", "Q3", "Q6"):
         s.query(queries[name])
     delta = kernels.stats_delta(before)
     assert delta[key] == grown
+    # the window line's ``kernels``: the source's growth, key by key
+    assert source.snapshot()[key] - window_from.get(key, 0) == grown
     text = metrics.render_prometheus()
     total = [line for line in text.splitlines()
              if line.startswith(f"tinysql_{key}_total ")]
     assert total and float(total[0].split()[1]) >= grown
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     path = os.path.join(root, "benchmark", "layer_metrics",
                         f"{key}_per_query.json")
-    if key == "pipe_joins_aligned":
+    if key in ("pipe_joins_aligned", "pipe_joins_folded"):
         assert not os.path.exists(path)
         return
     with open(path) as f:

@@ -153,7 +153,7 @@ def test_counters_read_what_the_plan_says(joins, kind, expect):
 
 @pytest.mark.parametrize("kind, shown", [
     ("q5", "joins:5/3view"), ("q10", "key_cut:1"), ("q18", "joins:3/"),
-    ("q18", "aligned:2")])
+    ("q18", "aligned:2"), ("q5", "folded:4"), ("q10", "folded:2")])
 def test_explain_analyze_shows_the_counters(joins, kind, shown):
     s, want = joins
     rows = s.query("explain analyze " + want[kind][0]).rows
@@ -801,3 +801,43 @@ def test_a_key_from_a_build_side_would_read_the_wrong_rows(atk,
     wrong, d = _counted(atk, sql)
     progcache.clear()
     assert d["pipe_joins_aligned"] == 1 and wrong != right
+
+
+# ---- folded joins: the build's validity read once a key ---------------------
+
+@pytest.mark.parametrize("where, kind, folded", [
+    # Q5: into supplier, the orders view, customer and region; Q10:
+    # into customer and nation; Q18: into customer
+    ("one", "q5", 4), ("one", "q10", 2), ("one", "q18", 1),
+    # over four devices a device probes with 2^15 of lineitem's rows:
+    # the orders view's 30,000 keys hold more than half and do not fold
+    ("mesh", "q5", 3), ("mesh", "q10", 2), ("mesh", "q18", 1)])
+def test_folded_joins_answer_bit_for_bit_as_the_unfolded_ones(
+        joins, request, monkeypatch, where, kind, folded):
+    """A join whose key -> row table holds at most half its probe's rows
+    on a device reads the build's validity once a key; the answers are
+    the unfolded program's, bit for bit (the same rows match, the same
+    values are read), and the reference's."""
+    from tinysql_tpu.executor import devpipe
+    s, want = joins
+    sql, ref = want[kind]
+    if where == "mesh":
+        request.getfixturevalue("four_chips")
+        s.execute("set @@tidb_mesh_parallel = 1")
+    try:
+        s.query(sql)
+        rows, d = _counted(s, sql)
+        with monkeypatch.context() as m:
+            m.setattr(devpipe._JoinNode, "_folded",
+                      lambda self, tbl_len, nb, mesh: False)
+            s.query(sql)
+            off, d_off = _counted(s, sql)
+    finally:
+        s.execute("set @@tidb_mesh_parallel = 0")
+    assert rows == off
+    _same(rows, [list(r) for r in ref])
+    assert d.get("pipe_joins_folded", 0) == folded
+    assert d_off.get("pipe_joins_folded", 0) == 0
+    assert d["pipe_joins"] == d_off["pipe_joins"]
+    assert d["dispatches"] == d_off["dispatches"] == 1
+    assert d.get("reshard_bytes", 0) == d_off.get("reshard_bytes", 0)

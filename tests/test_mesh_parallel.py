@@ -1134,6 +1134,44 @@ def test_aligned_join_answers_bit_for_bit_as_the_gathering_one(
     assert d.get("progcache_misses", 0) == 0
 
 
+@pytest.mark.parametrize("n, folded", [(0, 1), (2, 1), (4, 1), (8, 0)])
+@pytest.mark.parametrize("name", MESH_QUERIES)
+def test_folded_join_answers_bit_for_bit_as_the_unfolded_one(
+        tpch_mesh, monkeypatch, name, n, folded):
+    """Q3's inner join probes ``customer``'s table of 3,000 keys with
+    ``orders``' 2^15 rows.  On one device, two and four the table holds
+    at most half a device's rows, and each device folds the view's
+    validity into its own whole copy of the table; on eight (4,096 rows
+    a device) the join reads the validity at its rows as before.  The
+    rows are the unfolded program's, bit for bit, and the mirror's, and
+    nothing more crosses the mesh; Q1 and Q6 join nothing."""
+    from tinysql_tpu.executor import devpipe
+    s, mirror, queries = tpch_mesh
+    sql = queries[name]
+    folded = folded if name == "Q3" else 0
+    if n:
+        _mesh_of(monkeypatch, n)
+        s.execute("set @@tidb_mesh_parallel = 1")
+    try:
+        s.query(sql)
+        rows, d = _stats_of(s, sql)
+        with monkeypatch.context() as m:
+            m.setattr(devpipe._JoinNode, "_folded",
+                      lambda self, tbl_len, nb, mesh: False)
+            s.query(sql)
+            off, d_off = _stats_of(s, sql)
+    finally:
+        s.execute("set @@tidb_mesh_parallel = 0")
+    assert rows and rows == off
+    assert _rows_close(rows, [list(r) for r in
+                              mirror.execute(sql).fetchall()])
+    assert d.get("pipe_joins_folded", 0) == folded
+    assert d_off.get("pipe_joins_folded", 0) == 0
+    assert d["dispatches"] == d_off["dispatches"]
+    assert d.get("reshard_bytes", 0) == d_off.get("reshard_bytes", 0) == 0
+    assert d.get("progcache_misses", 0) == 0
+
+
 @pytest.mark.parametrize("n", [2, 4])
 def test_q3_over_the_mesh_gathers_two_null_lanes_fewer(tpch_mesh,
                                                        monkeypatch, n):

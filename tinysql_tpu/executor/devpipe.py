@@ -1636,6 +1636,9 @@ class _JoinNode:
       rows are the probe's own, in the same order (``_aligned``: a table
       stored in key order and an aggregate of another by that key, say),
       neither the table nor any gather: each build lane is read in place.
+      Where the table is small beside the probe (``_folded``), the
+      build's validity is read once a key into the table, not once a
+      probe row.
     - general multiplicity (reference join.go:244 / util/mvmap): the
       build replica's group index is a CSR layout; probe maps key ->
       group through the dense table, per-group VALID counts come from a
@@ -1860,6 +1863,21 @@ class _JoinNode:
         kernels.stats_add("pipe_joins_aligned", 1)
         return True
 
+    def _folded(self, tbl_len: int, nb: int, mesh) -> bool:
+        """Whether the join folds the build's validity into its key ->
+        row table (``_fold_validity``): where the table holds at most
+        half the probe's rows on a device (``nb``, over ``mesh``'s
+        devices where the probe is sharded).  A gather costs by its
+        indices, and a byte lane's up to twice a word's, so reading the
+        validity once a key then costs less than once a probe row.  A
+        function of shapes the program is keyed by, nothing else
+        (counter ``pipe_joins_folded``)."""
+        rows = nb // int(mesh.devices.size) if mesh is not None else nb
+        if tbl_len > rows // 2:
+            return False
+        kernels.stats_add("pipe_joins_folded", 1)
+        return True
+
     def _out(self, ppairs, bcols, nbc: int) -> list:
         """The output view: the probe's live columns as they are, the
         build's live ones (``bcols``, in ``self.bl``'s order), every
@@ -1954,6 +1972,7 @@ class _JoinNode:
         # table and the build's validity, both whole on every device
         from ..parallel import dist
         mesh = self.mesh if dist.shardable(nb, self.mesh) else None
+        fold = self._folded(tbl_len, nb, mesh)
         self._key(pb, ("semijoin", anti, nb, nbb, tbl_len, pk_slot,
                        len(ptv.meta), len(btv.meta))
                   + dist.layout_tag(_layouts(mesh)[0]))
@@ -1964,8 +1983,11 @@ class _JoinNode:
             lo_p, hi_p = pr[0][0], pr[0][1]
             inr = (kp >= lo_p) & (kp <= hi_p) & ~knull
             pos0 = jn.clip(kp - lo_p, 0, tbl_len - 1)
+            if fold:
+                tbl = _fold_validity(tbl, bvalid, nbb)
             pos = jn.where(inr, tbl[pos0].astype(jn.int64), -1)
-            match = (pos >= 0) & bvalid[jn.clip(pos, 0, nbb - 1)]
+            match = (pos >= 0) if fold \
+                else (pos >= 0) & bvalid[jn.clip(pos, 0, nbb - 1)]
             # anti (NOT EXISTS shape, never null-aware here): a NULL
             # probe key matches nothing and therefore SURVIVES
             return pvalid & (~match if anti else match)
@@ -2100,8 +2122,12 @@ class _JoinNode:
             pt.add_int(hi)
             pt.add_int(st)
         ip, fp = pb.params(pt)
+        from ..parallel import dist
+        fold = self._folded(total, nb, self.mesh if dist.shardable(
+            nb, self.mesh) else None)
         self._key(pb, ("joinmk", nb, nbb, total, pk_slots, outer,
-                       probe_is_left, len(btv.meta), len(ptv.meta)))
+                       probe_is_left, len(btv.meta), len(ptv.meta))
+                  + (("fold",) if fold else ()))
         bl, nbc = self.bl, len(btv.meta)
 
         def emit(args):
@@ -2118,9 +2144,11 @@ class _JoinNode:
                 ok = ok & (kv >= lo_) & (kv <= hi_) & ~kn
                 comp_t = comp_t + (kv - lo_) * st_
             pos0 = jn.clip(comp_t, 0, total - 1)
-            pos = jn.where(ok, args[it][pos0].astype(jn.int64), -1)
+            tbl = _fold_validity(args[it], bvalid, nbb) if fold \
+                else args[it]
+            pos = jn.where(ok, tbl[pos0].astype(jn.int64), -1)
             pos_safe = jn.clip(pos, 0, nbb - 1)
-            match = (pos >= 0) & bvalid[pos_safe]
+            match = (pos >= 0) if fold else (pos >= 0) & bvalid[pos_safe]
             valid_out = pvalid if outer else (pvalid & match)
             gathered = self._gather_build([bpairs[i] for i in bl],
                                           pos_safe, match)
@@ -2363,6 +2391,7 @@ class _JoinNode:
         from ..parallel import dist
         mesh = self.mesh if dist.shardable(nb, self.mesh) else None
         n_mesh = self.n_mesh if mesh is not None else 0
+        fold = self._folded(tbl_len, nb, mesh)
         probe_is_left = self.probe_is_left
         self._key(pb, ("join", nb, nbb, tbl_len, pk_slot, outer,
                        probe_is_left, len(btv.meta), len(ptv.meta),
@@ -2375,9 +2404,11 @@ class _JoinNode:
             lo_p, hi_p = pr[0][0], pr[0][1]
             inr = (kp >= lo_p) & (kp <= hi_p) & ~knull
             pos0 = jn.clip(kp - lo_p, 0, tbl_len - 1)
+            if fold:
+                tbl = _fold_validity(tbl, bvalid, nbb)
             pos = jn.where(inr, tbl[pos0].astype(jn.int64), -1)
             pos_safe = jn.clip(pos, 0, nbb - 1)
-            match = (pos >= 0) & bvalid[pos_safe]
+            match = (pos >= 0) if fold else (pos >= 0) & bvalid[pos_safe]
             if outer:
                 valid_out = pvalid
             else:
@@ -3278,6 +3309,16 @@ def _prepare_build_key_info(node, build_key, pb: _PipeBuilder, mesh=None):
     rep, key, lo, hi, tbl = got
     it = pb.lane(rep, key, lambda: tbl, _layouts(mesh)[1])
     return lo, hi, it, int(tbl.shape[0])
+
+
+def _fold_validity(tbl, bvalid, nbb: int):
+    """The key -> row table with -1 wherever the build row it names is
+    not valid in the build's view (``bvalid``, ``nbb`` rows): read at a
+    probe key, a row at or above 0 is a match, and ``bvalid`` is read
+    once a key (traced each dispatch: it carries the statement's filters
+    and parameters) in place of once a probe row."""
+    jn = _jn()
+    return jn.where((tbl >= 0) & bvalid[jn.clip(tbl, 0, nbb - 1)], tbl, -1)
 
 
 def _probe_rows(node, key):

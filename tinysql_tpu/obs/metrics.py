@@ -85,6 +85,12 @@ _DEVICE_METRICS = {
                            "unique or semi join the replicas prove "
                            "aligned), traced to read the build's lanes "
                            "in place: no key lookup, no gather"),
+    "pipe_joins_folded": ("tinysql_pipe_joins_folded_total",
+                          "Of those joins, the ones whose key -> row "
+                          "table holds at most half the probe's rows on "
+                          "a device, traced to fold the build's validity "
+                          "into the table: read once a key, not once a "
+                          "probe row"),
     "agg_key_cut": ("tinysql_agg_key_cut_total",
                     "Fused GROUP BYs above a join chain formed on the one "
                     "key (a table's primary key) that determines the "

@@ -247,6 +247,10 @@ def _device_info(st) -> str:
         # joins whose build rows are the probe's own rows in the same
         # order: the build's lanes read in place, no lookup or gather
         parts.append(f"aligned:{int(d['pipe_joins_aligned'])}")
+    if d.get("pipe_joins_folded"):
+        # joins whose key -> row table is small beside the probe: the
+        # build's validity folded into it, read once a key
+        parts.append(f"folded:{int(d['pipe_joins_folded'])}")
     if d.get("agg_key_cut"):
         parts.append(f"key_cut:{int(d['agg_key_cut'])}")
     if d.get("agg_key_mesh"):
