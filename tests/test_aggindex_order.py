@@ -344,8 +344,8 @@ def test_lowered_program_gathers_only_boundaries(tk, monkeypatch, layout,
 @pytest.mark.parametrize("layout", LAYOUTS)
 def test_never_null_arguments_take_presence_for_their_count(tk, monkeypatch,
                                                            layout):
-    """In the sorted formulation a count costs a 64-bit prefix sum and
-    two boundary gathers; a spec whose argument the replica proves NULL
+    """In the sorted formulation a count costs a prefix sum and a
+    boundary gather (two in 64 bits); a spec whose argument the replica proves NULL
     on no row (``b`` holds none; ``+ - *`` make none) reduces none and
     reads ``presence``.  ``x`` holds NULLs and a division makes them (by
     zero): those keep their counts.  Answers equal the host's, empty

@@ -370,10 +370,19 @@ def _no_fold(monkeypatch):
                         lambda self, tbl_len, nb, mesh: False)
 
 
+def _no_count32(monkeypatch):
+    """No sorted GROUP BY counts its rows in 32 bits: every count is an
+    int64, two words a boundary, as the programs did before."""
+    from tinysql_tpu.executor import devpipe
+    monkeypatch.setattr(devpipe._AggIndexNode, "_count32",
+                        lambda self, nb: False)
+
+
 #: ``customer``'s key -> row table at SF=0.05 (``c_custkey`` 1 to 7,500)
 CUSTOMER_TABLE = 7500
 
 
+@pytest.mark.parametrize("count32", ["on", "off"])
 @pytest.mark.parametrize("fold", ["on", "off"])
 @pytest.mark.parametrize("aligned", ["on", "off"])
 @pytest.mark.parametrize("live, expect", [
@@ -395,18 +404,25 @@ CUSTOMER_TABLE = 7500
     ("all", {"aggindex": 4, "join/join": 6}),
 ])
 def test_q3_gathers_at_the_bucket(one_chip, chip_branches, monkeypatch,
-                                  tpch_session, live, expect, aligned, fold):
+                                  tpch_session, live, expect, aligned, fold,
+                                  count32):
     """The inner join's key -> row table (``customer``'s 7,500 keys)
     holds under half of ``orders``' 2^17 rows: the join folds the
     view's validity into it, and its ``bvalid[pos]`` at the bucket
     becomes one ``pred`` gather of the table's length (``off``: as
     before the fold).  The outer join's group table (75,000 keys) holds
-    more than half: its gathering form (``aligned`` off) does not fold."""
+    more than half: its gathering form (``aligned`` off) does not fold.
+    ``presence`` is counted in 32 bits (``count32``), one boundary
+    gather where its int64 takes two (``off``)."""
     if live == "all":
         _all_live(monkeypatch)
     if aligned == "off":
         _no_alignment(monkeypatch)
         expect = dict(expect, join=6)
+    if count32 == "off":
+        _no_count32(monkeypatch)
+    else:
+        expect = dict(expect, aggindex=expect["aggindex"] - 1)
     if fold == "off":
         _no_fold(monkeypatch)
     else:
@@ -437,6 +453,7 @@ def _collectives(text, kind, rows):
     return out
 
 
+@pytest.mark.parametrize("count32", ["on", "off"])
 @pytest.mark.parametrize("fold", ["on", "off"])
 @pytest.mark.parametrize("aligned", ["on", "off"])
 @pytest.mark.parametrize("live, quarter", [
@@ -444,13 +461,15 @@ def _collectives(text, kind, rows):
     ("all", {"join/join": 6}),
 ])
 def test_q3_mesh_gathers_a_chip(topo, chip_branches, monkeypatch,
-                                tpch_session, live, quarter, aligned, fold):
-    """Under the mesh every chip bounds its own span of the groups (four
+                                tpch_session, live, quarter, aligned, fold,
+                                count32):
+    """Under the mesh every chip bounds its own span of the groups (three
     gathers at the span's pad, none at the whole table's bucket), the
     pieces cross as all-gathers and no whole table is summed over the
     chips; and it joins its quarter of ``orders``: the outer join reads
     its quarter of the merged group table in place, where the gathering
-    join (``off``) takes six gathers of a quarter lane."""
+    join (``off``) takes six gathers of a quarter lane.  ``presence``'s
+    piece is one 32-bit lane (``count32``), where its int64 is two."""
     if live == "all":
         _all_live(monkeypatch)
     if aligned == "off":
@@ -462,15 +481,19 @@ def test_q3_mesh_gathers_a_chip(topo, chip_branches, monkeypatch,
         _no_fold(monkeypatch)
     else:
         quarter = dict(quarter, **{"join/join": quarter["join/join"] - 1})
+    if count32 == "off":
+        _no_count32(monkeypatch)
+    words = 3 if count32 == "on" else 4
     text = _compile_mesh_statement(topo, monkeypatch, tpch_session,
                                    tpch.QUERIES["Q3"])
     assert _gathers(text, Q3_BUCKET) == {}
-    assert _gathers(text, Q3_SPAN_PAD) == {"aggindex": 4}
+    assert _gathers(text, Q3_SPAN_PAD) == {"aggindex": words}
     assert _gathers(text, Q3_BUCKET // 4) == quarter
     assert _gathers(text, CUSTOMER_TABLE, "pred") == \
         ({"join/join": 1} if fold == "on" else {})
-    # presence and revenue: two 32-bit halves each, a piece a chip
-    assert len(_collectives(text, "all-gather", 4 * Q3_SPAN_PAD)) == 4
+    # revenue's two 32-bit halves and presence's one (its int64's two),
+    # a piece a chip
+    assert len(_collectives(text, "all-gather", 4 * Q3_SPAN_PAD)) == words
     assert not _collectives(text, "all-gather", 4 * Q3_BUCKET)
     assert not _collectives(text, "all-reduce", Q3_BUCKET)
 
@@ -523,11 +546,12 @@ def test_lowered_text_is_the_parents(topo, one_chip, chip_branches,
     a chip bounds its own span of the groups, and the pieces are
     gathered and added into place where whole tables were summed.  With
     no join aligned (the gathering join's path) all twelve still do.
-    So they do with no join folded."""
+    So they do with no join folded and no count in 32 bits."""
     if flags == "off":
         _no_flags(monkeypatch)
     _no_alignment(monkeypatch)
     _no_fold(monkeypatch)
+    _no_count32(monkeypatch)
     case = f"{name}.{where}"
     if case == "Q3.mesh":
         pinned = _pinned("lowered_at_pr38.json")[f"{case}.{flags}"]
@@ -549,10 +573,11 @@ def test_aligned_programs_lower_to_the_text_pinned_at_pr40(
     lower to the text pinned with PR 40, with the NULL-freedom flags and
     without; Q5, whose joins are many-to-one or into another table's
     order, and Q1 and Q6, which have none, to their parents' text.
-    That holds with no join folded."""
+    That holds with no join folded and no count in 32 bits."""
     if flags == "off":
         _no_flags(monkeypatch)
     _no_fold(monkeypatch)
+    _no_count32(monkeypatch)
     case = f"{name}.{where}.{flags}"
     if name in ("Q3", "Q10", "Q18"):
         pinned = _pinned("lowered_at_pr40.json")[case]
@@ -577,15 +602,44 @@ def test_folded_programs_lower_to_the_pinned_text(
     programs of Q3, Q5, Q10 and Q18 lower to the text pinned in
     ``lowered_folded.json``, with the NULL-freedom flags and without
     (over the mesh as the four-chip cells price it); Q1 and Q6, which
-    join nothing, to their parents' text."""
+    join nothing, to their parents' text.  That holds with no count in
+    32 bits."""
+    if where == "mesh":
+        request.getfixturevalue("four_devices")
+    if flags == "off":
+        _no_flags(monkeypatch)
+    _no_count32(monkeypatch)
+    if name in ("Q1", "Q6"):
+        pinned = _pinned("lowered_at_129247b.json")[f"{name}.{where}"]
+    else:
+        pinned = _pinned("lowered_folded.json")[f"{name}.{where}.{flags}"]
+    assert _lowered(topo, one_chip, monkeypatch, tpch_session,
+                    tpch.ALL_QUERIES[name], where) == pinned
+
+
+@pytest.mark.parametrize("flags", ["off", "on"])
+@pytest.mark.parametrize("name, where", [
+    ("Q3", "one"), ("Q3", "mesh"), ("Q10", "one"), ("Q18", "one"),
+    ("Q5", "one"), ("Q5", "mesh"), ("Q1", "one"), ("Q6", "mesh")])
+def test_count32_programs_lower_to_the_pinned_text(
+        topo, one_chip, chip_branches, monkeypatch, request, tpch_session,
+        name, where, flags):
+    """With the sorted GROUP BYs counting their rows in 32 bits, the
+    programs of Q3, Q10 and Q18 lower to the text pinned in
+    ``lowered_count32.json``, with the NULL-freedom flags and without;
+    Q5 (its GROUP BY is keyed), Q1 (a dense one) and Q6 (a scalar
+    aggregate), which have no sorted GROUP BY, to their parents'
+    text."""
     if where == "mesh":
         request.getfixturevalue("four_devices")
     if flags == "off":
         _no_flags(monkeypatch)
     if name in ("Q1", "Q6"):
         pinned = _pinned("lowered_at_129247b.json")[f"{name}.{where}"]
-    else:
+    elif name == "Q5":
         pinned = _pinned("lowered_folded.json")[f"{name}.{where}.{flags}"]
+    else:
+        pinned = _pinned("lowered_count32.json")[f"{name}.{where}.{flags}"]
     assert _lowered(topo, one_chip, monkeypatch, tpch_session,
                     tpch.ALL_QUERIES[name], where) == pinned
 
@@ -661,6 +715,7 @@ FOLDED = {
 }
 
 
+@pytest.mark.parametrize("count32", ["on", "off"])
 @pytest.mark.parametrize("fold", ["on", "off"])
 @pytest.mark.parametrize("aligned", ["on", "off"])
 @pytest.mark.parametrize("name, rows, gathers, scatters, gathering", [
@@ -669,7 +724,8 @@ FOLDED = {
     # two u32 of the value each, and no null lane (the parent: 5 and 5);
     # both are many-to-one: nothing is aligned
     ("Q5", LINEITEM_BUCKET, {"join/join": 4, "join/join/join": 4}, 0, {}),
-    # at the orders bucket: the aggregate's four boundary gathers; the
+    # at the orders bucket: the aggregate's four boundary gathers with
+    # its counts in 64 bits (three in 32: ``count32``); the
     # join into the group table reads revenue in place (the gathering
     # join: tbl[pos0], bvalid[pos], two u32), the join below it
     # c_custkey's side, each without its null lane (the parent: 5 and
@@ -677,7 +733,8 @@ FOLDED = {
     # sum, whose own count is presence (the parent: 3 scatters)
     ("Q10", ORDERS_BUCKET, {"aggindex": 4, "join/join/join": 4}, 2,
      {"join/join": 4}),
-    # two aggregates of four boundary gathers; sum(l_quantity) off the
+    # two aggregates of four boundary gathers (three each in 32 bits);
+    # sum(l_quantity) off the
     # group table in place (the gathering join: 4), and two columns
     # under the join below, whose semi join on the HAVING view reads
     # that view's validity in place (its two gathers count there),
@@ -687,7 +744,7 @@ FOLDED = {
 ])
 def test_join_chains_gather_no_constant_null_lane(
         one_chip, chip_branches, monkeypatch, tpch_session, name, rows,
-        gathers, scatters, gathering, aligned, fold):
+        gathers, scatters, gathering, aligned, fold, count32):
     """The chains' gathers at the probes' buckets and the keyed GROUP
     BY's scatters, counted by the node that traced them: a build column
     whose view holds no NULL on a valid row (``_TView.nonnull``) is
@@ -696,7 +753,9 @@ def test_join_chains_gather_no_constant_null_lane(
     the probe's own (``aligned``) gathers nothing; ``off``: the
     gathering join's (``gathering`` more).  A join whose key -> row
     table holds at most half its probe's rows reads the build's
-    validity once a key (``FOLDED``); ``fold`` off: once a probe row."""
+    validity once a key (``FOLDED``); ``fold`` off: once a probe row.
+    A sorted GROUP BY counts its rows in 32 bits (``count32``): one
+    boundary gather of ``presence`` where its int64 takes two."""
     if aligned == "off":
         _no_alignment(monkeypatch)
         gathers = {k: gathers.get(k, 0) + gathering.get(k, 0)
@@ -706,6 +765,11 @@ def test_join_chains_gather_no_constant_null_lane(
         _no_fold(monkeypatch)
     else:
         gathers = {k: n - gone.get(k, 0) for k, n in gathers.items()}
+    if count32 == "off":
+        _no_count32(monkeypatch)
+    elif "aggindex" in gathers:
+        # four boundary gathers an aggregate, one of them a count's
+        gathers = dict(gathers, aggindex=gathers["aggindex"] * 3 // 4)
     fn, abstract = _capture(monkeypatch, tpch_session, tpch.WORKLOAD[name],
                             one_chip)
     text = _compile(fn, *abstract).as_text()
@@ -724,12 +788,13 @@ def test_join_chains_lower_to_the_parents_text_on_one_chip(
     mesh; on one device the three programs lower to the text pinned at
     f6b51c3 (PR 38), byte for byte, with the NULL-freedom flags and
     without: the one-chip joins cell runs the programs it ran.  So they
-    do with no join aligned (the gathering join's path) and none
-    folded."""
+    do with no join aligned (the gathering join's path), none folded
+    and no count in 32 bits."""
     if flags == "off":
         _no_flags(monkeypatch)
     _no_alignment(monkeypatch)
     _no_fold(monkeypatch)
+    _no_count32(monkeypatch)
     fn, abstract = _capture(monkeypatch, tpch_session, tpch.WORKLOAD[name],
                             one_chip)
     text = fn.lower(*abstract).as_text()
@@ -932,15 +997,16 @@ def test_fused_mesh_program_at_the_sf10_shapes(topo, chip_branches,
     assert "all-to-all" not in text
     if name == "Q3":
         # as test_q3_mesh_gathers_a_chip, at 2^24 groups: a chip bounds
-        # its span (2^22 groups give or take 1,024: five sixteenths)
+        # its span (2^22 groups give or take 1,024: five sixteenths),
+        # presence in one 32-bit word
         groups, pad = 1 << 24, 5 << 20
         assert _gathers(text, groups) == {}
-        assert _gathers(text, pad) == {"aggindex": 4}
+        assert _gathers(text, pad) == {"aggindex": 3}
         # the inner join folds: customer's 1.5 M keys against 2^22 rows
         # of orders a chip
         assert _gathers(text, groups // 4) == {"join/join": 1}
         assert _gathers(text, 1_500_000, "pred") == {"join/join": 1}
-        assert len(_collectives(text, "all-gather", 4 * pad)) == 4
+        assert len(_collectives(text, "all-gather", 4 * pad)) == 3
         assert not _collectives(text, "all-reduce", groups)
 
 

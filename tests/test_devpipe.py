@@ -829,6 +829,26 @@ def test_pipe_counters_of_the_benchmarks_statements(tpch_tk, name, dead,
     assert any(f"folded:{folded}" in str(r) for r in info) == bool(folded)
 
 
+@pytest.mark.parametrize("name, count32", [
+    ("Q1", 0), ("Q3", 1), ("Q6", 0), ("Q5", 0), ("Q10", 1), ("Q18", 2)])
+def test_agg_count32_of_the_benchmarks_statements(tpch_tk, name, count32):
+    """The sorted GROUP BYs that count their rows in 32 bits: Q3's, Q10's
+    and Q18's two over ``lineitem``; Q1's is dense, Q5's keyed above its
+    joins and Q6's scalar: none.  Once a statement, in ``EXPLAIN
+    ANALYZE`` as ``count32:<n>``."""
+    from tinysql_tpu.bench import tpch
+    s, _queries = tpch_tk
+    sql = {**tpch.QUERIES, **tpch.WORKLOAD}[name]
+    before = kernels.stats_snapshot()
+    assert s.query(sql).rows
+    delta = kernels.stats_delta(before)
+    assert delta.get("agg_count32", 0) == count32
+    assert delta.get("agg_count32", 0) <= delta.get("agg_sorted", 0)
+    info = s.query("explain analyze " + sql).rows
+    assert any(f"count32:{count32}" in str(r) for r in info) \
+        == bool(count32)
+
+
 def test_a_dead_slot_raises_when_read(tk, monkeypatch):
     """The placeholder fails the trace: a node that reads a slot it did
     not ask its child for is a failed statement, never a wrong answer."""
@@ -945,15 +965,17 @@ def test_dead_aggregate_slots_are_not_computed(tk, monkeypatch, case):
 @pytest.mark.parametrize("key, grown", [("pipe_dead_cols", 4),
                                         ("pipe_const_nulls", 2),
                                         ("pipe_joins_aligned", 1),
-                                        ("pipe_joins_folded", 1)])
+                                        ("pipe_joins_folded", 1),
+                                        ("agg_count32", 1)])
 def test_pipe_counters_on_metrics_and_in_the_benchmark(tpch_tk, key, grown):
     """The counters' other readers: ``/metrics`` and the benchmark's
     ``<key>_per_query.*`` (a data file over the accepted ``counter``
     reader; a program without the counter, as the parent, leaves the
     metric out).  ``pipe_joins_aligned`` (PR 40) has no entry, the
     benchmark's ``per_layer`` being full: the ``window`` line's
-    ``kernels`` reads it, as it reads ``pipe_joins_folded``: the growth
-    of the benchmark's source ``kernels`` over the window."""
+    ``kernels`` reads it, as it reads ``pipe_joins_folded`` and
+    ``agg_count32``: the growth of the benchmark's source ``kernels``
+    over the window."""
     import importlib.util
     import json
     import os
@@ -981,7 +1003,7 @@ def test_pipe_counters_on_metrics_and_in_the_benchmark(tpch_tk, key, grown):
     assert total and float(total[0].split()[1]) >= grown
     path = os.path.join(root, "benchmark", "layer_metrics",
                         f"{key}_per_query.json")
-    if key in ("pipe_joins_aligned", "pipe_joins_folded"):
+    if key in ("pipe_joins_aligned", "pipe_joins_folded", "agg_count32"):
         assert not os.path.exists(path)
         return
     with open(path) as f:

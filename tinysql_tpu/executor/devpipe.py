@@ -1121,21 +1121,26 @@ def _spec_slots_read(specs, needed) -> set:
 
 def _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid, seg_sum,
                   seg_mm, presence, n_out, needed, gather=lambda x: x,
-                  never_null=frozenset()):
+                  never_null=frozenset(), seg_count=None):
     """Shared per-spec aggregation loop for the device group-by nodes
     (the subtle NULL-when-empty / avg-pairing semantics live ONCE here).
     A spec outside ``needed`` (:func:`_needed_specs`) is left dead.  A
     spec in ``never_null`` has an argument that is NULL on no row: its
     live rows are the view's and its count IS ``presence``, so no count
-    of its own is reduced (in the sorted formulation a 64-bit prefix sum
-    and two boundary gathers).
+    of its own is reduced (in the sorted formulation a prefix sum and a
+    boundary gather).
     gather takes a lane into the order the reductions run in (the
     identity where the lanes arrive in it: row order for the dense and
     the scalar aggregate, the index's order for the sorted one); seg_sum
     reduces a lane to [n_out]; seg_mm(av_s, live_s, kind) likewise for
-    min/max.  The three stages carry their names into the profile:
-    ``args``, ``gather``, ``group_sums``."""
+    min/max; seg_count(mask) counts a mask's rows to int64 [n_out] (by
+    default seg_sum of the mask as int64; the sorted formulation's may
+    reduce it in 32 bits).  The three stages carry their names into the
+    profile: ``args``, ``gather``, ``group_sums``."""
     scope = kernels.jax().named_scope
+    if seg_count is None:
+        def seg_count(mask):
+            return seg_sum(mask.astype(jn.int64))
     res = []
     for k, (kind, af) in enumerate(zip(spec_kinds, arg_fns)):
         if k not in needed:
@@ -1151,8 +1156,7 @@ def _spec_results(jn, spec_kinds, arg_fns, pairs, pr, valid, seg_sum,
             if kind != "count":
                 av_s = gather(av)
         with scope("group_sums"):
-            cnt = presence if k in never_null \
-                else seg_sum(live_s.astype(jn.int64))
+            cnt = presence if k in never_null else seg_count(live_s)
             if kind == "count":
                 res.append((cnt, jn.zeros(n_out, dtype=bool)))
             elif kind in ("sum", "sum0"):
@@ -1253,7 +1257,10 @@ class _AggIndexNode:
       program holds no gather to sorted order: mask -> prefix sum ->
       boundary diff (one [ngb] gather a sum: a group's lower boundary
       is the group before's upper one), whose cost does not grow with
-      the group count.  Which host arrays go up is decided by what the
+      the group count.  A count of rows is reduced in 32 bits where the
+      leaf's bucket proves it fits (``_count32``; counter
+      ``agg_count32``): one word a boundary, where an int64 is two, so
+      one gather.  Which host arrays go up is decided by what the
       index observed of its input, never what is traced: over a
       clustered index (``GroupIndex.clustered``; counter
       ``agg_clustered``) the leaf's lanes are the replica's row-order
@@ -1306,6 +1313,20 @@ class _AggIndexNode:
         specs, slots = got
         return _AggIndexNode(leaf, plan, list(plan.group_by), specs, slots,
                              out_map)
+
+    def _count32(self, nb: int) -> bool:
+        """Whether the sorted formulation counts rows in 32 bits: where
+        the leaf's whole bucket ``nb``, every shard's rows together, is
+        below 2^31, so that no count (a shard's, or one merged over the
+        mesh) can leave int32's range.  A count is exact in either
+        width; on the TPU an int64 is two 32-bit words, its prefix sum
+        twice as wide and its boundary gather two.  A function of a
+        shape the program is keyed by, nothing else (counter
+        ``agg_count32``)."""
+        if nb >= 1 << 31:
+            return False
+        kernels.stats_add("agg_count32", 1)
+        return True
 
     def _host_key_cols(self, rep):
         """[(vals, nulls)] per key column (codes for strings), their
@@ -1408,6 +1429,7 @@ class _AggIndexNode:
         span = qb < ngb
         if span:
             kernels.stats_add("agg_span_cut", 1)
+        count32 = not dense and self._count32(nb)
         tv = self.leaf.prepare(pb, order=order)
         if tv is None:
             return None
@@ -1470,10 +1492,11 @@ class _AggIndexNode:
         # serves the sorted one too; the slot stays so that the dense
         # program and its parameter vector are what they were
         pt.add_int(rep.n_rows)
-        # in the sorted formulation a count costs a 64-bit prefix sum and
-        # two boundary gathers: a spec whose argument the replica proves
-        # NULL on no row takes ``presence`` for its count (the dense
-        # one's counts are masked reductions of a few passes and stay)
+        # in the sorted formulation a count costs a prefix sum and a
+        # boundary gather (two in 64 bits): a spec whose argument the
+        # replica proves NULL on no row takes ``presence`` for its count
+        # (the dense one's counts are masked reductions of a few passes
+        # and stay)
         from .tpu_executors import _slot_id
         chk = self.leaf.chunk()
         never_null = frozenset() if dense else _never_null_specs(
@@ -1492,7 +1515,11 @@ class _AggIndexNode:
         head = "aggdense" if dense else "aggindex"
         pb.key((head, tuple(keys), kdts, tuple(self.slots),
                 tuple(self.out_map), nb, ngb) + dist.layout_tag(lrows)
-               + ((("span", qb),) if span else ()),
+               + ((("span", qb),) if span else ())
+               # the counts' width follows from nb, in the key already;
+               # the 64-bit side (a bucket of 2^31 rows or more) says so
+               # too, and a program of one side never serves the other
+               + ((("count64",),) if not dense and not count32 else ()),
                live, len(self.out_map))
         spec_kinds = [k for k, _ in self.specs]
         slots = self.slots
@@ -1538,14 +1565,22 @@ class _AggIndexNode:
                 lo = jn.concatenate([zero[None], hi[:-1]])
                 return merge(hi - lo)
 
+            def seg_count(mask):
+                # in 32 bits where the bucket proves a count fits: one
+                # word a row and a boundary, merged over the mesh as one
+                # (an int64 is two), widened on the [ngb] result alone
+                if count32:
+                    return seg(mask.astype(jn.int32)).astype(jn.int64)
+                return seg(mask.astype(jn.int64))
+
             def seg_mm(av_s, live_s, kind):
                 gl = jn.where(live_s, isg, ngb)
                 op = j.ops.segment_min if kind == "min" \
                     else j.ops.segment_max
                 return merge_mm[kind](
                     op(av_s, gl, num_segments=ngb + 1)[:ngb])
-            return dict(seg_sum=seg, seg_mm=seg_mm,
-                        presence=seg(valid.astype(jn.int64)))
+            return dict(seg_sum=seg, seg_mm=seg_mm, seg_count=seg_count,
+                        presence=seg_count(valid))
         reducers = dense_reducers if dense else sorted_reducers
 
         def reduce(idx, valid, pairs, pr):

@@ -63,6 +63,10 @@ _DEVICE_METRICS = {
                      "each bounded only the span of groups their rows "
                      "hold (the pieces gathered and added into place, "
                      "no sum of whole tables)"),
+    "agg_count32": ("tinysql_agg_count32_total",
+                    "Sorted fused GROUP BYs whose bucket proves a count "
+                    "of rows fits 32 bits, traced to reduce their counts "
+                    "in one word: one boundary gather a count, not two"),
     "pipe_dead_cols": ("tinysql_pipe_dead_cols_total",
                        "Columns of fused programs' root views that no "
                        "consumer reads and the programs therefore did "

@@ -122,7 +122,7 @@ STATS = {"dispatches": 0, "d2h_transfers": 0, "d2h_bytes": 0,
          "h2d_transfers": 0, "h2d_bytes": 0,
          "host_dispatches": 0,
          "agg_dense": 0, "agg_sorted": 0, "agg_clustered": 0,
-         "agg_span_cut": 0,
+         "agg_span_cut": 0, "agg_count32": 0,
          "pipe_dead_cols": 0, "pipe_const_nulls": 0,
          "pipe_joins": 0, "pipe_view_builds": 0, "agg_key_cut": 0,
          "pipe_joins_aligned": 0, "pipe_joins_folded": 0,

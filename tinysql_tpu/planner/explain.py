@@ -225,6 +225,10 @@ def _device_info(st) -> str:
         parts.append(f"agg:{int(d.get('agg_dense', 0))}dense"
                      f"/{int(d.get('agg_sorted', 0))}sorted{clustered}"
                      f"{span_cut}")
+    if d.get("agg_count32"):
+        # sorted ones whose bucket proves a count fits 32 bits: their
+        # counts reduced in one word, not two
+        parts.append(f"count32:{int(d['agg_count32'])}")
     if d.get("pipe_dead_cols"):
         # columns of the fused program's root no consumer reads: not
         # computed, packed or downloaded
