@@ -27,8 +27,6 @@ NB = 4096                # of four shards (1024 rows each) is all padding
 
 @pytest.fixture
 def tk(monkeypatch):
-    # the chip's branches on the CPU: the fused pipeline on, no numpy twin
-    monkeypatch.setenv("TINYSQL_DEVICE_JOIN_ONLY", "1")
     monkeypatch.setattr(dist, "MIN_SHARD_ROWS", 16)
     monkeypatch.setattr(
         dist, "session_mesh",

@@ -155,6 +155,7 @@ def test_blockwise_join_above_budget(join_tk):
     """fact (5000 rows) > budget (512): the probe side streams in blocks
     against the resident build table; the probe key column must never
     upload whole."""
+    join_tk.execute("set @@tidb_devpipe = 0")  # the per-operator join
     q = ("select d.tag, count(*), sum(fact.v) from fact join d "
          "on fact.k = d.k group by d.tag order by d.tag")
     a, b, st = _both(join_tk, q)

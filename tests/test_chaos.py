@@ -584,6 +584,7 @@ def _mesh_session(s):
     s.execute("set @@tidb_use_tpu = 1")
     s.execute("set @@tidb_tpu_min_rows = 1")
     s.execute("set @@tidb_mesh_parallel = 1")
+    s.execute("set @@tidb_devpipe = 0")  # ops/shardops.py's tier
 
 
 #: probe side 600 rows, unique build side — the partitioned

@@ -6,9 +6,8 @@ fit the device, fails in tier-1 and costs no chip time.  A compile that
 passes is not a chip run (``chip_smoke.py`` is), and says nothing of
 results or times.
 
-Code that asks ``jax.default_backend()`` sees ``cpu`` here, so the tests
-steer it to the chip's branches with a monkeypatch — never through an
-option of the program.
+The program makes the same kernel choices on every backend, so what is
+compiled here is what the chip runs.
 
 Sizes.  Kernels whose compile time does not grow with the lane compile
 at the TPC-H SF=1 ``lineitem`` bucket, 2^23 rows.  A full sort of an
@@ -75,14 +74,6 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-@pytest.fixture
-def chip_branches(monkeypatch, no_persistent_cache):
-    """The branches the chip takes: every ``default_backend()`` question
-    answers ``tpu`` (unrolled segment reductions, no numpy twins, the
-    fused pipeline on)."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
-
 def _compile(fn, *abstract):
     """Lower + compile for the described device; the program must fit
     one chip's memory.  Returns the compiled executable."""
@@ -106,21 +97,21 @@ _ROWS = [SMALL_ROWS, pytest.param(SF1_ROWS, marks=pytest.mark.slow)]
 @pytest.mark.parametrize("rows", _ROWS)
 @pytest.mark.parametrize("dtype, desc", [("int64", False),
                                          ("float64", True)])
-def test_sort_kernel(one_chip, chip_branches, dtype, desc, rows):
+def test_sort_kernel(one_chip, no_persistent_cache, dtype, desc, rows):
     s = one_chip
     _compile(kernels._sort_kernel((desc,)), [_lane(s, dtype, rows)],
              [_lane(s, "bool", rows)], _lane(s, "bool", rows))
 
 
 @pytest.mark.parametrize("rows", _ROWS)
-def test_topk_kernel(one_chip, chip_branches, rows):
+def test_topk_kernel(one_chip, no_persistent_cache, rows):
     _compile(kernels._topk_kernel(16), _lane(one_chip, "float64", rows))
 
 
 @pytest.mark.parametrize("build_sorted, rows", [
     (True, SF1_ROWS), (False, SMALL_ROWS),
     pytest.param(False, SF1_ROWS, marks=pytest.mark.slow)])
-def test_unique_join_kernel(one_chip, chip_branches, build_sorted, rows):
+def test_unique_join_kernel(one_chip, no_persistent_cache, build_sorted, rows):
     s = one_chip
     probe = [_lane(s, "int64", rows), _lane(s, "bool", rows),
              _lane(s, "bool", rows)]
@@ -129,7 +120,7 @@ def test_unique_join_kernel(one_chip, chip_branches, build_sorted, rows):
     _compile(kernels._unique_join_kernel(build_sorted), *probe, *build)
 
 
-def test_segment_aggregate_unrolled(one_chip, chip_branches):
+def test_segment_aggregate_unrolled(one_chip, no_persistent_cache):
     """Q1's shape on the per-operator tier: a handful of segments, sums
     of expressions over float64 lanes, min/max, presence — unrolled
     masked reductions, the branch only a non-cpu backend takes."""
@@ -150,13 +141,13 @@ def test_segment_aggregate_unrolled(one_chip, chip_branches):
 
 
 @pytest.mark.parametrize("dtype", ["float64", "int64"])
-def test_prefix_sum(one_chip, chip_branches, dtype):
+def test_prefix_sum(one_chip, no_persistent_cache, dtype):
     """The group-index aggregate's segment sums: ``jnp.cumsum`` of a
     float64 lane crashed this compiler inside the fused Q1 program."""
     _compile(jax.jit(kernels.prefix_sum), _lane(one_chip, dtype, SF1_ROWS))
 
 
-def test_lex_head(one_chip, chip_branches):
+def test_lex_head(one_chip, no_persistent_cache):
     """Q3's TopN at SF=1: five sort operands over the 2^21 join output,
     a window of 16 — selected, not sorted."""
     s, n = one_chip, 1 << 21
@@ -166,7 +157,7 @@ def test_lex_head(one_chip, chip_branches):
     _compile(jax.jit(lambda o: kernels.lex_head(o, 16)), ops)
 
 
-def test_sharded_topk_on_four_chip_mesh(topo, chip_branches):
+def test_sharded_topk_on_four_chip_mesh(topo, no_persistent_cache):
     """One ``shard_map`` program of ops/shardops.py on a mesh built from
     the four described devices: per-shard top-k, all_gather, merge."""
     mesh = Mesh(np.array(topo.devices), ("shard",))
@@ -183,7 +174,7 @@ def test_sharded_topk_on_four_chip_mesh(topo, chip_branches):
 
 
 @pytest.mark.parametrize("dtype", ["int64", "float64"])
-def test_mesh_min_max_merge(topo, chip_branches, dtype):
+def test_mesh_min_max_merge(topo, no_persistent_cache, dtype):
     """The sharded aggregates' partial merge: ``lax.pmin`` of an int64
     was refused by this compiler (UNIMPLEMENTED: only Sum all-reduce of
     an emulated 64-bit type); gather-and-reduce compiles."""
@@ -239,7 +230,7 @@ def _capture(monkeypatch, session, sql, one_chip):
 
 
 @pytest.mark.parametrize("name", ["Q1", "Q3", "Q6"])
-def test_fused_devpipe_program(one_chip, chip_branches, monkeypatch,
+def test_fused_devpipe_program(one_chip, no_persistent_cache, monkeypatch,
                                tpch_session, name):
     """Run the statement up to its first dispatch, capture the fused
     program with its inputs, and compile THAT for the described device
@@ -309,8 +300,8 @@ def _capture_mesh(topo, monkeypatch, session, sql):
 
 
 @pytest.mark.parametrize("name", ["Q1", "Q3", "Q6"])
-def test_fused_mesh_program(topo, chip_branches, monkeypatch, tpch_session,
-                            name):
+def test_fused_mesh_program(topo, no_persistent_cache, monkeypatch,
+                            tpch_session, name):
     text = _compile_mesh_statement(topo, monkeypatch, tpch_session,
                                    tpch.QUERIES[name])
     assert re.search(r"all-(gather|reduce)", text)
@@ -403,7 +394,7 @@ CUSTOMER_TABLE = 7500
     ("consumer", {"aggindex": 4, "join/join": 2}),
     ("all", {"aggindex": 4, "join/join": 6}),
 ])
-def test_q3_gathers_at_the_bucket(one_chip, chip_branches, monkeypatch,
+def test_q3_gathers_at_the_bucket(one_chip, no_persistent_cache, monkeypatch,
                                   tpch_session, live, expect, aligned, fold,
                                   count32):
     """The inner join's key -> row table (``customer``'s 7,500 keys)
@@ -460,7 +451,7 @@ def _collectives(text, kind, rows):
     ("consumer", {"join/join": 2}),
     ("all", {"join/join": 6}),
 ])
-def test_q3_mesh_gathers_a_chip(topo, chip_branches, monkeypatch,
+def test_q3_mesh_gathers_a_chip(topo, no_persistent_cache, monkeypatch,
                                 tpch_session, live, quarter, aligned, fold,
                                 count32):
     """Under the mesh every chip bounds its own span of the groups (three
@@ -531,7 +522,7 @@ def _lowered(topo, one_chip, monkeypatch, session, sql, where):
 @pytest.mark.parametrize("flags", ["off", "on"])
 @pytest.mark.parametrize("where", ["one", "mesh"])
 @pytest.mark.parametrize("name", ["Q1", "Q3", "Q6"])
-def test_lowered_text_is_the_parents(topo, one_chip, chip_branches,
+def test_lowered_text_is_the_parents(topo, one_chip, no_persistent_cache,
                                      monkeypatch, tpch_session, name, where,
                                      flags):
     """With no view saying which of its slots hold no NULL (``off``),
@@ -567,7 +558,7 @@ def test_lowered_text_is_the_parents(topo, one_chip, chip_branches,
     ("Q3", "one"), ("Q3", "mesh"), ("Q10", "one"), ("Q18", "one"),
     ("Q5", "one"), ("Q1", "one"), ("Q6", "mesh")])
 def test_aligned_programs_lower_to_the_text_pinned_at_pr40(
-        topo, one_chip, chip_branches, monkeypatch, tpch_session, name,
+        topo, one_chip, no_persistent_cache, monkeypatch, tpch_session, name,
         where, flags):
     """With the aligned joins (PR 40) Q3's, Q10's and Q18's programs
     lower to the text pinned with PR 40, with the NULL-freedom flags and
@@ -595,8 +586,8 @@ def test_aligned_programs_lower_to_the_text_pinned_at_pr40(
     ("Q3", "one"), ("Q3", "mesh"), ("Q5", "one"), ("Q5", "mesh"),
     ("Q10", "one"), ("Q18", "one"), ("Q1", "one"), ("Q6", "mesh")])
 def test_folded_programs_lower_to_the_pinned_text(
-        topo, one_chip, chip_branches, monkeypatch, request, tpch_session,
-        name, where, flags):
+        topo, one_chip, no_persistent_cache, monkeypatch, request,
+        tpch_session, name, where, flags):
     """With the joins whose key -> row table holds at most half the
     probe's rows on a device folding the build's validity into it, the
     programs of Q3, Q5, Q10 and Q18 lower to the text pinned in
@@ -622,8 +613,8 @@ def test_folded_programs_lower_to_the_pinned_text(
     ("Q3", "one"), ("Q3", "mesh"), ("Q10", "one"), ("Q18", "one"),
     ("Q5", "one"), ("Q5", "mesh"), ("Q1", "one"), ("Q6", "mesh")])
 def test_count32_programs_lower_to_the_pinned_text(
-        topo, one_chip, chip_branches, monkeypatch, request, tpch_session,
-        name, where, flags):
+        topo, one_chip, no_persistent_cache, monkeypatch, request,
+        tpch_session, name, where, flags):
     """With the sorted GROUP BYs counting their rows in 32 bits, the
     programs of Q3, Q10 and Q18 lower to the text pinned in
     ``lowered_count32.json``, with the NULL-freedom flags and without;
@@ -665,7 +656,7 @@ def _sorted_lanes(text):
 
 
 @pytest.mark.parametrize("name", ["Q5", "Q10", "Q18"])
-def test_fused_join_chain_program(one_chip, chip_branches, monkeypatch,
+def test_fused_join_chain_program(one_chip, no_persistent_cache, monkeypatch,
                                   tpch_session, name):
     """Each of the three is ONE program (the capture raises at the first
     dispatch: a statement that fell to the per-operator tier would show
@@ -743,7 +734,7 @@ FOLDED = {
      {"join": 4, "join/join": 2}),
 ])
 def test_join_chains_gather_no_constant_null_lane(
-        one_chip, chip_branches, monkeypatch, tpch_session, name, rows,
+        one_chip, no_persistent_cache, monkeypatch, tpch_session, name, rows,
         gathers, scatters, gathering, aligned, fold, count32):
     """The chains' gathers at the probes' buckets and the keyed GROUP
     BY's scatters, counted by the node that traced them: a build column
@@ -783,7 +774,7 @@ def test_join_chains_gather_no_constant_null_lane(
 @pytest.mark.parametrize("flags", ["off", "on"])
 @pytest.mark.parametrize("name", ["Q5", "Q10", "Q18"])
 def test_join_chains_lower_to_the_parents_text_on_one_chip(
-        one_chip, chip_branches, monkeypatch, tpch_session, name, flags):
+        one_chip, no_persistent_cache, monkeypatch, tpch_session, name, flags):
     """PR 39 taught the join's view build and the keyed GROUP BY the
     mesh; on one device the three programs lower to the text pinned at
     f6b51c3 (PR 38), byte for byte, with the NULL-freedom flags and
@@ -831,7 +822,7 @@ def _all_gathers(text, scope):
     # aggregates below the joins: nothing keyed above them
     ("Q18", {"view_lanes": 0, "keygroup_reduce": False}),
 ])
-def test_fused_join_chain_mesh_program(topo, chip_branches, monkeypatch,
+def test_fused_join_chain_mesh_program(topo, no_persistent_cache, monkeypatch,
                                        four_devices, tpch_session, name,
                                        expect):
     """Under ``tidb_mesh_parallel`` each of the three is ONE program
@@ -867,7 +858,8 @@ def test_fused_join_chain_mesh_program(topo, chip_branches, monkeypatch,
 
 @pytest.mark.parametrize("fold", ["on", "off"])
 def test_q5_over_the_mesh_folds_by_a_chips_rows(
-        topo, chip_branches, monkeypatch, four_devices, tpch_session, fold):
+        topo, no_persistent_cache, monkeypatch, four_devices, tpch_session,
+        fold):
     """Over four chips each probes with its 2^17 of ``lineitem``'s rows.
     ``supplier``'s 500 keys fold; the table of (orders join customer),
     75,000 order keys, holds more than half a chip's rows and keeps its
@@ -980,7 +972,7 @@ def sf10_session():
 
 @pytest.mark.slow
 @pytest.mark.parametrize("name", ["Q1", "Q3", "Q6"])
-def test_fused_mesh_program_at_the_sf10_shapes(topo, chip_branches,
+def test_fused_mesh_program_at_the_sf10_shapes(topo, no_persistent_cache,
                                                monkeypatch, sf10_session,
                                                name):
     """The three programs of ``tpch_sf10_mesh4.power_stream`` (lanes of
@@ -1013,7 +1005,8 @@ def test_fused_mesh_program_at_the_sf10_shapes(topo, chip_branches,
 @pytest.mark.slow
 @pytest.mark.parametrize("name", ["Q5", "Q10", "Q18"])
 def test_fused_join_chain_mesh_program_at_the_sf10_shapes(
-        topo, chip_branches, monkeypatch, four_devices, sf10_session, name):
+        topo, no_persistent_cache, monkeypatch, four_devices, sf10_session,
+        name):
     """The three programs of ``tpch_sf10_joins_mesh4.join_stream``
     (``lineitem``'s lanes at 2^26, ``orders``' and the order keys'
     groups at 2^24, ``customer``'s at 2^21): ONE program each over the

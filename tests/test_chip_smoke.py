@@ -2,12 +2,11 @@
 phases, the checks and the last line — so a change that breaks the script
 fails tier-1 here and not a chip call.
 
-On the CPU the engine serves joins, top-k and group-by with numpy twins
-and keeps the fused pipeline off, so ``host_dispatches == 0`` cannot hold
-with defaults: THIS TEST turns the twins off and the pipeline on, lowers
-the row gate to the tiny data, and stops the server's profilers (their
-tracing makes a cold statement several times slower).  The script's own
-defaults stay the chip's.
+The engine makes the chip's kernel choices on the CPU too.  THIS TEST
+forces the fused pipeline (``tidb_devpipe = 1``: a pipeline fault raises
+instead of falling back), lowers the row gate to the tiny data, and stops
+the server's profilers (their tracing makes a cold statement several
+times slower).  The script's own defaults stay the chip's.
 """
 import json
 import logging
@@ -28,7 +27,6 @@ TINY = ["--sf", "0.01", "--expect-platform", "cpu"]
 
 @pytest.fixture
 def chip_like(monkeypatch):
-    monkeypatch.setenv("TINYSQL_DEVICE_JOIN_ONLY", "1")
     for name, value in (("tidb_devpipe", 1), ("tidb_tpu_min_rows", 64),
                         ("tidb_memprof_rate", 0), ("tidb_conprof_rate", 0)):
         monkeypatch.setitem(session_mod.DEFAULT_SYSVARS, name, value)

@@ -765,13 +765,20 @@ LIVE_CASES = {
 }
 
 
+#: dispatches of the per-operator kernels a statement runs beside its one
+#: program: star3's join on an expression key (f.b + 51) runs per
+#: operator above the program, its count and expansion kernels
+PER_OP_DISPATCHES = {"star3": 2}
+
+
 @pytest.mark.parametrize("case", sorted(LIVE_CASES))
 def test_liveness_rows_equal_the_cpu_executors(tk, counters, lives, case):
     _live_tables(tk)
     sql, dead, nodes = LIVE_CASES[case]
     got, want, delta = _dead_cols(tk, sql)
     assert _canon(got) == _canon(want) and got, sql
-    assert delta["dispatches"] == 1 and counters["join"] >= 1
+    assert delta["dispatches"] == 1 + PER_OP_DISPATCHES.get(case, 0)
+    assert counters["join"] >= 1
     if dead is not None:
         assert delta["pipe_dead_cols"] == dead
         assert lives == nodes
@@ -1233,7 +1240,7 @@ def test_a_null_written_after_the_program_is_cached(tk):
         got, want, delta = _dead_cols(tk, sql)
         assert _canon(got) == _canon(want)
         assert any(r[2] is None for r in got)
-        if delta.get("dispatches"):
+        if delta.get("pipe_joins"):  # the program ran, not the operators
             break
     assert delta["dispatches"] == 1 and delta["progcache_misses"] == 1
     assert delta.get("pipe_const_nulls", 0) == 0

@@ -122,6 +122,7 @@ def tk():
     s.execute("create database pipe")
     s.execute("use pipe")
     s.execute("set @@tidb_tpu_min_rows = 0")
+    s.execute("set @@tidb_devpipe = 0")  # the per-operator block walks
     rng = np.random.default_rng(41)
     s.execute("create table f (id bigint primary key, k bigint, "
               "g bigint, x double)")

@@ -3,9 +3,8 @@
 
 - each statement equals the benchmark's plain reference
   (``benchmark/datasets/tpch_joins.py``: numpy, independent of the engine)
-  row for row, and the per-operator tier's answer; one dispatch, no host
-  twin, a second run compiles nothing; the counters read what the plan
-  says;
+  row for row, and the per-operator tier's answer; one dispatch, a second
+  run compiles nothing; the counters read what the plan says;
 - the planner's part: a join order of foreign key -> primary key lookups
   (no many-to-many join), the lookup by one key with the other equality
   as a filter, a GROUP BY that the catalog proves to stand on a key;
@@ -51,8 +50,6 @@ def joins():
     tables at SF=0.02 and the cell's three statements, as its traffic
     file spells them."""
     import json
-    prev = os.environ.get("TINYSQL_DEVICE_JOIN_ONLY")
-    os.environ["TINYSQL_DEVICE_JOIN_ONLY"] = "1"
     data = _bench_file("datasets", "tpch_joins.py")
     traffic = _bench_file("traffic.py")
     with open(os.path.join(ROOT, "benchmark", "traffic",
@@ -72,10 +69,6 @@ def joins():
     want = {st.kind: (st.sql, data.REFERENCES[st.reference](ds, st.params))
             for st in statements}
     yield s, want
-    if prev is None:
-        os.environ.pop("TINYSQL_DEVICE_JOIN_ONLY", None)
-    else:
-        os.environ["TINYSQL_DEVICE_JOIN_ONLY"] = prev
 
 
 def _same(got, want, rel=1e-9):
@@ -205,7 +198,6 @@ def test_q18_aggregates_below_its_joins(joins):
 
 @pytest.fixture
 def tk(monkeypatch):
-    monkeypatch.setenv("TINYSQL_DEVICE_JOIN_ONLY", "1")
     monkeypatch.setattr(dist, "MIN_SHARD_ROWS", 16)
     monkeypatch.setattr(
         dist, "session_mesh",
@@ -342,7 +334,7 @@ def _mesh_counted(s, sql):
 def test_answers_right_under_a_forced_mesh(joins, kind, key_mesh,
                                            four_chips):
     """``tidb_mesh_parallel`` with four host devices: each of the three
-    is ONE fused program over the whole mesh, no host twin, with the
+    is ONE fused program over the whole mesh, with the
     joins, view builds and key cut of the one-device program (the views
     traced under the mesh, the keyed GROUP BY above a chain reduced a
     shard at a time), nothing downloaded but the final rows, and the
@@ -678,7 +670,6 @@ def atk(monkeypatch):
     lines for every ``a`` row, ``m`` lines for every third one, ``l3``
     one line for each of 800 keys (a group bucket wider than ``a``'s),
     ``s`` a small table ``b`` joins by a key with dangling values."""
-    monkeypatch.setenv("TINYSQL_DEVICE_JOIN_ONLY", "1")
     monkeypatch.setattr(dist, "MIN_SHARD_ROWS", 16)
     monkeypatch.setattr(
         dist, "session_mesh",

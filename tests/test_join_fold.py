@@ -42,7 +42,6 @@ def ftk(monkeypatch):
     holds NULLs; ``e`` is a second build ``d`` joins into (a view
     build); ``s`` is a probe too small for ``d``'s table to fold; ``m``
     is keyed by the pair (a, b) for the multi-key join."""
-    monkeypatch.setenv("TINYSQL_DEVICE_JOIN_ONLY", "1")
     monkeypatch.setattr(dist, "MIN_SHARD_ROWS", 16)
     monkeypatch.setattr(
         dist, "session_mesh",
@@ -235,13 +234,10 @@ def test_folded_table_marks_invalid_rows_unmatched():
 
 @pytest.fixture(scope="module")
 def tpch05():
-    """(session, sqlite mirror): TPC-H at SF=0.05 with the device kernels
+    """(session, sqlite mirror): TPC-H at SF=0.05 with the fused pipeline
     forced, the scale at which ``test_chip_compile`` counts the gathers."""
-    import os
     from tinysql_tpu.bench import tpch
-    prev_env = os.environ.get("TINYSQL_DEVICE_JOIN_ONLY")
     prev_floor = dist.MIN_SHARD_ROWS
-    os.environ["TINYSQL_DEVICE_JOIN_ONLY"] = "1"
     dist.MIN_SHARD_ROWS = 16
     data = tpch.generate(0.05)
     s = new_session()
@@ -250,10 +246,6 @@ def tpch05():
     s.execute("set @@tidb_tpu_min_rows = 64")
     yield s, tpch.sqlite_mirror(data)
     dist.MIN_SHARD_ROWS = prev_floor
-    if prev_env is None:
-        os.environ.pop("TINYSQL_DEVICE_JOIN_ONLY", None)
-    else:
-        os.environ["TINYSQL_DEVICE_JOIN_ONLY"] = prev_env
 
 
 @pytest.mark.parametrize("where, name, folded", [

@@ -45,11 +45,9 @@ def _bench_module(kind, name):
 @pytest.fixture(scope="module")
 def q3():
     """(session, dataset, reference module, Q3's SQL, its parameters):
-    the benchmark's own tables at SF 0.02, the chip's branches forced."""
+    the benchmark's own tables at SF 0.02."""
     tpch = _bench_module("datasets", "tpch")
-    prev_env = os.environ.get("TINYSQL_DEVICE_JOIN_ONLY")
     prev_floor = dist.MIN_SHARD_ROWS
-    os.environ["TINYSQL_DEVICE_JOIN_ONLY"] = "1"
     dist.MIN_SHARD_ROWS = 16
     ds = tpch.generate(SF, 33)
     s = new_session()
@@ -70,10 +68,6 @@ def q3():
     params = {k: v[0] for k, v in template["parameters"].items()}
     yield s, ds, tpch, template["sql"].format(**params), params
     dist.MIN_SHARD_ROWS = prev_floor
-    if prev_env is None:
-        os.environ.pop("TINYSQL_DEVICE_JOIN_ONLY", None)
-    else:
-        os.environ["TINYSQL_DEVICE_JOIN_ONLY"] = prev_env
 
 
 def _four_device_mesh(monkeypatch):

@@ -396,8 +396,8 @@ def test_a_readers_side_is_priced_by_its_scans_rows(join_tk):
 
 
 # ---- the mesh deployment: the replica laid out over the mesh ---------------
-# TPC-H's Q1 / Q3 / Q6 under tidb_mesh_parallel = 1 with the chip's
-# branches on (the fused pipeline forced, no numpy twin): the replica's
+# TPC-H's Q1 / Q3 / Q6 under tidb_mesh_parallel = 1 with the fused
+# pipeline forced: the replica's
 # lanes are placed row-sharded over the mesh (parallel/dist.py rows /
 # whole), each statement is ONE dispatch, and a warm one moves nothing.
 
@@ -407,15 +407,12 @@ MESH_QUERIES = ("Q1", "Q3", "Q6")
 
 @pytest.fixture(scope="module")
 def tpch_mesh():
-    """(session, sqlite mirror, queries): TPC-H at SF 0.02, the device
-    kernels forced (no host twin), the planner's rows-per-shard floor
-    lowered so that Q6's small estimate still fans out over 8 shards."""
-    import os
+    """(session, sqlite mirror, queries): TPC-H at SF 0.02, the planner's
+    rows-per-shard floor lowered so that Q6's small estimate still fans
+    out over 8 shards."""
     from tinysql_tpu.bench import tpch
     from tinysql_tpu.parallel import dist
-    prev_env = os.environ.get("TINYSQL_DEVICE_JOIN_ONLY")
     prev_floor = dist.MIN_SHARD_ROWS
-    os.environ["TINYSQL_DEVICE_JOIN_ONLY"] = "1"
     dist.MIN_SHARD_ROWS = 16
     s = new_session()
     data = tpch.generate(MESH_SF)
@@ -426,10 +423,6 @@ def tpch_mesh():
     s.execute("set @@tidb_tpu_min_rows = 64")
     yield s, tpch.sqlite_mirror(data), tpch.QUERIES
     dist.MIN_SHARD_ROWS = prev_floor
-    if prev_env is None:
-        os.environ.pop("TINYSQL_DEVICE_JOIN_ONLY", None)
-    else:
-        os.environ["TINYSQL_DEVICE_JOIN_ONLY"] = prev_env
 
 
 def _mesh_of(monkeypatch, n):
@@ -564,7 +557,7 @@ def test_q3_program_gathers_no_lineitem_lane(tpch_mesh, monkeypatch, n):
 @pytest.mark.parametrize("name", MESH_QUERIES)
 def test_warm_mesh_statement_moves_nothing(tpch_mesh, name):
     """The second statement: one dispatch over the whole mesh, no input
-    laid out anew, no numpy twin, no program built, nothing uploaded but
+    laid out anew, no program built, nothing uploaded but
     the parameters."""
     s, _mirror, queries = tpch_mesh
     s.execute("set @@tidb_mesh_parallel = 1")

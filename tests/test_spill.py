@@ -18,6 +18,13 @@ Four layers:
    spilling when enabled;
 4. observability: spill activity lands in statements_summary columns,
    /metrics, and EXPLAIN ANALYZE device info.
+
+EXACTLY holds where a group's float sum adds its rows in row order: the
+scatter lowering.  Masked reductions (at most SEG_UNROLL groups) sum in
+the reduction's own order, which a partition's shorter row set changes
+in the last bits, so this file pins the scatter lowering; and the SQL
+layers pin the per-operator tier (``tidb_devpipe = 0``), the tier that
+spills.
 """
 import threading
 import time
@@ -30,11 +37,23 @@ from tinysql_tpu import fail
 from tinysql_tpu.bench import tpch
 from tinysql_tpu.chunk.column import Column
 from tinysql_tpu.mytypes import new_int_type
-from tinysql_tpu.ops import kernels, spill
+from tinysql_tpu.ops import kernels, progcache, spill
 from tinysql_tpu.session.session import SessionError, new_session
 from tinysql_tpu.utils import memory
 from tinysql_tpu.utils.interrupt import QueryKilled
 from tinysql_tpu.utils.memory import MemQuotaExceeded, MemTracker
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _scatter_sums():
+    # the segment programs' key does not hold the lowering: clear them
+    # on both sides so no program of the other lowering is reused
+    prev = kernels.SEG_UNROLL
+    kernels.SEG_UNROLL = 0
+    progcache.clear()
+    yield
+    kernels.SEG_UNROLL = prev
+    progcache.clear()
 
 
 @pytest.fixture(autouse=True)
@@ -383,6 +402,7 @@ def tq():
     s.execute("use tpch")
     s.execute("set @@tidb_use_tpu = 1")
     s.execute("set @@tidb_tpu_min_rows = 1")
+    s.execute("set @@tidb_devpipe = 0")  # the per-operator tier spills
     want = {q: s.query(sql).rows for q, sql in tpch.QUERIES.items()}
     peaks = {}
     for q, sql in tpch.QUERIES.items():
@@ -453,6 +473,7 @@ def test_cold_session_quota_below_input_spills_first_run():
         s = new_session()
         s.execute("set @@tidb_use_tpu = 1")
         s.execute("set @@tidb_tpu_min_rows = 1")
+        s.execute("set @@tidb_devpipe = 0")
         s.execute("create database d")
         s.execute("use d")
         s.execute("create table t (a int, b double)")
@@ -536,6 +557,7 @@ def test_interleaved_sessions_tracker_isolation(tq):
     s2 = new_session(s.storage, db="tpch")
     s2.execute("set @@tidb_use_tpu = 1")
     s2.execute("set @@tidb_tpu_min_rows = 1")
+    s2.execute("set @@tidb_devpipe = 0")
     s.execute(f"set @@tidb_mem_quota_query = {peaks['Q3'] // 2}")
     for _ in range(2):
         assert s.query(tpch.Q3).rows == want["Q3"]

@@ -127,23 +127,28 @@ def test_stacked_group_by_tree_outputs(server):
     """The fused segment (group-by) path stacks too — "tree" outputs
     slice per member on device.  Round 1 may compile the batchable
     fused program (solo runs can ride the passthrough variant); round 2
-    must stack with zero compiles and sqlite-grade equality to solo."""
+    must stack with zero compiles and sqlite-grade equality to solo.
+    The per-operator tier's stacking, pinned with ``tidb_devpipe = 0``."""
     qs = [f"select b, sum(c), count(*) from t where c < {500.0 + i * 7} "
           "group by b" for i in range(3)]
-    solo = {q: _sess(server).query(q).rows for q in qs}
-    digest, _ = stmtsummary.normalize(qs[0])
-    assert batching.family_batchable(digest)
-    _drive_round(server, qs)       # round 1: warms the batchable route
-    kernels.prewarm_stacked()
-    st0 = batching.stats_snapshot()
-    miss0 = progcache.stats_snapshot()["misses"]
-    entries = _drive_round(server, qs)
-    for e, q in zip(entries, qs):
-        assert e.error is None, e.error
-        assert repr(e.result.rows) == repr(solo[q])
-    st = batching.stats_snapshot()
-    assert st["stacked_rounds"] == st0["stacked_rounds"] + 1
-    assert progcache.stats_snapshot()["misses"] == miss0
+    _sess(server).execute("set global tidb_devpipe = 0")
+    try:
+        solo = {q: _sess(server).query(q).rows for q in qs}
+        digest, _ = stmtsummary.normalize(qs[0])
+        assert batching.family_batchable(digest)
+        _drive_round(server, qs)       # round 1: warms the batchable route
+        kernels.prewarm_stacked()
+        st0 = batching.stats_snapshot()
+        miss0 = progcache.stats_snapshot()["misses"]
+        entries = _drive_round(server, qs)
+        for e, q in zip(entries, qs):
+            assert e.error is None, e.error
+            assert repr(e.result.rows) == repr(solo[q])
+        st = batching.stats_snapshot()
+        assert st["stacked_rounds"] == st0["stacked_rounds"] + 1
+        assert progcache.stats_snapshot()["misses"] == miss0
+    finally:
+        server.storage._global_vars.pop("tidb_devpipe", None)
 
 
 # =========================================================================

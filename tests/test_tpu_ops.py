@@ -331,8 +331,9 @@ def test_topk_multi_key_matches_full_sort():
 
 
 def test_np_unique_join_float_keys():
-    """Float (and mixed-cast) join keys must take the searchsorted
-    branch — range addressing over float keys crashed (r5 review)."""
+    """Float (and mixed-cast) join keys with NULLs through the unique
+    join: every pair matches on a live key, and every live probe key in
+    the build set is found."""
     import numpy as np
     from tinysql_tpu.ops import kernels
     rng = np.random.default_rng(4)
@@ -340,9 +341,8 @@ def test_np_unique_join_float_keys():
     ln = rng.random(5000) < 0.05
     rk = np.unique(np.round(rng.random(300) * 50, 1))
     rn = np.zeros(len(rk), dtype=bool)
-    li, ri = kernels._np_unique_join(
-        lk, ln, np.ones(5000, bool), rk, rn, np.ones(len(rk), bool),
-        False)
+    li, ri = kernels.unique_join_match((lk, ln), 5000, (rk, rn), len(rk))
+    li, ri = np.asarray(li), np.asarray(ri)
     for a, b in zip(li.tolist(), ri.tolist()):
         assert not ln[a] and lk[a] == rk[b]
     want = sum(1 for i in range(5000) if not ln[i] and lk[i] in set(rk))
@@ -350,10 +350,10 @@ def test_np_unique_join_float_keys():
 
 
 def test_np_join_expand_matches_device_contract():
-    """The generic-join host twin must reproduce the device expansion's
-    (li, ri) pairs AND order exactly (probe-major, stable key-sorted
-    build rows), for inner and outer, dense and sparse key ranges."""
-    import os
+    """The expansion join's (li, ri) pairs AND order against a brute-force
+    reference: probe-major, and within a probe row the live build rows in
+    stable key order; an outer join's valid probe row with no match
+    appears once with -1.  Inner and outer, dense and sparse key ranges."""
     import numpy as np
     from tinysql_tpu.ops import kernels
     rng = np.random.default_rng(12)
@@ -366,26 +366,25 @@ def test_np_join_expand_matches_device_contract():
         rk = rng.integers(0, 60, m).astype(np.int64) * mult  # duplicates
         rn = rng.random(m) < 0.06
         rv = rng.random(m) < 0.9
+        r_live = rv & ~rn
         for outer in (False, True):
-            host = kernels._np_join_expand(lk, ln, lv, rk, rn, rv, outer)
-            os.environ["TINYSQL_DEVICE_JOIN_ONLY"] = "1"
-            try:
-                dev = kernels.join_match((lk, ln), n, (rk, rn), m,
-                                         outer=outer, lvalid=lv,
-                                         rvalid=rv)
-            finally:
-                del os.environ["TINYSQL_DEVICE_JOIN_ONLY"]
-            assert np.array_equal(host[0], np.asarray(dev[0])), \
-                (sparse, outer)
-            assert np.array_equal(host[1], np.asarray(dev[1])), \
-                (sparse, outer)
+            want_l, want_r = [], []
+            for i in range(n):
+                hits = (np.nonzero(r_live & (rk == lk[i]))[0].tolist()
+                        if lv[i] and not ln[i] else [])
+                if outer and lv[i] and not hits:
+                    hits = [-1]
+                want_l += [i] * len(hits)
+                want_r += hits
+            li, ri = kernels.join_match((lk, ln), n, (rk, rn), m,
+                                        outer=outer, lvalid=lv, rvalid=rv)
+            assert np.asarray(li).tolist() == want_l, (sparse, outer)
+            assert np.asarray(ri).tolist() == want_r, (sparse, outer)
 
 
 def test_join_sentinel_collision_int64_max():
     """A LIVE build key equal to int64 max must match (and dead rows with
-    the +max sentinel must not shadow it) on BOTH the device kernels and
-    the host twins (r5 review finding)."""
-    import os
+    the +max sentinel must not shadow it) in both join kernels."""
     import numpy as np
     from tinysql_tpu.ops import kernels
     mx = np.iinfo(np.int64).max
@@ -394,27 +393,18 @@ def test_join_sentinel_collision_int64_max():
     rk = np.array([7, mx, 5], dtype=np.int64)
     rn = np.array([True, False, False])  # row 0 is a NULL key
     want = [(0, 1), (1, 2)]
-    for env in (None, "1"):
-        if env:
-            os.environ["TINYSQL_DEVICE_JOIN_ONLY"] = env
-        try:
-            for fn in (kernels.join_match, kernels.unique_join_match):
-                li, ri = fn((lk, ln), 2, (rk, rn), 3)
-                got = sorted(zip(np.asarray(li).tolist(),
-                                 np.asarray(ri).tolist()))
-                assert got == want, (fn.__name__, env, got)
-        finally:
-            os.environ.pop("TINYSQL_DEVICE_JOIN_ONLY", None)
+    for fn in (kernels.join_match, kernels.unique_join_match):
+        li, ri = fn((lk, ln), 2, (rk, rn), 3)
+        got = sorted(zip(np.asarray(li).tolist(), np.asarray(ri).tolist()))
+        assert got == want, (fn.__name__, got)
 
 
-def test_device_join_kernels_sql_parity(monkeypatch):
-    """With host twins serving the CPU backend, the DEVICE join kernels
-    (what a real chip runs) must keep SQL-level coverage: force them via
-    TINYSQL_DEVICE_JOIN_ONLY and compare against the CPU tier."""
+def test_device_join_kernels_sql_parity():
+    """The device join kernels (unique, expansion, outer with ON, sorted
+    build, semi, anti) answer SQL as the CPU tier does."""
     import numpy as np
     from tinysql_tpu.session.session import new_session
     from tinysql_tpu.columnar.store import bulk_load
-    monkeypatch.setenv("TINYSQL_DEVICE_JOIN_ONLY", "1")
     s = new_session()
     s.execute("create database dj")
     s.execute("use dj")

@@ -3989,19 +3989,10 @@ class DevPipeExec:
 
     @staticmethod
     def _enabled(ctx) -> bool:
-        """Pipelines win where transfers dominate (real devices).  On the
-        XLA:CPU backend the compact numpy per-operator tier is faster, so
-        auto mode engages only off-cpu; tests force with tidb_devpipe=1."""
+        """tidb_devpipe: auto (-1) and 1 run the pipeline (1 also makes
+        _bail re-raise); 0 selects the per-operator tier."""
         raw = ctx.session_vars.get("tidb_devpipe", -1)
-        mode = -1 if raw is None else int(raw)
-        if mode == 0:
-            return False
-        if mode == 1:
-            return True
-        try:
-            return kernels.jax().default_backend() != "cpu"
-        except Exception:
-            return False
+        return raw is None or int(raw) != 0
 
     def _open_fallback(self, ctx):
         self._fallback = self._fallback_builder(self.plan)

@@ -197,15 +197,10 @@ def _would_spill_here(ctx, plan) -> bool:
                                                       None)))
 
 
-def _mask_compact_threshold() -> float:
-    """Below this selectivity, compacting beats masking.  On real TPUs
-    masked full-table kernels win almost always (stable shapes = one
-    compile; throughput absorbs the extra rows); on the CPU backend the
-    extra rows are pure cost, so compact much more aggressively."""
-    try:
-        return 0.3 if kernels.jax().default_backend() == "tpu" else 0.75
-    except Exception:
-        return 0.3
+#: below this selectivity, compacting beats masking: masked full-table
+#: kernels win almost always (stable shapes = one compile; throughput
+#: absorbs the extra rows)
+MASK_COMPACT_THRESHOLD = 0.3
 
 
 def _take_replica_masked(ex: Executor, extra_conds=None):
@@ -444,7 +439,7 @@ def _compact_if_selective(chk: Chunk, mask):
     its final few rows — the gather defers to that cardinality."""
     from ..chunk.column import LazyTakeColumn
     if (mask is not None and mask.size
-            and mask.mean() < _mask_compact_threshold()):
+            and mask.mean() < MASK_COMPACT_THRESHOLD):
         sel = np.nonzero(mask)[0]
         cols = []
         for c in chk.columns:
@@ -640,7 +635,7 @@ class TPUHashAggExec(Executor):
         """Everything of ``_try_fused_device`` up to the launch: a
         closure that runs the one program and assembles its output, or
         what a path that does not come to that returned (None to fall
-        back; the block-wise and host twins' finished chunk)."""
+        back; the block-wise path's finished chunk)."""
         from .executors import TableReaderExec
         from ..ops.exprjit import is_jittable
         plan = self.plan
@@ -779,25 +774,6 @@ class TPUHashAggExec(Executor):
                 return out
             child._replica = rep
             return None
-
-        # ---- CPU-backend host twin for SCATTER-BOUND group-bys: above
-        # SEG_UNROLL segments the device kernel scatter-adds, which
-        # XLA:CPU runs serially, while np.bincount with REPLICA-MEMOIZED
-        # argument columns is the host-optimal kernel.  Below that
-        # threshold the fused device program still wins ON THIS BACKEND
-        # (measured on XLA:CPU: Q1 0.73s fused vs 1.47s host — its
-        # on-device args/mask avoid numpy's materialized temporaries).  Runs BEFORE the device-mask build so the twin never pays
-        # for a device filter program it would discard.
-        if (plan.group_by and n_segments > kernels.SEG_UNROLL
-                and kernels.host_kernels_ok()
-                and self._mesh_if_enabled(nb) is None
-                and self._host_groupby_ok(specs, slots, arg_exprs)):
-            out = self._fused_host_groupby(chk, child, rep, filters,
-                                           specs, arg_exprs, slots,
-                                           key_layouts, n_segments, n,
-                                           slot_ids)
-            if out is not None:
-                return out
 
         # ---- filter mask: on-device program when every condition lowers
         # (constants as runtime params — zero recompiles across constant
@@ -1337,94 +1313,6 @@ class TPUHashAggExec(Executor):
         return self._assemble_output(chk, plan, slots, out_keys, out_aggs,
                                      first_orig, [d for _, _, d in keys])
 
-    @staticmethod
-    def _host_groupby_ok(specs, slots, arg_exprs) -> bool:
-        """Host-twin eligibility: bincount-able specs only (min/max need
-        ufunc.at, which loses to the device kernel), no first_row
-        gathers, and no exact int64 SUMs (float64 accumulation caps at
-        the 2^53 mantissa) — checked UPFRONT so an ineligible query
-        never pays O(n) twin work before bailing."""
-        for (kind, _), a in zip(specs, arg_exprs):
-            if kind not in ("sum", "sum0", "count", "count_star"):
-                return False
-            if (kind == "sum" and a is not None
-                    and not isinstance(a, tuple)
-                    and a.eval_type is EvalType.INT):
-                return False
-        return all(sl[0] != "first" for sl in slots)
-
-    @staticmethod
-    def _host_arg_key(a, slot_ids) -> tuple:
-        """Replica-memo key for an argument expression: the shape key
-        plus the STABLE column ids its offsets refer to — replicas are
-        shared across queries with different column pruning, so the
-        offsets inside stable_key alone would collide (the slot-id
-        invariant at the top of _try_fused_device)."""
-        from ..ops.exprjit import stable_key
-        cols = sorted({c.index for c in a.collect_columns()})
-        return ("hostarg", stable_key(a),
-                tuple((i, slot_ids[i]) for i in cols))
-
-    def _fused_host_groupby(self, chk, child, rep, filters, specs,
-                            arg_exprs, slots, key_layouts,
-                            n_segments: int, n: int, slot_ids):
-        """numpy twin of the scatter-bound fused segment aggregate (CPU
-        backend): host filter mask + replica-MEMOIZED argument columns +
-        np.bincount per spec over the composite group ids.  Returns an
-        output chunk, or None to fall back to the device kernels."""
-        fmask = _fold_filter_masks(child, rep, chk, filters) \
-            if filters else None
-        gid = key_layouts[0][0] if len(key_layouts) == 1 else rep.memo(
-            ("gid_host", tuple(slot_ids[e.index]
-                               for e in self.plan.group_by)),
-            lambda: self._compose_gid(key_layouts, n))
-        ns = n_segments
-        kernels.host_dispatch()  # the twin IS the kernel on this backend
-        g_valid = gid if fmask is None else gid[fmask]
-        presence = np.bincount(g_valid, minlength=ns)
-        present = np.nonzero(presence > 0)[0]
-        out_aggs = []
-        for (kind, _has_arg), a in zip(specs, arg_exprs):
-            if kind == "count_star":
-                out_aggs.append((presence[present].astype(np.int64),
-                                 np.zeros(len(present), dtype=bool)))
-                continue
-            if isinstance(a, tuple):  # ("mask", slot): COUNT(col)
-                m = chk.columns[a[1]].null_mask()
-                vals = None
-            else:
-                # memoized per (replica version, expression shape, the
-                # STABLE ids of its columns): the twin's economics depend
-                # on never re-evaluating args per query
-                vals, m = rep.memo(self._host_arg_key(a, slot_ids),
-                                   lambda a=a: a.vec_eval(chk))
-            live = ~np.asarray(m, dtype=bool)
-            if fmask is not None:
-                live = live & fmask
-            gl = gid[live]
-            if kind == "count":
-                c = np.bincount(gl, minlength=ns)
-                out_aggs.append((c[present].astype(np.int64),
-                                 np.zeros(len(present), dtype=bool)))
-                continue
-            # sum / sum0: float64 accumulation — exact for counts and
-            # doubles (int64 SUMs were rejected upfront by the gate)
-            v = np.asarray(vals)[live]
-            if v.dtype != np.float64:
-                v = v.astype(np.float64)
-            ssum = np.bincount(gl, weights=v, minlength=ns)
-            if kind == "sum0":  # merged COUNT: 0 over empty, never NULL
-                out_aggs.append((ssum[present].astype(np.int64),
-                                 np.zeros(len(present), dtype=bool)))
-            else:
-                c = np.bincount(gl, minlength=ns)
-                out_aggs.append((ssum[present], (c == 0)[present]))
-        out_keys = self._decode_present(present, key_layouts)
-        first_orig = np.zeros(len(present), dtype=np.int64)
-        return self._assemble_output(chk, self.plan, slots, out_keys,
-                                     out_aggs, first_orig,
-                                     [l[3] for l in key_layouts])
-
     def _can_device_passthrough(self, plan, slots, key_layouts) -> bool:
         """Late-materialization gate (VERDICT r4 next-2): the aggregate's
         output chunk stays device-resident (DeviceColumn) when every
@@ -1673,11 +1561,6 @@ class TPUHashJoinExec(Executor):
         stream = (budget > 0 and probe_chk.full_rows() > budget
                   and sctx is None)
 
-        # every join branch has a numpy twin on the CPU backend
-        # (kernels.host_kernels_ok honors TINYSQL_DEVICE_JOIN_ONLY):
-        # route keys to host there; device-resident/memoized otherwise
-        host_keys = kernels.host_kernels_ok()
-
         # multi-key equi-joins ride ONE composite int64 lane (joint
         # factorization over both sides — collision-free by
         # construction), then the single-key kernels apply unchanged
@@ -1692,8 +1575,7 @@ class TPUHashJoinExec(Executor):
             if stream and side == probe_side:
                 v, m = expr.vec_eval(chk)  # host: no full-column upload
                 return np.asarray(v), np.asarray(m)
-            return self._key_arrays(expr, chk, rep, side,
-                                    host_keys=host_keys)
+            return self._key_arrays(expr, chk, rep, side)
 
         key_exprs = (plan.left_keys[0], plan.right_keys[0])
         side_chks = (lchk, rchk)
@@ -1748,22 +1630,16 @@ class TPUHashJoinExec(Executor):
             block start.  Stable block shapes = one compiled program.
 
             PIPELINED: the staging thread slices the next probe block
-            (and pre-uploads its padded key arrays when the device match
-            kernel will run) while the current block's match executes;
-            results concatenate in block order, so depth 0 (synchronous)
-            is byte-identical."""
-            dev_stage = not (kernels.host_kernels_ok()
-                             and isinstance(bkey[0], np.ndarray))
-            jn = kernels.jnp() if dev_stage else None
+            and pre-uploads its padded key arrays) while the current
+            block's match executes; results concatenate in block order, so
+            depth 0 (synchronous) is byte-identical."""
 
             def stage(s_):
                 e_ = min(s_ + budget, n_probe)
                 m = e_ - s_
-                kv, kn = pk[s_:e_], pn[s_:e_]
-                if dev_stage:
-                    blk = kernels.bucket(max(m, 1))
-                    kv = kernels.h2d_pad(kv, blk)
-                    kn = kernels.h2d_pad(kn, blk, True)
+                blk = kernels.bucket(max(m, 1))
+                kv = kernels.h2d_pad(pk[s_:e_], blk)
+                kn = kernels.h2d_pad(pn[s_:e_], blk, True)
                 pm = None if pmask is None else pmask[s_:e_]
                 return s_, (kv, kn), m, pm
 
@@ -1958,7 +1834,7 @@ class TPUHashJoinExec(Executor):
                       plan)
         # partitioned semijoin scatters HOST key lanes with the spill
         # partitioner — device-resident keys would round-trip anyway
-        host_keys = kernels.host_kernels_ok() or mesh is not None
+        host_keys = mesh is not None
         if len(plan.left_keys) > 1:
             (lk, lnull), (rk, rnull) = _composite_key_lanes(
                 plan.left_keys, lchk, plan.right_keys, rchk)
@@ -2090,9 +1966,7 @@ class TPUHashJoinExec(Executor):
         replica, PADDED DEVICE arrays memoized on the replica (no re-upload
         per query); device-resident for a DeviceColumn child (an aggregate
         output that never landed on host); numpy otherwise.  `host_keys`
-        (a unique-join on the CPU backend) lands keys on host instead —
-        XLA:CPU "device" buffers are host memory, so landing is a memcpy
-        and the numpy match twin beats the serial device kernels."""
+        (the partitioned semijoin) lands keys on host instead."""
         from ..chunk import DeviceColumn
         from ..expression import Column as ExprColumn
         from .executors import TableReaderExec

@@ -44,8 +44,8 @@ _DEVICE_METRICS = {
                             "Dispatches closed with block_until_ready "
                             "by the sampling profiler"),
     "host_dispatches": ("tinysql_host_dispatches_total",
-                        "Host-twin kernel invocations (numpy twins "
-                        "serving the XLA:CPU backend)"),
+                        "Always 0: no path counts here any more; kept "
+                        "for the benchmark's deployment check"),
     "agg_dense": ("tinysql_agg_dense_total",
                   "Fused GROUP BYs reduced in row order with masked "
                   "reductions (at most SEG_UNROLL groups): over a replica "

@@ -120,6 +120,8 @@ def jnp():
 # stats_delta — a high-water is not a per-interval delta).
 STATS = {"dispatches": 0, "d2h_transfers": 0, "d2h_bytes": 0,
          "h2d_transfers": 0, "h2d_bytes": 0,
+         # no writer: the benchmark's deployment check reads it as 0
+         # (benchmark/deployments/columnar_inprocess.py)
          "host_dispatches": 0,
          "agg_dense": 0, "agg_sorted": 0, "agg_clustered": 0,
          "agg_span_cut": 0, "agg_count32": 0,
@@ -171,18 +173,6 @@ def stats_set(key: str, n) -> None:
     stands, not an increment — stats_delta reports it whole."""
     with _STATS_MU:
         STATS[key] = n
-
-
-def host_dispatch(n: int = 1) -> None:
-    """Count one HOST-TWIN kernel invocation — the numpy implementations
-    that deliberately serve join match / top-k selection / group-by on
-    the XLA:CPU backend (host_kernels_ok), where they beat the serial
-    device lowerings.  Without this counter a query served entirely by
-    twins reports dispatches=0 and is indistinguishable from one that
-    silently fell off the accelerated paths (the BENCH_r05 Q3 mystery);
-    chip_smoke.py and benchmark/deployments/ require host_dispatches == 0
-    on the chip, tools/workload_smoke.py dispatches + host_dispatches > 0."""
-    stats_add("host_dispatches", n)
 
 
 def pipe_overlap_frac(d: dict) -> float:
@@ -930,16 +920,13 @@ SEG_UNROLL = 64
 class _SegReduce:
     """Segment-reduction strategy: scatter-based (any ns) or masked
     reductions in row order, one per segment (small ns).  gid/valid fixed
-    at construction.  ``unroll=None`` chooses from ns and the backend (the
-    per-operator tier); the fused pipeline's dense GROUP BY passes True,
-    having chosen from its group count alone."""
+    at construction.  ``unroll=None`` chooses from ns (the per-operator
+    tier); the fused pipeline's dense GROUP BY passes True, having chosen
+    from its group count alone."""
 
     def __init__(self, j, jn, gid, valid, ns: int, unroll=None):
         self.j, self.jn, self.gid, self.valid, self.ns = j, jn, gid, valid, ns
-        # XLA:CPU lowers scatter-adds to a tight loop (fast) and would pay
-        # ns full passes for the unroll; on TPU it's the reverse
-        self.unroll = (ns <= SEG_UNROLL and j.default_backend() != "cpu") \
-            if unroll is None else unroll
+        self.unroll = ns <= SEG_UNROLL if unroll is None else unroll
         if self.unroll:
             # [ns, n] membership: XLA fuses it into each reduction's
             # streaming pass over gid, it is never materialized
@@ -1628,57 +1615,6 @@ def _join_expand_kernel(outer: bool, ob2: int):
     return counted_jit(kernel), schema
 
 
-def _np_join_expand(lk, ln, lv, rk, rn, rv, outer: bool):
-    """Host twin of the expansion join: identical (li, ri) CONTRACT AND
-    ORDER (probe-major; within a probe row, build rows in stable
-    key-sorted order) so switching paths never reorders results.  Dense
-    int64 build keys use a direct-address CSR (bincount starts/counts)
-    instead of two searchsorted passes."""
-    r_live = rv & ~rn
-    bidx = np.nonzero(r_live)[0]
-    bk = rk[bidx]
-    l_live = lv & ~ln
-    n_l = len(lk)
-    if len(bk) == 0:
-        if outer:
-            li = np.nonzero(lv)[0]
-            return (li.astype(np.int64),
-                    np.full(len(li), -1, dtype=np.int64))
-        z = np.empty(0, dtype=np.int64)
-        return z, z
-    order = np.argsort(bk, kind="stable")
-    brow = bidx[order]          # build rows, key-sorted, stable
-    if bk.dtype == np.int64:
-        kmin = int(bk.min())
-        card = int(bk.max()) - kmin + 1
-    else:
-        card = None
-    if card is not None and card <= max(1 << 22, 4 * len(bk)):
-        cnt_k = np.bincount(bk - kmin, minlength=card)
-        starts_k = np.concatenate(([0], np.cumsum(cnt_k)[:-1]))
-        idx = np.clip(lk - kmin, 0, card - 1)
-        in_r = l_live & (lk >= kmin) & (lk < kmin + card)
-        lo = np.where(in_r, starts_k[idx], 0)
-        counts = np.where(in_r, cnt_k[idx], 0)
-    else:
-        bk_s = bk[order]
-        lo = np.searchsorted(bk_s, lk, side="left")
-        hi = np.searchsorted(bk_s, lk, side="right")
-        counts = np.where(l_live, hi - lo, 0)
-    eff = np.where(lv & (counts == 0), 1, counts) if outer else counts
-    total = int(eff.sum())
-    if total == 0:
-        z = np.empty(0, dtype=np.int64)
-        return z, z
-    li = np.repeat(np.arange(n_l, dtype=np.int64), eff)
-    starts = np.cumsum(eff) - eff
-    pos = np.arange(total, dtype=np.int64) - starts[li]
-    matched = counts[li] > 0
-    ridx = np.minimum(lo[li] + pos, len(brow) - 1)
-    ri = np.where(matched, brow[ridx], -1)
-    return li, ri.astype(np.int64)
-
-
 def join_match(lkey: Tuple[np.ndarray, np.ndarray], n_left: int,
                rkey: Tuple[np.ndarray, np.ndarray], n_right: int,
                outer: bool = False, lvalid: np.ndarray = None,
@@ -1687,19 +1623,7 @@ def join_match(lkey: Tuple[np.ndarray, np.ndarray], n_left: int,
     outer, unmatched VALID left rows appear once with right index -1.
     `lvalid`/`rvalid` fold side filters into the kernel's masks so callers
     skip host compaction AND keep bucket shapes stable across differently
-    selective filters (one TPU compile per table size, not per filter).
-    Host-array inputs on the CPU backend run the numpy twin."""
-    if (isinstance(lkey[0], np.ndarray) and isinstance(rkey[0], np.ndarray)
-            and host_kernels_ok()):
-        lv = np.ones(n_left, dtype=bool) if lvalid is None \
-            else np.asarray(lvalid[:n_left], dtype=bool)
-        rv = np.ones(n_right, dtype=bool) if rvalid is None \
-            else np.asarray(rvalid[:n_right], dtype=bool)
-        host_dispatch()
-        return _np_join_expand(
-            np.asarray(lkey[0])[:n_left], np.asarray(lkey[1])[:n_left],
-            lv, np.asarray(rkey[0])[:n_right],
-            np.asarray(rkey[1])[:n_right], rv, outer)
+    selective filters (one TPU compile per table size, not per filter)."""
     jn = jnp()
     nlb, nrb = bucket(max(n_left, 1)), bucket(max(n_right, 1))
     lv = np.zeros(nlb, dtype=bool)
@@ -1788,70 +1712,6 @@ def _unique_pick_kernel(ob: int, nlb: int, outer: bool):
     return counted_jit(kernel), schema
 
 
-def host_kernels_ok() -> bool:
-    """True when numpy kernel twins should serve host-array inputs: the
-    XLA:CPU backend (where device sort/searchsorted run serially) and no
-    TINYSQL_DEVICE_JOIN_ONLY override (tests force the device kernels
-    with it).  The ONE definition every host-vs-device routing decision
-    shares."""
-    if os.environ.get("TINYSQL_DEVICE_JOIN_ONLY"):
-        return False
-    try:
-        return jax().default_backend() == "cpu"
-    except Exception:
-        return False
-
-
-def _np_unique_join(lk, ln, lv, rk, rn, rv, outer: bool):
-    """Host twin of the unique-join kernel (same li/ri contract and tie
-    semantics): on XLA:CPU the device sort+searchsorted runs serially
-    while numpy's is substantially faster — the same backend-aware kernel
-    choice _topk_single makes."""
-    r_live = rv & ~rn
-    bidx = np.nonzero(r_live)[0]
-    bk = rk[bidx]
-    l_live = lv & ~ln
-    if len(bk) == 0:
-        if outer:
-            # ALL valid left rows survive (NULL keys null-extend)
-            li = np.nonzero(lv)[0]
-            return li, np.full(len(li), -1, dtype=np.int64)
-        z = np.empty(0, dtype=np.int64)
-        return z, z
-    if bk.dtype == np.int64:
-        kmin = int(bk.min())
-        kmax = int(bk.max())
-        card = kmax - kmin + 1
-    else:
-        card = None  # float keys: range addressing is meaningless
-    if card is not None and card <= max(1 << 22, 4 * len(bk)):
-        # direct-address table over the build key range (~10x faster
-        # than searchsorted per probe; devpipe's pos_table twin)
-        tbl = np.full(card, -1, dtype=np.int64)
-        tbl[bk - kmin] = bidx
-        idx = np.clip(lk - kmin, 0, card - 1)
-        cand = tbl[idx]
-        match = (l_live & (lk >= kmin) & (lk <= kmax) & (cand >= 0))
-        if outer:
-            li = np.nonzero(lv)[0]
-            ri = np.where(match[li], cand[li], -1)
-            return li.astype(np.int64), ri.astype(np.int64)
-        li = np.nonzero(match)[0]
-        return li.astype(np.int64), cand[li].astype(np.int64)
-    order = np.argsort(bk, kind="stable")
-    bk_s = bk[order]
-    brow = bidx[order]
-    pos = np.searchsorted(bk_s, lk, side="left")
-    pos_c = np.minimum(pos, len(bk_s) - 1)
-    match = l_live & (pos < len(bk_s)) & (bk_s[pos_c] == lk)
-    if outer:
-        li = np.nonzero(lv)[0]
-        ri = np.where(match[li], brow[pos_c[li]], -1)
-        return li.astype(np.int64), ri.astype(np.int64)
-    li = np.nonzero(match)[0]
-    return li.astype(np.int64), brow[pos_c[li]].astype(np.int64)
-
-
 def unique_join_match(lkey, n_left: int, rkey, n_right: int,
                       outer: bool = False, lvalid: np.ndarray = None,
                       rvalid: np.ndarray = None,
@@ -1862,22 +1722,7 @@ def unique_join_match(lkey, n_left: int, rkey, n_right: int,
     bounded by n_left — no count kernel, no expansion, and no
     device->host size sync.  Same (li, ri) contract as join_match.
     `build_sorted` asserts the build keys already ascend among live rows
-    (dead rows at the tail) and skips the device argsort.
-
-    On the CPU backend with HOST key arrays, the match runs in numpy
-    (TINYSQL_DEVICE_JOIN_ONLY=1 forces the device kernels, e.g. to
-    exercise block-streaming device economics in tests)."""
-    if (isinstance(lkey[0], np.ndarray) and isinstance(rkey[0], np.ndarray)
-            and host_kernels_ok()):
-        lv = np.ones(n_left, dtype=bool) if lvalid is None \
-            else np.asarray(lvalid[:n_left], dtype=bool)
-        rv = np.ones(n_right, dtype=bool) if rvalid is None \
-            else np.asarray(rvalid[:n_right], dtype=bool)
-        host_dispatch()
-        return _np_unique_join(
-            np.asarray(lkey[0])[:n_left], np.asarray(lkey[1])[:n_left],
-            lv, np.asarray(rkey[0])[:n_right],
-            np.asarray(rkey[1])[:n_right], rv, outer)
+    (dead rows at the tail) and skips the device argsort."""
     jn = jnp()
     nlb, nrb = bucket(max(n_left, 1)), bucket(max(n_right, 1))
     lv = np.zeros(nlb, dtype=bool)
@@ -1973,50 +1818,14 @@ def _semi_pick_kernel(ob: int, nlb: int):
     return counted_jit(kernel), schema
 
 
-def _np_semi_match(lk, ln, lv, rk, rn, rv, anti: bool, null_aware: bool):
-    """Host twin of the semi/anti membership kernel: identical keep
-    semantics and probe-order output."""
-    n_build = int(rv.sum())
-    if n_build == 0:
-        # empty subquery: semi keeps nothing, anti keeps every valid
-        # probe row (NULL probe keys included — NOT IN () is TRUE)
-        keep = lv if anti else np.zeros(len(lk), dtype=bool)
-        return np.nonzero(keep)[0].astype(np.int64)
-    if anti and null_aware and bool((rv & rn).any()):
-        return np.empty(0, dtype=np.int64)  # NULL in the build set
-    bk = rk[rv & ~rn]
-    l_live = lv & ~ln
-    member = np.zeros(len(lk), dtype=bool)
-    if len(bk):
-        member[l_live] = np.isin(lk[l_live], bk)
-    if anti:
-        keep = lv & ~member & (~ln if null_aware else True)
-    else:
-        keep = member
-    return np.nonzero(keep)[0].astype(np.int64)
-
-
 def semi_join_match(lkey, n_left: int, rkey, n_right: int,
                     anti: bool = False, null_aware: bool = False,
                     lvalid: np.ndarray = None,
                     rvalid: np.ndarray = None) -> np.ndarray:
     """Probe-row indices surviving a semi (membership) or anti
-    (non-membership) test against the build side, in probe order.
-    Same host-vs-device routing contract as join_match: host numpy twin
-    on the CPU backend, padded-bucket device kernels otherwise (the
-    progcache key is shape-only, so literal changes stay cache HITs)."""
-    if (isinstance(lkey[0], np.ndarray) and isinstance(rkey[0], np.ndarray)
-            and host_kernels_ok()):
-        lv = np.ones(n_left, dtype=bool) if lvalid is None \
-            else np.asarray(lvalid[:n_left], dtype=bool)
-        rv = np.ones(n_right, dtype=bool) if rvalid is None \
-            else np.asarray(rvalid[:n_right], dtype=bool)
-        host_dispatch()
-        return _np_semi_match(
-            np.asarray(lkey[0])[:n_left],
-            np.asarray(lkey[1])[:n_left], lv,
-            np.asarray(rkey[0])[:n_right],
-            np.asarray(rkey[1])[:n_right], rv, anti, null_aware)
+    (non-membership) test against the build side, in probe order, by
+    padded-bucket device kernels (the progcache key is shape-only, so
+    literal changes stay cache HITs)."""
     jn = jnp()
     nlb, nrb = bucket(max(n_left, 1)), bucket(max(n_right, 1))
     lv = np.zeros(nlb, dtype=bool)
@@ -2169,20 +1978,6 @@ def _topk_single(key, desc: bool, n_rows: int, k: int):
     if score is None:
         return None
     pad_val = np.iinfo(np.int64).min if v.dtype == np.int64 else -np.inf
-    if jax().default_backend() == "cpu":
-        # XLA:CPU's top_k lowering barely beats the full sort; host
-        # partition selection is ~100x faster there.  Exact stable-tie
-        # semantics: all rows above the threshold, then lowest-index rows
-        # AT the threshold.
-        host_dispatch()
-        s = score[:n_rows]
-        kk = min(k, n_rows)
-        t = np.partition(s, n_rows - kk)[n_rows - kk]
-        above = np.nonzero(s > t)[0]
-        at = np.nonzero(s == t)[0][:kk - len(above)]
-        ids = np.concatenate([above, at])
-        return ids[np.lexsort((ids, -s[ids]))]
-    jn = jnp()
     kb = bucket(max(k, 1))
     if kb > nb:
         return None
@@ -2244,7 +2039,6 @@ def host_sort_permutation(key_cols, descs, n_rows: int) -> np.ndarray:
     device kernel's exact semantics): the budget-respecting path for
     tables above tidb_device_block_rows, where uploading every sort key
     whole would violate the device memory budget."""
-    host_dispatch()
     keys = [(v[:n_rows], m[:n_rows]) for v, m in key_cols]
     return _np_lexsort_perm(keys, descs)
 
@@ -2265,7 +2059,6 @@ def _topk_multi(key_cols, descs, n_rows: int, k: int):
     cand = np.nonzero(s >= t)[0]
     if len(cand) * 4 > n_rows * 3:
         return None  # degenerate ties: the full sort is no worse
-    host_dispatch()
     order = _np_lexsort_perm(key_cols, descs, cand)
     return cand[order[:kk]]
 
@@ -2335,8 +2128,6 @@ def prewarm_bucket(nb: int, k_buckets=(16, 128)) -> int:
                 compiled += aot(("sort", (desc,), nb, (dts,)),
                                 lambda desc=desc: _sort_kernel((desc,)),
                                 [sds(dt)], [sds(jn.bool_)], sds(jn.bool_))
-            if j.default_backend() == "cpu":
-                continue  # _topk_single routes to np.partition on XLA:CPU
             for kb in k_buckets:
                 if kb <= nb:
                     compiled += aot(("topk", nb, kb, dts),

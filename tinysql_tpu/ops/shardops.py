@@ -405,7 +405,7 @@ def semi_join_match_sharded(mesh, lkey, n_left: int, rkey, n_right: int,
     """Partitioned semi/anti membership over the mesh: probe and build
     sides hash-partition with the spill partitioner, each shard answers
     membership for its partition, and the host applies the exact
-    semi/anti/NOT IN keep ladder (kernels._np_semi_match semantics) over
+    semi/anti/NOT IN keep ladder (kernels.semi_join_match's) over
     the re-assembled member bits.  Returns surviving probe indices in
     probe order, or None when sharding does not apply."""
     n = dist.mesh_shards(mesh)

@@ -39,8 +39,8 @@ DEFAULT_SYSVARS: Dict[str, Datum] = {
     "tidb_index_lookup_concurrency": 4,
     "tidb_use_tpu": 1,           # device enforcer master switch
     "tidb_tpu_min_rows": 8192,   # row gate: smaller inputs stay on CPU
-    "tidb_devpipe": -1,          # device pipelines: -1 auto (device
-                                 # backends only), 0 off, 1 force
+    "tidb_devpipe": -1,          # fused device pipeline: -1 auto (on,
+                                 # faults fall back), 0 off, 1 force
     "tidb_enable_cascades_planner": 0,
     "tidb_mesh_parallel": 0,     # shard fused aggregates over the device mesh
     # mesh join strategy: build sides with more (bucketed) rows than this

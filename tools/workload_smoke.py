@@ -5,9 +5,9 @@ TPC-H Q5/Q10/Q18 end-to-end through the SQL front door at SF=0.02:
 
 1. every workload query's rows must equal sqlite3 over the SAME
    generated data (canonicalized float compare);
-2. every query must do kernel work — >= 1 device (or host-twin)
-   dispatch — i.e. the multi-join/semijoin plans actually reached the
-   accelerated tier rather than silently falling back whole;
+2. every query is ONE fused device program (``tidb_devpipe = 1``, so a
+   pipeline fault raises instead of falling back): one dispatch, its
+   joins in the program;
 3. the second run of each query must compile NOTHING (the PR 6
    literal-parameterized program families cover the new semijoin /
    join-chain operators);
@@ -16,10 +16,7 @@ TPC-H Q5/Q10/Q18 end-to-end through the SQL front door at SF=0.02:
    ANALYZE`` must carry device counters on the join chain;
 5. UPDATE must round-trip through the same front door (the read path
    shares the decorrelated planner);
-6. with the chip's branches forced (``TINYSQL_DEVICE_JOIN_ONLY=1``,
-   ``tidb_devpipe = 1``) every query is ONE fused device program: one
-   dispatch, no host twin, the same rows;
-7. the same under a forced mesh (``tidb_mesh_parallel = 1`` over four
+6. the same under a forced mesh (``tidb_mesh_parallel = 1`` over four
    host devices): still one dispatch each, launched over the whole
    mesh, the views traced under it and Q5's and Q10's keyed GROUP BYs
    reduced a shard at a time.
@@ -82,27 +79,6 @@ def main() -> int:
     lite = tpch.sqlite_mirror(data)
     _canon = tpch.canon_rows
 
-    for q, sql in tpch.WORKLOAD.items():
-        want = _canon(lite.execute(sql).fetchall())
-        snap = kernels.stats_snapshot()
-        got = _canon(s.query(sql).rows)
-        d = kernels.stats_delta(snap)
-        check(f"{q} matches sqlite", got == want,
-              f"{len(got)} rows vs {len(want)}")
-        disp = d.get("dispatches", 0) + d.get("host_dispatches", 0)
-        check(f"{q} did kernel work", disp >= 1,
-              f"dispatches={d.get('dispatches', 0)} "
-              f"host={d.get('host_dispatches', 0)}")
-        snap = kernels.stats_snapshot()
-        s.query(sql)
-        d2 = kernels.stats_delta(snap)
-        check(f"{q} second run compiles nothing",
-              d2.get("progcache_misses", 0) == 0,
-              f"misses={d2.get('progcache_misses', 0)}")
-
-    # the chip's branches forced on the CPU: each of the three is ONE
-    # fused device program, no host twin, still sqlite's answer
-    os.environ["TINYSQL_DEVICE_JOIN_ONLY"] = "1"
     s.execute("set @@tidb_devpipe = 1")
     try:
         for q, sql in tpch.WORKLOAD.items():
@@ -110,7 +86,7 @@ def main() -> int:
             snap = kernels.stats_snapshot()
             got = _canon(s.query(sql).rows)
             d = kernels.stats_delta(snap)
-            check(f"{q} fused matches sqlite", got == want,
+            check(f"{q} matches sqlite", got == want,
                   f"{len(got)} rows vs {len(want)}")
             check(f"{q} is one fused program",
                   d.get("dispatches", 0) == 1
@@ -119,6 +95,12 @@ def main() -> int:
                   f"dispatches={d.get('dispatches', 0)} "
                   f"host={d.get('host_dispatches', 0)} "
                   f"joins={d.get('pipe_joins', 0)}")
+            snap = kernels.stats_snapshot()
+            s.query(sql)
+            d2 = kernels.stats_delta(snap)
+            check(f"{q} second run compiles nothing",
+                  d2.get("progcache_misses", 0) == 0,
+                  f"misses={d2.get('progcache_misses', 0)}")
         # and under a forced mesh of the host's devices
         from tinysql_tpu.parallel import dist
         floor, dist.MIN_SHARD_ROWS = dist.MIN_SHARD_ROWS, 16
@@ -148,7 +130,6 @@ def main() -> int:
             dist.MIN_SHARD_ROWS = floor
     finally:
         s.execute("set @@tidb_devpipe = -1")
-        del os.environ["TINYSQL_DEVICE_JOIN_ONLY"]
 
     plan = s.query("explain " + tpch.Q5).rows
     flat = "\n".join(str(r) for r in plan)
