@@ -1037,3 +1037,64 @@ def test_fused_join_chain_mesh_program_at_the_sf10_shapes(
         assert _gathers(text, 100_000, "pred") == {"join/join": 1}
         assert _gathers(text, 1_500_000, "pred") == \
             {"join/join/join/join": 1}
+
+
+# ---- the correlated-subquery reports: Q2, Q17 as one fused program each ----
+
+@pytest.fixture(scope="module")
+def subq_session():
+    """The benchmark's own tables at SF=0.05 (``lineitem``'s bucket 2^19,
+    ``partsupp``'s 2^14) and the cell ``tpch_sf1_subq.subq_stream``'s two
+    statements as its traffic file spells them."""
+    import importlib.util
+    from tinysql_tpu.columnar.store import bulk_load
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    mods = {}
+    for name in ("datasets/tpch_subq.py", "traffic.py"):
+        spec = importlib.util.spec_from_file_location(
+            "chip_compile_" + name.replace("/", "_").replace(".", "_"),
+            os.path.join(root, "benchmark", name))
+        mods[name] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mods[name])
+    data = mods["datasets/tpch_subq.py"]
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "subq_stream.json")) as f:
+        sqls = {st.kind: st.sql
+                for st in mods["traffic.py"].expand(json.load(f))}
+    ds = data.generate(DEVPIPE_SF, 2_147_483_747)
+    s = new_session()
+    s.execute(f"create database {data.DATABASE}")
+    s.execute(f"use {data.DATABASE}")
+    for table, ddl in data.SCHEMAS.items():
+        s.execute(ddl)
+        bulk_load(s.storage, s.infoschema().table_by_name(data.DATABASE,
+                                                          table),
+                  ds.tables[table])
+    s.execute("set @@tidb_devpipe = 1")
+    s.execute("set @@tidb_tpu_min_rows = 0")
+    return s, sqls
+
+
+@pytest.mark.parametrize("name", ["q2", "q17"])
+def test_correlated_subquery_reports_compile_as_one_program(
+        one_chip, no_persistent_cache, monkeypatch, subq_session, name):
+    """Q2 and Q17, their correlated scalar subqueries decorrelated into
+    a GROUP BY joined back: ONE program each (the capture raises at the
+    first dispatch), compiled inside the joins' budget, fitting the
+    chip, with no sort GROUP BY and no sort at all; Q17's comparison is
+    the 64-bit integer one (``ops/exactcmp.py``)."""
+    import time
+    from tinysql_tpu.executor import devpipe
+    session, sqls = subq_session
+    t0 = time.time()
+    progcache.clear()
+    monkeypatch.setattr(devpipe, "COMPILED_NODE_KEYS", set())
+    fn, abstract = _capture(monkeypatch, session, sqls[name], one_chip)
+    text = _compile(fn, *abstract).as_text()
+    assert time.time() - t0 < JOIN_COMPILE_BUDGET_S
+    kinds = {k[0] for k in devpipe.COMPILED_NODE_KEYS}
+    assert {"aggindex", "join", "sel"} <= kinds, kinds
+    assert "sortgroup" not in kinds and not _sorted_lanes(text)
+    sels = [k for k in devpipe.COMPILED_NODE_KEYS if k[0] == "sel"]
+    assert any(key.startswith("exact:<@") for k in sels for key in k[1]) \
+        == (name == "q17"), sels

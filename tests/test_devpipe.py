@@ -635,16 +635,25 @@ def test_multikey_join_nonunique_build_csr(tk, counters):
 
 def test_multikey_join_other_conds_cpu_guard(tk, counters):
     _mk_fixture(tk)
-    # a non-equi ON conjunct puts other_conditions on the join: devpipe
-    # declines ANY such join, and the per-op tier must route multi-key
-    # plans to the CPU hash join (never the single-key device kernel,
-    # which would silently join on the first key only)
-    assert_match(tk, "select factk.fid, dimk.v from factk join dimk "
-                     "on factk.f1 = dimk.k1 and factk.f2 = dimk.k2 "
-                     "and factk.x < dimk.v order by factk.fid limit 30")
+    # a non-equi ON conjunct puts other_conditions on the join: the fused
+    # pipeline takes an inner join's as a filter above the join over the
+    # composite key lane, and the per-op tier must route multi-key plans
+    # to the CPU hash join (never the single-key device kernel, which
+    # would silently join on the first key only)
+    sql = ("select factk.fid, dimk.v from factk join dimk "
+           "on factk.f1 = dimk.k1 and factk.f2 = dimk.k2 "
+           "and factk.x < dimk.v order by factk.fid limit 30")
+    assert_match(tk, sql)
     # the per-test prepare counter (not the process-global key set, which
-    # earlier tests already populate) proves no devpipe join node ran
-    assert counters["join"] == 0, counters
+    # earlier tests already populate): the fused join node ran
+    assert counters["join"] >= 1, counters
+    joined = counters["join"]
+    tk.execute("set @@tidb_devpipe = 0")
+    try:
+        assert_match(tk, sql)
+    finally:
+        tk.execute("set @@tidb_devpipe = 1")
+    assert counters["join"] == joined, counters
 
 
 def test_scalar_agg_above_join(tk, counters):
@@ -1036,9 +1045,11 @@ def test_pipe_counters_on_metrics_and_in_the_benchmark(tpch_tk, key, grown):
             (f"{key}_per_query.mesh", ["tpch_sf1_mesh4.power_stream"]),
             (f"{key}_per_query.mesh10", ["tpch_sf10_mesh4.power_stream"]),
             # the four-chip joins cell reports under the joins' names
-            # (PR 39: appended to every ``.joins`` list)
+            # (appended to every ``.joins`` list), and so does the
+            # correlated-subquery cell
             (f"{key}_per_query.joins", ["tpch_sf1_joins.join_stream",
-                                        "tpch_sf10_joins_mesh4.join_stream"])]
+                                        "tpch_sf10_joins_mesh4.join_stream",
+                                        "tpch_sf1_subq.subq_stream"])]
     for m in mine:
         assert (m["unit"], m["better"], m["source"], m["layer"],
                 m["moves"]) == ("count", "higher", "program_counter",

@@ -1075,9 +1075,11 @@ def test_benchmark_reads_the_window_span(not_tracing):
                    "tpch_sf1_joins.join_stream"),
             ("stream_queries_per_s", "serve_queries_per_s",
              "stream_queries_per_s", "stream_queries_per_s")):
-        # (the four-chip joins cell, PR 39, reports under ``.joins``)
-        # and the burst on the event-loop front end under ``.serve``
-        later = {"joins": ["tpch_sf10_joins_mesh4.join_stream"],
+        # (the four-chip joins cell and the correlated-subquery cell
+        # report under ``.joins``) and the burst on the
+        # event-loop front end under ``.serve``
+        later = {"joins": ["tpch_sf10_joins_mesh4.join_stream",
+                           "tpch_sf1_subq.subq_stream"],
                  "serve": ["tpch_sf1_aio.idle_1k_burst"]}
         assert m["workloads"][0] == cell and cell in cells
         assert m["workloads"][1:] == later.get(m["name"].rsplit(".")[-1],

@@ -87,7 +87,7 @@ from ..chunk.column import _np_dtype
 from ..expression import Column as ExprColumn, Constant
 from ..expression.aggregation import AGG_COUNT, AGG_SUM
 from ..mytypes import EvalType
-from ..ops import kernels, progcache
+from ..ops import exactcmp, kernels, progcache
 from ..ops.exprjit import (DEAD, ParamTable, compile_expr_params,
                            is_jittable, stable_shape_key)
 from ..planner.physical import (PhysicalHashAgg, PhysicalHashJoin,
@@ -820,6 +820,9 @@ class _ReplicaLeaf:
         #: a broadcast join clears it on its build side's leaf, whose
         #: lanes then lie whole on every device
         self.spread = True
+        #: ``prepare``'s ``order`` for a leaf that a sorted GROUP BY
+        #: reads through a chain of row filters (``_AggIndexNode``)
+        self.order = None
 
     def rows_mesh(self, nb: int):
         """The mesh this leaf's lanes are row-sharded over, or None (one
@@ -895,7 +898,7 @@ class _ReplicaLeaf:
         from ..parallel import dist
         lrows, lwhole = _layouts(self.mesh)
         lay = lrows if self.rows_mesh(nb) is not None else lwhole
-        tag, perm = order or ((), None)
+        tag, perm = order or self.order or ((), None)
 
         def lane(kind, sid, host, fill=0):
             return _leaf_lane(rep, kind, sid, nb, host, fill, tag, perm,
@@ -1292,6 +1295,8 @@ class _AggIndexNode:
         self.out_map = out_map      # schema slot -> ("agg", i) | ("gb", j)
         self.gidx: Optional[GroupIndex] = None
         self._sids: Optional[tuple] = None
+        #: the leaf, or the row filters over it that compile() found
+        self.chain = leaf
 
     @staticmethod
     def compile(plan: PhysicalHashAgg, ctx: _Ctx):
@@ -1300,19 +1305,28 @@ class _AggIndexNode:
         if not all(_gb_key_ok(e) for e in plan.group_by):
             return None
         child = plan.children[0]
-        if not isinstance(child, PhysicalTableReader):
+        if isinstance(child, PhysicalTableReader):
+            leaf = chain = _ReplicaLeaf.compile(child, ctx)
+        elif _filters_a_reader(child) and ctx.mesh is None:
+            chain = _compile_node(child, ctx)
+            leaf = _filtered_leaf(chain)
+            if leaf is None:
+                _close_node(chain)
+                return None
+        else:
             return None
-        leaf = _ReplicaLeaf.compile(child, ctx)
         if leaf is None:
             return None
         got = _assemble_agg_specs(plan)
         out_map = _agg_out_map(plan)
         if got is None or out_map is None:
-            leaf.close()
+            _close_node(chain)
             return None
         specs, slots = got
-        return _AggIndexNode(leaf, plan, list(plan.group_by), specs, slots,
+        node = _AggIndexNode(leaf, plan, list(plan.group_by), specs, slots,
                              out_map)
+        node.chain = chain
+        return node
 
     def _count32(self, nb: int) -> bool:
         """Whether the sorted formulation counts rows in 32 bits: where
@@ -1430,7 +1444,11 @@ class _AggIndexNode:
         if span:
             kernels.stats_add("agg_span_cut", 1)
         count32 = not dense and self._count32(nb)
-        tv = self.leaf.prepare(pb, order=order)
+        # the leaf's rows filtered by the chain above it (selections,
+        # semi joins: validity alone, the rows where they lie), in the
+        # index's order as the leaf's own are
+        self.leaf.order = order
+        tv = self.chain.prepare(pb)
         if tv is None:
             return None
         #: the index lanes the reduction reads, in the kernel's order
@@ -1657,7 +1675,39 @@ class _AggIndexNode:
         return -1
 
     def close(self):
-        self.leaf.close()
+        _close_node(self.chain)
+
+
+def _filters_a_reader(plan) -> bool:
+    """``plan`` is a table reader under row filters that keep its rows
+    and columns where they are: selections and semi / anti joins, the
+    reader on the probe side."""
+    while isinstance(plan, PhysicalSelection) or (
+            isinstance(plan, PhysicalHashJoin)
+            and not isinstance(plan, PhysicalMergeJoin)
+            and plan.tp in ("semi", "anti")):
+        plan = plan.children[0]
+    return isinstance(plan, PhysicalTableReader)
+
+
+def _filtered_leaf(node) -> Optional[_ReplicaLeaf]:
+    """The replica leaf at the bottom of a compiled chain of row
+    filters (``_filters_a_reader``), or None where the chain compiled
+    into anything else."""
+    if isinstance(node, _SelNode):
+        return _filtered_leaf(node.child)
+    if isinstance(node, _JoinNode) and node.tp in ("semi", "anti") \
+            and node.probe_is_left:
+        return _filtered_leaf(node.probe)
+    return node if isinstance(node, _ReplicaLeaf) else None
+
+
+def _without_others(plan: PhysicalHashJoin) -> PhysicalHashJoin:
+    """A shallow copy of ``plan`` without its residual conditions."""
+    import copy
+    bare = copy.copy(plan)
+    bare.other_conditions = []
+    return bare
 
 
 class _JoinNode:
@@ -1709,9 +1759,19 @@ class _JoinNode:
             return None
         if plan.tp not in ("inner", "left", "semi", "anti"):
             return None
-        if not plan.left_keys or plan.other_conditions \
+        if not plan.left_keys \
                 or len(plan.left_keys) != len(plan.right_keys):
             return None
+        if plan.other_conditions:
+            # an inner join's residual conditions filter its output
+            # rows: a selection directly above the join (a decorrelated
+            # subquery's comparison with the aggregate it joined)
+            if plan.tp != "inner" \
+                    or not all(is_jittable(c) for c in plan.other_conditions):
+                return None
+            join = _JoinNode.compile(_without_others(plan), ctx)
+            return None if join is None \
+                else _SelNode(join, plan.other_conditions, plan)
         for k in list(plan.left_keys) + list(plan.right_keys):
             if not isinstance(k, ExprColumn) \
                     or k.eval_type is not EvalType.INT \
@@ -3362,7 +3422,7 @@ def _probe_rows(node, key):
     there: through selections, projections that read it as it is and
     the probe side of probe-shaped joins.  None otherwise."""
     if isinstance(node, _ReplicaLeaf):
-        return node, key.index
+        return (node, key.index) if node.order is None else None
     if isinstance(node, _SelNode):
         return _probe_rows(node.child, key)
     if isinstance(node, _ProjNode):
@@ -3430,8 +3490,183 @@ def _composite_spec(cols):
     return los, his, strides, comp, null_any, total
 
 
+def _agg_output(node, slot: int) -> bool:
+    """Whether slot ``slot`` of ``node``'s view is computed from a
+    GROUP BY's aggregate outputs (through projections, selections and
+    the sides of joins), not from a table's own columns."""
+    if isinstance(node, (_AggIndexNode, _SortGroupNode, _KeyGroupNode)):
+        return node.out_map[slot][0] == "agg"
+    if isinstance(node, _SelNode):
+        return _agg_output(node.child, slot)
+    if isinstance(node, _ProjNode):
+        cols = node.exprs[slot].collect_columns()
+        return bool(cols) and any(_agg_output(node.child, c.index)
+                                  for c in cols)
+    if isinstance(node, _JoinNode):
+        return _agg_output(*_join_side_slot(node, slot))
+    return False
+
+
+def _view_conds(node, conds) -> bool:
+    """Whether ``conds``, filtering the output of the join ``node``,
+    read an aggregate output of its build side's view: the comparison
+    of a decorrelated scalar subquery (counter
+    ``pipe_join_view_conds``)."""
+    if not isinstance(node, _JoinNode) or node.tp in ("semi", "anti"):
+        return False
+    for i in _slots_read(conds):
+        side, at = _join_side_slot(node, i)
+        if side is node.build and _agg_output(side, at):
+            return True
+    return False
+
+
+def _source_column(node, slot: int):
+    """(replica leaf, its slot) whose column's values slot ``slot`` of
+    ``node``'s view holds, in whatever order: through selections,
+    projections that read a column as it is, either side of a join and
+    a sorted GROUP BY's keys.  None otherwise."""
+    if isinstance(node, _ReplicaLeaf):
+        return node, slot
+    if isinstance(node, _SelNode):
+        return _source_column(node.child, slot)
+    if isinstance(node, _ProjNode):
+        e = node.exprs[slot]
+        return _source_column(node.child, e.index) \
+            if isinstance(e, ExprColumn) else None
+    if isinstance(node, _JoinNode):
+        return _source_column(*_join_side_slot(node, slot))
+    if isinstance(node, _AggIndexNode):
+        kind, i = node.out_map[slot]
+        key = node.key_cols[i] if kind == "gb" else None
+        return _source_column(node.chain, key.index) \
+            if isinstance(key, ExprColumn) else None
+    return None
+
+
+def _agg_spec(node, slot: int):
+    """(sorted GROUP BY node, its (kind, argument) spec) that computes
+    slot ``slot`` of ``node``'s view, through selections, column
+    projections and either side of a join; None otherwise."""
+    if isinstance(node, _SelNode):
+        return _agg_spec(node.child, slot)
+    if isinstance(node, _ProjNode):
+        e = node.exprs[slot]
+        return _agg_spec(node.child, e.index) \
+            if isinstance(e, ExprColumn) else None
+    if isinstance(node, _JoinNode):
+        return _agg_spec(*_join_side_slot(node, slot))
+    if isinstance(node, _AggIndexNode):
+        kind, i = node.out_map[slot]
+        if kind == "agg" and node.slots[i][0] == "one":
+            return node, node.specs[node.slots[i][1]]
+    return None
+
+
+def _whole_bound(leaf: _ReplicaLeaf, slot: int) -> Optional[float]:
+    """The largest |value| of the leaf's column where every value is a
+    whole number (NULLs aside), else None: observed once a replica
+    version."""
+    from .tpu_executors import _slot_id
+    rep = leaf.replica()
+    sid = _slot_id(leaf.ex, slot)
+    if sid == "handle":
+        return float(np.abs(rep.handles).max(initial=0))
+    vals, nulls = rep.columns[sid]
+
+    def check():
+        v = vals[~nulls]
+        if v.dtype.kind == "f":
+            if not np.isfinite(v).all() or (v != np.round(v)).any():
+                return None
+        elif v.dtype.kind not in "iu":
+            return None
+        return float(np.abs(v).max(initial=0))
+    return rep.memo(("whole_bound", sid), check)
+
+
+def _scaled_quotient(e):
+    """(k, s, c) where ``e`` is ``k * (s / c)``, ``(s / c) * k`` or
+    ``s / c`` over two columns (k = 1), else None."""
+    k = 1.0
+    if getattr(e, "name", "") == "*" and len(e.children()) == 2:
+        a, b = e.children()
+        if isinstance(b, Constant):
+            a, b = b, a
+        if not (isinstance(a, Constant) and isinstance(a.value, (int, float))
+                and not isinstance(a.value, bool)):
+            return None
+        k, e = float(a.value), b
+    if getattr(e, "name", "") != "/" or len(e.children()) != 2:
+        return None
+    s, c = e.children()
+    if isinstance(s, ExprColumn) and isinstance(c, ExprColumn) \
+            and exactcmp.scalable(k):
+        return k, s, c
+    return None
+
+
+def _exact_ratio(node, cond):
+    """(op, x slot, s slot, c slot, k) where ``cond``, over ``node``'s
+    view, is ``x op k * (s / c)`` (either way round) with ``x`` a column
+    of whole numbers below 2^31, ``s`` a sorted GROUP BY's SUM of such a
+    column whose every sum stays below 2^40, and ``c`` its COUNT: a
+    comparison :mod:`~tinysql_tpu.ops.exactcmp` decides as IEEE doubles
+    do.  None otherwise (the comparison of the doubles as they are)."""
+    name = getattr(cond, "name", "")
+    if name not in exactcmp.FLIPPED or len(cond.children()) != 2:
+        return None
+    a, b = cond.children()
+    for x, e, op in ((a, b, name), (b, a, exactcmp.FLIPPED[name])):
+        got = _scaled_quotient(e)
+        if not isinstance(x, ExprColumn) or got is None:
+            continue
+        k, sc, cc = got
+        src = _source_column(node, x.index)
+        bound = _whole_bound(*src) if src is not None else None
+        if bound is None or bound >= 1 << 31:
+            return None
+        s_of, c_of = _agg_spec(node, sc.index), _agg_spec(node, cc.index)
+        if s_of is None or c_of is None or s_of[0] is not c_of[0] \
+                or s_of[1][0] != "sum" \
+                or c_of[1][0] not in ("count", "count_star"):
+            return None
+        agg, (_kind, arg) = s_of
+        src = _source_column(agg.chain, arg.index) \
+            if isinstance(arg, ExprColumn) else None
+        bound = _whole_bound(*src) if src is not None else None
+        if bound is None \
+                or bound * max(agg.leaf.replica().n_rows, 1) >= 2.0 ** 40:
+            return None
+        return op, x.index, sc.index, cc.index, k
+    return None
+
+
+def _exact_fn(op: str, xs: int, ss: int, cs: int, kslots: tuple):
+    """Device closure of an :func:`_exact_ratio` comparison: the lanes
+    as the whole numbers they hold, decided in 64-bit integers."""
+    def whole(jn, v, bits):
+        if v.dtype != jn.int64:
+            v = jn.round(v).astype(jn.int64)
+        return jn.clip(v, 1 - (1 << bits), (1 << bits) - 1)
+
+    def f(pairs, pr):
+        jn = _jn()
+        (xv, xn), (sv, sn), (cv, cn) = pairs[xs], pairs[ss], pairs[cs]
+        pi = pr[0]
+        v, null = exactcmp.compare(
+            jn, op, whole(jn, xv, 31), whole(jn, sv, exactcmp.S_BITS),
+            jn.clip(cv.astype(jn.int64), 0, (1 << 31) - 1),
+            *(pi[k] for k in kslots))
+        return v.astype(jn.int64), null | xn | sn | cn
+    return f
+
+
 class _SelNode:
-    """Filter over a device view: conditions AND into the validity mask."""
+    """Filter over a device view: conditions AND into the validity mask.
+    Directly above a join it holds the join's residual conditions, and a
+    condition there may compare a probe lane with the build view's
+    aggregate output (``_view_conds``)."""
 
     def __init__(self, child, conds, plan):
         self.child = child
@@ -3453,9 +3688,21 @@ class _SelNode:
             pb, frozenset(live | _slots_read(self.conds)))
         if tv is None:
             return None
+        if _view_conds(self.child, self.conds):
+            kernels.stats_add("pipe_join_view_conds", 1)
         pt = ParamTable()
-        fns = [compile_expr_params(c, pt) for c in self.conds]
-        keys = tuple(stable_shape_key(c) for c in self.conds)
+        fns, keys = [], []
+        for c in self.conds:
+            got = _exact_ratio(self.child, c)
+            if got is None:
+                fns.append(compile_expr_params(c, pt))
+                keys.append(stable_shape_key(c))
+                continue
+            op, xs, ss, cs, k = got
+            fns.append(_exact_fn(op, xs, ss, cs, tuple(
+                pt.add_int(v) for v in exactcmp.split_double(k))))
+            keys.append(f"exact:{op}@{xs},{ss},{cs}")
+        keys = tuple(keys)
         ip, fp = pb.params(pt)
         pb.key(("sel", keys, tv.nb, len(tv.meta)), live, len(tv.meta))
 

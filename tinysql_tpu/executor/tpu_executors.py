@@ -272,6 +272,65 @@ def _parse_string_cmp(chk, cond):
     return a, op, b.value
 
 
+def _parse_like(chk, cond):
+    """Recognize `string Column [NOT] LIKE string Constant`.  Returns
+    (col, pattern, escape, negated) or None."""
+    from ..expression import Column as ExprColumn, Constant, ScalarFunction
+    from ..mytypes import EvalType as ET
+    negated = False
+    if isinstance(cond, ScalarFunction) and cond.name == "not" \
+            and len(cond.args) == 1:
+        cond, negated = cond.args[0], True
+    if not (isinstance(cond, ScalarFunction) and cond.name == "like"
+            and len(cond.args) == 2):
+        return None
+    a, b = cond.args
+    escape = getattr(cond._scalar_fn, "like_escape", None)
+    if not (isinstance(a, ExprColumn) and isinstance(b, Constant)) \
+            or a.eval_type is not ET.STRING \
+            or not isinstance(b.value, str) or escape is None:
+        return None
+    if chk.columns[a.index].values().dtype.kind != "U":
+        return None
+    return a, b.value, escape, negated
+
+
+def _like_code_words(uniques, pattern: str, escape: str,
+                     negated: bool) -> np.ndarray:
+    """The dictionary codes a LIKE keeps, as a bit set: bit c of word
+    c >> 6 is set where entry c of the ordered dictionary ``uniques``
+    matches (the host's own ``like``, evaluated once an entry), or fails
+    to under NOT LIKE.  NULL (code = card) is in neither."""
+    from ..expression import like_to_regex
+    rx = like_to_regex(pattern, escape)
+    hit = np.fromiter((rx.match(u) is not None for u in uniques.tolist()),
+                      dtype=bool, count=len(uniques))
+    if negated:
+        hit = ~hit
+    bits = np.zeros(max(1, -(-len(hit) // 64)) * 64, dtype=bool)
+    bits[:len(hit)] = hit
+    weights = np.left_shift(np.uint64(1), np.arange(64, dtype=np.uint64))
+    return (bits.reshape(-1, 64).astype(np.uint64) * weights).sum(
+        axis=1, dtype=np.uint64).view(np.int64)
+
+
+def _code_set_fn(idx: int, word0: int, card_s: int):
+    """Device closure: membership of each row's dictionary code in the
+    bit set whose words are the int parameters from ``word0`` on (a LIKE
+    on a dictionary-coded column); a NULL is in no set."""
+    def f(cols, params):
+        jn = kernels.jnp()
+        code, null = cols[idx]
+        pi = params[0]
+        card = pi[card_s]
+        at = jn.clip(code, 0, jn.maximum(card - 1, 0))
+        word = pi[word0 + (at >> 6)]
+        hit = (word >> (at & 63)) & 1
+        live = (code != card) & ~null
+        return (hit * live).astype(jn.int64), jn.zeros_like(null)
+    return f
+
+
 def _code_cmp_fn(idx: int, op: str, lo_s: int, hi_s: int, card_s: int):
     """Device closure: string compare as an int compare over the slot's
     dictionary-code column, with per-query [lo, hi) bounds + NULL code as
@@ -305,6 +364,7 @@ def _build_device_mask(ex, rep, chk, conds, pt):
     needed = set()
     for cond in conds:
         sc = _parse_string_cmp(chk, cond)
+        like = _parse_like(chk, cond) if sc is None else None
         if sc is not None:
             col, op, val = sc
             idx = col.index
@@ -317,6 +377,21 @@ def _build_device_mask(ex, rep, chk, conds, pt):
             fns.append(_code_cmp_fn(idx, op, pt.add_int(lo),
                                     pt.add_int(hi), pt.add_int(card)))
             keys.append(f"strcmp@{idx}:{op}")
+            needed.add((idx, "codes"))
+        elif like is not None:
+            col, pattern, escape, negated = like
+            idx = col.index
+            got = _rep_string_dict(rep, _slot_id(ex, idx), chk, idx)
+            if got is None:
+                return None
+            _codes, card, _, uniques = got
+            words = _like_code_words(uniques, pattern, escape, negated)
+            word0 = pt.add_int(int(words[0]))
+            for w in words[1:]:
+                pt.add_int(int(w))
+            fns.append(_code_set_fn(idx, word0, pt.add_int(card)))
+            # the set's width is the program's; its bits are parameters
+            keys.append(f"like@{idx}:{len(words)}")
             needed.add((idx, "codes"))
         elif is_jittable(cond):
             fns.append(compile_expr_params(cond, pt))

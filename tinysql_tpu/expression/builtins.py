@@ -861,6 +861,9 @@ def new_function(name: str, args: List[Expression]) -> ScalarFunction:
                 escape = str(esc.value)
             args = args[:2]
         s, v = _make_like(escape)
+        # read by the device's LIKE over dictionary codes: the function
+        # object is carried along whenever the expression is rebuilt
+        s.like_escape = escape
         return ScalarFunction(name, args, new_int_type(), s, v)
     if name == "in":
         fam = _cmp_family(args)

@@ -95,6 +95,17 @@ _DEVICE_METRICS = {
                           "a device, traced to fold the build's validity "
                           "into the table: read once a key, not once a "
                           "probe row"),
+    "pipe_join_view_conds": ("tinysql_pipe_join_view_conds_total",
+                             "Of those joins, the ones under a condition "
+                             "that reads an aggregate output of the build "
+                             "side's view: a decorrelated scalar "
+                             "subquery's comparison, traced into the "
+                             "program as a filter of the join's rows"),
+    "subq_decorrelated": ("tinysql_subq_decorrelated_total",
+                          "Correlated scalar-aggregate subqueries the "
+                          "planner rewrote into a GROUP BY on the "
+                          "correlation columns joined back to the outer "
+                          "query, the comparison a filter above the join"),
     "agg_key_cut": ("tinysql_agg_key_cut_total",
                     "Fused GROUP BYs above a join chain formed on the one "
                     "key (a table's primary key) that determines the "

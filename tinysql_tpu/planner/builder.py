@@ -32,6 +32,15 @@ class PlanError(Exception):
 class UnknownColumn(PlanError):
     def __init__(self, name):
         super().__init__(f"Unknown column '{name}'")
+        self.name = str(name)
+
+
+class UnsupportedSubquery(PlanError):
+    """A subquery shape the planner cannot run, named (ER_NOT_SUPPORTED_YET)."""
+    mysql_code = 1235
+
+    def __init__(self, shape: str):
+        super().__init__(f"This version doesn't yet support '{shape}'")
 
 
 class AmbiguousColumn(PlanError):
@@ -142,13 +151,23 @@ class ExprRewriter:
             # of program-cache keys.  Note an IN-list landing here (not a
             # decorrelated top-level WHERE conjunct) gets SCALAR
             # semantics: >1 subquery row is a loud 1242 error.
-            v = self.builder.eval_scalar_subquery(e)
+            try:
+                v = self.builder.eval_scalar_subquery(e)
+            except UnknownColumn as err:
+                # a column of this scope referenced inside: correlated,
+                # and not where planner/decorrelate.py rewrites one
+                from .decorrelate import correlated_name_error
+                raise correlated_name_error(err, self.schema) from err
             return Constant(v, _lit_ft(v))
         if isinstance(e, ast.ExistsExpr):
             # EXISTS outside a decorrelatable WHERE conjunct: eager
-            # boolean evaluation (uncorrelated only — a correlated column
-            # fails name resolution inside)
-            v = self.builder.eval_exists_subquery(e)
+            # boolean evaluation (uncorrelated only: a correlated column
+            # inside is refused by name)
+            try:
+                v = self.builder.eval_exists_subquery(e)
+            except UnknownColumn as err:
+                from .decorrelate import correlated_name_error
+                raise correlated_name_error(err, self.schema) from err
             f = Constant(v, _lit_ft(v))
             return new_function("not", [f]) if e.negated else f
         if isinstance(e, ast.RowExpr):

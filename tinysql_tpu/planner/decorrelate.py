@@ -15,12 +15,26 @@ the shapes this grammar produces:
   join ``other_conditions``; fully-local conjuncts stay inside the
   subquery.  An uncorrelated EXISTS degenerates to a cartesian semi join
   (the executor only checks build-side emptiness).
-- A scalar subquery anywhere in an expression is evaluated EAGERLY at
-  plan time and folded to a Constant — the reference evaluates
-  uncorrelated scalar subqueries during optimization the same way, and
-  the PR 6 literal parameterization erases the folded constant from
-  program cache keys, so a changed subquery result is still a compiled
-  program HIT.
+- A CORRELATED scalar subquery that is a direct operand of a comparison
+  in a top-level WHERE conjunct (``l_quantity < (SELECT 0.2 * avg(x)
+  FROM t WHERE t.k = outer.k)``) becomes an aggregate grouped by the
+  subquery's correlation columns, computed once for every key, joined
+  back on those equalities, with the comparison as a filter above the
+  join (the Apply-over-aggregation arm of rule_decorrelate.go).  Exact
+  where the subquery's value is NULL over an empty group and the
+  comparison rejects NULL: one aggregate of MIN, MAX, SUM or AVG, or
+  arithmetic over one; an outer row with no group then drops out of the
+  inner join as its comparison with NULL dropped it.  COUNT (0 over an
+  empty group), ``<=>``, a correlation other than an equality of
+  columns, GROUP BY / HAVING / LIMIT in the subquery, a correlated
+  column in its select list or inside a subquery nested in it are
+  refused by name (``UnsupportedSubquery``, error 1235).
+- Any other scalar subquery is evaluated EAGERLY at plan time and folded
+  to a Constant — the reference evaluates uncorrelated scalar
+  subqueries during optimization the same way, and the literal
+  parameterization erases the folded constant from program cache keys,
+  so a changed subquery result is still a compiled program HIT.  A
+  correlated one there (the select list, under OR) is refused by name.
 
 The pass runs INSIDE PlanBuilder.build_select, before the residual WHERE
 becomes a LogicalSelection, so everything downstream (pushdown, pruning,
@@ -30,10 +44,18 @@ from __future__ import annotations
 
 from typing import List, Optional, Tuple
 
-from ..expression import Column, Expression, fold_constants, split_cnf
+from ..expression import (Column, Expression, fold_constants, new_function,
+                          split_cnf)
 from ..parser import ast
-from .logical import (JOIN_ANTI, JOIN_SEMI, LogicalJoin, LogicalPlan,
-                      LogicalSelection, LogicalTableDual)
+from .logical import (JOIN_ANTI, JOIN_INNER, JOIN_SEMI, LogicalJoin,
+                      LogicalPlan, LogicalSelection, LogicalTableDual)
+
+#: comparisons that reject NULL: an outer row whose group is empty
+#: compares with the subquery's NULL and drops out, as it drops out of
+#: the inner join that replaces the subquery (``<=>`` holds for NULLs)
+_NULL_REJECTING = ("=", "!=", "<", "<=", ">", ">=")
+#: aggregates whose value over no row is NULL (COUNT's is 0)
+_NULL_ON_EMPTY = ("min", "max", "sum", "avg")
 
 
 def split_and_conjuncts(e: ast.ExprNode) -> List[ast.ExprNode]:
@@ -81,7 +103,13 @@ def apply_where_subqueries(builder, p: LogicalPlan,
     for conj in split_and_conjuncts(where):
         got = _subquery_conjunct(conj)
         if got is None:
-            residual.append(conj)
+            cmp = _scalar_comparison(conj)
+            joined = build_scalar_agg_join(builder, p, *cmp) \
+                if cmp is not None else None
+            if joined is None:
+                residual.append(conj)
+            else:
+                p = joined
             continue
         kind, node, negated = got
         if kind == "in":
@@ -89,6 +117,158 @@ def apply_where_subqueries(builder, p: LogicalPlan,
         else:
             p = build_exists_join(builder, p, node, negated)
     return p, residual
+
+
+def _unparen(e: ast.ExprNode) -> ast.ExprNode:
+    while isinstance(e, ast.ParenExpr):
+        e = e.expr
+    return e
+
+
+def _scalar_comparison(e: ast.ExprNode):
+    """(comparison, its subquery operand, whether that is the left one)
+    when `e` compares with a scalar subquery as a direct operand."""
+    e = _unparen(e)
+    if not (isinstance(e, ast.BinaryOp)
+            and e.op in _NULL_REJECTING + ("<=>",)):
+        return None
+    for left, side in ((True, e.left), (False, e.right)):
+        side = _unparen(side)
+        if isinstance(side, ast.SubqueryExpr):
+            return e, side, left
+    return None
+
+
+def _has_subquery(e: ast.ExprNode) -> bool:
+    return any(isinstance(x, (ast.SubqueryExpr, ast.ExistsExpr))
+               for x in ast.walk_expr(e))
+
+
+def _arithmetic_over(e: ast.ExprNode, agg: ast.AggFunc) -> bool:
+    """`e` is `agg`, or + - * / and unary minus over it and literals:
+    NULL wherever `agg` is."""
+    e = _unparen(e)
+    if e is agg or isinstance(e, ast.Literal):
+        return True
+    if isinstance(e, ast.UnaryOp) and e.op == "-":
+        return _arithmetic_over(e.operand, agg)
+    return isinstance(e, ast.BinaryOp) and e.op in ("+", "-", "*", "/") \
+        and _arithmetic_over(e.left, agg) and _arithmetic_over(e.right, agg)
+
+
+def correlated_name_error(err, outer_schema):
+    """The error for an unknown column met inside a subquery: the shape
+    refused by name where the column is one of the enclosing query's
+    (``outer_schema``), else ``err`` as it is."""
+    from .builder import UnsupportedSubquery, _find_in_schema
+    parts = err.name.split(".")
+    ref = ast.ColumnRef(parts[-1], table=parts[-2] if len(parts) > 1
+                        else "")
+    if _find_in_schema(outer_schema, ref):
+        return UnsupportedSubquery(
+            "a correlated subquery other than a comparison operand in a"
+            " top-level WHERE conjunct, correlated by column equalities")
+    return err
+
+
+def build_scalar_agg_join(builder, p: LogicalPlan, cmp: ast.BinaryOp,
+                          sub: ast.SubqueryExpr, sub_left: bool
+                          ) -> Optional[LogicalPlan]:
+    """``outer_expr <op> (SELECT f(agg(x)) FROM ... WHERE inner.k =
+    outer.k AND ...)`` -> ``Selection(outer_expr <op> v, p JOIN (SELECT
+    inner.k, f(agg(x)) AS v FROM ... WHERE ... GROUP BY inner.k) ON
+    outer.k = inner.k)``.  None where the subquery references nothing of
+    the outer query in its WHERE (the eager fold's); refused by name
+    where it does in a shape this arm cannot make exact."""
+    from .builder import ExprRewriter, UnknownColumn, UnsupportedSubquery
+    stmt = sub.select
+    if stmt.from_ is None or stmt.where is None:
+        return None
+    sub_p = builder.build_table_refs(stmt.from_)
+    rw = ExprRewriter(sub_p.schema, builder, outer_schema=p.schema)
+    local: List[ast.ExprNode] = []
+    keys = []   # (AST of the inner column, outer Column)
+    for conj in split_and_conjuncts(stmt.where):
+        if _has_subquery(conj):
+            local.append(conj)  # built in its own scope below
+            continue
+        e = rw.rewrite(conj)
+        if all(sub_p.schema.contains(c) for c in e.collect_columns()):
+            local.append(conj)
+            continue
+        pair = _eq_pair(e, p.schema, sub_p.schema)
+        eq = _unparen(conj)
+        if pair is None or not all(isinstance(x, Column) for x in pair) \
+                or not isinstance(eq, ast.BinaryOp):
+            raise UnsupportedSubquery(
+                "correlated scalar subquery correlated by other than an"
+                " equality of an inner and an outer column")
+        inner_ast = next(_unparen(x) for x in (eq.left, eq.right)
+                         if rw.resolve_column(_unparen(x)) is pair[1])
+        keys.append((inner_ast, pair[0]))
+    if not keys:
+        return None
+
+    def refuse(what: str):
+        return UnsupportedSubquery(f"correlated scalar subquery {what}")
+    if cmp.op == "<=>":
+        raise refuse("under <=> (an empty group compares true there)")
+    if stmt.group_by or stmt.having is not None or stmt.distinct \
+            or stmt.order_by or stmt.limit is not None:
+        raise refuse("with GROUP BY, HAVING, DISTINCT, ORDER BY or LIMIT")
+    if len(stmt.fields) != 1 or stmt.fields[0].is_wildcard:
+        raise refuse("of more than one column")
+    field = stmt.fields[0].expr
+    aggs = [x for x in ast.walk_expr(field) if isinstance(x, ast.AggFunc)]
+    if len(aggs) != 1 or aggs[0].name not in _NULL_ON_EMPTY + ("count",):
+        raise refuse("whose value is not one aggregate")
+    if aggs[0].name == "count":
+        raise refuse("over COUNT (an empty group counts 0, which the"
+                     " join that replaces the subquery cannot give)")
+    from .builder import _find_in_schema
+    if any(isinstance(x, ast.ColumnRef)
+           and not _find_in_schema(sub_p.schema, x)
+           and _find_in_schema(p.schema, x) for x in ast.walk_expr(field)):
+        raise refuse("with a correlated column in its select list")
+    if not _arithmetic_over(field, aggs[0]):
+        raise refuse("whose value is not arithmetic over its aggregate")
+
+    n = getattr(builder, "subq_decorrelated", 0) + 1
+    builder.subq_decorrelated = n
+    where = None
+    for c in local:
+        where = c if where is None else ast.BinaryOp("and", where, c)
+    # the aggregate's parts are the derived table's columns and the
+    # arithmetic over them moves into the comparison above the join; an
+    # AVG is its SUM over its COUNT (NULL over no row, as the AVG), so
+    # that the comparison sees the two exact parts of the quotient
+    agg = aggs[0]
+    parts = [agg] if agg.name != "avg" else [
+        ast.AggFunc(name, list(agg.args), agg.distinct)
+        for name in ("sum", "count")]
+    derived = ast.SelectStmt(
+        fields=[ast.SelectField(k, as_name=f"_subq{n}_k{i}")
+                for i, (k, _) in enumerate(keys)]
+        + [ast.SelectField(a, as_name=f"_subq{n}_a{j}")
+           for j, a in enumerate(parts)],
+        from_=stmt.from_, where=where, group_by=[k for k, _ in keys])
+    try:
+        sub_plan = builder.build_select(derived)
+    except UnknownColumn as err:
+        raise correlated_name_error(err, p.schema) from err
+    join = LogicalJoin(JOIN_INNER, p, sub_plan)
+    join.eq_conditions.extend(
+        (outer, col)
+        for (_, outer), col in zip(keys, sub_plan.schema.columns))
+    got = sub_plan.schema.columns[len(keys):]
+    value = got[0] if len(got) == 1 else new_function("/", list(got))
+    rw = ExprRewriter(join.schema, builder, agg_mapper={id(agg): value})
+    v = rw.rewrite(field)
+    other = rw.rewrite(cmp.right if sub_left else cmp.left)
+    cond = new_function(cmp.op, [v, other] if sub_left else [other, v])
+    from ..ops import kernels
+    kernels.stats_add("subq_decorrelated", 1)
+    return LogicalSelection([fold_constants(cond)], join)
 
 
 def build_in_join(builder, p: LogicalPlan, ie: ast.InExpr,
@@ -122,9 +302,13 @@ def build_exists_join(builder, p: LogicalPlan, ex: ast.ExistsExpr,
         return LogicalJoin(tp, p, LogicalTableDual(row_count=0))
     if stmt.group_by or stmt.having or stmt.distinct or _has_aggs(stmt):
         # aggregate-shaped EXISTS: build the full subquery plan and use
-        # it as an (uncorrelated) cartesian build side.  A correlated
-        # column inside would fail name resolution — loudly.
-        sub = builder.build_select(stmt)
+        # it as an (uncorrelated) cartesian build side; a correlated
+        # column inside is refused by name
+        from .builder import UnknownColumn
+        try:
+            sub = builder.build_select(stmt)
+        except UnknownColumn as err:
+            raise correlated_name_error(err, p.schema) from err
         return LogicalJoin(tp, p, sub)
     if stmt.from_ is None:
         # EXISTS (SELECT <exprs>): one constant row, always non-empty

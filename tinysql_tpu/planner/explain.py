@@ -61,7 +61,9 @@ def _info(p: PhysicalPlan) -> str:
         mesh = getattr(p, "mesh_strategy", None)
         mesh = f", mesh:{mesh}" if mesh else ""
         na = ", null-aware" if getattr(p, "null_aware", False) else ""
-        return f"{p.tp} join, equal:[{keys}]{mesh}{na}"
+        other = ",".join(c.key() for c in p.other_conditions)
+        other = f", other cond:[{other}]" if other else ""
+        return f"{p.tp} join, equal:[{keys}]{other}{mesh}{na}"
     if isinstance(p, (PhysicalSort, PhysicalTopN)):
         by = ",".join(f"{e.key()}{' desc' if d else ''}" for e, d in p.by)
         extra = (f", offset:{p.offset}, count:{p.count}"
@@ -255,6 +257,10 @@ def _device_info(st) -> str:
         # joins whose key -> row table is small beside the probe: the
         # build's validity folded into it, read once a key
         parts.append(f"folded:{int(d['pipe_joins_folded'])}")
+    if d.get("pipe_join_view_conds"):
+        # joins whose condition reads the build view's aggregate output
+        # (a decorrelated subquery's comparison)
+        parts.append(f"view_conds:{int(d['pipe_join_view_conds'])}")
     if d.get("agg_key_cut"):
         parts.append(f"key_cut:{int(d['agg_key_cut'])}")
     if d.get("agg_key_mesh"):
@@ -294,6 +300,12 @@ def explain_analyze_text(p: PhysicalPlan, qobs, depth: int = 0,
     act = str(st.act_rows) if st is not None else ""
     einfo = _exec_info(st) if st is not None else ""
     dinfo = _device_info(st) if st is not None else ""
+    n = qobs.device_totals().get("subq_decorrelated") \
+        if qobs is not None and depth == 0 else 0
+    if n:
+        # the planner's count, made before any operator ran: the
+        # statement's, shown on its root
+        dinfo = ", ".join(x for x in (f"decorrelated:{int(n)}", dinfo) if x)
     out.append(["  " * depth + name, _est_rows(p), act, _task(p),
                 einfo, dinfo, _info(p)])
     if isinstance(p, PhysicalTableReader):

@@ -280,11 +280,15 @@ def _unique_on(side: LogicalPlan, key_uids: Set[int], n_keys: int) -> bool:
                          LogicalLimit)):
         return _unique_on(side.child(0), key_uids, n_keys)
     if isinstance(side, LogicalProjection):
-        # identity columns pass through; expression outputs don't
-        ident = {e.unique_id for e in side.exprs if isinstance(e, Column)}
-        if not key_uids <= ident:
+        # column outputs pass through, under their own name or an alias
+        # (a derived table's); expression outputs don't
+        src = {oc.unique_id: e.unique_id
+               for e, oc in zip(side.exprs, side.schema.columns)
+               if isinstance(e, Column)}
+        if not key_uids <= set(src):
             return False
-        return _unique_on(side.child(0), key_uids, n_keys)
+        return _unique_on(side.child(0), {src[u] for u in key_uids},
+                          n_keys)
     if isinstance(side, LogicalJoin) and side.tp in (JOIN_SEMI, JOIN_ANTI):
         # a semi/anti join never duplicates left rows: uniqueness of the
         # left child survives
@@ -494,7 +498,8 @@ def normalize_logical(logical: LogicalPlan,
     from .rules_extra import (eliminate_aggregation, eliminate_max_min,
                               eliminate_outer_joins, eliminate_projections,
                               join_reorder, push_agg_through_join,
-                              push_semi_joins_down, split_lookup_keys)
+                              push_semi_joins_down, semi_join_filters,
+                              split_lookup_keys)
     root_needed = {c.unique_id for c in logical.schema.columns}
     _propagate_constants_in_plan(logical)
     logical = eliminate_outer_joins(logical, root_needed)
@@ -507,6 +512,7 @@ def normalize_logical(logical: LogicalPlan,
     # Q18: the aggregate then stands on the inner joins and its partial
     # sums go below them, to the table stored in the key's order)
     logical = push_semi_joins_down(logical)
+    logical = semi_join_filters(logical)
     logical = push_agg_through_join(logical)
     column_pruning(logical, root_needed)
     logical = eliminate_aggregation(logical)

@@ -486,6 +486,89 @@ def _sink_semi(semi: LogicalJoin) -> LogicalPlan:
     return semi
 
 
+# ===== filter-only joins under a one-table aggregate ======================
+
+def semi_join_filters(p: LogicalPlan) -> LogicalPlan:
+    """Below a GROUP BY whose keys and arguments are all one table's, an
+    inner join whose other side is unique on its join keys and gives no
+    column above the join only FILTERS that table's rows: it becomes a
+    semi join (each row matches at most one row there, so the inner
+    join neither duplicates nor extends it), and sinks next to the side
+    its keys come from.  The aggregate then stands on its table under a
+    chain of row filters, as one over the table alone does (TPC-H Q2's
+    decorrelated subquery: min(ps_supplycost) by ps_partkey over
+    partsupp joined to supplier and nation only to keep a region's
+    suppliers)."""
+    p.children = [semi_join_filters(c) for c in p.children]
+    if not isinstance(p, LogicalAggregation) or not p.group_by:
+        return p
+    used = [e for e in p.group_by] + [a for d in p.agg_funcs
+                                      for a in d.args]
+    uids = {c.unique_id for e in used for c in e.collect_columns()}
+    got = [_column_source(p.child(0), u) for u in uids]
+    if not got or any(g is None for g in got) \
+            or len({id(g[0]) for g in got}) != 1:
+        return p
+    gb = {e.unique_id for e in p.group_by if isinstance(e, Column)}
+    while _semi_one(p, uids, gb):
+        p.children[0] = push_semi_joins_down(p.child(0))
+    return p
+
+
+def _semi_one(agg: LogicalAggregation, needed: Set[int],
+              gb: Set[int]) -> bool:
+    """Turn the topmost filter-only inner join below ``agg`` into a semi
+    join; whether one was found.  A join on GROUP BY columns alone is
+    left as it is: the aggregate pushdown moves the whole aggregate below
+    it (a partial aggregate grouped by the join's key is then complete),
+    where below a join on another column it leaves a final one above."""
+    needed = set(needed)
+    parent, at = agg, 0
+    node = agg.child(0)
+    while True:
+        if isinstance(node, LogicalSelection):
+            needed |= {c.unique_id for e in node.conditions
+                       for c in e.collect_columns()}
+        elif isinstance(node, LogicalJoin) and node.tp in ("semi", "anti"):
+            needed |= {c.unique_id for a, _ in node.eq_conditions
+                       for c in a.collect_columns()}
+            needed |= {c.unique_id for e in node.other_conditions
+                       for c in e.collect_columns()}
+        elif isinstance(node, LogicalJoin) and node.tp == JOIN_INNER \
+                and node.eq_conditions and not node.other_conditions \
+                and not node.left_conditions \
+                and not node.right_conditions:
+            sides = [{c.unique_id for c in ch.schema.columns}
+                     for ch in node.children]
+            keep = 0 if needed <= sides[0] else 1 if needed <= sides[1] \
+                else None
+            if keep is None:
+                return False
+            other = node.children[1 - keep]
+            keys = [(a, b) if keep == 0 else (b, a)
+                    for a, b in node.eq_conditions]
+            okeys = {c.unique_id for _, b in keys
+                     for c in b.collect_columns()}
+            kkeys = {c.unique_id for a, _ in keys
+                     for c in a.collect_columns()}
+            if not kkeys <= gb \
+                    and all(isinstance(b, Column) for _, b in keys) \
+                    and any(k and k <= okeys for k in unique_key_sets(other)):
+                semi = LogicalJoin("semi", node.children[keep], other)
+                semi.eq_conditions = keys
+                parent.children[at] = semi
+                return True
+            needed |= {c.unique_id for a, b in keys
+                       for c in a.collect_columns() + b.collect_columns()}
+            parent, at = node, keep
+            node = node.children[keep]
+            continue
+        else:
+            return False
+        parent, at = node, 0
+        node = node.children[0]
+
+
 # ===== aggregation pushdown through join ===================================
 
 def push_agg_through_join(p: LogicalPlan) -> LogicalPlan:
